@@ -338,8 +338,7 @@ def _lint_shard_map_sites(path: str, tree: ast.Module,
     (an internal builder whose CALLER tags the returned runner). An
     untagged site is a device schedule the layout explorer cannot see —
     exactly how a pad-waste or collective regression hides from
-    ``cli layout``. The compat shim (``fks_tpu.utils.compat``) is not a
-    site: it forwards to the underlying implementation by another name."""
+    ``cli layout``."""
     funcs = [n for n in ast.walk(tree)
              if isinstance(n, (ast.FunctionDef, ast.AsyncFunctionDef))]
     for site in _shard_map_sites(tree):

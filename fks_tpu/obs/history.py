@@ -18,11 +18,7 @@ that index:
   an alert per subsequent run;
 - ``best_healthy()``: the best healthy historical run for a metric —
   what ``cli compare --baseline auto`` resolves, replacing hand-picked
-  baselines;
-- ``last_healthy_headline()``: the newest healthy nonzero bench headline
-  — what a FAILED bench probe's fallback JSON carries (with a
-  ``stale_from_run`` marker) instead of a bare 0.0, so ``cli compare
-  --gate`` keeps a real denominator.
+  baselines.
 
 Serve-tier SLOs ride along: ``SLOConfig`` declares p99/qps targets and
 ``slo_burn`` prices observed latencies against them as burn rates (the
@@ -32,8 +28,9 @@ SLO is being violated), recorded as ``slo_burn`` metrics and surfaced by
 
 Health: a run dir is healthy when its meta status is ``ok`` and it
 recorded no alert events; a bench file is healthy when it carries a
-measured (nonzero, non-stale) headline. Stale fallback headlines are
-indexed but never re-selected as baselines — staleness must not chain.
+measured (nonzero, non-stale) headline. A record carrying another run's
+headline under ``stale_from_run`` (the retired bench fallback wrote
+them) is indexed but never selected as a baseline.
 """
 from __future__ import annotations
 
@@ -313,26 +310,6 @@ class RunHistory:
             if (v >= bv) if higher else (v <= bv):
                 best = e
         return best
-
-    def last_healthy_headline(self) -> Optional[Dict[str, Any]]:
-        """The NEWEST healthy entry with a measured ``evals_per_sec``
-        headline — the stale-fallback donor for a failed bench probe.
-        Returns ``{"value", "run", "path", "ts"}``, plus the donor's
-        memory budgets (``peak_device_bytes``/``exe_temp_bytes``) when it
-        recorded them — a failed probe's fallback line can then keep the
-        budget trend populated (explicitly stale: compare's candidate
-        side ignores them), or None."""
-        if not self.entries:
-            self.scan()
-        for e in reversed(self.entries):
-            if e["healthy"] and e["metrics"].get("evals_per_sec"):
-                out = {"value": e["metrics"]["evals_per_sec"],
-                       "run": e["run"], "path": e["path"], "ts": e["ts"]}
-                for key in ("peak_device_bytes", "exe_temp_bytes"):
-                    if key in e["metrics"]:
-                        out[key] = e["metrics"][key]
-                return out
-        return None
 
 
 def _median(vals: List[float]) -> float:

@@ -31,16 +31,22 @@ from fks_tpu.obs.recorder import get_recorder
 COMPILE_PREFIX = "/jax/core/compile"
 #: the key measuring the actual XLA backend compile (vs trace/lowering)
 BACKEND_COMPILE = "backend_compile_duration"
+#: the (duration-less) event JAX records when the persistent compilation
+#: cache answers a compile request
+CACHE_HIT = "/jax/compilation_cache/cache_hits"
 
 
 class CompileWatcher:
-    """Capture every jit compilation's (key, duration) while installed.
+    """Capture every jit compilation's (key, duration) while installed,
+    and count how many of them the persistent compilation cache answered
+    (``cache_hits``): JAX times a cache fetch under the same
+    ``backend_compile_duration`` key as a real compile, so the number of
+    programs XLA actually compiled is ``backend_compile_count -
+    cache_hits`` (``compiled_count``).
 
-    ``jax.monitoring`` listeners are global and additive; uninstall uses
-    the private-but-stable ``_unregister_event_duration_listener_by_
-    callback`` when available and otherwise leaves an inert callback
-    behind (the ``_installed`` gate makes it a no-op — never clear ALL
-    listeners, other subsystems may have their own).
+    ``jax.monitoring`` listeners are global and additive; uninstall
+    removes only this watcher's two callbacks (never clear ALL listeners,
+    other subsystems may have their own).
 
     Usable as a context manager::
 
@@ -53,6 +59,7 @@ class CompileWatcher:
         self.recorder = recorder if recorder is not None else get_recorder()
         self.prefix = prefix
         self.events: List[tuple] = []  # (key, seconds)
+        self.cache_hits = 0
         self._lock = threading.Lock()
         self._installed = False
 
@@ -64,22 +71,25 @@ class CompileWatcher:
             self.events.append((key, float(seconds)))
         self.recorder.event("compile", key=key, seconds=float(seconds))
 
+    def _listen_event(self, key: str, **kwargs) -> None:
+        if self._installed and key == CACHE_HIT:
+            with self._lock:
+                self.cache_hits += 1
+
     def install(self) -> "CompileWatcher":
         if not self._installed:
             self._installed = True
             jax.monitoring.register_event_duration_secs_listener(self._listen)
+            jax.monitoring.register_event_listener(self._listen_event)
         return self
 
     def uninstall(self) -> None:
         if not self._installed:
             return
         self._installed = False  # gate first: inert even if unregister fails
-        try:
-            from jax._src import monitoring as _monitoring
-            _monitoring._unregister_event_duration_listener_by_callback(
-                self._listen)
-        except Exception:  # pragma: no cover - private API moved
-            pass
+        from jax._src import monitoring as _monitoring
+        _monitoring.unregister_event_duration_listener(self._listen)
+        _monitoring.unregister_event_listener(self._listen_event)
 
     def __enter__(self) -> "CompileWatcher":
         return self.install()
@@ -108,6 +118,12 @@ class CompileWatcher:
         with self._lock:
             return sum(1 for k, _ in self.events
                        if k.endswith(BACKEND_COMPILE))
+
+    @property
+    def compiled_count(self) -> int:
+        """Programs XLA actually compiled: backend compile requests minus
+        the ones the persistent cache answered."""
+        return self.backend_compile_count - self.cache_hits
 
     @property
     def backend_compile_seconds(self) -> float:

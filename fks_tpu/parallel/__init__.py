@@ -10,6 +10,7 @@ from fks_tpu.parallel.population import (  # noqa: F401
 )
 from fks_tpu.parallel.mesh import (  # noqa: F401
     DCN_AXIS, POP_AXIS, hybrid_population_mesh, init_distributed,
+    lanes_per_device,
     make_sharded_code_eval, make_sharded_eval, make_sharded_generation_step,
     make_sharded_serve_fn, num_shards, occupancy_stats, pad_population,
     pad_stats, population_mesh, serve_lane_count, serve_sharding,

@@ -40,7 +40,6 @@ from fks_tpu.data.entities import Workload
 from fks_tpu.models import parametric
 from fks_tpu.parallel.population import ParamPolicyFn, lead_axis_size
 from fks_tpu.sim.engine import SimConfig, initial_state, make_population_run_fn
-from fks_tpu.utils.compat import shard_map
 from fks_tpu.utils.segments import segment_budget
 
 POP_AXIS = "pop"
@@ -75,8 +74,7 @@ def init_distributed(coordinator_address: Optional[str] = None,
     """
     explicit = any(v is not None
                    for v in (coordinator_address, num_processes, process_id))
-    from fks_tpu.utils.compat import distributed_is_initialized
-    if not distributed_is_initialized():
+    if not jax.distributed.is_initialized():
         try:
             jax.distributed.initialize(
                 coordinator_address=coordinator_address,
@@ -252,6 +250,20 @@ def shard_population(params, mesh: Mesh):
 _shard_params = shard_population  # internal alias, kept for call sites
 
 
+def lanes_per_device(x) -> dict:
+    """``{device id: lanes held}`` for a device array whose leading axis
+    is the lane/candidate axis (``addressable_shards``). A mesh launch in
+    which some device holds no lanes is a placement bug the result alone
+    cannot show; chip_smoke.py fails on it."""
+    out: dict = {}
+    for sh in x.addressable_shards:
+        # from the shard's index, not ``sh.data``: the serve tier calls
+        # this per batch, and ``.data`` materializes a per-device array
+        lanes = len(range(*sh.index[0].indices(x.shape[0])))
+        out[sh.device.id] = out.get(sh.device.id, 0) + lanes
+    return out
+
+
 # -------------------------------------------------------- serve batch axis
 #
 # The serving tier (fks_tpu.serve) coalesces concurrent what-if queries
@@ -296,7 +308,7 @@ def make_sharded_serve_fn(serve_fn, mesh: Mesh, layout=None):
     from fks_tpu.obs.layout import record_layout, tag_layout
     spec = _resolve_layout(layout)
     axes = _pop_axes(mesh)
-    fn = shard_map(serve_fn, mesh=mesh,
+    fn = jax.shard_map(serve_fn, mesh=mesh,
                    in_specs=(P(axes), P(axes), P(axes)),
                    out_specs=P(axes), check_vma=False)
     record_layout("serve", spec, mesh=mesh)
@@ -316,7 +328,7 @@ def make_sharded_vm_serve_fn(serve_fn, mesh: Mesh, layout=None):
     from fks_tpu.obs.layout import record_layout, tag_layout
     spec = _resolve_layout(layout)
     axes = _pop_axes(mesh)
-    fn = shard_map(serve_fn, mesh=mesh,
+    fn = jax.shard_map(serve_fn, mesh=mesh,
                    in_specs=(P(), P(axes), P(axes), P(axes)),
                    out_specs=P(axes), check_vma=False)
     record_layout("vm_serve", spec, mesh=mesh)
@@ -336,7 +348,7 @@ def make_sharded_portfolio_serve_fn(serve_fn, mesh: Mesh, layout=None):
     from fks_tpu.obs.layout import record_layout, tag_layout
     spec = _resolve_layout(layout)
     axes = _pop_axes(mesh)
-    fn = shard_map(serve_fn, mesh=mesh,
+    fn = jax.shard_map(serve_fn, mesh=mesh,
                    in_specs=(P(), P(axes), P(axes), P(axes), P(axes)),
                    out_specs=P(axes), check_vma=False)
     record_layout("portfolio_serve", spec, mesh=mesh)
@@ -376,16 +388,14 @@ def _top_k_real(global_scores, real_count, k):
 # NOTE on check_vma=False: the engine's inner heap loops mix invariant
 # literals into varying carries; the varying-manual-axes audit rejects that
 # even though the program is correct. Correctness of the sharded path is
-# covered by the sharded-vs-vmap parity tests instead. (On jax 0.4.x the
-# same audit is spelled check_rep — the fks_tpu.utils.compat shim
-# translates.)
+# covered by the sharded-vs-vmap parity tests instead.
 
 
 def _engine_runner(workload, param_policy, cfg, engine):
     """(population run fn, initial state) for the chosen engine."""
-    if engine == "fused":
-        from fks_tpu.parallel.population import fused_runner
-        frun = fused_runner(workload, param_policy, cfg)
+    from fks_tpu.parallel.population import FUSED_ENGINES, fused_runner
+    if engine in FUSED_ENGINES:
+        frun = fused_runner(workload, param_policy, cfg, engine)
         return (lambda params, _state0: frun(params)), None
     from fks_tpu.sim import get_engine
     mod = get_engine(engine)
@@ -452,7 +462,7 @@ def make_sharded_eval(workload: Workload, mesh: Mesh,
     out_specs = (P(axes), P(), P()) + ((P(axes),) if cfg.decision_trace else ())
 
     @functools.partial(
-        shard_map, mesh=mesh,
+        jax.shard_map, mesh=mesh,
         in_specs=(P(axes), P()),
         out_specs=out_specs,
         check_vma=False,
@@ -499,7 +509,7 @@ def make_sharded_generation_step(workload: Workload, mesh: Mesh,
     axes = _pop_axes(mesh)
 
     @functools.partial(
-        shard_map, mesh=mesh,
+        jax.shard_map, mesh=mesh,
         in_specs=(P(axes), P(), P()),
         out_specs=(P(axes), P(axes), P()),
         check_vma=False,
@@ -571,9 +581,10 @@ def make_sharded_code_eval(workload: Workload, mesh: Mesh,
     ``truncated``/``policy_score``, not a bare fitness vector.
 
     ``seg_steps > 0`` bounds each device call to ~``seg_steps`` events per
-    dispatch (the FKS_VM_SEG_STEPS contract, for runtimes that kill long
-    device executions); engines without segmented internals fall back to
-    the single-dispatch path. ``on_segment`` (zero-arg callable) fires on
+    dispatch (the FKS_VM_SEG_STEPS contract: the host regains control
+    between dispatches — see ``flat.make_segmented_population_run``);
+    engines without segmented internals fall back to the single-dispatch
+    path. ``on_segment`` (zero-arg callable) fires on
     the host after every segment dispatch — the flight recorder's segment
     counter; ignored on the single-dispatch path.
 
@@ -603,7 +614,7 @@ def make_sharded_code_eval(workload: Workload, mesh: Mesh,
     axes = _pop_axes(mesh)
 
     @functools.partial(
-        shard_map, mesh=mesh,
+        jax.shard_map, mesh=mesh,
         in_specs=(P(axes), P()),
         out_specs=(P(axes), P(), P()),
         check_vma=False,
@@ -655,7 +666,7 @@ def _make_segmented_code_eval(workload: Workload, mesh: Mesh, cfg: SimConfig,
 
     @jax.jit
     @functools.partial(
-        shard_map, mesh=mesh,
+        jax.shard_map, mesh=mesh,
         in_specs=(P(axes), P(axes)),
         out_specs=(P(axes), P()),
         check_vma=False,
@@ -677,7 +688,7 @@ def _make_segmented_code_eval(workload: Workload, mesh: Mesh, cfg: SimConfig,
 
     @jax.jit
     @functools.partial(
-        shard_map, mesh=mesh,
+        jax.shard_map, mesh=mesh,
         in_specs=(P(axes), P()),
         out_specs=(P(axes), P(), P()),
         check_vma=False,

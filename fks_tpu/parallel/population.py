@@ -47,21 +47,28 @@ def lead_axis_size(tree) -> int:
     return jax.tree_util.tree_leaves(tree)[0].shape[0]
 
 
+#: engine names served by the fused Pallas kernel. "fused" compiles under
+#: Mosaic (TPU only); "fused_interpret" is the same kernel in the Pallas
+#: interpreter — slow, for correctness tests on hosts without a TPU.
+FUSED_ENGINES = ("fused", "fused_interpret")
+
+
 def fused_runner(workload: Workload, param_policy, cfg: SimConfig,
-                 lanes: int = 64, interpret: bool | None = None):
+                 engine: str = "fused", lanes: int = 64):
     """The ONE dispatch point for the fused Pallas engine (shared by the
     vmap path here and the shard_map path in fks_tpu.parallel.mesh, so the
     fused contract cannot drift between them). The kernel hard-wires the
     parametric feature basis, so any other policy is rejected. ``lanes``
     caps the per-grid-step chunk (the kernel auto-shrinks it to the VMEM
-    budget); ``interpret=None`` auto-selects Mosaic on TPU."""
+    budget). Interpret mode is never inferred from the backend: it runs
+    only under the engine name that asks for it."""
     if param_policy is not parametric.score:
         raise ValueError("engine='fused' hard-wires the parametric feature "
                          "basis; pass param_policy=parametric.score or use "
                          "engine='flat'")
     from fks_tpu.sim import fused
-    return fused.make_fused_population_run(workload, cfg, lanes=lanes,
-                                           interpret=interpret)
+    return fused.make_fused_population_run(
+        workload, cfg, lanes=lanes, interpret=engine == "fused_interpret")
 
 
 def make_population_eval(workload: Workload,
@@ -82,10 +89,11 @@ def make_population_eval(workload: Workload,
     retry-time rule; ~an order of magnitude faster per step on TPU);
     "fused" is the Pallas whole-loop-in-VMEM kernel (fks_tpu.sim.fused —
     flat semantics, parametric policies ONLY: ``param_policy`` must be
-    the default ``parametric.score``).
+    the default ``parametric.score``; "fused_interpret" runs the same
+    kernel in the Pallas interpreter for hosts without a TPU).
     """
-    if engine == "fused":
-        run = fused_runner(workload, param_policy, cfg)
+    if engine in FUSED_ENGINES:
+        run = fused_runner(workload, param_policy, cfg, engine)
         # jit covers run()'s XLA-side pre/post work (padding, aux decode,
         # finalize) around the pallas_call
         return jax.jit(run) if jit else run

@@ -35,7 +35,6 @@ from fks_tpu.parallel.population import ParamPolicyFn
 from fks_tpu.parallel.traces import make_trace_batch_eval, stack_traces
 from fks_tpu.scenarios.suite import ScenarioSuite
 from fks_tpu.sim.engine import SimConfig
-from fks_tpu.utils.compat import shard_map
 
 AGGREGATIONS = ("mean", "min", "cvar")
 
@@ -148,7 +147,7 @@ def make_sharded_suite_eval(suite: ScenarioSuite, mesh: Mesh,
             population=True, jit=False, engine=engine)
 
         @functools.partial(
-            shard_map, mesh=mesh,
+            jax.shard_map, mesh=mesh,
             in_specs=(P(axes), P()),
             out_specs=(P(axes), P(axes), P(), P()),
             check_vma=False,
@@ -216,7 +215,7 @@ def _scenario_sharded_suite_eval(suite, mesh, param_policy, cfg, rc,
                              in_axes=(0, 0)), in_axes=(None, 0))
 
     @functools.partial(
-        shard_map, mesh=mesh,
+        jax.shard_map, mesh=mesh,
         in_specs=(P(axes), P(), P(SCN_AXIS), P(SCN_AXIS), P(SCN_AXIS)),
         out_specs=(P(axes), P(axes, SCN_AXIS), P(), P()),
         check_vma=False,

@@ -9,7 +9,7 @@
 - service: request/metrics layer, JSONL + localhost HTTP fronts, selftest.
 """
 from fks_tpu.serve.artifact import (
-    ChampionSpec, ServeEngine, ShapeEnvelope, enable_persistent_cache,
+    ChampionSpec, ServeEngine, ShapeEnvelope,
     latest_champion, load_champion,
 )
 from fks_tpu.serve.batcher import (
@@ -23,7 +23,7 @@ from fks_tpu.serve.vm_engine import VMServeEngine
 
 __all__ = [
     "ChampionSpec", "ServeEngine", "ShapeEnvelope", "VMServeEngine",
-    "enable_persistent_cache", "latest_champion", "load_champion",
+    "latest_champion", "load_champion",
     "DEFAULT_DURATION", "POD_FIELDS", "RequestBatcher",
     "build_query_workload", "pack_program_tables", "pack_query_tables",
     "pods_to_dicts", "query_pack_plan", "stack_queries",

@@ -18,10 +18,11 @@ plus a declared shape envelope into a **no-recompile** query engine:
   ARGUMENTS — the inverse of ``make_trace_batch_eval``'s closure capture,
   which would re-trace per batch. Calling the resulting ``Compiled``
   executable can never trigger compilation, so the zero-recompile warm
-  path is structural, not best-effort. ``jax.export`` does not exist on
-  the installed jax (0.4.37), so cross-process persistence rides the JAX
-  compilation cache instead (``enable_persistent_cache``): a reloaded
-  artifact re-lowers but fetches the XLA binary from the cache.
+  path is structural, not best-effort. An artifact on disk is its
+  ``artifact.json`` alone: a reloaded one re-lowers, and fetches the XLA
+  binaries from the process's one persistent compilation cache
+  (``fks_tpu.utils.place_compile_cache``, placed by the entry point —
+  never repointed here).
 
 The engine answers are plain dicts (score, scheduled count, per-pod
 placements) so the service layer can JSON them straight out.
@@ -46,8 +47,8 @@ from fks_tpu import obs
 from fks_tpu.data.entities import ClusterArrays, Workload
 from fks_tpu.obs.memory import record_footprint
 from fks_tpu.parallel.mesh import (
-    make_sharded_serve_fn, num_shards, occupancy_stats, pad_population,
-    serve_lane_count, serve_sharding,
+    lanes_per_device, make_sharded_serve_fn, num_shards, occupancy_stats,
+    pad_population, serve_lane_count, serve_sharding,
 )
 from fks_tpu.serve.batcher import (
     build_query_workload, pack_query_tables, pods_to_dicts, query_pack_plan,
@@ -237,22 +238,6 @@ class ShapeEnvelope:
 # ---------------------------------------------------------- persistence
 
 
-def enable_persistent_cache(cache_dir: str) -> None:
-    """Point the JAX compilation cache at ``cache_dir`` with the size/time
-    floors dropped, so even small serve programs persist. jax 0.4.37 has
-    no ``jax.export``; this cache is the AOT persistence story — a
-    process that re-lowers the same program fetches the compiled binary
-    instead of re-running XLA."""
-    os.makedirs(cache_dir, exist_ok=True)
-    jax.config.update("jax_compilation_cache_dir", cache_dir)
-    for opt, val in (("jax_persistent_cache_min_compile_time_secs", 0.0),
-                     ("jax_persistent_cache_min_entry_size_bytes", -1)):
-        try:
-            jax.config.update(opt, val)
-        except AttributeError:  # option renamed on some jax versions
-            pass
-
-
 def _cluster_to_json(c: ClusterArrays) -> dict:
     """Cluster arrays as JSON-serializable lists (clusters are small —
     O(nodes) ints — so JSON keeps the artifact single-file-inspectable)."""
@@ -389,6 +374,8 @@ class ServeEngine:
         # the engine already does: zero new fences, zero device effects.
         self.last_batch_timing: Dict[str, float] = {
             "pack_h2d_s": 0.0, "dispatch_s": 0.0}
+        # device id -> lanes it held in the most recent harvested chunk
+        self.last_lanes_per_device: Dict[int, int] = {}
 
         n, g = self.cluster.n_padded, self.cluster.g_padded
         self.param_policy, self.params, self.policy_tier = \
@@ -706,6 +693,7 @@ class ServeEngine:
                           real=real) as t:
                 t.sync(res.policy_score)
             hs.sync(res.policy_score)
+        self.last_lanes_per_device = lanes_per_device(res.policy_score)
         res = jax.device_get(res)
         self.last_batch_timing["dispatch_s"] += time.perf_counter() - t0
         # eval-time layout ledger row: per-batch occupancy attributed to
@@ -771,9 +759,9 @@ class ServeEngine:
 
     def save(self, directory: str) -> str:
         """Persist the engine spec (champion + cluster + envelope + knobs)
-        as ``artifact.json`` and point the JAX compilation cache at the
-        artifact's ``xla_cache/`` so compiled programs persist alongside.
-        ``warmup()`` first to bank every bucket's binary."""
+        as ``artifact.json``. Compiled programs are not part of the
+        artifact: they live in the process's persistent compilation
+        cache, wherever the entry point placed it."""
         os.makedirs(directory, exist_ok=True)
         doc = {
             "version": ARTIFACT_VERSION,
@@ -796,17 +784,16 @@ class ServeEngine:
         with open(tmp, "w") as f:
             json.dump(doc, f)
         os.replace(tmp, path)  # atomic: a loader never sees a half-write
-        enable_persistent_cache(os.path.join(directory, "xla_cache"))
         return path
 
     @classmethod
     def load(cls, directory: str, recorder=None, mesh=None) -> "ServeEngine":
         """Rebuild a saved engine. Self-contained: the artifact pins the
-        cluster arrays and the resolved prefilter-k (no re-probe), and
-        re-attaches the artifact's compilation cache so ``compiled_for``
-        fetches banked binaries instead of re-running XLA. ``mesh`` is a
-        RUNTIME property (device topology differs per process), so it is
-        passed here, never persisted."""
+        cluster arrays and the resolved prefilter-k (no re-probe).
+        ``compiled_for`` re-lowers each bucket and hits the process's
+        persistent compilation cache where an earlier run compiled it.
+        ``mesh`` is a RUNTIME property (device topology differs per
+        process), so it is passed here, never persisted."""
         with open(os.path.join(directory, "artifact.json")) as f:
             doc = json.load(f)
         if doc.get("version") != ARTIFACT_VERSION:
@@ -844,7 +831,6 @@ class ServeEngine:
                   state_pack=bool(doc["state_pack"]),
                   max_steps_factor=int(doc["max_steps_factor"]),
                   mesh=mesh, recorder=recorder, **extra)
-        enable_persistent_cache(os.path.join(directory, "xla_cache"))
         return eng
 
 

@@ -6,7 +6,8 @@ Why: the XLA flat engine (fks_tpu.sim.flat) is a while_loop whose body is
 bandwidth-bound at ~110 us/step for 256 lanes on a v5e chip (PROFILE.md).
 Every one of those bytes moves HBM<->VMEM each step because XLA keeps
 while_loop carries in HBM. The queue for 64 lanes is ~4 MB — it FITS in
-VMEM (~16 MB/core). This kernel keeps it there: the full event loop runs
+VMEM (128 MiB per v5e core; the kernel raises Mosaic's 16 MiB default
+scoped limit). This kernel keeps it there: the full event loop runs
 inside one ``pl.pallas_call``, so per-step traffic is zero HBM bytes and
 the step cost is pure VPU/MXU work on resident arrays.
 
@@ -21,8 +22,9 @@ Kernel shape notes (Mosaic/TPU constraints):
 - per-lane scalars are [L, 1] columns; iotas via ``broadcasted_iota``
   (1-D iota does not lower on TPU);
 - the popped pod's feature row is fetched with an MXU one-hot matmul
-  ``mask_f32 [L,Q] @ feat_f32 [Q,8]`` — exact because every pod feature
-  value is < 2**24 (asserted at build time); aux (which can exceed 2**24
+  ``mask_f32 [L,Q] @ feat_f32 [Q,8]`` at HIGHEST precision — exact
+  because every pod feature value is < 2**24 (asserted at build time) and
+  no operand is rounded to bf16; aux (which can exceed 2**24
   once node/gpu bits are packed) is fetched with an integer masked reduce;
 - the GPU best-fit sub-allocation is G static rounds of lexicographic
   min-picking over the winner node's [L, N, G] milli row — same
@@ -60,6 +62,16 @@ from fks_tpu.sim.types import SimResult
 _BIG = 2**30
 _EXACT_F32 = 1 << 24  # one-hot matmul gathers are exact below this
 
+#: What the lane plan may fill with the arrays the kernel names, and the
+#: scoped-VMEM limit the kernel asks Mosaic for. The two differ because the
+#: plan cannot see Mosaic's own allocations — the pipeline's double-buffered
+#: output blocks, the step's [L, Q] temporaries, the HIGHEST-precision
+#: matmul's passes: at the plan's 12.8 MB (64 lanes, default trace) the
+#: compiler asked for 16.8 MB, over its 16 MiB default limit. A v5e core
+#: has 128 MiB of VMEM.
+_VMEM_PLAN_BYTES = 14 * 2**20
+_VMEM_LIMIT_BYTES = 48 * 2**20
+
 
 def _iota(shape, dim):
     return jax.lax.broadcasted_iota(jnp.int32, shape, dim)
@@ -67,6 +79,19 @@ def _iota(shape, dim):
 
 def _round_up(x: int, m: int) -> int:
     return ((x + m - 1) // m) * m
+
+
+# [L, N, G] -> per-lane reductions that never pass through a rank-1
+# vector (Mosaic's layout inference aborts on a reshape of one): reduce
+# the minor axis first, then the node axis with keepdims.
+def _sum_ng(x):
+    return jnp.sum(jnp.sum(x, axis=2, dtype=jnp.int32), axis=1,
+                   keepdims=True, dtype=jnp.int32)            # [L,1]
+
+
+def _min_ng(x):
+    return jnp.min(jnp.min(x, axis=2, keepdims=True), axis=1,
+                   keepdims=True)                              # [L,1,1]
 
 
 class _Plan(NamedTuple):
@@ -240,9 +265,14 @@ def _kernel(plan: _Plan, lanes: int,
         # Mosaic) reject
         aux_s = jnp.sum(jnp.where(mask_b, auxv, 0), axis=1,
                         keepdims=True, dtype=jnp.int32)           # [L,1]
+        # HIGHEST, or the gather is not exact: at the TPU's default matmul
+        # precision the MXU rounds f32 operands to bf16, which keeps 8 bits
+        # of a pod's cpu/memory value (first chip run: every lane's
+        # trajectory left flat's within 50 pods)
         pf = jax.lax.dot_general(
             mask_b.astype(f32), feat_ref[:],
             dimension_numbers=(((1,), (1,)), ((), ())),
+            precision=jax.lax.Precision.HIGHEST,
             preferred_element_type=f32)                           # [L,8]
         pcpu = pf[:, 0:1].astype(jnp.int32)
         pmem = pf[:, 1:2].astype(jnp.int32)
@@ -311,7 +341,9 @@ def _kernel(plan: _Plan, lanes: int,
         raw = jnp.sum(feats * w_all[:, None, :], axis=-1) * SCORE_SCALE
         feasible = (nmask_b
                     & (pcpu <= cpu_v) & (pmem <= mem_v) & (pngpu <= gpu_v)
-                    & jnp.where(pod_gpu, eligible >= pngpu, True))
+                    # or-of-masks, not where(): Mosaic has no select
+                    # that produces an i1 vector
+                    & (~pod_gpu | (eligible >= pngpu)))
         scores = jnp.where(feasible,
                            jnp.maximum(1, jnp.trunc(raw).astype(jnp.int32)),
                            0)                                     # [L,N]
@@ -326,12 +358,11 @@ def _kernel(plan: _Plan, lanes: int,
         oh_w = (n_iota == wn).astype(jnp.int32)                   # [L,N]
         elig_w = (gmask_b & (gmil_v >= pmilli[:, :, None])
                   & (oh_w[:, :, None] > 0))                       # [L,N,G]
-        n_elig = jnp.sum(elig_w.astype(jnp.int32), axis=(1, 2),
-                         keepdims=False, dtype=jnp.int32)[:, None]  # [L,1]
+        n_elig = _sum_ng(elig_w.astype(jnp.int32))                # [L,1]
         key = jnp.where(elig_w, gmil_v * G + g_iota3, _BIG)
         sel = jnp.zeros((L, N, G), bool)
         for k in range(G):
-            cur = jnp.min(key, axis=(1, 2))[:, None, None]        # [L,1,1]
+            cur = _min_ng(key)                                    # [L,1,1]
             take = (k < pngpu)[:, :, None] & (cur < _BIG)
             pick = (key == cur) & take
             sel = sel | pick
@@ -346,9 +377,7 @@ def _kernel(plan: _Plan, lanes: int,
         gpu_v = gpu_v - oh_p * pngpu
         gmil_v = gmil_v - (oh_p[:, :, None] * pmilli[:, :, None]
                            * sel.astype(jnp.int32))
-        new_bits = jnp.sum(
-            jnp.where(sel, jnp.int32(1) << g_iota3, 0), axis=(1, 2),
-            dtype=jnp.int32)[:, None]
+        new_bits = _sum_ng(jnp.where(sel, jnp.int32(1) << g_iota3, 0))
 
         # ---- failed creation: waiting histogram + fragmentation + retry
         failp = create & ~placed
@@ -362,8 +391,7 @@ def _kernel(plan: _Plan, lanes: int,
         mn = jnp.where(has_w, mn, 0)
         frag_free = jnp.where(
             gmask_b & (gmil_v > 0) & (gmil_v < mn[:, :, None]), gmil_v, 0)
-        fsum = jnp.sum(frag_free, axis=(1, 2),
-                       dtype=jnp.int32)[:, None]                  # [L,1] i32
+        fsum = _sum_ng(frag_free)                                 # [L,1] i32
         frag_score = jnp.where(
             has_w & (t_gm > 0), fsum.astype(f32) / f32(max(t_gm, 1)),
             f32(0))
@@ -395,14 +423,14 @@ def _kernel(plan: _Plan, lanes: int,
         fire = valid & (snap_idx < K) & (events >= kt_at)
         firef = fire.astype(f32)
         u_cpu = f32(t_cpu) - jnp.sum(
-            cpu_v, axis=1, dtype=jnp.int32)[:, None].astype(f32)
+            cpu_v, axis=1, keepdims=True, dtype=jnp.int32).astype(f32)
         u_mem = f32(t_mem) - jnp.sum(
-            mem_v, axis=1, dtype=jnp.int32)[:, None].astype(f32)
+            mem_v, axis=1, keepdims=True, dtype=jnp.int32).astype(f32)
         u_gc = jnp.sum(
-            num_gpus - gpu_v, axis=1, dtype=jnp.int32)[:, None].astype(f32)
-        u_gm = f32(t_gm) - jnp.sum(
-            jnp.where(gmask_b, gmil_v, 0), axis=(1, 2),
-            dtype=jnp.int32)[:, None].astype(f32)
+            num_gpus - gpu_v, axis=1, keepdims=True,
+            dtype=jnp.int32).astype(f32)
+        u_gm = f32(t_gm) - _sum_ng(
+            jnp.where(gmask_b, gmil_v, 0)).astype(f32)
         utils = jnp.concatenate([
             0.0 * u_cpu if t_cpu <= 0 else u_cpu / f32(max(t_cpu, 1)),
             0.0 * u_mem if t_mem <= 0 else u_mem / f32(max(t_mem, 1)),
@@ -415,7 +443,7 @@ def _kernel(plan: _Plan, lanes: int,
         active_nodes = jnp.sum(
             (nmask_b & ((cpu_v < cpu_tot) | (mem_v < mem_tot)
                         | (gpu_v < gpu_dec))).astype(jnp.int32),
-            axis=1, dtype=jnp.int32)[:, None]
+            axis=1, keepdims=True, dtype=jnp.int32)
         acci[:, 0:1] = acci[:, 0:1] - (is_del | dropped).astype(jnp.int32)
         acci[:, 1:2] = steps + active.astype(jnp.int32)
         acci[:, 2:3] = events
@@ -445,16 +473,15 @@ def _kernel(plan: _Plan, lanes: int,
 def make_fused_population_run(workload: Workload,
                               cfg: SimConfig = SimConfig(),
                               lanes: int = 64,
-                              interpret: bool | None = None):
+                              interpret: bool = False):
     """``run(params[P, F]) -> SimResult`` (leading axis P) through the fused
     kernel. P is padded up to a multiple of ``lanes``; each chunk of
     ``lanes`` candidates is one grid step.
 
-    ``interpret=None`` (default) auto-selects: Mosaic-compile on TPU,
-    pallas interpreter elsewhere (slow — CPU callers should prefer
-    engine="exact"; the interpreter exists for correctness tests)."""
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+    Compiled by Mosaic (TPU only) unless ``interpret=True`` asks for the
+    Pallas interpreter by name — slow, for correctness tests off the chip.
+    The mode is never chosen from the backend: a non-TPU caller that
+    forgets to ask fails at compile instead of silently interpreting."""
     plan = _build_plan(workload, cfg)
     Q, N, G = plan.q, plan.n, plan.g
     p = workload.pods
@@ -500,6 +527,8 @@ def make_fused_population_run(workload: Workload,
                 pltpu.VMEM((L, 8), jnp.int32),
                 pltpu.VMEM((L, 8), jnp.float32),
             ],
+            compiler_params=pltpu.CompilerParams(
+                vmem_limit_bytes=_VMEM_LIMIT_BYTES),
             interpret=interpret,
         )(params_padded, plan.ev0, plan.feat_f, plan.ktable, plan.nrow,
           plan.gmt, plan.gmask)
@@ -511,11 +540,11 @@ def make_fused_population_run(workload: Workload,
     # VMEM feasibility: ~5 [L,q] i32 live arrays (ev, aux, blend mask +
     # fusion temps), the tile-padded [L,n,128] grids, the [L,hist]
     # waiting histogram, and slack for the small accumulators. Lanes
-    # auto-shrink to fit (~14 of the ~16 MB/core VMEM); shapes that
-    # cannot fit even 8 lanes are rejected up front instead of letting
-    # Mosaic fail opaquely — the XLA flat engine handles them.
+    # auto-shrink to fit _VMEM_PLAN_BYTES; shapes that cannot fit even 8
+    # lanes are rejected up front instead of letting Mosaic fail
+    # opaquely — the XLA flat engine handles them.
     per_lane_bytes = (5 * Q + 3 * N * 128 + plan.hist + 2048) * 4
-    lanes_fit = (14 * 2**20 // per_lane_bytes) // 8 * 8
+    lanes_fit = (_VMEM_PLAN_BYTES // per_lane_bytes) // 8 * 8
     if lanes_fit < 8:
         raise ValueError(
             f"workload too large for the fused kernel's VMEM plan "

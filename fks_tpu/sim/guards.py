@@ -1,8 +1,8 @@
 """Numerics watchdog guards: mask-and-flag NaN/Inf/range detection.
 
 The watchdog's device half. ``jax.experimental.checkify`` lifts errors out
-of jitted code but composes poorly with the repo's loop shapes on jax
-0.4.37 (``vmap``-of-``while_loop`` bodies under ``shard_map`` — checkify
+of jitted code but composes poorly with the repo's loop shapes
+(``vmap``-of-``while_loop`` bodies under ``shard_map`` — checkify
 functionalization inserts per-lane error state the manual-axes audit
 rejects), so guards are plain elementwise masks instead: ``isfinite`` +
 ``where`` survive ``vmap``/``shard_map`` trivially because they ARE the

@@ -3,7 +3,7 @@
 The reference has neither profiler hooks nor ``logging`` (SURVEY.md §5);
 these are framework additions with a reference-compatible metric schema.
 """
-from fks_tpu.utils.compat import distributed_is_initialized, shard_map
+from fks_tpu.utils.cache import place_compile_cache
 from fks_tpu.utils.logging import MetricsWriter, get_logger, result_record
 from fks_tpu.utils.profiling import (
     ThroughputMeter, Timing, block_timed, device_trace, timed,
@@ -11,8 +11,7 @@ from fks_tpu.utils.profiling import (
 from fks_tpu.utils.segments import validate_seg_steps
 
 __all__ = [
-    "MetricsWriter", "distributed_is_initialized", "get_logger",
-    "result_record", "shard_map",
+    "MetricsWriter", "get_logger", "place_compile_cache", "result_record",
     "ThroughputMeter", "Timing", "block_timed", "device_trace", "timed",
     "validate_seg_steps",
 ]

@@ -1,20 +1,17 @@
-"""bench.py stage wiring (fast tier): the code-candidate throughput stage
-runs in-process on the conftest 8-virtual-device mesh, and the fallback
-contract banks only CURRENT-round session measurements while the headline
-carries the last healthy historical value under stale_from_run
-provenance (round 14 — see the bench.py module docstring).
+"""bench.py wiring (fast tier): the code-candidate throughput stage runs
+in-process on the conftest 8-virtual-device mesh, and the controller's
+result-line contract — a number only from the run that measured it, with
+its device beside it; no accelerator or a failed stage is a non-zero exit
+with NO result line (see the bench.py module docstring).
 
 The heavy stages (flat/fused parametric throughput) need the full trace
-and are exercised by the TPU measurement session; here the codetput stage
-is routed to the micro workload so its wiring — candidate sourcing via
-``vm.lower_fake_candidates``, the sharded dispatch, the JSON contract —
-stops being device-only code.
+and a chip; here the codetput stage is routed to the micro workload so
+its wiring — candidate sourcing via ``vm.lower_fake_candidates``, the
+sharded dispatch, the JSON contract — stops being device-only code.
 """
 import json
 import os
 import sys
-
-import pytest
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 if REPO not in sys.path:  # `import bench` regardless of pytest rootdir
@@ -47,9 +44,8 @@ def test_stage_codetput_sharded_smoke(micro_workload, monkeypatch, capsys):
 
 
 def test_stage_codetput_gates_on_candidate_count(micro_workload, monkeypatch):
-    """Fewer VM-able candidates than the stage needs -> rc 1 (the
-    controller treats it as a skipped probe), not a crash or a fabricated
-    number."""
+    """Fewer VM-able candidates than the stage needs -> rc 1 (and a
+    failed controller run), not a crash or a fabricated number."""
     import fks_tpu.data
     from fks_tpu.funsearch import vm
 
@@ -60,119 +56,94 @@ def test_stage_codetput_gates_on_candidate_count(micro_workload, monkeypatch):
     assert bench.stage_codetput() == 1
 
 
-def _write_round(results_dir, n, records):
-    path = results_dir / f"round{n}_tpu.jsonl"
-    path.write_text("\n".join(json.dumps(r) for r in records) + "\n")
+def test_no_result_line_without_an_accelerator(monkeypatch, capsys):
+    """No device, no number: on a CPU-only host the controller exits
+    non-zero and stdout stays empty — no carried-forward headline, no
+    banked session value, nothing a driver could parse as a result."""
+    monkeypatch.setattr(sys, "argv", ["bench.py"])
+    monkeypatch.delenv("FKS_RUN_DIR", raising=False)
+    assert bench.main() == 1
+    cap = capsys.readouterr()
+    assert cap.out == ""
+    assert "no accelerator" in cap.err
 
 
-@pytest.fixture
-def banked_repo(tmp_path, monkeypatch):
-    """Point bench's results directory at a temp tree (it is derived from
-    the module's __file__; the env override must not leak in either)."""
-    results = tmp_path / "benchmarks" / "results"
-    results.mkdir(parents=True)
-    monkeypatch.setattr(bench, "__file__", str(tmp_path / "bench.py"))
-    monkeypatch.delenv("FKS_BENCH_RESULTS_DIR", raising=False)
-    return results
+def test_failed_stage_fails_the_run_with_no_number(monkeypatch, capsys):
+    """A failed stage is a failure: no engine fallback chain, no chunk
+    quartering, no substituted code-throughput number."""
+    monkeypatch.setattr(sys, "argv", ["bench.py"])
+    monkeypatch.setattr(bench, "_device_fields", lambda: {
+        "platform": "tpu", "device_kind": "test", "device_count": 1})
+    calls = []
+    monkeypatch.setattr(bench, "stage_parity", lambda engine: 0)
+
+    def boom(pop, chunk, reps, engine):
+        calls.append(engine)
+        raise RuntimeError("Mosaic refused the kernel")
+
+    monkeypatch.setattr(bench, "measure_throughput", boom)
+    monkeypatch.setenv("FKS_BENCH_ENGINE", "fused")
+    assert bench.main() == 1
+    assert calls == ["fused"]  # one engine, named, tried once
+    cap = capsys.readouterr()
+    assert cap.out == ""
+    assert "Mosaic refused the kernel" in cap.err
+    # the code stage failing is as fatal as the headline stage
+    monkeypatch.setattr(bench, "measure_throughput",
+                        lambda *a: {"evals_per_sec": 50.0})
+    monkeypatch.setattr(bench, "measure_codetput", lambda: None)
+    assert bench.main() == 1
+    assert capsys.readouterr().out == ""
 
 
-def test_banked_measurement_only_reads_current_round(banked_repo):
-    """A prior round's (higher!) number must not leak into this round's
-    fallback — only the highest-numbered round file is evidence."""
-    _write_round(banked_repo, 5, [
-        {"ok": True, "stage": "flat", "ts": 1,
-         "result": {"evals_per_sec": 999.0}},
-        {"ok": True, "stage": "codetput", "ts": 1,
-         "result": {"code_evals_per_sec": 777.0}},
-    ])
-    _write_round(banked_repo, 6, [
-        {"ok": True, "stage": "flat", "ts": 2,
-         "result": {"evals_per_sec": 100.0, "truncated": 0}},
-        {"ok": True, "stage": "vmbatch_pop64", "ts": 3,
-         "result": {"code_evals_per_sec": 50.0}},
-        {"ok": False, "stage": "fused64", "ts": 4,
-         "result": {"evals_per_sec": 12345.0}},  # failed probe: ignored
-    ])
-    best, code_best = bench._banked_measurement()
-    assert best["value"] == 100.0 and best["file"] == "round6_tpu.jsonl"
-    assert code_best["value"] == 50.0
-    assert code_best["file"] == "round6_tpu.jsonl"
+def test_headline_line_names_its_device(monkeypatch, capsys):
+    monkeypatch.setattr(sys, "argv", ["bench.py"])
+    device = {"platform": "tpu", "device_kind": "TPU v5 lite",
+              "device_count": 1}
+    monkeypatch.setattr(bench, "_device_fields", lambda: device)
+    monkeypatch.setattr(bench, "stage_parity", lambda engine: 0)
+    monkeypatch.setattr(bench, "measure_throughput",
+                        lambda *a: {"evals_per_sec": 50.0,
+                                    "compile_seconds": 2.5})
+    monkeypatch.setattr(bench, "measure_codetput",
+                        lambda: {"code_evals_per_sec": 0.25})
+    assert bench.main() == 0
+    payload = json.loads(capsys.readouterr().out.strip().splitlines()[-1])
+    assert payload["value"] == 50.0 and payload["vs_baseline"] == 1.25
+    assert {k: payload[k] for k in device} == device
+    assert payload["engine"] == "flat"
+    assert payload["code_evals_per_sec"] == 0.25
+    for gone in ("stale_from_run", "banked_from", "code_source", "note"):
+        assert gone not in payload
 
 
-def test_banked_measurement_empty_results(banked_repo):
-    assert bench._banked_measurement() == (None, None)
+def test_cpu_pinned_stages_say_so_in_their_line():
+    """Ten standalone stages pin the CPU backend; until ROADMAP S0
+    replaces them, the line each prints carries ``"platform": "cpu"`` so
+    no CPU timing leaves under a device metric's name."""
+    import ast
+    import inspect
 
-
-def test_fallback_json_carries_stale_headline(banked_repo):
-    """Round 14 revision of the round-6 contract: a failed probe's
-    headline carries the last HEALTHY historical value under an explicit
-    ``stale_from_run`` marker (here the session's own round file is the
-    newest healthy donor); the current round's session measurement still
-    rides along under banked_from."""
-    _write_round(banked_repo, 6, [
-        {"ok": True, "stage": "flatseed", "ts": 2,
-         "result": {"evals_per_sec": 321.0}},
-        {"ok": True, "stage": "codetput", "ts": 3,
-         "result": {"code_evals_per_sec": 7.5}},
-    ])
-    payload = json.loads(bench._fallback_json("tunnel wedged"))
-    assert payload["value"] == 321.0
-    assert payload["vs_baseline"] == pytest.approx(321.0 / 40.0, abs=1e-3)
-    assert payload["stale_from_run"]["value"] == 321.0
-    assert payload["error"] == "tunnel wedged"
-    assert payload["banked_from"]["value"] == 321.0
-    assert payload["code_banked_from"]["value"] == 7.5
-    assert "NOT a live measurement" in payload["note"]
-
-
-def test_fallback_json_without_any_bank(banked_repo):
-    payload = json.loads(bench._fallback_json("no device"))
-    assert payload["value"] == 0.0
-    assert "banked_from" not in payload
-    assert "no recorded" in payload["note"]
-
-
-def test_classify_probe_failure_taxonomy():
-    """The four structured probe-failure kinds (round 7): a timeout, a
-    signal death, an import failure, and a plain init failure are told
-    apart instead of collapsing into one error string."""
-    import signal as _signal
-
-    assert bench._classify_probe_failure(None, "")[0] == "timeout"
-    kind, detail = bench._classify_probe_failure(-_signal.SIGILL, "")
-    assert kind == "sigill-risk" and "SIGILL" in detail
-    kind, _ = bench._classify_probe_failure(-9999, "")  # unknown signal
-    assert kind == "sigill-risk"
-    kind, _ = bench._classify_probe_failure(
-        1, "Traceback...\nModuleNotFoundError: no module named jax")
-    assert kind == "import-error"
-    kind, detail = bench._classify_probe_failure(1, "RuntimeError: boom")
-    assert kind == "init-failure" and "rc=1" in detail
-
-
-def test_fallback_json_carries_failure_taxonomy(banked_repo):
-    """The taxonomy rides along in the fallback payload next to the
-    stale-carried headline and the banked session measurement."""
-    _write_round(banked_repo, 6, [
-        {"ok": True, "stage": "flatseed", "ts": 2,
-         "result": {"evals_per_sec": 321.0}},
-    ])
-    attempts = [
-        {"attempt": 1, "kind": "timeout",
-         "detail": "device backend initialization timed out"},
-        {"attempt": 2, "kind": "timeout",
-         "detail": "device backend initialization timed out"},
-        {"attempt": 3, "kind": "init-failure",
-         "detail": "backend initialization failed (rc=1)"},
-    ]
-    payload = json.loads(bench._fallback_json("probe failed",
-                                              failure_taxonomy=attempts))
-    assert payload["value"] == 321.0
-    assert payload["stale_from_run"]["value"] == 321.0
-    assert payload["banked_from"]["value"] == 321.0
-    assert payload["failure_taxonomy"]["kinds"] == {
-        "timeout": 2, "init-failure": 1}
-    assert payload["failure_taxonomy"]["attempts"] == attempts
+    pinned = 0
+    for name, fn in inspect.getmembers(bench, inspect.isfunction):
+        if not name.startswith("stage_"):
+            continue
+        src = inspect.getsource(fn)
+        if '"jax_platforms", "cpu"' not in src:
+            continue
+        pinned += 1
+        tree = ast.parse(src)
+        dicts = [n.value for n in ast.walk(tree)
+                 if isinstance(n, ast.Assign)
+                 and getattr(n.targets[0], "id", "") == "payload"
+                 and isinstance(n.value, ast.Dict)]
+        assert dicts, name
+        keys = {k.value: v.value for k, v in zip(dicts[0].keys,
+                                                 dicts[0].values)
+                if isinstance(k, ast.Constant)
+                and isinstance(v, ast.Constant)}
+        assert keys.get("platform") == "cpu", name
+    assert pinned == 10
 
 
 def test_gate_judges_headline_against_baseline(tmp_path, capsys):

@@ -1,0 +1,132 @@
+"""chip_smoke.py at micro size on the CPU (fast tier).
+
+The smoke itself only runs on a TPU; its steps are functions of their
+workloads and sizes, so tier-1 drives every one of them here on the
+2-node x 6-pod micro workload — through the same entry points, over the
+8-virtual-device mesh where the step has a mesh path — and pins the two
+ways the script must refuse to produce a result: no chip, and a step
+whose check fails.
+"""
+import os
+import subprocess
+import sys
+
+import jax
+import pytest
+
+REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+if REPO not in sys.path:  # `import chip_smoke` regardless of pytest rootdir
+    sys.path.insert(0, REPO)
+
+import chip_smoke  # noqa: E402
+
+from fks_tpu import cli  # noqa: E402
+from fks_tpu.parallel import population_mesh  # noqa: E402
+
+
+@pytest.fixture
+def micro_cli(micro_workload, monkeypatch):
+    monkeypatch.setattr(cli, "_parse_workload",
+                        lambda args: ("micro", micro_workload))
+    return micro_workload
+
+
+def test_exits_nonzero_and_prints_nothing_without_a_chip():
+    env = dict(os.environ, JAX_PLATFORMS="cpu")
+    r = subprocess.run([sys.executable, os.path.join(REPO, "chip_smoke.py")],
+                       env=env, capture_output=True, text=True, timeout=120)
+    assert r.returncode != 0
+    assert r.stdout.strip() == ""
+    assert "no TPU" in r.stderr
+
+
+def test_step_parity_micro(micro_cli, tmp_path):
+    out = chip_smoke.step_parity("micro", "micro",
+                                 {"first_fit": None, "best_fit": None},
+                                 n_pods=6, out_dir=str(tmp_path))
+    assert out["ok"], out
+    assert out["policies"]["best_fit"]["scheduled"] == 6
+    # a wrong expectation fails the step instead of being waved through
+    bad = chip_smoke.step_parity("micro", "micro", {"best_fit": (0.9, 1)},
+                                 n_pods=6, out_dir=str(tmp_path))
+    assert not bad["ok"] and bad["mismatch"] == ["best_fit"]
+
+
+def test_step_evaluate_parametric_micro_over_the_mesh(micro_workload):
+    mesh = population_mesh(jax.devices())
+    out = chip_smoke.step_evaluate_parametric(micro_workload, 16, mesh)
+    assert out["ok"], out
+    assert len(out["lanes_per_device"]) == len(jax.devices())
+    assert set(out["lanes_per_device"].values()) == {2}
+    # the exact-engine equality arm: a wrong reference fails the step
+    bad = chip_smoke.step_evaluate_parametric(micro_workload, 8,
+                                              exact_best_fit=0.123)
+    assert not bad["ok"]
+
+
+def test_step_evaluate_code_micro_over_the_mesh(micro_workload):
+    from fks_tpu.funsearch import FakeLLM, template
+
+    seeds = template.seed_policies()
+    fake = FakeLLM(seed=0, junk_rate=0.0)
+    checked = {n: (c, None) for n, c in seeds.items()}
+    checked["drafted"] = (template.fill_template(fake.complete("")), None)
+    mesh = population_mesh(jax.devices())
+    out = chip_smoke.step_evaluate_code(micro_workload, checked, mesh)
+    assert out["ok"], out
+    assert out["vm_batch"] and out["fallback_lanes"] == 0
+    assert len(out["lanes_per_device"]) == len(jax.devices())
+    got = out["scores"]["best_fit"]
+    checked["best_fit"] = (seeds["best_fit"], got + 0.01)
+    bad = chip_smoke.step_evaluate_code(micro_workload, checked, mesh)
+    assert not bad["ok"]
+
+
+def test_step_evolve_micro(micro_cli, tmp_path):
+    out = chip_smoke.step_evolve(str(tmp_path), generations=1,
+                                 population_size=7)
+    assert out["ok"], out
+    assert out["rescore_fallbacks"] == 0
+    assert out["rescore_platform"] == "cpu"
+    assert out["ledger_untouched"]
+
+
+def test_step_serve_micro(micro_workload):
+    from fks_tpu import obs
+    from fks_tpu.funsearch import template
+    from fks_tpu.serve import ChampionSpec
+
+    champs = [ChampionSpec(code=template.fill_template(lg), score=s)
+              for lg, s in (("score = 1000", 0.5),
+                            ("score = node.cpu_milli_left - pod.cpu_milli",
+                             0.4))]
+    with obs.CompileWatcher() as watcher:
+        out = chip_smoke.step_serve(micro_workload, micro_workload, champs,
+                                    [3, 5], [2], watcher, portfolio_pods=3)
+    assert out["ok"], out
+    assert out["A"]["second_pass_compiles"] == 0
+    assert out["A"]["max_drift"] == 0.0
+    assert not out["A"]["degraded_fallback_armed"]
+    assert out["portfolio"]["n_slots"] == 2
+
+
+def test_step_fused_micro_in_interpret_mode(micro_workload):
+    out = chip_smoke.step_fused(micro_workload, lanes=8, interpret=True)
+    assert out["ok"], out
+    assert out["scheduled_equal"]
+
+
+def test_summary_sources_are_tracked_files():
+    """What the smoke reads must be in the checkout the chip tool copies:
+    the audit row it checks against and the champion ledger."""
+    recorded = chip_smoke._audit_flat_scores(chip_smoke.PODS)
+    assert {"first_fit", "best_fit"} <= set(recorded)
+    checked = chip_smoke._generation_sources(chip_smoke.PODS)
+    assert len(checked) == 5
+    assert all(want is not None for _, want in checked.values())
+    tracked = subprocess.run(
+        ["git", "-C", REPO, "ls-files", "benchmarks/results", "policies"],
+        capture_output=True, text=True).stdout
+    if tracked:  # the driver's checkout may not be a git repository
+        assert "benchmarks/results/divergence_audit.jsonl" in tracked
+        assert "policies/discovered/" in tracked

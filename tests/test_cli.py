@@ -120,6 +120,21 @@ def test_scale_runs_sharded_over_virtual_mesh(tmp_path, capsys):
     assert rows[-1]["pods"] == 80
 
 
+def test_devices_without_cpu_takes_real_devices_or_fails(capsys):
+    """``--devices N`` without ``--cpu`` meshes the first N devices the
+    backend really has (here: of conftest's eight) and refuses when fewer
+    exist — it never quietly runs on one."""
+    argv = ["scale", "--nodes-count", "8", "--pods-count", "16", "--pop",
+            "2", "--seed", "1", "--engine", "flat"]
+    assert cli.main(argv + ["--devices", "2"]) == 0
+    out = json.loads(capsys.readouterr().out)
+    assert out["mode"] == "sharded over 2 devices"
+    with pytest.raises(SystemExit, match="only 8 cpu device"):
+        cli.main(argv + ["--devices", "64"])
+    with pytest.raises(SystemExit, match="only 8 cpu device"):
+        cli.main(["serve", "--devices", "64", "--selftest", "1"])
+
+
 def test_scale_code_pop_reports_code_tier(capsys):
     rc = cli.main(["scale", "--nodes-count", "8", "--pods-count", "16",
                    "--pop", "2", "--seed", "1", "--engine", "flat",

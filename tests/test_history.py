@@ -3,9 +3,9 @@
 The contracts this file holds: a synthetic 10-run history with one
 injected 30% throughput drop raises EXACTLY one trend alert (the change
 point) while +/-2% noise raises none; ``cli compare --baseline auto``
-resolves a non-0.0 healthy baseline; a failed bench probe's fallback
-carries the last healthy headline under ``stale_from_run`` and staleness
-never chains; SLO burn rates price p99/qps windows against the error
+resolves a non-0.0 healthy baseline; a record carrying another run's
+headline under ``stale_from_run`` is never a baseline; SLO burn rates
+price p99/qps windows against the error
 budget; and the exporter/watch/schema layers speak the three new metric
 kinds (``device_profile`` / ``trend_report`` / ``slo_burn``).
 """
@@ -113,12 +113,12 @@ def test_best_healthy_and_auto_baseline(tmp_path):
     assert resolve_auto_baseline(str(tmp_path / "nothing_here")) is None
 
 
-def test_stale_headline_never_chains(tmp_path):
+def test_stale_record_is_indexed_but_never_a_baseline(tmp_path):
+    """Records written by the retired bench fallback carried another
+    run's headline under ``stale_from_run``. bench.py no longer writes
+    them, but history still refuses to treat one found on disk as a
+    measurement."""
     paths = _write_history(tmp_path, [95.0, 101.5])
-    donor = RunHistory(str(tmp_path)).last_healthy_headline()
-    assert donor["value"] == 101.5 and donor["path"] == paths[1]
-    # a NEWER stale carry-forward is indexed but unhealthy: the next
-    # fallback must reach past it to the measured 101.5
     stale = tmp_path / "BENCH_r50.json"
     stale.write_text(json.dumps(
         {"value": 101.5, "unit": "evals/s", "error": "probe failed",
@@ -128,30 +128,8 @@ def test_stale_headline_never_chains(tmp_path):
     by_run = {e["run"]: e for e in hist.entries}
     assert by_run["BENCH_r50.json"]["stale"]
     assert not by_run["BENCH_r50.json"]["healthy"]
-    assert hist.last_healthy_headline()["path"] == paths[1]
+    assert hist.best_healthy("evals_per_sec")["path"] == paths[1]
     assert resolve_auto_baseline(str(tmp_path)) == paths[1]
-
-
-def test_bench_fallback_carries_stale_headline(tmp_path, monkeypatch):
-    _write_history(tmp_path, [95.0, 101.5])
-    monkeypatch.setenv("FKS_BENCH_RESULTS_DIR", str(tmp_path))
-    sys.path.insert(0, str(REPO))
-    try:
-        import bench
-    finally:
-        sys.path.pop(0)
-    out = json.loads(bench._fallback_json("tunnel wedged"))
-    assert out["value"] == 101.5
-    assert out["vs_baseline"] == pytest.approx(101.5 / 40.0, abs=1e-3)
-    assert out["stale_from_run"]["run"] == "BENCH_r01.json"
-    assert out["error"] == "tunnel wedged"
-    assert "NOT a live measurement" in out["note"]
-    # with no healthy history the headline stays 0.0
-    empty = tmp_path / "empty"
-    empty.mkdir()
-    monkeypatch.setenv("FKS_BENCH_RESULTS_DIR", str(empty))
-    out0 = json.loads(bench._fallback_json("still wedged"))
-    assert out0["value"] == 0.0 and "stale_from_run" not in out0
 
 
 def test_compare_refuses_stale_candidate_allows_stale_baseline(tmp_path):

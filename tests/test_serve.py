@@ -14,6 +14,7 @@ The ISSUE-8 acceptance criteria, as tests:
 """
 import json
 
+import jax
 import numpy as np
 import pytest
 
@@ -177,8 +178,14 @@ def test_artifact_round_trip(tmp_path, engine):
     q = _query(10, 2)
     before = engine.answer_batch([q])[0]
     d = str(tmp_path / "artifact")
+    cache_before = jax.config.jax_compilation_cache_dir
     engine.save(d)
     loaded = ServeEngine.load(d)
+    # an artifact is its artifact.json: neither direction repoints the
+    # process-wide compile cache (it is placed once, by the entry point)
+    assert jax.config.jax_compilation_cache_dir == cache_before
+    assert sorted(p.name for p in (tmp_path / "artifact").iterdir()) == [
+        "artifact.json"]
     after = loaded.answer_batch([q])[0]
     assert before["score"] == after["score"]
     assert before["placements"] == after["placements"]

@@ -157,7 +157,6 @@ def test_shard_map_mesh_per_lane_traces(micro_workload):
     from jax.sharding import PartitionSpec as P
 
     from fks_tpu.parallel.mesh import POP_AXIS, population_mesh
-    from fks_tpu.utils.compat import shard_map
 
     mesh = population_mesh()
     assert mesh.shape[POP_AXIS] == 8  # conftest forces 8 virtual devices
@@ -166,7 +165,7 @@ def test_shard_map_mesh_per_lane_traces(micro_workload):
                                         cfg)
     state0 = engine.initial_state(micro_workload, cfg)
 
-    @functools.partial(shard_map, mesh=mesh, in_specs=(P(POP_AXIS),),
+    @functools.partial(jax.shard_map, mesh=mesh, in_specs=(P(POP_AXIS),),
                        out_specs=(P(POP_AXIS), P(POP_AXIS)), check_vma=False)
     def shard_run(params_shard):
         res = run(params_shard, state0)

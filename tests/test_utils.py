@@ -198,3 +198,24 @@ def test_cli_metrics_flag(tmp_path, default_workload):
     assert recs and recs[0]["kind"] == "bench"
     assert recs[0]["policy"] == "first_fit"
     assert abs(recs[0]["policy_score"] - 0.4292) < 1e-3
+
+
+def test_compile_cache_is_placed_from_outside_or_at_the_fixed_path(
+        monkeypatch, tmp_path):
+    """One cache for every entry point: a set JAX_COMPILATION_CACHE_DIR
+    is left alone (JAX reads it itself); otherwise the fixed directory
+    inside the checkout, never a per-artifact or temporary one."""
+    from fks_tpu.utils import cache
+
+    before = jax.config.jax_compilation_cache_dir
+    try:
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+        assert cache.place_compile_cache() == str(tmp_path)
+        assert jax.config.jax_compilation_cache_dir == before  # untouched
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
+        placed = cache.place_compile_cache()
+        assert placed == cache.DEFAULT_CACHE_DIR
+        assert placed.endswith("benchmarks/results/.jax_cache")
+        assert jax.config.jax_compilation_cache_dir == placed
+    finally:
+        jax.config.update("jax_compilation_cache_dir", before)

@@ -149,7 +149,6 @@ def test_shard_map_mesh_lane_isolation(micro_workload):
     from jax.sharding import PartitionSpec as P
 
     from fks_tpu.parallel.mesh import POP_AXIS, population_mesh
-    from fks_tpu.utils.compat import shard_map
 
     mesh = population_mesh()
     assert mesh.shape[POP_AXIS] == 8  # conftest forces 8 virtual devices
@@ -157,7 +156,7 @@ def test_shard_map_mesh_lane_isolation(micro_workload):
     run = engine.make_population_run_fn(micro_workload, _poison_policy, cfg)
     state0 = engine.initial_state(micro_workload, cfg)
 
-    @functools.partial(shard_map, mesh=mesh, in_specs=(P(POP_AXIS),),
+    @functools.partial(jax.shard_map, mesh=mesh, in_specs=(P(POP_AXIS),),
                        out_specs=(P(POP_AXIS), P(POP_AXIS)), check_vma=False)
     def shard_run(params_shard):
         res = run(params_shard, state0)

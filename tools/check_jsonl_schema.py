@@ -27,10 +27,10 @@ exposition ends with ``# EOF``.
 Usage:
     python tools/check_jsonl_schema.py --run-dir runs/evolve1
     python tools/check_jsonl_schema.py --openmetrics metrics.prom
-    python tools/check_jsonl_schema.py benchmarks/results/round*_tpu.jsonl
+    python tools/check_jsonl_schema.py benchmarks/results/round*_cpu.jsonl
 
-The last form checks arbitrary JSONL evidence files (the TPU session
-logs under benchmarks/results/ predate the recorder and have no fixed
+The last form checks arbitrary JSONL evidence files (the round logs
+under benchmarks/results/ predate the recorder and have no fixed
 keys, so they are checked for parseability only unless --require is
 given). Exit code 0 = clean, 1 = violations (printed one per line).
 """
@@ -468,7 +468,7 @@ def main(argv=None) -> int:
     ap = argparse.ArgumentParser(description=__doc__.splitlines()[0])
     ap.add_argument("paths", nargs="*",
                     help="JSONL files to check (e.g. benchmarks/results/"
-                         "round*_tpu.jsonl)")
+                         "round*_cpu.jsonl)")
     ap.add_argument("--run-dir", default="",
                     help="validate a flight-recorder run directory instead")
     ap.add_argument("--require", default="",

@@ -171,9 +171,8 @@ def main():
     # attributed to the generation
     out["evolve_xla_compiles"] = ev.compile_count - compiles_before
 
-    # compact, single line: tpu_session.py's stage runner takes the LAST
-    # parsable stdout line as the stage payload — an indented dump would
-    # leave it only a closing brace
+    # compact, single line: callers take the LAST parsable stdout line as
+    # the payload — an indented dump would leave them a closing brace
     print(json.dumps(out))
     if args.metrics:
         from fks_tpu.utils import MetricsWriter
