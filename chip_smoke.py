@@ -62,11 +62,12 @@ PARITY_A = {"first_fit": (0.4292, 47), "best_fit": (0.4465, 40),
             "funsearch_4901": (0.4901, 67)}
 #: best_fit on deployment B: flat == exact to the last digit, zero retries
 BEST_FIT_B = 0.00492986
-#: served fitness against the unbatched exact reference. Placements must be
-#: identical; the f32 fitness is the same arithmetic compiled twice, and on
-#: four chips the shard_map program landed one ulp (2.3e-10) from the
-#: single-device reference on B. On one chip the drift is exactly 0.0.
-SCORE_TOL = 1e-6
+#: served fitness against the unbatched exact reference, as a bound
+#: RELATIVE to the score: 4 ulps of f32 (2**-23 each). Placements must be
+#: identical; the fitness is the same f32 arithmetic compiled twice, and on
+#: four chips the shard_map program landed one ulp (2.3e-10 at 0.0034) from
+#: the single-device reference on B. On one chip the drift is exactly 0.0.
+SCORE_RTOL = 4 * 2.0 ** -23
 AUDIT = os.path.join(REPO, "benchmarks", "results", "divergence_audit.jsonl")
 LEDGER = os.path.join(REPO, "policies", "discovered")
 
@@ -294,12 +295,17 @@ def step_evolve(out_dir: str, generations: int = 2,
     return out
 
 
+def _score_agrees(got: float, ref: float) -> bool:
+    """Within ``SCORE_RTOL`` of the reference's own magnitude."""
+    return abs(got - ref) <= SCORE_RTOL * abs(ref)
+
+
 def _serve_over_http(engine, queries: list, watcher) -> dict:
     """Stand the engine up behind the HTTP front on an ephemeral port,
     POST every query twice, and hold each first-pass answer to the
     engine's own unbatched exact answer (``serve.selftest``'s
-    comparison): placements identical, fitness to ``SCORE_TOL``. The
-    second pass must compile nothing and repeat the first bit for bit."""
+    comparison): placements identical, fitness to ``SCORE_RTOL`` of the
+    reference's. The second pass must compile nothing and repeat the first bit for bit."""
     from fks_tpu.serve import ServeService, make_http_server
 
     service = ServeService(engine, max_wait_s=0.002)
@@ -324,7 +330,8 @@ def _serve_over_http(engine, queries: list, watcher) -> dict:
         ref = engine.reference_answer(q)
         drift = abs(a["score"] - ref["score"])
         max_drift = max(max_drift, drift)
-        if (drift > SCORE_TOL or a["placements"] != ref["placements"]
+        if (not _score_agrees(a["score"], ref["score"])
+                or a["placements"] != ref["placements"]
                 or a["scheduled"] != ref["scheduled"]
                 or b["score"] != a["score"]
                 or b["placements"] != a["placements"]):
@@ -379,10 +386,12 @@ def step_serve(wl_a, wl_b, champions: list, sizes_a: list, sizes_b: list,
         out["portfolio"] = {k: res[k] for k in (
             "ok", "n_slots", "checked", "max_drift", "mixed_max_drift",
             "placements_match", "program_capacity", "failures")}
-        # the selftest's own tolerance is 1e-5
+        # the selftest's own tolerance is 1e-5 and it reports no scores,
+        # so the bound is absolute here: fitness is at most 1 (these
+        # queries score 0.1-0.5), and every chip run so far read 0.0
         out["portfolio"]["ok"] = bool(
-            res["ok"] and res["max_drift"] <= SCORE_TOL
-            and res["mixed_max_drift"] <= SCORE_TOL)
+            res["ok"] and res["max_drift"] <= SCORE_RTOL
+            and res["mixed_max_drift"] <= SCORE_RTOL)
     out["ok"] = all(v["ok"] for v in out.values())
     return out
 
@@ -508,13 +517,12 @@ def main(argv=None) -> int:
     # and for evolve: FakeLLM drafts runaway candidates into every
     # generation, so each generation is 65,216 lockstep events whatever
     # its width; 4 lanes instead of 8 roughly halve the per-event cost.
-    # Never below two lanes per device: four candidates over four chips
-    # (one lane each) ran 361 s and 755 s per generation.
+    # The same cut on any device count: the evaluator, not the smoke,
+    # keeps a mesh launch at two lanes per device (vm.bucket_lanes).
     elite = EvolutionConfig().elite_size
-    evolve_pop = elite + min(full, max(4, 2 * len(devices)))
-    if evolve_pop - elite < full:
-        log(f"evolve: candidates per generation cut from {full} to "
-            f"{evolve_pop - elite} (population_size {evolve_pop})")
+    evolve_pop = elite + min(full, 4)
+    log(f"evolve: candidates per generation cut from {full} to "
+        f"{evolve_pop - elite} (population_size {evolve_pop})")
     ledger = sorted(glob.glob(os.path.join(LEDGER, "funsearch_*.json")),
                     key=lambda p: -load_champion(p).score)
     champions = [load_champion(p) for p in ledger[:2]]
@@ -548,8 +556,8 @@ def main(argv=None) -> int:
     failed = None
     for name, fn in steps:
         t0 = time.perf_counter()
-        c0 = (watcher.backend_compile_seconds,
-              watcher.backend_compile_count, watcher.cache_hits)
+        c0 = (watcher.backend_compile_seconds, watcher.compiled_count,
+              watcher.cache_hits)
         try:
             check = fn()
         except Exception as e:  # noqa: BLE001 — the failure IS the result
@@ -564,7 +572,9 @@ def main(argv=None) -> int:
             "bringup_wall_s": round(time.perf_counter() - t0, 2),
             "bringup_compile_s": round(
                 watcher.backend_compile_seconds - c0[0], 2),
-            "backend_compiles": watcher.backend_compile_count - c0[1],
+            # programs XLA really compiled (requests minus persistent-
+            # cache hits), the summary's definition too
+            "backend_compiles": watcher.compiled_count - c0[1],
             "compile_cache_hits": watcher.cache_hits - c0[2],
             "check": check,
         }
@@ -580,7 +590,6 @@ def main(argv=None) -> int:
         "ok": failed is None, "device": device,
         "partial": bool(wanted), "failed_step": failed, "steps": done,
         "bringup_wall_s": round(time.perf_counter() - t_start, 2),
-        # programs XLA really compiled: requests minus persistent-cache hits
         "backend_compiles": watcher.compiled_count,
         "compile_cache_hits": watcher.cache_hits,
         "compile_cache_dir": cache_dir,

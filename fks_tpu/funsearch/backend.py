@@ -164,8 +164,9 @@ class CodeEvaluator:
         self._vm_mesh_run = None  # lazily built SHARDED population program
         self._budget_eval = None  # lazily built rung ladder (budget mode)
         self.vm_batch_count = 0  # observability: batched VM launches
-        # device id -> lanes it held in the most recent batched launch
-        self.last_lanes_per_device: Dict[int, int] = {}
+        # the most recent batched launch's [lanes] score array, on the
+        # device: last_lanes_per_device reads its placement when asked
+        self._last_scores = None
         # Mesh-sharded batched tier: with a >1-device mesh each device
         # interprets its shard of the stacked generation
         # (parallel.mesh.make_sharded_code_eval) — the jit/parametric
@@ -214,6 +215,15 @@ class CodeEvaluator:
         # restores the classic sync-per-segment loop for debugging.
         self.vm_double_buffer = (
             os.environ.get("FKS_VM_DOUBLE_BUFFER", "1") not in ("0", ""))
+
+    @property
+    def last_lanes_per_device(self) -> Dict[int, int]:
+        """device id -> lanes it held in the most recent batched launch.
+        Worked out when asked (chip_smoke.py does), never per launch."""
+        if self._last_scores is None:
+            return {}
+        from fks_tpu.parallel.mesh import lanes_per_device
+        return lanes_per_device(self._last_scores)
 
     # ----- VM tier: one engine program, candidates as data
 
@@ -364,8 +374,7 @@ class CodeEvaluator:
                 result, _, _ = self._vm_mesh_runner()(stacked, len(progs))
             else:
                 result = self._vm_pop_runner()(stacked, self.state0)
-            from fks_tpu.parallel.mesh import lanes_per_device
-            self.last_lanes_per_device = lanes_per_device(result.policy_score)
+            self._last_scores = result.policy_score
             # ONE device->host transfer for the whole generation: slicing
             # lazy device arrays would cost ~3 tiny syncs/lane in _record
             result = jax.device_get(result)

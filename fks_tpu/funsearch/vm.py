@@ -748,9 +748,18 @@ def bucket_lanes(n: int, multiple: int = 1) -> int:
     shard count, so a stacked batch divides evenly over the population
     shards. For power-of-two shard counts (every real topology) the
     round-up is absorbed by the bucket and the bucket set is unchanged.
+
+    Never fewer than two lanes per shard: the batch-of-one program is the
+    slow one. XLA folds the size-1 batch axis away, the op-slot loop then
+    carries per-slot scalars, and three of the five program-word arrays
+    stay in HBM instead of VMEM (optimized HLO, AOT-compiled for v5e). On
+    a v5e chip one lane costs 11.5 ms per lockstep event against 1.7 ms
+    for two lanes and 2.5 ms for four, with or without ``shard_map``
+    (PERF.md, PR 21). A pad lane repeats the last program, so it adds no
+    lockstep events.
     """
-    pop = max(1, 1 << (max(1, n) - 1).bit_length())
-    return -(-pop // multiple) * multiple
+    pop = max(2, 1 << (max(1, n) - 1).bit_length())
+    return max(2, -(-pop // multiple)) * multiple
 
 
 def lower_fake_candidates(n: int, g: int, need: int, *, capacity: int = 256,

@@ -257,8 +257,8 @@ def lanes_per_device(x) -> dict:
     cannot show; chip_smoke.py fails on it."""
     out: dict = {}
     for sh in x.addressable_shards:
-        # from the shard's index, not ``sh.data``: the serve tier calls
-        # this per batch, and ``.data`` materializes a per-device array
+        # from the shard's index: ``sh.data`` would materialize a
+        # per-device array just to read its length
         lanes = len(range(*sh.index[0].indices(x.shape[0])))
         out[sh.device.id] = out.get(sh.device.id, 0) + lanes
     return out

@@ -374,8 +374,9 @@ class ServeEngine:
         # the engine already does: zero new fences, zero device effects.
         self.last_batch_timing: Dict[str, float] = {
             "pack_h2d_s": 0.0, "dispatch_s": 0.0}
-        # device id -> lanes it held in the most recent harvested chunk
-        self.last_lanes_per_device: Dict[int, int] = {}
+        # the most recent harvested chunk's [lanes] score array, kept so
+        # that last_lanes_per_device can read its placement when asked
+        self._last_scores = None
 
         n, g = self.cluster.n_padded, self.cluster.g_padded
         self.param_policy, self.params, self.policy_tier = \
@@ -683,6 +684,14 @@ class ServeEngine:
     def _invoke(self, compiled, pods, kt_dev, s0):
         return compiled(pods, kt_dev, s0)
 
+    @property
+    def last_lanes_per_device(self) -> Dict[int, int]:
+        """device id -> lanes it held in the most recent harvested chunk.
+        Worked out when asked (chip_smoke.py does), never per batch."""
+        if self._last_scores is None:
+            return {}
+        return lanes_per_device(self._last_scores)
+
     def _harvest(self, inflight: "_Inflight", pod_lists, answers) -> None:
         """Block on a dispatched chunk and scatter its answers back."""
         res, idxs, bucket, lanes, real = inflight
@@ -693,7 +702,7 @@ class ServeEngine:
                           real=real) as t:
                 t.sync(res.policy_score)
             hs.sync(res.policy_score)
-        self.last_lanes_per_device = lanes_per_device(res.policy_score)
+        self._last_scores = res.policy_score
         res = jax.device_get(res)
         self.last_batch_timing["dispatch_s"] += time.perf_counter() - t0
         # eval-time layout ledger row: per-batch occupancy attributed to

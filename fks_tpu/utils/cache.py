@@ -19,12 +19,20 @@ DEFAULT_CACHE_DIR = os.path.join(REPO, "benchmarks", "results", ".jax_cache")
 
 def place_compile_cache() -> str:
     """Point this process at the compile cache and return its directory.
-    Call before the first compile: JAX binds the cache on first use."""
+    Call before the first compile: JAX binds the cache on first use.
+
+    Wherever the directory comes from, every program is persisted: JAX's
+    defaults keep only programs that took over 1 s to compile, and this
+    system's are mostly below that (the serve tier's bucket programs
+    compile in ~0.3 s each on a v5e), so with the defaults a reloaded
+    artifact would re-compile nearly all of them."""
+    import jax
+
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0)
+    jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
     env = os.environ.get("JAX_COMPILATION_CACHE_DIR")
     if env:
         return env
-    import jax
-
     os.makedirs(DEFAULT_CACHE_DIR, exist_ok=True)
     jax.config.update("jax_compilation_cache_dir", DEFAULT_CACHE_DIR)
     return DEFAULT_CACHE_DIR

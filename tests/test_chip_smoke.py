@@ -76,6 +76,9 @@ def test_step_evaluate_code_micro_over_the_mesh(micro_workload):
     assert out["ok"], out
     assert out["vm_batch"] and out["fallback_lanes"] == 0
     assert len(out["lanes_per_device"]) == len(jax.devices())
+    # five candidates over eight devices: the evaluator pads to two lanes
+    # per device, never to the batch-of-one program (vm.bucket_lanes)
+    assert set(out["lanes_per_device"].values()) == {2}
     got = out["scores"]["best_fit"]
     checked["best_fit"] = (seeds["best_fit"], got + 0.01)
     bad = chip_smoke.step_evaluate_code(micro_workload, checked, mesh)
@@ -108,6 +111,16 @@ def test_step_serve_micro(micro_workload):
     assert out["A"]["max_drift"] == 0.0
     assert not out["A"]["degraded_fallback_armed"]
     assert out["portfolio"]["n_slots"] == 2
+
+
+def test_served_score_bound_is_a_few_ulps_of_the_score():
+    """One f32 ulp at B's 0.0034 (the drift four chips showed) passes; an
+    absolute 1e-6 there, about 4,000 ulps, does not."""
+    ref = 0.003429
+    assert chip_smoke._score_agrees(ref + 2.3283064365386963e-10, ref)
+    assert chip_smoke._score_agrees(ref, ref)
+    assert not chip_smoke._score_agrees(ref + 1e-8, ref)
+    assert not chip_smoke._score_agrees(0.342081 + 1e-6, 0.342081)
 
 
 def test_step_fused_micro_in_interpret_mode(micro_workload):

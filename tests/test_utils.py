@@ -208,10 +208,16 @@ def test_compile_cache_is_placed_from_outside_or_at_the_fixed_path(
     from fks_tpu.utils import cache
 
     before = jax.config.jax_compilation_cache_dir
+    floors = ("jax_persistent_cache_min_compile_time_secs",
+              "jax_persistent_cache_min_entry_size_bytes")
+    floors_before = [getattr(jax.config, k) for k in floors]
     try:
         monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
         assert cache.place_compile_cache() == str(tmp_path)
         assert jax.config.jax_compilation_cache_dir == before  # untouched
+        # sub-second programs (most of this system's) are persisted too,
+        # wherever the directory came from
+        assert [getattr(jax.config, k) for k in floors] == [0, -1]
         monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
         placed = cache.place_compile_cache()
         assert placed == cache.DEFAULT_CACHE_DIR
@@ -219,3 +225,5 @@ def test_compile_cache_is_placed_from_outside_or_at_the_fixed_path(
         assert jax.config.jax_compilation_cache_dir == placed
     finally:
         jax.config.update("jax_compilation_cache_dir", before)
+        for k, v in zip(floors, floors_before):
+            jax.config.update(k, v)
