@@ -28,11 +28,14 @@ evaluate and serve steps go through the mesh entry points and fail if
 any device held no lanes.
 
 The run stops at the first failed step with a non-zero exit. The last
-line of stdout is the summary JSON; it ends with ``"claim": null``:
-every timing here is a BRING-UP timing (cold compiles included, one
-reading), never a benchmark number. ``--steps`` runs a subset (a builder
-with a chip budget re-runs the step they touched); the summary then says
-``"partial": true`` and proves nothing about the steps left out.
+two lines of stdout are the summary JSON, which ends with ``"claim":
+null`` — every timing here is a BRING-UP timing (cold compiles included,
+one reading), never a benchmark number — and then the verdict, exactly
+``{"ok": true, "device": {"platform": "tpu", "kind": "...", "count": N}}``
+with the device as JAX reports it (``"ok": false`` when a step failed).
+``--steps`` runs a subset (a builder with a chip budget re-runs the step
+they touched); the summary then says ``"partial": true`` and proves
+nothing about the steps left out.
 
 The step functions take their workloads and sizes as arguments so that
 tier-1 runs each of them at micro size on the CPU
@@ -457,6 +460,65 @@ def _generation_sources(trace: str) -> dict:
             for name, code in panel_sources(3).items()}
 
 
+def run_steps(steps: list, watcher, device: dict, partial: bool = False,
+              cache_dir: str | None = None,
+              t_start: float | None = None) -> int:
+    """Run ``steps`` (name, thunk) in order, stopping at the first whose
+    check fails; one JSON row per step on stdout, then the summary row
+    (ends with ``"claim": null``), then — last — the verdict the chip check
+    reads: ``{"ok": ..., "device": {"platform", "kind", "count"}}`` and
+    nothing else. ``t_start``: when the caller's set-up began, so the
+    summary's wall includes it. Returns the exit code."""
+    if t_start is None:
+        t_start = time.perf_counter()
+    done = []
+    failed = None
+    for name, fn in steps:
+        t0 = time.perf_counter()
+        c0 = (watcher.backend_compile_seconds, watcher.compiled_count,
+              watcher.cache_hits)
+        try:
+            check = fn()
+        except Exception as e:  # noqa: BLE001 — the failure IS the result
+            import traceback
+            traceback.print_exc()
+            check = {"ok": False,
+                     "error": f"{type(e).__name__}: {str(e)[-2000:]}"}
+        if "ok" not in check:  # a step over several deployments
+            check["ok"] = all(v["ok"] for v in check.values())
+        row = {
+            "step": name, "ok": bool(check["ok"]),
+            "bringup_wall_s": round(time.perf_counter() - t0, 2),
+            "bringup_compile_s": round(
+                watcher.backend_compile_seconds - c0[0], 2),
+            # programs XLA really compiled (requests minus persistent-
+            # cache hits), the summary's definition too
+            "backend_compiles": watcher.compiled_count - c0[1],
+            "compile_cache_hits": watcher.cache_hits - c0[2],
+            "check": check,
+        }
+        print(json.dumps(row), flush=True)
+        done.append({k: row[k] for k in (
+            "step", "ok", "bringup_wall_s", "bringup_compile_s",
+            "backend_compiles", "compile_cache_hits")})
+        if not row["ok"]:
+            failed = name
+            break
+    watcher.uninstall()
+    summary = {
+        "step": "summary", "ok": failed is None,
+        "partial": partial, "failed_step": failed, "steps": done,
+        "bringup_wall_s": round(time.perf_counter() - t_start, 2),
+        "backend_compiles": watcher.compiled_count,
+        "compile_cache_hits": watcher.cache_hits,
+        "compile_cache_dir": cache_dir,
+        "claim": None,
+    }
+    print(json.dumps(summary), flush=True)
+    print(json.dumps({"ok": failed is None, "device": device}), flush=True)
+    return 0 if failed is None else 1
+
+
 def main(argv=None) -> int:
     import argparse
 
@@ -552,51 +614,8 @@ def main(argv=None) -> int:
         return 2
     if wanted:
         steps = [(name, fn) for name, fn in steps if name in wanted]
-    done = []
-    failed = None
-    for name, fn in steps:
-        t0 = time.perf_counter()
-        c0 = (watcher.backend_compile_seconds, watcher.compiled_count,
-              watcher.cache_hits)
-        try:
-            check = fn()
-        except Exception as e:  # noqa: BLE001 — the failure IS the result
-            import traceback
-            traceback.print_exc()
-            check = {"ok": False,
-                     "error": f"{type(e).__name__}: {str(e)[-2000:]}"}
-        if "ok" not in check:  # a step over several deployments
-            check["ok"] = all(v["ok"] for v in check.values())
-        row = {
-            "step": name, "ok": bool(check["ok"]),
-            "bringup_wall_s": round(time.perf_counter() - t0, 2),
-            "bringup_compile_s": round(
-                watcher.backend_compile_seconds - c0[0], 2),
-            # programs XLA really compiled (requests minus persistent-
-            # cache hits), the summary's definition too
-            "backend_compiles": watcher.compiled_count - c0[1],
-            "compile_cache_hits": watcher.cache_hits - c0[2],
-            "check": check,
-        }
-        print(json.dumps(row), flush=True)
-        done.append({k: row[k] for k in (
-            "step", "ok", "bringup_wall_s", "bringup_compile_s",
-            "backend_compiles", "compile_cache_hits")})
-        if not row["ok"]:
-            failed = name
-            break
-    watcher.uninstall()
-    summary = {
-        "ok": failed is None, "device": device,
-        "partial": bool(wanted), "failed_step": failed, "steps": done,
-        "bringup_wall_s": round(time.perf_counter() - t_start, 2),
-        "backend_compiles": watcher.compiled_count,
-        "compile_cache_hits": watcher.cache_hits,
-        "compile_cache_dir": cache_dir,
-        "claim": None,
-    }
-    print(json.dumps(summary), flush=True)
-    return 0 if failed is None else 1
+    return run_steps(steps, watcher, device, partial=bool(wanted),
+                     cache_dir=cache_dir, t_start=t_start)
 
 
 if __name__ == "__main__":

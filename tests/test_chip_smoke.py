@@ -129,6 +129,52 @@ def test_step_fused_micro_in_interpret_mode(micro_workload):
     assert out["scheduled_equal"]
 
 
+def test_last_stdout_line_is_the_verdict_and_nothing_else(capsys):
+    """The chip check reads the LAST stdout line and wants exactly
+    ``{"ok", "device": {"platform", "kind", "count"}}``; the summary with
+    the timings and ``"claim": null`` is the line before it. A failed
+    step gives ``"ok": false``, exit 1, and stops the run."""
+    import json
+
+    from fks_tpu import obs
+
+    device = {"platform": "tpu", "kind": "TPU v5 lite", "count": 1}
+    ran = []
+
+    def step(name, ok):
+        return name, lambda: ran.append(name) or {"ok": ok}
+
+    rc = chip_smoke.run_steps([step("a", True), step("b", True)],
+                              obs.CompileWatcher().install(), device)
+    lines = capsys.readouterr().out.strip().splitlines()
+    assert rc == 0 and [json.loads(ln)["step"] for ln in lines[:-1]] == [
+        "a", "b", "summary"]
+    assert json.loads(lines[-1]) == {"ok": True, "device": device}
+    assert lines[-2].endswith('"claim": null}')
+    assert isinstance(json.loads(lines[-1])["device"]["count"], int)
+
+    ran.clear()
+    rc = chip_smoke.run_steps(
+        [step("a", False), step("b", True)],
+        obs.CompileWatcher().install(), device, partial=True)
+    lines = capsys.readouterr().out.strip().splitlines()
+    assert rc == 1 and ran == ["a"]
+    assert json.loads(lines[-1]) == {"ok": False, "device": device}
+    summary = json.loads(lines[-2])
+    assert summary["failed_step"] == "a" and summary["partial"]
+
+    # a step that raises is a failed step, not a crash without a verdict
+    def boom():
+        raise RuntimeError("device fault")
+
+    rc = chip_smoke.run_steps([("c", boom)], obs.CompileWatcher().install(),
+                              device)
+    lines = capsys.readouterr().out.strip().splitlines()
+    assert rc == 1 and json.loads(lines[-1]) == {"ok": False,
+                                                 "device": device}
+    assert "device fault" in json.loads(lines[0])["check"]["error"]
+
+
 def test_summary_sources_are_tracked_files():
     """What the smoke reads must be in the checkout the chip tool copies:
     the audit row it checks against and the champion ledger."""
