@@ -1,0 +1,7 @@
+"""``serve.harvest_ms_per_call``: see ``serve.harvest_ms_per_call.json`` (``doc``) and
+``chipbench/reduce/spans.py``."""
+from chipbench.reduce import spans
+
+
+def read(ctx: dict):
+    return spans.sum_ms_per_call(ctx, "serve/chunk/d2h", "serve/chunk/extract")

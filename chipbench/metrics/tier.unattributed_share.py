@@ -1,0 +1,7 @@
+"""``tier.unattributed_share``: see ``tier.unattributed_share.json`` (``doc``) and
+``chipbench/reduce/spans.py``."""
+from chipbench.reduce import spans
+
+
+def read(ctx: dict):
+    return spans.unattributed_share(ctx, "tier/evaluate")

@@ -348,21 +348,22 @@ class CodeEvaluator:
         Replaces the reference's one-subprocess-per-candidate fan-out
         (funsearch_integration.py:535-562) with one XLA program.
         """
-        from fks_tpu.obs import span
-
         pop = vm.bucket_lanes(len(progs), self._n_shards)
-        padded = list(progs) + [progs[-1]] * (pop - len(progs))
-        stacked = vm.stack_programs(padded)
-        # footprint the bucket's runner BEFORE the span: the once-per-
-        # bucket AOT lower must not land on the vm_batch device clock
+        with obs.span("tier/vm_batch/stack_programs",
+                      candidates=len(progs), lanes=pop):
+            padded = list(progs) + [progs[-1]] * (pop - len(progs))
+            stacked = vm.stack_programs(padded)
+        # footprint the bucket's runner outside the launch span: the
+        # once-per-bucket AOT lower must not land on the device clock
         # (same branch condition as the dispatch below)
         if not (self._n_shards > 1 and self.suite is None):
             self._maybe_record_vm_footprint(self._vm_pop_runner(),
                                             stacked, pop)
-        # the span's clock covers the device work AND the one transfer:
-        # device_get materializes the whole generation, so no extra sync
-        with span("vm_batch", candidates=len(progs), lanes=pop,
-                  shards=self._n_shards):
+        # launch + wait_device is the device's part of the generation (a
+        # segmented runner already waits for its segments inside launch);
+        # d2h is the one transfer and nothing else
+        with obs.span("tier/vm_batch/launch", lanes=pop,
+                      shards=self._n_shards):
             if self._n_shards > 1 and self.suite is None:
                 # each device interprets pop/shards lanes; the elite
                 # outputs are discarded here (the evolution loop ranks on
@@ -374,15 +375,20 @@ class CodeEvaluator:
                 result, _, _ = self._vm_mesh_runner()(stacked, len(progs))
             else:
                 result = self._vm_pop_runner()(stacked, self.state0)
-            self._last_scores = result.policy_score
-            # ONE device->host transfer for the whole generation: slicing
-            # lazy device arrays would cost ~3 tiny syncs/lane in _record
+        self._last_scores = result.policy_score
+        with obs.span("tier/vm_batch/wait_device"):
+            jax.block_until_ready(result)
+        # ONE device->host transfer for the whole generation: slicing
+        # lazy device arrays would cost ~3 tiny syncs/lane in _record
+        with obs.span("tier/vm_batch/d2h", bytes=int(sum(
+                x.nbytes for x in jax.tree_util.tree_leaves(result)))):
             result = jax.device_get(result)
         with self._lock:
             self.vm_batch_count += 1
             self.vm_count += len(progs)
-        return [jax.tree_util.tree_map(lambda x, i=i: x[i], result)
-                for i in range(len(progs))]
+        with obs.span("tier/record", lanes=len(progs)):
+            return [jax.tree_util.tree_map(lambda x, i=i: x[i], result)
+                    for i in range(len(progs))]
 
     # ----- budgeted batched tier: probe rung -> survivors -> full rung
 
@@ -544,7 +550,14 @@ class CodeEvaluator:
         released) overlap each other. Result order — and therefore
         population admission order — matches the input order regardless of
         completion order.
+
+        One ``tier/evaluate`` span is the root of the generation; its
+        stages are spans whether the profiler is enabled or not.
         """
+        with obs.span("tier/evaluate", candidates=len(codes)):
+            return self._evaluate(codes)
+
+    def _evaluate(self, codes: Sequence[str]) -> List[EvalRecord]:
         seg0 = self.segments_dispatched
         vm0 = self.vm_count
         pf_rejected = 0
@@ -556,7 +569,7 @@ class CodeEvaluator:
         analysis = None
         unique: Dict[str, str] = {}
         alias: Dict[str, str] = {}
-        with self.profiler.stage("sandbox+preflight",
+        with self.profiler.stage("sandbox+preflight", span="tier/preflight",
                                  candidates=len(codes)) as hp:
             if self.preflight or self.fp_dedup:
                 # lazy: fks_tpu.analysis pulls funsearch tables, and
@@ -622,7 +635,7 @@ class CodeEvaluator:
         jit_only: Dict[str, str] = {}  # known outside the VM vocabulary
         general: Dict[str, str] = {}  # default tier choice (VM then jit)
         c = self.workload.cluster
-        with self.profiler.stage("transpile") as ht:
+        with self.profiler.stage("transpile", span="tier/transpile") as ht:
             if self.use_vm and self.vm_batch and len(unique) > 1:
                 for key, code in unique.items():
                     try:
@@ -663,8 +676,9 @@ class CodeEvaluator:
                     else:
                         results = self._run_vm_batch(
                             [vm_progs[k] for k in vm_keys])
-                        for key, res in zip(vm_keys, results):
-                            memo[key] = self._record(unique[key], res)
+                        with obs.span("tier/record", lanes=len(vm_keys)):
+                            for key, res in zip(vm_keys, results):
+                                memo[key] = self._record(unique[key], res)
                     batch_served = len(vm_keys)
                 except Exception as e:  # noqa: BLE001 — batch failed:
                     # per-candidate fallback still produces scores, but say
@@ -677,8 +691,10 @@ class CodeEvaluator:
                         general.setdefault(key, unique[key])
 
             if jit_only or general:
-                with concurrent.futures.ThreadPoolExecutor(
-                        max_workers=self.max_workers) as ex:
+                with obs.span("tier/fallback",
+                              lanes=len(jit_only) + len(general)), \
+                        concurrent.futures.ThreadPoolExecutor(
+                            max_workers=self.max_workers) as ex:
                     futs = {key: ex.submit(self.evaluate_one, code,
                                            try_vm=False)
                             for key, code in jit_only.items()}

@@ -10,11 +10,20 @@ jitted code.
 
 - ``recorder``  — FlightRecorder/NullRecorder + the process-wide active
                   recorder (``get_recorder``/``recording``)
-- ``spans``     — nested wall-clock scopes mirrored into xprof
-                  (generalizes ``utils.profiling.timed``)
+- ``spans``     — THE timing mechanism: ``span(name)`` records name,
+                  ``t0``/``t1`` (perf_counter), own / parent / trace id,
+                  thread and a few fields into one process-wide bounded
+                  ring (``spans.LOG``, 65,536 records, counts its drops),
+                  enters ``TraceAnnotation("fks/<name>")`` for the
+                  profiler's timeline, and mirrors into an open run
+                  directory. Always on: two clock reads, one append, no
+                  fence, no file, no lock. The span names (``serve/...``,
+                  ``tier/...``, ``mesh/...``) are listed in its docstring
 - ``trace_ctx`` — causal trace contexts (trace_id/span_id/parent_id)
-                  propagated explicitly across thread boundaries, plus
-                  waterfall/critical-path reconstruction (``cli spans``)
+                  propagated explicitly across thread boundaries, spans
+                  known after the fact (``emit``, into the same ring),
+                  plus waterfall/critical-path reconstruction
+                  (``cli spans``)
 - ``telemetry`` — jax.monitoring compile listener, device memory_stats,
                   mesh/pad-waste snapshots
 - ``ledger``    — per-generation evolution records
@@ -28,8 +37,10 @@ jitted code.
                   (``cli export-metrics`` / ``cli watch``)
 - ``compare``   — cross-run regression gating (``cli compare``,
                   ``bench.py --gate``)
-- ``profiler``  — per-stage device-time attribution: wall/compile/
-                  compute split + occupancy (``device_profile`` metrics)
+- ``profiler``  — per-stage device-time attribution, a view of the
+                  spans: each stage opens one span and adds the compile
+                  split + occupancy (``device_profile`` metrics); enabled,
+                  it is what fences
 - ``history``   — cross-run index, trend/regression flagging, auto
                   baselines, SLO burn rates (``cli trends``)
 - ``memory``    — executable-footprint ledger, watermark sampler, leak
@@ -70,7 +81,7 @@ from fks_tpu.obs.recorder import (
     NULL, FlightRecorder, NullRecorder, get_recorder, recording,
 )
 from fks_tpu.obs.report import render_report, sparkline
-from fks_tpu.obs.spans import span, span_path
+from fks_tpu.obs.spans import SpanLog, SpanRecord, span, span_path
 from fks_tpu.obs import trace_ctx
 from fks_tpu.obs.trace_ctx import (
     TraceContext, activate_trace, critical_path, current_trace, emit_span,
@@ -102,7 +113,8 @@ __all__ = [
     "EvolutionLedger", "FlightRecorder", "FootprintLedger", "LayoutLedger",
     "LayoutSpec", "LeakSentinel",
     "NullRecorder", "ParitySentinel", "QueryFingerprinter", "RunHistory",
-    "SLOConfig", "StageProfiler", "TenantAccountant", "TenantLoad",
+    "SLOConfig", "SpanLog", "SpanRecord", "StageProfiler",
+    "TenantAccountant", "TenantLoad",
     "Threshold", "WatermarkSampler", "align_traces", "candidate_trace_diff",
     "check_result", "combined_flags", "compare_runs", "default_make_pods",
     "default_spec", "describe_flags", "device_snapshot", "explore_layouts",

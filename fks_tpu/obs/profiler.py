@@ -1,6 +1,15 @@
 """Device-time attribution: per-stage wall / compile / compute split.
 
-The flight recorder's spans say how long a scope took; this module says
+A stage is a VIEW of a span: ``StageProfiler.stage`` opens one
+``obs.span`` (the one timing mechanism, ``fks_tpu.obs.spans``) and, when
+the profiler is enabled, adds what a span does not carry: the compile
+delta, the occupancy arithmetic and the ``device_profile`` metric. A call
+site that names its span (``stage("h2d", span="serve/chunk/h2d")``) gets
+that span in the in-memory ring whether the profiler is enabled or not;
+one that does not gets ``stage/<name>`` and only when enabled. The
+stage's ``wall_seconds`` are the span's own ``t1 - t0``.
+
+The spans say how long a scope took; this module says
 where the time WENT. A ``StageProfiler`` owns a ``CompileWatcher``
 (fks_tpu.obs.telemetry) and carves a run into named stages — codegen /
 sandbox+preflight / transpile / device-eval / rank / ledger for the
@@ -28,7 +37,8 @@ an attribution table.
 
 The module follows the repo's Python-static-flag convention: a disabled
 profiler (``NULL_PROFILER``, or ``StageProfiler(enabled=False)``) is
-pure host-side no-op scaffolding — it never touches tracing, so any
+pure host-side scaffolding — no record, no fence, and only the spans
+its call sites name — and it never touches tracing, so any
 program lowered inside a stage is bit-identical with the profiler on or
 off (pinned as ``flat_step/profiled`` in the jaxpr manifest).
 """
@@ -41,6 +51,7 @@ from typing import Any, Dict, Iterator, List, Optional
 import jax
 
 from fks_tpu.obs.recorder import get_recorder
+from fks_tpu.obs.spans import span as _span
 from fks_tpu.obs.telemetry import CompileWatcher
 
 
@@ -48,11 +59,12 @@ class StageHandle:
     """What an enabled ``stage(...)`` scope yields: annotate launch-shape
     fields onto the stage record, fence device values into its clock."""
 
-    __slots__ = ("fields", "record")
+    __slots__ = ("fields", "record", "span")
 
-    def __init__(self, **fields) -> None:
+    def __init__(self, span, **fields) -> None:
         self.fields: Dict[str, Any] = dict(fields)
         self.record: Optional[Dict[str, Any]] = None  # set at stage exit
+        self.span = span    # the stage's open obs.span
 
     def annotate(self, **fields) -> None:
         """Attach occupancy/cost fields (e.g. ``parallel.mesh.pad_stats``
@@ -68,10 +80,14 @@ class StageHandle:
 
 class _NullHandle:
     """The disabled handle: annotate drops fields, sync is identity (the
-    unprofiled path must not grow extra device fences)."""
+    unprofiled path must not grow extra device fences). ``span`` is the
+    open span of a stage that names one, else None."""
 
-    __slots__ = ()
+    __slots__ = ("span",)
     record = None
+
+    def __init__(self, span=None) -> None:
+        self.span = span
 
     def annotate(self, **fields) -> None:
         pass
@@ -136,24 +152,33 @@ class StageProfiler:
     # ----- stages
 
     @contextlib.contextmanager
-    def stage(self, name: str, **fields) -> Iterator[Any]:
-        """A named attribution scope. Nested stages record with their
-        ``depth``; only depth-0 stages count toward the summary totals
-        (an inner stage's wall is already inside its parent's)."""
+    def stage(self, name: str, span: Optional[str] = None,
+              **fields) -> Iterator[Any]:
+        """A named attribution scope over one ``obs.span``: ``span`` names
+        it (and it is then opened by a disabled profiler too), else it is
+        ``stage/<name>``. ``fields`` go to the span and to the record,
+        ``handle.annotate`` to the record alone. Nested stages record with
+        their ``depth``; only depth-0 stages count toward the summary
+        totals (an inner stage's wall is already inside its parent's)."""
         if not self.enabled:
-            yield _NULL_HANDLE
+            if span is None:
+                yield _NULL_HANDLE
+            else:
+                with _span(span, **fields) as sp:
+                    yield _NullHandle(sp)
             return
-        handle = StageHandle(**fields)
+        sp = _span(span or "stage/" + name, **fields)
+        handle = StageHandle(sp, **fields)
         depth = self._depth
         self._depth += 1
         seg0 = self._segments
         c_s0 = self.watcher.backend_compile_seconds
         c_n0 = self.watcher.backend_compile_count
-        t0 = time.perf_counter()
         try:
-            yield handle
+            with sp:
+                yield handle
         finally:
-            wall = time.perf_counter() - t0
+            wall = sp.seconds
             self._depth -= 1
             compile_s = self.watcher.backend_compile_seconds - c_s0
             compile_n = self.watcher.backend_compile_count - c_n0

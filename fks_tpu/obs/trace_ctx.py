@@ -16,13 +16,15 @@ causality is lost. This module adds the missing identity layer:
   controller, the evolve loop) attach the context OBJECT to queued
   items/Futures and re-activate it on the consuming thread — there is
   no ambient cross-thread magic to get wrong.
-- ``emit`` — one ``trace_span`` event (trace_id/span_id/parent_id/path/
-  seconds) into a recorder. ``obs.span`` calls it automatically when a
-  context is active; code with better timing information (the batcher's
-  queue-wait split) calls it directly.
+- ``emit`` — one span that is only known after the fact (a request's
+  queue wait, a promotion's swap), written with EXPLICIT ``t0``/``t1``
+  stamps to the same in-memory ring ``obs.span`` writes (``obs.spans
+  .LOG``) and, when a recorder is open, as one ``trace_span`` event
+  (trace_id/span_id/parent_id/path/seconds/t0).
 
-The null path stays allocation-light: with no recorder, no context is
-ever created, and ``current()`` is a single thread-local read.
+Ids are cheap (a per-process random prefix plus a counter): every serve
+request and every batch gets one with or without a recorder, and
+``current()`` is a single thread-local read.
 
 Reconstruction (the ``cli spans`` viewer and the run_full_suite trace
 gate) lives here too: group ``trace_span`` events by trace id, build
@@ -32,14 +34,16 @@ LLM-idle seconds — the numbers the async-island ROADMAP item needs).
 """
 from __future__ import annotations
 
+import itertools
 import threading
+import time
 import uuid
 from contextlib import contextmanager
 from typing import Dict, List, Optional
 
 __all__ = [
     "TraceContext", "new_trace", "new_span_id", "current", "activate",
-    "child_of", "emit", "trace_spans", "traces_by_id", "build_tree",
+    "swap", "child_of", "emit", "trace_spans", "traces_by_id", "build_tree",
     "render_waterfall", "critical_path", "waterfall_complete",
     "SERVE_ROOT", "SERVE_COMPONENTS", "activate_trace", "current_trace",
     "emit_span",
@@ -47,32 +51,43 @@ __all__ = [
 
 #: canonical serve-request span paths (the waterfall vocabulary)
 SERVE_ROOT = "serve/request"
-SERVE_COMPONENTS = ("queue_wait", "batch_wait", "pack_h2d", "dispatch",
-                    "scatter_back")
+SERVE_COMPONENTS = ("queue_wait", "batch_wait", "stack", "pack", "h2d",
+                    "enqueue", "wait_device", "d2h", "extract")
 
 
 class TraceContext:
     """One (trace_id, span_id) hop of a causal chain. Immutable by
-    convention; cheap enough to attach to every queued request."""
+    convention; cheap enough to attach to every queued request. A
+    ``span_id`` of None is a trace with no open parent: the next span
+    under it is a root. ``carries`` lists the trace ids of what this
+    context's work answers (a batch carries its requests)."""
 
-    __slots__ = ("trace_id", "span_id")
+    __slots__ = ("trace_id", "span_id", "carries")
 
-    def __init__(self, trace_id: str, span_id: str):
+    def __init__(self, trace_id: str, span_id: Optional[str],
+                 carries: tuple = ()):
         self.trace_id = trace_id
         self.span_id = span_id
+        self.carries = carries
 
     def __repr__(self):  # pragma: no cover - debugging aid
         return f"TraceContext({self.trace_id!r}, {self.span_id!r})"
 
 
+_PROCESS = uuid.uuid4().hex[:8]
+_ids = itertools.count(1)
+
+
 def new_span_id() -> str:
-    return uuid.uuid4().hex[:16]
+    """16 hex digits, unique in the process (a counter) and across
+    processes writing one run directory (a random prefix)."""
+    return f"{_PROCESS}{next(_ids):08x}"
 
 
 def new_trace(prefix: str = "req") -> TraceContext:
     """Fresh trace with the ROOT span id preallocated — children created
     before the root event is written still get a resolvable parent_id."""
-    return TraceContext(f"{prefix}-{uuid.uuid4().hex[:16]}", new_span_id())
+    return TraceContext(f"{prefix}-{new_span_id()}", new_span_id())
 
 
 def child_of(ctx: TraceContext) -> TraceContext:
@@ -88,6 +103,15 @@ def current() -> Optional[TraceContext]:
     return getattr(_local, "ctx", None)
 
 
+def swap(ctx: Optional[TraceContext]) -> Optional[TraceContext]:
+    """Bind ``ctx`` (or None) to the thread and return what was bound:
+    the two halves of ``activate`` for callers that are context managers
+    themselves (``obs.span``)."""
+    prev = getattr(_local, "ctx", None)
+    _local.ctx = ctx
+    return prev
+
+
 @contextmanager
 def activate(ctx: Optional[TraceContext]):
     """Bind ``ctx`` as the thread's active context for the block
@@ -95,37 +119,57 @@ def activate(ctx: Optional[TraceContext]):
     if ctx is None:
         yield None
         return
-    prev = getattr(_local, "ctx", None)
-    _local.ctx = ctx
+    prev = swap(ctx)
     try:
         yield ctx
     finally:
         _local.ctx = prev
 
 
-def emit(recorder, path: str, seconds: float, *,
+def emit(recorder, path: str, seconds: Optional[float] = None, *,
+         t0: Optional[float] = None, t1: Optional[float] = None,
          ctx: Optional[TraceContext] = None,
          span_id: Optional[str] = None,
          parent_id: Optional[str] = None,
-         root: bool = False, **fields) -> Optional[str]:
-    """Write one ``trace_span`` event. ``ctx`` defaults to the thread's
-    active context; with neither, this is a no-op (returns None).
+         root: bool = False, ring: bool = True,
+         **fields) -> Optional[str]:
+    """Write one span known after the fact. ``t0``/``t1`` are its stamps
+    on ``time.perf_counter``; a caller that only knows a length passes
+    ``seconds`` and the span ends at ``t1`` (default: now). ``ctx``
+    defaults to the thread's active context; without one this is a no-op
+    (returns None).
+
+    The span goes to the in-memory ring (``obs.spans.LOG``) and, when
+    ``recorder`` is enabled, to the run directory as one ``trace_span``
+    event whose ``ts`` is the span's END on the wall clock. ``ring=False``
+    writes the event alone: a copy, for the run directory's view, of a
+    span the ring already holds.
 
     ``root=True`` reuses the context's preallocated span id as this
     span's OWN id with a null parent — the request/generation root.
     Otherwise a fresh span id is minted with ``parent_id`` defaulting to
     the context's span id."""
     ctx = ctx if ctx is not None else current()
-    if ctx is None or not getattr(recorder, "enabled", False):
+    if ctx is None:
         return None
+    now = time.perf_counter()
+    if t1 is None:
+        t1 = now
+    if t0 is None:
+        t0 = t1 - float(seconds or 0.0)
     if root:
         sid, pid = ctx.span_id, None
     else:
         sid = span_id or new_span_id()
         pid = parent_id if parent_id is not None else ctx.span_id
-    recorder.event("trace_span", trace_id=ctx.trace_id, span_id=sid,
-                   parent_id=pid, path=path,
-                   seconds=round(float(seconds), 6), **fields)
+    if ring:
+        from fks_tpu.obs import spans
+        spans.LOG.append(path, t0, t1, sid, pid, ctx.trace_id, fields)
+    if getattr(recorder, "enabled", False):
+        recorder.event("trace_span", trace_id=ctx.trace_id, span_id=sid,
+                       parent_id=pid, path=path,
+                       seconds=round(t1 - t0, 6), t0=round(t0, 6),
+                       **{"ts": time.time() - (now - t1), **fields})
     return sid
 
 

@@ -703,31 +703,39 @@ def _make_segmented_code_eval(workload: Workload, mesh: Mesh, cfg: SimConfig,
 
     state0 = mod.initial_state(workload, cfg)
     from fks_tpu.obs.layout import record_layout, tag_layout
+    from fks_tpu.obs.spans import span
     if spec is None:
         spec = _resolve_layout(None, seg_steps=seg_steps)
     record_layout("code_eval", spec, mesh=mesh)
 
     def run(stacked, real_count=None):
-        stacked = shard_population(stacked, mesh)
-        pop = jax.tree_util.tree_leaves(stacked)[0].shape[0]
-        if real_count is None:
-            real_count = pop
-        bstate = jax.device_put(mod.broadcast_state(state0, pop),
-                                NamedSharding(mesh, P(_pop_axes(mesh))))
+        with span("mesh/shard_put"):
+            stacked = shard_population(stacked, mesh)
+            pop = jax.tree_util.tree_leaves(stacked)[0].shape[0]
+            if real_count is None:
+                real_count = pop
+            bstate = jax.device_put(mod.broadcast_state(state0, pop),
+                                    NamedSharding(mesh, P(_pop_axes(mesh))))
         active = True
         prev = None
         segments = 0
         for _ in range(segment_budget(max_steps, seg_steps, slack=2)):
-            bstate, active = advance(stacked, bstate)
-            segments += 1
-            if on_segment is not None:
-                on_segment()
-            # double-buffered handoff: sync on the PREVIOUS segment's
-            # psum'd flag only after this segment is already in flight
-            if prev is not None and not bool(prev):
-                active = prev
-                break
-            prev = active
+            # one span per segment; its self time is the launch, its
+            # ``wait`` child the host blocked on the device
+            with span("mesh/segment", segment=segments):
+                bstate, active = advance(stacked, bstate)
+                segments += 1
+                if on_segment is not None:
+                    on_segment()
+                # double-buffered handoff: sync on the PREVIOUS segment's
+                # psum'd flag only after this segment is already in flight
+                if prev is not None:
+                    with span("mesh/segment/wait"):
+                        drained = not bool(prev)
+                    if drained:
+                        active = prev
+                        break
+                prev = active
         if bool(active):
             raise RuntimeError(
                 "sharded segmented runner exhausted its segment budget "
@@ -737,6 +745,7 @@ def _make_segmented_code_eval(workload: Workload, mesh: Mesh, cfg: SimConfig,
         # here, after the host loop drained (dedupes on identical repeats)
         record_layout("code_eval", spec, mesh=mesh,
                       real_count=int(real_count), segments=segments)
-        return finish(bstate, jnp.asarray(real_count, jnp.int32))
+        with span("mesh/finish"):
+            return finish(bstate, jnp.asarray(real_count, jnp.int32))
 
     return tag_layout(run, spec.key)

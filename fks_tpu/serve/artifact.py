@@ -29,12 +29,12 @@ placements) so the service layer can JSON them straight out.
 """
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 import glob
 import hashlib
 import json
 import os
-import time
 import warnings
 from collections import OrderedDict
 from typing import Any, Dict, List, NamedTuple, Optional, Sequence, Tuple
@@ -45,6 +45,8 @@ import numpy as np
 
 from fks_tpu import obs
 from fks_tpu.data.entities import ClusterArrays, Workload
+from fks_tpu.obs import trace_ctx
+from fks_tpu.obs.layout import record_layout
 from fks_tpu.obs.memory import record_footprint
 from fks_tpu.parallel.mesh import (
     lanes_per_device, make_sharded_serve_fn, num_shards, occupancy_stats,
@@ -279,6 +281,7 @@ class _Inflight(NamedTuple):
     bucket: int
     lanes: int
     real: int
+    chunk: int          # its index in the batch, as the spans carry it
 
 
 class ServeEngine:
@@ -368,12 +371,16 @@ class ServeEngine:
         # H2D accounting (bytes actually shipped per answered query)
         self.h2d_bytes_total = 0
         self.h2d_queries = 0
-        # host-wall split of the last answer_batch call (pack+upload vs
-        # dispatch+harvest) — the serve-request waterfall's engine stages
-        # (fks_tpu.serve.service). Plain perf_counter stamps around work
-        # the engine already does: zero new fences, zero device effects.
+        # host-wall split of the last answer_batch call, summed over its
+        # chunks from the stamps of the chunk spans (``_reset_batch_log``
+        # says what each key covers)
         self.last_batch_timing: Dict[str, float] = {
             "pack_h2d_s": 0.0, "dispatch_s": 0.0}
+        # the last answer_batch call's chunk spans and, per chunk index,
+        # the answer slots it carried: the per-request waterfall's source
+        # (fks_tpu.serve.service._trace_batch)
+        self.last_batch_spans: List[obs.SpanRecord] = []
+        self.last_batch_chunks: List[List[int]] = []
         # the most recent harvested chunk's [lanes] score array, kept so
         # that last_lanes_per_device can read its placement when asked
         self._last_scores = None
@@ -620,6 +627,26 @@ class ServeEngine:
             self._ktable_cache_bytes -= freed
         return dev
 
+    def _reset_batch_log(self) -> None:
+        """Start the per-batch views of the chunk spans.
+        ``last_batch_timing`` keeps its two keys (and their names) for
+        the readers that have them; what they cover, per chunk:
+        ``pack_h2d_s`` is the start of ``serve/chunk/stack`` to the end of
+        ``serve/chunk/enqueue`` (stacking, packing, lane padding, the
+        upload, the executable lookup AND the enqueue: all host staging,
+        not the upload alone); ``dispatch_s`` is the start of
+        ``serve/chunk/wait_device`` to the end of ``serve/chunk/d2h``
+        (the wait for the device plus the device-to-host copy: no
+        dispatch is in it)."""
+        self.last_batch_timing = {"pack_h2d_s": 0.0, "dispatch_s": 0.0}
+        self.last_batch_spans = []
+        self.last_batch_chunks = []
+
+    def _batch_guard(self):
+        """What a whole batch is answered under (the VM engine: its swap
+        lock)."""
+        return contextlib.nullcontext()
+
     def answer_batch(self, pod_lists: Sequence[Sequence[dict]]) -> List[dict]:
         """Answer N "place this pod list" queries. Queries are grouped by
         pod bucket, chunked at the mesh-wide max batch, lane-padded to
@@ -628,11 +655,23 @@ class ServeEngine:
         input order. Chunks are DOUBLE-BUFFERED: chunk i+1 is stacked,
         uploaded and dispatched before chunk i's results are pulled, so
         host staging and H2D overlap device compute (the segmented
-        replay runner's one-behind handoff, at the batch level)."""
+        replay runner's one-behind handoff, at the batch level).
+
+        One ``serve/batch`` span is the root of the call; when the
+        batcher's flush context is active it joins that flush's trace
+        and lists the request traces it carries."""
+        ctx = trace_ctx.current()
+        with obs.span("serve/batch", queries=len(pod_lists)) as root:
+            if ctx is not None and ctx.carries:
+                root.set(requests=list(ctx.carries))
+            with self._batch_guard():
+                return self._answer_chunks(pod_lists)
+
+    def _answer_chunks(self, pod_lists) -> List[dict]:
         for pods in pod_lists:
             validate_query_pods(pods, max_pods=self.envelope.max_pods,
                                 max_gpu_milli=self.envelope.max_gpu_milli)
-        self.last_batch_timing = {"pack_h2d_s": 0.0, "dispatch_s": 0.0}
+        self._reset_batch_log()
         answers: List[Optional[dict]] = [None] * len(pod_lists)
         groups: Dict[int, List[int]] = {}
         for i, pods in enumerate(pod_lists):
@@ -653,33 +692,43 @@ class ServeEngine:
 
     def _dispatch_chunk(self, bucket: int, idxs: List[int],
                         pod_lists) -> "_Inflight":
-        """Stack + pack + upload one chunk and dispatch it (async): the
-        h2d profiler stage covers exactly the bytes shipped; execution
-        cost lands in ``_harvest``'s steady stage."""
-        t0 = time.perf_counter()
+        """Stack, pack, upload and enqueue one chunk (async), one span
+        each. The ``h2d`` span reads what the upload costs the HOST (the
+        enqueue of the copy; an enabled profiler fences it); the
+        execution lands in ``_harvest``'s ``wait_device``."""
+        chunk = len(self.last_batch_chunks)
+        self.last_batch_chunks.append(list(idxs))
         lanes = self._global_lanes(len(idxs))
-        cfg = self.bucket_config(bucket)
-        pods, kt, s0 = stack_query_tables(
-            self._mod, self.cluster, [pod_lists[i] for i in idxs], bucket,
-            cfg, self._klen(bucket))
-        pods, kt = pack_query_tables(pods, kt, self._pack_plan(bucket))
-        compiled = self.compiled_for(lanes, bucket)
-        (pods, s0), real = pad_population((pods, s0), lanes)
-        with self.profiler.stage("h2d", lanes=lanes, pods=bucket) as hh:
+        with obs.span("serve/chunk/stack", chunk=chunk, bucket=bucket,
+                      lanes=lanes, real=len(idxs)) as t_stack:
+            pods, kt, s0 = stack_query_tables(
+                self._mod, self.cluster, [pod_lists[i] for i in idxs],
+                bucket, self.bucket_config(bucket), self._klen(bucket))
+        with obs.span("serve/chunk/pack", chunk=chunk) as t_pack:
+            pods, kt = pack_query_tables(pods, kt, self._pack_plan(bucket))
+        with self.profiler.stage("h2d", span="serve/chunk/h2d", chunk=chunk,
+                                 lanes=lanes, pods=bucket) as hh:
+            sent0 = self.h2d_bytes_total
+            (pods, s0), real = pad_population((pods, s0), lanes)
             kt_dev = self._ktable_for(lanes, bucket, kt)
             if self._sharding is not None:
                 pods, s0 = jax.device_put((pods, s0), self._sharding)
             else:
                 pods, s0 = jax.device_put((pods, s0))
             self.h2d_bytes_total += tree_h2d_bytes(pods, s0)
+            hh.span.set(bytes=self.h2d_bytes_total - sent0)
             hh.sync(jax.tree_util.tree_leaves(s0)[0])
         self.h2d_queries += len(idxs)
         # async dispatch; per-batch buffers donated. _invoke is the
         # engine-kind seam: the AOT engine calls the executable directly,
         # the VM engine prepends its device-resident champion tables.
-        res = self._invoke(compiled, pods, kt_dev, s0)
-        self.last_batch_timing["pack_h2d_s"] += time.perf_counter() - t0
-        return _Inflight(res, list(idxs), bucket, lanes, real)
+        with obs.span("serve/chunk/enqueue", chunk=chunk) as t_enq:
+            compiled = self.compiled_for(lanes, bucket)
+            res = self._invoke(compiled, pods, kt_dev, s0)
+        self.last_batch_timing["pack_h2d_s"] += t_enq.t1 - t_stack.t0
+        self.last_batch_spans += [t_stack.record, t_pack.record,
+                                  hh.span.record, t_enq.record]
+        return _Inflight(res, list(idxs), bucket, lanes, real, chunk)
 
     def _invoke(self, compiled, pods, kt_dev, s0):
         return compiled(pods, kt_dev, s0)
@@ -693,30 +742,36 @@ class ServeEngine:
         return lanes_per_device(self._last_scores)
 
     def _harvest(self, inflight: "_Inflight", pod_lists, answers) -> None:
-        """Block on a dispatched chunk and scatter its answers back."""
-        res, idxs, bucket, lanes, real = inflight
-        t0 = time.perf_counter()
-        with self.profiler.stage("steady", **occupancy_stats(real, lanes)) \
-                as hs:
-            with obs.span("serve_batch", lanes=lanes, bucket_pods=bucket,
-                          real=real) as t:
-                t.sync(res.policy_score)
-            hs.sync(res.policy_score)
-        self._last_scores = res.policy_score
-        res = jax.device_get(res)
-        self.last_batch_timing["dispatch_s"] += time.perf_counter() - t0
-        # eval-time layout ledger row: per-batch occupancy attributed to
-        # the serve layout key (deduped by the ledger across equal rows)
-        from fks_tpu.obs.layout import record_layout
-        record_layout(getattr(self, "layout_component", None) or
-                      ("vm_serve" if self.engine_kind == "vm" else "serve"),
-                      getattr(self, "_layout_key", None) or
-                      "shard[candidates]|vmap[candidates]|seg=0",
-                      mesh=self.mesh, recorder=self.recorder,
-                      **occupancy_stats(real, lanes))
-        for lane, i in enumerate(idxs):
-            answers[i] = self._extract(res, lane, len(pod_lists[i]),
-                                       bucket, lanes)
+        """Block on a dispatched chunk and scatter its answers back. The
+        ``wait_device`` span holds the blocking call and nothing else."""
+        res, idxs, bucket, lanes, real, chunk = inflight
+        with self.profiler.stage("steady", span="serve/chunk/wait_device",
+                                 chunk=chunk, lanes=lanes, real=real) as hs:
+            jax.block_until_ready(res.policy_score)
+            if self.profiler.enabled:
+                hs.annotate(**occupancy_stats(real, lanes))
+        with obs.span("serve/chunk/d2h", chunk=chunk) as t_d2h:
+            self._last_scores = res.policy_score
+            res = jax.device_get(res)
+            t_d2h.set(bytes=tree_h2d_bytes(res))
+        self.last_batch_timing["dispatch_s"] += t_d2h.t1 - hs.span.t0
+        if self.recorder.enabled:
+            # eval-time layout ledger row: per-batch occupancy attributed
+            # to the serve layout key (the ledger dedupes equal rows)
+            record_layout(
+                getattr(self, "layout_component", None) or
+                ("vm_serve" if self.engine_kind == "vm" else "serve"),
+                getattr(self, "_layout_key", None) or
+                "shard[candidates]|vmap[candidates]|seg=0",
+                mesh=self.mesh, recorder=self.recorder,
+                **occupancy_stats(real, lanes))
+        with obs.span("serve/chunk/extract", chunk=chunk,
+                      real=len(idxs)) as t_ext:
+            for lane, i in enumerate(idxs):
+                answers[i] = self._extract(res, lane, len(pod_lists[i]),
+                                           bucket, lanes)
+        self.last_batch_spans += [hs.span.record, t_d2h.record,
+                                  t_ext.record]
 
     def _extract(self, res, lane: Optional[int], p_real: int,
                  bucket: int, lanes: int) -> dict:

@@ -330,10 +330,10 @@ def pods_to_dicts(pods: PodArrays, limit: Optional[int] = None) -> List[dict]:
 
 
 class QueuedRequest:
-    """One queued submit: the query, its Future, its timestamps, and —
-    when tracing is on — the caller's ``TraceContext``, carried OBJECT-
-    in-hand across the submit-thread -> worker-thread boundary (the hop
-    where thread-local span nesting loses causality)."""
+    """One queued submit: the query, its Future, its timestamps, and the
+    request's ``TraceContext`` (the caller's, or one made at submit),
+    carried OBJECT-in-hand across the submit-thread -> worker-thread
+    boundary (the hop where thread-local span nesting loses causality)."""
 
     __slots__ = ("query", "fut", "t_enq", "deadline", "ctx", "t_deq")
 
@@ -376,13 +376,18 @@ class RequestBatcher:
       grace budget to finish real work, then shed whatever remains with
       a typed error so no client ever hangs on a dying server.
 
-    Tracing hooks (fks_tpu.obs.trace_ctx): ``submit(..., ctx=)`` (or the
-    submitting thread's active context) rides the ``QueuedRequest`` to
-    the worker; every typed error raised or completed for a traced
-    request carries its ``trace_id`` (so 503 bodies correlate to the
-    flight-recorder trail), and the handler can read the in-flight
-    requests' contexts/timestamps via ``inflight()`` to emit per-request
-    waterfall spans."""
+    Spans (fks_tpu.obs.spans; always on): ``submit(..., ctx=)`` (or the
+    submitting thread's active context, or a fresh one) rides the
+    ``QueuedRequest`` to the worker, which stamps each request's
+    ``serve/request/queue_wait`` (submit to dequeue) and
+    ``serve/request/batch_wait`` (dequeue to batch start) when the batch
+    starts and its ``serve/request`` root (submit to answer) when its
+    Future completes. The handler runs under a per-flush context, so the
+    engine's ``serve/batch`` root joins that flush's trace, lists the
+    request traces it carries, and every request root names it (``batch``).
+    Every typed error raised or completed for a request carries its
+    ``trace_id`` (so 503 bodies correlate to the flight-recorder trail),
+    and the handler can read the in-flight requests via ``inflight()``."""
 
     def __init__(self, handle_batch: Callable[[list, list], list],
                  max_batch: int = 8, max_wait_s: float = 0.005,
@@ -425,8 +430,8 @@ class RequestBatcher:
     def submit(self, query, deadline: Optional[Deadline] = None,
                ctx: Optional[trace_ctx.TraceContext] = None) -> Future:
         if ctx is None:  # inherit the submitting thread's trace, if any
-            ctx = trace_ctx.current()
-        tid = ctx.trace_id if ctx is not None else None
+            ctx = trace_ctx.current() or trace_ctx.new_trace()
+        tid = ctx.trace_id
         if self._draining:  # before the closed check: drain() sets both,
             # and a drained server sheds with a TYPED error
             self.shed_draining += 1
@@ -573,12 +578,22 @@ class RequestBatcher:
         queries = [r.query for r in live]
         enq = [r.t_enq for r in live]
         t0 = time.perf_counter()
+        batch = trace_ctx.TraceContext(
+            "batch-" + trace_ctx.new_span_id(), None,
+            carries=tuple(r.trace_id for r in live))
+        for r in live:
+            r.t_deq = min(max(r.t_deq, r.t_enq), t0)
+            self._emit("serve/request/queue_wait", r, t0=r.t_enq,
+                       t1=r.t_deq)
+            self._emit("serve/request/batch_wait", r, t0=r.t_deq, t1=t0)
         self._inflight = live
         try:
-            answers = self._handle(queries, enq)
+            with trace_ctx.activate(batch):
+                answers = self._handle(queries, enq)
         except Exception as e:  # noqa: BLE001 — fail the batch, not the loop
             for r in live:
                 self._complete(r.fut, exc=e)
+                self._request_span(r, batch, error=type(e).__name__)
             return
         finally:
             self._inflight = ()
@@ -595,3 +610,24 @@ class RequestBatcher:
                     f"batch handler returned {len(answers)} answers for "
                     f"{len(live)} queries", reason="short_answer",
                     trace_id=r.trace_id))
+            self._request_span(r, batch)
+
+    def _request_span(self, r: QueuedRequest, batch, **fields) -> None:
+        """The ``serve/request`` root of one request that went through the
+        handler: submit to the completion of its Future. The service's
+        query tuple carries the request id at index 0 and the tenant at
+        index 2 (the admission convention)."""
+        q = r.query
+        if isinstance(q, tuple):
+            if q and isinstance(q[0], str):
+                fields["request"] = q[0]
+            if len(q) > 2 and q[2]:
+                fields["tenant"] = q[2]
+        self._emit(trace_ctx.SERVE_ROOT, r, t0=r.t_enq, root=True,
+                   batch=batch.trace_id, **fields)
+
+    def _emit(self, path: str, r: QueuedRequest, **kw) -> None:
+        try:
+            trace_ctx.emit(self.recorder, path, ctx=r.ctx, **kw)
+        except Exception:  # noqa: BLE001 — a run directory that cannot be
+            pass  # written must never cost a request its answer

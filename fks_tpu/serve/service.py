@@ -212,10 +212,9 @@ class ServeService:
         tenant = tenant_of(query)
         deadline = Deadline.from_query(query, self.default_deadline_s)
         # every admitted request starts ONE causal trace; the context
-        # object rides the queue to the batcher thread (null path: no
-        # recorder -> no context is ever allocated)
-        ctx = (trace_ctx.new_trace()
-               if getattr(self.recorder, "enabled", False) else None)
+        # object rides the queue to the batcher thread, which stamps the
+        # request's spans (a counter-made id: recorder or not)
+        ctx = trace_ctx.new_trace()
         try:
             return self._batcher.submit(
                 self._make_item(rid, pods, tenant, query),
@@ -298,7 +297,9 @@ class ServeService:
             answers = self._answer(engine, items)
         done = time.perf_counter()
         inflight = self._batcher.inflight()
-        self._trace_batch(engine, inflight, t_start, done, fault)
+        traced = getattr(self.recorder, "enabled", False)
+        if traced:
+            self._trace_batch(engine, inflight, t_start, fault)
         if self._t_first is None:
             self._t_first = min(enq_times)
         self._t_last = done
@@ -309,7 +310,8 @@ class ServeService:
             latency_ms = (done - enq) * 1e3
             ans["id"] = rid
             ans["latency_ms"] = round(latency_ms, 3)
-            tid = inflight[i].trace_id if i < len(inflight) else None
+            tid = inflight[i].trace_id \
+                if traced and i < len(inflight) else None
             if tid:
                 ans["trace_id"] = tid
             self._replay.append(pods)
@@ -349,60 +351,37 @@ class ServeService:
         return answers
 
     def _trace_batch(self, engine: ServeEngine, inflight, t_start: float,
-                     done: float, fault) -> None:
-        """Per-request latency waterfalls: one ``serve/request`` root plus
-        queue_wait / batch_wait / pack_h2d / dispatch / scatter_back
-        children for every traced request of the batch just answered.
-
-        All spans are written after the fact with EXPLICIT end
-        timestamps (``ts`` override), so reconstruction places each bar
-        where the work actually happened. The engine-stage split reuses
-        the host-wall decomposition the engine already measures
-        (``last_batch_timing``); the batch-level pack/dispatch costs are
-        shared by every lane, so each request reports the same split —
-        the truthful statement for a coalesced batch. A degraded-mode
-        retry adds a ``primary_attempt`` child carrying the fault class,
-        linking primary-fail -> fallback-retry on ONE trace."""
-        if not getattr(self.recorder, "enabled", False):
-            return
-        timing = getattr(engine, "last_batch_timing", None) or {}
-        pack_s = float(timing.get("pack_h2d_s", 0.0))
-        disp_s = float(timing.get("dispatch_s", 0.0))
-        retry_s = fault[1] if fault is not None else 0.0
-        scatter_s = max((done - t_start) - retry_s - pack_s - disp_s, 0.0)
-        wall_done = time.time()
-
-        def _ts(perf_t: float) -> float:
-            # perf_counter point -> wall-clock event timestamp
-            return wall_done - (done - perf_t)
-
+                     fault) -> None:
+        """The run directory's per-request waterfalls (recorder on only).
+        The batcher has already written each request's ``queue_wait`` and
+        ``batch_wait`` and writes its ``serve/request`` root when the
+        Future completes; this adds, under each request's root, the REAL
+        spans of the chunk that carried it (``stack``, ``pack``, ``h2d``,
+        ``enqueue``, ``wait_device``, ``d2h``, ``extract``), copied from
+        the engine's ``last_batch_spans`` with their own stamps: requests
+        of one chunk share them, requests of different chunks do not. A
+        batch the engine saw in another order than the batcher (the
+        portfolio's fallback split) gives every request every chunk. A
+        degraded-mode retry adds a ``primary_attempt`` child carrying the
+        fault class, linking primary-fail -> fallback-retry on ONE
+        trace. The copies go to the run directory alone: the ring holds
+        each chunk span once, under its batch."""
+        spans = getattr(engine, "last_batch_spans", None) or ()
+        chunks = getattr(engine, "last_batch_chunks", None) or ()
+        aligned = sum(len(c) for c in chunks) == len(inflight)
         rec = self.recorder
-        t_run = t_start + retry_s  # successful attempt began here
-        for r in inflight:
-            ctx = r.ctx
-            if ctx is None:
-                continue
-            t_deq = min(max(r.t_deq, r.t_enq), t_start)
-            # tenant identity rides the root span as an attribute, so a
-            # waterfall (and any span query) can slice by tenant
-            tenant = r.query[2] if len(r.query) > 2 else ""
-            trace_ctx.emit(rec, trace_ctx.SERVE_ROOT, done - r.t_enq,
-                           ctx=ctx, root=True, ts=_ts(done),
-                           **({"tenant": tenant} if tenant else {}))
-            trace_ctx.emit(rec, "serve/request/queue_wait",
-                           t_deq - r.t_enq, ctx=ctx, ts=_ts(t_deq))
-            trace_ctx.emit(rec, "serve/request/batch_wait",
-                           t_start - t_deq, ctx=ctx, ts=_ts(t_start))
+        for i, r in enumerate(inflight):
             if fault is not None:
                 trace_ctx.emit(rec, "serve/request/primary_attempt",
-                               retry_s, ctx=ctx, ts=_ts(t_run),
-                               fault=type(fault[0]).__name__)
-            trace_ctx.emit(rec, "serve/request/pack_h2d", pack_s,
-                           ctx=ctx, ts=_ts(t_run + pack_s))
-            trace_ctx.emit(rec, "serve/request/dispatch", disp_s,
-                           ctx=ctx, ts=_ts(t_run + pack_s + disp_s))
-            trace_ctx.emit(rec, "serve/request/scatter_back", scatter_s,
-                           ctx=ctx, ts=_ts(done))
+                               t0=t_start, t1=t_start + fault[1],
+                               ctx=r.ctx, fault=type(fault[0]).__name__)
+            for s in spans:
+                if aligned and i not in chunks[s.fields["chunk"]]:
+                    continue
+                trace_ctx.emit(
+                    rec, "serve/request/" + s.name.rpartition("/")[2],
+                    t0=s.t0, t1=s.t1, ctx=r.ctx, ring=False,
+                    chunk=s.fields["chunk"])
 
     def _audit(self, engine: ServeEngine, rid: str, pods: List[dict],
                ans: dict) -> None:

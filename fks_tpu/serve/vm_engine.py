@@ -28,6 +28,7 @@ remains the exact reference and the escape hatch.
 """
 from __future__ import annotations
 
+import contextlib
 import copy
 import dataclasses
 import hashlib
@@ -258,7 +259,7 @@ class VMServeEngine(ServeEngine):
         shadow.params = prog
         shadow._prog_dev = self._upload_program(prog)
         shadow._swap_lock = threading.RLock()
-        shadow.last_batch_timing = {"pack_h2d_s": 0.0, "dispatch_s": 0.0}
+        shadow._reset_batch_log()
         shadow.last_swap_breakdown = {}
         return shadow
 
@@ -341,8 +342,13 @@ class VMServeEngine(ServeEngine):
     def _invoke(self, compiled, pods, kt_dev, s0):
         return compiled(self._prog_dev, pods, kt_dev, s0)
 
-    def answer_batch(self, pod_lists):
+    @contextlib.contextmanager
+    def _batch_guard(self):
         # a whole batch answers under ONE champion: swap_program's flip
         # waits for the in-flight batch instead of tearing it
-        with self._swap_lock:
-            return super().answer_batch(pod_lists)
+        with obs.span("serve/batch/swap_wait"):
+            self._swap_lock.acquire()
+        try:
+            yield
+        finally:
+            self._swap_lock.release()

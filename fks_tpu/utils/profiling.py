@@ -1,73 +1,26 @@
-"""Profiling hooks: wall-clock timing that respects async dispatch, JAX
-device tracing, and throughput counters.
+"""Timing one-liners and throughput counters.
 
 The reference's only instrumentation is ad-hoc ``time.time()`` deltas around
 runs (reference: tests/test_scheduler.py:266-269, test_integration.py:130-137,
 funsearch/funsearch_integration.py:586-589) — no profiler hooks at all
-(SURVEY.md §5). Here timing is a first-class utility that (a) blocks on the
-actual device result before stopping the clock (JAX dispatch is async; a
-naive delta measures enqueue time, not compute), and (b) can capture a real
-XLA profile for TensorBoard/xprof when a hotspot needs the instruction-level
-view.
+(SURVEY.md §5). Scoped timing lives in ONE place, ``fks_tpu.obs.span`` (it
+blocks on a registered device value before stopping the clock, records to
+the in-memory span ring and lands on any profiler session's timeline); what
+stays here is ``block_timed``, the call-and-materialize one-liner, and the
+``ThroughputMeter``.
 """
 from __future__ import annotations
 
-import contextlib
 import time
 from dataclasses import dataclass, field
-from typing import Any, Iterator, List, Optional
+from typing import List, Optional
 
 import jax
 
 
-@dataclass
-class Timing:
-    """Result of a ``timed`` block. ``seconds`` is valid after the block."""
-
-    label: str = ""
-    seconds: float = 0.0
-    _sync: Any = None
-
-    def sync(self, value):
-        """Register a value (any pytree of jax arrays) produced inside the
-        block; the clock stops only after it is materialized on device.
-        Returns the value for inline use."""
-        self._sync = value
-        return value
-
-
-@contextlib.contextmanager
-def timed(label: str = "", sync: Any = None,
-          on_exit: Any = None) -> Iterator[Timing]:
-    """Measure a block's wall time. For device work, register the block's
-    output via ``t.sync(...)`` so the clock includes the actual compute
-    (JAX dispatch is async; without a sync the delta measures enqueue
-    time). ``sync=`` covers values that already exist at entry.
-
-    ``on_exit`` (``Callable[[Timing], None]``) fires after the clock stops,
-    device sync included — the extension point ``fks_tpu.obs.span`` builds
-    its flight-recorder span events on (nesting, xprof mirroring, and the
-    run-dir event live there; this stays the bare mechanism).
-
-    >>> with timed("eval") as t:
-    ...     result = t.sync(ev(params))
-    >>> t.seconds
-    """
-    out = Timing(label=label, _sync=sync)
-    t0 = time.perf_counter()
-    try:
-        yield out
-    finally:
-        if out._sync is not None:
-            jax.block_until_ready(out._sync)
-        out.seconds = time.perf_counter() - t0
-        if on_exit is not None:
-            on_exit(out)
-
-
 def block_timed(fn, *args, **kwargs):
     """Call ``fn`` and return (result, seconds) with the result fully
-    materialized — the one-liner version of ``timed``.
+    materialized.
 
     The result must be a pytree of jax arrays (or plain scalars):
     ``jax.block_until_ready`` treats unregistered custom objects as opaque
@@ -77,23 +30,6 @@ def block_timed(fn, *args, **kwargs):
     result = fn(*args, **kwargs)
     jax.block_until_ready(result)
     return result, time.perf_counter() - t0
-
-
-@contextlib.contextmanager
-def device_trace(logdir: str) -> Iterator[None]:
-    """Capture a JAX/XLA profile into ``logdir`` (viewable with
-    TensorBoard's profile plugin / xprof). No-op if the profiler is
-    unavailable on this backend."""
-    try:
-        jax.profiler.start_trace(logdir)
-        started = True
-    except Exception:  # pragma: no cover - backend without profiler
-        started = False
-    try:
-        yield
-    finally:
-        if started:
-            jax.profiler.stop_trace()
 
 
 @dataclass

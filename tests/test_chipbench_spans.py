@@ -1,0 +1,213 @@
+"""The benchmark's span reducer and the per-layer metrics that read it.
+
+``chipbench/reduce/spans.py`` on hand-made span lists (union, self time,
+per-call sums, the self-check failing on a missing root and on a ring that
+dropped), and every span metric present and finite after a tiny traced run
+of whatif8 and of codegen8x4 on four virtual CPU devices. Presence only:
+no CPU time is written under a device metric's name.
+"""
+import contextlib
+import io
+import json
+import math
+import os
+
+import pytest
+
+from chipbench import cells, run
+from chipbench.reduce import spans as rs
+from fks_tpu.obs.spans import SpanRecord
+
+SPAN_METRICS = sorted(
+    os.path.basename(p)[:-3]
+    for p in os.listdir(os.path.join(cells.HERE, "metrics"))
+    if p.endswith(".py") and "chipbench.reduce import spans" in open(
+        os.path.join(cells.HERE, "metrics", p)).read())
+
+
+def rec(seq, name, t0, t1, sid, parent=None, trace="t", **fields):
+    return SpanRecord(seq, name, t0, t1, sid, parent, trace, 1,
+                      fields or None)
+
+
+def whatif_ring(calls=2, gap=0.001):
+    """``calls`` calls of two requests each; per call one batch of two
+    chunks: stack 10 ms, wait 30 ms, stack 10 ms (hidden), wait 20 ms."""
+    out, seq = [rec(0, "serve/request", 0.0, 0.5, "w", request="c-1-0")], 1
+    t = 1.0
+    for i in range(calls):
+        b = f"b{i}"
+        kids = [("serve/chunk/stack", 0.000, 0.010, {"chunk": 0}),
+                ("serve/chunk/h2d", 0.010, 0.012, {"bytes": 2000}),
+                ("serve/chunk/wait_device", 0.012, 0.042, {}),
+                ("serve/chunk/stack", 0.042, 0.052, {"chunk": 1}),
+                ("serve/chunk/h2d", 0.052, 0.054, {"bytes": 1000}),
+                ("serve/chunk/wait_device", 0.054, 0.074, {})]
+        for name, a, z, f in kids:
+            out.append(rec(seq, name, t + a, t + z, f"{b}k{seq}", b, b, **f))
+            seq += 1
+        out.append(rec(seq, "serve/batch", t, t + 0.075, b, None, b))
+        seq += 1
+        for j in range(2):
+            tid = f"r{i}{j}"
+            out.append(rec(seq, "serve/request/queue_wait", t - 0.002,
+                           t - 0.001, f"{tid}q", tid, tid))
+            out.append(rec(seq + 1, "serve/request", t - 0.002, t + 0.078,
+                           tid, None, tid, request=f"c{i}-{j}", batch=b))
+            seq += 2
+        t += 0.080 + gap
+    return out
+
+
+# ------------------------------------------------------------- arithmetic
+
+def test_union_merges_overlaps_and_keeps_gaps():
+    assert rs.union([(0, 1), (0.5, 2), (3, 4)]) == pytest.approx(3.0)
+    assert rs.union([(3, 4), (0, 1), (0.2, 0.4)]) == pytest.approx(2.0)
+    assert rs.union([]) == 0.0
+
+
+def test_self_time_looks_through_stage_spans():
+    root = rec(9, "tier/evaluate", 0.0, 10.0, "root")
+    recs = [rec(0, "tier/preflight", 0.0, 1.0, "a", "root"),
+            rec(1, "stage/device-eval", 1.0, 10.0, "st", "root"),
+            rec(2, "tier/vm_batch/launch", 1.5, 9.0, "b", "st"),
+            # a grandchild of a real span does not count twice
+            rec(3, "mesh/segment", 2.0, 3.0, "c", "b"), root]
+    kids = rs.children(recs)
+    # 10 s minus preflight (1 s) minus launch (7.5 s): the stage span only
+    # groups, so the 0.5 + 1 s around the launch stay unattributed
+    assert rs.self_time(root, kids) == pytest.approx(1.5)
+
+
+def test_whatif_selection_and_per_call_sums():
+    ring = whatif_ring()
+    calls = rs.select_whatif(ring, 0, 2, 4, 0.160)
+    assert len(calls) == 2
+    assert rs.extent_s(calls) == pytest.approx(0.160)
+    assert rs.union_s(calls, ("serve/chunk/wait_device",)) \
+        == pytest.approx(0.100)
+    assert rs.sum_s(calls, ("serve/chunk/stack",)) == pytest.approx(0.040)
+    assert rs.field_sum(calls, ("serve/chunk/h2d",), "bytes") == 6000
+    ctx = {"_span_calls": calls}
+    assert rs.exposed_ms_per_call(ctx, "serve/chunk/wait_device") \
+        == pytest.approx(30.0)
+    assert rs.union_ms_per_call(ctx, "serve/chunk/wait_device") \
+        == pytest.approx(50.0)
+    assert rs.sum_ms_per_call(ctx, "serve/chunk/stack") \
+        == pytest.approx(20.0)
+    assert rs.kb_per_call(ctx, "serve/chunk/h2d") == pytest.approx(3.0)
+    assert rs.request_wait_p50_ms(ctx, "serve/request/queue_wait") \
+        == pytest.approx(1.0)
+    # the batch root is 75 ms, its chunk spans cover 74
+    assert rs.unattributed_share(ctx, "serve/batch") \
+        == pytest.approx(100 / 75)
+    assert rs.sum_ms_per_call(ctx, "serve/chunk/extract") is None
+
+
+def test_selection_fails_on_a_missing_root_and_on_a_wrong_clock():
+    ring = whatif_ring(calls=3)
+    assert rs.select_whatif(ring, 0, 3, 6, 0.240) is not None
+    no_c1 = [r for r in ring
+             if (r.fields or {}).get("request") not in ("c1-0", "c1-1")]
+    assert rs.select_whatif(no_c1, 0, 3, 6, 0.240) is None
+    one_gone = [r for r in ring if (r.fields or {}).get("request") != "c1-1"]
+    assert rs.select_whatif(one_gone, 0, 3, 6, 0.240) is None
+    # the driver's clock disagrees by more than 0.5 %
+    assert rs.select_whatif(ring, 0, 3, 6, 0.240 * 1.006) is None
+    assert rs.select_whatif(ring, 0, 3, 6, 0.240 * 1.004) is not None
+
+
+def test_selection_fails_when_the_ring_dropped_inside_the_window():
+    ring = whatif_ring()
+    # the warm-up span (ended before the window) is still held: whatever
+    # was dropped is older than the window
+    assert rs.select_whatif(ring, 7, 2, 4, 0.160) is not None
+    # it is gone: the oldest held record is the window's own
+    assert rs.select_whatif(ring[1:], 7, 2, 4, 0.160) is None
+    assert rs.select_whatif(ring[1:], 0, 2, 4, 0.160) is not None
+
+
+def test_generations_are_taken_in_order_after_the_warm_up():
+    recs, t = [], 0.0
+    for i, length in enumerate([9.0, 2.0, 2.0, 2.0, 2.0]):
+        recs.append(rec(2 * i, "tier/preflight", t, t + 0.5, f"p{i}",
+                        f"g{i}", f"g{i}"))
+        recs.append(rec(2 * i + 1, "tier/evaluate", t, t + length, f"g{i}",
+                        None, f"g{i}"))
+        t += length + 0.01
+    calls = rs.select_generations(recs, 0, 3, 6.0)
+    assert [round(c.t0, 2) for c in calls] == [9.01, 11.02, 13.03]
+    assert rs.sum_s(calls, ("tier/preflight",)) == pytest.approx(1.5)
+    # the warm-up call is not a window call: with it the clock disagrees
+    assert rs.select_generations(recs, 0, 3, 13.0) is None
+    assert rs.select_generations(recs, 0, 5, 17.0) is None  # too few roots
+
+
+def test_a_program_without_the_ring_reads_as_nothing(monkeypatch):
+    from fks_tpu.obs import spans
+
+    monkeypatch.delattr(spans, "LOG")
+    assert rs.ring() is None
+    ctx = {"queries": 4, "calls": 2, "call_seconds": 0.16}
+    assert rs.window_calls(ctx) is None
+    for name in SPAN_METRICS:
+        assert cells.metric_reader(name)(dict(ctx)) is None
+
+
+def test_every_span_metric_is_declared_with_its_files():
+    bench = json.load(open(os.path.join(cells.ROOT, "BENCHMARK.json")))
+    declared = {m["name"]: m for m in bench["per_layer"]}
+    assert len(SPAN_METRICS) == 16
+    for name in SPAN_METRICS:
+        assert name in declared, name
+        meta = json.load(open(os.path.join(cells.HERE, "metrics",
+                                           name + ".json")))
+        for key in ("unit", "better", "source", "layer", "moves"):
+            assert meta[key] == declared[name][key], (name, key)
+        assert declared[name]["workloads"], name
+
+
+# ------------------------------------------------- the cells, end to end
+
+def _traced_cell(name, tmp_path, monkeypatch):
+    from chipbench.selftest.tests import TINY, batched_vm_on_cpu
+    from fks_tpu import utils
+    from fks_tpu.obs import spans
+
+    # the benchmark is one fresh process: generations are counted from
+    # its warm-up call, so what earlier tests of this worker left in the
+    # ring has to go
+    spans.LOG.clear()
+    # tier-1 keeps the persistent compile cache off (conftest)
+    monkeypatch.setattr(utils, "place_compile_cache", lambda: str(tmp_path))
+    # what the chip picks by itself: the batched VM tier, bounded segments
+    monkeypatch.setenv("FKS_VM_SEG_STEPS", "32")
+    with batched_vm_on_cpu(), contextlib.redirect_stdout(io.StringIO()):
+        return run.run_cell(name, 2 ** 31 + 5, 0.5, True, require_tpu=False,
+                            overrides=TINY)
+
+
+@pytest.mark.parametrize("name", ["openb1523.whatif8", "openb16.codegen8x4"])
+def test_traced_cell_reports_every_span_metric(name, tmp_path, monkeypatch):
+    res = _traced_cell(name, tmp_path, monkeypatch)
+    assert res["correct"] and res["failed"] == 0
+    cell = cells.load_cell(name)
+    want = [m["name"] for m in cell.per_layer if m["name"] in SPAN_METRICS]
+    assert want
+    for m in want:
+        assert m in res["metrics"], m
+        assert math.isfinite(res["metrics"][m]["value"]), m
+    if name == "openb1523.whatif8":
+        assert len(want) == 10
+        v = {m: res["metrics"][m]["value"] for m in want}
+        # exposed + waited is the call, as the driver's own clock has it
+        per_call = v["serve.exposed_host_ms_per_call"] \
+            + v["serve.wait_device_ms_per_call"]
+        assert v["serve.wait_device_ms_per_call"] > 0
+        assert per_call > v["serve.stack_ms_per_call"] \
+            + v["serve.h2d_ms_per_call"] + v["serve.harvest_ms_per_call"]
+        assert v["serve.h2d_kb_per_call"] > 0 and v["serve.d2h_kb_per_call"] > 0
+    else:
+        assert len(want) == 6
+        assert res["metrics"]["mesh.host_ms_per_call"]["value"] > 0

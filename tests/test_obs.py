@@ -126,6 +126,73 @@ def test_span_stack_unwinds_on_exception():
     assert obs.span_path() == ""
 
 
+def test_span_records_to_the_ring_without_a_recorder():
+    """The default state: no run directory, and the span is still kept,
+    with its stamps, ids, thread and fields, children before parents."""
+    import threading
+    import time
+
+    from fks_tpu.obs import spans
+
+    before = time.perf_counter()
+    with obs.span("outer", lanes=4) as outer:
+        with obs.span("inner") as inner:
+            inner.set(bytes=128)
+    after = time.perf_counter()
+    got = {r.name: r for r in spans.LOG.snapshot()[-2:]}
+    o, i = got["outer"], got["inner"]
+    assert o is outer.record and i is inner.record
+    assert before <= o.t0 <= i.t0 <= i.t1 <= o.t1 <= after
+    assert i.seq < o.seq
+    assert o.parent_id is None and o.trace_id == o.span_id
+    assert i.parent_id == o.span_id and i.trace_id == o.trace_id
+    assert o.fields == {"lanes": 4} and i.fields == {"bytes": 128}
+    assert o.thread == i.thread == threading.get_ident()
+    assert outer.seconds == o.t1 - o.t0 == o.seconds
+
+
+def test_span_lands_on_the_profiler_timeline_and_names_no_ops(monkeypatch):
+    """Every span enters ``TraceAnnotation("fks/<name>")``; the host span
+    no longer enters ``jax.named_scope`` (it named nothing: the programs
+    it calls are already compiled)."""
+    import jax
+
+    seen = []
+
+    class Ann:
+        def __init__(self, name):
+            self.name = name
+
+        def __enter__(self):
+            seen.append(("enter", self.name))
+
+        def __exit__(self, *exc):
+            seen.append(("exit", self.name))
+
+    monkeypatch.setattr(jax.profiler, "TraceAnnotation", Ann)
+    monkeypatch.setattr(jax, "named_scope", lambda name: seen.append(
+        ("named_scope", name)))
+    with obs.span("serve/chunk/h2d"):
+        seen.append("body")
+    assert seen == [("enter", "fks/serve/chunk/h2d"), "body",
+                    ("exit", "fks/serve/chunk/h2d")]
+
+
+def test_recorded_span_event_carries_t0(tmp_path):
+    import time
+
+    with obs.FlightRecorder(str(tmp_path / "r")) as rec:
+        t_before = time.perf_counter()
+        with obs.span("stage", recorder=rec, chunk=2) as t:
+            pass
+    (row,) = [json.loads(l) for l in
+              (tmp_path / "r" / "events.jsonl").read_text().splitlines()
+              if json.loads(l)["kind"] == "span"]
+    assert row["chunk"] == 2 and row["depth"] == 0
+    assert row["t0"] == pytest.approx(t.t0, abs=1e-6) and t.t0 >= t_before
+    assert row["seconds"] == pytest.approx(t.seconds, abs=1e-6)
+
+
 # -------------------------------------------------------------- telemetry
 
 def test_compile_watcher_captures_compile_events(tmp_path):

@@ -19,6 +19,7 @@ import pytest
 from fks_tpu.obs.profiler import (
     NULL_PROFILER, StageProfiler, profile_launch,
 )
+from fks_tpu.obs.spans import span as obs_span
 from fks_tpu.obs.telemetry import CompileWatcher
 from fks_tpu.parallel.mesh import occupancy_stats, pad_stats
 
@@ -67,6 +68,46 @@ def test_stage_records_wall_and_compile_split():
     assert steady["compute_seconds"] == steady["wall_seconds"]
     # each stage landed as one device_profile metric
     assert [r["kind"] for r in rec.rows] == ["device_profile"] * 2
+
+
+def test_stage_is_built_on_one_span():
+    """Enabled: one ``obs.span`` per stage, named ``stage/<name>`` unless
+    the call site names it, and ``wall_seconds`` is that span's length."""
+    from fks_tpu.obs import spans
+
+    with StageProfiler(scope="t", recorder=_Recorder()) as prof:
+        with prof.stage("steady", lanes=8) as h:
+            h.annotate(pad_waste_fraction=0.25)
+        with prof.stage("h2d", span="serve/chunk/h2d", chunk=1) as h2:
+            h2.span.set(bytes=64)
+    a, b = spans.LOG.snapshot()[-2:]
+    assert (a.name, b.name) == ("stage/steady", "serve/chunk/h2d")
+    assert a is h.span.record and b is h2.span.record
+    # fields given to stage() ride on both; annotate() is the record's
+    assert a.fields == {"lanes": 8}
+    assert b.fields == {"chunk": 1, "bytes": 64}
+    ra, rb = prof.records
+    assert ra["wall_seconds"] == pytest.approx(a.t1 - a.t0, abs=1e-6)
+    assert rb["wall_seconds"] == pytest.approx(b.t1 - b.t0, abs=1e-6)
+    assert ra["lanes"] == 8 and ra["occupancy"] == 0.75
+    assert rb["chunk"] == 1 and "bytes" not in rb
+
+
+def test_disabled_stage_opens_only_the_spans_call_sites_name():
+    from fks_tpu.obs import spans
+
+    with obs_span("test/fence") as fence:
+        pass
+    with NULL_PROFILER.stage("steady", lanes=8) as h:
+        assert h.span is None
+    with NULL_PROFILER.stage("h2d", span="serve/chunk/h2d", chunk=0) as h2:
+        assert h2.sync("value") == "value"     # no fence when disabled
+        h2.annotate(anything=1)
+        h2.span.set(bytes=32)
+    new = [r for r in spans.LOG.snapshot() if r.seq > fence.record.seq]
+    assert [(r.name, r.fields) for r in new] == [
+        ("serve/chunk/h2d", {"chunk": 0, "bytes": 32})]
+    assert NULL_PROFILER.records == [] and h2.record is None
 
 
 def test_compile_split_matches_watcher():

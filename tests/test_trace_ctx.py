@@ -75,11 +75,18 @@ def test_context_object_crosses_threads():
     assert seen["during"] is ctx
 
 
-def test_emit_noop_without_context_or_recorder(tmp_path):
-    from fks_tpu.obs import NULL
+def test_emit_without_recorder_reaches_the_ring_only(tmp_path):
+    """No recorder: the span still lands in the in-memory ring, with the
+    explicit stamps; no context: nothing is written anywhere."""
+    from fks_tpu.obs import NULL, spans
 
-    assert trace_ctx.emit(NULL, "x", 0.1,
-                          ctx=trace_ctx.new_trace()) is None
+    ctx = trace_ctx.new_trace()
+    sid = trace_ctx.emit(NULL, "x", t0=10.0, t1=10.25, ctx=ctx, lanes=2)
+    got = [r for r in spans.LOG.snapshot() if r.span_id == sid]
+    assert len(got) == 1
+    assert (got[0].name, got[0].t0, got[0].t1) == ("x", 10.0, 10.25)
+    assert got[0].trace_id == ctx.trace_id
+    assert got[0].parent_id == ctx.span_id and got[0].fields == {"lanes": 2}
     rec = FlightRecorder(str(tmp_path / "r"))
     try:
         assert trace_ctx.emit(rec, "x", 0.1) is None  # no active ctx
@@ -88,6 +95,51 @@ def test_emit_noop_without_context_or_recorder(tmp_path):
     ep = tmp_path / "r" / "events.jsonl"
     rows = read_jsonl(str(ep)) if ep.exists() else []
     assert trace_ctx.trace_spans(rows) == []
+
+
+def test_ids_are_cheap_unique_and_sixteen_hex():
+    ids = [trace_ctx.new_span_id() for _ in range(1000)]
+    assert len(set(ids)) == 1000
+    assert all(len(i) == 16 and int(i, 16) >= 0 for i in ids)
+    a, b = trace_ctx.new_trace(), trace_ctx.new_trace("gen")
+    assert a.trace_id.startswith("req-") and b.trace_id.startswith("gen-")
+    assert len({a.trace_id, b.trace_id, a.span_id, b.span_id}) == 4
+    assert a.carries == ()
+
+
+def test_emit_copy_mode_writes_the_event_but_not_the_ring(tmp_path):
+    """``ring=False``: the run directory's copy of a span the ring already
+    holds (the per-request view of a chunk span)."""
+    from fks_tpu.obs import spans
+
+    ctx = trace_ctx.new_trace()
+    rec = FlightRecorder(str(tmp_path / "r"))
+    n0 = len(spans.LOG.snapshot())
+    sid = trace_ctx.emit(rec, "serve/request/stack", t0=5.0, t1=5.5,
+                         ctx=ctx, ring=False, chunk=1)
+    rec.close()
+    assert len(spans.LOG.snapshot()) == n0
+    (row,) = trace_ctx.trace_spans(
+        read_jsonl(str(tmp_path / "r" / "events.jsonl")))
+    assert row["span_id"] == sid and row["parent_id"] == ctx.span_id
+    assert (row["t0"], row["seconds"], row["chunk"]) == (5.0, 0.5, 1)
+
+
+def test_span_under_a_parentless_context_is_that_traces_root():
+    """The batcher's flush context: a trace id with no open parent. The
+    span opened under it is a root of that trace, and parents the rest."""
+    from fks_tpu import obs
+
+    flush = trace_ctx.TraceContext("batch-x", None, carries=("req-1",))
+    with trace_ctx.activate(flush):
+        with obs.span("serve/batch") as root:
+            assert trace_ctx.current().span_id == root.span_id
+            with obs.span("serve/chunk/stack") as kid:
+                pass
+        assert trace_ctx.current() is flush
+    assert root.record.parent_id is None
+    assert root.record.trace_id == kid.record.trace_id == "batch-x"
+    assert kid.record.parent_id == root.record.span_id
 
 
 def test_emit_root_and_child_linkage(tmp_path):
@@ -170,7 +222,7 @@ def test_build_tree_and_orphans():
 def test_waterfall_complete_requires_every_component():
     rows = _serve_trace()
     assert trace_ctx.waterfall_complete(rows)
-    assert not trace_ctx.waterfall_complete(rows[:-1])  # scatter_back gone
+    assert not trace_ctx.waterfall_complete(rows[:-1])  # extract gone
     assert not trace_ctx.waterfall_complete([])
     two_roots = rows + [_span("req-x", "r2", None, "serve/request",
                               0.01, 10.01)]
@@ -182,7 +234,8 @@ def test_waterfall_complete_requires_every_component():
 def test_render_waterfall_orders_and_labels():
     out = trace_ctx.render_waterfall(_serve_trace())
     lines = out.splitlines()
-    assert "req-x" in lines[0] and "6 spans" in lines[0]
+    n = 1 + len(trace_ctx.SERVE_COMPONENTS)
+    assert "req-x" in lines[0] and f"{n} spans" in lines[0]
     assert "serve/request" in lines[1]
     # components render indented under the root, in start order
     for comp, line in zip(trace_ctx.SERVE_COMPONENTS, lines[2:]):
@@ -280,11 +333,19 @@ def test_served_requests_reconstruct_complete_waterfalls(tmp_path, stack):
         assert trace_ctx.waterfall_complete(spans)
         root = next(s for s in spans if s["parent_id"] is None)
         assert root["path"] == trace_ctx.SERVE_ROOT
-        # children sum exactly to the root wall (scatter_back is the
-        # clamped remainder, so the waterfall never lies about totals)
-        child_sum = sum(s["seconds"] for s in spans
-                        if s["parent_id"] == root["span_id"])
-        assert child_sum == pytest.approx(root["seconds"], abs=5e-6)
+        # the children are the chunk's REAL spans with their own stamps:
+        # each lies inside the root, they do not overlap, and nothing is
+        # made up to fill the root (what is left is bookkeeping between
+        # the spans)
+        kids = sorted((s for s in spans
+                       if s["parent_id"] == root["span_id"]),
+                      key=lambda s: s["t0"])
+        lo, hi = root["t0"], root["t0"] + root["seconds"]
+        for a, b in zip(kids, kids[1:]):
+            assert a["t0"] + a["seconds"] <= b["t0"] + 2e-6
+        assert lo - 2e-6 <= kids[0]["t0"]
+        assert kids[-1]["t0"] + kids[-1]["seconds"] <= hi + 2e-6
+        assert sum(s["seconds"] for s in kids) <= root["seconds"] + 1e-5
 
 
 def test_degraded_retry_stays_on_one_trace(tmp_path, stack):

@@ -9,31 +9,30 @@ import pytest
 import jax
 import jax.numpy as jnp
 
+from fks_tpu.obs import span
 from fks_tpu.utils import (
     MetricsWriter, ThroughputMeter, block_timed, get_logger, result_record,
-    timed,
 )
 
 
-def test_timed_syncs_registered_value(monkeypatch):
-    """The clock must stop only after the value registered via t.sync() is
+def test_span_syncs_registered_value_at_exit(monkeypatch):
+    """The one scoped timer (``obs.span``, which took over ``timed``): the
+    clock must stop only after the value registered via t.sync() is
     materialized — i.e. block_until_ready is invoked on exactly that value
     at context exit (deleting the sync would regress to enqueue timing)."""
-    from fks_tpu.utils import profiling
-
     synced = []
-    monkeypatch.setattr(profiling.jax, "block_until_ready",
+    monkeypatch.setattr(jax, "block_until_ready",
                         lambda v: synced.append(v))
     sentinel = object()
-    with timed("eval") as t:
+    with span("eval") as t:
         got = t.sync(sentinel)
         assert synced == []  # not yet: only at context exit
     assert got is sentinel
     assert synced == [sentinel]
-    assert t.seconds >= 0
+    assert t.seconds >= 0 and t.t1 >= t.t0 > 0
 
     pre = object()
-    with timed("pre-existing", sync=pre):
+    with span("pre-existing", sync=pre):
         pass
     assert synced == [sentinel, pre]
 
@@ -101,37 +100,6 @@ def test_block_timed_pytree_result(monkeypatch):
     assert float(tree["pair"][1]) == 4.0
     assert secs > 0
     assert len(synced) == 1 and synced[0] is tree  # whole tree, one call
-
-
-def test_device_trace_noop_when_profiler_unavailable(tmp_path, monkeypatch):
-    """A backend without profiler support must not break the traced block,
-    and stop_trace must not be called for a trace that never started."""
-    from fks_tpu.utils import profiling
-
-    stopped = []
-    monkeypatch.setattr(
-        profiling.jax.profiler, "start_trace",
-        lambda d: (_ for _ in ()).throw(RuntimeError("no profiler")))
-    monkeypatch.setattr(profiling.jax.profiler, "stop_trace",
-                        lambda: stopped.append(True))
-    ran = []
-    with profiling.device_trace(str(tmp_path)):
-        ran.append(True)
-    assert ran == [True]
-    assert stopped == []  # never started => never stopped
-
-
-def test_device_trace_stops_started_trace(tmp_path, monkeypatch):
-    from fks_tpu.utils import profiling
-
-    calls = []
-    monkeypatch.setattr(profiling.jax.profiler, "start_trace",
-                        lambda d: calls.append(("start", d)))
-    monkeypatch.setattr(profiling.jax.profiler, "stop_trace",
-                        lambda: calls.append(("stop",)))
-    with profiling.device_trace(str(tmp_path)):
-        pass
-    assert calls == [("start", str(tmp_path)), ("stop",)]
 
 
 def test_metrics_writer_coerces_accelerator_scalars(tmp_path):

@@ -54,8 +54,9 @@ is broken — its alerts on the real archive would be noise or silence.
 Recorded as ``trends_gate``. Pure-host (no jax import needed).
 
 A SPAN TRACE GATE follows: a recorded ``cli serve --selftest`` run must
-yield a COMPLETE causal waterfall (queue_wait / batch_wait / pack_h2d /
-dispatch / scatter_back under one root) for 100% of its served requests
+yield a COMPLETE causal waterfall (queue_wait / batch_wait and the carrying
+chunk's stack / pack / h2d / enqueue / wait_device / d2h / extract under
+one root) for 100% of its served requests
 (``cli spans <dir> --check-complete``), and a recorded 1-generation
 fake-LLM evolve must attribute >= 95% of the generation wall to traced
 stages (``cli spans <dir> --critical-path --min-fraction 0.95``). A
