@@ -439,7 +439,7 @@ def measure_codetput():
 
         mode = f"sharded over {len(devices)} devices"
     else:
-        seg = flat.make_segmented_population_run(wl, vm.score_static, cfg,
+        seg = flat.make_segmented_population_run(wl, vm.score, cfg,
                                                  seg_steps=4096)
         state0 = flat.initial_state(wl, cfg)
 
@@ -814,7 +814,7 @@ def stage_scale1k(gate: str = "") -> int:
                              wl_v.cluster.g_padded, capacity=256)
     stacked = vm.stack_programs([prog] * pop, capacity=256)
     _, vm_speedup, vm_drift = ratio_pair(
-        wl_v, 4 * vm_pods, vm.score_static, stacked, "vm_ratio")
+        wl_v, 4 * vm_pods, vm.score, stacked, "vm_ratio")
 
     # -- parametric ratio: the cheap-policy tier, reported as the honest
     # negative control (queue-dominated step; prefilter cannot pay here

@@ -60,6 +60,7 @@ import dataclasses
 import hashlib
 import json
 import os
+import re
 from pathlib import Path
 from typing import Dict, Iterable, List, Optional, Sequence, Set, Tuple
 
@@ -452,8 +453,11 @@ def _micro_workload():
 def _jaxpr_hash(fn, *args) -> str:
     import jax
 
-    return hashlib.sha256(
-        str(jax.make_jaxpr(fn)(*args)).encode()).hexdigest()
+    # a jaxpr prints function-valued params by repr (the batching rule of
+    # vm._loop_bound's ``custom_vmap_call``): the address is the process's,
+    # not the program's
+    text = re.sub(r" at 0x[0-9a-f]+", "", str(jax.make_jaxpr(fn)(*args)))
+    return hashlib.sha256(text.encode()).hexdigest()
 
 
 def compute_pins() -> Dict[str, object]:

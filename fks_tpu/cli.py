@@ -564,7 +564,7 @@ def cmd_scale(args):
             # so auto may choose k>0 here while the parametric tier above
             # stayed dense
             pk_code = resolve_auto_prefilter(
-                vm.score_static, progs[0], c.n_padded, c.g_padded,
+                vm.score, progs[0], c.n_padded, c.g_padded,
                 override=pk_override, recorder=rec)
             ccfg = dataclasses.replace(cfg, node_prefilter_k=pk_code)
             if len(devices) > 1:
@@ -576,7 +576,7 @@ def cmd_scale(args):
                     cres = ct.sync(cev(cpadded, creal)[0])
             else:
                 mod = get_engine(code_engine)
-                crun = mod.make_population_run_fn(wl, vm.score_static, ccfg)
+                crun = mod.make_population_run_fn(wl, vm.score, ccfg)
                 with span("code_eval", code_population=args.code_pop) as ct:
                     cres = ct.sync(crun(stacked, mod.initial_state(wl, ccfg)))
             cscores = cres.policy_score[: args.code_pop]

@@ -193,8 +193,8 @@ class CodeEvaluator:
         self.vm_batch = vm_batch
         # Bounded device-call length for the batched tier (flat engine
         # only). A full-trace batched-VM launch is minutes of device time
-        # whatever the population size (v5e, 8 lanes x 512 op slots:
-        # 3.8 ms/event, up to 65k events), and one device call can be
+        # whatever the population size (v5e, 8 lanes x 370 live op
+        # slots: 2.9 ms/event, up to 65k events), and one device call can be
         # neither observed nor interrupted: segments hand control back to
         # the host every ``vm_seg_steps`` events, which is what ticks the
         # profiler/flight-recorder segment counters and lets Ctrl-C land
@@ -275,7 +275,7 @@ class CodeEvaluator:
                 # segmented runners have no trace-batched variant, so
                 # suite mode always takes the single-dispatch path
                 from fks_tpu.scenarios.robust import make_suite_eval
-                ev = make_suite_eval(self.suite, vm.score_static, self.cfg,
+                ev = make_suite_eval(self.suite, vm.score, self.cfg,
                                      population=True, engine=self.engine)
                 self._vm_pop_run = lambda progs, _s: ev(progs)
                 return self._vm_pop_run
@@ -287,14 +287,14 @@ class CodeEvaluator:
                 # manages its own inner jits; results identical to the
                 # unsegmented runner (tests/test_flat_engine.py)
                 self._vm_pop_run = self._mod.make_segmented_population_run(
-                    self.workload, vm.score_static, self.cfg,
+                    self.workload, vm.score, self.cfg,
                     seg_steps=self.vm_seg_steps,
                     on_segment=self._count_segment,
                     double_buffer=self.vm_double_buffer)
             else:
                 self._vm_pop_run = jax.jit(
                     self._mod.make_population_run_fn(
-                        self.workload, vm.score_static, self.cfg))
+                        self.workload, vm.score, self.cfg))
         return self._vm_pop_run
 
     def _vm_mesh_runner(self):
@@ -362,8 +362,12 @@ class CodeEvaluator:
         # launch + wait_device is the device's part of the generation (a
         # segmented runner already waits for its segments inside launch);
         # d2h is the one transfer and nothing else
+        # slots / capacity: how far the op-slot loop runs (vm._loop_bound:
+        # the longest live program; the slowest shard's when sharded)
         with obs.span("tier/vm_batch/launch", lanes=pop,
-                      shards=self._n_shards):
+                      shards=self._n_shards,
+                      slots=max(int(p.n_ops) for p in progs),
+                      capacity=int(stacked.opcode.shape[-1])):
             if self._n_shards > 1 and self.suite is None:
                 # each device interprets pop/shards lanes; the elite
                 # outputs are discarded here (the evolution loop ranks on

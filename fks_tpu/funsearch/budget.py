@@ -158,7 +158,7 @@ class BudgetedSuiteEval:
             from fks_tpu.funsearch import vm
             from fks_tpu.scenarios.robust import make_suite_eval
             self._probe_run = make_suite_eval(
-                self._probe_suite, vm.score_static, self._probe_cfg,
+                self._probe_suite, vm.score, self._probe_cfg,
                 population=True, engine=self.engine)
         return self._probe_run
 

@@ -20,10 +20,13 @@ Pipeline:
     -> ``VMProgram`` pytree of int32/float32 arrays, padded to a bucket size
        so ONE compiled engine serves every candidate of that bucket.
 
-Execution (`score`): ``fori_loop`` over live ops, each a ``lax.switch``
-over a deliberately minimal 33-opcode table on [N, G] values (scalar
-literals load from a pooled register block, not op slots; boolean and
-sign ops are canonicalized into arithmetic at lowering — see the
+Execution (`score`, the one entry point, batched or not): ``fori_loop``
+over the LIVE op slots — one program's ``n_ops``, or under ``vmap`` the
+longest live program of the batch as one unbatched scalar
+(`_loop_bound`); NOP padding past it never runs. Each slot is a
+``lax.switch`` over a deliberately minimal 33-opcode table on [N, G]
+values (scalar literals load from a pooled register block, not op slots;
+boolean and sign ops are canonicalized into arithmetic at lowering — see the
 CONST_POOL / opcode-table comments below for the vmap rationale). Numeric model: everything runs at the
 AMBIENT float precision — f64 when x64 is on (CPU tests / golden parity,
 where the transpiler also computes floats in f64, matching the reference's
@@ -108,7 +111,8 @@ class VMProgram(NamedTuple):
     c: jax.Array  # i32[O]
     imm: jax.Array  # f32[O] immediate (COL/SETCOL column index)
     consts: jax.Array  # f32[CONST_POOL] pooled scalar literals
-    n_ops: jax.Array  # i32[] live op count (fori bound; padding never runs)
+    n_ops: jax.Array  # i32[] live op count (the op-slot loop's bound is the
+    # largest among the lanes of a batch: `_loop_bound`)
     out_reg: jax.Array  # i32[]
 
     @property
@@ -648,31 +652,53 @@ def _execute(prog: VMProgram, pod: PodView, nodes: NodeView,
     return out.astype(jnp.int32)
 
 
+@jax.custom_batching.custom_vmap
+def _loop_bound(n_ops: jax.Array) -> jax.Array:
+    """Trip count of the op-slot loop: the program's live op count — and,
+    under ``vmap`` over stacked programs, ONE scalar for the whole batch,
+    the longest live program among the lanes that share the loop.
+
+    A *per-lane* ``n_ops`` under ``vmap`` would be a batched loop bound:
+    ``fori_loop`` then lowers to a while_loop whose every iteration
+    selects the full [N_INPUTS+CONST_POOL+cap, N, G] register file per
+    lane to freeze finished lanes — far more HBM traffic than the ops
+    themselves. The bound only has to cover every lane, not be each
+    lane's own: a lane shorter than the longest runs its OP_NOP padding
+    up to the shared bound (each copies register 0 into a fresh register
+    the output never reads), which is semantically free. So the batching
+    rule below reduces the lanes' counts to their maximum and declares
+    the result UNBATCHED: the loop predicate stays a scalar, nothing is
+    selected, and the padding past the longest live program never runs.
+    The maximum is taken on the device from the tables already there, so
+    a new generation or a hot swap changes a value, never a shape. A
+    program mapped with ``in_axes=None`` (serving) never reaches the
+    rule; inside ``shard_map`` the maximum is over the device's own
+    lanes (no collective)."""
+    return n_ops
+
+
+@_loop_bound.def_vmap
+def _loop_bound_lanes(axis_size, in_batched, n_ops):
+    del axis_size, in_batched  # one operand: the rule runs only if batched
+    # through the primitive again: an enclosing vmap that ALSO batches the
+    # programs reduces over its axis the same way
+    return _loop_bound(jnp.max(n_ops)), False
+
+
 def score(prog: VMProgram, pod: PodView, nodes: NodeView) -> jax.Array:
     """Execute a lowered candidate -> i32 scores over the node axis.
 
     The signature matches ``ParamPolicyFn`` with the program as the
     parameter pytree, so every engine runner (plain, population, trace
-    batch, mesh) accepts VM candidates unchanged.
+    batch, mesh, serving) accepts VM candidates unchanged: stack
+    candidates with ``stack_programs`` and pass this as the
+    ``param_policy`` of ``make_population_run_fn``. The op-slot loop runs
+    the LIVE slots only — to ``n_ops`` for one program, to the longest
+    live program of the batch under ``vmap`` (``_loop_bound``) — while
+    shapes and the register file stay at the padded capacity, so one
+    executable serves every program of a capacity bucket.
     """
-    return _execute(prog, pod, nodes, prog.n_ops)
-
-
-def score_static(prog: VMProgram, pod: PodView, nodes: NodeView) -> jax.Array:
-    """`score` with a STATIC trip count (the padded capacity) — the
-    population-batched variant.
-
-    Under ``vmap`` the per-candidate ``n_ops`` is a batched loop bound, so
-    ``fori_loop`` would lower to a while_loop whose every iteration selects
-    the full [N_INPUTS+CONST_POOL+cap, N, G] register file per lane to
-    freeze finished lanes — far more HBM traffic than the ops themselves. Padding slots are
-    OP_NOPs (they copy register 0 into a fresh register the output never
-    reads), so running every lane to the static capacity is semantically
-    free and keeps the loop bound unbatched. Stack candidates with
-    ``stack_programs`` (which right-sizes the shared capacity) and pass this
-    as the ``param_policy`` of ``make_population_run_fn``.
-    """
-    return _execute(prog, pod, nodes, prog.capacity)
+    return _execute(prog, pod, nodes, _loop_bound(prog.n_ops))
 
 
 def capacity_bucket(n_ops: int) -> int:
@@ -736,8 +762,9 @@ def select_slot(stacked: VMProgram, slot) -> VMProgram:
     a single executable answers a batch that MIXES champions: each lane
     reads its own opcode/operand rows out of the resident slot tables.
     The selected program's ``capacity`` stays shape-derived (static under
-    tracing); ``n_ops``/``out_reg`` become traced scalars, which
-    ``score_static`` never uses as loop bounds."""
+    tracing); ``n_ops``/``out_reg`` become per-lane traced scalars, and
+    ``score`` bounds the shared op-slot loop by the longest program any
+    lane of the batch selected (``_loop_bound``)."""
     return jax.tree_util.tree_map(lambda x: x[slot], stacked)
 
 
@@ -756,7 +783,8 @@ def bucket_lanes(n: int, multiple: int = 1) -> int:
     a v5e chip one lane costs 11.5 ms per lockstep event against 1.7 ms
     for two lanes and 2.5 ms for four, with or without ``shard_map``
     (PERF.md, PR 21). A pad lane repeats the last program, so it adds no
-    lockstep events.
+    lockstep events and never raises the op-slot loop's bound
+    (``_loop_bound``: the longest live program among the lanes).
     """
     pop = max(2, 1 << (max(1, n) - 1).bit_length())
     return max(2, -(-pop // multiple)) * multiple

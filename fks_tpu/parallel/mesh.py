@@ -569,7 +569,7 @@ def make_sharded_code_eval(workload: Workload, mesh: Mesh,
     pytree-generic, and forward ``real_count`` so pad duplicates are
     excluded from the elite ranking). Inside ``shard_map`` each device
     interprets its shard of the program batch through the population
-    engine (``vm.score_static`` — one compiled program for the whole VM
+    engine (``vm.score`` — one compiled program for the whole VM
     vocabulary, zero per-candidate XLA compiles), then the fitness vector
     is all-gathered over the pop axes so every device computes the
     identical global top-k. This closes the gap between the parametric
@@ -609,7 +609,7 @@ def make_sharded_code_eval(workload: Workload, mesh: Mesh,
         return _make_segmented_code_eval(workload, mesh, cfg, elite_k, mod,
                                          seg_steps, on_segment, spec)
 
-    run = mod.make_population_run_fn(workload, vm.score_static, cfg)
+    run = mod.make_population_run_fn(workload, vm.score, cfg)
     state0 = mod.initial_state(workload, cfg)
     axes = _pop_axes(mesh)
 
@@ -659,7 +659,7 @@ def _make_segmented_code_eval(workload: Workload, mesh: Mesh, cfg: SimConfig,
 
     def step_one(prog, s):
         return mod.build_step(
-            workload, lambda pod, nodes: vm.score_static(prog, pod, nodes),
+            workload, lambda pod, nodes: vm.score(prog, pod, nodes),
             cfg, ktable, max_steps)(s)
 
     vstep = jax.vmap(step_one, in_axes=(0, 0))

@@ -26,7 +26,7 @@ from __future__ import annotations
 import dataclasses
 import time
 import warnings
-from typing import List, Optional, Sequence
+from typing import Dict, List, Optional, Sequence
 
 import jax
 import numpy as np
@@ -79,6 +79,7 @@ class PortfolioEngine(VMServeEngine):
         self.last_slot_swapped: Optional[int] = None
         self._batch_slots: Optional[List[int]] = None
         self._pending_slots_dev = None
+        self._pending_slots: List[int] = [0]
         super().__init__(champions[0], workload,
                          program_capacity=program_capacity, **kw)
         # the parent uploaded slot 0 alone; replace with the full table
@@ -90,7 +91,7 @@ class PortfolioEngine(VMServeEngine):
         """Lower EVERY pending champion, size the shared capacity bucket
         to the longest member, pad all to it, seed the transpile cache
         (re-swapping any construction champion is a warm swap). The
-        parent contract (score_static, slot-0 program, "vm") holds."""
+        parent contract (``vm.score``, slot-0 program, "vm") holds."""
         champs = self._pending_portfolio
         raw = [vm.compile_policy(c.code, n, g) for c in champs]
         cap = self._capacity_override or max(
@@ -103,7 +104,7 @@ class PortfolioEngine(VMServeEngine):
         spare = self.n_slots - len(champs)
         self._slot_champions = list(champs) + [champs[0]] * spare
         self._slot_progs = list(progs) + [progs[0]] * spare
-        return vm.score_static, progs[0], "vm"
+        return vm.score, progs[0], "vm"
 
     @property
     def slot_champions(self) -> List[ChampionSpec]:
@@ -205,7 +206,7 @@ class PortfolioEngine(VMServeEngine):
             prog = vm.select_slot(stacked, slot)
             w = Workload(cluster=cluster, pods=p, faults=None)
             return mod.build_step(
-                w, lambda pod, nodes: vm.score_static(prog, pod, nodes),
+                w, lambda pod, nodes: vm.score(prog, pod, nodes),
                 cfg, k, max_steps)(s)
 
         vstep = jax.vmap(step_one, in_axes=(None, 0, 0, 0, 0))
@@ -307,7 +308,15 @@ class PortfolioEngine(VMServeEngine):
         padded = np.asarray(chunk + [chunk[-1]] * (lanes - len(chunk)),
                             np.int32)
         self._pending_slots_dev = self._lane_put(padded)
+        self._pending_slots = chunk
         return super()._dispatch_chunk(bucket, idxs, pod_lists)
+
+    def _loop_fields(self) -> Dict[str, int]:
+        """The longest live program any lane of the chunk selected: what
+        ``vm._loop_bound`` reduces to under the per-lane slot gather."""
+        return {"slots": max(int(self._slot_progs[s].n_ops)
+                             for s in self._pending_slots),
+                "capacity": int(self.program_capacity)}
 
     def _invoke(self, compiled, pods, kt_dev, s0):
         return compiled(self._prog_dev, self._pending_slots_dev,

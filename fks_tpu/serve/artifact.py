@@ -404,7 +404,7 @@ class ServeEngine:
 
         try:
             prog = vm.compile_policy(code, n, g)
-            return vm.score_static, prog, "vm"
+            return vm.score, prog, "vm"
         except vm.VMUnsupported:
             policy = transpiler.transpile(code)
             return (lambda _p, pod, nodes: policy(pod, nodes)), None, "jit"
@@ -722,7 +722,8 @@ class ServeEngine:
         # async dispatch; per-batch buffers donated. _invoke is the
         # engine-kind seam: the AOT engine calls the executable directly,
         # the VM engine prepends its device-resident champion tables.
-        with obs.span("serve/chunk/enqueue", chunk=chunk) as t_enq:
+        with obs.span("serve/chunk/enqueue", chunk=chunk,
+                      **self._loop_fields()) as t_enq:
             compiled = self.compiled_for(lanes, bucket)
             res = self._invoke(compiled, pods, kt_dev, s0)
         self.last_batch_timing["pack_h2d_s"] += t_enq.t1 - t_stack.t0
@@ -732,6 +733,15 @@ class ServeEngine:
 
     def _invoke(self, compiled, pods, kt_dev, s0):
         return compiled(pods, kt_dev, s0)
+
+    def _loop_fields(self) -> Dict[str, int]:
+        """``slots`` / ``capacity`` of the chunk being enqueued: how far
+        the VM's op-slot loop runs (``vm._loop_bound``) and the padded
+        bucket it runs in. Empty for a champion on the jit tier."""
+        if self.policy_tier != "vm":
+            return {}
+        return {"slots": int(self.params.n_ops),
+                "capacity": int(self.params.capacity)}
 
     @property
     def last_lanes_per_device(self) -> Dict[int, int]:

@@ -108,7 +108,7 @@ class VMServeEngine(ServeEngine):
     # ----- champion lowering / residency
 
     def _resolve_policy(self, code: str, n: int, g: int):
-        """Champion source -> (score_static, padded VMProgram, "vm").
+        """Champion source -> (``vm.score``, padded VMProgram, "vm").
         No jit fallback here — a champion outside the VM vocabulary
         raises ``VMUnsupported`` to the caller, who serves it on the AOT
         closure engine instead."""
@@ -120,7 +120,7 @@ class VMServeEngine(ServeEngine):
         # champion (rollback after a failed promotion) is a warm swap
         with self._transpile_lock:
             self._transpile_cache[self._code_key(code, n, g, cap)] = prog
-        return vm.score_static, prog, "vm"
+        return vm.score, prog, "vm"
 
     @staticmethod
     def _code_key(code: str, n: int, g: int, cap: int) -> tuple:
@@ -279,7 +279,7 @@ class VMServeEngine(ServeEngine):
         def step_one(prog, p, k, s):
             w = Workload(cluster=cluster, pods=p, faults=None)
             return mod.build_step(
-                w, lambda pod, nodes: vm.score_static(prog, pod, nodes),
+                w, lambda pod, nodes: vm.score(prog, pod, nodes),
                 cfg, k, max_steps)(s)
 
         vstep = jax.vmap(step_one, in_axes=(None, 0, 0, 0))

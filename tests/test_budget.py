@@ -181,10 +181,10 @@ def test_flat_segmented_runner_uses_helper():
 
     wl = micro_workload()
     with pytest.raises(ValueError, match="make_population_run_fn"):
-        flat.make_segmented_population_run(wl, vm.score_static, SimConfig(),
+        flat.make_segmented_population_run(wl, vm.score, SimConfig(),
                                            seg_steps=0)
     with pytest.raises(ValueError, match="must be an integer"):
-        flat.make_segmented_population_run(wl, vm.score_static, SimConfig(),
+        flat.make_segmented_population_run(wl, vm.score, SimConfig(),
                                            seg_steps="junk")
 
 
@@ -287,7 +287,7 @@ def test_budgeted_suite_eval_direct():
 
     from fks_tpu.scenarios.robust import make_suite_eval
     suite = get_suite("smoke3", wl)
-    full_ev = make_suite_eval(suite, vm.score_static, cfg,
+    full_ev = make_suite_eval(suite, vm.score, cfg,
                               population=True, engine="exact")
     ladder = BudgetedSuiteEval(
         wl, cfg, budget, robust,

@@ -592,3 +592,52 @@ def test_transpile_overlap_rides_fleet_promotion(tmp_path, wl, envelope,
     assert swaps[0]["transpile_cache"] == "miss"
     assert swaps[1]["transpile_cache"] == "hit"
     assert swaps[1]["transpile_overlapped"] is True
+
+
+# ------------------------------------- the op-slot loop under slot dispatch
+
+
+def test_mixed_slot_batch_runs_to_the_longest_selected_program(
+        wl, envelope, monkeypatch):
+    """Per-lane slot dispatch gathers a per-lane ``n_ops``; the shared
+    op-slot loop runs to the longest program any lane of the batch
+    SELECTED (never past the table's longest, and never the padded
+    capacity), its predicate stays a scalar, and the ``enqueue`` span
+    says the same number."""
+    import jax
+
+    from tests.test_vm_batch import (
+        _assert_unbatched_op_slot_loop, _champion_code,
+        _count_slot_iterations,
+    )
+
+    long_ = ChampionSpec(code=_champion_code(), score=0.9, source="<ledger>")
+    eng = PortfolioEngine([_champ(SEED_LOGIC, 0.4, "<c0>"), long_], wl,
+                          envelope=envelope, engine="flat", n_slots=3)
+    n_short, n_long = (int(p.n_ops) for p in eng._slot_progs[:2])
+    assert n_short < n_long == 370 < eng.program_capacity == 512
+    lanes, bucket = 2, 8
+    fn = eng._make_serve_fn(bucket)
+    batch = eng._example_batch(lanes, bucket)
+
+    def run(slots):
+        return fn(eng._prog_dev, slots, *batch)
+
+    _assert_unbatched_op_slot_loop(
+        jax.make_jaxpr(run)(np.asarray([0, 1], np.int32)),
+        eng.program_capacity)
+    for slots, longest in (([0, 1], n_long), ([0, 0], n_short),
+                           ([2, 0], n_short)):   # slot 2: a spare, = slot 0
+        res, fired = _count_slot_iterations(
+            monkeypatch, run, np.asarray(slots, np.int32))
+        monkeypatch.undo()
+        events = int(np.max(np.asarray(res.events_processed)))
+        assert events > 0 and fired == longest * events, (slots, fired)
+    # the host's copy of the same number, on the span the benchmark reads
+    q = [_query(eng.base_pods, 0), _query(eng.base_pods, 1)]
+    for slots, longest in (([0, 1], n_long), ([0, 0], n_short)):
+        eng.answer_batch(q, slots=slots)
+        got = [(r.fields["slots"], r.fields["capacity"])
+               for r in eng.last_batch_spans
+               if r.name == "serve/chunk/enqueue"]
+        assert got and set(got) == {(longest, 512)}

@@ -295,7 +295,7 @@ def test_four_device_generation_leaves_the_mesh_spans(micro_workload,
     """The sharded segmented runner (what a TPU host picks): shard_put,
     one span per segment with the host's wait for the device as its child,
     finish; all inside the tier's launch span."""
-    from fks_tpu.funsearch import backend
+    from fks_tpu.funsearch import backend, vm
     from fks_tpu.parallel import population_mesh
 
     monkeypatch.setenv("FKS_VM_SEG_STEPS", "8")
@@ -308,7 +308,14 @@ def test_four_device_generation_leaves_the_mesh_spans(micro_workload,
     got = _since(mark)
     assert all(r.ok for r in recs)
     launch = next(r for r in got if r.name == "tier/vm_batch/launch")
-    assert launch.fields == {"lanes": 8, "shards": 4}
+    # slots / capacity: the op-slot loop runs to the longest live program
+    # of the generation, in the stack's power-of-two bucket
+    c = micro_workload.cluster
+    longest = max(int(vm.compile_policy(code, c.n_padded, c.g_padded).n_ops)
+                  for code in _codes())
+    assert launch.fields == {"lanes": 8, "shards": 4, "slots": longest,
+                             "capacity": vm.capacity_bucket(longest)}
+    assert longest < launch.fields["capacity"]
     mesh_spans = [r for r in got if r.name.startswith("mesh/")]
     top = sorted((r for r in mesh_spans if r.parent_id == launch.span_id),
                  key=lambda r: r.t0)

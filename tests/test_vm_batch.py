@@ -14,8 +14,8 @@ from tests.test_vm import _corpus, _rand_views, G, N
 
 
 def test_pad_capacity_is_semantically_neutral():
-    """NOP padding never changes scores: score_static over the padded
-    capacity equals score over the live op count."""
+    """NOP padding never changes scores: a program re-padded to twice
+    its capacity scores what it scored before."""
     rng = np.random.default_rng(11)
     code = list(template.seed_policies().values())[0]
     prog = vm.compile_policy(code, N, G)
@@ -25,7 +25,7 @@ def test_pad_capacity_is_semantically_neutral():
         pod, nodes = _rand_views(rng)
         np.testing.assert_array_equal(
             np.asarray(vm.score(prog, pod, nodes)),
-            np.asarray(vm.score_static(padded, pod, nodes)))
+            np.asarray(vm.score(padded, pod, nodes)))
 
 
 def test_stack_programs_shapes_and_bucket():
@@ -51,14 +51,14 @@ def test_bucket_lanes_never_builds_the_batch_of_one_program():
 
 
 def test_stacked_scores_match_per_candidate():
-    """vmapped score_static over a stacked generation == per-candidate
-    score, integer-exact."""
+    """vmapped score over a stacked generation == per-candidate score,
+    integer-exact."""
     rng = np.random.default_rng(5)
     codes = _corpus()[:6]
     progs = [vm.compile_policy(c, N, G) for c in codes]
     stacked = vm.stack_programs(progs)
     pod, nodes = _rand_views(rng)
-    batched = jax.jit(jax.vmap(vm.score_static, in_axes=(0, None, None)))
+    batched = jax.jit(jax.vmap(vm.score, in_axes=(0, None, None)))
     got = np.asarray(batched(stacked, pod, nodes))
     for i, prog in enumerate(progs):
         np.testing.assert_array_equal(
@@ -164,7 +164,7 @@ def test_sharded_code_eval_matches_single_device(micro_workload, seg_steps):
     ev = make_sharded_code_eval(wl, mesh, cfg=cfg, elite_k=3,
                                 engine="flat", seg_steps=seg_steps)
     res, elite_idx, elite_scores = ev(padded, real)
-    ref = flat.make_population_run_fn(wl, vm.score_static, cfg)(
+    ref = flat.make_population_run_fn(wl, vm.score, cfg)(
         stacked, flat.initial_state(wl, cfg))
     got = np.asarray(res.policy_score)[:real]
     want = np.asarray(ref.policy_score)
@@ -214,3 +214,253 @@ def test_segmented_batch_tier_matches_unsegmented(micro_workload, monkeypatch):
     assert seg.vm_batch_count == 1 and mono.vm_batch_count == 1
     for ra, rb in zip(a, b):
         assert ra.score == rb.score and ra.ok == rb.ok
+
+
+# ------------------------------------------- the op-slot loop's trip count
+#
+# vm.score bounds the op-slot loop by the longest LIVE program among the
+# lanes that share it (vm._loop_bound): one unbatched scalar, so nothing
+# is selected per lane and NOP padding past it never runs.
+
+CAP = 512
+
+
+def _champion_code():
+    """The pinned ledger champion (score 0.5365): 370 live ops, like all
+    13 ledger champions."""
+    import glob
+    import json
+    import os
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    path = sorted(glob.glob(os.path.join(
+        root, "policies", "discovered", "funsearch_*score0.5365.json")))[0]
+    with open(path) as f:
+        return json.load(f)["code"]
+
+
+def _mix_codes(mix):
+    seeds = template.seed_policies()
+    by = {"ff": seeds["first_fit"], "bf": seeds["best_fit"],
+          "champ": _champion_code()}
+    return [by[k] for k in mix]
+
+
+def test_ledger_champions_leave_a_quarter_of_the_bucket_empty():
+    """What the benchmark's VM cells run: a 370-op champion in the 512
+    bucket; the seed policies are shorter still."""
+    progs = [vm.compile_policy(c, N, G, capacity=CAP)
+             for c in _mix_codes(["ff", "bf", "champ"])]
+    assert int(progs[2].n_ops) == 370
+    assert vm.capacity_bucket(370) == CAP
+    assert int(progs[0].n_ops) < int(progs[1].n_ops) < 370
+
+
+def _count_slot_iterations(monkeypatch, fn, *args):
+    """Run ``fn`` counting the op-slot loop's iterations: a debug
+    callback with no operands beside ``lax.switch`` (the one call of the
+    loop body) is unbatched, so it fires once per slot, not per lane."""
+    from jax import lax
+
+    fired = []
+    real = lax.switch
+
+    def counting(index, branches, *operands):
+        jax.debug.callback(lambda: fired.append(1))
+        return real(index, branches, *operands)
+
+    monkeypatch.setattr(vm.lax, "switch", counting)
+    out = jax.block_until_ready(fn(*args))
+    jax.effects_barrier()
+    return out, len(fired)
+
+
+@pytest.mark.parametrize("mix,lanes", [
+    (["ff", "champ"], 4),           # short + champion + two pad lanes
+    (["ff", "bf"], 2),              # no champion: the bound is best_fit's
+    (["champ", "ff", "bf"], 8),
+])
+def test_trip_count_is_the_longest_live_program(monkeypatch, mix, lanes):
+    """A stack whose longest program has k live ops at capacity 512 runs
+    k slot iterations (pad lanes repeat the last program and never raise
+    the bound), and every lane scores what its program scores alone."""
+    rng = np.random.default_rng(21)
+    progs = [vm.compile_policy(c, N, G, capacity=CAP)
+             for c in _mix_codes(mix)]
+    stacked = vm.stack_programs(progs + [progs[-1]] * (lanes - len(progs)),
+                                capacity=CAP)
+    assert stacked.opcode.shape == (lanes, CAP)
+    pod, nodes = _rand_views(rng)
+    batched = jax.vmap(vm.score, in_axes=(0, None, None))
+    got, slots = _count_slot_iterations(monkeypatch, batched, stacked, pod,
+                                        nodes)
+    assert slots == max(int(p.n_ops) for p in progs) < CAP
+    monkeypatch.undo()
+    for i, prog in enumerate(progs):
+        np.testing.assert_array_equal(
+            np.asarray(got[i]), np.asarray(vm.score(prog, pod, nodes)))
+    # one program alone runs its own live ops
+    _, alone = _count_slot_iterations(monkeypatch, vm.score, progs[0], pod,
+                                      nodes)
+    assert alone == int(progs[0].n_ops)
+
+
+def _sub_jaxprs(eqn):
+    for v in eqn.params.values():
+        for x in (v if isinstance(v, (list, tuple)) else (v,)):
+            if hasattr(x, "eqns"):
+                yield x
+            elif hasattr(x, "jaxpr") and hasattr(x.jaxpr, "eqns"):
+                yield x.jaxpr
+
+
+def _op_slot_loops(jaxpr, regs):
+    """Every ``while`` of the jaxpr tree that carries a register file
+    ([..., regs, N, G])."""
+    found = []
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "while" and any(
+                len(v.aval.shape) >= 3 and v.aval.shape[-3] == regs
+                for v in eqn.outvars):
+            found.append(eqn)
+        for sub in _sub_jaxprs(eqn):
+            found += _op_slot_loops(sub, regs)
+    return found
+
+
+def _assert_unbatched_op_slot_loop(closed_jaxpr, capacity):
+    """The thing a PER-LANE bound under vmap would cost: a batched
+    predicate (reduced over the lanes to drive the loop) and a
+    ``select_n`` of the whole register file per slot to freeze finished
+    lanes. Neither may be there."""
+    regs = vm.N_INPUTS + vm.CONST_POOL + capacity
+    loops = _op_slot_loops(closed_jaxpr.jaxpr, regs)
+    assert loops, "no op-slot loop in the program"
+    for eqn in loops:
+        cond = eqn.params["cond_jaxpr"].jaxpr
+        for c in cond.eqns:
+            assert all(v.aval.shape == () for v in c.invars + c.outvars), c
+        for b in eqn.params["body_jaxpr"].jaxpr.eqns:
+            if b.primitive.name == "select_n":
+                shape = b.outvars[0].aval.shape
+                assert not (len(shape) >= 3 and shape[-3] == regs), b
+    return len(loops)
+
+
+def _lowered_batched_paths(wl):
+    """name -> (closed jaxpr, capacity) of every batched runner's device
+    program, traced on a short + long stack."""
+    from fks_tpu.parallel import (
+        make_sharded_code_eval, pad_population, population_mesh,
+    )
+    from fks_tpu.scenarios import get_suite
+    from fks_tpu.scenarios.robust import make_suite_eval
+    from fks_tpu.sim import engine as exact, flat
+    from fks_tpu.sim.engine import SimConfig
+
+    c = wl.cluster
+    progs = [vm.compile_policy(code, c.n_padded, c.g_padded, capacity=CAP)
+             for code in _mix_codes(["ff", "champ"])]
+    stacked = vm.stack_programs(progs, capacity=CAP)
+    cfg = SimConfig()
+    out = {}
+    for name, mod in (("flat", flat), ("exact", exact)):
+        run = mod.make_population_run_fn(wl, vm.score, cfg)
+        out[f"population/{name}"] = jax.make_jaxpr(run)(
+            stacked, mod.initial_state(wl, cfg))
+    seg = flat.make_segmented_population_run(wl, vm.score, cfg, seg_steps=3)
+    out["segmented/flat"] = jax.make_jaxpr(seg.advance)(
+        stacked, flat.broadcast_state(flat.initial_state(wl, cfg), 2))
+    mesh = population_mesh(jax.devices()[:4])
+    padded, real = pad_population(
+        vm.stack_programs(progs * 4, capacity=CAP), mesh)
+    ev = make_sharded_code_eval(wl, mesh, cfg=cfg, elite_k=1, engine="flat")
+    out["mesh/flat"] = jax.make_jaxpr(lambda st: ev(st, real))(padded)
+    # candidates x scenarios: the programs ride the OUTER vmap only
+    suite = make_suite_eval(get_suite("smoke3", wl), vm.score, cfg,
+                            population=True, jit=False, engine="exact")
+    out["suite/exact"] = jax.make_jaxpr(suite)(stacked)
+    return {k: (v, CAP) for k, v in out.items()}
+
+
+def test_no_batched_path_selects_the_register_file(micro_workload):
+    for name, (jaxpr, cap) in _lowered_batched_paths(micro_workload).items():
+        assert _assert_unbatched_op_slot_loop(jaxpr, cap) >= 1, name
+
+
+def test_a_per_lane_bound_would_be_caught():
+    """The check above is not vacuous: the loop it must never see (each
+    lane bounded by its own ``n_ops``) trips it."""
+    rng = np.random.default_rng(2)
+    progs = [vm.compile_policy(c, N, G, capacity=256)
+             for c in _mix_codes(["ff", "bf"])]
+    stacked = vm.stack_programs(progs, capacity=256)
+    pod, nodes = _rand_views(rng)
+    per_lane = jax.vmap(
+        lambda p, pod, nodes: vm._execute(p, pod, nodes, p.n_ops),
+        in_axes=(0, None, None))
+    with pytest.raises(AssertionError):
+        _assert_unbatched_op_slot_loop(
+            jax.make_jaxpr(per_lane)(stacked, pod, nodes), 256)
+    shared = jax.vmap(vm.score, in_axes=(0, None, None))
+    assert _assert_unbatched_op_slot_loop(
+        jax.make_jaxpr(shared)(stacked, pod, nodes), 256) == 1
+
+
+@pytest.mark.parametrize("engine,devices,lanes,seg_steps,mix", [
+    ("flat", 1, 4, 0, ["ff", "champ"]),
+    ("exact", 1, 4, 0, ["ff", "champ"]),
+    ("flat", 1, 2, 2, ["champ", "bf"]),
+    ("flat", 4, 8, 0, ["ff", "champ", "bf", "champ", "ff"]),
+    ("exact", 4, 8, 0, ["champ", "ff", "bf"]),
+    ("flat", 4, 8, 2, ["ff", "bf", "champ"]),
+])
+def test_mixed_length_stack_matches_each_program_alone(
+        micro_workload, engine, devices, lanes, seg_steps, mix):
+    """A stack of short and long programs plus pad lanes, capacity 512,
+    through the population runner (one device) or the sharded code eval
+    (four virtual devices): every lane's result is bit for bit what its
+    program gives alone through ``vm.score`` and what the transpiled jit
+    policy gives."""
+    from fks_tpu.funsearch import transpiler
+    from fks_tpu.parallel import make_sharded_code_eval, population_mesh
+    from fks_tpu.sim import get_engine
+    from fks_tpu.sim.engine import SimConfig
+
+    wl = micro_workload
+    c = wl.cluster
+    mod = get_engine(engine)
+    cfg = SimConfig()
+    codes = _mix_codes(mix)
+    progs = [vm.compile_policy(code, c.n_padded, c.g_padded, capacity=CAP)
+             for code in codes]
+    stacked = vm.stack_programs(progs + [progs[-1]] * (lanes - len(progs)),
+                                capacity=CAP)
+    s0 = mod.initial_state(wl, cfg)
+    if devices > 1:
+        mesh = population_mesh(jax.devices()[:devices])
+        ev = make_sharded_code_eval(wl, mesh, cfg=cfg, elite_k=1,
+                                    engine=engine, seg_steps=seg_steps)
+        res, _, _ = ev(stacked, len(progs))
+    elif seg_steps:
+        res = mod.make_segmented_population_run(
+            wl, vm.score, cfg, seg_steps=seg_steps)(stacked, s0)
+    else:
+        res = jax.jit(mod.make_population_run_fn(wl, vm.score, cfg))(
+            stacked, s0)
+    res = jax.device_get(res)
+    alone = jax.jit(mod.make_param_run_fn(wl, vm.score, cfg))
+    seen = {}
+    for i, (code, prog) in enumerate(zip(codes, progs)):
+        if code not in seen:
+            seen[code] = (
+                jax.device_get(alone(prog, s0)),
+                jax.device_get(jax.jit(mod.make_run_fn(
+                    wl, transpiler.transpile(code), cfg))(s0)))
+        for want in seen[code]:
+            for field in ("assigned_node", "assigned_gpus", "policy_score",
+                          "scheduled_pods", "events_processed", "failed",
+                          "truncated"):
+                np.testing.assert_array_equal(
+                    np.asarray(getattr(res, field))[i],
+                    np.asarray(getattr(want, field)), err_msg=f"{i} {field}")

@@ -240,6 +240,89 @@ def test_swap_program_returns_rollback_handle(wl, envelope):
     assert rolled[0]["placements"] == before[0]["placements"]
 
 
+def _ledger_champion(score=0.9):
+    """The pinned ledger champion: 370 live ops, the 512 bucket."""
+    from tests.test_vm_batch import _champion_code
+
+    return ChampionSpec(code=_champion_code(), score=score,
+                        source="<ledger>")
+
+
+def _enqueue_fields(eng):
+    return [(r.fields["slots"], r.fields["capacity"])
+            for r in eng.last_batch_spans if r.name == "serve/chunk/enqueue"]
+
+
+def test_swap_between_lengths_in_one_bucket_compiles_nothing(wl, envelope):
+    """The op-slot loop's bound is data, not shape: two champions of
+    different live lengths in ONE capacity bucket share the executables,
+    each swap is a table upload (zero XLA compiles), the ``enqueue`` span
+    says how far the loop runs, and every answer equals the unbatched
+    reference's."""
+    short, long_ = _champ(SEED_LOGIC, 0.4, "<short>"), _ledger_champion()
+    eng = VMServeEngine(short, wl, envelope=envelope, engine="flat",
+                        program_capacity=512)
+    eng.warmup()
+    n_short = int(eng.params.n_ops)
+    queries = [_query(3), _query(9, 5)]
+    eng.answer_batch(queries)  # the eager stacking ops of this batch shape
+    # compile_policy's own eager dtype cast compiles once per capacity it
+    # lowers AT (the short source lowered at 256 and was padded): not the
+    # swap's, and not the serve executables'
+    c = wl.cluster
+    vm.compile_policy(long_.code, c.n_padded, c.g_padded)
+    watcher = CompileWatcher().install()
+    try:
+        a_short = eng.answer_batch(queries)
+        f_short = _enqueue_fields(eng)
+        eng.swap_program(long_)
+        a_long = eng.answer_batch(queries)
+        f_long = _enqueue_fields(eng)
+        eng.swap_program(short)
+        a_back = eng.answer_batch(queries)
+        compiles = watcher.backend_compile_count
+    finally:
+        watcher.uninstall()
+    assert compiles == 0, f"{compiles} programs compiled across the swaps"
+    assert n_short < 370 <= eng.program_capacity == 512
+    assert f_short and set(f_short) == {(n_short, 512)}
+    assert f_long and set(f_long) == {(370, 512)}
+    for q, a, b in zip(queries, a_short, a_back):
+        ref = eng.reference_answer(q)
+        assert a["score"] == b["score"]
+        assert abs(a["score"] - ref["score"]) <= 1e-5
+        assert a["placements"] == b["placements"] == ref["placements"]
+    eng.swap_program(long_)
+    for q, a in zip(queries, a_long):
+        ref = eng.reference_answer(q)
+        assert abs(a["score"] - ref["score"]) <= 1e-5
+        assert a["placements"] == ref["placements"]
+        assert a["scheduled"] == ref["scheduled"]
+
+
+def test_serve_program_loops_over_the_champions_live_slots(
+        vm_engine, monkeypatch):
+    """Serving maps ONE program over the lanes (``in_axes=None``): the
+    op-slot loop's bound is the resident champion's ``n_ops``, read from
+    the uploaded tables: a scalar predicate, nothing selected, and as
+    many slot iterations per lockstep event as the champion has ops."""
+    from tests.test_vm_batch import (
+        _assert_unbatched_op_slot_loop, _count_slot_iterations,
+    )
+
+    eng = vm_engine
+    fn = eng._make_serve_fn(8)
+    batch = eng._example_batch(2, 8)
+    _assert_unbatched_op_slot_loop(
+        jax.make_jaxpr(fn)(eng._prog_dev, *batch), eng.program_capacity)
+    res, fired = _count_slot_iterations(monkeypatch, fn, eng._prog_dev,
+                                        *batch)
+    events = int(np.max(np.asarray(res.events_processed)))
+    live = int(eng.params.n_ops)
+    assert live < eng.program_capacity
+    assert events > 0 and fired == live * events
+
+
 def test_transpile_cache_makes_reswap_warm(wl, envelope):
     """Host-side transpile cache (ISSUE-18): re-promoting a champion the
     engine already lowered must skip ``compile_policy`` entirely — the
