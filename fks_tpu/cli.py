@@ -1871,7 +1871,8 @@ def main(argv=None) -> int:
                          "replay traffic)")
     sv.add_argument("--prefilter-k", type=int, default=None,
                     help="SimConfig.node_prefilter_k override (default: "
-                         "auto via the policy-cost probe)")
+                         "the cluster-shape rule for a VM champion, the "
+                         "policy-cost probe for an AOT one)")
     sv.add_argument("--state-pack", action="store_true",
                     help="SimConfig.state_pack for the serving engine; "
                          "also engages the 16-bit packed query-upload "

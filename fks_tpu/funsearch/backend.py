@@ -45,7 +45,7 @@ import dataclasses as _dc
 from fks_tpu import obs
 from fks_tpu.data.entities import Workload
 from fks_tpu.funsearch import transpiler, vm
-from fks_tpu.sim.engine import SimConfig
+from fks_tpu.sim.engine import SimConfig, shape_prefilter_k
 from fks_tpu.sim.types import SimResult
 from fks_tpu.utils.segments import validate_seg_steps
 
@@ -99,6 +99,22 @@ class CodeEvaluator:
         # NULL_PROFILER keeps every stage a no-op with no fences.
         self.profiler = (profiler if profiler is not None
                          else obs.NULL_PROFILER)
+        # The large-cluster rule, chosen from the cluster's SHAPE and for
+        # every tier at once (batched VM, unbatched VM, per-AST jit, the
+        # thread-pool fallback, suite and budget rungs: all read
+        # ``self.cfg``; the exact re-rank and the watchdog's reference
+        # copy it), so a fitness does not depend on which tier answered.
+        # A caller's non-zero value wins. 0 is the field's default and
+        # reads as "not set": on a large cluster the dense sweep is asked
+        # for as ``node_prefilter_k=n_padded``. ``prefilter_derived`` says
+        # that the rule, not the caller, chose. The fused kernel has no
+        # prefilter path and keeps what it was given.
+        self.prefilter_derived = False
+        if engine != "fused":
+            k = shape_prefilter_k(workload.cluster.n_padded,
+                                  cfg.node_prefilter_k or None)
+            self.prefilter_derived = k != cfg.node_prefilter_k
+            cfg = _dc.replace(cfg, node_prefilter_k=k)
         self.cfg = cfg
         self.engine = engine
         self._mod = get_engine(engine)
@@ -364,10 +380,20 @@ class CodeEvaluator:
         # d2h is the one transfer and nothing else
         # slots / capacity: how far the op-slot loop runs (vm._loop_bound:
         # the longest live program; the slowest shard's when sharded)
+        # nodes / view / register_bytes: the node axis, the width of it the
+        # policy sees (the prefilter's k, or every node) and the register
+        # file one device carries through the op-slot loop, which is what
+        # each slot's update copies
+        c = self.workload.cluster
+        capacity = int(stacked.opcode.shape[-1])
+        view = self.cfg.resolve_prefilter_k(c.n_padded) or c.n_padded
         with obs.span("tier/vm_batch/launch", lanes=pop,
                       shards=self._n_shards,
                       slots=max(int(p.n_ops) for p in progs),
-                      capacity=int(stacked.opcode.shape[-1])):
+                      capacity=capacity, nodes=c.n_padded, view=view,
+                      register_bytes=(pop // self._n_shards)
+                      * vm.register_rows(capacity) * view * c.g_padded
+                      * stacked.imm.dtype.itemsize):
             if self._n_shards > 1 and self.suite is None:
                 # each device interprets pop/shards lanes; the elite
                 # outputs are discarded here (the evolution loop ranks on
@@ -735,6 +761,9 @@ class CodeEvaluator:
             "vm_batch_lanes": batch_served,
             "fallback_lanes": len(jit_only) + len(general),
             "segments": self.segments_dispatched - seg0,
+            # the large-cluster rule in effect (0 = every node is scored)
+            "prefilter_k": self.cfg.resolve_prefilter_k(c.n_padded),
+            "prefilter_derived": self.prefilter_derived,
             "budget_pruned": sum(r["entered"] - r["survived"]
                                  for r in self.last_budget_stats),
             # fraction of the batch's unique candidates served by the

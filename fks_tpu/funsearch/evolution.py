@@ -1024,6 +1024,12 @@ def run(workload, config: Optional[EvolutionConfig] = None,
                                      mesh=mesh),
                        config, backend, log,
                        on_generation=on_generation, recorder=recorder)
+    if fs.evaluator.prefilter_derived:
+        c = workload.cluster
+        log(f"note: {c.n_padded} padded nodes: candidates score the first "
+            f"{fs.evaluator.cfg.node_prefilter_k} feasible nodes in node "
+            "order, not every node (sim.engine.shape_prefilter_k); pass "
+            f"SimConfig(node_prefilter_k={c.n_padded}) for the dense sweep")
     if checkpoint_path and os.path.exists(checkpoint_path):
         fs.restore(checkpoint_path)
         log(f"resumed from {checkpoint_path} at generation {fs.generation}")

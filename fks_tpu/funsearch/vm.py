@@ -701,6 +701,12 @@ def score(prog: VMProgram, pod: PodView, nodes: NodeView) -> jax.Array:
     return _execute(prog, pod, nodes, _loop_bound(prog.n_ops))
 
 
+def register_rows(capacity: int) -> int:
+    """Rows of the [rows, N, G] register file ``_execute`` carries through
+    the op-slot loop: inputs, the constant pool, one row per op slot."""
+    return N_INPUTS + CONST_POOL + int(capacity)
+
+
 def capacity_bucket(n_ops: int) -> int:
     """Program-capacity bucket for ``n_ops`` live ops: the smallest power
     of two covering it, floored at 64 (``compile_policy``'s own default

@@ -314,7 +314,8 @@ class ServeEngine:
     ``engine`` picks the simulation module ("exact" serves reference
     semantics and is the parity default; "flat" trades the documented
     retry-rule divergence for throughput). ``prefilter_k=None`` engages
-    the auto-enable heuristic (``sim.engine.resolve_auto_prefilter``).
+    the auto-enable heuristic (``sim.engine.resolve_auto_prefilter``; the
+    VM engine takes the shape rule, ``sim.engine.shape_prefilter_k``).
     """
 
     #: how this engine binds the champion: "aot" bakes the policy into
@@ -388,10 +389,19 @@ class ServeEngine:
         n, g = self.cluster.n_padded, self.cluster.g_padded
         self.param_policy, self.params, self.policy_tier = \
             self._resolve_policy(champion.code, n, g)
-        self.prefilter_k = resolve_auto_prefilter(
+        self.prefilter_k = self._resolve_prefilter(prefilter_k, n, g)
+
+    def _resolve_prefilter(self, override: Optional[int], n: int,
+                           g: int) -> int:
+        """``SimConfig.node_prefilter_k`` for every bucket: the timing
+        probe's answer (``resolve_auto_prefilter``) unless overridden.
+        A baked-in champion may be a handful of fused ops or a long
+        program, so its cost is measured; retiring the probe is ROADMAP
+        D4's."""
+        return resolve_auto_prefilter(
             self.param_policy, self.params, n, g,
-            override=prefilter_k, recorder=self.recorder,
-            work_hint=self._static_work_hint(champion.code, g))
+            override=override, recorder=self.recorder,
+            work_hint=self._static_work_hint(self.champion.code, g))
 
     @staticmethod
     def _resolve_policy(code: str, n: int, g: int):

@@ -50,7 +50,7 @@ from fks_tpu.serve.batcher import (
     pack_program_tables, tree_h2d_bytes, unpack_program_tables,
     unpack_query_tables,
 )
-from fks_tpu.sim.engine import run_batched_lanes
+from fks_tpu.sim.engine import run_batched_lanes, shape_prefilter_k
 
 
 class VMServeEngine(ServeEngine):
@@ -121,6 +121,13 @@ class VMServeEngine(ServeEngine):
         with self._transpile_lock:
             self._transpile_cache[self._code_key(code, n, g, cap)] = prog
         return vm.score, prog, "vm"
+
+    def _resolve_prefilter(self, override: Optional[int], n: int,
+                           g: int) -> int:
+        """A VM champion is interpreted per node, so it is served under
+        the rule it was evaluated under (``CodeEvaluator``): chosen from
+        the cluster's shape, never from a timing probe."""
+        return shape_prefilter_k(n, override)
 
     @staticmethod
     def _code_key(code: str, n: int, g: int, cap: int) -> tuple:

@@ -116,6 +116,11 @@ class SimConfig:
     # the candidate set, so fitness parity vs the dense sweep must be
     # validated per policy (tests/test_scale_tier.py). k >= n_padded
     # falls back to the dense sweep (a full gather is strictly slower).
+    # Code candidates do not leave this at 0 on a large cluster:
+    # CodeEvaluator and VMServeEngine read 0 as "not set" and fill it
+    # from the cluster's shape (``shape_prefilter_k`` below: 64 from 256
+    # padded nodes on). To have them sweep every node of a large cluster,
+    # pass ``node_prefilter_k=n_padded``.
     node_prefilter_k: int = 0
     # packed state dtypes (flat engine only; the exact engine ignores the
     # flag). True narrows FlatState columns whose full value range is
@@ -883,6 +888,28 @@ def auto_prefilter_k(n_padded: int, policy_cost_s: Optional[float], *,
     if policy_cost_s is None or policy_cost_s <= threshold_s:
         return 0
     return k
+
+
+def shape_prefilter_k(n_padded: int, override: Optional[int] = None) -> int:
+    """The large-cluster rule for candidates that are interpreted or traced
+    per node (the VM, the per-AST jit tier, the thread-pool fallback, a VM
+    champion being served): a pure function of the padded node count, no
+    timing. Once the node axis reaches ``PREFILTER_MIN_NODES`` the policy
+    scores the first ``PREFILTER_AUTO_K`` feasible nodes in node order;
+    below that it sweeps every node, and the compiled program is the one
+    an explicit 0 compiles. An explicit ``override`` wins, 0 included
+    (``VMServeEngine(prefilter_k=0)``); ``CodeEvaluator`` hands its
+    ``SimConfig`` field's 0 on as None, because 0 is that field's
+    default, so there the dense sweep on a large cluster is asked for as
+    ``n_padded`` or more (``SimConfig.resolve_prefilter_k``). One
+    function for evaluation (``funsearch.backend.CodeEvaluator``) and VM
+    serving (``serve.vm_engine.VMServeEngine``), so that a fitness does
+    not depend
+    on the tier that answered and a champion is served under the
+    semantics it was scored under."""
+    if override is not None:
+        return int(override)
+    return PREFILTER_AUTO_K if n_padded >= PREFILTER_MIN_NODES else 0
 
 
 def resolve_auto_prefilter(param_policy, params, n_padded: int,

@@ -195,7 +195,7 @@ def test_a_program_without_the_ring_reads_as_nothing(monkeypatch):
 def test_every_span_metric_is_declared_with_its_files():
     bench = json.load(open(os.path.join(cells.ROOT, "BENCHMARK.json")))
     declared = {m["name"]: m for m in bench["per_layer"]}
-    assert len(SPAN_METRICS) == 18
+    assert len(SPAN_METRICS) == 20
     for name in SPAN_METRICS:
         assert name in declared, name
         meta = json.load(open(os.path.join(cells.HERE, "metrics",
@@ -252,6 +252,6 @@ def test_traced_cell_reports_every_span_metric(name, tmp_path, monkeypatch):
             + v["serve.h2d_ms_per_call"] + v["serve.harvest_ms_per_call"]
         assert v["serve.h2d_kb_per_call"] > 0 and v["serve.d2h_kb_per_call"] > 0
     else:
-        assert len(want) == 7
+        assert len(want) == 9
         assert 0 < res["metrics"]["vm.live_slot_share"]["value"] <= 100
         assert res["metrics"]["mesh.host_ms_per_call"]["value"] > 0

@@ -313,8 +313,14 @@ def test_four_device_generation_leaves_the_mesh_spans(micro_workload,
     c = micro_workload.cluster
     longest = max(int(vm.compile_policy(code, c.n_padded, c.g_padded).n_ops)
                   for code in _codes())
-    assert launch.fields == {"lanes": 8, "shards": 4, "slots": longest,
-                             "capacity": vm.capacity_bucket(longest)}
+    # nodes / view / register_bytes: the node axis, what of it the policy
+    # sees, and the register file one device (2 lanes here) carries
+    cap = vm.capacity_bucket(longest)
+    assert launch.fields == {
+        "lanes": 8, "shards": 4, "slots": longest, "capacity": cap,
+        "nodes": c.n_padded, "view": c.n_padded,
+        "register_bytes": 2 * vm.register_rows(cap) * c.n_padded
+        * c.g_padded * 8}
     assert longest < launch.fields["capacity"]
     mesh_spans = [r for r in got if r.name.startswith("mesh/")]
     top = sorted((r for r in mesh_spans if r.parent_id == launch.span_id),

@@ -1,0 +1,152 @@
+"""Builder's readings for the real-cluster deployment (``openb1523-
+inflated``), on the machine with the chip. Outside the benchmark: nothing
+here is a cell, and nothing is measured on another backend (exit 3).
+
+    python tools/chip_cluster_check.py whole --seed N
+    python tools/chip_cluster_check.py dense --seed N [--events 32]
+
+``whole``: ONE whole evaluation (no step cap: fill, pressure, drain, about
+14,000 lockstep events) of the cell's 8 sources of one seed through
+``CodeEvaluator.evaluate``, built as ``cli evolve`` builds it (default
+``SimConfig``, the flat engine; ``fp_dedup`` off so that 8 jittered
+sources stay 8 lanes), compared with the plain reference's whole free run
+under the same rule, fitness included.
+
+``dense``: the same 8 lanes for ``--events`` events under the rule the
+program chooses (64) and DENSE (an explicit ``node_prefilter_k`` of the
+padded node count, which ``SimConfig.resolve_prefilter_k`` turns into the
+sweep of every node), ms per lockstep event each, from the second call's
+``tier/vm_batch/launch`` + ``wait_device`` spans.
+
+Prints JSON lines; the last is the summary.
+"""
+from __future__ import annotations
+
+import argparse
+import json
+import os
+import sys
+import time
+
+sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
+
+from chipbench import cells  # noqa: E402
+from chipbench.drivers import common  # noqa: E402
+
+CELL = "openb1523-inflated.codegen8"
+DEVICE = ("tier/vm_batch/launch", "tier/vm_batch/wait_device")
+
+
+def say(**row) -> None:
+    print(json.dumps(row), flush=True)
+
+
+def _inputs(seed: int):
+    cell = cells.load_cell(CELL)
+    files = cells.verify_files(cell.config)
+    driver = cells.load_driver(cell.traffic["driver"]).Driver(
+        cell, seed, files, None, False)
+    return cell, files, common.parse_workload(cell.config, files), \
+        driver._sources()
+
+
+def _device_seconds(since: int) -> float:
+    from fks_tpu.obs import spans
+    return sum(r.t1 - r.t0 for r in spans.LOG.snapshot()
+               if r.seq >= since and r.name in DEVICE)
+
+
+def _next_seq() -> int:
+    from fks_tpu.obs import spans
+    snap = spans.LOG.snapshot()
+    return snap[-1].seq + 1 if snap else 0
+
+
+def whole(seed: int) -> bool:
+    from chipbench.reference import policies
+    from chipbench.reference.compare import Output, compare
+    from chipbench.reference.plain_sim import simulate
+    from fks_tpu.funsearch.backend import CodeEvaluator
+    from fks_tpu.sim.engine import SimConfig
+
+    cell, files, wl, sources = _inputs(seed)
+    ev = CodeEvaluator(wl, SimConfig(), engine="flat", fp_dedup=False)
+    seq, t0 = _next_seq(), time.perf_counter()
+    recs = ev.evaluate(sources)
+    wall = time.perf_counter() - t0
+    stats = ev.last_eval_stats
+    events = max(int(r.result.events_processed) for r in recs)
+    say(row="evaluated", seed=seed, wall_s=wall, lockstep_events=events,
+        device_ms_per_event=_device_seconds(seq) / events * 1e3,
+        prefilter_k=stats["prefilter_k"], segments=stats["segments"],
+        vm_batch_lanes=stats["vm_batch_lanes"],
+        fallback_lanes=stats["fallback_lanes"])
+    cluster, pods = common.reference_inputs(cell.config, files)
+    ok = stats["vm_batch_lanes"] == len(sources)
+    for lane, (rec, code) in enumerate(zip(recs, sources)):
+        t0 = time.perf_counter()
+        ref = simulate(cluster, pods, policies.source_policy(code),
+                       retry=cell.config["retry_rule"],
+                       prefilter_k=int(cell.config["node_prefilter_k"]))
+        numbers = compare(f"lane{lane}", ref,
+                          Output.of_lane(rec.result, pods.p),
+                          cell.config["guarantees"])
+        ok &= all(n.ok for n in numbers) and ref.policy_score > 0
+        say(row="lane", lane=lane, fitness=rec.score,
+            reference_fitness=ref.policy_score,
+            events=int(rec.result.events_processed),
+            scheduled=int(rec.result.scheduled_pods),
+            reference_s=time.perf_counter() - t0,
+            compared={n.name.split(".", 1)[1]: n.value for n in numbers},
+            ok=all(n.ok for n in numbers))
+    say(row="whole", seed=seed, lanes=len(sources), all_equal=bool(ok))
+    return bool(ok)
+
+
+def dense(seed: int, events: int) -> bool:
+    import numpy as np
+    from fks_tpu.funsearch.backend import CodeEvaluator
+    from fks_tpu.sim.engine import SimConfig
+
+    _, _, wl, sources = _inputs(seed)
+    n = wl.cluster.n_padded
+    out, placed = {}, {}
+    for name, k in (("rule", 0), ("dense", n)):
+        ev = CodeEvaluator(wl, SimConfig(max_steps=events,
+                                         node_prefilter_k=k),
+                           engine="flat", fp_dedup=False)
+        ev.evaluate(sources)                 # compiles
+        seq = _next_seq()
+        recs = ev.evaluate(sources)
+        out[name] = _device_seconds(seq) / events * 1e3
+        placed[name] = np.stack([np.asarray(r.result.assigned_node)
+                                 for r in recs])
+        say(row="reading", view=name,
+            prefilter_k=ev.last_eval_stats["prefilter_k"], events=events,
+            device_ms_per_event=out[name])
+    say(row="dense", seed=seed, events=events, nodes_padded=n,
+        rule_ms_per_event=out["rule"], dense_ms_per_event=out["dense"],
+        ratio=out["dense"] / out["rule"],
+        placements_differ=int((placed["rule"] != placed["dense"]).sum()))
+    return True
+
+
+def main(argv=None) -> int:
+    ap = argparse.ArgumentParser(description=__doc__.splitlines()[0])
+    ap.add_argument("what", choices=("whole", "dense"))
+    ap.add_argument("--seed", type=int, required=True)
+    ap.add_argument("--events", type=int, default=32)
+    a = ap.parse_args(argv)
+    import jax
+    from fks_tpu.utils import place_compile_cache
+
+    if jax.devices()[0].platform != "tpu":
+        print("chip_cluster_check: no TPU", file=sys.stderr)
+        return 3
+    place_compile_cache()
+    ok = whole(a.seed) if a.what == "whole" else dense(a.seed, a.events)
+    return 0 if ok else 1
+
+
+if __name__ == "__main__":
+    sys.exit(main())
