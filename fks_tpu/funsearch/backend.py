@@ -666,6 +666,7 @@ class CodeEvaluator:
         general: Dict[str, str] = {}  # default tier choice (VM then jit)
         c = self.workload.cluster
         with self.profiler.stage("transpile", span="tier/transpile") as ht:
+            runs0 = transpiler.body_runs()
             if self.use_vm and self.vm_batch and len(unique) > 1:
                 for key, code in unique.items():
                     try:
@@ -690,6 +691,10 @@ class CodeEvaluator:
                 general = dict(unique)
             ht.annotate(vm_lanes=len(vm_progs),
                         jit_fallback=len(jit_only) + len(general))
+            # traces: counted where a policy body runs, so a second trace
+            # per source shows (chipbench: tier.traces_per_source)
+            ht.span.set(sources=len(unique),
+                        traces=transpiler.body_runs() - runs0)
 
         batch_served = 0
         self.last_budget_stats = []

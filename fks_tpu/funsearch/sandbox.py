@@ -137,8 +137,10 @@ def validate_structure(code: str,
 
 
 def validate(code: str, entry_point: str = "priority_function") -> ValidationResult:
-    """Both static stages. The third, TPU-specific stage is
-    ``transpiler.transpile`` itself (raises TranspileError)."""
+    """Both static stages. The third, TPU-specific stage is the first trace
+    of the transpiled body (raises TranspileError): ``transpiler.transpile``'s
+    2 x 2 dry trace, or on the VM path ``vm.compile_policy``'s one trace at
+    the workload's shape."""
     r = validate_source_text(code)
     if not r:
         return r

@@ -34,6 +34,7 @@ from __future__ import annotations
 
 import ast
 import math
+import threading
 from typing import Any, Dict, Optional
 
 import jax.numpy as jnp
@@ -719,12 +720,25 @@ def canonical_key(code: str) -> str:
     return ast.dump(ast.parse(code))
 
 
-def transpile(code: str, entry_point: str = "priority_function") -> PolicyFn:
-    """Validate + compile candidate source into a vectorized PolicyFn.
+_BODY_RUNS = threading.local()
 
-    Raises ``TranspileError`` for code outside the lowerable subset (this is
-    the TPU-tightened third validation stage, SURVEY.md §2 fine print 10).
-    """
+
+def body_runs() -> int:
+    """How many times a policy body built here has run on THIS thread, the
+    dry trace included; under ``jit`` / ``make_jaxpr`` / ``eval_shape`` a
+    run is a trace. ``backend._evaluate`` reads the difference over its
+    transpile stage (the ``traces`` field of ``tier/transpile``)."""
+    return getattr(_BODY_RUNS, "n", 0)
+
+
+def build_policy(code: str,
+                 entry_point: str = "priority_function") -> PolicyFn:
+    """Validate ``code`` (``sandbox.validate``) and build its vectorized
+    PolicyFn WITHOUT running it: the subset checks of ``_Interp`` fire when
+    the body first runs. For callers that hold the workload's shape and
+    trace the closure at once (``vm.compile_policy``'s ``make_jaxpr``), so
+    that one trace is also the validation; everyone else wants
+    ``transpile``."""
     r = sandbox.validate(code, entry_point)
     if not r:
         raise TranspileError(f"validation failed: {r.reason}")
@@ -733,6 +747,7 @@ def transpile(code: str, entry_point: str = "priority_function") -> PolicyFn:
     body = fn.body
 
     def policy(pod: PodView, nodes: NodeView):
+        _BODY_RUNS.n = body_runs() + 1
         interp = _Interp(pod, nodes)
         interp.run_block(body, jnp.ones(interp.n, bool))
         val = interp.retval
@@ -745,6 +760,18 @@ def transpile(code: str, entry_point: str = "priority_function") -> PolicyFn:
         out = _int_trunc(vf).astype(jnp.int32)
         return jnp.where(interp.returned & ~interp.poison, out, 0)
 
+    return policy
+
+
+def transpile(code: str, entry_point: str = "priority_function") -> PolicyFn:
+    """Validate + compile candidate source into a vectorized PolicyFn, and
+    dry-trace it once on 2 x 2 dummy views (``_dry_trace``): the entry
+    point of every caller that has no shape to trace at.
+
+    Raises ``TranspileError`` for code outside the lowerable subset (this is
+    the TPU-tightened third validation stage, SURVEY.md §2 fine print 10).
+    """
+    policy = build_policy(code, entry_point)
     _dry_trace(policy)
     return policy
 
@@ -752,7 +779,10 @@ def transpile(code: str, entry_point: str = "priority_function") -> PolicyFn:
 def _dry_trace(policy: PolicyFn) -> None:
     """Abstractly evaluate the lowered policy on tiny dummy views so subset
     violations (unsupported calls, oversized unrolls, unknown attributes)
-    surface at transpile time, not at first simulation."""
+    surface at transpile time, not at first simulation. Not run on the VM
+    path: ``vm.compile_policy`` traces ``build_policy``'s closure at the
+    workload's padded shape straight away, and that trace raises the same
+    errors."""
     import jax
 
     n, g = 2, 2

@@ -11,8 +11,12 @@ a few arrays uploaded to the device, not a recompilation.
 
 Pipeline:
   candidate source
-    -> transpiler.transpile (validation + vectorization, unchanged)
-    -> jax.make_jaxpr on the padded (N, G) view shapes
+    -> transpiler.build_policy (sandbox validation + the vectorized
+       closure, not yet run)
+    -> jax.make_jaxpr on the padded (N, G) view shapes: the ONE trace of
+       the candidate's body, so also its subset validation (a violation
+       raises TranspileError from inside it; transpiler.transpile's 2 x 2
+       dry trace is for callers without a shape and is not run here)
     -> this module lowers the (inlined) jaxpr to a register program:
        every value lives as an f32[N, G] register (scalars and [N] values
        broadcast across G), each op writes one fresh register, reductions
@@ -507,9 +511,11 @@ def compile_policy(code: str, n: int, g: int,
     """Lower candidate source to a VMProgram for padded shapes (n, g).
 
     Raises TranspileError (invalid candidate) or VMUnsupported (valid but
-    outside the VM vocabulary -> caller uses the jit tier).
+    outside the VM vocabulary -> caller uses the jit tier). The candidate's
+    body runs under a JAX trace once, here, at (n, g): a candidate is valid
+    on this path iff that trace succeeds.
     """
-    policy = transpiler.transpile(code)
+    policy = transpiler.build_policy(code)
     pod, nodes = _dummy_views(n, g)
     closed = jax.make_jaxpr(policy)(pod, nodes)
 

@@ -22,7 +22,8 @@ TINY = {"config": {"pod_limit": 150, "code_eval_max_steps": 48},
 SPAN_METRICS = ("tier.preflight_ms_per_call", "tier.transpile_ms_per_call",
                 "tier.harvest_ms_per_call", "tier.unattributed_share",
                 "vm.device_ms_per_event", "vm.live_slot_share",
-                "vm.us_per_slot", "vm.register_mb")
+                "vm.us_per_slot", "vm.register_mb",
+                "tier.traces_per_source")
 
 
 def _run(monkeypatch, tmp_path, trace, seed=2 ** 31 + 5):
@@ -84,6 +85,7 @@ def test_cell_runs_end_to_end_and_reports_its_span_metrics(monkeypatch,
     # 4 lanes x 561 rows x 64 nodes x 8 GPUs x 8 bytes (x64 in the tests)
     assert v["vm.register_mb"] == 4 * 561 * 64 * 8 * 8 / 1e6
     assert 0 < v["vm.live_slot_share"] <= 100
+    assert v["tier.traces_per_source"] == 1.0   # no dry trace before it
     slots = v["vm.live_slot_share"] / 100 * 512
     assert v["vm.us_per_slot"] == pytest.approx(
         v["vm.device_ms_per_event"] * 1e3 / slots, rel=1e-6)

@@ -181,6 +181,28 @@ def test_live_slot_share_reads_slots_over_capacity(metric, span, make,
     assert got == (want if want is None else pytest.approx(want))
 
 
+@pytest.mark.parametrize("fields,want", [
+    ({"sources": 8, "traces": 8}, 1.0),     # the one trace at the real shape
+    ({"sources": 8, "traces": 16}, 2.0),    # a dry trace came back
+    ({"sources": 8, "traces": 0}, 0.0),     # a memo answered: not this cell's
+    ({"sources": 0, "traces": 0}, None),    # nothing entered the stage
+    ({}, None),                             # a parent without the fields
+])
+def test_traces_per_source_reads_the_transpile_spans_fields(fields, want):
+    recs, t = [], 0.0
+    for i in range(3):
+        recs.append(rec(2 * i, "tier/transpile", t + 0.1, t + 1.0, f"x{i}",
+                        f"g{i}", f"g{i}", **fields))
+        recs.append(rec(2 * i + 1, "tier/evaluate", t, t + 2.0, f"g{i}",
+                        None, f"g{i}"))
+        t += 2.01
+    calls = rs.select_generations(recs, 0, 2, 4.0)
+    assert calls
+    got = cells.metric_reader("tier.traces_per_source")(
+        {"_span_calls": calls})
+    assert got == want
+
+
 def test_a_program_without_the_ring_reads_as_nothing(monkeypatch):
     from fks_tpu.obs import spans
 
@@ -195,7 +217,7 @@ def test_a_program_without_the_ring_reads_as_nothing(monkeypatch):
 def test_every_span_metric_is_declared_with_its_files():
     bench = json.load(open(os.path.join(cells.ROOT, "BENCHMARK.json")))
     declared = {m["name"]: m for m in bench["per_layer"]}
-    assert len(SPAN_METRICS) == 20
+    assert len(SPAN_METRICS) == 21
     for name in SPAN_METRICS:
         assert name in declared, name
         meta = json.load(open(os.path.join(cells.HERE, "metrics",
@@ -252,6 +274,8 @@ def test_traced_cell_reports_every_span_metric(name, tmp_path, monkeypatch):
             + v["serve.h2d_ms_per_call"] + v["serve.harvest_ms_per_call"]
         assert v["serve.h2d_kb_per_call"] > 0 and v["serve.d2h_kb_per_call"] > 0
     else:
-        assert len(want) == 9
+        assert len(want) == 10
+        # a recorded generation: every source traced once, where it runs
+        assert res["metrics"]["tier.traces_per_source"]["value"] == 1.0
         assert 0 < res["metrics"]["vm.live_slot_share"]["value"] <= 100
         assert res["metrics"]["mesh.host_ms_per_call"]["value"] > 0

@@ -3,6 +3,8 @@
 scores EQUAL the directly-transpiled policy's scores (integer-exact), and
 full-simulation fitness through the shared engine program equals the
 per-candidate jit tier; candidates outside the vocabulary fall back."""
+import json
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -10,6 +12,8 @@ import pytest
 
 from fks_tpu.funsearch import backend, llm, template, transpiler, vm
 from fks_tpu.sim.types import NodeView, PodView
+from tests import lowering_corpus as corpus
+from tests.conftest import FIXTURES
 
 N, G = 16, 8
 
@@ -134,3 +138,109 @@ def test_vm_matches_jit_tier_scores():
     with_vm = backend.CodeEvaluator(wl, use_vm=True).scores(codes)
     without = backend.CodeEvaluator(wl, use_vm=False).scores(codes)
     np.testing.assert_array_equal(with_vm, without)
+
+
+# ---------------------------------------------------------------------------
+# One trace per source (PR 28): compile_policy traces the candidate's body
+# once, at the workload's padded shape, and that trace is its validation;
+# transpiler.transpile keeps its 2 x 2 dry trace for callers with no shape.
+# The pins below were recorded from the PARENT, which ran both traces.
+
+with open(FIXTURES / "vm_lowering_pins.json") as _f:
+    PINS = json.load(_f)
+with open(FIXTURES / "mixed_batch_records.json") as _f:
+    MIXED_RECORDS = json.load(_f)
+
+
+def test_the_pins_cover_the_corpus():
+    assert set(PINS) == set(corpus.sources()) and len(PINS) == 122
+    bad = [k for k, v in PINS.items() if "error" in v["16x8"]]
+    assert len(bad) == 31 and sum(k.startswith("champion:")
+                                  for k in PINS) == 13
+
+
+@pytest.mark.parametrize("shape", corpus.SHAPES,
+                         ids=lambda s: corpus.shape_key(*s))
+@pytest.mark.parametrize("name", sorted(PINS))
+def test_lowering_is_the_parents(name, shape):
+    """Valid sources lower to the parent's program, leaf for leaf (opcode,
+    a, b, c, imm, consts, n_ops, out_reg); invalid ones raise the parent's
+    exception class with the parent's message, from the one trace."""
+    want = PINS[name][corpus.shape_key(*shape)]
+    got = corpus.outcome(corpus.sources()[name], *shape)
+    assert got == want
+
+
+def test_compile_policy_traces_the_body_once_and_transpile_still_dry_traces(
+        monkeypatch):
+    code = template.seed_policies()["best_fit"]
+    dry = []
+    real_dry = transpiler._dry_trace
+    monkeypatch.setattr(transpiler, "_dry_trace",
+                        lambda policy: (dry.append(1), real_dry(policy)))
+    r0 = transpiler.body_runs()
+    vm.compile_policy(code, N, G, capacity=512)
+    assert transpiler.body_runs() - r0 == 1 and not dry
+    transpiler.transpile(code)
+    assert transpiler.body_runs() - r0 == 2 and dry == [1]
+    # the closure without the dry trace has not run at all
+    transpiler.build_policy(code)
+    assert transpiler.body_runs() - r0 == 2
+    # a violation surfaces from the one trace, as the same class
+    bad = template.fill_template("score = pod.nonexistent_field")
+    transpiler.build_policy(bad)                         # not yet run
+    with pytest.raises(transpiler.TranspileError, match="unknown pod attr"):
+        vm.compile_policy(bad, N, G, capacity=512)
+    assert transpiler.body_runs() - r0 == 3 and dry == [1]
+
+
+def test_body_runs_counts_per_thread():
+    import threading
+
+    code = template.seed_policies()["first_fit"]
+    r0 = transpiler.body_runs()
+    t = threading.Thread(
+        target=lambda: vm.compile_policy(code, N, G, capacity=512))
+    t.start()
+    t.join()
+    assert transpiler.body_runs() == r0
+
+
+@pytest.mark.parametrize("vm_batch,preflight", corpus.MIXED_MODES)
+def test_mixed_generation_returns_the_parents_records(vm_batch, preflight):
+    """valid, subset violation, VMUnsupported and syntax error in one
+    generation: every field of every record is what the parent returned,
+    through the batched tier's loop and through evaluate_one's VM branch."""
+    want = MIXED_RECORDS[corpus.mode_key(vm_batch, preflight)]
+    got = corpus.mixed_batch_records(vm_batch, preflight)
+    assert len(got) == len(want) == len(corpus.MIXED)
+    for g, w in zip(got, want):
+        assert g == w, w["source"]
+    errors = [w["error"] for w in want]
+    assert sum(e is None for e in errors) == 6
+    assert sum((e or "").startswith("transpile:") for e in errors) == \
+        (1 if preflight else 3)
+
+
+def test_transpile_stage_counts_sources_and_traces():
+    """``tier/transpile`` carries ``sources`` and ``traces``: one trace per
+    unique source on the batched tier; none in the stage when the lane
+    count sends every source to the unbatched tier."""
+    from fks_tpu.obs import spans
+
+    src = corpus.sources()
+    codes = [src[n] for n in corpus.MIXED]
+    ev = backend.CodeEvaluator(corpus.mixed_workload(), vm_batch=True,
+                               preflight=False)
+    mark = len(spans.LOG.snapshot())
+    ev.evaluate(codes)
+    (sp,) = [r for r in spans.LOG.snapshot()[mark:]
+             if r.name == "tier/transpile"]
+    # 10 sources, one repeated, one a syntax error: 8 enter the stage
+    assert sp.fields == {"sources": 8, "traces": 8}
+    ev1 = backend.CodeEvaluator(corpus.mixed_workload(), vm_batch=False)
+    mark = len(spans.LOG.snapshot())
+    ev1.evaluate(codes[:1])
+    (sp,) = [r for r in spans.LOG.snapshot()[mark:]
+             if r.name == "tier/transpile"]
+    assert sp.fields == {"sources": 1, "traces": 0}
