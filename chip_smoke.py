@@ -140,8 +140,7 @@ def step_parity(trace: str, nodes: str, expect: dict, n_pods: int,
 def step_evaluate_parametric(wl, pop: int, mesh=None, seed: int = 0,
                              exact_best_fit=None) -> dict:
     """``parallel.make_population_eval(engine="flat")`` at ``pop`` lanes
-    (the throughput config of bench.py: step cap 4x pods, ctime tracking
-    off). Two lanes — the best_fit seed weights and one random lane — are
+    (the throughput config: step cap 4x pods, ctime tracking off). Two lanes — the best_fit seed weights and one random lane — are
     re-run UNBATCHED on the host CPU and must agree with the device in
     ``scheduled_pods``/``events_processed``/``assigned_node`` exactly and
     in ``policy_score`` to 1e-5. With a mesh the same population also
@@ -403,7 +402,7 @@ def step_fused(wl, lanes: int = 64, seed: int = 0,
                interpret: bool = False) -> dict:
     """``make_fused_population_run(..., interpret=False)``: the Pallas
     kernel compiled by libtpu's Mosaic at ``lanes`` candidates, then
-    bench.py's fused-vs-flat device gate (8 candidates: scheduled counts
+    the fused-vs-flat device gate (8 candidates: scheduled counts
     equal, scores to 2e-5). ``interpret=True`` is for the CPU tests."""
     import jax
     import numpy as np

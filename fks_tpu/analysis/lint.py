@@ -31,12 +31,6 @@ per-site waivers:
   decorator-jitted functions — because every cached executable claims
   device memory for its lifetime, and an unpriced one is invisible to
   ``cli mem`` and the memory budget gate.
-- FKS107: a ``shard_map`` site (direct call or ``partial(shard_map,
-  ...)`` decorator) whose enclosing function never touches the layout
-  ledger (``record_layout`` / ``tag_layout`` / ``_resolve_layout`` / a
-  ``layout_key``) and carries no ``layout-exempt`` docstring waiver —
-  an untagged device schedule is invisible to ``cli layout`` and the
-  layout explorer (mirrors FKS106's footprint-coverage rule).
 
 **Jaxpr pins** (``compute_pins`` / ``check_pins`` / ``write_pins``) —
 the dynamic half of the same contract. Every Python-static SimConfig
@@ -77,21 +71,11 @@ LINT_CODES = {
     "FKS104": "numpy usage inside a jitted function",
     "FKS105": "SimConfig passed as a traced jit argument",
     "FKS106": "AOT .lower(...).compile() without a footprint record",
-    "FKS107": "shard_map site without a layout key tag",
 }
 
 #: names whose presence in the enclosing function waives FKS106 — the
 #: compile site is priced into the footprint ledger (fks_tpu.obs.memory)
 _FOOTPRINT_MARKS = {"record_footprint", "footprint_of", "memory_analysis"}
-
-#: names whose presence in the enclosing function waives FKS107 — the
-#: shard_map site is attributed to a named layout in the layout ledger
-#: (fks_tpu.obs.layout); ``layout-exempt`` in the enclosing function's
-#: docstring waives intentionally untagged internals (a builder whose
-#: caller tags the returned runner)
-_LAYOUT_MARKS = {"record_layout", "tag_layout", "layout_key",
-                 "_resolve_layout", "_layout_eval_wrapper"}
-_LAYOUT_WAIVER = "layout-exempt"
 
 
 @dataclasses.dataclass(frozen=True)
@@ -298,73 +282,6 @@ def _lint_compile_sites(path: str, tree: ast.Module,
             f"(or price it via footprint_of/memory_analysis)"))
 
 
-def _is_shard_map(expr: ast.expr) -> bool:
-    return ((isinstance(expr, ast.Name) and expr.id == "shard_map")
-            or (isinstance(expr, ast.Attribute)
-                and expr.attr == "shard_map"))
-
-
-def _shard_map_sites(tree: ast.Module) -> Iterable[ast.Call]:
-    """Both shard_map idioms the repo uses: a direct ``shard_map(fn,
-    mesh=...)`` call, and the ``functools.partial(shard_map, mesh=...)``
-    decorator form."""
-    for node in ast.walk(tree):
-        if not isinstance(node, ast.Call):
-            continue
-        if _is_shard_map(node.func):
-            yield node
-        elif ((isinstance(node.func, ast.Name)
-               and node.func.id == "partial")
-              or (isinstance(node.func, ast.Attribute)
-                  and node.func.attr == "partial")) \
-                and node.args and _is_shard_map(node.args[0]):
-            yield node
-
-
-def _references_layout(fn: ast.AST) -> bool:
-    for sub in ast.walk(fn):
-        if isinstance(sub, ast.Name) and sub.id in _LAYOUT_MARKS:
-            return True
-        if isinstance(sub, ast.Attribute) and sub.attr in _LAYOUT_MARKS:
-            return True
-    return False
-
-
-def _lint_shard_map_sites(path: str, tree: ast.Module,
-                          findings: List[Finding]) -> None:
-    """FKS107: every shard_map site must be attributed to a named layout
-    — waived when the innermost enclosing function references the layout
-    ledger (``record_layout`` / ``tag_layout`` / ``_resolve_layout`` /
-    a ``layout_key``), or carries ``layout-exempt`` in its docstring
-    (an internal builder whose CALLER tags the returned runner). An
-    untagged site is a device schedule the layout explorer cannot see —
-    exactly how a pad-waste or collective regression hides from
-    ``cli layout``."""
-    funcs = [n for n in ast.walk(tree)
-             if isinstance(n, (ast.FunctionDef, ast.AsyncFunctionDef))]
-    for site in _shard_map_sites(tree):
-        enclosing = None
-        for fn in funcs:
-            end = getattr(fn, "end_lineno", None) or fn.lineno
-            if fn.lineno <= site.lineno <= end:
-                if enclosing is None or fn.lineno > enclosing.lineno:
-                    enclosing = fn
-        if enclosing is not None:
-            if _references_layout(enclosing):
-                continue
-            doc = ast.get_docstring(enclosing) or ""
-            if _LAYOUT_WAIVER in doc:
-                continue
-        where = (f"in '{enclosing.name}'" if enclosing is not None
-                 else "at module scope")
-        findings.append(Finding(
-            path, site.lineno, "FKS107",
-            f"{LINT_CODES['FKS107']}: {where} — resolve a LayoutSpec "
-            f"(obs.layout) and tag_layout/record_layout the runner, or "
-            f"mark the function '{_LAYOUT_WAIVER}' when its caller tags "
-            f"the returned runner"))
-
-
 def lint_source(path: str, source: str) -> List[Finding]:
     """Lint one module's source. Syntax errors surface as a finding (the
     gate must not crash on a broken tree mid-refactor)."""
@@ -387,7 +304,6 @@ def lint_source(path: str, source: str) -> List[Finding]:
                          _simconfig_params(node), findings)
             break
     _lint_compile_sites(path, tree, findings)
-    _lint_shard_map_sites(path, tree, findings)
     return findings
 
 
@@ -525,11 +441,10 @@ def compute_pins() -> Dict[str, object]:
     pins["segmented_advance/baseline"] = _jaxpr_hash(
         run.advance, params, bstate)
 
-    # the default LayoutSpec must lower the identical program as the
-    # pre-LayoutSpec hard-coded behavior (obs.layout): pinned on the
-    # sharded population eval over a 1-device mesh so a refactor that
-    # quietly changes the default schedule (a different in_spec, an
-    # extra collective) trips lint — intentional layout changes re-pin
+    # the sharded population eval over a 1-device mesh, so a refactor
+    # that quietly changes the mesh layer's schedule (a different
+    # in_spec, an extra collective) trips lint — intentional changes
+    # re-pin
     from fks_tpu.models import parametric
     from fks_tpu.parallel.mesh import make_sharded_eval, population_mesh
 

@@ -603,7 +603,7 @@ def cmd_serve(args):
     every shape bucket, then answer queries over stdin/JSONL, a file, or
     a localhost HTTP listener. ``--selftest N`` instead runs the
     batched-vs-unbatched exact-parity sweep and exits nonzero on any
-    drift — the run_full_suite serve gate."""
+    drift."""
     _apply_platform_flags(args)
     from fks_tpu import obs
     from fks_tpu.serve import (
@@ -909,8 +909,7 @@ def cmd_portfolio(args):
     request. ``--selftest N`` runs the per-slot parity sweep (every
     resident slot vs a single-champion VM engine, plus a mixed-slot
     batch) and then promotes one slot mid-traffic under a compile
-    watcher — the run_full_suite portfolio gate. ``--http`` serves the
-    routed front instead."""
+    watcher. ``--http`` serves the routed front instead."""
     _apply_platform_flags(args)
     from fks_tpu import obs
     from fks_tpu.funsearch import template
@@ -1065,8 +1064,7 @@ def cmd_pipeline(args):
     the promotion.jsonl state-machine status (per-attempt states, the
     active promotion, interrupted attempts, torn lines). ``--drill``
     runs the deterministic fault-injection drill matrix instead and
-    exits nonzero on any failed drill — the run_full_suite promotion
-    gate."""
+    exits nonzero on any failed drill."""
     import os
 
     _apply_platform_flags(args)
@@ -1153,7 +1151,7 @@ def cmd_spans(args):
     (fks_tpu.obs.trace_ctx): list traces, render one request's latency
     waterfall (``--trace``), rank the slowest requests (``--slowest``),
     verify every served request reconstructs a complete waterfall
-    (``--check-complete``, the run_full_suite trace gate), or print the
+    (``--check-complete``), or print the
     per-generation critical path with the device-idle vs LLM-idle split
     (``--critical-path``, gated by ``--min-fraction``)."""
     from fks_tpu.obs import trace_ctx
@@ -1289,8 +1287,7 @@ def cmd_compare(args):
 
 
 def _default_history_root() -> str:
-    """benchmarks/results under the repo root — where bench.py banks
-    headline evidence and run_full_suite lands its rows."""
+    """benchmarks/results under the repo root."""
     import os
 
     return os.environ.get("FKS_BENCH_RESULTS_DIR") or os.path.join(
@@ -1304,8 +1301,7 @@ def cmd_trends(args):
     per-metric timelines as sparklines, and flag regressions with the
     robust z-score pass. Exit code contract: 0 = rendered (alerts print
     but don't fail), 1 with ``--fail-on-alert`` when any metric alerted,
-    2 = bad/empty root — scriptable like ``compare``
-    (tools/run_full_suite.py's trends gate leans on it)."""
+    2 = bad/empty root — scriptable like ``compare``."""
     from fks_tpu.obs.history import RunHistory
     from fks_tpu.obs.report import sparkline
 
@@ -1356,8 +1352,7 @@ def cmd_trace_diff(args):
     """Replay one policy through two engines with the decision trace on and
     report the first divergent scheduling step (fks_tpu.obs.tracing).
     Exit code contract: 0 = no divergence, 1 = divergence found, 2 = error
-    — scriptable like ``compare`` (tools/run_full_suite.py's trace gate
-    leans on the 0 path)."""
+    — scriptable like ``compare``."""
     _apply_platform_flags(args)
     from fks_tpu.obs import tracing
     from fks_tpu.sim.engine import SimConfig
@@ -1431,7 +1426,7 @@ def cmd_lint(args):
     then the pinned-jaxpr manifest check (key entry points lowered with
     each Python-static SimConfig flag and hashed). Exit code contract:
     0 = clean, 1 = findings or pin drift, 2 = error — scriptable like
-    ``compare`` (tools/run_full_suite.py's lint gate leans on it).
+    ``compare``.
     ``--write-pins`` re-lowers and rewrites the manifest instead of
     checking it (exit 0)."""
     _apply_platform_flags(args)
@@ -1577,89 +1572,6 @@ def cmd_mem(args):
     return 0
 
 
-def cmd_layout(args):
-    """Layout observability (fks_tpu.obs.layout). Two modes:
-
-    - view (default): render the per-layout cost ledger of a recorded
-      run from ``--run-dir``'s JSONL alone — one row per
-      (workload_key, mesh_layout, layout_key) with pad waste, lane-step
-      occupancy, cost-analysis bytes, and the predicted HBM claim
-      joined from the footprint ledger;
-    - ``--explore``: enumerate the valid layouts of a (population x
-      suite) shape over the virtual CPU mesh (``--cpu --devices N``) or
-      the real devices, run one warm probe each, persist the best into
-      ``RunHistory``, and print the summary JSON. Exit 1 when the
-      CHOSEN layout (``--mesh-shape CxS``, default the candidates-only
-      default layout) is measurably dominated by another probe — the
-      scriptable seam run_full_suite's layout_gate leans on."""
-    if args.explore:
-        import os
-
-        _apply_platform_flags(args)
-        from fks_tpu.data.synthetic import synthetic_workload
-        from fks_tpu.obs import get_recorder
-        from fks_tpu.obs.layout import explore_layouts
-        from fks_tpu.scenarios import get_suite
-
-        wl = synthetic_workload(16, 32, seed=args.seed)
-        suite = get_suite(args.suite, wl)
-        wkey = f"pop{args.pop}_{args.suite}"
-        history = None
-        root = args.history_root or _default_history_root()
-        if os.path.isdir(root):
-            from fks_tpu.obs.history import RunHistory
-            history = RunHistory(root)
-        engine = args.engine if args.engine != "fused" else "flat"
-        with _flight_recorder(args, "layout"):
-            summary = explore_layouts(
-                suite, population=args.pop, engine=engine,
-                recorder=get_recorder(), history=history,
-                workload_key=wkey)
-        chosen = summary["default_layout_key"]
-        chosen_steady = summary["default_steady_seconds"]
-        if args.mesh_shape:
-            match = [p for p in summary["probes"]
-                     if p["mesh_shape"] == args.mesh_shape]
-            if not match:
-                shapes = [p["mesh_shape"] for p in summary["probes"]]
-                print(f"error: --mesh-shape {args.mesh_shape} not among "
-                      f"the valid layouts {shapes}", file=sys.stderr)
-                return 2
-            chosen = match[0]["layout_key"]
-            chosen_steady = match[0]["steady_seconds"]
-        best = summary["best_steady_seconds"]
-        dominated = (summary["best_layout_key"] != chosen
-                     and best > 0
-                     and chosen_steady / best > 1.05)
-        summary["chosen_layout_key"] = chosen
-        summary["chosen_dominated"] = dominated
-        print(json.dumps(summary, indent=2))
-        if dominated:
-            print(f"DOMINATED: chosen layout {chosen} is "
-                  f"{chosen_steady / best:.2f}x slower than "
-                  f"{summary['best_layout_key']}", file=sys.stderr)
-            return 1
-        return 0
-    if not args.run_dir:
-        print("error: layout needs --run-dir DIR (view mode) or "
-              "--explore", file=sys.stderr)
-        return 2
-    from fks_tpu.obs.report import _layout_section, load_run
-
-    try:
-        _meta, _events, metrics = load_run(args.run_dir)
-    except FileNotFoundError as e:
-        print(f"error: {e}", file=sys.stderr)
-        return 2
-    lines = _layout_section(metrics)
-    if not lines:
-        print(f"(no layout records in {args.run_dir} — ledger rows land "
-              "when a sharded entry point runs under --run-dir)")
-        return 0
-    print("\n".join(lines))
-    return 0
-
-
 def cmd_traces(args):
     """Dataset discovery (reference: parser.py:103-115)."""
     from fks_tpu.data import TraceParser
@@ -1674,7 +1586,9 @@ def cmd_traces(args):
     return 0
 
 
-def main(argv=None) -> int:
+def build_parser() -> argparse.ArgumentParser:
+    """The whole command line: one sub-parser per command, each bound to
+    its ``cmd_*`` through ``fn``. Builds only; nothing runs."""
     ap = argparse.ArgumentParser(prog="fks_tpu", description=__doc__)
     sub = ap.add_subparsers(dest="cmd", required=True)
     common = argparse.ArgumentParser(add_help=False)
@@ -1896,8 +1810,7 @@ def main(argv=None) -> int:
                          "instead of JSONL")
     sv.add_argument("--selftest", type=int, default=0,
                     help="run the batched-vs-unbatched exact-parity sweep "
-                         "with N queries and exit (nonzero on drift) — "
-                         "the run_full_suite serve gate")
+                         "with N queries and exit (nonzero on drift)")
     sv.add_argument("--pods-per-query", type=int, default=4,
                     help="query size for --selftest (default 4)")
     sv.add_argument("--audit-every", type=int, default=0,
@@ -2025,8 +1938,7 @@ def main(argv=None) -> int:
                     help="run the per-slot + mixed-batch parity sweep "
                          "with N queries per slot, then promote one "
                          "slot mid-traffic under a compile watcher, "
-                         "and exit (nonzero on drift or any compile) — "
-                         "the run_full_suite portfolio gate")
+                         "and exit (nonzero on drift or any compile)")
     pf.add_argument("--pods-per-query", type=int, default=3,
                     help="query size for --selftest (default 3)")
     pf.add_argument("--audit-tol", type=float, default=1e-5,
@@ -2050,8 +1962,7 @@ def main(argv=None) -> int:
                          "outage, plus the resilience matrix: deadline "
                          "storm, queue overload, device loss mid-batch, "
                          "degrade-then-recover, SIGTERM drain, WAL "
-                         "resume) and exit nonzero on any failure — the "
-                         "run_full_suite promotion gate")
+                         "resume) and exit nonzero on any failure")
     pp.add_argument("--only", default="",
                     help="comma-separated drill-name substrings: run only "
                          "the matching drills (e.g. "
@@ -2238,38 +2149,13 @@ def main(argv=None) -> int:
                          "fail unless that many real devices are visible)")
     mm.set_defaults(fn=cmd_mem)
 
-    ly = sub.add_parser(
-        "layout",
-        help="layout observability: per-layout cost ledger view of a "
-             "run, or --explore to measure every valid layout of a "
-             "(population x suite x mesh) shape (exit 1 when the chosen "
-             "layout is measurably dominated)",
-        parents=[common])
-    ly.add_argument("--explore", action="store_true",
-                    help="enumerate + probe every valid layout and print "
-                         "the summary JSON (persists the best into "
-                         "RunHistory as a prior)")
-    ly.add_argument("--devices", type=int, default=0,
-                    help="with --cpu: size of the virtual CPU device "
-                         "mesh to explore over (without --cpu: fail unless "
-                         "that many real devices are visible)")
-    ly.add_argument("--pop", type=int, default=64,
-                    help="explore population size (default 64)")
-    ly.add_argument("--suite", default="default8",
-                    help="scenario suite to explore (default: default8)")
-    ly.add_argument("--mesh-shape", default="",
-                    help="the chosen CxS layout to defend (e.g. 4x2); "
-                         "default: the candidates-only default layout")
-    ly.add_argument("--seed", type=int, default=0,
-                    help="synthetic base-workload seed (default 0)")
-    ly.add_argument("--history-root", default="",
-                    help="RunHistory root for the layout prior (default: "
-                         "benchmarks/results)")
-    ly.set_defaults(fn=cmd_layout)
-
     t = sub.add_parser("traces", help="list available trace files")
     t.set_defaults(fn=cmd_traces)
+    return ap
 
+
+def main(argv=None) -> int:
+    ap = build_parser()
     args = ap.parse_args(argv)
     from fks_tpu.utils import place_compile_cache
     place_compile_cache()

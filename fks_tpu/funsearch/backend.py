@@ -342,14 +342,11 @@ class CodeEvaluator:
             return
         done.add(key)
         try:
-            from fks_tpu.obs.layout import default_spec
             from fks_tpu.obs.memory import record_footprint
             compiled = run.lower(stacked, self.state0).compile()
             record_footprint("evolve", f"pop={pop},cap={cap}", compiled,
                              mesh=self.mesh, recorder=rec,
-                             engine=self.engine,
-                             layout_key=getattr(run, "_fks_layout_key",
-                                                default_spec().key))
+                             engine=self.engine)
         except Exception:  # noqa: BLE001 — pricing is best-effort
             pass
 

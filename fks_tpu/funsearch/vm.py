@@ -806,8 +806,8 @@ def lower_fake_candidates(n: int, g: int, need: int, *, capacity: int = 256,
                           seed: int = 7, max_tries_factor: int = 12):
     """Generate + lower ``need`` FakeLLM candidates to VM programs.
 
-    The shared candidate source for code-candidate throughput (bench.py's
-    ``codetput`` stage, ``cli scale --code-pop``): deterministic
+    The candidate source for code-candidate throughput (``cli scale
+    --code-pop``, ``__graft_entry__.py``): deterministic
     FakeLLM completions, template-filled, lowered via ``compile_policy``;
     junk/too-long candidates are skipped. Returns ``(progs, lower_seconds)``
     — per-candidate host lowering times ride along for the lowering-cost

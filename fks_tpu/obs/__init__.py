@@ -35,8 +35,7 @@ jitted code.
                   localization across engines (``cli trace-diff``)
 - ``exporter``  — OpenMetrics text export + heartbeat liveness
                   (``cli export-metrics`` / ``cli watch``)
-- ``compare``   — cross-run regression gating (``cli compare``,
-                  ``bench.py --gate``)
+- ``compare``   — cross-run regression gating (``cli compare``)
 - ``profiler``  — per-stage device-time attribution, a view of the
                   spans: each stage opens one span and adds the compile
                   split + occupancy (``device_profile`` metrics); enabled,
@@ -45,13 +44,9 @@ jitted code.
                   baselines, SLO burn rates (``cli trends``)
 - ``memory``    — executable-footprint ledger, watermark sampler, leak
                   sentinel + drills (``cli mem``, ``fks_mem_*`` gauges)
-- ``layout``    — declarative LayoutSpec for the three batchable axes,
-                  the per-layout cost ledger, and the measured layout
-                  explorer (``cli layout``, ``fks_layout_*`` gauges)
 - ``workload``  — query fingerprinting, per-tenant accounting with SLO
                   burn + fairness, and the multi-tenant load generator
-                  (``cli loadgen`` / ``bench --stage loadgen``,
-                  ``fks_tenant_*`` gauges)
+                  (``cli loadgen``, ``fks_tenant_*`` gauges)
 """
 from fks_tpu.obs.compare import (
     DEFAULT_THRESHOLDS, Threshold, compare_runs, extract_metrics,
@@ -63,11 +58,6 @@ from fks_tpu.obs.exporter import (
 from fks_tpu.obs.history import (
     RunHistory, SLOConfig, record_slo_burn, resolve_auto_baseline, slo_burn,
 )
-from fks_tpu.obs.layout import (
-    LAYOUT_AXES, LAYOUT_COMPONENTS, LayoutLedger, LayoutSpec, default_spec,
-    explore_layouts, parse_layout_key, record_layout, rollup_layouts,
-    tag_layout, valid_layouts,
-)
 from fks_tpu.obs.ledger import EvolutionLedger
 from fks_tpu.obs.memory import (
     LEAK_LOOPS, MEMORY_COMPONENTS, NULL_SAMPLER, FootprintLedger,
@@ -75,7 +65,7 @@ from fks_tpu.obs.memory import (
     live_array_stats, record_footprint, rollup, run_drill,
 )
 from fks_tpu.obs.profiler import (
-    NULL_PROFILER, StageProfiler, profile_launch,
+    NULL_PROFILER, StageProfiler,
 )
 from fks_tpu.obs.recorder import (
     NULL, FlightRecorder, NullRecorder, get_recorder, recording,
@@ -107,30 +97,24 @@ from fks_tpu.obs.workload import (
 
 __all__ = [
     "DEFAULT_TENANT", "DEFAULT_THRESHOLDS", "FLAG_INF", "FLAG_NAN",
-    "FLAG_RANGE", "LAYOUT_AXES", "LAYOUT_COMPONENTS", "LEAK_LOOPS",
-    "LOADGEN_MODES", "MEMORY_COMPONENTS",
+    "FLAG_RANGE", "LEAK_LOOPS", "LOADGEN_MODES", "MEMORY_COMPONENTS",
     "NULL", "NULL_PROFILER", "NULL_SAMPLER", "CompileWatcher",
-    "EvolutionLedger", "FlightRecorder", "FootprintLedger", "LayoutLedger",
-    "LayoutSpec", "LeakSentinel",
+    "EvolutionLedger", "FlightRecorder", "FootprintLedger", "LeakSentinel",
     "NullRecorder", "ParitySentinel", "QueryFingerprinter", "RunHistory",
     "SLOConfig", "SpanLog", "SpanRecord", "StageProfiler",
     "TenantAccountant", "TenantLoad",
     "Threshold", "WatermarkSampler", "align_traces", "candidate_trace_diff",
     "check_result", "combined_flags", "compare_runs", "default_make_pods",
-    "default_spec", "describe_flags", "device_snapshot", "explore_layouts",
-    "extract_metrics",
+    "describe_flags", "device_snapshot", "extract_metrics",
     "extract_trace", "footprint_of", "format_comparison", "format_diff",
     "get_recorder", "has_regression", "health_line", "http_client",
     "jain_fairness", "leak_fence", "live_array_stats", "mesh_snapshot",
-    "normalize_memory_stats", "parse_layout_key", "parse_tenant_spec",
-    "parse_threshold_overrides", "profile_launch", "record_devices",
-    "record_footprint", "record_layout", "record_mesh", "record_slo_burn",
-    "recording",
-    "render_report", "resolve_auto_baseline", "rollup", "rollup_layouts",
-    "run_drill",
+    "normalize_memory_stats", "parse_tenant_spec",
+    "parse_threshold_overrides", "record_devices",
+    "record_footprint", "record_mesh", "record_slo_burn", "recording",
+    "render_report", "resolve_auto_baseline", "rollup", "run_drill",
     "run_health", "run_loadgen", "service_client", "slo_burn", "span",
-    "span_path", "sparkline", "tag_layout", "tenant_of", "to_openmetrics",
-    "trace_diff", "valid_layouts",
+    "span_path", "sparkline", "tenant_of", "to_openmetrics", "trace_diff",
     "watch", "watch_compiles",
     "TraceContext", "activate_trace", "critical_path", "current_trace",
     "emit_span", "new_trace", "render_waterfall", "trace_ctx",

@@ -1,6 +1,6 @@
 """Cross-run regression gating: diff two runs, exit nonzero on regression.
 
-``cli compare BASELINE CANDIDATE`` (and ``bench.py --gate``) accept
+``cli compare BASELINE CANDIDATE`` accepts
 either flight-recorder run DIRECTORIES or bench JSONL FILES (the
 one-line headline contract or a ``round*_tpu.jsonl`` session log), pull
 a common metric vocabulary out of each, and judge the candidate against
@@ -52,55 +52,27 @@ DEFAULT_THRESHOLDS: Dict[str, Threshold] = {
     "parity_max_drift": Threshold(higher_is_better=False, abs_tol=1e-5),
     "watchdog_violations": Threshold(higher_is_better=False, abs_tol=0.0),
     "alerts": Threshold(higher_is_better=False, abs_tol=0.0),
-    # eval-budget allocation (bench stage_budget): pruned-vs-full device
-    # seconds per generation must not regress by more than 10%, and the
+    # eval-budget allocation (``bench_stage`` rows, stage "budget"):
+    # pruned-vs-full device seconds per generation must not regress by more than 10%, and the
     # pruned run's champion must keep matching the full run's (0/1 flag)
     "budget_speedup": Threshold(higher_is_better=True, rel=0.10),
     "budget_champion_match": Threshold(higher_is_better=True, abs_tol=0.0),
-    # large-cluster scale tier (bench stage_scale1k): 1k-node x 100k-pod
+    # large-cluster scale tier (stage "scale1k"): 1k-node x 100k-pod
     # completion throughput on the flat engine must not drop >10%
     "scale1k_events_per_sec": Threshold(higher_is_better=True, rel=0.10),
-    # champion serving (bench stage_serve): warm tail latency must not
+    # champion serving (stage "serve"): warm tail latency must not
     # inflate more than 25% (with a 2 ms noise floor — CPU timer jitter
     # at millisecond scale), and batched throughput must not drop >10%
     "serve_p99_ms": Threshold(higher_is_better=False, rel=0.25, abs_tol=2.0),
     "serve_qps": Threshold(higher_is_better=True, rel=0.10),
-    # mesh-sharded serving (bench stage_serve --devices): global
-    # throughput across the device mesh must not drop >10%, and the
-    # per-query upload volume (post-packing, snapshot-cache-discounted)
-    # must not regress — growth means packing broke or the cache stopped
-    # hitting (64-byte floor absorbs padding jitter at tiny shapes)
-    "serve_sharded_qps": Threshold(higher_is_better=True, rel=0.10),
-    "serve_h2d_bytes_per_query": Threshold(higher_is_better=False,
-                                           rel=0.0, abs_tol=64.0),
-    # causal tracing (bench stage_serve): per-request trace emission must
-    # stay within noise of the untraced service path — more than a
-    # 2-point absolute jump in overhead means the null/hot path grew a
-    # real cost (the value is already a percentage, so abs only)
-    "trace_overhead_pct": Threshold(higher_is_better=False, abs_tol=2.0),
-    # VM-native promotion (bench stage_promote): the zero-rebuild swap
-    # must stay a swap — transpile + pack + H2D only. Latency gets the
-    # serve_p99_ms treatment (25% rel with a 2 ms CPU-jitter floor);
-    # the swap's device traffic must not regress at all beyond a
-    # 64-byte padding-jitter floor — growth means program packing broke
-    "promotion_swap_ms": Threshold(higher_is_better=False, rel=0.25,
-                                   abs_tol=2.0),
-    "vm_swap_h2d_bytes": Threshold(higher_is_better=False,
-                                   rel=0.0, abs_tol=64.0),
-    # memory budgets (obs.memory / bench stages): the run's peak
-    # predicted device bytes and the largest executable's XLA scratch
+    # memory budgets (obs.memory): the run's peak predicted device bytes and the largest executable's XLA scratch
     # claim must not grow — one 4 KiB page of absolute floor absorbs
     # buffer-assignment jitter at tiny CPU shapes, any real growth gates
     "peak_device_bytes": Threshold(higher_is_better=False, rel=0.0,
                                    abs_tol=4096.0),
     "exe_temp_bytes": Threshold(higher_is_better=False, rel=0.0,
                                 abs_tol=4096.0),
-    # static pre-flight (bench stage_preflight): the fraction of the
-    # candidate stream rejected before sandbox/transpile must not drop
-    # more than 5 points — a drop means the analyzer stopped catching a
-    # junk class it used to catch (absolute: the rate is already a ratio)
-    "preflight_reject_rate": Threshold(higher_is_better=True, abs_tol=0.05),
-    # sustained multi-tenant load (bench stage_loadgen): throughput and
+    # sustained multi-tenant load (`cli loadgen`): throughput and
     # the Jain fairness index over per-tenant goodput must not drop,
     # tail latency and shed rate must not grow. qps/p99 get the serve
     # treatment; shed rate and fairness are already ratios, so absolute
@@ -112,25 +84,6 @@ DEFAULT_THRESHOLDS: Dict[str, Threshold] = {
     "loadgen_shed_rate": Threshold(higher_is_better=False, abs_tol=0.02),
     "loadgen_fairness_index": Threshold(higher_is_better=True,
                                         abs_tol=0.05),
-    # portfolio serving (bench stage_portfolio): routed multi-champion
-    # throughput through the shared slot-vmapped executable must not
-    # drop >10%, and the mid-traffic slot promotion must stay a table
-    # upload — same latency treatment as the single-slot swap (25% rel
-    # with a 2 ms CPU-jitter floor)
-    "portfolio_qps": Threshold(higher_is_better=True, rel=0.10),
-    "portfolio_slot_swap_ms": Threshold(higher_is_better=False, rel=0.25,
-                                        abs_tol=2.0),
-    # layout explorer (bench stage_layout): best-measured-over-default
-    # steady ratio must not drop more than 10 points (a drop means the
-    # default layout got relatively worse, or the explorer stopped
-    # finding the better layout it used to find), and the best layout's
-    # padded-lane waste must not grow more than 5 points — both are
-    # already ratios, so absolute tolerances absorb single-host
-    # time-slicing jitter on the dryrun mesh
-    "layout_best_over_default": Threshold(higher_is_better=True,
-                                          abs_tol=0.10),
-    "layout_pad_waste_frac": Threshold(higher_is_better=False,
-                                       abs_tol=0.05),
 }
 
 
@@ -165,19 +118,14 @@ def _from_run_dir(run_dir: str) -> Dict[str, float]:
         for key in ("evals_per_sec", "code_evals_per_sec",
                     "budget_speedup", "budget_champion_match",
                     "scale1k_events_per_sec", "serve_qps",
-                    "serve_sharded_qps", "preflight_reject_rate",
-                    "loadgen_qps", "loadgen_fairness_index",
-                    "portfolio_qps", "layout_best_over_default"):
+                    "loadgen_qps", "loadgen_fairness_index"):
             v = _num(m.get(key))
             if v is not None:
                 out[key] = max(out.get(key, 0.0), v)
-        # latency/upload volume/trace cost: best (lowest) observation,
-        # mirroring serve_qps's max
-        for key in ("serve_p99_ms", "serve_h2d_bytes_per_query",
-                    "trace_overhead_pct", "promotion_swap_ms",
-                    "vm_swap_h2d_bytes", "loadgen_p99_ms",
-                    "loadgen_shed_rate", "portfolio_slot_swap_ms",
-                    "layout_pad_waste_frac"):
+        # latency / shed rate: best (lowest) observation, mirroring
+        # serve_qps's max
+        for key in ("serve_p99_ms", "loadgen_p99_ms",
+                    "loadgen_shed_rate"):
             v = _num(m.get(key))
             if v is not None:
                 out[key] = min(out.get(key, v), v)
@@ -223,14 +171,9 @@ def _from_jsonl(path: str, allow_stale: bool = False) -> Dict[str, float]:
                     "compile_seconds", "best_score", "median_score",
                     "parity_max_drift", "budget_speedup",
                     "budget_champion_match", "scale1k_events_per_sec",
-                    "serve_p99_ms", "serve_qps", "serve_sharded_qps",
-                    "serve_h2d_bytes_per_query", "preflight_reject_rate",
-                    "trace_overhead_pct", "promotion_swap_ms",
-                    "vm_swap_h2d_bytes", "peak_device_bytes",
+                    "serve_p99_ms", "serve_qps", "peak_device_bytes",
                     "exe_temp_bytes", "loadgen_qps", "loadgen_p99_ms",
-                    "loadgen_shed_rate", "loadgen_fairness_index",
-                    "portfolio_qps", "portfolio_slot_swap_ms",
-                    "layout_best_over_default", "layout_pad_waste_frac"):
+                    "loadgen_shed_rate", "loadgen_fairness_index"):
             v = _num(rec.get(key))
             if v is None:
                 continue
@@ -242,10 +185,7 @@ def _from_jsonl(path: str, allow_stale: bool = False) -> Dict[str, float]:
                     and key in ("peak_device_bytes", "exe_temp_bytes")):
                 continue
             if key in ("compile_seconds", "serve_p99_ms",
-                       "serve_h2d_bytes_per_query", "trace_overhead_pct",
-                       "promotion_swap_ms", "vm_swap_h2d_bytes",
-                       "loadgen_p99_ms", "loadgen_shed_rate",
-                       "portfolio_slot_swap_ms"):
+                       "loadgen_p99_ms", "loadgen_shed_rate"):
                 out[key] = min(out.get(key, v), v)
             elif key in ("peak_device_bytes", "exe_temp_bytes"):
                 # peak metrics: the high-water mark across records

@@ -414,38 +414,6 @@ def to_openmetrics(run_dir: str) -> str:
             "1 when the fenced loop stayed within drift tolerance").add(
             1 if m.get("ok") else 0, run_id=run_id, loop=loop)
 
-    # per-layout cost ledger (fks_tpu.obs.layout): the roll-up per
-    # (workload, mesh, layout) — pad waste and lane-step occupancy of
-    # every layout the run exercised, plus the explorer's latest
-    # steady-seconds probe per mesh shape
-    layout_rows = [m for m in metrics if m.get("kind") == "layout_ledger"]
-    if layout_rows:
-        from fks_tpu.obs.layout import rollup_layouts  # deferred
-        for a in rollup_layouts(
-                layout_rows,
-                footprints=[m for m in metrics
-                            if m.get("kind") == "memory_footprint"]):
-            labels = dict(run_id=run_id,
-                          workload=a["workload_key"] or "-",
-                          mesh=a["mesh_layout"] or "unsharded",
-                          layout=a["layout_key"])
-            fam("layout_pad_waste_fraction", "gauge",
-                "worst padded-lane waste fraction recorded under this "
-                "layout").add(a["pad_waste_fraction_max"], **labels)
-            fam("layout_occupancy", "gauge",
-                "real / launched lane-steps under this layout").add(
-                a["occupancy"], **labels)
-    latest_probe: Dict[str, dict] = {}
-    for m in (m for m in metrics if m.get("kind") == "layout_probe"):
-        latest_probe[str(m.get("mesh_shape", "?"))] = m
-    for shape in sorted(latest_probe):
-        m = latest_probe[shape]
-        fam("layout_probe_seconds", "gauge",
-            "best warm steady seconds measured for this mesh shape by "
-            "the layout explorer").add(
-            m.get("steady_seconds"), run_id=run_id, mesh=shape,
-            layout=m.get("layout_key"))
-
     # per-request latency histogram with trace-id EXEMPLARS: each bucket
     # cites the slowest request that landed in it, so a fat-tail bucket
     # on a dashboard links straight to the ``cli spans --trace`` waterfall

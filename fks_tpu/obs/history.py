@@ -51,11 +51,7 @@ TREND_METRICS = (
     "budget_speedup", "peak_device_bytes", "exe_temp_bytes",
     "loadgen_qps", "loadgen_p99_ms", "loadgen_shed_rate",
     "loadgen_fairness_index",
-    "layout_best_over_default", "layout_pad_waste_frac",
 )
-
-#: filename of the measured-layout prior store inside a history root
-LAYOUTS_NAME = "layouts.json"
 
 
 # ------------------------------------------------------------------ index
@@ -175,50 +171,6 @@ class RunHistory:
                 f.write(json.dumps(e) + "\n")
         os.replace(tmp, path)
         return path
-
-    # ----- measured layout priors (obs.layout.explore_layouts)
-
-    def _layouts_path(self) -> str:
-        return os.path.join(self.root, LAYOUTS_NAME)
-
-    def _load_layouts(self) -> Dict[str, Any]:
-        try:
-            with open(self._layouts_path()) as f:
-                doc = json.load(f)
-        except (OSError, json.JSONDecodeError):
-            return {}
-        return doc if isinstance(doc, dict) else {}
-
-    def record_layout_prior(self, workload_key: str, mesh_shape: str,
-                            layout_key: str,
-                            metrics: Optional[Dict[str, Any]] = None
-                            ) -> str:
-        """Persist the best MEASURED layout for (workload_key,
-        mesh_shape) — what ``obs.layout.explore_layouts`` found — into
-        ``layouts.json`` under the root; atomic replace, newest
-        measurement wins. Returns the store path. The future layout
-        autotuner reads this back (``layout_prior``) to seed its search
-        instead of re-probing from scratch."""
-        doc = self._load_layouts()
-        doc[f"{workload_key}@{mesh_shape}"] = {
-            "workload_key": workload_key,
-            "mesh_shape": str(mesh_shape),
-            "layout_key": layout_key,
-            **(metrics or {}),
-        }
-        path = self._layouts_path()
-        tmp = path + ".tmp"
-        with open(tmp, "w") as f:
-            json.dump(doc, f, indent=2, sort_keys=True)
-            f.write("\n")
-        os.replace(tmp, path)
-        return path
-
-    def layout_prior(self, workload_key: str, mesh_shape: str
-                     ) -> Optional[Dict[str, Any]]:
-        """The stored best-layout record for (workload_key, mesh_shape),
-        or None when never measured."""
-        return self._load_layouts().get(f"{workload_key}@{mesh_shape}")
 
     # ----- timelines & trends
 

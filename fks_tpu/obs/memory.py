@@ -26,8 +26,8 @@ Python-static-flag convention, pinned as ``flat_step/mem_sampled``):
   count/bytes around N iterations of a hot loop (serve batches, VM
   ``swap_program``, promotion cycles, evolve generations) and records a
   ``leak_check`` verdict against a drift tolerance. Two deterministic
-  drills (``vm_swap_leak``, ``snapshot_cache_bound``) back the
-  ``memory_gate`` in tools/run_full_suite.py.
+  drills (``vm_swap_leak``, ``snapshot_cache_bound``) run under
+  ``cli mem --drill``.
 
 Read back by ``cli mem`` (footprint ladder + watermark table), the
 ``cli report`` memory section, and the ``fks_mem_*`` OpenMetrics gauges.
@@ -100,7 +100,7 @@ def footprint_of(compiled: Any) -> Optional[Dict[str, int]]:
 
 
 def mesh_layout_label(mesh: Any) -> str:
-    """A mesh's layout as a stable comparison key: ``"pop=4,scn=2"``
+    """A mesh's layout as a stable comparison key: ``"dcn=2,pop=4"``
     from its axis shape (empty for single-device / no mesh)."""
     if mesh is None:
         return ""
@@ -570,7 +570,7 @@ def drill_snapshot_cache_bound(max_bytes: int = 0,
 
 
 #: drill name -> callable returning {"ok": bool, ...} — the ``cli mem
-#: --drill`` / run_full_suite ``memory_gate`` dispatch table
+#: --drill`` dispatch table
 DRILLS: Dict[str, Callable[..., Dict[str, Any]]] = {
     "vm_swap_leak": drill_vm_swap_leak,
     "snapshot_cache_bound": drill_snapshot_cache_bound,

@@ -107,8 +107,8 @@ class StageProfiler:
     callers, zero filesystem writes, zero effect on lowering. The
     ``recorder`` (default: the process-wide active flight recorder)
     receives one ``device_profile`` metric per completed stage; in-memory
-    ``records`` accumulate regardless, so recorder-less tools
-    (tools/profile_step.py) can read the attribution directly.
+    ``records`` accumulate regardless, so recorder-less callers can
+    read the attribution directly.
     """
 
     def __init__(self, enabled: bool = True, scope: str = "evolve",
@@ -292,37 +292,3 @@ def _finish_utilization(rec: Dict[str, Any]) -> None:
 #: profiling never needs an ``if profiler:`` guard (same pattern as
 #: ``obs.recorder.NULL``)
 NULL_PROFILER = StageProfiler(enabled=False, scope="null")
-
-
-def profile_launch(fn, *args, name: str = "launch",
-                   profiler: Optional[StageProfiler] = None,
-                   reps: int = 1, **fields):
-    """Warmup-then-measure attribution for one jitted launch — the shared
-    code path behind tools/profile_step.py and bench.py's throughput
-    stages. The first call runs in a ``{name}:compile`` stage (its
-    compile split read off the watcher), then ``reps`` fenced calls in a
-    ``{name}:steady`` stage. Returns ``(result, record)`` where record
-    carries first/compile/best-steady seconds plus the two stage
-    records."""
-    prof = profiler if profiler is not None else NULL_PROFILER
-    with prof.stage(f"{name}:compile", **fields) as hc:
-        out = hc.sync(fn(*args))
-    best = None
-    with prof.stage(f"{name}:steady", reps=int(reps), **fields) as hs:
-        for _ in range(max(1, int(reps))):
-            t0 = time.perf_counter()
-            out = hs.sync(fn(*args))
-            dt = time.perf_counter() - t0
-            best = dt if best is None or dt < best else best
-    record = {
-        "name": name,
-        "reps": int(reps),
-        "best_seconds": best,
-    }
-    if hc.record is not None:  # enabled profiler: fold in the compile split
-        record.update(
-            first_call_seconds=hc.record["wall_seconds"],
-            compile_seconds=hc.record["compile_seconds"],
-            compile_count=hc.record["compile_count"],
-            steady_total_seconds=hs.record["wall_seconds"])
-    return out, record

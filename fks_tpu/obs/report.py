@@ -356,61 +356,6 @@ def _memory_section(metrics: List[Dict[str, Any]]) -> List[str]:
     return lines
 
 
-def _layout_section(metrics: List[Dict[str, Any]]) -> List[str]:
-    """Layout observability (fks_tpu.obs.layout): the per-layout cost
-    roll-up — one row per (workload_key, mesh_layout, layout_key) with
-    pad waste, lane-step occupancy, cost-analysis bytes and the
-    predicted HBM claim joined from the run's footprint records — plus
-    the explorer's probe table and best-vs-default verdict when the run
-    swept layouts."""
-    rows = [m for m in metrics if m.get("kind") == "layout_ledger"]
-    probes = [m for m in metrics if m.get("kind") == "layout_probe"]
-    if not (rows or probes):
-        return []
-    lines = ["layouts (obs.layout):"]
-    if rows:
-        from fks_tpu.obs.layout import rollup_layouts  # deferred
-        fps = [m for m in metrics if m.get("kind") == "memory_footprint"]
-        aggs = rollup_layouts(rows, footprints=fps)
-        tab = [{
-            "workload": a["workload_key"] or "-",
-            "mesh": a["mesh_layout"] or "unsharded",
-            "layout": a["layout_key"],
-            "rows": a["rows"],
-            "pad_waste": _num(a["pad_waste_fraction_max"], 4),
-            "occupancy": _num(a["occupancy"], 4),
-            "hbm_MiB": ("" if "predicted_hbm_bytes" not in a else
-                        _num(a["predicted_hbm_bytes"] / 2**20, 2)),
-            "steady_s": ("" if "steady_seconds" not in a else
-                         _num(a["steady_seconds"], 4)),
-        } for a in aggs]
-        lines.append(f"  ledger roll-up ({len(aggs)} layouts):")
-        lines += ["  " + ln for ln in _fmt_table(
-            tab, ["workload", "mesh", "layout", "rows", "pad_waste",
-                  "occupancy", "hbm_MiB", "steady_s"])]
-    if probes:
-        tab = [{
-            "mesh": p.get("mesh_shape", "?"),
-            "layout": p.get("layout_key", "?"),
-            "steady_s": _num(float(p.get("steady_seconds", 0.0)), 6),
-            "compile_s": _num(float(p.get("first_call_seconds", 0.0)), 2),
-            "pad_waste": _num(float(p.get("pad_waste_fraction", 0.0)), 4),
-            "parity": _num(float(p.get("parity_max_abs", 0.0)), 8),
-        } for p in probes]
-        lines.append(f"  explorer probes ({len(probes)}):")
-        lines += ["  " + ln for ln in _fmt_table(
-            tab, ["mesh", "layout", "steady_s", "compile_s", "pad_waste",
-                  "parity"])]
-        best = min(probes,
-                   key=lambda p: float(p.get("steady_seconds", 0.0)))
-        lines.append(f"  best measured: {best.get('mesh_shape')} "
-                     f"{best.get('layout_key')} at "
-                     f"{float(best.get('steady_seconds', 0.0)):.6f}s "
-                     "steady (single-process CPU meshes time-slice one "
-                     "host; ranks are relative)")
-    return lines
-
-
 def _tenant_section(metrics: List[Dict[str, Any]]) -> List[str]:
     """Per-tenant accounting (fks_tpu.obs.workload): latest tenant_stats
     row per tenant — request/shed/expired/degraded counters, EWMA and
@@ -573,8 +518,7 @@ def render_report(run_dir: str) -> str:
                     _device_profile_section(metrics), _slo_section(metrics),
                     _tenant_section(metrics),
                     _portfolio_section(metrics, events),
-                    _memory_section(metrics), _layout_section(metrics),
-                    _compile_section(events),
+                    _memory_section(metrics), _compile_section(events),
                     _span_section(events)):
         if section:
             lines.append("")

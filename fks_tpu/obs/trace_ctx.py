@@ -26,8 +26,7 @@ Ids are cheap (a per-process random prefix plus a counter): every serve
 request and every batch gets one with or without a recorder, and
 ``current()`` is a single thread-local read.
 
-Reconstruction (the ``cli spans`` viewer and the run_full_suite trace
-gate) lives here too: group ``trace_span`` events by trace id, build
+Reconstruction (the ``cli spans`` viewer) lives here too: group ``trace_span`` events by trace id, build
 the parent/child tree, render per-request latency waterfalls, and
 compute the critical path of an evolve generation (device-idle vs
 LLM-idle seconds — the numbers the async-island ROADMAP item needs).

@@ -31,7 +31,7 @@ Storage layout (round 3): the four per-item fields live as COLUMNS of one
 ``i32[cap, 4]`` matrix, so every heap mutation is a single row-gather plus
 a single row-scatter instruction instead of four of each. On TPU,
 per-lane-indexed gathers/scatters in a vmapped loop body cost serialized
-latency PER INSTRUCTION (~35 us each, tools/probe_ops.py / PROFILE.md), so
+latency PER INSTRUCTION (~35 us each, PROFILE.md), so
 instruction count -- not bytes -- is the price; rows cut it 4x.
 """
 from __future__ import annotations

@@ -44,7 +44,6 @@ from fks_tpu.utils.segments import segment_budget
 
 POP_AXIS = "pop"
 DCN_AXIS = "dcn"
-SCN_AXIS = "scn"  # scenario axis of a layout_mesh (obs.layout specs)
 
 
 def population_mesh(devices: Optional[Sequence] = None) -> Mesh:
@@ -105,50 +104,9 @@ def hybrid_population_mesh(devices: Optional[Sequence] = None,
     return Mesh(devices.reshape(slices, n // slices), (DCN_AXIS, POP_AXIS))
 
 
-def layout_mesh(devices: Optional[Sequence] = None,
-                scenario_shards: int = 1) -> Mesh:
-    """The mesh for a declared layout (fks_tpu.obs.layout.LayoutSpec):
-    ``scenario_shards=1`` is the default layout's 1-D population mesh;
-    ``scenario_shards>1`` factorizes the devices into a 2-D
-    ``("pop", "scn")`` mesh — candidates shard the outer axis, scenarios
-    the inner one, so the per-scenario all-gather rides the fastest
-    (innermost) fabric while the elite gather crosses candidate shards
-    exactly as on the 1-D mesh."""
-    devices = np.asarray(devices if devices is not None else jax.devices())
-    s = int(scenario_shards)
-    if s <= 1:
-        return population_mesh(devices)
-    if devices.size % s:
-        raise ValueError(f"{devices.size} devices not divisible into "
-                         f"{s} scenario shards")
-    return Mesh(devices.reshape(devices.size // s, s), (POP_AXIS, SCN_AXIS))
-
-
-def _resolve_layout(layout, *, scenarios: bool = False, seg_steps: int = 0,
-                    scenario_shardable: bool = False):
-    """Resolve an entry point's ``layout`` argument to a LayoutSpec:
-    None means the historical hard-coded behavior (the default spec —
-    bit-identical lowering, jaxpr-pinned). Entry points without a
-    scenario axis reject specs that shard scenarios."""
-    from fks_tpu.obs.layout import LayoutSpec, default_spec
-    if layout is None:
-        return default_spec(scenarios=scenarios, seg_steps=seg_steps)
-    if not isinstance(layout, LayoutSpec):
-        raise TypeError(f"layout must be a LayoutSpec or None, got "
-                        f"{type(layout).__name__}")
-    if "scenarios" in layout.shard and not scenario_shardable:
-        raise ValueError(
-            f"layout {layout.key!r} shards the scenario axis, but this "
-            "entry point has no scenario axis (mesh-sharded SUITE "
-            "evaluation lives at fks_tpu.scenarios.robust."
-            "make_sharded_suite_eval)")
-    return layout
-
-
 def _pop_axes(mesh: Mesh):
     """The axes the population is sharded over, in mesh order: ("pop",) on
-    a 1-D mesh, ("dcn", "pop") on a hybrid mesh. A layout_mesh's "scn"
-    axis is never a population axis."""
+    a 1-D mesh, ("dcn", "pop") on a hybrid mesh."""
     return tuple(a for a in mesh.axis_names if a in (DCN_AXIS, POP_AXIS))
 
 
@@ -201,8 +159,7 @@ def pad_stats(real_count: int, num_shards) -> dict:
     itself or a shard count): how many of the launched lanes are padding
     duplicates of the last candidate rather than real work.
     ``pad_waste_fraction`` is the device-time share spent on pad lanes —
-    the number the flight recorder's mesh snapshot reports
-    (fks_tpu.obs.telemetry.mesh_snapshot)."""
+    the number the flight recorder's ``mesh_snapshot`` reports."""
     if isinstance(num_shards, Mesh):
         num_shards = _num_shards(num_shards)
     real = int(real_count)
@@ -220,7 +177,7 @@ def occupancy_stats(real_count: int, num_shards, scenarios: int = 1,
     """``pad_stats`` extended with the other two batch axes a launch
     multiplies over — scenarios (``scenarios.suite`` vmap) and trace
     segments (the segmented runner's host loop) — for the device-time
-    attribution profiler (fks_tpu.obs.profiler): ``launched_lane_steps``
+    attribution profiler (``StageProfiler``): ``launched_lane_steps``
     is the total lane-dispatch count, ``real_lane_steps`` the share that
     was real candidates. Pad waste is per-lane, so it is unchanged by
     the extra axes; they scale the absolute accounting only."""
@@ -293,7 +250,7 @@ def serve_sharding(mesh: Mesh) -> NamedSharding:
     return NamedSharding(mesh, P(_pop_axes(mesh)))
 
 
-def make_sharded_serve_fn(serve_fn, mesh: Mesh, layout=None):
+def make_sharded_serve_fn(serve_fn, mesh: Mesh):
     """Wrap a lane-batched serve pipeline ``(pods, ktable, state0) ->
     SimResult`` in ``shard_map`` over the pop axes: every argument and
     result pytree shards on its leading lane axis. The pipeline contains
@@ -301,21 +258,14 @@ def make_sharded_serve_fn(serve_fn, mesh: Mesh, layout=None):
     ``run_batched_lanes`` while_loop, so per-device trip counts are
     independent and a short lane never stalls a long one across the mesh.
     ``check_vma=False`` for the same engine-internal reason as the
-    population entry points (see NOTE above). The returned callable is
-    tagged with the layout's canonical key and the wiring lands one
-    ``layout_ledger`` row (component "serve"); the serve engine's
-    per-batch occupancy rows join it at eval time."""
-    from fks_tpu.obs.layout import record_layout, tag_layout
-    spec = _resolve_layout(layout)
+    population entry points (see NOTE below)."""
     axes = _pop_axes(mesh)
-    fn = jax.shard_map(serve_fn, mesh=mesh,
-                   in_specs=(P(axes), P(axes), P(axes)),
-                   out_specs=P(axes), check_vma=False)
-    record_layout("serve", spec, mesh=mesh)
-    return tag_layout(fn, spec.key)
+    return jax.shard_map(serve_fn, mesh=mesh,
+                         in_specs=(P(axes), P(axes), P(axes)),
+                         out_specs=P(axes), check_vma=False)
 
 
-def make_sharded_vm_serve_fn(serve_fn, mesh: Mesh, layout=None):
+def make_sharded_vm_serve_fn(serve_fn, mesh: Mesh):
     """``make_sharded_serve_fn`` for the VM-native serving pipeline
     ``(program, pods, ktable, state0) -> SimResult``: the batch axes
     shard exactly as before, while the champion's packed ``VMProgram``
@@ -323,19 +273,14 @@ def make_sharded_vm_serve_fn(serve_fn, mesh: Mesh, layout=None):
     — so every device holds the full register program and lanes stay
     collective-free. One executable per (global_lanes, pod_bucket,
     program_capacity) then serves EVERY champion of that capacity bucket
-    across the whole mesh. Layout-tagged like ``make_sharded_serve_fn``
-    (component "vm_serve")."""
-    from fks_tpu.obs.layout import record_layout, tag_layout
-    spec = _resolve_layout(layout)
+    across the whole mesh."""
     axes = _pop_axes(mesh)
-    fn = jax.shard_map(serve_fn, mesh=mesh,
-                   in_specs=(P(), P(axes), P(axes), P(axes)),
-                   out_specs=P(axes), check_vma=False)
-    record_layout("vm_serve", spec, mesh=mesh)
-    return tag_layout(fn, spec.key)
+    return jax.shard_map(serve_fn, mesh=mesh,
+                         in_specs=(P(), P(axes), P(axes), P(axes)),
+                         out_specs=P(axes), check_vma=False)
 
 
-def make_sharded_portfolio_serve_fn(serve_fn, mesh: Mesh, layout=None):
+def make_sharded_portfolio_serve_fn(serve_fn, mesh: Mesh):
     """``make_sharded_vm_serve_fn`` for the portfolio serving pipeline
     ``(slot_tables, slots, pods, ktable, state0) -> SimResult``: the
     stacked per-slot program tables (argument 0) are REPLICATED exactly
@@ -343,16 +288,11 @@ def make_sharded_portfolio_serve_fn(serve_fn, mesh: Mesh, layout=None):
     portfolio, so any lane on any device can dispatch to any slot — while
     the per-lane slot indices (argument 1) shard with the batch axes they
     index. Lanes stay collective-free: slot dispatch is a local gather
-    into the replicated tables. Layout-tagged with component
-    "portfolio_serve"."""
-    from fks_tpu.obs.layout import record_layout, tag_layout
-    spec = _resolve_layout(layout)
+    into the replicated tables."""
     axes = _pop_axes(mesh)
-    fn = jax.shard_map(serve_fn, mesh=mesh,
-                   in_specs=(P(), P(axes), P(axes), P(axes), P(axes)),
-                   out_specs=P(axes), check_vma=False)
-    record_layout("portfolio_serve", spec, mesh=mesh)
-    return tag_layout(fn, spec.key)
+    return jax.shard_map(serve_fn, mesh=mesh,
+                         in_specs=(P(), P(axes), P(axes), P(axes), P(axes)),
+                         out_specs=P(axes), check_vma=False)
 
 
 def _global_results(run, state0, params_shard, axes):
@@ -403,37 +343,10 @@ def _engine_runner(workload, param_policy, cfg, engine):
             mod.initial_state(workload, cfg))
 
 
-def _layout_eval_wrapper(jitted, component: str, spec, mesh: Mesh,
-                         scenarios: int = 1, segments: int = 1):
-    """Host-side wrap of a jitted ``(params, real_count=None)`` entry
-    point: one ``layout_ledger`` row per launch (the eval-time pad/
-    occupancy accounting — identical repeats dedupe in the ledger, so a
-    steady generation loop costs one row until its population size
-    changes padding). The jitted program is untouched — recording is
-    pure host work before dispatch — and its AOT seam is forwarded
-    (``.lower``), so the default layout still lowers bit-identically
-    (the ``sharded_eval/default_layout`` jaxpr pin)."""
-    from fks_tpu.obs.layout import record_layout, tag_layout
-
-    record_layout(component, spec, mesh=mesh)
-
-    def run(params, real_count=None):
-        real = (lead_axis_size(params) if real_count is None
-                else int(real_count))
-        record_layout(component, spec, mesh=mesh, real_count=real,
-                      scenarios=scenarios, segments=segments)
-        return jitted(params, real_count)
-
-    run.lower = jitted.lower
-    run._fks_jitted = jitted
-    return tag_layout(run, spec.key)
-
-
 def make_sharded_eval(workload: Workload, mesh: Mesh,
                       param_policy: ParamPolicyFn = parametric.score,
                       cfg: SimConfig = SimConfig(),
-                      elite_k: int = 8, engine: str = "exact",
-                      layout=None):
+                      elite_k: int = 8, engine: str = "exact"):
     """Build ``eval(params[C, F], real_count) -> (scores[C], elite_idx[K],
     elite_scores[K])``.
 
@@ -449,14 +362,7 @@ def make_sharded_eval(workload: Workload, mesh: Mesh,
     per-candidate TraceBuffer pytree, sharded over ``pop`` like the scores
     (a ``P(axes)`` out_spec prefix over the whole subtree). Existing
     callers index the first three slots, so the extension is opt-in.
-
-    ``layout`` declares the axis mapping (fks_tpu.obs.layout.LayoutSpec);
-    None is the default spec — the behavior above, lowered bit-identically
-    and jaxpr-pinned. This entry point has no scenario axis, so specs
-    sharding scenarios are rejected. Wiring and every launch land
-    ``layout_ledger`` rows (component "eval").
     """
-    spec = _resolve_layout(layout)
     run, state0 = _engine_runner(workload, param_policy, cfg, engine)
     axes = _pop_axes(mesh)
     out_specs = (P(axes), P(), P()) + ((P(axes),) if cfg.decision_trace else ())
@@ -481,7 +387,7 @@ def make_sharded_eval(workload: Workload, mesh: Mesh,
             real_count = params.shape[0]
         return shard_eval(params, jnp.asarray(real_count, jnp.int32))
 
-    return _layout_eval_wrapper(jax.jit(sharded_eval), "eval", spec, mesh)
+    return jax.jit(sharded_eval)
 
 
 def make_sharded_generation_step(workload: Workload, mesh: Mesh,
@@ -489,8 +395,7 @@ def make_sharded_generation_step(workload: Workload, mesh: Mesh,
                                  cfg: SimConfig = SimConfig(),
                                  elite_k: int = 4,
                                  noise: float = 0.05,
-                                 engine: str = "exact",
-                                 layout=None):
+                                 engine: str = "exact"):
     """One full on-device evolution generation for parametric populations:
     evaluate (sharded) -> all-gather fitness -> top-k elites -> mutate
     offspring from elites. This is the framework's "training step" — the
@@ -501,10 +406,8 @@ def make_sharded_generation_step(workload: Workload, mesh: Mesh,
     Returns ``step(params[C,F], key, real_count=None) -> (new_params[C,F],
     scores[C], elite_scores[K])``; both params arrays are sharded over
     ``pop``. Forward ``pad_population``'s ``real_count`` so pad duplicates
-    never win elite slots. Layout-tagged like ``make_sharded_eval``
-    (component "gen_step"; no scenario axis here either).
+    never win elite slots.
     """
-    spec = _resolve_layout(layout)
     run, state0 = _engine_runner(workload, param_policy, cfg, engine)
     axes = _pop_axes(mesh)
 
@@ -540,26 +443,13 @@ def make_sharded_generation_step(workload: Workload, mesh: Mesh,
             real_count = params.shape[0]
         return gen_step(params, key, jnp.asarray(real_count, jnp.int32))
 
-    from fks_tpu.obs.layout import record_layout, tag_layout
-    jitted = jax.jit(step)
-    record_layout("gen_step", spec, mesh=mesh)
-
-    def run_step(params, key, real_count=None):
-        real = (lead_axis_size(params) if real_count is None
-                else int(real_count))
-        record_layout("gen_step", spec, mesh=mesh, real_count=real)
-        return jitted(params, key, real_count)
-
-    run_step.lower = jitted.lower
-    run_step._fks_jitted = jitted
-    return tag_layout(run_step, spec.key)
+    return jax.jit(step)
 
 
 def make_sharded_code_eval(workload: Workload, mesh: Mesh,
                            cfg: SimConfig = SimConfig(),
                            elite_k: int = 8, engine: str = "exact",
-                           seg_steps: int = 0, on_segment=None,
-                           layout=None):
+                           seg_steps: int = 0, on_segment=None):
     """Build ``eval(stacked, real_count) -> (result, elite_idx[K],
     elite_scores[K])`` for STACKED VM code candidates — the code-candidate
     analogue of ``make_sharded_eval``.
@@ -587,27 +477,14 @@ def make_sharded_code_eval(workload: Workload, mesh: Mesh,
     path. ``on_segment`` (zero-arg callable) fires on
     the host after every segment dispatch — the flight recorder's segment
     counter; ignored on the single-dispatch path.
-
-    ``layout`` declares the axis mapping; None is the default spec with
-    the ``seg_steps`` argument folded in as its segment size. Passing a
-    spec whose ``seg_steps`` disagrees with a nonzero ``seg_steps``
-    argument is an error (one declaration, one truth); specs sharding
-    scenarios are rejected (no scenario axis here — see
-    fks_tpu.scenarios.robust.make_sharded_suite_eval).
     """
     from fks_tpu.funsearch import vm
     from fks_tpu.sim import get_engine
 
-    spec = _resolve_layout(layout, seg_steps=seg_steps)
-    if layout is not None and seg_steps and spec.seg_steps != seg_steps:
-        raise ValueError(
-            f"layout {spec.key!r} declares seg_steps={spec.seg_steps} but "
-            f"the seg_steps argument says {seg_steps}; declare it once")
-    seg_steps = spec.seg_steps
     mod = get_engine(engine)
     if seg_steps > 0 and hasattr(mod, "make_segmented_population_run"):
         return _make_segmented_code_eval(workload, mesh, cfg, elite_k, mod,
-                                         seg_steps, on_segment, spec)
+                                         seg_steps, on_segment)
 
     run = mod.make_population_run_fn(workload, vm.score, cfg)
     state0 = mod.initial_state(workload, cfg)
@@ -633,13 +510,12 @@ def make_sharded_code_eval(workload: Workload, mesh: Mesh,
             real_count = jax.tree_util.tree_leaves(stacked)[0].shape[0]
         return shard_eval(stacked, jnp.asarray(real_count, jnp.int32))
 
-    return _layout_eval_wrapper(jax.jit(sharded_eval), "code_eval", spec,
-                                mesh)
+    return jax.jit(sharded_eval)
 
 
 def _make_segmented_code_eval(workload: Workload, mesh: Mesh, cfg: SimConfig,
                               elite_k: int, mod, seg_steps: int,
-                              on_segment=None, spec=None):
+                              on_segment=None):
     """The segmented body of ``make_sharded_code_eval``: a host loop of
     jitted shard_map'd segments — ``flat.make_segmented_population_run``
     mirrored one level up, at the mesh. Per segment every shard advances
@@ -702,11 +578,7 @@ def _make_segmented_code_eval(workload: Workload, mesh: Mesh, cfg: SimConfig,
         return res, elite_idx, elite_scores
 
     state0 = mod.initial_state(workload, cfg)
-    from fks_tpu.obs.layout import record_layout, tag_layout
     from fks_tpu.obs.spans import span
-    if spec is None:
-        spec = _resolve_layout(None, seg_steps=seg_steps)
-    record_layout("code_eval", spec, mesh=mesh)
 
     def run(stacked, real_count=None):
         with span("mesh/shard_put"):
@@ -741,11 +613,7 @@ def _make_segmented_code_eval(workload: Workload, mesh: Mesh, cfg: SimConfig,
                 "sharded segmented runner exhausted its segment budget "
                 "with lanes still active — cond/step divergence in the "
                 "population engine")
-        # eval-time layout accounting: the segment count is only known
-        # here, after the host loop drained (dedupes on identical repeats)
-        record_layout("code_eval", spec, mesh=mesh,
-                      real_count=int(real_count), segments=segments)
         with span("mesh/finish"):
             return finish(bstate, jnp.asarray(real_count, jnp.int32))
 
-    return tag_layout(run, spec.key)
+    return run

@@ -17,7 +17,7 @@ proving each failure mode degrades gracefully.
 - ``faults``     — FaultPlan / KillSwitch / OutageBackend injection
                    primitives (pure host)
 - ``drills``     — the deterministic drill matrix (``cli pipeline
-                   --drill``, the run_full_suite promotion gate)
+                   --drill``)
 """
 from fks_tpu.pipeline.controller import (
     PromotionConfig, PromotionController, attempt_id, follow_ledger,

@@ -294,7 +294,7 @@ class PromotionController:
         unbatched exact reference: re-jitting it for the new champion
         would compile on the serving process, defeating the zero-compile
         swap. VM-vs-AOT score parity is instead guaranteed offline
-        (tests/test_vm_serve.py and the run_full_suite vm_serve_gate);
+        (tests/test_vm_serve.py);
         the replay still gates latency, SLO burn and the robust suite."""
         cfg = self.cfg
         queries = self.service.recent_queries(cfg.shadow_queries)

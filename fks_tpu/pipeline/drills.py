@@ -21,8 +21,8 @@ queue overload, device loss mid-batch, degrade-then-recover, SIGTERM
 drain, and WAL resume mid-generation.
 
 Everything is seeded and fault-driven — no timing races, no
-probabilities — so the matrix is a CI gate (``run_full_suite``), a CLI
-(``cli pipeline --drill``), and a slow-tier test, all from one function.
+probabilities — so the matrix is a CLI (``cli pipeline --drill``) and
+a slow-tier test, both from one function.
 Engines are cached per champion code so the matrix pays each XLA
 compile once.
 """
@@ -100,8 +100,8 @@ def run_drills(log: Callable[[str], None] = print,
                only: str = "") -> List[Dict[str, Any]]:
     """Run the whole matrix; one result dict per drill, ``ok`` per drill.
     ``only`` is a comma-separated list of name substrings — the CLI's
-    ``--only`` and the run_full_suite resilience gate run a subset
-    without paying for the rest of the matrix."""
+    ``--only`` runs a subset without paying for the rest of the
+    matrix."""
     from fks_tpu.resilience.drills import RESILIENCE_DRILLS
 
     stack = DrillStack()

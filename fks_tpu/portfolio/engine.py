@@ -58,7 +58,6 @@ class PortfolioEngine(VMServeEngine):
     coverage fallback keeps such champions on the AOT escape hatch."""
 
     is_portfolio = True
-    layout_component = "portfolio_serve"
 
     def __init__(self, champions: Sequence[ChampionSpec],
                  workload: Workload, *, n_slots: Optional[int] = None,
@@ -249,9 +248,6 @@ class PortfolioEngine(VMServeEngine):
                 fn = self._make_serve_fn(pod_bucket)
                 if self.mesh is not None:
                     fn = make_sharded_portfolio_serve_fn(fn, self.mesh)
-                from fks_tpu.obs.layout import default_spec
-                self._layout_key = getattr(fn, "_fks_layout_key",
-                                           default_spec().key)
                 slots0 = self._lane_put(np.zeros(lanes, np.int32))
                 example = ((self._prog_dev, slots0)
                            + self._example_batch(lanes, pod_bucket))
@@ -267,8 +263,7 @@ class PortfolioEngine(VMServeEngine):
             f"lanes={lanes},pods={pod_bucket},"
             f"cap={self.program_capacity},slots={self.n_slots}",
             compiled, mesh=self.mesh, recorder=self.recorder,
-            engine=self.engine_name, engine_kind=self.engine_kind,
-            layout_key=self._layout_key)
+            engine=self.engine_name, engine_kind=self.engine_kind)
         return compiled
 
     # ----- answering (slot threading)

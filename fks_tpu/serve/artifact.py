@@ -46,7 +46,6 @@ import numpy as np
 from fks_tpu import obs
 from fks_tpu.data.entities import ClusterArrays, Workload
 from fks_tpu.obs import trace_ctx
-from fks_tpu.obs.layout import record_layout
 from fks_tpu.obs.memory import record_footprint
 from fks_tpu.parallel.mesh import (
     lanes_per_device, make_sharded_serve_fn, num_shards, occupancy_stats,
@@ -543,9 +542,6 @@ class ServeEngine:
                 fn = self._make_serve_fn(pod_bucket)
                 if self.mesh is not None:
                     fn = make_sharded_serve_fn(fn, self.mesh)
-                from fks_tpu.obs.layout import default_spec
-                self._layout_key = getattr(fn, "_fks_layout_key",
-                                           default_spec().key)
                 example = self._example_batch(lanes, pod_bucket)
                 with warnings.catch_warnings():
                     # buckets whose SimResult cannot alias a donated
@@ -562,8 +558,7 @@ class ServeEngine:
         record_footprint("serve_aot", f"lanes={lanes},pods={pod_bucket}",
                          compiled, mesh=self.mesh, recorder=self.recorder,
                          engine=self.engine_name,
-                         engine_kind=self.engine_kind,
-                         layout_key=self._layout_key)
+                         engine_kind=self.engine_kind)
         return compiled
 
     def warmup(self, lane_buckets: Optional[Sequence[int]] = None,
@@ -775,16 +770,6 @@ class ServeEngine:
             res = jax.device_get(res)
             t_d2h.set(bytes=tree_h2d_bytes(res))
         self.last_batch_timing["dispatch_s"] += t_d2h.t1 - hs.span.t0
-        if self.recorder.enabled:
-            # eval-time layout ledger row: per-batch occupancy attributed
-            # to the serve layout key (the ledger dedupes equal rows)
-            record_layout(
-                getattr(self, "layout_component", None) or
-                ("vm_serve" if self.engine_kind == "vm" else "serve"),
-                getattr(self, "_layout_key", None) or
-                "shard[candidates]|vmap[candidates]|seg=0",
-                mesh=self.mesh, recorder=self.recorder,
-                **occupancy_stats(real, lanes))
         with obs.span("serve/chunk/extract", chunk=chunk,
                       real=len(idxs)) as t_ext:
             for lane, i in enumerate(idxs):

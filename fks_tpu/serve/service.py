@@ -10,8 +10,8 @@ engine. Two fronts ride on it: stdin/JSONL (``run_jsonl``) and a
 localhost-only HTTP listener (``run_http``); both are thin — the service
 is the library entrypoint.
 
-``selftest`` is the batched-vs-unbatched parity sweep the
-``run_full_suite`` serve gate (and ``cli serve --selftest``) runs.
+``selftest`` is the batched-vs-unbatched parity sweep ``cli serve
+--selftest`` runs.
 """
 from __future__ import annotations
 
@@ -632,8 +632,9 @@ def selftest(engine: ServeEngine, count: int = 8, pods_per_query: int = 4,
     The batched pass runs through a real ``ServeService`` (submit ->
     coalescer -> handler), not a bare ``answer_batch`` call, so every
     selftest request exercises — and, under a flight recorder, TRACES —
-    the same path production requests take (the run_full_suite trace
-    gate reconstructs a complete waterfall per request from this)."""
+    the same path production requests take (``cli spans
+    --check-complete`` reconstructs a complete waterfall per request
+    from this)."""
     base = engine.base_pods
     if not base:  # artifact pinned with an empty trace — synthesize
         base = [{"cpu_milli": 1 + i, "memory_mib": 1, "creation_time": i,

@@ -322,9 +322,6 @@ class VMServeEngine(ServeEngine):
                 fn = self._make_serve_fn(pod_bucket)
                 if self.mesh is not None:
                     fn = make_sharded_vm_serve_fn(fn, self.mesh)
-                from fks_tpu.obs.layout import default_spec
-                self._layout_key = getattr(fn, "_fks_layout_key",
-                                           default_spec().key)
                 example = ((self._prog_dev,)
                            + super()._example_batch(lanes, pod_bucket))
                 with warnings.catch_warnings():
@@ -340,8 +337,7 @@ class VMServeEngine(ServeEngine):
             "serve_vm",
             f"lanes={lanes},pods={pod_bucket},cap={self.program_capacity}",
             compiled, mesh=self.mesh, recorder=self.recorder,
-            engine=self.engine_name, engine_kind=self.engine_kind,
-            layout_key=self._layout_key)
+            engine=self.engine_name, engine_kind=self.engine_kind)
         return compiled
 
     # ----- answering

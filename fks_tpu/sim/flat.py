@@ -4,8 +4,7 @@ Why a second engine: the exact engine (fks_tpu.sim.engine) replicates the
 reference's CPython heap bit-for-bit (required for the layout-dependent
 retry rule, reference: simulator/event_simulator.py:51-58), but heap sifts
 are chains of ~14 dependent tiny gather/scatters per event — the worst
-possible shape for a TPU. Measurement on a v5e chip (tools/probe_ops.py,
-PROFILE.md) showed something stronger: EVERY per-lane-indexed scatter or
+possible shape for a TPU. Measurement on a v5e chip (PROFILE.md) showed something stronger: EVERY per-lane-indexed scatter or
 gather in a vmapped loop body costs ~35 us/step of serialized latency,
 while full-array vector passes (reduces, dense blends) run at HBM
 bandwidth. So this engine is built from exactly two kinds of op:
@@ -50,7 +49,7 @@ fitness) is shared with or identical to the exact engine, so:
 - runs with ZERO failed placements are bit-identical to the exact engine
   (and therefore to the reference) — enforced by differential tests;
 - runs with retries differ only in retry timing; the exact engine remains
-  the parity/golden path (bench.py's parity gate uses it).
+  the parity/golden path.
 
 Like the reference, a pod that fails placement when NO deletion is pending
 is silently dropped (event_simulator.py:51-58 falls through) -> unassigned

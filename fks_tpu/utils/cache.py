@@ -3,7 +3,7 @@
 The cache directory is part of the cache key's lookup path, so it is
 placed ONCE per process, from outside the program where possible: when
 ``JAX_COMPILATION_CACHE_DIR`` is set JAX reads it itself and nothing here
-touches it; otherwise every entry point (``cli.main``, ``bench.py``,
+touches it; otherwise every entry point (``cli.main``, ``chipbench.run``,
 ``chip_smoke.py``) lands on the same fixed directory inside the checkout,
 so a second run of any of them finds what the first compiled.
 """

@@ -36,7 +36,7 @@ def block_timed(fn, *args, **kwargs):
 class ThroughputMeter:
     """Accumulate (count, seconds) batches; report rates.
 
-    ``bench.py`` feeds it timed benchmark repetitions. ``rate`` is total
+    ``cli scale`` feeds it timed repetitions. ``rate`` is total
     count over total seconds (not a mean of rates, which would overweight
     small batches).
     """

@@ -12,7 +12,7 @@ import os
 
 # Tests run on the CPU whatever the environment points at: they model the
 # mesh with 8 virtual CPU devices and compare in float64. The chip is
-# reached only by chip_smoke.py (and bench.py), never from here.
+# reached only by chip_smoke.py and chipbench, never from here.
 # jax.config.update, not the environment: it wins over an inherited
 # JAX_PLATFORMS and holds until the first backend initialization.
 # XLA_FLAGS is read at backend creation, so setting it here still works.

@@ -369,8 +369,7 @@ def test_lint_syntax_error_is_a_finding():
 
 def test_repo_lints_clean():
     """The acceptance criterion: the package's own sources carry zero
-    findings (the gate tools/run_full_suite.py runs is a subprocess of
-    the same function)."""
+    findings (``cli lint`` runs the same function)."""
     root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
     assert lint.lint_paths([os.path.join(root, "fks_tpu")]) == []
 

@@ -5,8 +5,7 @@ when a candidate run carries an injected regression (throughput -20% or
 parity drift above 1e-5) and zero on identical runs; the OpenMetrics
 exposition round-trips through the schema checker's validator; heartbeat
 liveness classifies FINISHED/HEALTHY/STALE/DEAD from the run's own
-cadence. The golden run-dir fixture under tests/fixtures/golden_run is
-the same one tools/run_full_suite.py gates on.
+cadence. The golden run-dir fixture is tests/fixtures/golden_run.
 """
 import json
 import os

@@ -115,8 +115,8 @@ def test_best_healthy_and_auto_baseline(tmp_path):
 
 def test_stale_record_is_indexed_but_never_a_baseline(tmp_path):
     """Records written by the retired bench fallback carried another
-    run's headline under ``stale_from_run``. bench.py no longer writes
-    them, but history still refuses to treat one found on disk as a
+    run's headline under ``stale_from_run``. Nothing writes them any
+    more, but history still refuses to treat one found on disk as a
     measurement."""
     paths = _write_history(tmp_path, [95.0, 101.5])
     stale = tmp_path / "BENCH_r50.json"

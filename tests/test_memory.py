@@ -18,9 +18,8 @@ The ISSUE-17 acceptance criteria, as tests:
   stale-fallback donor values on the candidate side;
 - ``cli mem`` smoke over the golden fixture.
 
-The deterministic drills themselves run here at reduced scale; the full
-50-swap/200-batch criterion is gated end-to-end by
-tools/run_full_suite.py's ``memory_gate``.
+The deterministic drills themselves run here at reduced scale; ``cli mem
+--drill`` runs the full 50-swap/200-batch criterion.
 """
 import json
 import os

@@ -5,6 +5,9 @@ each candidate through the single-policy engine — the TPU replacement for
 the reference's per-candidate subprocess fan-out must not change fitness
 (reference: funsearch/funsearch_integration.py:30-64, 535-562).
 """
+import json
+import pathlib
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -18,6 +21,8 @@ from fks_tpu.parallel.mesh import (
 )
 from fks_tpu.parallel.population import make_population_eval
 from fks_tpu.sim.engine import SimConfig, simulate
+
+FIXTURES = pathlib.Path(__file__).parent / "fixtures"
 
 
 def micro_workload():
@@ -161,3 +166,30 @@ def test_hybrid_mesh_rejects_indivisible_slices():
 
     with pytest.raises(ValueError, match="divisible"):
         hybrid_population_mesh(num_slices=3)
+
+
+# ------------------------------------------- the mesh layer's own lowering
+
+def test_default_layout_lowers_bit_identically():
+    """``make_sharded_eval`` hands back the jitted program itself, and
+    built the pinner's way (micro workload, one-device mesh) it lowers to
+    the program the manifest has pinned since before the mesh layer
+    decided its own sharding (``sharded_eval/default_layout``)."""
+    from fks_tpu.analysis import lint
+
+    mesh = population_mesh(jax.devices()[:1])
+    params = parametric.init_population(jax.random.PRNGKey(0), 2)
+    ev = make_sharded_eval(lint._micro_workload(), mesh, cfg=SimConfig(),
+                           elite_k=2, engine="flat")
+    doc = json.loads((FIXTURES / "jaxpr_pins.json").read_text())
+    assert (lint._jaxpr_hash(ev, params)
+            == doc["pins"]["sharded_eval/default_layout"])
+    # the AOT seam and the (params, real_count=None) call signature
+    assert ev.lower(params).compile() is not None
+    np.testing.assert_array_equal(np.asarray(ev(params)[0]),
+                                  np.asarray(ev(params, 2)[0]))
+
+
+def test_default_layout_pin_present():
+    doc = json.loads((FIXTURES / "jaxpr_pins.json").read_text())
+    assert "sharded_eval/default_layout" in doc["pins"]

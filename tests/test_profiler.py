@@ -16,9 +16,7 @@ import jax
 import jax.numpy as jnp
 import pytest
 
-from fks_tpu.obs.profiler import (
-    NULL_PROFILER, StageProfiler, profile_launch,
-)
+from fks_tpu.obs.profiler import NULL_PROFILER, StageProfiler
 from fks_tpu.obs.spans import span as obs_span
 from fks_tpu.obs.telemetry import CompileWatcher
 from fks_tpu.parallel.mesh import occupancy_stats, pad_stats
@@ -214,24 +212,6 @@ def test_utilization_from_occupancy_and_flops():
     assert r["est_flops_per_sec"] == pytest.approx(
         1e6 / r["compute_seconds"], rel=1e-3)
     assert r0["utilization_pct"] == pytest.approx(100.0, abs=0.1)
-
-
-def test_profile_launch_record_shape():
-    prof = StageProfiler(scope="t", recorder=_Recorder())
-    f = _fresh_jit()
-    out, rec = profile_launch(f, jnp.ones(8), name="step", profiler=prof,
-                              reps=3)
-    prof.close()
-    assert out.shape == (8,)
-    assert rec["name"] == "step" and rec["reps"] == 3
-    assert rec["compile_count"] >= 1
-    assert 0.0 < rec["best_seconds"] <= rec["steady_total_seconds"]
-    assert rec["compile_seconds"] <= rec["first_call_seconds"]
-    stages = [r["stage"] for r in prof.records]
-    assert stages == ["step:compile", "step:steady"]
-    # the disabled path still measures best_seconds, without stage records
-    out2, rec2 = profile_launch(f, jnp.ones(8), name="off")
-    assert "compile_seconds" not in rec2 and rec2["best_seconds"] > 0
 
 
 def test_summary_attribution_and_emit():

@@ -408,8 +408,7 @@ def test_scale_tier_schema_and_compare_threshold(tmp_path):
 def test_scale_smoke_1k_nodes_10k_pods():
     """The scale-tier shape at reduced pod count: 1k nodes x 10k pods
     runs to completion through the double-buffered segmented runner with
-    prefiltering + packed dtypes on (run_full_suite's slow tier; the
-    full 100k-pod headline lives in bench.py --stage scale1k)."""
+    prefiltering + packed dtypes on (slow tier)."""
     wl = synthetic_workload(1000, 10000, seed=1)
     cfg = SimConfig(max_steps=4 * 10000, track_ctime=False,
                     node_prefilter_k=64, state_pack=True)

@@ -479,7 +479,7 @@ def test_cli_scenarios_describe_and_schema(monkeypatch, capsys, tmp_path):
     assert out["name"] == "fault1"
     assert any(e["kind"] == "NODE_DOWN" for e in out["fault_timeline"])
     # the flight-recorder output (scenario_suite metric record) must pass
-    # the schema gate that tools/run_full_suite.py enforces
+    # the schema check
     chk = subprocess.run(
         [sys.executable, str(REPO / "tools" / "check_jsonl_schema.py"),
          "--run-dir", str(run_dir)], capture_output=True, text=True)

@@ -20,9 +20,8 @@ The ISSUE-18 acceptance criteria, as tests:
   stdlib-only copies, and the golden fixture carries schema-complete
   exemplar rows for all three new metric kinds.
 
-The end-to-end two-tenant run through the real HTTP front is gated by
-``bench.py --stage loadgen`` via tools/run_full_suite.py's
-``loadgen_gate``; here the drivers run against fakes.
+The end-to-end two-tenant run through the real HTTP front is ``cli
+loadgen --http``; here the drivers run against fakes.
 """
 import json
 import os

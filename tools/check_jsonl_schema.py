@@ -27,12 +27,11 @@ exposition ends with ``# EOF``.
 Usage:
     python tools/check_jsonl_schema.py --run-dir runs/evolve1
     python tools/check_jsonl_schema.py --openmetrics metrics.prom
-    python tools/check_jsonl_schema.py benchmarks/results/round*_cpu.jsonl
+    python tools/check_jsonl_schema.py benchmarks/results/divergence_audit.jsonl
 
-The last form checks arbitrary JSONL evidence files (the round logs
-under benchmarks/results/ predate the recorder and have no fixed
-keys, so they are checked for parseability only unless --require is
-given). Exit code 0 = clean, 1 = violations (printed one per line).
+The last form checks arbitrary JSONL evidence files (they have no
+fixed keys, so they are checked for parseability only unless --require
+is given). Exit code 0 = clean, 1 = violations (printed one per line).
 """
 from __future__ import annotations
 
@@ -131,21 +130,10 @@ LEAK_LOOPS = {"serve_batch", "vm_swap", "promotion", "evolve_generation",
 #: fks_tpu.obs.workload.LOADGEN_MODES; tests/test_workload.py pins the
 #: two copies) — the arrival process that produced the numbers
 LOADGEN_MODES = {"open", "closed", "mixed"}
-#: closed vocabulary of batchable layout axes, and the components that
-#: may file layout_ledger rows (duplicated from fks_tpu.obs.layout
-#: .LAYOUT_AXES / .LAYOUT_COMPONENTS; tests/test_layout.py pins the two
-#: copies) — which axis a LayoutSpec shards/vmaps, and who recorded it
-LAYOUT_AXES = {"candidates", "scenarios", "segments"}
-LAYOUT_COMPONENTS = {"eval", "code_eval", "gen_step", "suite_eval",
-                     "serve", "vm_serve", "portfolio_serve", "probe",
-                     "bench"}
 #: legal ``reason`` values on a portfolio_route metric (duplicated from
 #: fks_tpu.portfolio.router.ROUTE_REASONS; tests/test_portfolio.py pins
 #: the two copies) — which routing rule placed the request
 ROUTE_REASONS = {"pin", "affinity", "ab", "default", "fallback", "query"}
-#: canonical LayoutSpec key shape (fks_tpu.obs.layout.LayoutSpec.key)
-_LAYOUT_KEY_RE = re.compile(
-    r"^shard\[[a-z_,]*\]\|vmap\[[a-z_,]*\]\|seg=\d+$")
 METRIC_KIND_REQUIRED: Dict[str, Tuple[str, ...]] = {
     "generation": ("generation", "best_score"),
     "parity": ("generation", "checked", "max_drift"),
@@ -159,7 +147,7 @@ METRIC_KIND_REQUIRED: Dict[str, Tuple[str, ...]] = {
     # and what the rung cost in device wall seconds
     "budget_rung": ("generation", "rung", "entered", "survived",
                     "device_seconds"),
-    # large-cluster scale tier (bench stage_scale1k / cli scale): the
+    # large-cluster scale tier (cli scale): the
     # completion-run throughput record must say what shape ran and which
     # scale knobs (prefilter / packed dtypes) produced the number
     "scale_tier": ("nodes", "pods", "events_per_sec",
@@ -221,13 +209,6 @@ METRIC_KIND_REQUIRED: Dict[str, Tuple[str, ...]] = {
     # request — which slot answered it and which rule decided (slot -1
     # means the AOT coverage-fallback engine served it)
     "portfolio_route": ("request_id", "tenant", "slot", "reason"),
-    # per-layout cost ledger (fks_tpu.obs.layout): one row per sharded
-    # entry point wiring/launch, tagged with the canonical LayoutSpec key
-    # and the mesh layout it ran on
-    "layout_ledger": ("component", "layout_key", "mesh_layout"),
-    # layout explorer (fks_tpu.obs.layout.explore_layouts): one warm
-    # probe per valid layout of a (population x suite x mesh) shape
-    "layout_probe": ("layout_key", "mesh_shape", "steady_seconds"),
 }
 
 #: an OpenMetrics sample line: name, optional {labels}, value, optional
@@ -344,23 +325,6 @@ def check_kinds(path: str, records: List[dict],
                 raise SchemaError(
                     f"{path}: record {i + 1}: unknown loadgen mode "
                     f"{mode!r} (expect one of {sorted(LOADGEN_MODES)})")
-        elif rec.get("kind") in ("layout_ledger", "layout_probe"):
-            lk = rec.get("layout_key")
-            if not isinstance(lk, str) or not _LAYOUT_KEY_RE.match(lk):
-                raise SchemaError(
-                    f"{path}: record {i + 1}: malformed layout_key {lk!r} "
-                    "(expect 'shard[...]|vmap[...]|seg=N')")
-            for ax in rec.get("axes", []):
-                if ax not in LAYOUT_AXES:
-                    raise SchemaError(
-                        f"{path}: record {i + 1}: unknown layout axis "
-                        f"{ax!r} (expect one of {sorted(LAYOUT_AXES)})")
-            if rec.get("kind") == "layout_ledger" \
-                    and rec.get("component") not in LAYOUT_COMPONENTS:
-                raise SchemaError(
-                    f"{path}: record {i + 1}: unknown layout component "
-                    f"{rec.get('component')!r} (expect one of "
-                    f"{sorted(LAYOUT_COMPONENTS)})")
         elif rec.get("kind") == "decision_trace":
             _check_embedded_events(path, i, rec.get("events", []))
         elif rec.get("kind") == "trace_diff":
@@ -468,7 +432,7 @@ def main(argv=None) -> int:
     ap = argparse.ArgumentParser(description=__doc__.splitlines()[0])
     ap.add_argument("paths", nargs="*",
                     help="JSONL files to check (e.g. benchmarks/results/"
-                         "round*_cpu.jsonl)")
+                         "divergence_audit.jsonl)")
     ap.add_argument("--run-dir", default="",
                     help="validate a flight-recorder run directory instead")
     ap.add_argument("--require", default="",
