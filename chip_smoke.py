@@ -20,7 +20,9 @@ compile seconds and count from ``obs.CompileWatcher``, and its check):
 (``parallel.make_population_eval``, flat engine, lanes re-run on the host
 CPU), ``evaluate_code`` (``funsearch.backend.CodeEvaluator`` with the
 defaults the chip selects, against the recorded divergence audit),
-``evolve`` (``cli evolve --fake-llm``), ``serve`` (the best ledger
+``evaluate_forked`` (two lanes forked from the pinned snapshot of the
+loaded cluster B, 1,024 events past the fork, against the plain
+reference's counts), ``evolve`` (``cli evolve --fake-llm``), ``serve`` (the best ledger
 champion behind ``serve.service.make_http_server``, VM engine, plus the
 two-slot portfolio selftest) and ``fused`` (the Mosaic-compiled Pallas
 kernel gated against flat). With more than one device visible the
@@ -65,6 +67,12 @@ PARITY_A = {"first_fit": (0.4292, 47), "best_fit": (0.4465, 40),
             "funsearch_4901": (0.4901, 67)}
 #: best_fit on deployment B: flat == exact to the last digit, zero retries
 BEST_FIT_B = 0.00492986
+#: deployment B loaded: the inflated arrival list forked from the pinned
+#: snapshot of its first 5,888 arrivals; policy -> (scheduled pods, failed
+#: placements) at event 6,912 by the plain reference's ``simulate_from``
+PODS_LOADED = "openb_pod_list_inflated080.csv"
+SNAPSHOT = "openb_snapshot_inflated080_e5888.csv"
+FORKED_B = {"first_fit": (6694, 73), "best_fit": (6695, 0)}
 #: served fitness against the unbatched exact reference, as a bound
 #: RELATIVE to the score: 4 ulps of f32 (2**-23 each). Placements must be
 #: identical; the fitness is the same f32 arithmetic compiled twice, and on
@@ -254,6 +262,96 @@ def step_evaluate_code(wl, checked: dict, mesh=None) -> dict:
     if mesh is not None and ev.vm_batch:
         out["lanes_per_device"] = _require_every_device(
             ev.last_lanes_per_device, mesh)
+    out["ok"] = not bad
+    out["mismatch"] = bad
+    return out
+
+
+def carry_leaves_differing(wl, cfg, placed_by, loaded) -> list:
+    """Leaves of ``loaded`` (the carry ``flat.initial_state`` builds on
+    the host from the workload's snapshot) that are not, bit for bit,
+    what the flat engine itself reaches ON THIS DEVICE after the
+    snapshot's ``E0`` steps from the empty cluster under ``placed_by``,
+    the policy that placed the residents. The float leaf is
+    ``snap_sums``: the host rounds the utilization sums as the compiled
+    step does (a division by a constant total becomes a product with its
+    reciprocal), and only a run on the device says that it still does."""
+    import dataclasses
+
+    import jax
+    import numpy as np
+
+    from fks_tpu.sim import flat
+    from fks_tpu.sim.engine import loop_tables
+
+    empty = dataclasses.replace(wl, snapshot=None)
+    ktable, max_steps = loop_tables(empty, cfg)
+    step = flat.build_step(empty, placed_by, cfg, ktable, max_steps)
+    e0 = wl.snapshot.e0
+    stepped = jax.jit(lambda s: jax.lax.while_loop(
+        lambda s: flat.lane_active(s, max_steps) & (s.steps < e0),
+        step, s))(flat.initial_state(empty, cfg))
+    return [field for field, a, b in zip(stepped._fields, stepped, loaded)
+            if (a is None) != (b is None) or (a is not None and not (
+                np.asarray(a).dtype == np.asarray(b).dtype
+                and np.array_equal(np.asarray(a), np.asarray(b))))]
+
+
+def step_evaluate_forked(wl, window: int, expect: dict, mesh=None,
+                         placed_by=None) -> dict:
+    """One generation of two lanes (the seed policies) forked from the
+    workload's snapshot (``fks_tpu.data.snapshot``), ``window`` events
+    past the fork, through ``CodeEvaluator(wl, engine="flat")`` as the
+    platform builds it. Every lane must stop at event ``E0 + window`` of
+    the WHOLE run with the residents where the snapshot put them, and
+    ``expect`` (name -> (scheduled pods, failed placements), the plain
+    reference's ``simulate_from``; None to skip) must reproduce. With
+    ``placed_by`` (the policy that placed the residents) the evaluator's
+    forked carry must also be the engine's own after those placements,
+    leaf by leaf (``carry_leaves_differing``)."""
+    import numpy as np
+
+    from fks_tpu.funsearch import template
+    from fks_tpu.funsearch.backend import CodeEvaluator
+    from fks_tpu.sim.engine import SimConfig
+
+    snap = wl.snapshot
+    stop = snap.e0 + window
+    ev = CodeEvaluator(wl, SimConfig(max_steps=stop), engine="flat",
+                       mesh=mesh, fp_dedup=False)
+    sources = template.seed_policies()
+    records = ev.evaluate(list(sources.values()))
+    stats = ev.last_eval_stats
+    out = {"start_event": stats["start_event"], "window": window,
+           "residents": snap.e0, "vm_batch": bool(ev.vm_batch),
+           "vm_batch_lanes": stats["vm_batch_lanes"],
+           "frag_events": stats["frag_events"], "lanes": {}}
+    bad = []
+    if stats["start_event"] != snap.e0:
+        bad.append("the evaluator did not start at the fork")
+    if ev.vm_batch and stats["vm_batch_lanes"] != len(sources):
+        bad.append("batched tier did not serve the whole generation")
+    if placed_by is not None:
+        out["carry_leaves_differing"] = carry_leaves_differing(
+            wl, ev.cfg, placed_by, ev.state0)
+        if out["carry_leaves_differing"]:
+            bad.append("the forked carry is not the engine's own")
+    pod = np.asarray(snap.pod)
+    for name, rec in zip(sources, records):
+        res = rec.result
+        got = (int(res.scheduled_pods), int(res.num_fragmentation_events))
+        out["lanes"][name] = {"events": int(res.events_processed),
+                              "scheduled": got[0], "frag_events": got[1]}
+        stayed = (np.array_equal(np.asarray(res.assigned_node)[pod],
+                                 np.asarray(snap.node))
+                  and np.array_equal(np.asarray(res.assigned_gpus)[pod],
+                                     np.asarray(snap.gpus)))
+        want = expect.get(name)
+        if (int(res.events_processed) != stop or not stayed
+                or bool(res.failed) or (want is not None and got != want)):
+            bad.append({"policy": name, "got": got, "want": want,
+                        "events": int(res.events_processed),
+                        "residents_stayed": bool(stayed)})
     out["ok"] = not bad
     out["mismatch"] = bad
     return out
@@ -536,6 +634,7 @@ def main(argv=None) -> int:
     from fks_tpu import obs
     from fks_tpu.data import TraceParser
     from fks_tpu.funsearch import EvolutionConfig
+    from fks_tpu.models import zoo
     from fks_tpu.parallel import population_mesh
     from fks_tpu.serve import load_champion
     from fks_tpu.utils import place_compile_cache
@@ -598,6 +697,10 @@ def main(argv=None) -> int:
         ("evaluate_code", lambda: {
             **step_evaluate_code(wl_a, checked, mesh),
             "population_cut_from": full}),
+        ("evaluate_forked", lambda: step_evaluate_forked(
+            parser.parse_workload(node_file=NODES_B, pod_file=PODS_LOADED,
+                                  snapshot_file=SNAPSHOT),
+            1024, FORKED_B, mesh, placed_by=zoo.best_fit())),
         ("evolve", lambda: {
             **step_evolve(out_dir, population_size=evolve_pop),
             "candidates_per_generation_cut": [full, evolve_pop - elite]}),

@@ -25,6 +25,7 @@ Usage:
     python -m fks_tpu.cli lint [PATHS...] [--write-pins | --no-pins]
     python -m fks_tpu.cli mem [--run-dir DIR | --sample | --drill NAME]
     python -m fks_tpu.cli traces
+    python -m fks_tpu.cli snapshot
 
 Every subcommand accepts ``--run-dir DIR`` to flight-record the run
 (fks_tpu.obs): spans, compile/device telemetry, and per-generation
@@ -138,15 +139,22 @@ def _parse_workload(args):
     from fks_tpu.data import TraceParser
 
     parser = TraceParser()
-    return parser, parser.parse_workload(node_file=args.nodes,
-                                         pod_file=args.trace)
+    return parser, parser.parse_workload(
+        node_file=args.nodes, pod_file=args.trace,
+        snapshot_file=getattr(args, "snapshot", "") or None)
 
 
-def _add_trace_flags(p):
+def _add_trace_flags(p, snapshot=False):
     p.add_argument("--trace", default="openb_pod_list_default.csv",
                    help="pod CSV under benchmarks/traces/csv/")
     p.add_argument("--nodes", default="gpu_models_filtered.csv",
                    help="node CSV under benchmarks/traces/csv/")
+    if snapshot:
+        p.add_argument("--snapshot", default="",
+                       help="snapshot CSV (name,node_sn,gpus) under "
+                            "benchmarks/traces/csv/: start from the loaded "
+                            "cluster it pins; needs --engine flat "
+                            "(fks_tpu.data.snapshot)")
 
 
 def _result_row(name, res, wall):
@@ -1586,6 +1594,43 @@ def cmd_traces(args):
     return 0
 
 
+def write_snapshot(path=None):
+    """Rewrite the committed snapshot of the loaded cluster: the first
+    5,888 arrivals of the inflated list (70 % of the cluster's GPUs) as
+    the zoo's ``best_fit`` places them on the 1,523 nodes (flat engine,
+    float32, the large-cluster rule). ``path`` defaults to the committed
+    file beside the traces. Returns (path, snapshot)."""
+    from pathlib import Path
+
+    from fks_tpu.data import TraceParser
+    from fks_tpu.data.snapshot import write_snapshot_csv_gz
+    from fks_tpu.models import zoo
+    from fks_tpu.sim import flat
+    from fks_tpu.sim.engine import SimConfig
+
+    parser = TraceParser()
+    wl = parser.parse_workload(node_file="openb_node_list_all_node.csv",
+                               pod_file="openb_pod_list_inflated080.csv")
+    snap = flat.make_snapshot(wl, zoo.best_fit(), 5888,
+                              SimConfig(node_prefilter_k=64))
+    if path is None:
+        path = Path(parser.csv_dir) / \
+            "openb_snapshot_inflated080_e5888.csv.gz"
+    write_snapshot_csv_gz(wl, snap, path)
+    return path, snap
+
+
+def cmd_snapshot(args):
+    """Rewrite the committed ``--snapshot`` file byte for byte, as
+    ``python -m fks_tpu.data.inflate`` rewrites its pod list."""
+    import numpy as np
+
+    path, snap = write_snapshot()
+    print(f"{path}: {snap.e0} residents on "
+          f"{len(np.unique(np.asarray(snap.node)))} nodes")
+    return 0
+
+
 def build_parser() -> argparse.ArgumentParser:
     """The whole command line: one sub-parser per command, each bound to
     its ``cmd_*`` through ``fn``. Builds only; nothing runs."""
@@ -1610,7 +1655,7 @@ def build_parser() -> argparse.ArgumentParser:
                              "populations — 'scale' command only)")
 
     b = sub.add_parser("bench", help="policy comparison table", parents=[common])
-    _add_trace_flags(b)
+    _add_trace_flags(b, snapshot=True)
     b.add_argument("--policies", default="",
                    help="comma-separated zoo policy names (default: all)")
     b.add_argument("--f64", action="store_true",
@@ -1620,14 +1665,14 @@ def build_parser() -> argparse.ArgumentParser:
     b.set_defaults(fn=cmd_bench)
 
     s = sub.add_parser("simulate", help="one policy, detailed JSON result", parents=[common])
-    _add_trace_flags(s)
+    _add_trace_flags(s, snapshot=True)
     s.add_argument("--policy", default="best_fit")
     s.add_argument("--f64", action="store_true")
     s.add_argument("--validate", action="store_true")
     s.set_defaults(fn=cmd_simulate)
 
     e = sub.add_parser("evolve", help="run FunSearch evolution", parents=[common])
-    _add_trace_flags(e)
+    _add_trace_flags(e, snapshot=True)
     e.add_argument("--config", default="", help="reference-format llm_config.json")
     e.add_argument("--fake-llm", action="store_true",
                    help="deterministic offline codegen backend")
@@ -2151,6 +2196,12 @@ def build_parser() -> argparse.ArgumentParser:
 
     t = sub.add_parser("traces", help="list available trace files")
     t.set_defaults(fn=cmd_traces)
+
+    ss = sub.add_parser(
+        "snapshot",
+        help="rewrite the committed snapshot of the loaded cluster (the "
+             "--snapshot file of simulate / bench / evolve)")
+    ss.set_defaults(fn=cmd_snapshot)
     return ap
 
 
@@ -2164,6 +2215,12 @@ def main(argv=None) -> int:
                  "it applies to the 'scale' command (other commands run "
                  "single policies or arbitrary evolved code; use "
                  "'exact'/'flat' there)")
+    if getattr(args, "snapshot", "") and args.engine != "flat":
+        ap.error("--snapshot: flat engine only (the exact engine's heap at "
+                 "the fork is not rebuilt yet); pass --engine flat")
+    if getattr(args, "snapshot", "") and getattr(args, "parity_sample", 0):
+        ap.error("--snapshot: no parity sentinel (--parity-sample rescored "
+                 "candidates on the exact engine, which cannot fork yet)")
     return args.fn(args)
 
 

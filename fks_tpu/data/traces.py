@@ -25,6 +25,7 @@ Differences (deliberate):
 from __future__ import annotations
 
 import csv
+import dataclasses
 import gzip
 import io
 import json
@@ -276,11 +277,21 @@ class TraceParser:
                        pod_file: str = "openb_pod_list_default.csv",
                        pad_nodes_to: Optional[int] = None,
                        pad_gpus_to: Optional[int] = None,
-                       pad_pods_to: Optional[int] = None) -> Workload:
-        """Defaults match the reference benchmark workload (parser.py:117-118)."""
+                       pad_pods_to: Optional[int] = None,
+                       snapshot_file: Optional[str] = None) -> Workload:
+        """Defaults match the reference benchmark workload (parser.py:117-118).
+        ``snapshot_file`` (a ``name,node_sn,gpus`` CSV beside the traces,
+        ``fks_tpu.data.snapshot``) makes it a loaded cluster: the flat
+        engine then starts after the snapshot's arrivals. An invalid
+        snapshot raises ``ValueError`` here."""
         cluster = self.parse_cluster(node_file, pad_nodes_to, pad_gpus_to)
         pods = self.parse_pods(pod_file, pad_pods_to)
-        return Workload(cluster=cluster, pods=pods)
+        wl = Workload(cluster=cluster, pods=pods)
+        if snapshot_file:
+            from fks_tpu.data.snapshot import load_snapshot
+            wl = dataclasses.replace(wl, snapshot=load_snapshot(
+                self.csv_dir / snapshot_file, wl))
+        return wl
 
     # ------------------------------------------------------------ discovery
     def get_available_node_files(self) -> List[str]:

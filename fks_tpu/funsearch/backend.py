@@ -154,7 +154,24 @@ class CodeEvaluator:
             if robust is None:
                 from fks_tpu.scenarios.robust import RobustConfig
                 self.robust = RobustConfig()
-        self.state0 = self._mod.initial_state(workload, cfg)
+        # A workload with a snapshot (fks_tpu.data.snapshot) forks: the
+        # flat engine's initial_state is the carry after the snapshot's
+        # events, so every tier below starts from the loaded cluster and
+        # a result still counts the whole run. ``start_event`` is where
+        # the policy takes over (0 without a snapshot).
+        snap = workload.snapshot
+        self.start_event = 0 if snap is None else snap.e0
+        if snap is None:
+            self.state0 = self._mod.initial_state(workload, cfg)
+        else:
+            with obs.span("tier/fork_state", start_event=snap.e0,
+                          residents=snap.e0, nodes_loaded=int(len(
+                              np.unique(np.asarray(snap.node))))) as sp:
+                self.state0 = jax.block_until_ready(
+                    self._mod.initial_state(workload, cfg))
+                sp.set(bytes=int(sum(
+                    x.nbytes
+                    for x in jax.tree_util.tree_leaves(self.state0))))
         self._cache: Dict[str, object] = {}
         self._lock = threading.Lock()
         self.compile_count = 0  # observability: unique programs built
@@ -385,7 +402,7 @@ class CodeEvaluator:
         capacity = int(stacked.opcode.shape[-1])
         view = self.cfg.resolve_prefilter_k(c.n_padded) or c.n_padded
         with obs.span("tier/vm_batch/launch", lanes=pop,
-                      shards=self._n_shards,
+                      shards=self._n_shards, start_event=self.start_event,
                       slots=max(int(p.n_ops) for p in progs),
                       capacity=capacity, nodes=c.n_padded, view=view,
                       register_bytes=(pop // self._n_shards)
@@ -581,7 +598,8 @@ class CodeEvaluator:
         One ``tier/evaluate`` span is the root of the generation; its
         stages are spans whether the profiler is enabled or not.
         """
-        with obs.span("tier/evaluate", candidates=len(codes)):
+        with obs.span("tier/evaluate", candidates=len(codes),
+                      start_event=self.start_event):
             return self._evaluate(codes)
 
     def _evaluate(self, codes: Sequence[str]) -> List[EvalRecord]:
@@ -767,6 +785,14 @@ class CodeEvaluator:
                                  if works else 0),
             "vm_batch_lanes": batch_served,
             "fallback_lanes": len(jit_only) + len(general),
+            # where the policy took over (0: from the empty cluster) and
+            # the placements that failed after it, over the generation's
+            # lanes: a snapshot holds no failed placement, so a result's
+            # whole-run count is the count after the fork
+            "start_event": self.start_event,
+            "frag_events": sum(
+                int(np.sum(r.result.num_fragmentation_events))
+                for r in memo.values() if r.result is not None),
             "segments": self.segments_dispatched - seg0,
             # the large-cluster rule in effect (0 = every node is scored)
             "prefilter_k": self.cfg.resolve_prefilter_k(c.n_padded),

@@ -273,6 +273,12 @@ class FunSearch:
                          else evaluator.profiler)
         self.rng = random.Random(config.seed)
         self.log = log
+        if evaluator.engine != "exact" and self._search_fitness_is_final:
+            log(f"snapshot: the exact engine cannot fork yet, so elites "
+                f"are ranked and champions saved by their "
+                f"[{evaluator.engine}] fitness from event "
+                f"{evaluator.start_event}, with no exact re-rank; saved "
+                "entries say so (score_engine, start_event)")
         # flight recorder: explicit > process-wide active (cli --run-dir
         # installs one via obs.recording); defaults to the NullRecorder,
         # under which the ledger performs zero filesystem writes
@@ -358,7 +364,7 @@ class FunSearch:
         exact-ranked, as the reference's single-engine sort trivially is
         (reference: funsearch_integration.py:494-496)."""
         self.population.sort(key=lambda m: m[1], reverse=True)
-        if self.evaluator.engine == "exact" or self.cfg.elite_size <= 0:
+        if self._search_fitness_is_final or self.cfg.elite_size <= 0:
             return
         window = min(len(self.population), 2 * self.cfg.elite_size)
         if window <= 1:
@@ -393,6 +399,14 @@ class FunSearch:
                     return True
         return False
 
+    @property
+    def _search_fitness_is_final(self) -> bool:
+        """No exact rescore: the search engine IS exact, or the workload
+        forks from a snapshot, which the exact engine refuses by name
+        (its heap at the fork is not rebuilt yet, ROADMAP)."""
+        return (self.evaluator.engine == "exact"
+                or self.evaluator.workload.snapshot is not None)
+
     def _exact_score(self, code: str, score: float) -> float:
         """Fitness under the exact reference-replica engine. Identity when
         the search engine already IS exact; otherwise one VM-tier (or
@@ -403,7 +417,7 @@ class FunSearch:
         only an unparseable candidate maps to 0.0 — the rule the
         reference applies to failed evaluations (reference:
         funsearch_integration.py:63-64)."""
-        if self.evaluator.engine == "exact":
+        if self._search_fitness_is_final:
             return score
         from fks_tpu.funsearch import transpiler
         try:
@@ -491,7 +505,7 @@ class FunSearch:
         if self.best is None or score > self.best[1]:
             self.best = (code, score)
             self.best_exact = self._exact_score(code, score)
-            if self.evaluator.engine == "exact":
+            if self._search_fitness_is_final:
                 self.log(f"  NEW BEST {score:.4f} (gen {self.generation})")
             else:
                 self.log(f"  NEW BEST {score:.4f} "
@@ -817,15 +831,23 @@ class FunSearch:
     # ----- persistence (reference funsearch_integration.py:606-679) + resume
 
     def _champion_fields(self, code: str, score: float) -> dict:
-        """The persisted ``score`` is ALWAYS exact-engine fitness — the only
+        """The persisted ``score`` is exact-engine fitness — the only
         number comparable to the reference's published table. When the
         search ran on a fast engine, the raw search fitness and the engine
-        name ride along as ``search_score``/``search_engine``."""
+        name ride along as ``search_score``/``search_engine``. The one
+        exception marks itself: a search forked from a snapshot cannot be
+        rescored (the exact engine refuses a snapshot), so ``score`` is
+        the search engine's and the entry carries ``score_engine`` and
+        ``start_event`` — a reader that ranks entries must not compare
+        such a score with an exact one."""
         exact = self._exact_score(code, score)
         fields = {"score": exact}
         if self.evaluator.engine != "exact":
             fields["search_score"] = score
             fields["search_engine"] = self.evaluator.engine
+            if self._search_fitness_is_final:
+                fields["score_engine"] = self.evaluator.engine
+                fields["start_event"] = self.evaluator.start_event
         suite = self.evaluator.suite
         if suite is not None:
             fields["scenario_suite"] = suite.name

@@ -34,6 +34,11 @@ def strip_ids(wl: Workload) -> Workload:
     share one treedef and can stack under vmap. Public: the serving tier
     (fks_tpu.serve.batcher) stacks per-query workloads with exactly this
     normalization so queries match the AOT-compiled example's treedef."""
+    if wl.snapshot is not None:
+        raise ValueError(
+            "snapshot: trace batching and serving start every workload "
+            "from the empty cluster; evaluate a loaded cluster through "
+            "CodeEvaluator / make_population_eval with engine='flat'")
     return Workload(
         cluster=ClusterArrays(**{
             **{f: getattr(wl.cluster, f) for f in (

@@ -128,6 +128,10 @@ def perturb_workload(base: Workload, spec: ScenarioSpec,
     if base.faults is not None:
         raise ValueError("base workload already carries fault events; "
                          "perturb the fault-free original")
+    if base.snapshot is not None:
+        raise ValueError("snapshot: a scenario perturbs the arrivals the "
+                         "snapshot placed; build suites on the workload "
+                         "without it")
     p = base.pods
     c = base.cluster
     pm = np.asarray(p.pod_mask)

@@ -161,6 +161,10 @@ class SimConfig:
 def initial_state(workload: Workload, cfg: SimConfig) -> SimState:
     """Build the t=0 carry. Host-side; the initial heap layout is produced
     by real CPython heapq so it matches the reference bit-for-bit."""
+    if workload.snapshot is not None:
+        raise ValueError(
+            "snapshot: flat engine only (the exact engine's heap at the "
+            "fork is not rebuilt yet); use engine='flat'")
     c, p = workload.cluster, workload.pods
     n_real = p.num_pods
     pm = np.asarray(p.pod_mask)

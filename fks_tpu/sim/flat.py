@@ -66,6 +66,7 @@ cost.
 """
 from __future__ import annotations
 
+import dataclasses
 import functools
 from typing import Any, Callable, NamedTuple
 
@@ -150,7 +151,11 @@ def _rank_perm(pod_mask, tie_rank):
 
 def initial_state(workload: Workload, cfg: SimConfig) -> FlatState:
     """t=0 carry: every real pod's slot (in tie-rank order) holds its
-    CREATE time; ``aux`` starts at AUX_FRESH."""
+    CREATE time; ``aux`` starts at AUX_FRESH. A workload with a
+    ``snapshot`` (``fks_tpu.data.snapshot``) gives the carry AFTER the
+    snapshot's ``E0`` events instead, leaf for leaf what ``build_step``
+    reaches when a policy makes those placements: every runner that
+    starts from here forks from the loaded cluster."""
     c, p = workload.cluster, workload.pods
     pp = p.p_padded
     pm = np.asarray(p.pod_mask)
@@ -168,7 +173,7 @@ def initial_state(workload: Workload, cfg: SimConfig) -> FlatState:
             "fragmentation min_needed would be miscounted")
     f = cfg.score_dtype
     dt = _pack_dtypes(cfg, c, p)
-    return FlatState(
+    state = FlatState(
         ev_time=jnp.asarray(ev_time, jnp.int32),
         aux=jnp.full(pp, AUX_FRESH, dt["aux"]),
         aux_gpus=None if packed else jnp.zeros(pp, dt["aux_gpus"]),
@@ -197,6 +202,94 @@ def initial_state(workload: Workload, cfg: SimConfig) -> FlatState:
         node_avail=(None if workload.faults is None
                     else jnp.ones(c.n_padded, bool)),
     )
+    if workload.snapshot is None:
+        return state
+    return state._replace(**_loaded_leaves(workload, cfg, perm, ev_time, dt))
+
+
+def _loaded_leaves(workload: Workload, cfg: SimConfig, perm, ev_time,
+                   dt: dict) -> dict:
+    """The leaves of the carry that the snapshot's ``E0`` events change,
+    in NumPy. Those events are ``E0`` placed CREATEs (the snapshot's
+    validation), so every one counts as an event and a step, nothing
+    waits or fragments, and the cluster only fills: the utilization
+    snapshots among them are sums of the residents' requests up to the
+    trigger points, and ``max_nodes`` is the count at the end."""
+    from fks_tpu.data.snapshot import gpu_slots, place_residents
+
+    if workload.faults is not None or cfg.decision_trace:
+        raise ValueError(
+            "snapshot: a workload with fault events or a decision trace "
+            "cannot start from a snapshot (the prefix holds neither)")
+    c, p, snap = workload.cluster, workload.pods, workload.snapshot
+    left = place_residents(workload, snap)
+    e0, g = snap.e0, c.g_padded
+    pod = np.asarray(snap.pod, np.int64)
+    node = np.asarray(snap.node, np.int64)
+    bits = np.asarray(snap.gpus, np.int64)
+    slot = np.empty(p.p_padded, np.int64)
+    slot[perm] = np.arange(p.p_padded)    # slot index of each pod
+    slot = slot[pod]
+
+    # residents are placed pods with their DELETE pending
+    ev_time = ev_time.copy()
+    ev_time[slot] = (np.asarray(p.creation_time, np.int64)
+                     + np.asarray(p.duration, np.int64))[pod]
+    packed = _packable(c.n_padded, g)
+    aux = np.full(p.p_padded, AUX_FRESH, np.int64)
+    aux[slot] = (node << g) | bits if packed else node
+    out = dict(ev_time=jnp.asarray(ev_time, jnp.int32),
+               aux=jnp.asarray(aux, dt["aux"]))
+    if not packed:
+        aux_gpus = np.zeros(p.p_padded, np.int64)
+        aux_gpus[slot] = bits
+        out["aux_gpus"] = jnp.asarray(aux_gpus, dt["aux_gpus"])
+
+    # the evaluator's sums, as the step accumulates them: one snapshot at
+    # most per event, when the event count reaches the next trigger
+    ktable, _ = loop_tables(workload, cfg)
+    f = np.dtype(cfg.score_dtype)
+    totals = np.asarray([np.asarray(x, np.int64).sum() for x in (
+        c.cpu_total, c.mem_total, c.num_gpus, c.gpu_milli_total)])
+    # the step divides by totals that XLA folds to constants, and XLA
+    # turns a division by a constant into a product with its reciprocal
+    # (AlgebraicSimplifier, every backend): the same two roundings here,
+    # or the sums are an ulp off the engine's own
+    inv = f.type(1) / np.maximum(totals, 1).astype(f)
+    ngpu = np.asarray(p.num_gpu, np.int64)[pod]
+    held = gpu_slots(snap, g).sum(axis=1)
+    used = np.stack([
+        np.cumsum(np.asarray(p.cpu, np.int64)[pod]),
+        np.cumsum(np.asarray(p.mem, np.int64)[pod]),
+        np.cumsum(ngpu) + int((np.asarray(c.num_gpus, np.int64)
+                               - np.asarray(c.gpu_declared, np.int64)).sum()),
+        np.cumsum(np.asarray(p.gpu_milli, np.int64)[pod] * held),
+    ], axis=1)                            # [E0, 4] after each event
+    snap_sums = np.zeros(4, f)
+    snap_idx = events = 0
+    for trigger in ktable:
+        events = max(events + 1, int(trigger))
+        if events > e0:
+            break
+        utils = np.where(totals <= 0, f.type(0),
+                         used[events - 1].astype(f) * inv)
+        snap_sums = (snap_sums + utils).astype(f)
+        snap_idx += 1
+    nm = np.asarray(c.node_mask)
+    active = nm & ((left.cpu_left < np.asarray(c.cpu_total))
+                   | (left.mem_left < np.asarray(c.mem_total))
+                   | (left.gpu_left < np.asarray(c.num_gpus)))
+    out.update(
+        cpu_left=jnp.asarray(left.cpu_left, jnp.int32),
+        mem_left=jnp.asarray(left.mem_left, jnp.int32),
+        gpu_left=jnp.asarray(left.gpu_left, dt["gpu_left"]),
+        gpu_milli_left=jnp.asarray(left.gpu_milli_left,
+                                   dt["gpu_milli_left"]),
+        events_processed=jnp.int32(e0), steps=jnp.int32(e0),
+        snap_idx=jnp.int32(snap_idx),
+        snap_sums=jnp.asarray(snap_sums, cfg.score_dtype),
+        max_nodes=jnp.int32(int(active.sum()) if e0 else 0))
+    return out
 
 
 def lane_active(s: FlatState, max_steps: int):
@@ -606,6 +699,31 @@ def simulate(workload: Workload, policy: PolicyFn,
     if jit:
         run = jax.jit(run)
     return run(initial_state(workload, cfg))
+
+
+def make_snapshot(workload: Workload, policy: PolicyFn, e0: int,
+                  cfg: SimConfig = SimConfig()):
+    """The ``fks_tpu.data.snapshot.Snapshot`` of ``workload``'s first
+    ``e0`` arrivals as ``policy`` places them: this engine run for ``e0``
+    steps from the empty cluster, its placements read back. Raises
+    ``ValueError`` when those steps are not ``e0`` placed CREATEs (a
+    placement failed, or a pod left before arrival ``e0 - 1``)."""
+    from fks_tpu.data.snapshot import from_placements
+
+    if workload.snapshot is not None:
+        workload = dataclasses.replace(workload, snapshot=None)
+    res = simulate(workload, policy,
+                   dataclasses.replace(cfg, max_steps=int(e0)))
+    placed, events, frag = (int(res.scheduled_pods),
+                            int(res.events_processed),
+                            int(res.num_fragmentation_events))
+    if (placed, events, frag) != (int(e0), int(e0), 0):
+        raise ValueError(
+            f"snapshot: the first {e0} events under this policy are not "
+            f"{e0} placed CREATEs ({placed} pods placed, {events} events, "
+            f"{frag} failed placements)")
+    return from_placements(workload, e0, res.assigned_node,
+                           res.assigned_gpus)
 
 
 def broadcast_state(state0: FlatState, lanes: int) -> FlatState:

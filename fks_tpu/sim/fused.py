@@ -119,6 +119,10 @@ class _Plan(NamedTuple):
 def _build_plan(workload: Workload, cfg: SimConfig) -> _Plan:
     c, p = workload.cluster, workload.pods
     n, g, pp = c.n_padded, c.g_padded, p.p_padded
+    if workload.snapshot is not None:
+        raise ValueError(
+            "snapshot: flat engine only (the fused kernel builds its own "
+            "initial state); use engine='flat'")
     if not _packable(n, g):
         raise ValueError("fused kernel needs packed aux (node_bits+G<=31); "
                          "use the XLA flat engine")

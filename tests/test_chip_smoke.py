@@ -85,6 +85,31 @@ def test_step_evaluate_code_micro_over_the_mesh(micro_workload):
     assert not bad["ok"]
 
 
+def test_step_evaluate_forked_micro(micro_workload):
+    import dataclasses
+
+    from fks_tpu.models import zoo
+    from fks_tpu.sim import flat
+
+    forked = dataclasses.replace(micro_workload, snapshot=flat.make_snapshot(
+        micro_workload, zoo.best_fit(), 2))
+    out = chip_smoke.step_evaluate_forked(forked, 3, {},
+                                          placed_by=zoo.best_fit())
+    assert out["ok"], out
+    assert out["carry_leaves_differing"] == []
+    # residents placed by another policy than the one named: not its carry
+    other = chip_smoke.step_evaluate_forked(forked, 3, {},
+                                            placed_by=zoo.first_fit())
+    assert not other["ok"] and "cpu_left" in other["carry_leaves_differing"]
+    assert (out["start_event"], out["residents"]) == (2, 2)
+    assert {v["events"] for v in out["lanes"].values()} == {5}
+    got = out["lanes"]["best_fit"]
+    # a wrong expectation fails the step instead of being waved through
+    bad = chip_smoke.step_evaluate_forked(
+        forked, 3, {"best_fit": (got["scheduled"] + 1, 0)})
+    assert not bad["ok"] and bad["mismatch"][0]["policy"] == "best_fit"
+
+
 def test_step_evolve_micro(micro_cli, tmp_path):
     out = chip_smoke.step_evolve(str(tmp_path), generations=1,
                                  population_size=7)

@@ -239,7 +239,7 @@ def test_a_program_without_the_ring_reads_as_nothing(monkeypatch):
 def test_every_span_metric_is_declared_with_its_files():
     bench = json.load(open(os.path.join(cells.ROOT, "BENCHMARK.json")))
     declared = {m["name"]: m for m in bench["per_layer"]}
-    assert len(SPAN_METRICS) == 22
+    assert len(SPAN_METRICS) == 23     # + sim.fork_state_ms (PR 31)
     for name in SPAN_METRICS:
         assert name in declared, name
         meta = json.load(open(os.path.join(cells.HERE, "metrics",

@@ -159,6 +159,7 @@ def test_flat_engine_champions_rescored_on_exact(tmp_path):
         payload = json.load(f)
     assert {"score", "search_score", "search_engine"} <= set(payload)
     assert payload["search_engine"] == "flat"
+    assert not {"score_engine", "start_event"} & set(payload)  # rescored
     assert payload["search_score"] == fs.best[1]
     # the persisted score really is the exact engine's verdict on this code
     want = float(simulate(wl, transpiler.transpile(payload["code"])).policy_score)

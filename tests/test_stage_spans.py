@@ -213,7 +213,8 @@ def test_generation_leaves_the_tier_tree_without_a_profiler(micro_workload):
     got = _since(mark)
     assert all(r.ok for r in recs)
     roots = [r for r in got if r.name == "tier/evaluate"]
-    assert len(roots) == 1 and roots[0].fields == {"candidates": 4}
+    assert len(roots) == 1 and roots[0].fields == {"candidates": 4,
+                                                   "start_event": 0}
     root = roots[0]
     kids = [r for r in got if r.parent_id == root.span_id]
     assert {r.name for r in kids} == TIER_SPANS      # no fallback ran
@@ -317,8 +318,8 @@ def test_four_device_generation_leaves_the_mesh_spans(micro_workload,
     # sees, and the register file one device (2 lanes here) carries
     cap = vm.capacity_bucket(longest)
     assert launch.fields == {
-        "lanes": 8, "shards": 4, "slots": longest, "capacity": cap,
-        "nodes": c.n_padded, "view": c.n_padded,
+        "lanes": 8, "shards": 4, "start_event": 0, "slots": longest,
+        "capacity": cap, "nodes": c.n_padded, "view": c.n_padded,
         "register_bytes": 2 * vm.register_rows(cap) * c.n_padded
         * c.g_padded * 8}
     assert longest < launch.fields["capacity"]

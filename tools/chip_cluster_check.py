@@ -3,6 +3,7 @@ inflated``), on the machine with the chip. Outside the benchmark: nothing
 here is a cell, and nothing is measured on another backend (exit 3).
 
     python tools/chip_cluster_check.py whole --seed N
+    python tools/chip_cluster_check.py forked --seed N
     python tools/chip_cluster_check.py dense --seed N [--events 32]
 
 ``whole``: ONE whole evaluation (no step cap: fill, pressure, drain, about
@@ -11,6 +12,12 @@ here is a cell, and nothing is measured on another backend (exit 3).
 ``SimConfig``, the flat engine; ``fp_dedup`` off so that 8 jittered
 sources stay 8 lanes), compared with the plain reference's whole free run
 under the same rule, fitness included.
+
+``forked``: ``whole`` from the pinned snapshot of ``openb1523-loaded``:
+every lane starts after the snapshot's 5,888 arrivals and runs to the
+queue's end (7,500-8,750 further events), compared with
+``plain_sim_loaded.simulate_from``; the first fitness a code cell's
+configuration compares on the chip.
 
 ``dense``: the same 8 lanes for ``--events`` events under the rule the
 program chooses (64) and DENSE (an explicit ``node_prefilter_k`` of the
@@ -34,6 +41,7 @@ from chipbench import cells  # noqa: E402
 from chipbench.drivers import common  # noqa: E402
 
 CELL = "openb1523-inflated.codegen8"
+LOADED = "openb1523-loaded.codegen8"
 DEVICE = ("tier/vm_batch/launch", "tier/vm_batch/wait_device")
 
 
@@ -41,13 +49,26 @@ def say(**row) -> None:
     print(json.dumps(row), flush=True)
 
 
-def _inputs(seed: int):
-    cell = cells.load_cell(CELL)
+def _inputs(seed: int, name: str = CELL):
+    """(cell, files, workload, sources, reference run): the workload is
+    the driver's own (with the snapshot where the configuration pins one)
+    and the reference is ``simulate`` or, forked, ``simulate_from`` on the
+    snapshot's rows."""
+    cell = cells.load_cell(name)
     files = cells.verify_files(cell.config)
     driver = cells.load_driver(cell.traffic["driver"]).Driver(
         cell, seed, files, None, False)
-    return cell, files, common.parse_workload(cell.config, files), \
-        driver._sources()
+    if "snapshot" in files:
+        import functools
+
+        from chipbench.reference.plain_sim_loaded import simulate_from
+        driver.e0 = int(cell.config["start_event"])
+        wl = driver._workload()
+        reference = functools.partial(simulate_from, rows=driver.rows())
+    else:
+        from chipbench.reference.plain_sim import simulate as reference
+        wl = common.parse_workload(cell.config, files)
+    return cell, files, wl, driver._sources(), reference
 
 
 def _device_seconds(since: int) -> float:
@@ -62,21 +83,30 @@ def _next_seq() -> int:
     return snap[-1].seq + 1 if snap else 0
 
 
-def whole(seed: int) -> bool:
+def whole(seed: int, name: str = CELL) -> bool:
     from chipbench.reference import policies
     from chipbench.reference.compare import Output, compare
-    from chipbench.reference.plain_sim import simulate
     from fks_tpu.funsearch.backend import CodeEvaluator
     from fks_tpu.sim.engine import SimConfig
 
-    cell, files, wl, sources = _inputs(seed)
+    cell, files, wl, sources, simulate = _inputs(seed, name)
     ev = CodeEvaluator(wl, SimConfig(), engine="flat", fp_dedup=False)
+    # when each segment's dispatch returned: the double-buffered runner
+    # syncs on the segment before, so the gaps are segment lengths
+    ticks, count = [], ev._count_segment
+    ev._count_segment = lambda: (ticks.append(time.perf_counter()), count())
     seq, t0 = _next_seq(), time.perf_counter()
     recs = ev.evaluate(sources)
     wall = time.perf_counter() - t0
+    if len(ticks) > 2:
+        say(row="segments", seg_steps=ev.vm_seg_steps,
+            ms_per_event=[round((b - a) / ev.vm_seg_steps * 1e3, 4)
+                          for a, b in zip(ticks[1:], ticks[2:])])
     stats = ev.last_eval_stats
-    events = max(int(r.result.events_processed) for r in recs)
-    say(row="evaluated", seed=seed, wall_s=wall, lockstep_events=events,
+    start = int(stats.get("start_event", 0))
+    events = max(int(r.result.events_processed) for r in recs) - start
+    say(row="evaluated", seed=seed, wall_s=wall, start_event=start,
+        lockstep_events=events, frag_events=stats.get("frag_events"),
         device_ms_per_event=_device_seconds(seq) / events * 1e3,
         prefilter_k=stats["prefilter_k"], segments=stats["segments"],
         vm_batch_lanes=stats["vm_batch_lanes"],
@@ -85,7 +115,7 @@ def whole(seed: int) -> bool:
     ok = stats["vm_batch_lanes"] == len(sources)
     for lane, (rec, code) in enumerate(zip(recs, sources)):
         t0 = time.perf_counter()
-        ref = simulate(cluster, pods, policies.source_policy(code),
+        ref = simulate(cluster, pods, policy=policies.source_policy(code),
                        retry=cell.config["retry_rule"],
                        prefilter_k=int(cell.config["node_prefilter_k"]))
         numbers = compare(f"lane{lane}", ref,
@@ -96,6 +126,7 @@ def whole(seed: int) -> bool:
             reference_fitness=ref.policy_score,
             events=int(rec.result.events_processed),
             scheduled=int(rec.result.scheduled_pods),
+            frag_events=int(rec.result.num_fragmentation_events),
             reference_s=time.perf_counter() - t0,
             compared={n.name.split(".", 1)[1]: n.value for n in numbers},
             ok=all(n.ok for n in numbers))
@@ -108,7 +139,7 @@ def dense(seed: int, events: int) -> bool:
     from fks_tpu.funsearch.backend import CodeEvaluator
     from fks_tpu.sim.engine import SimConfig
 
-    _, _, wl, sources = _inputs(seed)
+    _, _, wl, sources, _ = _inputs(seed)
     n = wl.cluster.n_padded
     out, placed = {}, {}
     for name, k in (("rule", 0), ("dense", n)):
@@ -133,7 +164,7 @@ def dense(seed: int, events: int) -> bool:
 
 def main(argv=None) -> int:
     ap = argparse.ArgumentParser(description=__doc__.splitlines()[0])
-    ap.add_argument("what", choices=("whole", "dense"))
+    ap.add_argument("what", choices=("whole", "forked", "dense"))
     ap.add_argument("--seed", type=int, required=True)
     ap.add_argument("--events", type=int, default=32)
     a = ap.parse_args(argv)
@@ -144,7 +175,8 @@ def main(argv=None) -> int:
         print("chip_cluster_check: no TPU", file=sys.stderr)
         return 3
     place_compile_cache()
-    ok = whole(a.seed) if a.what == "whole" else dense(a.seed, a.events)
+    ok = (dense(a.seed, a.events) if a.what == "dense"
+          else whole(a.seed, LOADED if a.what == "forked" else CELL))
     return 0 if ok else 1
 
 
