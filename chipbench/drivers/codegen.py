@@ -19,8 +19,10 @@ import numpy as np
 
 from chipbench import cells
 from chipbench.drivers import common
+from chipbench.reduce import anchor
 from chipbench.reference import policies
 from chipbench.reference.compare import Output, compare
+from chipbench.reference.nearties import admit
 from chipbench.reference.plain_sim import simulate
 
 #: a parenthesised float literal, as the ledger's champions carry their
@@ -130,6 +132,14 @@ class Driver:
         return {"lanes": lanes, "lane_events": sum(ev),
                 "lockstep_events": max(ev, default=0)}
 
+    @staticmethod
+    def device_stage(call, for_s: float):
+        """Where the traced slice belongs in a generation: from the launch
+        of the batched VM to the end of the wait for it (on four chips the
+        segmented runner waits for its segments inside ``launch``)."""
+        return anchor.between(call, "tier/vm_batch/launch",
+                              "tier/vm_batch/wait_device")
+
     def counters(self) -> dict:
         out = {"call_seconds": self.call_s, "lockstep_events": self.events}
         if self.profiler:
@@ -141,15 +151,26 @@ class Driver:
     def attempted_failed(self, rows) -> tuple:
         return sum(r["lanes"] for r in rows), self.failed
 
+    def _policy(self, lane: int):
+        """The lane's source for the plain reference, in the precision
+        the configuration states (``guarantees.score_dtype``)."""
+        return policies.source_policy(
+            self.sources[lane],
+            dtype=self.cell.config["guarantees"]["score_dtype"])
+
     def check(self) -> list:
         cluster, pods = common.reference_inputs(self.cell.config, self.files)
         numbers = []
         for lane in range(len(self.sources)):
             got = Output.of_lane(self.last[lane].result, pods.p)
-            ref = simulate(cluster, pods,
-                           policies.source_policy(self.sources[lane]),
-                           retry=self.cell.config["retry_rule"],
-                           max_steps=self.k)
+            ref, ties = admit(
+                lambda decide, lane=lane: simulate(
+                    cluster, pods, self._policy(lane),
+                    retry=self.cell.config["retry_rule"],
+                    max_steps=self.k, decide=decide),
+                got.assigned_node, self.cell.config["guarantees"],
+                f"lane{lane}")
+            numbers.append(ties)
             numbers += compare(f"lane{lane}", ref, got,
                                self.cell.config["guarantees"])
         return numbers

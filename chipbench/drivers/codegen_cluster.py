@@ -13,8 +13,8 @@ killed. And the comparison hands the same rule to the plain reference.
 from __future__ import annotations
 
 from chipbench.drivers import codegen, common
-from chipbench.reference import policies
 from chipbench.reference.compare import Output, compare
+from chipbench.reference.nearties import admit
 from chipbench.reference.plain_sim import simulate
 
 
@@ -48,10 +48,15 @@ class Driver(codegen.Driver):
         numbers = []
         for lane in range(len(self.sources)):
             got = Output.of_lane(self.last[lane].result, pods.p)
-            ref = simulate(cluster, pods,
-                           policies.source_policy(self.sources[lane]),
-                           retry=self.cell.config["retry_rule"],
-                           max_steps=self.k, prefilter_k=self._rule())
+            ref, ties = admit(
+                lambda decide, lane=lane: simulate(
+                    cluster, pods, self._policy(lane),
+                    retry=self.cell.config["retry_rule"],
+                    max_steps=self.k, prefilter_k=self._rule(),
+                    decide=decide),
+                got.assigned_node, self.cell.config["guarantees"],
+                f"lane{lane}")
+            numbers.append(ties)
             numbers += compare(f"lane{lane}", ref, got,
                                self.cell.config["guarantees"])
         return numbers

@@ -39,8 +39,8 @@ import dataclasses
 import numpy as np
 
 from chipbench.drivers import codegen, codegen_cluster, common
-from chipbench.reference import policies
 from chipbench.reference.compare import Number, Output, compare
+from chipbench.reference.nearties import admit
 
 F = np.float32
 
@@ -201,10 +201,15 @@ class Driver(codegen_cluster.Driver):
         rows = self.rows()
         numbers, failed = [], 0
         for lane in range(len(self.sources)):
-            ref = simulate_from(cluster, pods, rows,
-                                policies.source_policy(self.sources[lane]),
-                                retry=self.cell.config["retry_rule"],
-                                max_steps=self.k, prefilter_k=self._rule())
+            ref, ties = admit(
+                lambda decide, lane=lane: simulate_from(
+                    cluster, pods, rows, self._policy(lane),
+                    retry=self.cell.config["retry_rule"],
+                    max_steps=self.k, prefilter_k=self._rule(),
+                    decide=decide),
+                np.asarray(self.last[lane].result.assigned_node)[:pods.p],
+                self.cell.config["guarantees"], f"lane{lane}")
+            numbers.append(ties)
             numbers += compare_whole(f"lane{lane}", ref,
                                      self.last[lane].result, pods.p,
                                      self.cell.config["guarantees"])

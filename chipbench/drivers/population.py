@@ -16,6 +16,7 @@ from chipbench import cells
 from chipbench.drivers import common
 from chipbench.reference import policies
 from chipbench.reference.compare import Output, compare
+from chipbench.reference.nearties import admit
 from chipbench.reference.plain_sim import simulate
 
 
@@ -83,6 +84,12 @@ class Driver:
         return {"lanes": len(trunc), "evals": int((~trunc & ~bad).sum()),
                 "lockstep_steps": steps}
 
+    @staticmethod
+    def device_stage(call, for_s: float):
+        """The call is one device program: the traced slice may lie
+        anywhere in it."""
+        return call.t0, call.t1
+
     def counters(self) -> dict:
         return {"call_seconds": self.call_s, "lockstep_steps": self.steps,
                 "truncated_lanes": self.truncated, "lanes": self.lanes_run}
@@ -107,11 +114,15 @@ class Driver:
         numbers = []
         for lane in picks:
             got = Output.of_lane(res, p, lane)
-            ref = simulate(cluster, pods,
-                           policies.parametric_policy(self.weights[lane]),
-                           retry=self.cell.config["retry_rule"],
-                           max_steps=self.max_steps)
             tag = f"lane{lane}" + ("t" if got.truncated else "c")
+            ref, ties = admit(
+                lambda decide, lane=lane: simulate(
+                    cluster, pods,
+                    policies.parametric_policy(self.weights[lane]),
+                    retry=self.cell.config["retry_rule"],
+                    max_steps=self.max_steps, decide=decide),
+                got.assigned_node, self.cell.config["guarantees"], tag)
+            numbers.append(ties)
             numbers += compare(tag, ref, got,
                                self.cell.config["guarantees"])
         return numbers

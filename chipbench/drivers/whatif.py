@@ -18,6 +18,7 @@ from chipbench import cells
 from chipbench.drivers import common
 from chipbench.reference import policies
 from chipbench.reference.compare import Output, compare
+from chipbench.reference.nearties import admit
 from chipbench.reference.plain_sim import simulate
 
 FIELDS = (("cpu_milli", "cpu"), ("memory_mib", "mem"),
@@ -125,6 +126,32 @@ class Driver:
                 "lockstep_events": events,
                 "chunks": len(by_bucket)}
 
+    @staticmethod
+    def device_stage(call, for_s: float):
+        """Where the traced slice belongs in a coalesced call: the wait for
+        the LARGEST chunk (bucket x lanes: most of the call's device time
+        is its one op-slot loop), so that the slice reads a loop and not
+        the seams between chunks; from that chunk's enqueue where the wait
+        alone is shorter than the slice."""
+        stacks = [r for r in call.spans
+                  if r.name == "serve/chunk/stack" and r.fields]
+        if not stacks:
+            return None
+        big = max(stacks, key=lambda r: (r.fields.get("bucket", 0)
+                                         * r.fields.get("lanes", 0), r.t0))
+
+        def of(name):
+            return [r for r in call.spans if r.name == name
+                    and r.trace_id == big.trace_id
+                    and (r.fields or {}).get("chunk") == big.fields["chunk"]]
+
+        wait, enqueue = of("serve/chunk/wait_device"), of("serve/chunk/enqueue")
+        if not wait:
+            return None
+        if wait[0].t1 - wait[0].t0 >= for_s or not enqueue:
+            return wait[0].t0, wait[0].t1
+        return enqueue[0].t0, wait[0].t1
+
     def counters(self) -> dict:
         batches = self.service.summary(record=False)["batches"] \
             - self.batches0
@@ -142,7 +169,9 @@ class Driver:
         queries, answers = self.last
         env = self.engine.envelope
         numbers = []
-        policy = policies.source_policy(self.champion.code)
+        policy = policies.source_policy(
+            self.champion.code,
+            dtype=self.cell.config["guarantees"]["score_dtype"])
         for j, ((start, rows), a) in enumerate(zip(queries, answers)):
             n = len(rows)
             if "error" in a:
@@ -156,13 +185,17 @@ class Driver:
                          failed=bool(a["failed"]),
                          truncated=bool(a["truncated"]))
             bucket = env.pod_bucket_for(n)
-            ref = simulate(
-                self.cluster, self.pods.take(range(start, start + n),
-                                             query=True),
-                policy, retry=self.cell.config["retry_rule"],
-                max_steps=max(64, int(self.cell.config["max_steps_factor"])
-                              * bucket),
-                prefilter_k=self.k_ref)
+            taken = self.pods.take(range(start, start + n), query=True)
+            ref, ties = admit(
+                lambda decide, taken=taken, bucket=bucket: simulate(
+                    self.cluster, taken, policy,
+                    retry=self.cell.config["retry_rule"],
+                    max_steps=max(
+                        64, int(self.cell.config["max_steps_factor"])
+                        * bucket),
+                    prefilter_k=self.k_ref, decide=decide),
+                nodes, self.cell.config["guarantees"], f"query{j}n{n}")
+            numbers.append(ties)
             numbers += compare(f"query{j}n{n}", ref, got,
                                self.cell.config["guarantees"])
         return numbers

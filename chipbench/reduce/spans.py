@@ -94,6 +94,10 @@ def _checked(extents: List[tuple], records, dropped: int,
     # began and is still held proves that nothing of the window has left
     if dropped and not (records and records[0].t1 <= start):
         return None
+    return _calls(extents, records)
+
+
+def _calls(extents, records) -> List[Call]:
     return [Call(t0, t1, [r for r in records if r.t0 >= t0 and r.t1 <= t1])
             for t0, t1 in extents]
 
@@ -146,6 +150,14 @@ def window_calls(ctx: dict) -> Optional[List[Call]]:
             ctx["_span_calls"] = select_generations(
                 *got, len(ctx.get("rows", ())), ctx.get("call_seconds"))
     return ctx["_span_calls"]
+
+
+def calls_between(extents: Sequence[tuple]) -> List[Call]:
+    """Calls whose ``(t0, t1)`` the caller stamped itself (the traced
+    slice's own calls), each with the ring's records inside it; without a
+    ring, without spans."""
+    got = ring()
+    return _calls(extents, got[0] if got else [])
 
 
 def named(calls: Sequence[Call], names: Sequence[str]) -> list:

@@ -25,6 +25,9 @@ class Number:
     name: str
     value: float
     limit: float
+    #: the kind of row it is printed as; a count of admitted near ties
+    #: (``nearties.admit``) is compared like any other, and says "admitted"
+    row: str = "compared"
 
     @property
     def ok(self) -> bool:

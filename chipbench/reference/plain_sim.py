@@ -216,10 +216,12 @@ def _best_fit_gpus(milli_left: np.ndarray, mask: np.ndarray, req: int,
 def simulate(cluster: Cluster, pods: Pods, policy: Policy, *,
              retry: str = "heap_array", max_steps: Optional[int] = None,
              prefilter_k: int = 0, interval: float = 0.05,
-             acc_dtype=F) -> Result:
+             acc_dtype=F, decide=None) -> Result:
     """``acc_dtype``: the evaluator's accumulation type (utilization and
     fragmentation sums, the fitness): float32 as the configurations state.
-    Only the control passes a lower one."""
+    Only the control passes a lower one. ``decide(i, cand, scores)``,
+    where given, returns for pod ``i`` the winner's position in ``cand``
+    in place of the argmax (``chipbench/reference/nearties.py`` is its one user)."""
     F = acc_dtype  # noqa: N806 — shadows the module's float32
     if retry not in ("heap_array", "earliest_delete"):
         raise ValueError(f"unknown retry rule {retry!r}")
@@ -273,7 +275,8 @@ def simulate(cluster: Cluster, pods: Pods, policy: Policy, *,
             else:
                 cand = all_nodes
             scores = np.asarray(policy(pod, s, cand), np.int64)
-            k = int(np.argmax(scores))
+            k = int(np.argmax(scores)) if decide is None \
+                else int(decide(i, cand, scores))
             best = int(scores[k])
             node = int(cand[k]) if best > 0 else -1
             if node >= 0:

@@ -74,7 +74,8 @@ def _snapshot_placement(rows: Rows, i: int, kind: int, pod: PodObj,
 def simulate_from(cluster: Cluster, pods: Pods, rows: Rows, policy, *,
                   retry: str = "heap_array",
                   max_steps: Optional[int] = None, prefilter_k: int = 0,
-                  interval: float = 0.05, acc_dtype=F) -> Result:
+                  interval: float = 0.05, acc_dtype=F,
+                  decide=None) -> Result:
     """``plain_sim.simulate`` with steps ``< len(rows)`` decided by
     ``rows``. ``max_steps`` is absolute: the prefix counts."""
     F = acc_dtype  # noqa: N806 — shadows the module's float32
@@ -138,7 +139,8 @@ def simulate_from(cluster: Cluster, pods: Pods, rows: Rows, policy, *,
             else:
                 cand = all_nodes
             scores = np.asarray(policy(pod, s, cand), np.int64)
-            k = int(np.argmax(scores))
+            k = int(np.argmax(scores)) if decide is None \
+                else int(decide(i, cand, scores))
             best = int(scores[k])
             node = int(cand[k]) if best > 0 else -1
             if node >= 0:

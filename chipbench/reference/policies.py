@@ -1,8 +1,19 @@
 """Policies for the plain reference.
 
 - ``source_policy``: a candidate's source run as the Python function it
-  is (``priority_function(pod, node)`` per node, float64), in an
-  environment holding only upstream's whitelisted builtins and ``math``.
+  is (``priority_function(pod, node)`` per node), in an environment
+  holding only upstream's whitelisted builtins and ``math``. In
+  ``dtype="float64"`` the fields are Python ints and the arithmetic is
+  CPython's binary64, as upstream runs. In ``dtype="float32"``, what a
+  configuration's ``guarantees.score_dtype`` states and the drivers pass,
+  every field the source reads, and every number it gets from ``len``,
+  ``sum``, ``int``, ``round`` or ``float``, is a ``numpy.float32``
+  scalar: an operation with such an operand then rounds to float32, one
+  rounding an operation in the source's own order, and arithmetic among
+  the source's own literals stays Python's (NumPy's scalar rules; they
+  are what the configuration's words mean: one float32 register model,
+  constants folded before). The fields are integers under 2**24, exact in
+  either type, so the two differ only where a score is rounded.
 - ``parametric_policy``: the weight-vector policy (16 features, float32)
   written out in NumPy from its definition in the README/ROADMAP E1
   config: ``max(1, int(f . w * 10000))`` on feasible nodes, 0 elsewhere.
@@ -32,19 +43,40 @@ def _bf16(x):
                  .astype(np.float32))
 
 
-def source_policy(code: str, low_precision: bool = False):
+class _Data:
+    """An entity as a float32 register model holds it: every field a
+    ``numpy.float32``, a GPU list a list of such entities."""
+
+    def __init__(self, obj):
+        for name in obj.__slots__:
+            v = getattr(obj, name)
+            setattr(self, name,
+                    [_Data(g) for g in v] if isinstance(v, list) else F(v))
+
+
+def source_policy(code: str, low_precision: bool = False,
+                  dtype: str = "float64"):
+    if dtype not in ("float64", "float32"):
+        raise ValueError(f"source_policy: no dtype {dtype!r}")
     env = {k: getattr(builtins, k) for k in (
         "abs", "min", "max", "sum", "len", "range", "enumerate", "int",
         "float", "bool", "str", "round", "sorted")}
     if low_precision:
         env["int"] = lambda x: int(_bf16(x))
+    view = lambda obj: obj  # noqa: E731
+    if dtype == "float32":
+        # what these make of data is data: a count or a truncated score
+        # that is divided next divides in float32, not as two Python ints
+        for name in ("len", "sum", "int", "round", "float"):
+            env[name] = (lambda fn: lambda *a: F(fn(*a)))(env[name])
+        view = _Data
     glob = {"__builtins__": env, "math": math}
     exec(compile(code, "<candidate>", "exec"), glob)  # noqa: S102 — repo's own champions
     fn = glob["priority_function"]
 
     def policy(pod, state, cand):
-        nodes = state.nodes
-        return [int(max(0, fn(pod, nodes[int(i)]))) for i in cand]
+        nodes, pod = state.nodes, view(pod)
+        return [int(max(0, fn(pod, view(nodes[int(i)])))) for i in cand]
 
     return policy
 

@@ -56,14 +56,19 @@ def control_outputs(d, kind: str) -> None:
         cluster, pods = common.reference_inputs(d.cell.config, d.files)
         for lane, rec in enumerate(d.last):
             low = simulate(cluster, pods,
-                           policies.source_policy(d.sources[lane], True),
+                           policies.source_policy(
+                               d.sources[lane], True,
+                               dtype=d.cell.config["guarantees"][
+                                   "score_dtype"]),
                            retry=d.cell.config["retry_rule"], max_steps=d.k,
                            acc_dtype=BF16)
             rec.result = low
     else:
         queries, answers = d.last
         env = d.engine.envelope
-        policy = policies.source_policy(d.champion.code, True)
+        policy = policies.source_policy(
+            d.champion.code, True,
+            dtype=d.cell.config["guarantees"]["score_dtype"])
         for (start, rows), a in zip(queries, answers):
             n = len(rows)
             low = simulate(

@@ -27,6 +27,7 @@ from chipbench import cells
 from chipbench.drivers import common
 from chipbench.reference import policies
 from chipbench.reference.compare import Output, compare
+from chipbench.reference.nearties import admit
 from chipbench.reference.plain_sim import simulate
 
 CELL = "openb1523-inflated.codegen8"
@@ -38,13 +39,20 @@ def control_numbers(config: dict, files: dict, sources: list,
     cluster, pods = common.reference_inputs(config, files)
     kw = dict(retry=config["retry_rule"], max_steps=max_steps,
               prefilter_k=int(config["node_prefilter_k"]))
+    dtype = config["guarantees"]["score_dtype"]
     out = []
     for lane, code in enumerate(sources):
-        ref = simulate(cluster, pods, policies.source_policy(code), **kw)
-        low = simulate(cluster, pods, policies.source_policy(code, True),
+        low = simulate(cluster, pods,
+                       policies.source_policy(code, True, dtype=dtype),
                        acc_dtype=ml_dtypes.bfloat16, **kw)
-        out.append(compare(f"lane{lane}", ref, Output.of_lane(low, pods.p),
-                           config["guarantees"]))
+        ref, ties = admit(
+            lambda decide, code=code: simulate(
+                cluster, pods, policies.source_policy(code, dtype=dtype),
+                decide=decide, **kw),
+            low.assigned_node, config["guarantees"], f"lane{lane}")
+        out.append([ties] + compare(f"lane{lane}", ref,
+                                    Output.of_lane(low, pods.p),
+                                    config["guarantees"]))
     return out
 
 

@@ -26,6 +26,7 @@ from chipbench import cells
 from chipbench.drivers import common
 from chipbench.reference import policies
 from chipbench.drivers.codegen_loaded import compare_whole
+from chipbench.reference.nearties import admit
 from chipbench.reference.plain_sim_loaded import simulate_from
 from chipbench.selftest.control_cluster import _largest
 
@@ -39,15 +40,20 @@ def control_numbers(config: dict, files: dict, sources: list,
     cluster, pods = common.reference_inputs(config, files)
     kw = dict(retry=config["retry_rule"], max_steps=max_steps,
               prefilter_k=int(config["node_prefilter_k"]))
+    dtype = config["guarantees"]["score_dtype"]
     out = []
     for lane, code in enumerate(sources):
-        ref = simulate_from(cluster, pods, rows,
-                            policies.source_policy(code), **kw)
         low = simulate_from(cluster, pods, rows,
-                            policies.source_policy(code, True),
+                            policies.source_policy(code, True, dtype=dtype),
                             acc_dtype=ml_dtypes.bfloat16, **kw)
-        out.append(compare_whole(f"lane{lane}", ref, low, pods.p,
-                                 config["guarantees"]))
+        ref, ties = admit(
+            lambda decide, code=code: simulate_from(
+                cluster, pods, rows,
+                policies.source_policy(code, dtype=dtype), decide=decide,
+                **kw),
+            low.assigned_node, config["guarantees"], f"lane{lane}")
+        out.append([ties] + compare_whole(f"lane{lane}", ref, low, pods.p,
+                                          config["guarantees"]))
     return out
 
 

@@ -1,21 +1,26 @@
 """The selftest's tests (see ``__main__``). Tiny sizes, CPU only."""
 from __future__ import annotations
 
+import collections
 import contextlib
+import glob
 import io
 import json
 import os
 import subprocess
 import sys
+import time
 from unittest import mock
 
 import numpy as np
 
 from chipbench import cells, window
-from chipbench.reduce import xplane
+from chipbench.reduce import anchor, xplane
+from chipbench.reduce.spans import Call
 from chipbench.reference import data, policies
 from chipbench.reference import plain_sim as ps
 from chipbench.reference.compare import Output, compare
+from chipbench.reference.nearties import admit
 
 HERE = os.path.dirname(os.path.abspath(__file__))
 FIXTURES = os.path.join(cells.ROOT, "tests", "fixtures")
@@ -24,9 +29,10 @@ CELLS = ("openb16.param256", "openb1523.whatif8", "openb16.codegen8",
 #: tiny sizes for the CPU: the first 150 pods, 64-event generations of 4
 TINY = {"config": {"pod_limit": 150, "code_eval_max_steps": 64},
         "traffic": {"lanes": 4, "sizes": [4, 8, 20], "max_batch": 3,
-                    "trace_at_s": 0.0, "trace_for_s": 0.05,
+                    "trace_for_s": 0.05,
                     "max_wait_s": 2.0}}
-GUARANTEES = {"fitness_rtol": 16 * 2.0 ** -23}
+GUARANTEES = {"fitness_rtol": 16 * 2.0 ** -23, "score_near_tie_units": 1,
+              "near_ties_per_run": 2}
 
 
 @contextlib.contextmanager
@@ -99,6 +105,210 @@ def test_reduce_leaves_loop_containers_out():
     again = xplane.reduce_events(devices, spans)
     assert again["busy_s"] == red["busy_s"]
     assert xplane.reduce_events({}, spans) is None             # a CPU trace
+
+
+# ------------------------------------------- where the traced slice goes
+
+Rec = collections.namedtuple("Rec", "name t0 t1 trace_id fields",
+                             defaults=("t", None))
+
+
+def _generation(t: float, host_s: float, device_s: float) -> Call:
+    """One generation's spans as the program leaves them: ``host_s`` of
+    preflight, transpile and stacking, ``device_s`` of launch + wait, then
+    40 ms of transfer and records."""
+    d0, d1 = t + host_s, t + host_s + device_s
+    end = d1 + 0.04
+    recs = [Rec("tier/preflight", t, t + 0.05),
+            Rec("tier/transpile", t + 0.05, d0 - 0.02),
+            Rec("tier/vm_batch/stack_programs", d0 - 0.02, d0),
+            Rec("tier/vm_batch/launch", d0, d0 + 0.001),
+            Rec("tier/vm_batch/wait_device", d0 + 0.001, d1),
+            Rec("tier/vm_batch/d2h", d1, d1 + 0.03),
+            Rec("tier/record", d1 + 0.03, end),
+            Rec("tier/evaluate", t, end)]
+    return Call(t, end, recs)
+
+
+def test_the_slice_follows_the_generation_where_three_seconds_do_not():
+    """The four-chip generation as the ledger has it (0.9 s of host stages,
+    2.26 s of device loop), the same with the loop a quarter shorter and
+    with the transpile 0.25 s shorter: the slice lies inside the device
+    stage every time; ``3.0 s after the first call's start``, the accepted
+    harness's constant, only the first time (it then falls into the NEXT
+    call's host stages). Cut further (a loop of 1.1 s, a transpile of
+    0.2 s) the constant lands in the next call's loop by luck; the slice
+    placed from the calls does not care."""
+    from chipbench.drivers import codegen
+
+    for_s = 0.01
+    for host_s, device_s, fixed_is_inside in (
+            (0.9, 2.26, True), (0.9, 1.7, False), (0.65, 2.26, False),
+            (0.9, 1.1, True), (0.27, 2.26, True)):
+        calls, t = [], 100.0
+        for _ in range(5):
+            calls.append(_generation(t, host_s, device_s))
+            t = calls[-1].t1
+        stage = anchor.stage_of(calls, codegen.Driver.device_stage, for_s)
+        assert stage.source == anchor.FROM_DRIVER
+        assert abs(stage.d0 - host_s) < 1e-9, stage
+        assert abs(stage.d1 - host_s - device_s) < 1e-9, stage
+        begin = anchor.place(stage, for_s, 0.5)
+        assert stage.d0 <= begin and begin + for_s <= stage.d1
+        assert abs(begin + for_s / 2 - (stage.d0 + stage.d1) / 2) < 1e-9
+        # where 3.0 s of wall clock after the first call's start falls
+        fixed = 3.0 % stage.call_s
+        assert (stage.d0 <= fixed and fixed + for_s <= stage.d1) \
+            is fixed_is_inside, (host_s, device_s, fixed)
+        if not fixed_is_inside:      # it is in the next call's host stages
+            assert 3.0 >= stage.call_s and fixed + for_s < host_s
+        # a phase near the end is clipped to end before the stage does
+        late = anchor.place(stage, for_s, 1.0)
+        assert abs(late + for_s - stage.d1) < 1e-9
+        assert anchor.place(stage, for_s, 0.0) == stage.d0
+
+
+def test_without_spans_the_slice_is_placed_in_the_calls_extent():
+    """A program without the ring (or a selection that failed its check)
+    leaves the window's rows: the stage is the call, and the row says so;
+    a stage shorter than the slice is covered from its start."""
+    from chipbench.drivers import codegen, population
+
+    rows = [{"t0": 0.0, "t1": 3.2}, {"t0": 3.2, "t1": 6.5},
+            {"t0": 6.5, "t1": 9.7}]
+    calls = anchor.calls_of_rows(rows)
+    stage = anchor.stage_of(calls, codegen.Driver.device_stage, 0.03)
+    assert stage.source == anchor.FROM_EXTENT
+    assert (stage.d0, round(stage.d1, 9), round(stage.call_s, 9)) \
+        == (0.0, 3.2, 3.2)
+    assert abs(anchor.place(stage, 0.03, 0.5) - (1.6 - 0.015)) < 1e-9
+    # one call without the stage among calls that have it: the extent too
+    mixed = [_generation(0.0, 0.9, 2.26), calls[1]]
+    assert anchor.stage_of(mixed, codegen.Driver.device_stage,
+                           0.03).source == anchor.FROM_EXTENT
+    # the population's call is one device program: its extent by intent
+    whole = anchor.stage_of(calls, population.Driver.device_stage, 0.1)
+    assert whole.source == anchor.FROM_DRIVER and whole.d0 == 0.0
+    short = anchor.Stage(0.5, 0.52, 1.0, anchor.FROM_DRIVER)
+    assert anchor.place(short, 0.25, 0.5) == 0.5
+    assert anchor.innermost([Rec("a", 0, 10), Rec("b", 2, 3)], 2.5) == "b"
+    assert anchor.innermost([Rec("a", 0, 10)], 11) is None
+
+
+def _coalesced(t: float, waits) -> Call:
+    """A whatif call of three chunks (2 x 16, 4 x 64, 2 x 256) whose
+    ``serve/chunk/wait_device`` spans last ``waits``."""
+    recs, at = [Rec("serve/batch", t, t + 1.05, "b")], t
+    for chunk, ((bucket, lanes), wait) in enumerate(zip(
+            ((16, 2), (64, 4), (256, 2)), waits)):
+        f = {"chunk": chunk}
+        recs += [Rec("serve/chunk/stack", at, at + 0.2, "b",
+                     {**f, "bucket": bucket, "lanes": lanes}),
+                 Rec("serve/chunk/enqueue", at + 0.2, at + 0.21, "b", f),
+                 Rec("serve/chunk/wait_device", at + 0.25, at + 0.25 + wait,
+                     "b", f)]
+        at += 0.25
+    # another batch's chunk 2 in the same extent is not this call's
+    recs.append(Rec("serve/chunk/wait_device", t, t + 0.9, "other",
+                    {"chunk": 2}))
+    return Call(t, t + 1.05, recs)
+
+
+def test_the_serving_slice_reads_the_largest_chunks_loop():
+    from chipbench.drivers import whatif
+
+    call = _coalesced(50.0, (0.001, 0.002, 0.55))
+    t0, t1 = whatif.Driver.device_stage(call, 0.25)
+    assert (round(t0 - 50.0, 9), round(t1 - 50.0, 9)) == (0.75, 1.3)
+    stage = anchor.stage_of([call], whatif.Driver.device_stage, 0.25)
+    begin = anchor.place(stage, 0.25, 0.5)
+    assert stage.d0 <= begin and begin + 0.25 <= stage.d1
+    # a wait shorter than the slice: from the chunk's enqueue on
+    call = _coalesced(50.0, (0.001, 0.002, 0.2))
+    t0, t1 = whatif.Driver.device_stage(call, 0.25)
+    assert (round(t0 - 50.0, 9), round(t1 - 50.0, 9)) == (0.7, 0.95)
+    assert whatif.Driver.device_stage(Call(0.0, 1.0, []), 0.25) is None
+
+
+class _StubDriver:
+    """Whole calls of 100 ms without a span: the slice is placed in the
+    call's extent."""
+    span = "bench/stub_call"
+
+    def __init__(self):
+        self.made = []
+
+    def call(self, i: int) -> dict:
+        self.made.append(i)
+        time.sleep(0.1)
+        return {}
+
+    @staticmethod
+    def device_stage(call, for_s):
+        return None
+
+
+def test_a_missed_slice_is_retaken_then_named():
+    """A slice in which no device instruction worked is taken again in
+    the next call, three times in all; after the third miss the run fails
+    and says where each fell. Few device events are no miss."""
+    from chipbench import run
+
+    hit = {"busy_s": 1e-6, "window_s": 0.01, "chips": 1, "device_events": 1,
+           "device_ops": [], "idle_gaps": []}
+    window = [Call(0.0, 0.1, [])]
+    for answers, attempts in (([hit], 1), ([None, dict(hit, device_events=0),
+                                            hit], 3)):
+        reduced = mock.Mock(side_effect=answers)
+        d = _StubDriver()
+        with mock.patch.object(xplane, "reduce_trace", reduced), \
+                mock.patch.object(glob, "glob", lambda *a: ["a trace"]):
+            got = run.trace_slice(d, window, 0.01, 0.5, 0.001, True)
+        assert got["attempts"] == attempts == reduced.call_count, got
+        assert got["device"] is hit and got["stage_from"] == "call_extent"
+        assert got["calls_during_trace"] == len(d.made) >= attempts
+        assert d.made == [-2 - i for i in range(len(d.made))]
+        assert got["call_offset_s"][1] - got["call_offset_s"][0] >= 0.01
+        assert got["profiler_start_s"] > 0 and got["stage_s"][0] == 0.0
+        assert got["inside"] in ("bench/stub_call", "_between_calls_")
+    reduced = mock.Mock(side_effect=[None, None, None])
+    with mock.patch.object(xplane, "reduce_trace", reduced), \
+            mock.patch.object(glob, "glob", lambda *a: ["a trace"]):
+        try:
+            run.trace_slice(_StubDriver(), window, 0.01, 0.5, 0.001, True)
+        except SystemExit as e:
+            said = str(e)
+        else:
+            raise AssertionError("three misses gave a result")
+    assert reduced.call_count == 3
+    assert said.count("s of a call") == 3 and said.count("phase") == 3, said
+    assert "from call_extent" in said and "inside " in said, said
+    # device events, but outside the device stage its own call turned out
+    # to have (the window's calls showed it at 0-20 ms, the calls made
+    # here have it at 100-200 ms of 200): retaken, placed by those
+    class Moved(_StubDriver):
+        def call(self, i):
+            self.made.append(i)
+            time.sleep(0.2)
+            return {}
+
+        @staticmethod
+        def device_stage(call, for_s):
+            return (call.t0, call.t0 + 0.02) if call.t1 < 1.0 \
+                else (call.t0 + 0.1, call.t0 + 0.2)
+
+    reduced = mock.Mock(side_effect=[hit, hit, hit])
+    with mock.patch.object(xplane, "reduce_trace", reduced), \
+            mock.patch.object(glob, "glob", lambda *a: ["a trace"]):
+        got = run.trace_slice(Moved(), [Call(0.0, 0.2, [])], 0.02, 0.5, 0.001,
+                              True)
+    assert got["attempts"] == 2 and got["in_stage"], got
+    assert got["stage_from"] == "driver" and got["stage_s"][0] > 0.09, got
+    # the selftest's CPU runs: no device plane is a legal answer, once
+    reduced = mock.Mock(side_effect=[None])
+    with mock.patch.object(xplane, "reduce_trace", reduced):
+        got = run.trace_slice(_StubDriver(), window, 0.01, 0.5, 0.001, False)
+    assert got["attempts"] == 1 and got["device"] is None
 
 
 # ------------------------------------------------------- rate arithmetic
@@ -247,7 +457,11 @@ def test_every_metric_and_cell_has_its_files():
     a, b = (cells.load_cell(n).traffic for n in ("openb16.codegen8",
                                                  "openb16.codegen8x4"))
     b.pop("same_as")
-    for t in (a, b):    # four chips write four times the device events
+    # four chips write four times the device events, and a shard that
+    # holds both seed policies is done at half of the stage
+    assert b.pop("trace_phase") < 0.5 and "trace_phase" not in a
+    assert b.pop("trace_phase_why")
+    for t in (a, b):
         t.pop("trace_for_s"), t.pop("traced")
     assert a == b
 
@@ -261,6 +475,7 @@ def test_work_per_call_is_the_same_for_every_seed():
     from fks_tpu.serve import ShapeEnvelope
 
     seen = {c: set() for c in CELLS}
+    longest = 0        # live ops of the longest program any seed compiles
     for seed in range(4):
         for name in CELLS:
             cell = cells.load_cell(name)
@@ -269,12 +484,12 @@ def test_work_per_call_is_the_same_for_every_seed():
                 cell, seed, files, None, False)
             if cell.traffic["driver"] == "codegen":
                 srcs = d._sources()
-                caps = tuple(sorted(
-                    vm.capacity_bucket(int(vm.compile_policy(s, 16, 8).n_ops))
-                    for s in srcs))
+                ops = [int(vm.compile_policy(s, 16, 8).n_ops) for s in srcs]
+                longest = max(longest, *ops)
                 assert len(set(srcs)) == len(srcs) == 8
                 shorts = sum(len(s) < 2000 for s in srcs)   # seed policies
-                seen[name].add((len(srcs), max(caps), shorts, len(srcs)
+                seen[name].add((len(srcs), vm.capacity_bucket(max(ops)),
+                                shorts, len(srcs)
                                 * int(cell.config["code_eval_max_steps"])))
             elif cell.traffic["driver"] == "whatif":
                 _, d.pods = None, data.load_pods(files["trace"])
@@ -292,7 +507,10 @@ def test_work_per_call_is_the_same_for_every_seed():
                                 cell.config["param_eval_max_steps_factor"]))
     assert all(len(v) == 1 for v in seen.values()), seen
     assert seen["openb1523.whatif8"] == {(416, ((16, 2), (64, 4), (256, 2)))}
-    assert seen["openb16.codegen8"] == {(8, 512, 2, 8 * 2048)}
+    # the generation's bucket is the program's: a lowering that packs the
+    # champions into a smaller one moves it for every seed alike
+    assert seen["openb16.codegen8"] == {
+        (8, vm.capacity_bucket(longest), 2, 8 * 2048)}
 
 
 def test_population_driver_permutes_the_pinned_lanes():
@@ -375,7 +593,8 @@ def test_a_tie_broken_another_way_is_a_difference():
     """first_fit scores every feasible node alike, so the reference takes
     the lowest index (upstream's rule). An output that took another of the
     tied nodes for one pod scores no worse anywhere and is still not the
-    reference's trajectory: the reference runs free and follows nothing."""
+    reference's trajectory: ``compare`` has no tolerance of its own (what
+    ``nearties.admit`` does before it is tested further down)."""
     with open(os.path.join(FIXTURES, "golden_fuzz.json")) as f:
         cases = json.load(f)["cases"]
     checked = 0
@@ -413,8 +632,12 @@ def _control(cluster, pods, make_policy, **kw):
     import ml_dtypes
     low = ps.simulate(cluster, pods, make_policy(True),
                       acc_dtype=ml_dtypes.bfloat16, **kw)
-    ref = ps.simulate(cluster, pods, make_policy(False), **kw)
-    return compare("control", ref, Output.of_lane(low, pods.p), GUARANTEES)
+    ref, ties = admit(
+        lambda decide: ps.simulate(cluster, pods, make_policy(False),
+                                   decide=decide, **kw),
+        low.assigned_node, GUARANTEES, "control")
+    return [ties] + compare("control", ref, Output.of_lane(low, pods.p),
+                            GUARANTEES)
 
 
 def test_control_lower_precision_is_not_correct():
@@ -433,11 +656,188 @@ def test_control_lower_precision_is_not_correct():
         code = json.load(f)["code"]
     makers = [lambda lp: policies.parametric_policy(pop[7], lp),
               lambda lp: policies.parametric_policy(pop[1], lp),
-              lambda lp: policies.source_policy(code, lp)]
+              lambda lp: policies.source_policy(code, lp, dtype="float32")]
     for make in makers:
         control = _control(cluster, pods, make, retry="earliest_delete",
                            max_steps=10 ** 6)
         assert not all(n.ok for n in control), control
+
+
+# ------------------------------- the reference's precision is the cell's
+
+#: a weighted sum of two ratios, a count over a length and a literal
+#: product, as the ledger's champions are written
+ROUNDING_SOURCE = """
+def priority_function(pod, node):
+    fit = sum(1 for gpu in node.gpus if gpu.gpu_milli_left >= pod.gpu_milli)
+    score = SCALE * ((0.101951) * (1.0)
+    + (0.344618) * (1.0 - node.cpu_milli_left / max(1, node.cpu_milli_total))
+    + (0.141436) * ((node.memory_mib_left - pod.memory_mib) / max(1, node.memory_mib_total))
+    + (0.384283) * (fit / max(1, len(node.gpus))))
+    return max(1, int(score))
+"""
+
+
+def _one_node(cpu_left, cpu_total, mem_left, mem_total, gpus):
+    nd = ps.NodeObj()
+    nd.cpu_milli_left, nd.cpu_milli_total = cpu_left, cpu_total
+    nd.memory_mib_left, nd.memory_mib_total = mem_left, mem_total
+    nd.gpu_left, nd.gpus = len(gpus), []
+    for left in gpus:
+        g = ps.GPUObj(1000)
+        g.gpu_milli_left = left
+        nd.gpus.append(g)
+    return nd
+
+
+def test_float32_source_policy_rounds_every_operation_to_float32():
+    """``source_policy(dtype="float32")`` is the source with one float32
+    rounding an operation, in the source's order, constants folded in
+    Python first: written out by hand in ``numpy.float32`` for a source of
+    the champions' form, it gives the same integer on every state tried;
+    a count over a length (two Python ints in binary64) divides in
+    float32 too. At the champions' scale of 10,000 the float64 policy
+    parts from it by one, once in some ten thousand scores (seed
+    451715641 met one that decided an argmax); at a scale of 10 million,
+    where a float32 has no digit to spare, it parts on a quarter of the
+    states: the two references are not the same reference, and the one a
+    driver takes is its configuration's ``score_dtype``."""
+    f = np.float32
+    differ = {}
+    for scale in (10000.0, 1.0e7):
+        differ[scale] = _float32_against_hand(
+            ROUNDING_SOURCE.replace("SCALE", repr(scale)), f(scale))
+    assert differ[10000.0][0] < 3, differ
+    assert differ[1.0e7][0] > differ[1.0e7][1] // 5, differ
+
+
+def _float32_against_hand(source: str, scale) -> tuple:
+    """(states on which float64 gives another integer, states tried);
+    every state's float32 score is held to the hand-written one."""
+    f = np.float32
+    p32 = policies.source_policy(source, dtype="float32")
+    p64 = policies.source_policy(source)
+    pod = ps.PodObj()
+    pod.cpu_milli, pod.memory_mib, pod.num_gpu, pod.gpu_milli = 500, 64, 1, 300
+    pod.creation_time = pod.duration_time = 0
+    State = collections.namedtuple("State", "nodes")
+    differ = states = 0
+    for cpu_left in range(1000, 96000, 997):
+        for gpus in ((1000, 200, 700), (1000,) * 6 + (100,), ()):
+            nd = _one_node(cpu_left, 96000, 3 * cpu_left + 17, 393216, gpus)
+            got32 = p32(pod, State([nd]), [0])[0]
+            got64 = p64(pod, State([nd]), [0])[0]
+            fit = f(sum(1 for g in gpus if g >= 300))
+            want = scale * (
+                f(0.101951)
+                + f(0.344618) * (f(1.0) - f(cpu_left) / f(96000))
+                + f(0.141436) * (f(3 * cpu_left + 17 - 64) / f(393216))
+                + f(0.384283) * (fit / f(max(1, len(gpus)))))
+            assert isinstance(want, np.float32)
+            assert got32 == max(1, int(want)), (cpu_left, gpus, got32, want)
+            assert abs(got32 - got64) <= 2e-6 * got64 + 1
+            differ += got32 != got64
+            states += 1
+    return differ, states
+
+
+def test_float32_reference_scores_are_the_vm_programs():
+    """``chipbench.selftest.score_parity`` at the 16-node cell's size on
+    the CPU backend (this process runs float32, as the chip does): the
+    plain reference in the configuration's float32 gives the batched VM's
+    score for every node of every kept state, bit for bit; the same
+    instrument sees upstream's float64 part from the program somewhere in
+    the cluster cell's scores (the reading's own control). On the chip,
+    where the builder runs the module itself, neither count is 0 and every
+    difference is one unit (PERF.md section 2): hence ``nearties``."""
+    from chipbench.selftest import score_parity
+
+    for cell_name, every, some64 in (("openb16.codegen8", 16, False),
+                                     ("openb1523-loaded.codegen8", 256,
+                                      True)):
+        with contextlib.redirect_stdout(io.StringIO()) as out:
+            rc = score_parity.main(["--workload", cell_name, "--seeds",
+                                    "451715641", "--lanes", "2", "--every",
+                                    str(every), "--cpu"])
+        total = json.loads(out.getvalue().splitlines()[-1])
+        assert rc == 0 and total["differ_float32"] == 0, total
+        assert total["scores"] > 1000, total
+        if some64:
+            assert total["differ_float64"] > 0, total
+
+
+# ------------------------------------------- a decision one unit decides
+
+def _loaded_lane(seed: int, lane: int, events: int):
+    """The real ``openb1523-loaded.codegen8`` lane of a seed, cut to
+    ``events`` after the fork: ``run(policy, decide)`` and the source."""
+    from chipbench.drivers import common
+    from chipbench.reference.plain_sim_loaded import simulate_from
+
+    cell = cells.load_cell("openb1523-loaded.codegen8")
+    files = cells.verify_files(cell.config)
+    d = cells.load_driver(cell.traffic["driver"]).Driver(
+        cell, seed, files, None, False)
+    d.e0 = int(cell.config["start_event"])
+    rows = d.rows()
+    cluster, pods = common.reference_inputs(cell.config, files)
+
+    def run(policy, decide=None):
+        return simulate_from(cluster, pods, rows, policy,
+                             retry=cell.config["retry_rule"],
+                             max_steps=len(rows) + events,
+                             prefilter_k=int(cell.config["node_prefilter_k"]),
+                             decide=decide)
+
+    return run, d._sources()[lane], dict(cell.config["guarantees"])
+
+
+def test_a_decision_that_one_unit_decides_is_admitted_and_counted():
+    """The refused run itself (seed 451715641, lane 1, the cell's real
+    size): upstream's float64 arithmetic and the configuration's float32
+    part at ONE decision of 1,024, where the reference's two best nodes
+    are a unit apart, and 311 placements differ after it. Put in the
+    program's place, the float64 run is the float32 reference's once that
+    one decision is admitted: count 1 of 2 allowed, nothing else differs.
+    With no unit stated the difference stands."""
+    run, src, g = _loaded_lane(451715641, 1, 1024)
+    p32 = policies.source_policy(src, dtype="float32")
+    got = run(policies.source_policy(src))
+    free = run(p32)
+    assert int((free.assigned_node != got.assigned_node).sum()) == 311
+    ref, ties = admit(lambda decide: run(p32, decide), got.assigned_node,
+                      g, "lane1")
+    assert (ties.value, ties.limit, ties.ok) == (1.0, 2.0, True)
+    assert (ref.assigned_node == got.assigned_node).all()
+    assert (ref.assigned_gpus == got.assigned_gpus).all()
+    assert ref.num_frag_events == got.num_frag_events
+    ref, ties = admit(lambda decide: run(p32, decide), got.assigned_node,
+                      {}, "lane1")
+    assert ties.value == 0.0
+    assert int((ref.assigned_node != got.assigned_node).sum()) == 311
+
+
+def test_what_is_a_unit_off_at_every_decision_fails_the_count():
+    """The two controls of the count, each within one unit at every single
+    decision: ties among equal nodes to the HIGHEST index (upstream's rule
+    is the lowest), and every score moved by -1, 0 or +1. Each needs more
+    admissions than a run may have within its first 128 decisions (at the
+    real 1,024: over 40 in all 7 lanes tried, PERF.md section 2), so the
+    count reads 3 against 2 and the lane is not correct."""
+    run, src, g = _loaded_lane(451715641, 2, 128)
+    p32 = policies.source_policy(src, dtype="float32")
+    rng = np.random.default_rng(7)
+
+    def jittered(pod, s, cand):
+        sc = np.asarray(p32(pod, s, cand), np.int64)
+        return np.where(sc > 0,
+                        np.maximum(1, sc + rng.integers(-1, 2, len(sc))), sc)
+
+    last = lambda i, cand, sc: len(sc) - 1 - int(np.argmax(sc[::-1]))  # noqa: E731
+    for got in (run(p32, last), run(jittered)):
+        ref, ties = admit(lambda decide: run(p32, decide),
+                          got.assigned_node, g, "lane2")
+        assert (ties.value, ties.ok) == (3.0, False), ties
 
 
 # ------------------------------------------------------------ no chip
