@@ -35,7 +35,7 @@ import concurrent.futures
 import dataclasses
 import os
 import threading
-from typing import Dict, List, Optional, Sequence
+from typing import Dict, List, Optional, Sequence, Tuple
 
 import jax
 import numpy as np
@@ -197,6 +197,9 @@ class CodeEvaluator:
         self._vm_mesh_run = None  # lazily built SHARDED population program
         self._budget_eval = None  # lazily built rung ladder (budget mode)
         self.vm_batch_count = 0  # observability: batched VM launches
+        # (lanes, capacity) -> (slice, scatter) of vm.write_count() over
+        # the first batched launch of that bucket, which traced it
+        self._vm_writes: Dict[Tuple[int, int], Tuple[int, int]] = {}
         # the most recent batched launch's [lanes] score array, on the
         # device: last_lanes_per_device reads its placement when asked
         self._last_scores = None
@@ -367,6 +370,20 @@ class CodeEvaluator:
         except Exception:  # noqa: BLE001 — pricing is best-effort
             pass
 
+    def _vm_write_fields(self, bucket: Tuple[int, int],
+                         before: Tuple[int, int]) -> Dict[str, int]:
+        """``slice_writes`` / ``scatter_writes`` of a batched launch: how
+        the bucket's runner lowered the op-slot loop's row write
+        (``vm.write_count``, counted while a program is traced). The
+        launch that moved the count since ``before`` traced the bucket's
+        program and the difference stays with the bucket; every later
+        launch of it traces nothing and reports the same two numbers."""
+        traced = vm.writes_since(before)
+        if any(traced):
+            self._vm_writes[bucket] = traced
+        slices, scatters = self._vm_writes.get(bucket, (0, 0))
+        return {"slice_writes": slices, "scatter_writes": scatters}
+
     def _run_vm_batch(self, progs: List[vm.VMProgram]) -> List[SimResult]:
         """Evaluate stacked VM candidates in ONE device launch — sharded
         over the mesh when one with >1 device was passed.
@@ -407,7 +424,8 @@ class CodeEvaluator:
                       capacity=capacity, nodes=c.n_padded, view=view,
                       register_bytes=(pop // self._n_shards)
                       * vm.register_rows(capacity) * view * c.g_padded
-                      * stacked.imm.dtype.itemsize):
+                      * stacked.imm.dtype.itemsize) as t_launch:
+            writes0 = vm.write_count()
             if self._n_shards > 1 and self.suite is None:
                 # each device interprets pop/shards lanes; the elite
                 # outputs are discarded here (the evolution loop ranks on
@@ -419,6 +437,7 @@ class CodeEvaluator:
                 result, _, _ = self._vm_mesh_runner()(stacked, len(progs))
             else:
                 result = self._vm_pop_runner()(stacked, self.state0)
+            t_launch.set(**self._vm_write_fields((pop, capacity), writes0))
         self._last_scores = result.policy_score
         with obs.span("tier/vm_batch/wait_device"):
             jax.block_until_ready(result)

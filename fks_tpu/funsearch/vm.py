@@ -56,6 +56,7 @@ a correctness gate.
 """
 from __future__ import annotations
 
+import functools
 import math
 import threading
 from typing import Any, Dict, List, NamedTuple, Optional, Sequence, Tuple
@@ -835,7 +836,7 @@ def _execute(prog: VMProgram, pod: PodView, nodes: NodeView,
         res = lax.switch(
             prog.opcode[k], branches,
             regs[prog.a[k]], regs[prog.b[k]], regs[prog.c[k]], prog.imm[k])
-        return lax.dynamic_update_index_in_dim(regs, res, op_base + k, 0)
+        return _write_row(regs, res, op_base + k)
 
     regs = lax.fori_loop(0, bound, body, regs)
     out = regs[prog.out_reg][:, 0]
@@ -880,6 +881,78 @@ def _loop_bound_lanes(axis_size, in_batched, n_ops):
     # through the primitive again: an enclosing vmap that ALSO batches the
     # programs reduces over its axis the same way
     return _loop_bound(jnp.max(n_ops)), False
+
+
+_WRITE_COUNT = threading.local()
+
+
+def write_count() -> Tuple[int, int]:
+    """(slice, scatter): how often, on THIS thread, the batching rule of
+    the op-slot loop's row write (`_row_writer`) kept the write one
+    ``dynamic_update_slice`` and how often it fell back to JAX's scatter.
+    Counted where the rule runs, that is while a runner is TRACED: once
+    per ``vmap`` around a write and per pass the tracer makes over the
+    loop's body, so the two counts say which way the writes went, not how
+    many there are; an unbatched program never reaches the rule. The
+    evaluator and the serve engines keep `writes_since` the start of a
+    runner's first call (``slice_writes`` / ``scatter_writes`` of
+    ``tier/vm_batch/launch`` and ``serve/chunk/enqueue``)."""
+    return getattr(_WRITE_COUNT, "n", (0, 0))
+
+
+def writes_since(before: Tuple[int, int]) -> Tuple[int, int]:
+    """`write_count` now less an earlier reading of it."""
+    slices, scatters = write_count()
+    return slices - before[0], scatters - before[1]
+
+
+@functools.lru_cache(maxsize=None)
+def _row_writer(axis: int):
+    """``write(regs, res, row)``: ``res`` into row ``row`` of ``regs``,
+    whose row axis is ``axis`` (the number of ``vmap`` levels around the
+    write), as ONE ``dynamic_update_slice``, with a batching rule of its
+    own.
+
+    JAX's rule for ``dynamic_update_slice`` has no case for an unbatched
+    index: it always rewrites the update as a scatter over a concatenated
+    index vector. On the chip that scatter's bounds test is an AND-reduce
+    to ``pred[]`` every slot, and where the file is also gathered from
+    with per-lane indices in the same iteration XLA no longer updates the
+    loop-carried file in place and copies all of it, every slot (ledger,
+    PR 33: half of a code cell's wall). But the row is ``op_base`` plus
+    the loop counter, the same for every lane in every runner, so the
+    batched write IS a slice update of the batched file one axis further
+    right. The rule says so, and says it through the writer of the next
+    axis so that an enclosing ``vmap`` (suite x population, ``vmap`` in
+    ``shard_map``) keeps the slice at every depth. A batched row, which
+    no runner makes, takes JAX's own rule and is counted
+    (`write_count`)."""
+
+    def write_plain(regs, res, row):
+        return lax.dynamic_update_slice_in_dim(
+            regs, jnp.expand_dims(res, axis), row, axis)
+
+    write = jax.custom_batching.custom_vmap(write_plain)
+
+    @write.def_vmap
+    def write_lanes(axis_size, in_batched, regs, res, row):
+        slices, scatters = write_count()
+        if in_batched[2]:
+            _WRITE_COUNT.n = (slices, scatters + 1)
+            return jax.vmap(
+                write_plain, in_axes=[0 if b else None for b in in_batched],
+                axis_size=axis_size)(regs, res, row), True
+        _WRITE_COUNT.n = (slices + 1, scatters)
+        regs, res = (x if b else jnp.broadcast_to(x, (axis_size,) + x.shape)
+                     for x, b in zip((regs, res), in_batched))
+        return _row_writer(axis + 1)(regs, res, row), True
+
+    return write
+
+
+def _write_row(regs: jax.Array, res: jax.Array, row) -> jax.Array:
+    """The op-slot loop's register write (`_row_writer`)."""
+    return _row_writer(0)(regs, res, row)
 
 
 def score(prog: VMProgram, pod: PodView, nodes: NodeView) -> jax.Array:

@@ -59,6 +59,7 @@ class CompileWatcher:
         self.recorder = recorder if recorder is not None else get_recorder()
         self.prefix = prefix
         self.events: List[tuple] = []  # (key, seconds)
+        self.programs: List[str] = []  # fun_name per backend compile
         self.cache_hits = 0
         self._lock = threading.Lock()
         self._installed = False
@@ -69,6 +70,9 @@ class CompileWatcher:
             return
         with self._lock:
             self.events.append((key, float(seconds)))
+            if key.endswith(BACKEND_COMPILE):
+                # jax names the program it compiled (log_elapsed_time)
+                self.programs.append(str(kwargs.get("fun_name", "?")))
         self.recorder.event("compile", key=key, seconds=float(seconds))
 
     def _listen_event(self, key: str, **kwargs) -> None:

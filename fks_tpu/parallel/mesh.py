@@ -616,4 +616,7 @@ def _make_segmented_code_eval(workload: Workload, mesh: Mesh, cfg: SimConfig,
         with span("mesh/finish"):
             return finish(bstate, jnp.asarray(real_count, jnp.int32))
 
+    # the jitted one-segment program, as flat's segmented runner exposes
+    # its own: what the lowering tests trace
+    run.advance = advance
     return run

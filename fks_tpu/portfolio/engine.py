@@ -251,11 +251,13 @@ class PortfolioEngine(VMServeEngine):
                 slots0 = self._lane_put(np.zeros(lanes, np.int32))
                 example = ((self._prog_dev, slots0)
                            + self._example_batch(lanes, pod_bucket))
+                writes0 = vm.write_count()
                 with warnings.catch_warnings():
                     warnings.filterwarnings("ignore",
                                             message="Some donated")
                     compiled = jax.jit(fn, donate_argnums=(2, 4)) \
                         .lower(*example).compile()
+                self._keep_writes(compiled, writes0)
         self._compiled[key] = compiled
         self.cold_compiles += 1
         record_footprint(

@@ -352,6 +352,9 @@ class ServeEngine:
                          else obs.NULL_PROFILER)
         self._mod = get_engine(engine)
         self._compiled: Dict[Tuple[int, int], Any] = {}
+        # id(executable) -> (slice, scatter) of vm.write_count() over its
+        # trace: how the op-slot loop's row write was lowered in it
+        self._vm_writes: Dict[int, Tuple[int, int]] = {}
         self.cold_compiles = 0
         # mesh-wide serving: lane axis sharded over the pop axes
         self.mesh = mesh
@@ -542,7 +545,9 @@ class ServeEngine:
                 fn = self._make_serve_fn(pod_bucket)
                 if self.mesh is not None:
                     fn = make_sharded_serve_fn(fn, self.mesh)
+                from fks_tpu.funsearch import vm
                 example = self._example_batch(lanes, pod_bucket)
+                writes0 = vm.write_count()
                 with warnings.catch_warnings():
                     # buckets whose SimResult cannot alias a donated
                     # input warn once per compile; donation still lets
@@ -551,6 +556,7 @@ class ServeEngine:
                                             message="Some donated")
                     compiled = jax.jit(fn, donate_argnums=(0, 2)) \
                         .lower(*example).compile()
+                self._keep_writes(compiled, writes0)
         self._compiled[key] = compiled
         self.cold_compiles += 1
         # executable-footprint ledger: every ladder rung's predicted HBM
@@ -730,11 +736,29 @@ class ServeEngine:
         with obs.span("serve/chunk/enqueue", chunk=chunk,
                       **self._loop_fields()) as t_enq:
             compiled = self.compiled_for(lanes, bucket)
+            t_enq.set(**self._write_fields(compiled))
             res = self._invoke(compiled, pods, kt_dev, s0)
         self.last_batch_timing["pack_h2d_s"] += t_enq.t1 - t_stack.t0
         self.last_batch_spans += [t_stack.record, t_pack.record,
                                   hh.span.record, t_enq.record]
         return _Inflight(res, list(idxs), bucket, lanes, real, chunk)
+
+    def _keep_writes(self, compiled, before: Tuple[int, int]) -> None:
+        """Keep with a new executable how its trace lowered the VM's row
+        write: ``vm.write_count`` since ``before`` (read just before the
+        ``lower()`` that traced it, on this thread; the executable is
+        never traced again)."""
+        from fks_tpu.funsearch import vm
+        self._vm_writes[id(compiled)] = vm.writes_since(before)
+
+    def _write_fields(self, compiled) -> Dict[str, int]:
+        """``slice_writes`` / ``scatter_writes`` of the executable being
+        enqueued (`_keep_writes`). Empty where its trace held no batched
+        VM write (a champion on the jit tier)."""
+        slices, scatters = self._vm_writes.get(id(compiled), (0, 0))
+        if not slices + scatters:
+            return {}
+        return {"slice_writes": slices, "scatter_writes": scatters}
 
     def _invoke(self, compiled, pods, kt_dev, s0):
         return compiled(pods, kt_dev, s0)

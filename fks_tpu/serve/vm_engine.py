@@ -324,11 +324,13 @@ class VMServeEngine(ServeEngine):
                     fn = make_sharded_vm_serve_fn(fn, self.mesh)
                 example = ((self._prog_dev,)
                            + super()._example_batch(lanes, pod_bucket))
+                writes0 = vm.write_count()
                 with warnings.catch_warnings():
                     warnings.filterwarnings("ignore",
                                             message="Some donated")
                     compiled = jax.jit(fn, donate_argnums=(1, 3)) \
                         .lower(*example).compile()
+                self._keep_writes(compiled, writes0)
         self._compiled[key] = compiled
         self.cold_compiles += 1
         # footprint ledger: the capacity-bucket executable's predicted

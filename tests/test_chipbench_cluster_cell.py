@@ -18,12 +18,13 @@ from chipbench.selftest.tests import batched_vm_on_cpu
 CELL = "openb1523-inflated.codegen8"
 #: the first 150 arrivals, 48-event generations of 4 lanes
 TINY = {"config": {"pod_limit": 150, "code_eval_max_steps": 48},
-        "traffic": {"lanes": 4, "trace_at_s": 0.0, "trace_for_s": 0.05}}
+        "traffic": {"lanes": 4, "trace_for_s": 0.05}}
 SPAN_METRICS = ("tier.preflight_ms_per_call", "tier.transpile_ms_per_call",
                 "tier.harvest_ms_per_call", "tier.unattributed_share",
                 "vm.device_ms_per_event", "vm.live_slot_share",
                 "vm.us_per_slot", "vm.register_mb",
-                "tier.traces_per_source", "vm.ops_kept_share")
+                "tier.traces_per_source", "vm.ops_kept_share",
+                "vm.scatter_write_share")
 
 
 def _run(monkeypatch, tmp_path, trace, seed=2 ** 31 + 5):
@@ -87,6 +88,7 @@ def test_cell_runs_end_to_end_and_reports_its_span_metrics(monkeypatch,
     assert 0 < v["vm.live_slot_share"] <= 100
     assert v["tier.traces_per_source"] == 1.0   # no dry trace before it
     assert 0 < v["vm.ops_kept_share"] < 100     # the simplifier engaged
+    assert v["vm.scatter_write_share"] == 0.0   # every write stayed a slice
     slots = v["vm.live_slot_share"] / 100 * 512
     assert v["vm.us_per_slot"] == pytest.approx(
         v["vm.device_ms_per_event"] * 1e3 / slots, rel=1e-6)

@@ -23,7 +23,7 @@ from tests.test_chipbench_loaded_decl import (CELL, COUNTER_METRICS,
 #: the first 300 arrivals, forked after 200: 48-event generations of 4
 TINY = {"config": {"pod_limit": 300, "start_event": 200,
                    "code_eval_max_steps": 48},
-        "traffic": {"lanes": 4, "trace_at_s": 0.0, "trace_for_s": 0.05}}
+        "traffic": {"lanes": 4, "trace_for_s": 0.05}}
 #: what ``check`` compares a lane by, and the call by
 LANE_NUMBERS = {"placements_differ", "gpu_picks_differ", "scheduled_diff",
                 "events_diff", "flags_differ", "snapshots_diff",

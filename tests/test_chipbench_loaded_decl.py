@@ -15,6 +15,7 @@ SPAN_METRICS = ("tier.preflight_ms_per_call", "tier.transpile_ms_per_call",
                 "vm.device_ms_per_event", "vm.live_slot_share",
                 "vm.us_per_slot", "vm.register_mb",
                 "tier.traces_per_source", "vm.ops_kept_share",
+                "vm.scatter_write_share",
                 "sim.fork_state_ms")
 #: read from the driver's counters: the profiler's device-eval stage
 #: against the calls' seconds and the window's lockstep events
@@ -71,16 +72,21 @@ def test_benchmark_json_only_gained_entries():
         bench = json.load(f)
     assert bench["configs"][-1]["name"] == "openb1523-loaded"
     assert bench["workloads"][-1]["name"] == CELL
-    assert [m["name"] for m in bench["per_layer"][-2:]] \
-        == ["sim.retry_share", "sim.fork_state_ms"]
-    for m in bench["end_to_end"] + bench["per_layer"][:-2]:
+    # the cell's own two metrics, in the place PR 31 gave them (later PRs
+    # append after them)
+    names = [m["name"] for m in bench["per_layer"]]
+    at = names.index("sim.retry_share")
+    assert names[at:at + 2] == ["sim.retry_share", "sim.fork_state_ms"]
+    own = bench["per_layer"][at:at + 2]
+    others = bench["per_layer"][:at] + bench["per_layer"][at + 2:]
+    for m in bench["end_to_end"] + others:
         lists = m.get("workloads", [])
         assert (CELL in lists) == (
             "openb1523-inflated.codegen8" in lists
             or m["name"] in ("tier.host_share", "vm.ms_per_event"))
         if CELL in lists:
             assert lists[-1] == CELL
-    for m in bench["per_layer"][-2:]:
+    for m in own:
         assert m["workloads"] == [CELL]
         assert m["layer"] == "engines sim/flat.py"
 

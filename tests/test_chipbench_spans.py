@@ -225,6 +225,38 @@ def test_ops_kept_share_reads_the_transpile_spans_fields(fields, want):
     assert got == (want if want is None else pytest.approx(want))
 
 
+def _launch_calls(fields):
+    """The window's calls of three generations (the first is the warm-up),
+    each with a ``tier/vm_batch/launch`` span that carries ``fields``."""
+    recs, t = [], 0.0
+    for i in range(3):
+        recs.append(rec(2 * i, "tier/vm_batch/launch", t + 0.5, t + 1.5,
+                        f"l{i}", f"g{i}", f"g{i}", lanes=8, shards=1,
+                        **fields))
+        recs.append(rec(2 * i + 1, "tier/evaluate", t, t + 2.0, f"g{i}",
+                        None, f"g{i}"))
+        t += 2.01
+    calls = rs.select_generations(recs, 0, 2, 4.0)
+    assert calls
+    return calls
+
+
+@pytest.mark.parametrize("fields,want", [
+    # the population runner: one vmap around the op-slot loop's write
+    ({"slice_writes": 1, "scatter_writes": 0}, 0.0),
+    ({"slice_writes": 2, "scatter_writes": 0}, 0.0),   # suite x population
+    ({"slice_writes": 0, "scatter_writes": 1}, 100.0),  # a batched row
+    ({"slice_writes": 1, "scatter_writes": 1}, 50.0),
+    ({"slice_writes": 0, "scatter_writes": 0}, None),   # the rule never ran
+    ({"slots": 292, "capacity": 512}, None),   # a parent without the fields
+    ({}, None),
+])
+def test_scatter_write_share_reads_the_launch_spans_fields(fields, want):
+    got = cells.metric_reader("vm.scatter_write_share")(
+        {"_span_calls": _launch_calls(fields)})
+    assert got == (want if want is None else pytest.approx(want))
+
+
 def test_a_program_without_the_ring_reads_as_nothing(monkeypatch):
     from fks_tpu.obs import spans
 
@@ -239,7 +271,7 @@ def test_a_program_without_the_ring_reads_as_nothing(monkeypatch):
 def test_every_span_metric_is_declared_with_its_files():
     bench = json.load(open(os.path.join(cells.ROOT, "BENCHMARK.json")))
     declared = {m["name"]: m for m in bench["per_layer"]}
-    assert len(SPAN_METRICS) == 23     # + sim.fork_state_ms (PR 31)
+    assert len(SPAN_METRICS) == 24     # + vm.scatter_write_share (PR 35)
     for name in SPAN_METRICS:
         assert name in declared, name
         meta = json.load(open(os.path.join(cells.HERE, "metrics",
@@ -296,10 +328,12 @@ def test_traced_cell_reports_every_span_metric(name, tmp_path, monkeypatch):
             + v["serve.h2d_ms_per_call"] + v["serve.harvest_ms_per_call"]
         assert v["serve.h2d_kb_per_call"] > 0 and v["serve.d2h_kb_per_call"] > 0
     else:
-        assert len(want) == 11
+        assert len(want) == 12
         # a recorded generation: every source traced once, where it runs,
         # and the simplifier dropped part of what the lowering emitted
         assert res["metrics"]["tier.traces_per_source"]["value"] == 1.0
         assert 0 < res["metrics"]["vm.ops_kept_share"]["value"] < 100
         assert 0 < res["metrics"]["vm.live_slot_share"]["value"] <= 100
         assert res["metrics"]["mesh.host_ms_per_call"]["value"] > 0
+        # the mesh runner kept every register write one slice
+        assert res["metrics"]["vm.scatter_write_share"]["value"] == 0.0

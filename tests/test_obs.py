@@ -209,6 +209,10 @@ def test_compile_watcher_captures_compile_events(tmp_path):
         assert len(w.events) >= 1
         assert w.backend_compile_count >= 1
         assert w.backend_compile_seconds > 0
+        # one name per backend compile, as jax gives it: what a "zero
+        # compiles" assertion prints when it fails
+        assert len(w.programs) == w.backend_compile_count
+        assert any("_fresh" in name for name in w.programs), w.programs
         summary = w.summary()
         assert any(k.startswith("/jax/core/compile") for k in summary)
     events = [json.loads(l) for l in

@@ -646,3 +646,27 @@ def test_mixed_slot_batch_runs_to_the_longest_selected_program(
                for r in eng.last_batch_spans
                if r.name == "serve/chunk/enqueue"]
         assert got and set(got) == {(longest, 512)}
+
+
+def test_slot_dispatch_writes_a_register_as_one_slice(wl, envelope):
+    """Per-lane programs out of the slot tables (``vm.select_slot``): the
+    row the loop writes is still ``op_base`` plus the loop counter, so the
+    batch keeps one ``dynamic_update_slice`` of the register file a slot,
+    no scatter, and the ``enqueue`` span carries the two counts."""
+    import jax
+
+    from tests.test_vm_batch import _assert_one_slice_write_a_slot
+
+    eng = PortfolioEngine([_champ(SEED_LOGIC, 0.4, "<c0>"),
+                           _champ(BETTER_LOGIC, 0.9, "<c1>")], wl,
+                          envelope=envelope, engine="flat", n_slots=2)
+    fn = eng._make_serve_fn(8)
+    batch = eng._example_batch(2, 8)
+    assert _assert_one_slice_write_a_slot(
+        jax.make_jaxpr(lambda slots: fn(eng._prog_dev, slots, *batch))(
+            np.asarray([0, 1], np.int32)), eng.program_capacity) == 1
+    eng.answer_batch([_query(eng.base_pods, 0), _query(eng.base_pods, 1)],
+                     slots=[0, 1])
+    got = [(r.fields["slice_writes"], r.fields["scatter_writes"])
+           for r in eng.last_batch_spans if r.name == "serve/chunk/enqueue"]
+    assert got and all(s >= 1 and c == 0 for s, c in got), got

@@ -321,7 +321,11 @@ def test_four_device_generation_leaves_the_mesh_spans(micro_workload,
         "lanes": 8, "shards": 4, "start_event": 0, "slots": longest,
         "capacity": cap, "nodes": c.n_padded, "view": c.n_padded,
         "register_bytes": 2 * vm.register_rows(cap) * c.n_padded
-        * c.g_padded * 8}
+        * c.g_padded * 8,
+        # how the warm call's trace lowered the register write, kept with
+        # the (lanes, capacity) bucket: every run of the rule a slice
+        "slice_writes": launch.fields["slice_writes"], "scatter_writes": 0}
+    assert launch.fields["slice_writes"] >= 1
     assert longest < launch.fields["capacity"]
     mesh_spans = [r for r in got if r.name.startswith("mesh/")]
     top = sorted((r for r in mesh_spans if r.parent_id == launch.span_id),

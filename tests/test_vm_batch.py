@@ -380,6 +380,11 @@ def _lowered_batched_paths(wl):
         vm.stack_programs(progs * 4, capacity=CAP), mesh)
     ev = make_sharded_code_eval(wl, mesh, cfg=cfg, elite_k=1, engine="flat")
     out["mesh/flat"] = jax.make_jaxpr(lambda st: ev(st, real))(padded)
+    # what the chip's four-chip generation runs: bounded segments
+    seg_ev = make_sharded_code_eval(wl, mesh, cfg=cfg, elite_k=1,
+                                    engine="flat", seg_steps=3)
+    out["mesh-segmented/flat"] = jax.make_jaxpr(seg_ev.advance)(
+        padded, flat.broadcast_state(flat.initial_state(wl, cfg), 8))
     # candidates x scenarios: the programs ride the OUTER vmap only
     suite = make_suite_eval(get_suite("smoke3", wl), vm.score, cfg,
                             population=True, jit=False, engine="exact")
@@ -387,9 +392,174 @@ def _lowered_batched_paths(wl):
     return {k: (v, CAP) for k, v in out.items()}
 
 
-def test_no_batched_path_selects_the_register_file(micro_workload):
-    for name, (jaxpr, cap) in _lowered_batched_paths(micro_workload).items():
+@pytest.fixture(scope="module")
+def batched_paths(micro_workload):
+    return _lowered_batched_paths(micro_workload)
+
+
+BATCHED_PATHS = ["population/flat", "population/exact", "segmented/flat",
+                 "mesh/flat", "mesh-segmented/flat", "suite/exact"]
+
+
+def test_no_batched_path_selects_the_register_file(batched_paths):
+    assert sorted(batched_paths) == sorted(BATCHED_PATHS)
+    for name, (jaxpr, cap) in batched_paths.items():
         assert _assert_unbatched_op_slot_loop(jaxpr, cap) >= 1, name
+
+
+def _walk(jaxpr):
+    for eqn in jaxpr.eqns:
+        yield eqn
+        for sub in _sub_jaxprs(eqn):
+            yield from _walk(sub)
+
+
+def _assert_one_slice_write_a_slot(closed_jaxpr, capacity):
+    """What JAX's batching rule for ``dynamic_update_slice`` makes of the
+    op-slot loop's row write: a ``scatter`` of the register file, every
+    slot (on the chip a bounds test over its index vector and a copy of
+    the whole file). The body of every op-slot loop must hold no scatter
+    on a value of the file's shape and exactly ONE ``dynamic_update_slice``
+    on it (``vm._row_writer``)."""
+    regs = vm.N_INPUTS + vm.CONST_POOL + capacity
+    loops = _op_slot_loops(closed_jaxpr.jaxpr, regs)
+    assert loops, "no op-slot loop in the program"
+    for eqn in loops:
+        writes = [b.primitive.name
+                  for b in _walk(eqn.params["body_jaxpr"].jaxpr)
+                  if b.primitive.name.startswith(("scatter",
+                                                  "dynamic_update_slice"))
+                  and any(len(v.aval.shape) >= 3
+                          and v.aval.shape[-3] == regs for v in b.outvars)]
+        assert writes == ["dynamic_update_slice"], writes
+    return len(loops)
+
+
+@pytest.mark.parametrize("name", BATCHED_PATHS)
+def test_every_batched_path_writes_a_register_as_one_slice(batched_paths,
+                                                           name):
+    jaxpr, cap = batched_paths[name]
+    assert _assert_one_slice_write_a_slot(jaxpr, cap) >= 1
+
+
+def test_the_default_rule_scatter_would_be_caught(monkeypatch):
+    """The check above is not vacuous: the write as it was, a bare
+    ``dynamic_update_index_in_dim`` that ``vmap`` turns into a scatter of
+    the register file, trips it; and unbatched, where no rule runs, both
+    are the same one slice."""
+    from jax import lax
+
+    rng = np.random.default_rng(2)
+    progs = [vm.compile_policy(c, N, G, capacity=256)
+             for c in _mix_codes(["ff", "bf"])]
+    stacked = vm.stack_programs(progs, capacity=256)
+    pod, nodes = _rand_views(rng)
+    batched = jax.vmap(vm.score, in_axes=(0, None, None))
+    assert _assert_one_slice_write_a_slot(
+        jax.make_jaxpr(batched)(stacked, pod, nodes), 256) == 1
+    assert _assert_one_slice_write_a_slot(
+        jax.make_jaxpr(vm.score)(progs[0], pod, nodes), 256) == 1
+    monkeypatch.setattr(
+        vm, "_write_row",
+        lambda regs, res, row: lax.dynamic_update_index_in_dim(
+            regs, res, row, 0))
+    # fresh functions: a trace of the same function object is cached
+    as_it_was = jax.vmap(lambda *a: vm.score(*a), in_axes=(0, None, None))
+    with pytest.raises(AssertionError, match="scatter"):
+        _assert_one_slice_write_a_slot(
+            jax.make_jaxpr(as_it_was)(stacked, pod, nodes), 256)
+    assert _assert_one_slice_write_a_slot(
+        jax.make_jaxpr(lambda *a: vm.score(*a))(progs[0], pod, nodes),
+        256) == 1
+
+
+def test_unbatched_score_never_reaches_the_write_rule():
+    """One program alone: the jaxpr holds one ``dynamic_update_slice`` on
+    the file and no scatter, and the rule's counter does not move."""
+    rng = np.random.default_rng(4)
+    prog = vm.compile_policy(_mix_codes(["bf"])[0], N, G, capacity=256)
+    pod, nodes = _rand_views(rng)
+    before = vm.write_count()
+    jaxpr = jax.make_jaxpr(vm.score)(prog, pod, nodes)
+    assert vm.write_count() == before
+    names = [e.primitive.name for e in _walk(jaxpr.jaxpr)]
+    assert names.count("dynamic_update_slice") == 1
+    assert not [n for n in names if n.startswith("scatter")]
+
+
+@pytest.mark.parametrize("inner,outer,prog_of,view_of", [
+    # programs x programs: lane (i, j) runs program j on the one view
+    ((0, None, None), (0, None, None), lambda i, j: j, None),
+    # queries inside, programs outside (a serve batch per tenant)
+    ((None, 0, 0), (0, None, None), lambda i, j: i, lambda i, j: j),
+    # programs inside, scenarios outside (suite x population)
+    ((0, None, None), (None, 0, 0), lambda i, j: j, lambda i, j: i),
+])
+def test_nested_vmap_keeps_the_slice(inner, outer, prog_of, view_of):
+    """Two ``vmap`` levels around ``score``: the rule hands the write to
+    the writer of the next axis, so it is still ONE slice of the
+    [A, B, rows, N, G] file, no scatter one level up, and every lane
+    scores what its program scores alone on its view."""
+    rng = np.random.default_rng(9)
+    progs = [vm.compile_policy(c, N, G, capacity=256)
+             for c in _mix_codes(["ff", "bf"])]
+    views = [_rand_views(rng) for _ in range(3)]
+    stacked = vm.stack_programs(progs, capacity=256)
+    if view_of is None:     # both levels batch the programs: [2, 2, ...]
+        prog = jax.tree_util.tree_map(lambda x: jnp.stack([x, x]), stacked)
+        view = views[0]
+    else:
+        prog = stacked
+        view = jax.tree_util.tree_map(lambda *x: jnp.stack(x), *views)
+    f = jax.vmap(jax.vmap(vm.score, in_axes=inner), in_axes=outer)
+    before = vm.write_count()
+    jaxpr = jax.make_jaxpr(f)(prog, *view)
+    slices, scatters = (x - y for x, y in zip(vm.write_count(), before))
+    assert slices >= 2 and scatters == 0     # the rule ran at both levels
+    assert _assert_one_slice_write_a_slot(jaxpr, 256) == 1
+    got = np.asarray(f(prog, *view))
+    for i in range(got.shape[0]):
+        for j in range(got.shape[1]):
+            pod, nodes = views[view_of(i, j) if view_of else 0]
+            np.testing.assert_array_equal(
+                got[i, j],
+                np.asarray(vm.score(progs[prog_of(i, j)], pod, nodes)))
+
+
+def test_a_batched_row_takes_the_counted_fall_back():
+    """No runner batches the row index (it is ``op_base`` plus the loop
+    counter). If one did, the rule falls back to JAX's own (a scatter),
+    the result is the reference's, and ``write_count`` says so."""
+    from jax import lax
+
+    rng = np.random.default_rng(5)
+    regs = jnp.asarray(rng.normal(size=(4, 12, 3, 2)))
+    res = jnp.asarray(rng.normal(size=(4, 3, 2)))
+    rows = jnp.asarray([0, 11, 5, 5], jnp.int32)
+    before = vm.write_count()
+    got = jax.vmap(vm._write_row)(regs, res, rows)
+    slices, scatters = (a - b for a, b in zip(vm.write_count(), before))
+    assert (slices, scatters) == (0, 1)
+    want = jnp.stack([lax.dynamic_update_index_in_dim(r, v, i, 0)
+                      for r, v, i in zip(regs, res, rows)])
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+    names = [e.primitive.name for e in _walk(
+        jax.make_jaxpr(jax.vmap(vm._write_row))(regs, res, rows).jaxpr)]
+    assert "scatter" in names and "dynamic_update_slice" not in names
+    # an unbatched row with only ONE of file and value batched: a slice
+    for axes in ((0, None, None), (None, 0, None), (0, 0, None)):
+        before = vm.write_count()
+        a = [regs if axes[0] == 0 else regs[0],
+             res if axes[1] == 0 else res[0], 7]
+        got = jax.vmap(vm._write_row, in_axes=axes)(*a)
+        assert tuple(x - y for x, y in zip(vm.write_count(), before)) \
+            == (1, 0)
+        for lane in range(4):
+            np.testing.assert_array_equal(
+                np.asarray(got[lane]),
+                np.asarray(lax.dynamic_update_index_in_dim(
+                    a[0][lane] if axes[0] == 0 else a[0],
+                    a[1][lane] if axes[1] == 0 else a[1], 7, 0)))
 
 
 def test_a_per_lane_bound_would_be_caught():

@@ -201,6 +201,7 @@ def test_double_hot_swap_zero_recompiles(wl, envelope, tmp_path):
             v2 = ctrl.poll_once()
             _traffic(service, 3)
             compiles = watcher.backend_compile_count
+            programs = list(watcher.programs)
         finally:
             watcher.uninstall()
         assert v1.get("action") == "promoted" and \
@@ -209,7 +210,8 @@ def test_double_hot_swap_zero_recompiles(wl, envelope, tmp_path):
             v2.get("engine_kind") == "vm", v2
         assert compiles == 0, (
             f"{compiles} XLA programs compiled across two VM hot-swaps "
-            "— promotion must be transpile + pack + H2D only")
+            f"({', '.join(programs)}) — promotion must be transpile + "
+            "pack + H2D only")
         # the swap was IN PLACE: same engine object, new champion tables
         assert service.engine is incumbent
         assert incumbent.vm_swaps == 2
@@ -326,6 +328,26 @@ def test_serve_program_loops_over_the_champions_live_slots(
     live = int(eng.params.n_ops)
     assert live < eng.program_capacity
     assert events > 0 and fired == live * events
+
+
+def test_serve_program_writes_a_register_as_one_slice(vm_engine):
+    """The serve batch (one program, ``in_axes=None``, lanes of queries):
+    the op-slot loop's body holds one ``dynamic_update_slice`` of the
+    register file and no scatter of it, the executables say so on the
+    ``enqueue`` span (``slice_writes`` / ``scatter_writes``: how the rule
+    ran while each was traced), and a swap changes neither."""
+    from tests.test_vm_batch import _assert_one_slice_write_a_slot
+
+    eng = vm_engine
+    fn = eng._make_serve_fn(8)
+    batch = eng._example_batch(2, 8)
+    assert _assert_one_slice_write_a_slot(
+        jax.make_jaxpr(fn)(eng._prog_dev, *batch),
+        eng.program_capacity) == 1
+    eng.answer_batch([_query(3), _query(9, 5)])
+    got = [(r.fields["slice_writes"], r.fields["scatter_writes"])
+           for r in eng.last_batch_spans if r.name == "serve/chunk/enqueue"]
+    assert got and all(s >= 1 and c == 0 for s, c in got), got
 
 
 def test_transpile_cache_makes_reswap_warm(wl, envelope):
