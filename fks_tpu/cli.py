@@ -153,8 +153,11 @@ def _add_trace_flags(p, snapshot=False):
         p.add_argument("--snapshot", default="",
                        help="snapshot CSV (name,node_sn,gpus) under "
                             "benchmarks/traces/csv/: start from the loaded "
-                            "cluster it pins; needs --engine flat "
-                            "(fks_tpu.data.snapshot)")
+                            "cluster it pins; needs --engine flat on "
+                            "these commands (fks_tpu.data.snapshot; "
+                            "serving forks on the exact engine through "
+                            "the library: VMServeEngine on a workload "
+                            "parsed with snapshot_file=)")
 
 
 def _result_row(name, res, wall):
@@ -2216,11 +2219,13 @@ def main(argv=None) -> int:
                  "single policies or arbitrary evolved code; use "
                  "'exact'/'flat' there)")
     if getattr(args, "snapshot", "") and args.engine != "flat":
-        ap.error("--snapshot: flat engine only (the exact engine's heap at "
-                 "the fork is not rebuilt yet); pass --engine flat")
+        ap.error("--snapshot: flat engine only on this command (candidate "
+                 "evaluation forks on the flat engine; the exact engine's "
+                 "fork is what serve engines use); pass --engine flat")
     if getattr(args, "snapshot", "") and getattr(args, "parity_sample", 0):
-        ap.error("--snapshot: no parity sentinel (--parity-sample rescored "
-                 "candidates on the exact engine, which cannot fork yet)")
+        ap.error("--snapshot: no parity sentinel (--parity-sample rescores "
+                 "candidates on the exact engine, and candidate evaluation "
+                 "forks on the flat engine only)")
     return args.fn(args)
 
 

@@ -15,9 +15,16 @@ a window of ``k`` events after the fork is ``max_steps = E0 + k``.
 
 The snapshot is data on the workload (``Workload.snapshot``, as
 ``faults`` is): ``TraceParser.parse_workload(..., snapshot_file=...)``
-sets it and ``fks_tpu.sim.flat.initial_state`` then returns the carry
-after those events, so every runner built on the flat engine forks with
-no further argument. The exact and fused engines refuse it by name.
+sets it and ``initial_state`` of the flat and of the exact engine
+(``fks_tpu.sim.flat``, ``fks_tpu.sim.engine``) then returns the carry
+after those events, so every runner built on either forks with no
+further argument: candidate evaluation on the flat engine
+(``CodeEvaluator``), what-if serving on the exact one (``ServeEngine`` /
+``VMServeEngine``, whose queries arrive after the residents:
+``fks_tpu.serve.batcher.QueryFork``). The exact engine's heap at the
+fork is CPython's own, slot for slot (``fks_tpu.ops.heap.
+heap_rows_after_prefix``: its retry rule reads the heap in array order).
+The fused engine refuses a snapshot by name.
 
 File format: a CSV (plain or ``.gz``) with the header ``name,node_sn,
 gpus``: the pod's ``name`` in the pod list, the node's ``sn`` in the node

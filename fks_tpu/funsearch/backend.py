@@ -160,6 +160,11 @@ class CodeEvaluator:
         # a result still counts the whole run. ``start_event`` is where
         # the policy takes over (0 without a snapshot).
         snap = workload.snapshot
+        if snap is not None and engine != "flat":
+            raise ValueError(
+                "snapshot: flat engine only for candidate evaluation (the "
+                "tiers are not wired to the exact engine's fork, which "
+                "serving uses; ROADMAP, Reach); use engine='flat'")
         self.start_event = 0 if snap is None else snap.e0
         if snap is None:
             self.state0 = self._mod.initial_state(workload, cfg)

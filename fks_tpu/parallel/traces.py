@@ -36,9 +36,11 @@ def strip_ids(wl: Workload) -> Workload:
     normalization so queries match the AOT-compiled example's treedef."""
     if wl.snapshot is not None:
         raise ValueError(
-            "snapshot: trace batching and serving start every workload "
-            "from the empty cluster; evaluate a loaded cluster through "
-            "CodeEvaluator / make_population_eval with engine='flat'")
+            "snapshot: trace batching starts every workload from the "
+            "empty cluster; evaluate a loaded cluster through "
+            "CodeEvaluator / make_population_eval with engine='flat', "
+            "serve one through ServeEngine / VMServeEngine "
+            "(engine='exact')")
     return Workload(
         cluster=ClusterArrays(**{
             **{f: getattr(wl.cluster, f) for f in (

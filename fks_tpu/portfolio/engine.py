@@ -65,6 +65,11 @@ class PortfolioEngine(VMServeEngine):
         champions = list(champions)
         if not champions:
             raise ValueError("PortfolioEngine needs at least one champion")
+        if workload.snapshot is not None:
+            raise ValueError(
+                "snapshot: serving forks one champion an engine "
+                "(VMServeEngine); the portfolio's slot-table executables "
+                "are not built from a fork")
         self.n_slots = int(n_slots) if n_slots else len(champions)
         if self.n_slots < len(champions):
             raise ValueError(

@@ -52,8 +52,8 @@ from fks_tpu.parallel.mesh import (
     pad_population, serve_lane_count, serve_sharding,
 )
 from fks_tpu.serve.batcher import (
-    build_query_workload, pack_query_tables, pods_to_dicts, query_pack_plan,
-    stack_query_tables, tree_h2d_bytes, unpack_query_tables,
+    QueryFork, build_query_workload, pack_query_tables, pods_to_dicts,
+    query_pack_plan, stack_query_tables, tree_h2d_bytes, unpack_query_tables,
     validate_query_pods,
 )
 from fks_tpu.sim import get_engine
@@ -310,6 +310,19 @@ class ServeEngine:
     synchronizing one chunk behind dispatch like the segmented replay
     runner.
 
+    A ``workload`` that carries a ``snapshot`` (``fks_tpu.data.snapshot``)
+    makes every query a FORK from the loaded cluster (``serve.batcher.
+    QueryFork``, exact engine only): a query's run is ``residents ++ query
+    pods`` with the snapshot deciding the first ``E0`` events; the pod
+    axis of a bucket is ``E0 + bucket``, its step budget counts from the
+    fork (``SimConfig.max_steps`` stays absolute: ``E0 + max(64,
+    max_steps_factor x bucket)``), and an answer lists the query's pods
+    only, says which of them still wait at the cut (``waiting``) and
+    reports whole-run counts (``events``, ``scheduled``: the residents'
+    included; ``start_event`` says where the champion took over). The
+    snapshot is the engine's, not a query's: a query pod created before
+    the snapshot's last arrival is refused.
+
     ``engine`` picks the simulation module ("exact" serves reference
     semantics and is the parity default; "flat" trades the documented
     retry-rule divergence for throughput). ``prefilter_k=None`` engages
@@ -340,6 +353,25 @@ class ServeEngine:
         self.cluster = workload.cluster
         self.base_pods = pods_to_dicts(workload.pods)
         self.envelope = envelope or ShapeEnvelope()
+        #: the loaded cluster every query forks from, or None
+        self.fork: Optional[QueryFork] = None
+        if workload.snapshot is not None:
+            if engine != "exact":
+                raise ValueError(
+                    "snapshot: serving forks on the exact engine "
+                    "(ServeEngine / VMServeEngine with engine='exact'); "
+                    "candidates are evaluated from a snapshot on "
+                    "engine='flat' (CodeEvaluator)")
+            # as given, with the pods' tie order: what ``save`` keeps
+            self._snapshot = (workload.snapshot, np.asarray(
+                workload.pods.tie_rank)[np.asarray(workload.pods.pod_mask)])
+            with obs.span("serve/fork_state",
+                          start_event=workload.snapshot.e0) as sp:
+                self.fork = QueryFork(workload)
+                sp.set(residents=self.fork.e0,
+                       nodes_loaded=self.fork.nodes_loaded,
+                       heap_size=self.fork.e0,   # their DELETEs, pending
+                       bytes=self.fork.lane_bytes)
         self.engine_name = engine
         self.state_pack = bool(state_pack)
         self.max_steps_factor = int(max_steps_factor)
@@ -441,26 +473,34 @@ class ServeEngine:
         unbatched exact reference (``reference_answer``), so bucket
         padding is part of the serving semantics, not a parity leak."""
         return SimConfig(
-            max_steps=max(64, self.max_steps_factor * pod_bucket),
+            max_steps=self.start_event
+            + max(64, self.max_steps_factor * pod_bucket),
             wait_hist_size=self.envelope.wait_hist_size,
             node_prefilter_k=self.prefilter_k,
             state_pack=self.state_pack,
         )
+
+    @property
+    def start_event(self) -> int:
+        """Where the champion takes over: 0, or the fork's ``E0``."""
+        return 0 if self.fork is None else self.fork.e0
 
     def _klen(self, pod_bucket: int) -> int:
         """Fixed snapshot-table width for the bucket, sized at the
         SMALLEST real pod count routing can send here (tables grow as
         real pods shrink; see ``ShapeEnvelope.min_real_pods``)."""
         cfg = self.bucket_config(pod_bucket)
-        return max_snapshot_count(cfg.max_steps,
-                                  self.envelope.min_real_pods(pod_bucket),
-                                  cfg.snapshot_interval)
+        return max_snapshot_count(
+            cfg.max_steps,
+            self.start_event + self.envelope.min_real_pods(pod_bucket),
+            cfg.snapshot_interval)
 
     def _pack_plan(self, pod_bucket: int) -> dict:
         """The bucket's static upload-packing plan (empty unless
         ``state_pack``) — shared by compile, example and dispatch so the
         packed avals can never diverge from the executable's."""
-        return query_pack_plan(self.bucket_config(pod_bucket), pod_bucket,
+        return query_pack_plan(self.bucket_config(pod_bucket),
+                               self.start_event + pod_bucket,
                                self.envelope.max_gpu_milli)
 
     def _make_serve_fn(self, pod_bucket: int):
@@ -492,9 +532,16 @@ class ServeEngine:
             pods, kt = unpack_query_tables(pods, kt, plan)
             final = run_batched_lanes(lambda s: vstep(pods, kt, s), state0,
                                       max_steps, active_fn=mod.lane_active)
-            return vfin(pods, final)
+            return self._result(vfin(pods, final), final)
 
         return serve_fn
+
+    def _result(self, res, final):
+        """What a bucket's executable returns: the lanes' ``SimResult``
+        and, from a fork, their pods' waiting flags at the end beside it
+        (a cut run leaves pods in the waiting set; ``SimResult`` does not
+        say which)."""
+        return res if self.fork is None else (res, final.waiting)
 
     @staticmethod
     def _pad_kt(kt: np.ndarray, lanes: int) -> np.ndarray:
@@ -511,13 +558,14 @@ class ServeEngine:
         mesh, exact shardings), for ``lower()``: the smallest query
         routing can send here, replicated across lanes by the same
         pack/pad path real batches use."""
-        pods = [{"cpu_milli": 1, "memory_mib": 1, "creation_time": t,
+        t0 = 0 if self.fork is None else self.fork.last_arrival or 0
+        pods = [{"cpu_milli": 1, "memory_mib": 1, "creation_time": t0 + t,
                  "duration_time": 10}
                 for t in range(self.envelope.min_real_pods(pod_bucket))]
         cfg = self.bucket_config(pod_bucket)
         pq, kt, s0 = stack_query_tables(self._mod, self.cluster, [pods],
                                         pod_bucket, cfg,
-                                        self._klen(pod_bucket))
+                                        self._klen(pod_bucket), self.fork)
         pq, kt = pack_query_tables(pq, kt, self._pack_plan(pod_bucket))
         (pq, s0), _ = pad_population((pq, s0), lanes)
         example = (pq, jnp.asarray(self._pad_kt(kt, lanes)), s0)
@@ -678,10 +726,18 @@ class ServeEngine:
             with self._batch_guard():
                 return self._answer_chunks(pod_lists)
 
+    def validate_query(self, pods: Sequence[dict]) -> None:
+        """``validate_query_pods`` under this engine's envelope and fork
+        (``ValueError``: the service's 4xx)."""
+        validate_query_pods(
+            pods, max_pods=self.envelope.max_pods,
+            max_gpu_milli=self.envelope.max_gpu_milli,
+            not_before=None if self.fork is None
+            else self.fork.last_arrival)
+
     def _answer_chunks(self, pod_lists) -> List[dict]:
         for pods in pod_lists:
-            validate_query_pods(pods, max_pods=self.envelope.max_pods,
-                                max_gpu_milli=self.envelope.max_gpu_milli)
+            self.validate_query(pods)
         self._reset_batch_log()
         answers: List[Optional[dict]] = [None] * len(pod_lists)
         groups: Dict[int, List[int]] = {}
@@ -710,11 +766,17 @@ class ServeEngine:
         chunk = len(self.last_batch_chunks)
         self.last_batch_chunks.append(list(idxs))
         lanes = self._global_lanes(len(idxs))
+        # of a forked chunk's upload, what is the residents' and not the
+        # queries' (the base is shipped with every batch, lane for lane)
+        forked = {} if self.fork is None else {
+            "start_event": self.fork.e0,
+            "resident_bytes": lanes * self.fork.lane_bytes}
         with obs.span("serve/chunk/stack", chunk=chunk, bucket=bucket,
-                      lanes=lanes, real=len(idxs)) as t_stack:
+                      lanes=lanes, real=len(idxs), **forked) as t_stack:
             pods, kt, s0 = stack_query_tables(
                 self._mod, self.cluster, [pod_lists[i] for i in idxs],
-                bucket, self.bucket_config(bucket), self._klen(bucket))
+                bucket, self.bucket_config(bucket), self._klen(bucket),
+                self.fork)
         with obs.span("serve/chunk/pack", chunk=chunk) as t_pack:
             pods, kt = pack_query_tables(pods, kt, self._pack_plan(bucket))
         with self.profiler.stage("h2d", span="serve/chunk/h2d", chunk=chunk,
@@ -727,7 +789,7 @@ class ServeEngine:
             else:
                 pods, s0 = jax.device_put((pods, s0))
             self.h2d_bytes_total += tree_h2d_bytes(pods, s0)
-            hh.span.set(bytes=self.h2d_bytes_total - sent0)
+            hh.span.set(bytes=self.h2d_bytes_total - sent0, **forked)
             hh.sync(jax.tree_util.tree_leaves(s0)[0])
         self.h2d_queries += len(idxs)
         # async dispatch; per-batch buffers donated. _invoke is the
@@ -786,31 +848,47 @@ class ServeEngine:
         res, idxs, bucket, lanes, real, chunk = inflight
         with self.profiler.stage("steady", span="serve/chunk/wait_device",
                                  chunk=chunk, lanes=lanes, real=real) as hs:
-            jax.block_until_ready(res.policy_score)
+            score = (res if self.fork is None else res[0]).policy_score
+            jax.block_until_ready(score)
             if self.profiler.enabled:
                 hs.annotate(**occupancy_stats(real, lanes))
         with obs.span("serve/chunk/d2h", chunk=chunk) as t_d2h:
-            self._last_scores = res.policy_score
+            self._last_scores = score
             res = jax.device_get(res)
             t_d2h.set(bytes=tree_h2d_bytes(res))
+            res, waiting = res if self.fork is not None else (res, None)
         self.last_batch_timing["dispatch_s"] += t_d2h.t1 - hs.span.t0
         with obs.span("serve/chunk/extract", chunk=chunk,
                       real=len(idxs)) as t_ext:
             for lane, i in enumerate(idxs):
                 answers[i] = self._extract(res, lane, len(pod_lists[i]),
-                                           bucket, lanes)
+                                           bucket, lanes, waiting)
+            if self.fork is not None:
+                # the regime a forked call ran in: failed placements of
+                # its real lanes over their events after the fork
+                real_ans = [answers[i] for i in idxs]
+                t_ext.set(frag_events=sum(a["frag_events"]
+                                          for a in real_ans),
+                          lane_events=sum(a["events"] - self.fork.e0
+                                          for a in real_ans))
         self.last_batch_spans += [hs.span.record, t_d2h.record,
                                   t_ext.record]
 
     def _extract(self, res, lane: Optional[int], p_real: int,
-                 bucket: int, lanes: int) -> dict:
+                 bucket: int, lanes: int, waiting=None) -> dict:
         """One lane's SimResult slice -> an answer dict (``lane=None``
         reads an unbatched scalar result). Placements cover REAL pods
-        only; node -1 means unplaced; GPU bitmask unpacked to indices."""
+        only; node -1 means unplaced; GPU bitmask unpacked to indices.
+        From a fork the query's pods follow the residents on the pod
+        axis and are the only ones listed; the counts stay the whole
+        run's, and ``waiting`` (the lanes' flags, where the executable
+        gave them) names the query's pods that a placement failed for
+        and that hold no node at the end."""
         pick = (lambda x: np.asarray(x)) if lane is None else \
             (lambda x: np.asarray(x)[lane])
-        assigned = pick(res.assigned_node)[:p_real]
-        gpus = pick(res.assigned_gpus)[:p_real].astype(np.int64)
+        mine = slice(self.start_event, self.start_event + p_real)
+        assigned = pick(res.assigned_node)[mine]
+        gpus = pick(res.assigned_gpus)[mine].astype(np.int64)
         node_ids = self.cluster.node_ids
         placements = []
         for i, (nd, gm) in enumerate(zip(assigned, gpus)):
@@ -820,7 +898,7 @@ class ServeEngine:
             if 0 <= int(nd) < len(node_ids):
                 row["node_id"] = node_ids[int(nd)]
             placements.append(row)
-        return {
+        out = {
             "score": float(pick(res.policy_score)),
             "scheduled": int(pick(res.scheduled_pods)),
             "failed": bool(pick(res.failed)),
@@ -830,6 +908,24 @@ class ServeEngine:
             "bucket_pods": bucket,
             "bucket_lanes": lanes,
         }
+        if self.fork is not None:
+            # the evaluator of the whole run, as the run ended or was
+            # cut: what the cluster's next events look like with the
+            # queue in it
+            out.update(
+                start_event=self.fork.e0,
+                frag_events=int(pick(res.num_fragmentation_events)),
+                snapshots=int(pick(res.num_snapshots)),
+                max_nodes=int(pick(res.max_nodes)),
+                utilization=[float(pick(x)) for x in (
+                    res.avg_cpu_utilization, res.avg_memory_utilization,
+                    res.avg_gpu_count_utilization,
+                    res.avg_gpu_memory_utilization)],
+                fragmentation=float(pick(res.gpu_fragmentation_score)))
+            if waiting is not None:
+                out["waiting"] = np.flatnonzero(
+                    pick(waiting)[mine]).tolist()
+        return out
 
     def reference_answer(self, pods: Sequence[dict]) -> dict:
         """The UNBATCHED exact-engine answer for one query, at the same
@@ -839,11 +935,10 @@ class ServeEngine:
         own ``loop_tables`` sizing, no vmap, no lane padding."""
         from fks_tpu.sim import engine as exact
 
-        validate_query_pods(pods, max_pods=self.envelope.max_pods,
-                            max_gpu_milli=self.envelope.max_gpu_milli)
+        self.validate_query(pods)
         bucket = self.envelope.pod_bucket_for(len(pods))
         cfg = self.bucket_config(bucket)
-        wl = build_query_workload(self.cluster, pods, bucket)
+        wl = build_query_workload(self.cluster, pods, bucket, self.fork)
         run = jax.jit(exact.make_param_run_fn(wl, self.param_policy, cfg))
         res = jax.device_get(run(self.params, exact.initial_state(wl, cfg)))
         return self._extract(res, None, len(pods), bucket, 1)
@@ -869,6 +964,14 @@ class ServeEngine:
             "cluster": _cluster_to_json(self.cluster),
             "base_pods": self.base_pods,
         }
+        if self.fork is not None:
+            # base_pods are in input order; the rows name them by index
+            snap, ranks = self._snapshot
+            doc["snapshot"] = {
+                "pod": np.asarray(snap.pod).tolist(),
+                "node": np.asarray(snap.node).tolist(),
+                "gpus": np.asarray(snap.gpus).tolist(),
+                "tie_rank": ranks.tolist()}
         cap = getattr(self, "program_capacity", None)
         if cap is not None:
             doc["program_capacity"] = int(cap)
@@ -896,6 +999,16 @@ class ServeEngine:
         cluster = _cluster_from_json(doc["cluster"])
         wl = Workload(cluster=cluster,
                       pods=_pods_from_dicts(doc.get("base_pods", [])))
+        if doc.get("snapshot"):
+            from fks_tpu.data.snapshot import Snapshot
+            rows = doc["snapshot"]
+            wl = dataclasses.replace(
+                wl, pods=dataclasses.replace(wl.pods, tie_rank=np.asarray(
+                    rows["tie_rank"], np.int32)),
+                snapshot=Snapshot(
+                    pod=np.asarray(rows["pod"], np.int32),
+                    node=np.asarray(rows["node"], np.int32),
+                    gpus=np.asarray(rows["gpus"], np.uint32)))
         extra = {}
         portfolio = doc.get("portfolio")
         if doc.get("engine_kind", "aot") == "vm" and cls.engine_kind != "vm":

@@ -55,9 +55,12 @@ DEFAULT_DURATION = 1_000_000
 
 
 def validate_query_pods(pods: Sequence[Dict[str, Any]], *, max_pods: int,
-                        max_gpu_milli: int) -> None:
+                        max_gpu_milli: int,
+                        not_before: Optional[int] = None) -> None:
     """Reject malformed queries before any device work (the error message
-    is the service's 4xx body)."""
+    is the service's 4xx body). ``not_before``: an engine that forks from
+    a snapshot (``QueryFork``) takes no pod created before the snapshot's
+    last arrival, because the events before the fork are decided."""
     if not pods:
         raise ValueError("query has no pods")
     if len(pods) > max_pods:
@@ -74,24 +77,37 @@ def validate_query_pods(pods: Sequence[Dict[str, Any]], *, max_pods: int,
         for field in ("cpu_milli", "memory_mib", "num_gpu", "gpu_milli"):
             if int(p.get(field, 0)) < 0:
                 raise ValueError(f"pod {i} {field} is negative")
+        if not_before is not None \
+                and int(p.get("creation_time", 0)) < not_before:
+            raise ValueError(
+                f"pod {i} creation_time {int(p.get('creation_time', 0))} "
+                f"lies before the fork: this engine answers from a "
+                f"snapshot whose last arrival is at {not_before}")
 
 
 def build_query_workload(cluster, pods: Sequence[Dict[str, Any]],
-                         bucket: int) -> Workload:
+                         bucket: int, fork: "Optional[QueryFork]" = None
+                         ) -> Workload:
     """One query -> a ``Workload`` padded to the pod bucket.
 
     Pod ids are zero-padded ordinals, so the reference's lexicographic
     tie order equals index order and ``tie_rank = arange`` reproduces it
     exactly. Padding rows are zeros under a False pod_mask (the
-    ``pad_workload`` idiom — never read by the engine)."""
+    ``pad_workload`` idiom — never read by the engine). With a ``fork``
+    the workload is ``residents ++ query pods`` on a pod axis of ``E0 +
+    bucket`` and carries the fork's snapshot, the query's ``tie_rank``
+    after the residents'."""
     p_real = len(pods)
     if p_real > bucket:
         raise ValueError(f"{p_real} pods exceed pod bucket {bucket}")
+    e0 = 0 if fork is None else fork.e0
 
     def col(field: str, default: int = 0) -> np.ndarray:
-        a = np.zeros(bucket, np.int32)
+        a = np.zeros(e0 + bucket, np.int32)
+        if e0:
+            a[:e0] = fork.cols[field]
         for i, p in enumerate(pods):
-            a[i] = int(p.get(field, default))
+            a[e0 + i] = int(p.get(field, default))
         return a
 
     pa = PodArrays(
@@ -101,18 +117,103 @@ def build_query_workload(cluster, pods: Sequence[Dict[str, Any]],
         gpu_milli=col("gpu_milli"),
         creation_time=col("creation_time"),
         duration=col("duration_time", DEFAULT_DURATION),
-        tie_rank=np.arange(bucket, dtype=np.int32),
-        pod_mask=np.arange(bucket) < p_real,
-        pod_ids=tuple(f"q-{i:05d}" for i in range(p_real)),
+        tie_rank=(np.arange(bucket, dtype=np.int32) if fork is None
+                  else np.concatenate([
+                      fork.rank, np.arange(e0, e0 + bucket, dtype=np.int32)])),
+        pod_mask=np.arange(e0 + bucket) < e0 + p_real,
+        pod_ids=(() if fork is None else fork.pod_ids)
+        + tuple(f"q-{i:05d}" for i in range(p_real)),
     )
-    return Workload(cluster=cluster, pods=pa, faults=None)
+    return Workload(cluster=cluster, pods=pa, faults=None,
+                    snapshot=None if fork is None else fork.snapshot)
+
+
+class QueryFork:
+    """The loaded cluster every query of one serve engine forks from: the
+    part of a workload's ``snapshot`` (``fks_tpu.data.snapshot``) that no
+    query changes, built ONCE per engine. A forked query's run is the run
+    of ``residents ++ query pods`` in which the snapshot decides the
+    first ``E0`` events and the champion every later one, so its answer
+    is where the queue lands on the cluster as it stands and what the
+    next events of the cluster's life look like with the queue in it.
+
+    Held here, all NumPy: the residents' pod columns in event order, the
+    snapshot re-indexed to that order, ``sim.engine.ForkPrefix`` (the
+    cluster after the residents, their running sums for the evaluator).
+    Per query, ``stack`` adds what does depend on the query: the trigger
+    table, which is sized from the WHOLE run's pod count, with the
+    evaluator's sums at the fork read off the prefix, and the heap, which
+    CPython builds by heapifying the query's CREATEs together with the
+    residents' before the prefix runs (``ops.heap.heap_rows_after_prefix``
+    through ``sim.engine.forked_state``: the retry rule reads the heap in
+    array order, so the replay is made for every query; 6 ms for 5,888
+    residents)."""
+
+    def __init__(self, workload: Workload):
+        from fks_tpu.data.snapshot import Snapshot
+        from fks_tpu.sim.engine import fork_prefix
+
+        snap, p = workload.snapshot, workload.pods
+        self.prefix = fork_prefix(workload)          # validates
+        order = np.asarray(snap.pod, np.int64)
+        self.e0 = snap.e0
+        fields = {"cpu_milli": p.cpu, "memory_mib": p.mem,
+                  "num_gpu": p.num_gpu, "gpu_milli": p.gpu_milli,
+                  "creation_time": p.creation_time,
+                  "duration_time": p.duration}
+        self.cols = {k: np.asarray(v)[order].astype(np.int32)
+                     for k, v in fields.items()}
+        rank = np.empty(self.e0, np.int32)
+        rank[np.argsort(np.asarray(p.tie_rank)[order], kind="stable")] = \
+            np.arange(self.e0, dtype=np.int32)
+        self.rank = rank        # the residents' pod-id order, made dense
+        self.pod_ids = tuple(p.pod_ids[int(i)] for i in order)
+        self.snapshot = Snapshot(
+            pod=np.arange(self.e0, dtype=np.int32),
+            node=np.asarray(snap.node, np.int32),
+            gpus=np.asarray(snap.gpus, np.uint32))
+        #: no query pod may be created before this (the 4xx of
+        #: ``validate_query_pods``): the last resident's arrival
+        self.last_arrival = int(self.cols["creation_time"][-1]) \
+            if self.e0 else None
+        self.nodes_loaded = int(len(np.unique(self.snapshot.node)))
+        c = workload.cluster
+        #: bytes of one lane's upload that are the residents' and not the
+        #: query's: their pod columns and mask, their heap and pod_state
+        #: rows, the cluster's four ``*_left`` arrays
+        self.lane_bytes = int(
+            self.e0 * (7 * 4 + 1 + 16 + 16)
+            + 4 * c.n_padded * (3 + c.g_padded))
+
+    def stack(self, cluster, pod_lists: Sequence[Sequence[dict]],
+              bucket: int, cfg, klen: int):
+        """``stack_query_tables`` for forked queries, in NumPy throughout
+        (the one upload is the engine's h2d stage): ``(pods[Q, E0 +
+        bucket], ktable[Q, K], state0[Q, ...])``, ``state0`` the exact
+        engine's ``forked_state`` of each query. ``cfg.max_steps`` is
+        absolute: ``E0`` plus the bucket's budget."""
+        from fks_tpu.sim.engine import forked_state
+
+        wls = [build_query_workload(cluster, p, bucket, self)
+               for p in pod_lists]
+        kt = _query_ktable(wls, cfg, klen)
+        states = [forked_state(w, cfg, self.prefix, kt[i])
+                  for i, w in enumerate(wls)]
+        stack = lambda *xs: np.stack([np.asarray(x) for x in xs])  # noqa: E731
+        # the ids are static pytree meta: dropped, as ``strip_ids`` does
+        bare = [dataclasses.replace(w.pods, pod_ids=()) for w in wls]
+        return (jax.tree_util.tree_map(stack, *bare), kt,
+                jax.tree_util.tree_map(stack, *states))
 
 
 def _query_ktable(wls: Sequence[Workload], cfg, klen: int) -> np.ndarray:
     """Per-query snapshot trigger tables at the bucket's fixed width:
     each table is sized from the query's REAL pod count (the reference's
     ``initialize(total_events)`` semantics) and padded with the INT32_MAX
-    sentinel, which never fires."""
+    sentinel, which never fires. A forked query's real pod count is the
+    WHOLE run's, residents included (``Workload.num_pods``), and
+    ``cfg.max_steps`` its absolute cap: the snapshots that lie before the
+    fork are in the table."""
     kt = np.full((len(wls), klen), KT_SENTINEL, np.int32)
     for i, w in enumerate(wls):
         tbl = snapshot_trigger_table(
@@ -154,7 +255,8 @@ def stack_queries(mod, cluster, pod_lists: Sequence[Sequence[dict]],
 
 
 def stack_query_tables(mod, cluster, pod_lists: Sequence[Sequence[dict]],
-                       bucket: int, cfg, klen: int):
+                       bucket: int, cfg, klen: int,
+                       fork: Optional[QueryFork] = None):
     """``stack_queries`` split for the device-resident serve hot path:
     returns ``(pods[Q,...] numpy, ktable[Q,K] numpy, state0[Q,...])``.
 
@@ -168,6 +270,8 @@ def stack_query_tables(mod, cluster, pod_lists: Sequence[Sequence[dict]],
     engine's h2d stage."""
     max_steps = cfg.max_steps
     assert max_steps is not None, "bucket SimConfig must pin max_steps"
+    if fork is not None:
+        return fork.stack(cluster, pod_lists, bucket, cfg, klen)
     wls = [build_query_workload(cluster, p, bucket) for p in pod_lists]
     kt = _query_ktable(wls, cfg, klen)
     stacked_pods = jax.tree_util.tree_map(

@@ -183,7 +183,25 @@ class ServeService:
         whatever fits the envelope) against the PINNED cluster, which is
         the serving question ("what would the champion do with this
         arrival stream here"), not a re-evaluation on the trace's own
-        cluster."""
+        cluster.
+
+        The fork. An engine built on a workload that carries a snapshot
+        (``fks_tpu.data.snapshot``; ``engine.fork``) answers every query
+        from the LOADED cluster: the snapshot's residents stay where it
+        put them and leave when their duration ends, and the query's
+        pods arrive among them. The snapshot belongs to the engine's
+        workload, never to a query, and the events before it are
+        decided: every pod's ``creation_time`` must be at or after the
+        snapshot's last arrival (``engine.fork.last_arrival``), on the
+        clock of the pod list the snapshot was taken from; an earlier
+        one is this request's ``ValueError`` (HTTP 400) at submit, before
+        it can reach a batch. The answer lists the query's pods only,
+        names those still ``waiting`` for a node when the run ended or
+        was cut at the bucket's step budget (which counts from the fork),
+        and reports the whole run's ``events``, ``scheduled``,
+        ``snapshots``, ``max_nodes``, ``utilization``, ``fragmentation``
+        and ``frag_events``, the residents' included, with
+        ``start_event`` saying where the champion took over."""
         if not isinstance(query, dict):
             raise ValueError("query must be a JSON object")
         rid = str(query.get("id", ""))
@@ -201,6 +219,8 @@ class ServeService:
         else:
             raise ValueError("query needs 'pods' (pod list) or 'trace' "
                              "(what-if trace to replay)")
+        if getattr(self.engine, "fork", None) is not None:
+            self.engine.validate_query(pods)
         return rid, pods
 
     def submit(self, query: Dict[str, Any]):

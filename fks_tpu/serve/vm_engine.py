@@ -301,7 +301,7 @@ class VMServeEngine(ServeEngine):
             final = run_batched_lanes(
                 lambda s: vstep(prog, pods, kt, s), state0,
                 max_steps, active_fn=mod.lane_active)
-            return vfin(pods, final)
+            return self._result(vfin(pods, final), final)
 
         return serve_fn
 

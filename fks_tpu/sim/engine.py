@@ -25,12 +25,20 @@ Semantics replicated exactly (SURVEY.md §2 fine print):
 
 The policy is a vectorized ``PolicyFn`` scoring all nodes at once; the
 population axis is added OUTSIDE via ``vmap`` (see fks_tpu.parallel).
+
+A workload that carries a ``snapshot`` (``fks_tpu.data.snapshot``) starts
+AFTER its ``E0`` events: ``initial_state`` returns ``forked_state``, the
+carry those events leave (the heap slot for slot CPython's own after the
+prefix, because the retry rule above reads it in array order; the
+residents on the pod axis). ``fork_prefix`` / ``fork_leaves`` hold the
+arithmetic that this engine and the flat one (``sim.flat._loaded_leaves``)
+share; the fused engine refuses a snapshot by name.
 """
 from __future__ import annotations
 
 import dataclasses
 import functools
-from typing import Any, Callable, Optional
+from typing import Any, Callable, NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
@@ -41,6 +49,7 @@ from fks_tpu.ops.allocator import best_fit_gpus, first_fit_gpus
 from fks_tpu.ops.heap import (
     KIND_CREATE, KIND_DELETE, KIND_NODE_DOWN, KIND_NODE_UP, EventHeap,
     first_deletion_in_array_order, heap_from_events, heap_pop, heap_push,
+    heap_rows_after_prefix,
 )
 from fks_tpu.sim.evaluator import max_snapshot_count, snapshot_trigger_table
 from fks_tpu.sim.guards import fitness_flags, guard_scores
@@ -158,13 +167,26 @@ class SimConfig:
         return self.resolve_max_steps(num_pods)
 
 
+def _hist_size(p: PodArrays, cfg: SimConfig) -> int:
+    max_milli = int(np.asarray(p.gpu_milli).max(initial=0))
+    hist_size = (cfg.wait_hist_size if cfg.wait_hist_size is not None
+                 else max(1001, max_milli + 2))
+    if hist_size <= max_milli:
+        raise ValueError(
+            f"wait_hist_size {hist_size} <= trace max gpu_milli; "
+            "fragmentation min_needed would be miscounted")
+    return hist_size
+
+
 def initial_state(workload: Workload, cfg: SimConfig) -> SimState:
     """Build the t=0 carry. Host-side; the initial heap layout is produced
-    by real CPython heapq so it matches the reference bit-for-bit."""
+    by real CPython heapq so it matches the reference bit-for-bit. A
+    workload with a ``snapshot`` (``fks_tpu.data.snapshot``) gives the
+    carry AFTER the snapshot's ``E0`` events instead (``forked_state``):
+    every runner that starts from here forks from the loaded cluster."""
     if workload.snapshot is not None:
-        raise ValueError(
-            "snapshot: flat engine only (the exact engine's heap at the "
-            "fork is not rebuilt yet); use engine='flat'")
+        return jax.tree_util.tree_map(jnp.asarray,
+                                      forked_state(workload, cfg))
     c, p = workload.cluster, workload.pods
     n_real = p.num_pods
     pm = np.asarray(p.pod_mask)
@@ -190,13 +212,7 @@ def initial_state(workload: Workload, cfg: SimConfig) -> SimState:
         capacity = p.p_padded + fpad
     heap = heap_from_events(times, ranks, kinds, payload, capacity=capacity)
     n, g, pp = c.n_padded, c.g_padded, p.p_padded
-    max_milli = int(np.asarray(p.gpu_milli).max(initial=0))
-    hist_size = (cfg.wait_hist_size if cfg.wait_hist_size is not None
-                 else max(1001, max_milli + 2))
-    if hist_size <= max_milli:
-        raise ValueError(
-            f"wait_hist_size {hist_size} <= trace max gpu_milli; "
-            "fragmentation min_needed would be miscounted")
+    hist_size = _hist_size(p, cfg)
     f = cfg.score_dtype
     pod_state = jnp.stack([
         jnp.full(pp, -1, jnp.int32),                     # assigned node
@@ -226,6 +242,136 @@ def initial_state(workload: Workload, cfg: SimConfig) -> SimState:
                if cfg.decision_trace else None),
         node_avail=None if fe is None else jnp.ones(n, bool),
     )
+
+
+class ForkPrefix(NamedTuple):
+    """What a snapshot's ``E0`` events leave of the cluster and of the
+    evaluator, whatever pods come after them: the part of a forked carry
+    that is computed once (serving builds it once per engine and forks
+    every query from it)."""
+
+    e0: int
+    left: Any           # data.snapshot.Loaded: the four ``*_left`` arrays
+    used: np.ndarray    # i64[E0, 4] cpu, mem, GPU count, GPU milli in use
+    totals: np.ndarray  # i64[4] the cluster's capacity of each
+    max_nodes: int      # active nodes after the last of the events
+
+
+def fork_prefix(workload: Workload) -> ForkPrefix:
+    """Validate the workload's snapshot (``place_residents`` raises
+    ``ValueError``) and sum its residents' requests in event order. The
+    ``E0`` events are placed CREATEs, so the cluster only fills: what is
+    in use after event ``i`` is a running sum, and ``max_nodes`` is the
+    count at the end."""
+    from fks_tpu.data.snapshot import gpu_slots, place_residents
+
+    if workload.faults is not None:
+        raise ValueError(
+            "snapshot: a workload with fault events or a decision trace "
+            "cannot start from a snapshot (the prefix holds neither)")
+    c, p, snap = workload.cluster, workload.pods, workload.snapshot
+    left = place_residents(workload, snap)
+    pod = np.asarray(snap.pod, np.int64)
+    totals = np.asarray([np.asarray(x, np.int64).sum() for x in (
+        c.cpu_total, c.mem_total, c.num_gpus, c.gpu_milli_total)])
+    ngpu = np.asarray(p.num_gpu, np.int64)[pod]
+    held = gpu_slots(snap, c.g_padded).sum(axis=1)
+    used = np.stack([
+        np.cumsum(np.asarray(p.cpu, np.int64)[pod]),
+        np.cumsum(np.asarray(p.mem, np.int64)[pod]),
+        np.cumsum(ngpu) + int((np.asarray(c.num_gpus, np.int64)
+                               - np.asarray(c.gpu_declared, np.int64)).sum()),
+        np.cumsum(np.asarray(p.gpu_milli, np.int64)[pod] * held),
+    ], axis=1)                            # [E0, 4] after each event
+    active = np.asarray(c.node_mask) & (
+        (left.cpu_left < np.asarray(c.cpu_total))
+        | (left.mem_left < np.asarray(c.mem_total))
+        | (left.gpu_left < np.asarray(c.num_gpus)))
+    return ForkPrefix(e0=snap.e0, left=left, used=used, totals=totals,
+                      max_nodes=int(active.sum()) if snap.e0 else 0)
+
+
+def fork_leaves(workload: Workload, cfg: SimConfig,
+                prefix: Optional[ForkPrefix] = None, ktable=None) -> dict:
+    """The leaves of a carry that a snapshot's ``E0`` events change and
+    that both engines hold alike, in NumPy: the cluster after the
+    residents, the counters and the evaluator's sums. Every one of the
+    events counts as an event and a step, nothing waits or fragments; the
+    utilization snapshots among them are the residents' running sums at
+    the trigger points of ``ktable`` (the workload's own, from
+    ``loop_tables``, unless given: it is sized from the WHOLE run's pod
+    count, so it differs by what follows the residents while ``prefix``
+    does not)."""
+    if cfg.decision_trace:
+        raise ValueError(
+            "snapshot: a workload with fault events or a decision trace "
+            "cannot start from a snapshot (the prefix holds neither)")
+    if prefix is None:
+        prefix = fork_prefix(workload)
+    if ktable is None:
+        ktable, _ = loop_tables(workload, cfg)
+    e0, totals = prefix.e0, prefix.totals
+    f = np.dtype(cfg.score_dtype)
+    # the step divides by totals that XLA folds to constants, and XLA
+    # turns a division by a constant into a product with its reciprocal
+    # (AlgebraicSimplifier, every backend): the same two roundings here,
+    # or the sums are an ulp off the engine's own
+    inv = f.type(1) / np.maximum(totals, 1).astype(f)
+    # the evaluator's sums, as the step accumulates them: one snapshot at
+    # most per event, when the event count reaches the next trigger
+    snap_sums = np.zeros(4, f)
+    snap_idx = events = 0
+    for trigger in np.asarray(ktable).tolist():
+        events = max(events + 1, int(trigger))
+        if events > e0:
+            break
+        utils = np.where(totals <= 0, f.type(0),
+                         prefix.used[events - 1].astype(f) * inv)
+        snap_sums = (snap_sums + utils).astype(f)
+        snap_idx += 1
+    left = prefix.left
+    return dict(
+        cpu_left=left.cpu_left.astype(np.int32),
+        mem_left=left.mem_left.astype(np.int32),
+        gpu_left=left.gpu_left.astype(np.int32),
+        gpu_milli_left=left.gpu_milli_left.astype(np.int32),
+        events_processed=np.int32(e0), steps=np.int32(e0),
+        snap_idx=np.int32(snap_idx), snap_sums=snap_sums,
+        max_nodes=np.int32(prefix.max_nodes))
+
+
+def forked_state(workload: Workload, cfg: SimConfig,
+                 prefix: Optional[ForkPrefix] = None,
+                 ktable=None) -> SimState:
+    """The exact engine's carry after the workload's snapshot, leaf for
+    leaf what ``build_step`` reaches when a policy makes those ``E0``
+    placements, as NumPy (``initial_state`` uploads it; serving stacks a
+    batch of them first). The heap is CPython's own after the prefix
+    (``ops.heap.heap_rows_after_prefix``: the retry rule reads it in array
+    order); ``pod_state`` holds the residents' node and GPU mask. ``prefix``
+    and ``ktable`` as in ``fork_leaves``."""
+    c, p, snap = workload.cluster, workload.pods, workload.snapshot
+    shared = fork_leaves(workload, cfg, prefix, ktable)
+    real = np.flatnonzero(np.asarray(p.pod_mask))
+    rows, size = heap_rows_after_prefix(
+        np.asarray(p.creation_time)[real], np.asarray(p.tie_rank)[real],
+        real, np.asarray(p.duration), snap.e0, capacity=p.p_padded)
+    pp = p.p_padded
+    pod_state = np.zeros((pp, 4), np.int32)
+    pod_state[:, SimState.COL_NODE] = -1
+    pod_state[:, SimState.COL_CTIME] = np.asarray(p.creation_time)
+    res = np.asarray(snap.pod, np.int64)
+    pod_state[res, SimState.COL_NODE] = np.asarray(snap.node)
+    pod_state[res, SimState.COL_BITS] = np.asarray(
+        snap.gpus, np.uint32).view(np.int32)
+    f = np.dtype(cfg.score_dtype)
+    return SimState(
+        heap=EventHeap(data=rows, size=np.int32(size)),
+        pod_state=pod_state,
+        wait_hist=np.zeros(_hist_size(p, cfg), np.int32),
+        frag_sum=np.zeros((), f), frag_count=np.int32(0),
+        failed=np.bool_(False), violations=np.int32(0),
+        numeric_flags=np.int32(0), trace=None, node_avail=None, **shared)
 
 
 def _widest_int():

@@ -79,7 +79,7 @@ from fks_tpu.ops.allocator import best_fit_gpus, first_fit_gpus
 from fks_tpu.ops.heap import KIND_NODE_UP
 from fks_tpu.sim.engine import (
     SimConfig, _audit, _gather_node_view, _node_view, _prefilter_candidates,
-    _trace_append, _widest_int, finalize_fields, loop_tables,
+    _trace_append, _widest_int, finalize_fields, fork_leaves, loop_tables,
     run_batched_lanes,
 )
 from fks_tpu.sim.guards import guard_scores
@@ -209,21 +209,14 @@ def initial_state(workload: Workload, cfg: SimConfig) -> FlatState:
 
 def _loaded_leaves(workload: Workload, cfg: SimConfig, perm, ev_time,
                    dt: dict) -> dict:
-    """The leaves of the carry that the snapshot's ``E0`` events change,
-    in NumPy. Those events are ``E0`` placed CREATEs (the snapshot's
-    validation), so every one counts as an event and a step, nothing
-    waits or fragments, and the cluster only fills: the utilization
-    snapshots among them are sums of the residents' requests up to the
-    trigger points, and ``max_nodes`` is the count at the end."""
-    from fks_tpu.data.snapshot import gpu_slots, place_residents
-
-    if workload.faults is not None or cfg.decision_trace:
-        raise ValueError(
-            "snapshot: a workload with fault events or a decision trace "
-            "cannot start from a snapshot (the prefix holds neither)")
+    """The leaves of the carry that the snapshot's ``E0`` events change.
+    The cluster, the counters and the evaluator's sums are
+    ``sim.engine.fork_leaves``' (one arithmetic for both engines); this
+    engine's own are the residents' slots: placed pods with their DELETE
+    pending."""
     c, p, snap = workload.cluster, workload.pods, workload.snapshot
-    left = place_residents(workload, snap)
-    e0, g = snap.e0, c.g_padded
+    shared = fork_leaves(workload, cfg)
+    g = c.g_padded
     pod = np.asarray(snap.pod, np.int64)
     node = np.asarray(snap.node, np.int64)
     bits = np.asarray(snap.gpus, np.int64)
@@ -231,7 +224,6 @@ def _loaded_leaves(workload: Workload, cfg: SimConfig, perm, ev_time,
     slot[perm] = np.arange(p.p_padded)    # slot index of each pod
     slot = slot[pod]
 
-    # residents are placed pods with their DELETE pending
     ev_time = ev_time.copy()
     ev_time[slot] = (np.asarray(p.creation_time, np.int64)
                      + np.asarray(p.duration, np.int64))[pod]
@@ -244,51 +236,8 @@ def _loaded_leaves(workload: Workload, cfg: SimConfig, perm, ev_time,
         aux_gpus = np.zeros(p.p_padded, np.int64)
         aux_gpus[slot] = bits
         out["aux_gpus"] = jnp.asarray(aux_gpus, dt["aux_gpus"])
-
-    # the evaluator's sums, as the step accumulates them: one snapshot at
-    # most per event, when the event count reaches the next trigger
-    ktable, _ = loop_tables(workload, cfg)
-    f = np.dtype(cfg.score_dtype)
-    totals = np.asarray([np.asarray(x, np.int64).sum() for x in (
-        c.cpu_total, c.mem_total, c.num_gpus, c.gpu_milli_total)])
-    # the step divides by totals that XLA folds to constants, and XLA
-    # turns a division by a constant into a product with its reciprocal
-    # (AlgebraicSimplifier, every backend): the same two roundings here,
-    # or the sums are an ulp off the engine's own
-    inv = f.type(1) / np.maximum(totals, 1).astype(f)
-    ngpu = np.asarray(p.num_gpu, np.int64)[pod]
-    held = gpu_slots(snap, g).sum(axis=1)
-    used = np.stack([
-        np.cumsum(np.asarray(p.cpu, np.int64)[pod]),
-        np.cumsum(np.asarray(p.mem, np.int64)[pod]),
-        np.cumsum(ngpu) + int((np.asarray(c.num_gpus, np.int64)
-                               - np.asarray(c.gpu_declared, np.int64)).sum()),
-        np.cumsum(np.asarray(p.gpu_milli, np.int64)[pod] * held),
-    ], axis=1)                            # [E0, 4] after each event
-    snap_sums = np.zeros(4, f)
-    snap_idx = events = 0
-    for trigger in ktable:
-        events = max(events + 1, int(trigger))
-        if events > e0:
-            break
-        utils = np.where(totals <= 0, f.type(0),
-                         used[events - 1].astype(f) * inv)
-        snap_sums = (snap_sums + utils).astype(f)
-        snap_idx += 1
-    nm = np.asarray(c.node_mask)
-    active = nm & ((left.cpu_left < np.asarray(c.cpu_total))
-                   | (left.mem_left < np.asarray(c.mem_total))
-                   | (left.gpu_left < np.asarray(c.num_gpus)))
-    out.update(
-        cpu_left=jnp.asarray(left.cpu_left, jnp.int32),
-        mem_left=jnp.asarray(left.mem_left, jnp.int32),
-        gpu_left=jnp.asarray(left.gpu_left, dt["gpu_left"]),
-        gpu_milli_left=jnp.asarray(left.gpu_milli_left,
-                                   dt["gpu_milli_left"]),
-        events_processed=jnp.int32(e0), steps=jnp.int32(e0),
-        snap_idx=jnp.int32(snap_idx),
-        snap_sums=jnp.asarray(snap_sums, cfg.score_dtype),
-        max_nodes=jnp.int32(int(active.sum()) if e0 else 0))
+    for name, leaf in shared.items():
+        out[name] = jnp.asarray(leaf, dt.get(name, leaf.dtype))
     return out
 
 

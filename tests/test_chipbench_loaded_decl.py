@@ -70,8 +70,9 @@ def test_benchmark_json_only_gained_entries():
     that held the fifth cell, nothing else."""
     with open(os.path.join(cells.ROOT, "BENCHMARK.json")) as f:
         bench = json.load(f)
-    assert bench["configs"][-1]["name"] == "openb1523-loaded"
-    assert bench["workloads"][-1]["name"] == CELL
+    # in the place PR 31 gave them (later PRs append after them)
+    assert bench["configs"][3]["name"] == "openb1523-loaded"
+    assert bench["workloads"][5]["name"] == CELL
     # the cell's own two metrics, in the place PR 31 gave them (later PRs
     # append after them)
     names = [m["name"] for m in bench["per_layer"]]
@@ -84,8 +85,9 @@ def test_benchmark_json_only_gained_entries():
         assert (CELL in lists) == (
             "openb1523-inflated.codegen8" in lists
             or m["name"] in ("tier.host_share", "vm.ms_per_event"))
-        if CELL in lists:
-            assert lists[-1] == CELL
+        if CELL in lists:      # last of the cells there were at PR 31
+            assert [w for w in lists
+                    if w != "openb1523-loaded.whatif8"][-1] == CELL
     for m in own:
         assert m["workloads"] == [CELL]
         assert m["layer"] == "engines sim/flat.py"

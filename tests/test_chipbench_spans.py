@@ -271,7 +271,9 @@ def test_a_program_without_the_ring_reads_as_nothing(monkeypatch):
 def test_every_span_metric_is_declared_with_its_files():
     bench = json.load(open(os.path.join(cells.ROOT, "BENCHMARK.json")))
     declared = {m["name"]: m for m in bench["per_layer"]}
-    assert len(SPAN_METRICS) == 24     # + vm.scatter_write_share (PR 35)
+    # + vm.scatter_write_share (PR 35), serve.fork_state_ms and
+    # serve.retry_share (PR 37)
+    assert len(SPAN_METRICS) == 26
     for name in SPAN_METRICS:
         assert name in declared, name
         meta = json.load(open(os.path.join(cells.HERE, "metrics",

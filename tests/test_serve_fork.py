@@ -1,0 +1,379 @@
+"""What-if serving forked from a loaded cluster: the exact engine's carry
+after a snapshot (``sim.engine.initial_state`` on a workload that carries
+one), its heap against CPython's own slot for slot, and forked queries
+through ``ServeService`` against the plain reference
+(``chipbench/reference/forked_query.py`` around ``simulate_from``,
+``retry="heap_array"``). The flat engine's fork is
+``tests/test_snapshot_carry.py``; the benchmark's cell on this path is
+``tests/test_chipbench_whatif_loaded.py``."""
+import dataclasses
+import hashlib
+import heapq
+import os
+
+import jax
+import numpy as np
+import pytest
+
+from chipbench.reference import forked_query, plain_sim, policies
+from fks_tpu import obs
+from fks_tpu.data import TraceParser, default_traces_dir
+from fks_tpu.data.build import make_pods
+from fks_tpu.data.entities import Workload
+from fks_tpu.data.snapshot import from_placements
+from fks_tpu.funsearch import transpiler
+from fks_tpu.models import zoo
+from fks_tpu.ops import heap as heap_ops
+from fks_tpu.serve import (ServeService, ShapeEnvelope, VMServeEngine,
+                           load_champion)
+from fks_tpu.serve.batcher import (QueryFork, build_query_workload,
+                                   pods_to_dicts, stack_query_tables)
+from fks_tpu.sim import engine as exact
+from fks_tpu.sim.engine import SimConfig, loop_tables
+from tests import pressure_traces as pt
+
+RULE = 64
+SEED, E0 = 5, 250      # the 60 arrivals after event 250 retry 11-20 times
+
+
+def _champion():
+    root = default_traces_dir().parent.parent / "policies" / "discovered"
+    return load_champion(str(root / pt.CHAMPIONS[0]))
+
+
+@pytest.fixture(scope="module")
+def pressure(tmp_path_factory):
+    d = str(tmp_path_factory.mktemp("fork"))
+    wl = pt.write_traces(d, SEED).parse_workload(pt.NODE_FILE, pt.POD_FILE)
+    return d, wl
+
+
+def _snapshot_of(wl, policy, e0, cfg):
+    """The exact engine's own first ``e0`` placements under ``policy``."""
+    res = exact.simulate(wl, policy,
+                         dataclasses.replace(cfg, max_steps=int(e0)))
+    assert int(res.scheduled_pods) == int(res.events_processed) == e0
+    return from_placements(wl, e0, res.assigned_node, res.assigned_gpus)
+
+
+# -------- the heap at the fork is CPython's, slot for slot
+
+def _cpython_heap(times, ranks, durations, e0):
+    """``heapify`` of every CREATE in pod-list order, then ``e0`` rounds
+    of pop-CREATE / push-DELETE: the plain reference's loop."""
+    h = [(int(t), int(r), 0, i) for i, (t, r) in enumerate(zip(times, ranks))]
+    heapq.heapify(h)
+    for _ in range(e0):
+        t, r, kind, i = heapq.heappop(h)
+        assert kind == 0
+        heapq.heappush(h, (t + int(durations[i]), r, 1, i))
+    return np.asarray(h, np.int64)
+
+
+@pytest.mark.parametrize("e0", [1, 7, 64, 129, 300])
+def test_fork_heap_is_cpythons_slot_for_slot_on_16_nodes(e0):
+    """A 16-node cluster under 400 small pods whose arrival order, name
+    order and list order all differ (so heapify has work to do and equal
+    times are broken by rank): the heap array of ``initial_state`` on the
+    forked workload equals ``heapq``'s list after the prefix."""
+    rng = np.random.default_rng(e0)
+    n = 400
+    ctime = rng.integers(0, 120, n)              # many equal times
+    names = rng.permutation(n)
+    rows = [{"pod_id": f"p{names[i]:04d}", "cpu_milli": 10,
+             "memory_mib": 10, "num_gpu": 0, "gpu_milli": 0,
+             "creation_time": int(ctime[i]),
+             "duration_time": int(rng.integers(200, 900))}
+            for i in range(n)]
+    cluster = TraceParser().parse_workload().cluster
+    assert cluster.num_nodes == 16
+    wl = Workload(cluster=cluster, pods=make_pods(rows, pad_pods_to=512))
+    cfg = SimConfig()
+    forked = dataclasses.replace(
+        wl, snapshot=_snapshot_of(wl, zoo.first_fit(), e0, cfg))
+    state = exact.initial_state(forked, cfg)
+    p = wl.pods
+    want = _cpython_heap(np.asarray(p.creation_time)[:n],
+                         np.asarray(p.tie_rank)[:n],
+                         np.asarray(p.duration)[:n], e0)
+    size = int(state.heap.size)
+    assert size == n == len(want)
+    assert np.array_equal(np.asarray(state.heap.data)[:size], want)
+    # and it is the array the engine's own heap ops leave after e0 steps
+    ktable, max_steps = loop_tables(wl, cfg)
+    step = exact.build_step(wl, zoo.first_fit(), cfg, ktable, max_steps)
+    stepped = jax.jit(lambda s: jax.lax.while_loop(
+        lambda s: s.steps < e0, step, s))(exact.initial_state(wl, cfg))
+    assert np.array_equal(np.asarray(stepped.heap.data)[:size], want)
+
+
+def test_a_prefix_that_pops_a_delete_is_refused():
+    with pytest.raises(ValueError, match="not 2 CREATEs"):
+        heap_ops.heap_rows_after_prefix([0, 5], [0, 1], [0, 1], [1, 1], 2)
+
+
+# -------- exact-engine fork identity
+
+def _policies():
+    return {"first_fit": zoo.first_fit(), "best_fit": zoo.best_fit(),
+            "champion": transpiler.transpile(_champion().code)}
+
+
+@pytest.mark.parametrize("name", ["first_fit", "best_fit", "champion"])
+def test_exact_fork_is_the_whole_run(pressure, name):
+    """Policy ``p`` run whole == the snapshot of ``p``'s first ``e0``
+    placements, then ``p`` from the snapshot: the carry at the fork leaf
+    by leaf (the live heap slot for slot) and the ``SimResult`` at the
+    end bit for bit, on a trace whose retries all lie after the fork."""
+    _, wl = pressure
+    pol = _policies()[name]
+    e0 = 200
+    cfg = SimConfig(node_prefilter_k=RULE)
+    ktable, max_steps = loop_tables(wl, cfg)
+    step = exact.build_step(wl, pol, cfg, ktable, max_steps)
+
+    @jax.jit
+    def advance(s, bound):
+        return jax.lax.while_loop(
+            lambda s: exact.lane_active(s, max_steps) & (s.steps < bound),
+            step, s)
+
+    finish = jax.jit(lambda s: exact.finalize(wl, cfg, s))
+    stepped = advance(exact.initial_state(wl, cfg), e0)
+    assert int(stepped.frag_count) == 0
+    forked = dataclasses.replace(wl, snapshot=from_placements(
+        wl, e0, stepped.assigned_node, stepped.assigned_gpus))
+    loaded = exact.initial_state(forked, cfg)
+    live = int(stepped.heap.size)
+    for (path, a), (_, b) in zip(
+            jax.tree_util.tree_leaves_with_path(stepped),
+            jax.tree_util.tree_leaves_with_path(loaded)):
+        a, b = np.asarray(a), np.asarray(b)
+        assert a.dtype == b.dtype and a.shape == b.shape, (name, path)
+        if a.shape == stepped.heap.data.shape:      # slots past size: stale
+            a, b = a[:live], b[:live]
+        assert np.array_equal(a, b), (name, path)
+    whole = finish(advance(stepped, 2 ** 30))
+    from_fork = finish(advance(loaded, 2 ** 30))
+    for a, b in zip(jax.tree_util.tree_leaves(whole),
+                    jax.tree_util.tree_leaves(from_fork)):
+        assert np.array_equal(np.asarray(a), np.asarray(b)), name
+    assert int(whole.num_fragmentation_events) > 0      # a retry cascade
+    assert float(whole.policy_score) > 0
+
+
+# -------- forked serving against the plain reference
+
+@pytest.fixture(scope="module")
+def forked(pressure):
+    """(forked workload, reference cluster, reference pods, rows): the
+    first ``E0`` arrivals as best_fit places them."""
+    d, wl = pressure
+    cfg = SimConfig(node_prefilter_k=RULE)
+    fwl = dataclasses.replace(
+        wl, snapshot=_snapshot_of(wl, zoo.best_fit(), E0, cfg))
+    cluster, pods = pt.reference_inputs(d)
+    snap = fwl.snapshot
+    rows = {int(i): (int(nd), int(g)) for i, nd, g in zip(
+        np.asarray(snap.pod), np.asarray(snap.node), np.asarray(snap.gpus))}
+    return fwl, cluster, pods, rows
+
+
+def _engine(fwl, factor, **kw):
+    return VMServeEngine(_champion(), fwl, engine="exact",
+                         max_steps_factor=factor, prefilter_k=RULE,
+                         envelope=ShapeEnvelope(max_batch=4, max_pods=256),
+                         **kw)
+
+
+def _ask(service, queries, tag):
+    futs = [service.submit({"id": f"{tag}-{j}", "pods": q})
+            for j, q in enumerate(queries)]
+    return [f.result(timeout=600) for f in futs]
+
+
+def _check(engine, forked, query_idx, answer):
+    fwl, cluster, pods, rows = forked
+    policy = policies.source_policy(_champion().code, dtype="float32")
+    n = len(query_idx)
+    bucket = engine.envelope.pod_bucket_for(n)
+    budget = max(64, engine.max_steps_factor * bucket)
+    taken, keyed = forked_query.inputs(pods, rows, query_idx)
+    ref, waiting = forked_query.simulate_query(
+        cluster, taken, keyed, policy, max_steps=E0 + budget,
+        prefilter_k=RULE, retry="heap_array")
+    a = answer
+    assert [r["node"] for r in a["placements"]] \
+        == ref.assigned_node[E0:].tolist()
+    assert [sum(1 << b for b in r["gpus"]) for r in a["placements"]] \
+        == ref.assigned_gpus[E0:].tolist()
+    assert len(a["placements"]) == n
+    assert (a["scheduled"], a["events"], a["failed"], a["truncated"]) \
+        == (ref.scheduled_pods, ref.events_processed, ref.failed,
+            ref.truncated)
+    assert a["waiting"] == waiting
+    assert (a["snapshots"], a["frag_events"], a["max_nodes"]) \
+        == (ref.num_snapshots, ref.num_frag_events, ref.max_nodes)
+    assert a["start_event"] == E0
+    np.testing.assert_allclose(a["utilization"], ref.avg_util, rtol=2e-6)
+    np.testing.assert_allclose(a["fragmentation"], ref.frag_mean,
+                               rtol=2e-6, atol=1e-9)
+    np.testing.assert_allclose(a["score"], ref.policy_score, rtol=2e-6)
+    return ref, waiting
+
+
+def test_forked_serving_answers_are_the_plain_references(forked):
+    """Mixed sizes in ONE coalesced batch through ``ServeService``, cut at
+    the bucket's budget from the fork (factor 1: 64 events): the query
+    that is the whole backlog is cut with nine of its pods waiting after
+    eleven failed placements; and a second call compiles nothing."""
+    fwl, _, pods, rows = forked
+    rest = [i for i in range(pods.p) if i not in rows]
+    dicts = pods_to_dicts(fwl.pods)
+    engine = _engine(fwl, 1)
+    service = ServeService(engine, max_batch=4, max_wait_s=0.25)
+    picks = [rest[:60], rest[3:11], rest[20:40], rest[5:6]]
+    try:
+        answers = _ask(service, [[dicts[i] for i in q] for q in picks], "a")
+        with obs.CompileWatcher(obs.NULL) as second:
+            again = _ask(service, [[dicts[i] for i in q] for q in picks],
+                         "b")
+    finally:
+        service.close()
+    assert second.backend_compile_count == 0, second.programs
+    for q, a, b in zip(picks, answers, again):
+        ref, waiting = _check(engine, forked, q, a)
+        assert ref.truncated and ref.events_processed == E0 + 64
+        drop = ("id", "latency_ms", "trace_id")
+        assert {k: v for k, v in a.items() if k not in drop} \
+            == {k: v for k, v in b.items() if k not in drop}
+    assert len(answers[0]["waiting"]) == 9
+    assert answers[0]["frag_events"] == 11
+    # the spans of a forked call say so
+    log = obs.spans.LOG.snapshot()
+    fork_spans = [r for r in log if r.name == "serve/fork_state"]
+    assert fork_spans and fork_spans[-1].fields["residents"] == E0
+    assert fork_spans[-1].fields["heap_size"] == E0
+    stacks = [r for r in log if r.name == "serve/chunk/stack"
+              and (r.fields or {}).get("start_event") == E0]
+    assert stacks and all(r.fields["resident_bytes"] > 0 for r in stacks)
+    extracts = [r for r in log if r.name == "serve/chunk/extract"
+                and "lane_events" in (r.fields or {})]
+    assert sum(r.fields["frag_events"] for r in extracts[-2:]) == 11
+
+
+def test_forked_serving_uncut_finishes_with_the_references_fitness(forked):
+    """Factor 8: the whole backlog's run drains (every resident leaves)
+    and reports the whole run's fitness; the unbatched reference answer of
+    the engine agrees."""
+    fwl, _, pods, rows = forked
+    rest = [i for i in range(pods.p) if i not in rows]
+    dicts = pods_to_dicts(fwl.pods)
+    engine = _engine(fwl, 8)
+    query = [dicts[i] for i in rest[:60]]
+    answer = engine.answer_batch([query, query[:30]])[0]
+    ref, waiting = _check(engine, forked, rest[:60], answer)
+    assert not ref.truncated and ref.policy_score > 0 and not waiting
+    assert ref.num_frag_events > 0
+    unbatched = engine.reference_answer(query)
+    assert unbatched["placements"] == answer["placements"]
+    assert unbatched["score"] == answer["score"]
+
+
+def test_a_query_pod_created_before_the_fork_is_a_4xx(forked):
+    fwl = forked[0]
+    engine = _engine(fwl, 1)
+    last = engine.fork.last_arrival
+    service = ServeService(engine, max_batch=2, max_wait_s=0.01)
+    try:
+        with pytest.raises(ValueError, match="lies before the fork"):
+            service.submit({"pods": [{"cpu_milli": 1, "memory_mib": 1,
+                                      "creation_time": last - 1}]})
+        with pytest.raises(ValueError, match="lies before the fork"):
+            engine.answer_batch([[{"cpu_milli": 1, "memory_mib": 1}]])
+    finally:
+        service.close()
+    # at the last arrival itself a query pod sorts after the residents
+    wl = build_query_workload(
+        fwl.cluster, [{"cpu_milli": 1, "memory_mib": 1,
+                       "creation_time": last}], 16, engine.fork)
+    assert int(exact.initial_state(wl, engine.bucket_config(16)).steps) == E0
+
+
+def test_the_forked_stack_is_initial_state_of_each_query(forked):
+    """What ``QueryFork.stack`` stages in NumPy for a chunk is, lane for
+    lane and leaf for leaf, ``initial_state`` of that query's forked
+    workload (the generic path, which validates and sums the residents
+    anew)."""
+    fwl = forked[0]
+    fork = QueryFork(fwl)
+    dicts = pods_to_dicts(fwl.pods)
+    queries = [dicts[E0:E0 + 5], dicts[E0 + 9:E0 + 25]]
+    cfg = SimConfig(max_steps=E0 + 64, wait_hist_size=1001,
+                    node_prefilter_k=RULE)
+    pods, kt, s0 = stack_query_tables(exact, fwl.cluster, queries, 16, cfg,
+                                      40, fork)
+    assert pods.cpu.shape == (2, E0 + 16) and kt.shape == (2, 40)
+    for lane, q in enumerate(queries):
+        wl = build_query_workload(fwl.cluster, q, 16, fork)
+        assert wl.num_pods == E0 + len(q)
+        want = exact.initial_state(wl, cfg)
+        got = jax.tree_util.tree_map(lambda x: x[lane], s0)
+        for a, b in zip(jax.tree_util.tree_leaves(want),
+                        jax.tree_util.tree_leaves(got)):
+            assert np.asarray(a).dtype == np.asarray(b).dtype
+            assert np.array_equal(np.asarray(a), np.asarray(b))
+        assert np.array_equal(kt[lane][:len(loop_tables(wl, cfg)[0])],
+                              loop_tables(wl, cfg)[0])
+
+
+def test_a_forked_engine_survives_save_and_load(forked, tmp_path):
+    fwl = forked[0]
+    engine = _engine(fwl, 1)
+    engine.save(str(tmp_path))
+    again = VMServeEngine.load(str(tmp_path))
+    assert again.fork is not None and again.fork.e0 == E0
+    assert again.fork.last_arrival == engine.fork.last_arrival
+    assert np.array_equal(again.fork.rank, engine.fork.rank)
+    assert again.bucket_config(64).max_steps == E0 + 64
+
+
+def test_engines_that_cannot_fork_say_so(forked):
+    fwl = forked[0]
+    with pytest.raises(ValueError, match="snapshot: serving forks on the "
+                                         "exact engine"):
+        VMServeEngine(_champion(), fwl, engine="flat")
+    from fks_tpu.portfolio.engine import PortfolioEngine
+    with pytest.raises(ValueError, match="snapshot: serving forks one "
+                                         "champion"):
+        PortfolioEngine([_champion()], fwl, engine="exact")
+
+
+# -------- without a snapshot nothing moves
+
+def test_without_a_snapshot_the_stacked_tables_are_todays_bytes():
+    """``stack_query_tables`` on a workload without a snapshot: the bytes
+    of every leaf as the parent commit (f985578) stacks them."""
+    wl = TraceParser().parse_workload()
+    rows = pods_to_dicts(wl.pods, limit=64)
+    queries = [rows[:5], rows[7:23], rows[30:33]]
+    cfg = SimConfig(max_steps=128, wait_hist_size=1001)
+    h = hashlib.sha256()
+    for leaf in jax.tree_util.tree_leaves(stack_query_tables(
+            exact, wl.cluster, queries, 16, cfg, 900)):
+        a = np.asarray(leaf)
+        h.update(str((a.dtype, a.shape)).encode())
+        h.update(np.ascontiguousarray(a).tobytes())
+    assert h.hexdigest() == ("e81c60f356716b9437252de1365d7e3b540a5a420ec0"
+                             "b18fdcf4b5a1f1ef71ce")
+
+
+def test_without_a_snapshot_an_answer_has_todays_keys():
+    wl = TraceParser().parse_workload()
+    engine = VMServeEngine(_champion(), wl, engine="exact",
+                           envelope=ShapeEnvelope(max_batch=2, max_pods=16))
+    assert engine.fork is None and engine.start_event == 0
+    assert engine.bucket_config(16).max_steps == 128
+    a = engine.answer_batch([pods_to_dicts(wl.pods, limit=4)])[0]
+    assert sorted(a) == ["bucket_lanes", "bucket_pods", "events", "failed",
+                         "placements", "scheduled", "score", "truncated"]

@@ -189,16 +189,17 @@ def test_a_placement_that_fails_makes_no_snapshot():
 
 def test_engines_and_runners_that_cannot_fork_refuse_by_name():
     wl = dataclasses.replace(_tiny(), snapshot=_snap([0], [0], [1]))
-    with pytest.raises(ValueError, match="snapshot: flat engine only"):
-        exact.initial_state(wl, SimConfig())
+    # the exact engine forks since PR 37 (tests/test_serve_fork.py); the
+    # candidate tiers stay on the flat engine's fork
+    assert int(exact.initial_state(wl, SimConfig()).steps) == 1
     with pytest.raises(ValueError, match="snapshot: flat engine only"):
         CodeEvaluator(wl, engine="exact")
     from fks_tpu.parallel import make_population_eval
     with pytest.raises(ValueError, match="snapshot: flat engine only"):
         make_population_eval(wl, engine="fused_interpret")
     from fks_tpu.parallel.traces import strip_ids
-    with pytest.raises(ValueError, match="snapshot: trace batching and "
-                                         "serving"):
+    with pytest.raises(ValueError, match="snapshot: trace batching "
+                                         "starts"):
         strip_ids(wl)
     from fks_tpu.scenarios.generator import ScenarioSpec, perturb_workload
     with pytest.raises(ValueError, match="snapshot: a scenario"):
