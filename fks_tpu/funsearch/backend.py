@@ -44,7 +44,7 @@ import dataclasses as _dc
 
 from fks_tpu import obs
 from fks_tpu.data.entities import Workload
-from fks_tpu.funsearch import transpiler, vm
+from fks_tpu.funsearch import lower_pool, transpiler, vm
 from fks_tpu.sim.engine import SimConfig, shape_prefilter_k
 from fks_tpu.sim.types import SimResult
 from fks_tpu.utils.segments import validate_seg_steps
@@ -90,9 +90,42 @@ class CodeEvaluator:
                  mesh=None, suite=None, robust=None, budget=None,
                  preflight: bool = True, fp_dedup: bool = True,
                  profiler=None):
+        from fks_tpu.parallel.mesh import num_shards
         from fks_tpu.sim import get_engine
 
         self.workload = workload
+        # Mesh-sharded batched tier: with a >1-device mesh each device
+        # interprets its shard of the stacked generation
+        # (parallel.mesh.make_sharded_code_eval) — the jit/parametric
+        # tiers and single-device behavior are unchanged.
+        self.mesh = mesh
+        self._n_shards = num_shards(mesh) if mesh is not None else 1
+        # Batched VM evaluation: under vmap the interpreter's lax.switch
+        # over a per-lane opcode executes ALL ~40 branches and selects.
+        # On TPU each branch is one elementwise vreg op — noise next to
+        # the engine step — so a generation as ONE launch wins; on a CPU
+        # host the same 40x op fan-out runs serially and loses badly to
+        # the sequential unbatched VM tier. Auto: batch iff the default
+        # backend is an accelerator — or a multi-device mesh was passed,
+        # which only the batched tier can use.
+        if vm_batch is None:
+            vm_batch = (jax.default_backend() != "cpu"
+                        or self._n_shards > 1
+                        # the budget rung ladder IS a batched-tier
+                        # construct (one stacked launch per rung); with
+                        # an enabled budget the pruning win dominates the
+                        # CPU switch-fan-out loss, so batch there too
+                        or (budget is not None and budget.enabled))
+        self.vm_batch = vm_batch
+        self.use_vm = use_vm
+        # The batched tier lowers a generation's sources in the process's
+        # pool of workers (fks_tpu.funsearch.lower_pool). Its first
+        # evaluator starts it, before its own work, so that the workers'
+        # start overlaps that and the first generation, which is lowered
+        # here as far as they are not yet up.
+        if use_vm and vm_batch:
+            c = workload.cluster
+            lower_pool.start(c.n_padded, c.g_padded)
         # Device-time attribution (fks_tpu.obs.profiler): when an enabled
         # StageProfiler is passed, evaluate() fences and attributes its
         # sandbox+preflight / transpile / device-eval stages; the default
@@ -196,7 +229,6 @@ class CodeEvaluator:
         self.segments_dispatched = 0
         self.last_eval_stats: Dict[str, int] = {}  # most recent evaluate()
         self.max_workers = max_workers or min(8, os.cpu_count() or 1)
-        self.use_vm = use_vm
         self._vm_run = None  # lazily built shared engine program
         self._vm_pop_run = None  # lazily built POPULATION engine program
         self._vm_mesh_run = None  # lazily built SHARDED population program
@@ -208,30 +240,6 @@ class CodeEvaluator:
         # the most recent batched launch's [lanes] score array, on the
         # device: last_lanes_per_device reads its placement when asked
         self._last_scores = None
-        # Mesh-sharded batched tier: with a >1-device mesh each device
-        # interprets its shard of the stacked generation
-        # (parallel.mesh.make_sharded_code_eval) — the jit/parametric
-        # tiers and single-device behavior are unchanged.
-        self.mesh = mesh
-        from fks_tpu.parallel.mesh import num_shards
-        self._n_shards = num_shards(mesh) if mesh is not None else 1
-        # Batched VM evaluation: under vmap the interpreter's lax.switch
-        # over a per-lane opcode executes ALL ~40 branches and selects.
-        # On TPU each branch is one elementwise vreg op — noise next to
-        # the engine step — so a generation as ONE launch wins; on a CPU
-        # host the same 40x op fan-out runs serially and loses badly to
-        # the sequential unbatched VM tier. Auto: batch iff the default
-        # backend is an accelerator — or a multi-device mesh was passed,
-        # which only the batched tier can use.
-        if vm_batch is None:
-            vm_batch = (jax.default_backend() != "cpu"
-                        or self._n_shards > 1
-                        # the budget rung ladder IS a batched-tier
-                        # construct (one stacked launch per rung); with
-                        # an enabled budget the pruning win dominates the
-                        # CPU switch-fan-out loss, so batch there too
-                        or self.budget is not None)
-        self.vm_batch = vm_batch
         # Bounded device-call length for the batched tier (flat engine
         # only). A full-trace batched-VM launch is minutes of device time
         # whatever the population size (v5e, 8 lanes x 370 live op
@@ -705,12 +713,21 @@ class CodeEvaluator:
         general: Dict[str, str] = {}  # default tier choice (VM then jit)
         c = self.workload.cluster
         with self.profiler.stage("transpile", span="tier/transpile") as ht:
-            runs0, ops0 = transpiler.body_runs(), vm.ops_count()
+            lowered: List[lower_pool.Lowered] = []
+            pool = lower_pool.NOT_POOLED
             if self.use_vm and self.vm_batch and len(unique) > 1:
-                for key, code in unique.items():
+                # every source is lowered once, side by side in the
+                # process's workers where it has them; what is raised or
+                # recorded for a source is what its lowering raised,
+                # wherever it ran, and vm_progs keeps unique's order (it
+                # is the lane order)
+                lowered, pool = lower_pool.lower_all(
+                    list(unique.values()), c.n_padded, c.g_padded)
+                for (key, code), low in zip(unique.items(), lowered):
                     try:
-                        prog = vm.compile_policy(code, c.n_padded,
-                                                 c.g_padded)
+                        if low.error is not None:
+                            raise low.error
+                        prog = vm.pack_program(*low.kept)
                         if prog.capacity > self.VM_CAPACITY:
                             raise vm.VMUnsupported(
                                 f"program too long: capacity "
@@ -733,12 +750,15 @@ class CodeEvaluator:
             # traces: counted where a policy body runs, so a second trace
             # per source shows (chipbench: tier.traces_per_source);
             # ops_lowered / ops_kept: what vm.simplify_ops was given and
-            # what it left to pack (chipbench: vm.ops_kept_share)
-            lowered, kept = vm.ops_count()
+            # what it left to pack (chipbench: vm.ops_kept_share);
+            # pooled / workers: lower_pool.lower_all (chipbench:
+            # tier.pooled_source_share)
             ht.span.set(sources=len(unique),
-                        traces=transpiler.body_runs() - runs0,
-                        ops_lowered=lowered - ops0[0],
-                        ops_kept=kept - ops0[1])
+                        traces=sum(low.traces for low in lowered),
+                        ops_lowered=sum(low.ops_lowered for low in lowered),
+                        ops_kept=sum(len(low.kept[0]) for low in lowered
+                                     if low.kept is not None),
+                        **pool)
 
         batch_served = 0
         self.last_budget_stats = []

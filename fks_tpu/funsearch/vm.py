@@ -686,16 +686,6 @@ def simplify_ops(ops, consts, out_reg: int):
     return kept, s.consts, slot.get(out, out)
 
 
-_OPS_COUNT = threading.local()
-
-
-def ops_count() -> Tuple[int, int]:
-    """(ops `_Lowerer` emitted, ops packed) over every `compile_policy` of
-    THIS thread: ``backend._evaluate`` reads the difference over its
-    transpile stage (``ops_lowered`` / ``ops_kept`` of ``tier/transpile``)."""
-    return getattr(_OPS_COUNT, "n", (0, 0))
-
-
 def pack_program(ops, consts, out_reg: int,
                  capacity: Optional[int] = None) -> VMProgram:
     """An op list as a ``VMProgram`` padded (OP_NOP) to ``capacity``, or to
@@ -732,11 +722,7 @@ def compile_policy(code: str, n: int, g: int,
     body runs under a JAX trace once, here, at (n, g): a candidate is valid
     on this path iff that trace succeeds.
     """
-    ops, consts, out_reg = lower_ops(code, n, g)
-    kept = simplify_ops(ops, consts, out_reg)
-    lowered, packed = ops_count()
-    _OPS_COUNT.n = (lowered + len(ops), packed + len(kept[0]))
-    return pack_program(*kept, capacity)
+    return pack_program(*simplify_ops(*lower_ops(code, n, g)), capacity)
 
 
 def compile_for_workload(code: str, workload, capacity: int = 512) -> VMProgram:

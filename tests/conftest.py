@@ -32,6 +32,13 @@ jax.config.update("jax_enable_compilation_cache", False)
 import json  # noqa: E402
 import pathlib  # noqa: E402
 
+from fks_tpu.funsearch import lower_pool  # noqa: E402
+
+# the driver runs tier-1 in six pytest processes side by side: each keeps
+# its pool of lowering workers (one per process, started by its first
+# batched-VM CodeEvaluator) to two, whatever the machine's cores
+lower_pool.MAX_WORKERS = 2
+
 import pytest  # noqa: E402
 
 FIXTURES = pathlib.Path(__file__).parent / "fixtures"

@@ -24,7 +24,7 @@ SPAN_METRICS = ("tier.preflight_ms_per_call", "tier.transpile_ms_per_call",
                 "vm.device_ms_per_event", "vm.live_slot_share",
                 "vm.us_per_slot", "vm.register_mb",
                 "tier.traces_per_source", "vm.ops_kept_share",
-                "vm.scatter_write_share")
+                "vm.scatter_write_share", "tier.pooled_source_share")
 
 
 def _run(monkeypatch, tmp_path, trace, seed=2 ** 31 + 5):
@@ -89,6 +89,7 @@ def test_cell_runs_end_to_end_and_reports_its_span_metrics(monkeypatch,
     assert v["tier.traces_per_source"] == 1.0   # no dry trace before it
     assert 0 < v["vm.ops_kept_share"] < 100     # the simplifier engaged
     assert v["vm.scatter_write_share"] == 0.0   # every write stayed a slice
+    assert 0.0 <= v["tier.pooled_source_share"] <= 100.0
     slots = v["vm.live_slot_share"] / 100 * 512
     assert v["vm.us_per_slot"] == pytest.approx(
         v["vm.device_ms_per_event"] * 1e3 / slots, rel=1e-6)

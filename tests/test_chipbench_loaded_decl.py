@@ -15,7 +15,7 @@ SPAN_METRICS = ("tier.preflight_ms_per_call", "tier.transpile_ms_per_call",
                 "vm.device_ms_per_event", "vm.live_slot_share",
                 "vm.us_per_slot", "vm.register_mb",
                 "tier.traces_per_source", "vm.ops_kept_share",
-                "vm.scatter_write_share",
+                "vm.scatter_write_share", "tier.pooled_source_share",
                 "sim.fork_state_ms")
 #: read from the driver's counters: the profiler's device-eval stage
 #: against the calls' seconds and the window's lockstep events

@@ -257,6 +257,40 @@ def test_scatter_write_share_reads_the_launch_spans_fields(fields, want):
     assert got == (want if want is None else pytest.approx(want))
 
 
+def _transpile_calls(fields):
+    """The window's calls of three generations (the first is the warm-up),
+    each with a ``tier/transpile`` span that carries ``fields``."""
+    recs, t = [], 0.0
+    for i in range(3):
+        recs.append(rec(2 * i, "tier/transpile", t + 0.1, t + 0.3,
+                        f"x{i}", f"g{i}", f"g{i}", **fields))
+        recs.append(rec(2 * i + 1, "tier/evaluate", t, t + 2.0, f"g{i}",
+                        None, f"g{i}"))
+        t += 2.01
+    calls = rs.select_generations(recs, 0, 2, 4.0)
+    assert calls
+    return calls
+
+
+@pytest.mark.parametrize("fields,want", [
+    # every source of every generation lowered by a worker
+    ({"sources": 8, "traces": 8, "pooled": 8, "workers": 7}, 100.0),
+    # lowered in process (one usable core, a broken pool): a reading
+    ({"sources": 8, "traces": 8, "pooled": 0, "workers": 0}, 0.0),
+    # workers that were still starting: the rest was lowered in process
+    ({"sources": 8, "pooled": 4, "workers": 2}, 50.0),
+    ({"sources": 0, "pooled": 0, "workers": 0}, None),
+    # a parent without the fields (older than PR 39)
+    ({"sources": 8, "traces": 8, "ops_lowered": 2614, "ops_kept": 2026},
+     None),
+    ({}, None),
+])
+def test_pooled_source_share_reads_the_transpile_spans_fields(fields, want):
+    got = cells.metric_reader("tier.pooled_source_share")(
+        {"_span_calls": _transpile_calls(fields)})
+    assert got == (want if want is None else pytest.approx(want))
+
+
 def test_a_program_without_the_ring_reads_as_nothing(monkeypatch):
     from fks_tpu.obs import spans
 
@@ -272,8 +306,8 @@ def test_every_span_metric_is_declared_with_its_files():
     bench = json.load(open(os.path.join(cells.ROOT, "BENCHMARK.json")))
     declared = {m["name"]: m for m in bench["per_layer"]}
     # + vm.scatter_write_share (PR 35), serve.fork_state_ms and
-    # serve.retry_share (PR 37)
-    assert len(SPAN_METRICS) == 26
+    # serve.retry_share (PR 37), tier.pooled_source_share (PR 39)
+    assert len(SPAN_METRICS) == 27
     for name in SPAN_METRICS:
         assert name in declared, name
         meta = json.load(open(os.path.join(cells.HERE, "metrics",
@@ -330,7 +364,7 @@ def test_traced_cell_reports_every_span_metric(name, tmp_path, monkeypatch):
             + v["serve.h2d_ms_per_call"] + v["serve.harvest_ms_per_call"]
         assert v["serve.h2d_kb_per_call"] > 0 and v["serve.d2h_kb_per_call"] > 0
     else:
-        assert len(want) == 12
+        assert len(want) == 13
         # a recorded generation: every source traced once, where it runs,
         # and the simplifier dropped part of what the lowering emitted
         assert res["metrics"]["tier.traces_per_source"]["value"] == 1.0
@@ -339,3 +373,7 @@ def test_traced_cell_reports_every_span_metric(name, tmp_path, monkeypatch):
         assert res["metrics"]["mesh.host_ms_per_call"]["value"] > 0
         # the mesh runner kept every register write one slice
         assert res["metrics"]["vm.scatter_write_share"]["value"] == 0.0
+        # the generations went through the process's lowering pool as
+        # far as this machine has the cores and the workers were up
+        assert 0.0 <= res["metrics"]["tier.pooled_source_share"]["value"] \
+            <= 100.0

@@ -1,0 +1,402 @@
+"""The lowering pool (``fks_tpu.funsearch.lower_pool``, ISSUE 39): a
+generation's sources lowered side by side by worker processes give what
+the in-process loop gives, bit for bit and record for record; the pool is
+one a process, adapts to the cores it sees, never makes a generation wait
+for its start, survives a lost worker and never outlives its parent.
+
+``conftest.py`` caps a pytest process at two workers; the tests that need
+the pool to engage tell it there are cores for that (``usable_cores``),
+whatever this machine has, and wait for its workers themselves (``up``).
+"""
+import os
+import struct
+import subprocess
+import sys
+import threading
+import time
+
+import jax
+import numpy as np
+import pytest
+
+from fks_tpu.funsearch import backend, lower_pool, transpiler, vm
+from fks_tpu.obs import spans
+from tests import lowering_corpus as corpus
+
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+
+
+def up(n: int = 2, within: float = 120.0) -> list:
+    """The live pool's workers once ``n`` of them are through their
+    start: a generation does not wait for them, a test has to."""
+    deadline = time.perf_counter() + within
+    while len(lower_pool.workers()) < n and time.perf_counter() < deadline:
+        time.sleep(0.05)
+    ws = lower_pool.workers()
+    assert len(ws) == n, ws
+    return ws
+
+
+@pytest.fixture
+def cores(monkeypatch):
+    """A host with four usable cores and the process's pool (two workers,
+    ``conftest.py``) up."""
+    monkeypatch.setattr(lower_pool, "usable_cores", lambda: 4)
+    lower_pool.start(16, 8)
+    up()
+
+
+def bits(x):
+    """``x`` with every float as its eight bytes: -0.0, NaN and the last
+    digit all tell."""
+    if isinstance(x, float):
+        return struct.pack("<d", x)
+    if isinstance(x, (list, tuple)):
+        return tuple(bits(v) for v in x)
+    return x
+
+
+def same(a: lower_pool.Lowered, b: lower_pool.Lowered) -> bool:
+    return (bits(a.kept) == bits(b.kept)
+            and (a.ops_lowered, a.traces) == (b.ops_lowered, b.traces)
+            and type(a.error) is type(b.error)
+            and str(a.error) == str(b.error))
+
+
+def transpile_span(mark: int):
+    (sp,) = [r for r in spans.LOG.snapshot()[mark:]
+             if r.name == "tier/transpile"]
+    return sp.fields
+
+
+CHAMPION = "champion:20260801_045536_score0.5365"
+
+
+def mixed_records(capacity: int, monkeypatch):
+    """``corpus.MIXED`` and a ledger champion (220 ops at 4 x 4, the 256
+    bucket) as one generation on the batched tier with the op budget at
+    ``capacity``: every field of every record (the result as a hash of its
+    leaves), how the generation was served, and the stage's span fields."""
+    monkeypatch.setattr(backend.CodeEvaluator, "VM_CAPACITY", capacity)
+    src = corpus.sources()
+    codes = [src[n] for n in corpus.MIXED + (CHAMPION,)]
+    ev = backend.CodeEvaluator(corpus.mixed_workload(), vm_batch=True,
+                               preflight=False)
+    mark = len(spans.LOG.snapshot())
+    recs = [(r.code, r.score, r.error, r.result and corpus._hash_leaves(
+        (f, leaf) for f, leaf in zip(r.result._fields, r.result)
+        if leaf is not None)) for r in ev.evaluate(codes)]
+    served = {k: ev.last_eval_stats[k]
+              for k in ("vm_batch_lanes", "fallback_lanes", "unique")}
+    return recs, served, transpile_span(mark)
+
+
+# ----------------------------------------------- (a) pooled == in process
+
+@pytest.mark.parametrize("shape,x64,whole", [
+    ((16, 8), False, True), ((16, 8), True, False),
+    ((1528, 8), False, False), ((1528, 8), True, True)],
+    ids=("16x8-f32-corpus", "16x8-x64", "1528x8-f32", "1528x8-x64-corpus"))
+def test_pooled_lowering_is_the_in_process_lowering(shape, x64, whole,
+                                                    cores):
+    """The 13 ledger champions and the seed policies at both shapes in
+    both precisions, the whole lowering corpus in two of the four:
+    ``(ops, consts, out_reg)``, the counts, the exception's class and
+    message, and every array of the packed program."""
+    named = {k: v for k, v in corpus.sources().items()
+             if whole or k.startswith(("champion:", "seed:"))}
+    codes = list(named.values())
+    with jax.enable_x64(x64):
+        got, stats = lower_pool.lower_all(codes, *shape)
+        want = [lower_pool.lower_source(c, *shape) for c in codes]
+        assert stats["pooled"] == len(codes) and stats["workers"] == 2
+        lowered = 0
+        for name, g, w in zip(named, got, want):
+            assert same(g, w), name
+            if w.kept is None:
+                continue
+            lowered += 1
+            pg, pw = vm.pack_program(*g.kept), vm.pack_program(*w.kept)
+            assert pg.capacity == pw.capacity \
+                == vm.capacity_bucket(len(w.kept[0])), name
+            assert int(pg.n_ops) == int(pw.n_ops) == len(w.kept[0]), name
+            assert corpus.program_hash(pg) == corpus.program_hash(pw), name
+            assert pg.imm.dtype == (np.float64 if x64 else np.float32)
+        # every champion and seed policy lowers, the violations do not
+        assert (80 < lowered < len(codes)) if whole \
+            else (13 < lowered == len(codes))
+
+
+# --------------------------- (b), (c) records, routing, the span's fields
+
+@pytest.mark.parametrize("capacity", (512, 128), ids=("cap512", "cap128"))
+def test_generation_records_and_routing_are_the_in_process_ones(
+        capacity, cores, monkeypatch):
+    """TranspileError, VMUnsupported and (at an op budget of 128, which
+    the champion overruns) an over-capacity source, beside valid ones:
+    every field of every record and the tier each source went to, pooled
+    and in process; the stage's span says where the sources were lowered
+    and counts alike."""
+    pooled, served_p, fp = mixed_records(capacity, monkeypatch)
+    monkeypatch.setattr(lower_pool, "usable_cores", lambda: 1)
+    serial, served_s, fs = mixed_records(capacity, monkeypatch)
+    assert pooled == serial and served_p == served_s
+    errors = [e or "" for _, _, e, _ in pooled]
+    assert sum(e.startswith("transpile:") for e in errors) == 3
+    assert sum(e.startswith("syntax:") for e in errors) == 1
+    assert sum(not e for e in errors) == 7
+    # VMUnsupported goes to the jit tier, and over the budget the champion
+    assert served_p == {"unique": 9, "vm_batch_lanes": 5 - (capacity < 256),
+                        "fallback_lanes": 1 + (capacity < 256)}
+    # 9 unique sources enter the stage; each is traced once, somewhere
+    assert (fp["sources"], fp["pooled"], fp["traces"]) == (9, 9, 9)
+    assert 1 <= fp["workers"] <= 2
+    assert (fs["sources"], fs["pooled"], fs["workers"], fs["traces"]) \
+        == (9, 0, 0, 9)
+    assert fp["ops_lowered"] == fs["ops_lowered"] > fp["ops_kept"] \
+        == fs["ops_kept"] > 0
+
+
+# ----------------------------------------------------- (d) a lost worker
+
+def test_a_killed_worker_costs_one_generation_its_pool_not_its_records(
+        cores):
+    src = corpus.sources()
+    codes = [src[n] for n in corpus.MIXED]
+    ev = backend.CodeEvaluator(corpus.mixed_workload(), vm_batch=True,
+                               preflight=False)
+    want = ev.evaluate(codes)
+    before, drops = up(), lower_pool.drops()
+    os.kill(before[0]["pid"], 9)
+    mark = len(spans.LOG.snapshot())
+    got = ev.evaluate(codes)
+    fields = transpile_span(mark)
+    assert (fields["pooled"], fields["workers"], fields["traces"]) \
+        == (0, 0, 8)
+    assert lower_pool.drops() == drops + 1
+    assert not any(alive(w["pid"]) for w in before)
+    # the next generation starts a pool of its own (and does not wait for
+    # it); once that is up a generation is pooled again
+    mark = len(spans.LOG.snapshot())
+    mid = ev.evaluate(codes)
+    assert transpile_span(mark)["traces"] == 8
+    after = up()
+    assert not {w["pid"] for w in after} & {w["pid"] for w in before}
+    mark = len(spans.LOG.snapshot())
+    again = ev.evaluate(codes)
+    fields = transpile_span(mark)
+    assert (fields["pooled"], fields["traces"]) == (8, 8)
+    assert lower_pool.drops() == drops + 1
+    for a, b, c, d in zip(want, got, mid, again):
+        assert (a.score, a.error) == (b.score, b.error) \
+            == (c.score, c.error) == (d.score, d.error)
+
+
+def test_a_generation_does_not_wait_for_workers_that_are_starting(
+        monkeypatch):
+    """A fresh pool is two seconds from its first worker: the generation
+    that starts it is lowered here, whole, and comes back before any
+    worker is up; the same sources later go to the workers."""
+    monkeypatch.setattr(lower_pool, "usable_cores", lambda: 4)
+    monkeypatch.setattr(lower_pool, "_pool", None)
+    codes = [corpus.sources()[n] for n in
+             ("seed:first_fit", "seed:best_fit", "fake3:00")]
+    try:
+        out, stats = lower_pool.lower_all(codes, 16, 8)
+        assert lower_pool._pool is not None and not lower_pool._pool.ready
+        assert stats == lower_pool.NOT_POOLED
+        assert [o.traces for o in out] == [1, 1, 1]
+        up()
+        again, stats = lower_pool.lower_all(codes, 16, 8)
+        assert stats == {"pooled": 3, "workers": 2}
+        assert all(same(a, b) for a, b in zip(out, again))
+    finally:
+        lower_pool._pool.close()   # this test's own; the process's returns
+
+
+# ------------------------------------------ (e) what a worker runs under
+
+def test_a_worker_is_on_the_cpu_in_the_parents_x64(cores):
+    ws = up()
+    assert len({w["pid"] for w in ws}) == 2
+    assert os.getpid() not in {w["pid"] for w in ws}
+    for w in ws:
+        assert w["backend"] == "cpu"
+        assert w["x64"] is bool(jax.config.jax_enable_x64) is True
+
+
+# --------------------------------- adapting: cores, a single source, size
+
+def test_one_usable_core_or_one_source_is_lowered_in_process(monkeypatch):
+    codes = list(corpus.sources().values())[:3]
+    monkeypatch.setattr(lower_pool, "_Pool", None)  # must not be built
+    monkeypatch.setattr(lower_pool, "_pool", None)
+    monkeypatch.setattr(lower_pool, "usable_cores", lambda: 1)
+    out, stats = lower_pool.lower_all(codes, 16, 8)
+    assert stats == lower_pool.NOT_POOLED and len(out) == 3
+    lower_pool.start(16, 8)
+    monkeypatch.setattr(lower_pool, "usable_cores", lambda: 12)
+    out, stats = lower_pool.lower_all(codes[:1], 16, 8)
+    assert stats == lower_pool.NOT_POOLED and out[0].traces == 1
+    assert lower_pool._pool is None
+
+
+@pytest.mark.parametrize("sources,usable,cap,want", [
+    (8, 12, 16, 8), (8, 7, 16, 7), (64, 29, 16, 16), (8, 12, 2, 2),
+    (8, 1, 16, 1), (1, 12, 16, 1), (8, 0, 16, 0)])
+def test_pool_size_is_the_least_of_sources_cores_and_cap(
+        sources, usable, cap, want, monkeypatch):
+    monkeypatch.setattr(lower_pool, "usable_cores", lambda: usable)
+    monkeypatch.setattr(lower_pool, "MAX_WORKERS", cap)
+    assert lower_pool._size(sources) == want
+
+
+def test_a_pool_that_cannot_start_is_counted_and_the_generation_lowered(
+        monkeypatch):
+    def no_pool(*a):
+        raise OSError("no more processes")
+
+    monkeypatch.setattr(lower_pool, "usable_cores", lambda: 4)
+    monkeypatch.setattr(lower_pool, "_pool", None)
+    monkeypatch.setattr(lower_pool, "_Pool", no_pool)
+    drops = lower_pool.drops()
+    lower_pool.start(16, 8)
+    codes = list(corpus.sources().values())[:3]
+    out, stats = lower_pool.lower_all(codes, 16, 8)
+    assert lower_pool.drops() == drops + 2
+    assert stats == lower_pool.NOT_POOLED
+    assert [o.traces for o in out] == [1, 1, 1]
+    assert lower_pool._pool is None
+
+
+def test_lower_source_returns_what_compile_policy_raises():
+    src = corpus.sources()
+    low = lower_pool.lower_source(src["vm:unsupported"], 16, 8)
+    assert type(low.error) is vm.VMUnsupported and low.kept is None
+    with pytest.raises(vm.VMUnsupported) as e:
+        vm.compile_policy(src["vm:unsupported"], 16, 8)
+    assert str(e.value) == str(low.error)
+    low = lower_pool.lower_source(src["subset:2"], 16, 8)
+    assert type(low.error) is transpiler.TranspileError and low.traces == 1
+    with pytest.raises(transpiler.TranspileError) as e:
+        vm.compile_policy(src["subset:2"], 16, 8)
+    assert str(e.value) == str(low.error)
+    low = lower_pool.lower_source(src["syntax:broken"], 16, 8)
+    assert low.error is not None and low.traces == 0
+
+
+# --------------------- (f) one pool a process; none outlives its process
+
+def test_two_evaluators_and_two_threads_share_one_pool(cores):
+    src = corpus.sources()
+    codes = [src[n] for n in corpus.MIXED]
+    evs = [backend.CodeEvaluator(corpus.mixed_workload(), vm_batch=True,
+                                 preflight=False) for _ in range(2)]
+    want = evs[0].evaluate(codes)
+    pids = {w["pid"] for w in up()}
+    got, marks = {}, len(spans.LOG.snapshot())
+
+    def run(i):
+        got[i] = [evs[i].evaluate(codes) for _ in range(3)]
+
+    threads = [threading.Thread(target=run, args=(i,)) for i in range(2)]
+    for t in threads:
+        t.start()
+    for t in threads:
+        t.join(timeout=300)
+    assert not any(t.is_alive() for t in threads)
+    assert {w["pid"] for w in lower_pool.workers()} == pids
+    stages = [r.fields for r in spans.LOG.snapshot()[marks:]
+              if r.name == "tier/transpile"]
+    assert len(stages) == 6 and all(f["pooled"] == 8 for f in stages)
+    for recs in got[0] + got[1]:
+        assert [(r.score, r.error) for r in recs] \
+            == [(r.score, r.error) for r in want]
+
+
+def alive(pid: int) -> bool:
+    """A process that runs (a zombie nobody reaped does not)."""
+    try:
+        with open(f"/proc/{pid}/stat") as f:
+            return f.read().rpartition(")")[2].split()[0] != "Z"
+    except (FileNotFoundError, ProcessLookupError):
+        return False
+
+
+def group_alive(pgid: int) -> bool:
+    """Any running process of the process group (a pool's nursery leads
+    one, its workers are in it)."""
+    for pid in filter(str.isdigit, os.listdir("/proc")):
+        try:
+            with open(f"/proc/{pid}/stat") as f:
+                state, _, pgrp = f.read().rpartition(")")[2].split()[:3]
+        except (FileNotFoundError, ProcessLookupError):
+            continue
+        if int(pgrp) == pgid and state != "Z":
+            return True
+    return False
+
+
+CHILD = """
+import time
+import jax
+jax.config.update("jax_platforms", "cpu")
+from fks_tpu.funsearch import backend, lower_pool
+from tests import lowering_corpus as corpus
+lower_pool.usable_cores = lambda: 4
+lower_pool.MAX_WORKERS = 2
+src = corpus.sources()
+ev = backend.CodeEvaluator(corpus.mixed_workload(), vm_batch=True,
+                           preflight=False)
+{work}
+print("group", lower_pool._pool.proc.pid, len(lower_pool.workers()),
+      flush=True)
+raise SystemExit(1)
+"""
+
+
+@pytest.mark.parametrize("work,ready", [
+    # a pooled generation, then the exit: idle workers
+    ("while len(lower_pool.workers()) < 2: time.sleep(0.05)\n"
+     "ev.evaluate([src[n] for n in corpus.MIXED])", 2),
+    # the exit while the nursery still imports (nothing waited for it)
+    ("", 0),
+], ids=("after_a_generation", "during_the_start"))
+def test_a_process_that_exits_leaves_no_worker_behind(work, ready):
+    env = dict(os.environ, PYTHONPATH=ROOT)
+    proc = subprocess.Popen([sys.executable, "-c", CHILD.format(work=work)],
+                            stdout=subprocess.PIPE, cwd=ROOT, env=env,
+                            text=True)
+    try:
+        line = proc.stdout.readline().split()
+        t0 = time.perf_counter()
+        assert line[0] == "group" and int(line[2]) == ready, line
+        pgid = int(line[1])
+        assert proc.wait(timeout=5) == 1
+        while group_alive(pgid) and time.perf_counter() - t0 < 5:
+            time.sleep(0.05)
+        assert not group_alive(pgid)
+        assert time.perf_counter() - t0 < 5
+    finally:
+        proc.kill()
+        proc.wait()
+
+
+def test_serving_starts_no_worker(monkeypatch):
+    """``VMServeEngine`` compiles its one champion in process."""
+    from tests import test_vm_serve as tv
+    from fks_tpu.data.synthetic import synthetic_workload
+    from fks_tpu.serve import ShapeEnvelope, VMServeEngine
+
+    def no_pool(*a):
+        raise AssertionError("serving built a lowering pool")
+
+    monkeypatch.setattr(lower_pool, "_pool", None)
+    monkeypatch.setattr(lower_pool, "_Pool", no_pool)
+    monkeypatch.setattr(lower_pool, "usable_cores", lambda: 12)
+    eng = VMServeEngine(
+        tv._champ(tv.BETTER_LOGIC), synthetic_workload(8, 16, seed=0),
+        envelope=ShapeEnvelope(max_pods=8, min_pod_bucket=8, max_batch=2,
+                               max_gpu_milli=1000), engine="flat")
+    assert len(eng.answer_batch([tv._query(0)])) == 1
+    assert lower_pool._pool is None

@@ -10,7 +10,9 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from fks_tpu.funsearch import backend, llm, template, transpiler, vm
+from fks_tpu.funsearch import (
+    backend, llm, lower_pool, template, transpiler, vm,
+)
 from fks_tpu.sim.types import NodeView, PodView
 from tests import lowering_corpus as corpus
 from tests.conftest import FIXTURES
@@ -239,7 +241,7 @@ def test_transpile_stage_counts_sources_and_traces():
     (sp,) = [r for r in spans.LOG.snapshot()[mark:]
              if r.name == "tier/transpile"]
     assert sp.fields == {"sources": 1, "traces": 0, "ops_lowered": 0,
-                         "ops_kept": 0}
+                         "ops_kept": 0, "pooled": 0, "workers": 0}
 
 
 # ---------------------------------------------------------------------------
@@ -331,9 +333,9 @@ def test_the_best_champion_keeps_292_of_370_ops(shape):
     assert (count(raw[0], vm.OP_MUL), count(kept[0], vm.OP_MUL)) == (130, 66)
     # the grids rebuilt column by column stay (see simplify_ops)
     assert count(raw[0], vm.OP_SETCOL) == count(kept[0], vm.OP_SETCOL) == 40
-    c0 = vm.ops_count()
     assert int(vm.compile_policy(code, *shape).n_ops) == 292
-    assert tuple(np.subtract(vm.ops_count(), c0)) == (370, 292)
+    low = lower_pool.lower_source(code, *shape)
+    assert (low.ops_lowered, len(low.kept[0]), low.traces) == (370, 292, 1)
 
 
 # -- rule by rule, on hand-made op lists --------------------------------
