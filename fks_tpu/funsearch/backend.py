@@ -714,7 +714,7 @@ class CodeEvaluator:
         c = self.workload.cluster
         with self.profiler.stage("transpile", span="tier/transpile") as ht:
             lowered: List[lower_pool.Lowered] = []
-            pool = lower_pool.NOT_POOLED
+            pool, misfit = lower_pool.NOT_POOLED, False
             if self.use_vm and self.vm_batch and len(unique) > 1:
                 # every source is lowered once, side by side in the
                 # process's workers where it has them; what is raised or
@@ -723,22 +723,43 @@ class CodeEvaluator:
                 # is the lane order)
                 lowered, pool = lower_pool.lower_all(
                     list(unique.values()), c.n_padded, c.g_padded)
-                for (key, code), low in zip(unique.items(), lowered):
-                    try:
-                        if low.error is not None:
-                            raise low.error
-                        prog = vm.pack_program(*low.kept)
-                        if prog.capacity > self.VM_CAPACITY:
-                            raise vm.VMUnsupported(
-                                f"program too long: capacity "
-                                f"{prog.capacity}")
-                        vm_progs[key] = prog
-                    except vm.VMUnsupported:
-                        jit_only[key] = code
-                    except transpiler.TranspileError as e:
-                        memo[key] = EvalRecord(code, 0.0, f"transpile: {e}")
-                    except Exception as e:  # noqa: BLE001 — untrusted code
-                        memo[key] = EvalRecord(code, 0.0, f"runtime: {e}")
+                # each lowering as a child span, on the stamps of the
+                # process that did it; none of them where a worker's
+                # clock is not this one's (a wrong interval is worse)
+                misfit = lower_pool.clock_misfit(lowered)
+                if not misfit:
+                    for i, low in enumerate(lowered):
+                        ht.span.child(
+                            "tier/transpile/lower", low.t0, low.t1,
+                            source=i, pid=low.pid,
+                            pooled=int(low.sent is not None),
+                            trace_ms=(low.t_traced - low.t0) * 1e3,
+                            ops_lowered=low.ops_lowered,
+                            ops_kept=len(low.kept[0]) if low.kept else 0)
+                packed = 0
+                with obs.span("tier/transpile/pack") as tp:
+                    for (key, code), low in zip(unique.items(), lowered):
+                        try:
+                            if low.error is not None:
+                                raise low.error
+                            prog = vm.pack_program(*low.kept)
+                            packed += 1
+                            if prog.capacity > self.VM_CAPACITY:
+                                raise vm.VMUnsupported(
+                                    f"program too long: capacity "
+                                    f"{prog.capacity}")
+                            vm_progs[key] = prog
+                        except vm.VMUnsupported:
+                            jit_only[key] = code
+                        except transpiler.TranspileError as e:
+                            memo[key] = EvalRecord(code, 0.0,
+                                                   f"transpile: {e}")
+                        except Exception as e:  # noqa: BLE001 — untrusted
+                            memo[key] = EvalRecord(code, 0.0,
+                                                   f"runtime: {e}")
+                    # every array of a program is an upload of its own
+                    tp.set(programs=packed,
+                           uploads=packed * len(vm.VMProgram._fields))
                 if len(vm_progs) == 1:  # a population program for one lane
                     (key,) = vm_progs  # isn't worth it: unbatched VM tier
                     general[key] = unique[key]
@@ -752,8 +773,9 @@ class CodeEvaluator:
             # ops_lowered / ops_kept: what vm.simplify_ops was given and
             # what it left to pack (chipbench: vm.ops_kept_share);
             # pooled / workers: lower_pool.lower_all (chipbench:
-            # tier.pooled_source_share)
-            ht.span.set(sources=len(unique),
+            # tier.pooled_source_share); clock_misfit: 1 where the
+            # workers' stamps were refused and no lower span written
+            ht.span.set(sources=len(unique), clock_misfit=int(misfit),
                         traces=sum(low.traces for low in lowered),
                         ops_lowered=sum(low.ops_lowered for low in lowered),
                         ops_kept=sum(len(low.kept[0]) for low in lowered

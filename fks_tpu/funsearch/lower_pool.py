@@ -59,12 +59,23 @@ NOT_POOLED = {"pooled": 0, "workers": 0}
 
 
 class Lowered(NamedTuple):
-    """What lowering one source leaves behind, as plain Python."""
+    """What lowering one source leaves behind, as plain Python, and where
+    and when it was lowered: stamps on ``time.perf_counter``, which is
+    ``CLOCK_MONOTONIC`` on Linux, one clock for every process of the host
+    (`clock_misfit` checks that it was)."""
 
     kept: Optional[tuple]  # simplify_ops' (ops, consts, out_reg)
     ops_lowered: int  # ops the lowering emitted, before simplify_ops
     traces: int  # times the policy's body ran (transpiler.body_runs)
     error: Optional[Exception]  # what `vm.compile_policy` would have raised
+    pid: int = 0  # the process that lowered it
+    t0: float = 0.0  # `lower_source` entered
+    t_traced: float = 0.0  # ``vm.lower_ops`` was through (or raised)
+    t1: float = 0.0  # `lower_source` returned
+    # the parent's own stamps around a worker's task (before the send,
+    # after the receive); None for a source lowered in process
+    sent: Optional[float] = None
+    received: Optional[float] = None
 
 
 def lower_source(code: str, n: int, g: int) -> Lowered:
@@ -74,15 +85,28 @@ def lower_source(code: str, n: int, g: int) -> Lowered:
     ``VMUnsupported`` and ``TranspileError`` keep their class, anything
     else (candidate code is untrusted) becomes a ``RuntimeError``."""
     runs0 = transpiler.body_runs()
-    kept, lowered, error = None, 0, None
+    kept, lowered, error, t_traced = None, 0, None, None
+    t0 = time.perf_counter()
     try:
         ops, consts, out_reg = vm.lower_ops(code, n, g)
+        t_traced = time.perf_counter()
         kept, lowered = vm.simplify_ops(ops, consts, out_reg), len(ops)
     except (vm.VMUnsupported, transpiler.TranspileError) as e:
         error = type(e)(str(e))
     except Exception as e:  # noqa: BLE001 — untrusted code
         error = RuntimeError(str(e))
-    return Lowered(kept, lowered, transpiler.body_runs() - runs0, error)
+    t1 = time.perf_counter()
+    return Lowered(kept, lowered, transpiler.body_runs() - runs0, error,
+                   os.getpid(), t0, t1 if t_traced is None else t_traced, t1)
+
+
+def clock_misfit(lowered: Sequence[Lowered]) -> bool:
+    """True when a worker's stamps do not lie inside the parent's own
+    send and receive stamps for that task: the two processes do not read
+    one clock (a container boundary, another clock source), and an
+    interval from the worker's would be wrong on the parent's."""
+    return any(not low.sent <= low.t0 <= low.t1 <= low.received
+               for low in lowered if low.sent is not None)
 
 
 def serve(fd: int, n: int, g: int, x64: bool) -> None:
@@ -181,21 +205,23 @@ class _Pool:
         out: List[Optional[Lowered]] = [None] * len(tasks)
         todo = collections.deque(enumerate(tasks))
         idle = list(self.ready)
-        busy: Dict[mpc.Connection, int] = {}
+        busy: Dict[mpc.Connection, Tuple[int, float]] = {}  # -> task, sent
         used, pooled = set(), 0
         while todo or busy:
             idle += self.arrivals()
             while todo and idle:
                 i, task = todo.popleft()
                 conn = idle.pop()
+                busy[conn] = i, time.perf_counter()
                 conn.send(task)
-                busy[conn] = i
             if todo and self.starting:
                 i, (code, n, g, _) = todo.popleft()
                 out[i] = lower_source(code, n, g)
                 continue
             for conn in mpc.wait(list(busy)):
-                out[busy.pop(conn)] = conn.recv()
+                i, sent = busy.pop(conn)
+                out[i] = conn.recv()._replace(
+                    sent=sent, received=time.perf_counter())
                 pooled += 1
                 used.add(conn)
                 idle.append(conn)

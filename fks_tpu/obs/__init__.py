@@ -18,7 +18,17 @@ jitted code.
                   profiler's timeline, and mirrors into an open run
                   directory. Always on: two clock reads, one append, no
                   fence, no file, no lock. The span names (``serve/...``,
-                  ``tier/...``, ``mesh/...``) are listed in its docstring
+                  ``tier/...``, ``mesh/...``) are listed in its docstring.
+                  Two prefixes are the ring's own, never opened by a call
+                  site: ``host/gc`` (the collector's pauses, from the
+                  ``gc.callbacks`` entry installed with the ring) and
+                  ``obs/slow_root`` (a ``tier/evaluate`` or ``serve/batch``
+                  root 1.25 times the median of its like: kept with its
+                  stage sums in ``spans.SLOW`` / ``spans.slow_roots()``,
+                  counted, and said in ONE warning line that names the
+                  span that grew); ``span.child`` writes a child whose
+                  stamps another process of the host took (the lowering
+                  workers' ``tier/transpile/lower``)
 - ``trace_ctx`` — causal trace contexts (trace_id/span_id/parent_id)
                   propagated explicitly across thread boundaries, spans
                   known after the fact (``emit``, into the same ring),

@@ -21,6 +21,7 @@ from __future__ import annotations
 
 import contextlib
 import threading
+import time
 from typing import Any, Dict, List, Optional
 
 import jax
@@ -60,6 +61,7 @@ class CompileWatcher:
         self.prefix = prefix
         self.events: List[tuple] = []  # (key, seconds)
         self.programs: List[str] = []  # fun_name per backend compile
+        self.compiled_at: List[float] = []  # its end, time.perf_counter
         self.cache_hits = 0
         self._lock = threading.Lock()
         self._installed = False
@@ -73,6 +75,7 @@ class CompileWatcher:
             if key.endswith(BACKEND_COMPILE):
                 # jax names the program it compiled (log_elapsed_time)
                 self.programs.append(str(kwargs.get("fun_name", "?")))
+                self.compiled_at.append(time.perf_counter())
         self.recorder.event("compile", key=key, seconds=float(seconds))
 
     def _listen_event(self, key: str, **kwargs) -> None:
@@ -83,6 +86,7 @@ class CompileWatcher:
     def install(self) -> "CompileWatcher":
         if not self._installed:
             self._installed = True
+            _INSTALLED.append(self)
             jax.monitoring.register_event_duration_secs_listener(self._listen)
             jax.monitoring.register_event_listener(self._listen_event)
         return self
@@ -91,6 +95,7 @@ class CompileWatcher:
         if not self._installed:
             return
         self._installed = False  # gate first: inert even if unregister fails
+        _INSTALLED.remove(self)
         from jax._src import monitoring as _monitoring
         _monitoring.unregister_event_duration_listener(self._listen)
         _monitoring.unregister_event_listener(self._listen_event)
@@ -135,6 +140,23 @@ class CompileWatcher:
         with self._lock:
             return sum(s for k, s in self.events
                        if k.endswith(BACKEND_COMPILE))
+
+
+#: the watchers installed now, for `compiles_between`
+_INSTALLED: List[CompileWatcher] = []
+
+
+def compiles_between(t0: float, t1: float) -> Optional[int]:
+    """Backend compiles (cache fetches among them) that ended inside
+    ``[t0, t1]`` on ``time.perf_counter``, as the installed watcher that
+    saw most counts them; None where none is installed (``obs.spans``
+    writes it into a slow call's record)."""
+    seen = None
+    for w in list(_INSTALLED):
+        with w._lock:
+            n = sum(t0 <= t <= t1 for t in w.compiled_at)
+        seen = n if seen is None else max(seen, n)
+    return seen
 
 
 def watch_compiles(recorder=None):

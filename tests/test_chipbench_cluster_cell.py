@@ -24,7 +24,11 @@ SPAN_METRICS = ("tier.preflight_ms_per_call", "tier.transpile_ms_per_call",
                 "vm.device_ms_per_event", "vm.live_slot_share",
                 "vm.us_per_slot", "vm.register_mb",
                 "tier.traces_per_source", "vm.ops_kept_share",
-                "vm.scatter_write_share", "tier.pooled_source_share")
+                "vm.scatter_write_share", "tier.pooled_source_share",
+                # the ring's other writers (PR 40; chipbench/reduce/hostspans.py)
+                "tier.lower_ms_per_source", "tier.pack_ms_per_call",
+                "tier.pool_overhead_ms_per_call", "tier.gc_ms_per_call",
+                "tier.slow_call_share")
 
 
 def _run(monkeypatch, tmp_path, trace, seed=2 ** 31 + 5):

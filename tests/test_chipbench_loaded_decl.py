@@ -16,6 +16,10 @@ SPAN_METRICS = ("tier.preflight_ms_per_call", "tier.transpile_ms_per_call",
                 "vm.us_per_slot", "vm.register_mb",
                 "tier.traces_per_source", "vm.ops_kept_share",
                 "vm.scatter_write_share", "tier.pooled_source_share",
+                # the ring's other writers (PR 40; chipbench/reduce/hostspans.py)
+                "tier.lower_ms_per_source", "tier.pack_ms_per_call",
+                "tier.pool_overhead_ms_per_call", "tier.gc_ms_per_call",
+                "tier.slow_call_share",
                 "sim.fork_state_ms")
 #: read from the driver's counters: the profiler's device-eval stage
 #: against the calls' seconds and the window's lockstep events

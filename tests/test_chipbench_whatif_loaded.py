@@ -75,8 +75,13 @@ def test_benchmark_json_only_gained_entries():
     names = [m["name"] for m in bench["per_layer"]]
     at = names.index(NEW[0])
     assert names[at:at + 2] == list(NEW)
-    # appended since, at the end: PR 39's metric of the code cells
-    assert names[at + 2:] == ["tier.pooled_source_share"]
+    # appended since, at the end: PR 39's metric of the code cells and
+    # PR 40's seven readers of the ring's other writers
+    assert names[at + 2:] == [
+        "tier.pooled_source_share", "tier.lower_ms_per_source",
+        "tier.pack_ms_per_call", "tier.pool_overhead_ms_per_call",
+        "tier.gc_ms_per_call", "serve.gc_ms_per_call",
+        "tier.slow_call_share", "serve.slow_call_share"]
     new = bench["per_layer"][at:at + 2]
     for m in new:
         assert m["workloads"] == [CELL] and m["layer"] == "serving serve/"

@@ -157,6 +157,98 @@ def test_generation_records_and_routing_are_the_in_process_ones(
         == fs["ops_kept"] > 0
 
 
+# ------------------------ the workers' own spans come home (ISSUE 40)
+
+def generation_spans(cores_now, monkeypatch, lower_all=None):
+    """One generation of ``corpus.MIXED`` (eight unique sources reach the
+    stage) with ``cores_now`` usable cores: its ``tier/transpile`` record
+    and that record's children."""
+    monkeypatch.setattr(lower_pool, "usable_cores", lambda: cores_now)
+    if lower_all is not None:
+        monkeypatch.setattr(lower_pool, "lower_all", lower_all)
+    src = corpus.sources()
+    ev = backend.CodeEvaluator(corpus.mixed_workload(), vm_batch=True,
+                               preflight=False)
+    mark = len(spans.LOG.snapshot())
+    ev.evaluate([src[n] for n in corpus.MIXED])
+    new = spans.LOG.snapshot()[mark:]
+    (stage,) = [r for r in new if r.name == "tier/transpile"]
+    return stage, [r for r in new if r.parent_id == stage.span_id]
+
+
+@pytest.mark.parametrize("pooled", (1, 0), ids=("pooled", "in_process"))
+def test_every_source_leaves_a_lower_span_inside_the_stage(
+        pooled, cores, monkeypatch):
+    """Wherever a source is lowered it writes one ``tier/transpile/lower``
+    on the parent's clock, nested in the stage, with the process that did
+    it; the uploads are ``tier/transpile/pack``."""
+    stage, kids = generation_spans(4 if pooled else 1, monkeypatch)
+    assert stage.fields["sources"] == 8
+    assert stage.fields["pooled"] == 8 * pooled
+    assert stage.fields["clock_misfit"] == 0
+    lowers = [r for r in kids if r.name == "tier/transpile/lower"]
+    (pack,) = [r for r in kids if r.name == "tier/transpile/pack"]
+    assert len(kids) == 9 and len(lowers) == 8
+    assert sorted(r.fields["source"] for r in lowers) == list(range(8))
+    for r in lowers:
+        assert stage.t0 <= r.t0 < r.t1 <= stage.t1
+        assert r.trace_id == stage.trace_id
+        assert r.fields["pooled"] == pooled
+        assert 0 <= r.fields["trace_ms"] <= (r.t1 - r.t0) * 1e3
+        # lowered, then packed: the uploads follow the last lowering
+        assert r.t1 <= pack.t0
+    pids = {r.fields["pid"] for r in lowers}
+    if pooled:
+        assert pids <= {w["pid"] for w in up()} and os.getpid() not in pids
+    else:
+        assert pids == {os.getpid()}
+        # one after another on one core: no two overlap
+        ordered = sorted(lowers, key=lambda r: r.t0)
+        assert all(a.t1 <= b.t0 for a, b in zip(ordered, ordered[1:]))
+    assert stage.fields["ops_lowered"] \
+        == sum(r.fields["ops_lowered"] for r in lowers)
+    assert stage.fields["ops_kept"] \
+        == sum(r.fields["ops_kept"] for r in lowers)
+    # three of the eight do not lower (TranspileError, VMUnsupported ...)
+    packed = sum(r.fields["ops_kept"] > 0 for r in lowers)
+    assert pack.fields == {"programs": packed,
+                           "uploads": packed * len(vm.VMProgram._fields)}
+    assert stage.t0 <= pack.t0 <= pack.t1 <= stage.t1
+
+
+def test_a_worker_on_another_clock_leaves_no_lower_span(cores, monkeypatch):
+    """Stamps that do not lie inside the parent's own send and receive
+    stamps are refused: the stage says so and writes no child from them
+    (the uploads are the parent's own and stay)."""
+    real = lower_pool.lower_all
+
+    def shifted(codes, n, g):
+        out, stats = real(codes, n, g)
+        assert stats["pooled"] == len(codes)
+        return [low._replace(t0=low.t0 + 3600.0, t_traced=low.t_traced
+                             + 3600.0, t1=low.t1 + 3600.0)
+                for low in out], stats
+
+    stage, kids = generation_spans(4, monkeypatch, shifted)
+    assert stage.fields["clock_misfit"] == 1 and stage.fields["pooled"] == 8
+    assert [r.name for r in kids] == ["tier/transpile/pack"]
+
+
+def test_clock_misfit_reads_the_parents_window():
+    low = lower_pool.lower_source(corpus.sources()["seed:best_fit"], 16, 8)
+    assert low.pid == os.getpid() and low.sent is None
+    assert low.t0 < low.t_traced < low.t1
+    assert not lower_pool.clock_misfit([low])        # in process: no window
+    inside = low._replace(sent=low.t0 - 1e-3, received=low.t1 + 1e-3)
+    assert not lower_pool.clock_misfit([low, inside])
+    for bad in (inside._replace(sent=low.t0 + 1e-6),
+                inside._replace(received=low.t1 - 1e-6)):
+        assert lower_pool.clock_misfit([inside, bad])
+    # a source that does not lower is stamped all the same
+    bad = lower_pool.lower_source(corpus.sources()["subset:2"], 16, 8)
+    assert bad.error is not None and bad.t0 < bad.t_traced == bad.t1
+
+
 # ----------------------------------------------------- (d) a lost worker
 
 def test_a_killed_worker_costs_one_generation_its_pool_not_its_records(

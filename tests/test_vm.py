@@ -241,7 +241,8 @@ def test_transpile_stage_counts_sources_and_traces():
     (sp,) = [r for r in spans.LOG.snapshot()[mark:]
              if r.name == "tier/transpile"]
     assert sp.fields == {"sources": 1, "traces": 0, "ops_lowered": 0,
-                         "ops_kept": 0, "pooled": 0, "workers": 0}
+                         "ops_kept": 0, "pooled": 0, "workers": 0,
+                         "clock_misfit": 0}
 
 
 # ---------------------------------------------------------------------------
