@@ -20,6 +20,8 @@ feasible nodes score ``max(1, int(raw))`` so they are never refused.
 """
 from __future__ import annotations
 
+import functools
+
 import jax
 import jax.numpy as jnp
 
@@ -54,14 +56,26 @@ NUM_FEATURES = len(FEATURE_NAMES)
 SCORE_SCALE = 10_000.0
 
 
+def _fold_gpus(op, x):
+    """``op`` folded over the GPU planes ``x[:, g]`` of an ``[N, G]`` array.
+
+    G is static and small (8), and the fold is what the reduction IS in
+    integer arithmetic, so the result is ``jnp.sum / max / min(x, axis=1)``
+    bit for bit. Written this way no XLA ``reduce`` runs along the GPU
+    axis, and under a population ``vmap`` the compiler is free to lay
+    ``[lanes, N, G]`` out with the lanes, not the 8 GPUs, on the chip's 128
+    vector lanes (PERF.md section 6, PR 41)."""
+    return functools.reduce(op, [x[:, g] for g in range(x.shape[1])])
+
+
 def features(pod: PodView, nodes: NodeView, dtype=jnp.float32):
     """Feature matrix f[N, F] for one pod against all nodes."""
     d = dtype
     cpu_tot = jnp.maximum(nodes.cpu_milli_total, 1).astype(d)
     mem_tot = jnp.maximum(nodes.memory_mib_total, 1).astype(d)
     ngpus = jnp.maximum(nodes.num_gpus, 1).astype(d)
-    milli_tot = jnp.maximum(
-        jnp.sum(jnp.where(nodes.gpu_mask, nodes.gpu_milli_total, 0), axis=1), 1
+    milli_tot = jnp.maximum(_fold_gpus(
+        jnp.add, jnp.where(nodes.gpu_mask, nodes.gpu_milli_total, 0)), 1
     ).astype(d)
 
     rem_cpu = (nodes.cpu_milli_left - pod.cpu_milli).astype(d) / cpu_tot
@@ -71,21 +85,23 @@ def features(pod: PodView, nodes: NodeView, dtype=jnp.float32):
     mem_util = 1 - nodes.memory_mib_left.astype(d) / mem_tot
     gpu_count_util = 1 - nodes.gpu_left.astype(d) / ngpus
 
-    free_milli = jnp.sum(jnp.where(nodes.gpu_mask, nodes.gpu_milli_left, 0), axis=1)
+    milli_left = jnp.where(nodes.gpu_mask, nodes.gpu_milli_left, 0)
+    free_milli = _fold_gpus(jnp.add, milli_left)
     gpu_milli_util = 1 - free_milli.astype(d) / milli_tot
 
     balance = 1 - jnp.abs(cpu_util - mem_util)
     pod_gpu = pod.num_gpu > 0
     frag_mod = jnp.where(
         pod_gpu, (free_milli % jnp.maximum(pod.gpu_milli, 1)).astype(d) / 1000.0, 0.0)
-    eligible = jnp.sum(
-        (nodes.gpu_mask & (nodes.gpu_milli_left >= pod.gpu_milli)).astype(jnp.int32),
-        axis=1)
+    eligible = _fold_gpus(jnp.add, (
+        nodes.gpu_mask & (nodes.gpu_milli_left >= pod.gpu_milli)
+    ).astype(jnp.int32))
     eligible_frac = eligible.astype(d) / ngpus
     node_has_gpu = (nodes.num_gpus > 0).astype(d)
     best_fit = 1 - (rem_cpu * 0.33 + rem_mem * 0.33 + rem_gpu * 0.34)
-    gmax = jnp.max(jnp.where(nodes.gpu_mask, nodes.gpu_milli_left, 0), axis=1)
-    gmin = jnp.min(jnp.where(nodes.gpu_mask, nodes.gpu_milli_left, 2**30), axis=1)
+    gmax = _fold_gpus(jnp.maximum, milli_left)
+    gmin = _fold_gpus(
+        jnp.minimum, jnp.where(nodes.gpu_mask, nodes.gpu_milli_left, 2**30))
     gpu_imbalance = jnp.where(
         nodes.num_gpus > 0, (gmax - jnp.minimum(gmin, gmax)).astype(d) / 1000.0, 0.0)
     headroom = ((nodes.cpu_milli_left > pod.cpu_milli * 2)

@@ -25,14 +25,20 @@ def best_fit_gpus(milli_left, gpu_mask, gpu_milli_req, num_gpu):
     main.py:164-165). For num_gpu == 0: empty selection, ok=True.
     """
     g = milli_left.shape[0]
-    iota = jnp.arange(g, dtype=jnp.int32)
     eligible = gpu_mask & (milli_left >= gpu_milli_req)
-    # lexicographic (milli_left, index) key; ineligible sorted last
-    key = jnp.where(eligible, milli_left * g + iota, _BIG)
-    order = jnp.argsort(key)
-    rank = jnp.zeros(g, jnp.int32).at[order].set(iota)
-    select = eligible & (rank < num_gpu)
-    ok = jnp.sum(eligible.astype(jnp.int32)) >= num_gpu
+    # lexicographic (milli_left, index) key per GPU slot; ineligible last.
+    # Eligible keys are unique, so a slot's place in the sorted order is
+    # the number of keys below its own: G comparisons over the G static
+    # slots, no sort and no scatter along the 8-wide GPU axis (which would
+    # pin that axis to the chip's 128 lanes under a population vmap;
+    # PERF.md section 6, PR 41). Ineligible slots tie at _BIG and are
+    # masked out of the selection whatever they count.
+    keys = [jnp.where(eligible[i], milli_left[i] * g + i, _BIG)
+            for i in range(g)]
+    rank = [sum((keys[j] < keys[i]).astype(jnp.int32)
+                for j in range(g) if j != i) for i in range(g)]
+    select = eligible & (jnp.stack(rank) < num_gpu)
+    ok = sum(eligible[i].astype(jnp.int32) for i in range(g)) >= num_gpu
     return select, ok
 
 

@@ -78,9 +78,9 @@ from fks_tpu.data.entities import Workload
 from fks_tpu.ops.allocator import best_fit_gpus, first_fit_gpus
 from fks_tpu.ops.heap import KIND_NODE_UP
 from fks_tpu.sim.engine import (
-    SimConfig, _audit, _gather_node_view, _node_view, _prefilter_candidates,
-    _trace_append, _widest_int, finalize_fields, fork_leaves, loop_tables,
-    run_batched_lanes,
+    PREFILTER_MIN_NODES, SimConfig, _audit, _gather_node_view, _node_view,
+    _prefilter_candidates, _trace_append, _widest_int, finalize_fields,
+    fork_leaves, loop_tables, run_batched_lanes,
 )
 from fks_tpu.sim.guards import guard_scores
 from fks_tpu.sim.types import FlatState, PodView, PolicyFn, SimResult, empty_trace
@@ -239,6 +239,22 @@ def _loaded_leaves(workload: Workload, cfg: SimConfig, perm, ev_time,
     for name, leaf in shared.items():
         out[name] = jnp.asarray(leaf, dt.get(name, leaf.dtype))
     return out
+
+
+def _node_row(gpu_milli_left, gpu_mask, w):
+    """Node ``w``'s rows of the two ``[N, G]`` grids. On a node axis short
+    enough to sweep (under ``PREFILTER_MIN_NODES``, a static size) the row
+    is a masked sum over the node planes, not a gather: one dense pass over
+    a grid the step passes over anyway, where a gather pins the grid's
+    layout to G-minor under the population ``vmap``, 8 GPUs on the chip's
+    128 lanes (PERF.md section 6, PR 41). Exact either way."""
+    n = gpu_milli_left.shape[0]
+    if n >= PREFILTER_MIN_NODES:
+        return gpu_milli_left[w], gpu_mask[w]
+    at_w = (jnp.arange(n, dtype=jnp.int32) == w)[:, None]
+    return (jnp.sum(jnp.where(at_w, gpu_milli_left, 0), axis=0,
+                    dtype=gpu_milli_left.dtype),
+            jnp.any(at_w & gpu_mask, axis=0))
 
 
 def lane_active(s: FlatState, max_steps: int):
@@ -402,7 +418,8 @@ def build_step(workload: Workload, policy: PolicyFn, cfg: SimConfig,
         w = cand[wk] if prefilter_k else wk
         placed = create & (scores[wk] > 0)
 
-        sel, ok = alloc(gpu_milli_left[w], c.gpu_mask[w], pmilli, pngpu)
+        sel, ok = alloc(*_node_row(gpu_milli_left, c.gpu_mask, w),
+                        pmilli, pngpu)
         alloc_fail = placed & (pngpu > 0) & ~ok  # reference raises here
         pl = placed & ~alloc_fail
         pli = pl.astype(jnp.int32)
