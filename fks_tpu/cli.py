@@ -151,13 +151,16 @@ def _add_trace_flags(p, snapshot=False):
                    help="node CSV under benchmarks/traces/csv/")
     if snapshot:
         p.add_argument("--snapshot", default="",
-                       help="snapshot CSV (name,node_sn,gpus) under "
-                            "benchmarks/traces/csv/: start from the loaded "
-                            "cluster it pins; needs --engine flat on "
+                       help="snapshot CSV under benchmarks/traces/csv/ "
+                            "(name,node_sn,gpus for a cluster loaded by "
+                            "arrivals alone; with event,rule for a moment "
+                            "of a run with departures and refusals): start "
+                            "from the state it pins; needs --engine flat on "
                             "these commands (fks_tpu.data.snapshot; "
                             "serving forks on the exact engine through "
-                            "the library: VMServeEngine on a workload "
-                            "parsed with snapshot_file=)")
+                            "the library, from arrivals alone: "
+                            "VMServeEngine on a workload parsed with "
+                            "snapshot_file=)")
 
 
 def _result_row(name, res, wall):
@@ -1597,12 +1600,28 @@ def cmd_traces(args):
     return 0
 
 
-def write_snapshot(path=None):
-    """Rewrite the committed snapshot of the loaded cluster: the first
-    5,888 arrivals of the inflated list (70 % of the cluster's GPUs) as
-    the zoo's ``best_fit`` places them on the 1,523 nodes (flat engine,
-    float32, the large-cluster rule). ``path`` defaults to the committed
-    file beside the traces. Returns (path, snapshot)."""
+#: the committed snapshots: file -> (node list, pod list, placing policy
+#: of the zoo, events, node_prefilter_k), each the flat engine's float32
+#: run of the policy for that many events from the empty cluster
+COMMITTED_SNAPSHOTS = {
+    # the loaded cluster: the first 5,888 arrivals of the inflated list
+    # (70 % of the cluster's GPUs) under the large-cluster rule; nobody
+    # has left, nothing was refused
+    "openb_snapshot_inflated080_e5888.csv.gz": (
+        "openb_node_list_all_node.csv", "openb_pod_list_inflated080.csv",
+        "best_fit", 5888, 64),
+    # a real trace mid-run: upstream's 16 nodes after 12,288 events of
+    # cpu250 (5,618 departures, 1,002 refused placements, a pod waiting)
+    "openb_snapshot_cpu250_firstfit_e12288.csv.gz": (
+        "gpu_models_filtered.csv", "openb_pod_list_cpu250.csv",
+        "first_fit", 12288, 0),
+}
+
+
+def write_snapshot(path=None,
+                   name="openb_snapshot_inflated080_e5888.csv.gz"):
+    """Rewrite one of ``COMMITTED_SNAPSHOTS``. ``path`` defaults to the
+    committed file beside the traces. Returns (path, snapshot)."""
     from pathlib import Path
 
     from fks_tpu.data import TraceParser
@@ -1611,26 +1630,28 @@ def write_snapshot(path=None):
     from fks_tpu.sim import flat
     from fks_tpu.sim.engine import SimConfig
 
+    nodes, pods, policy, e0, k = COMMITTED_SNAPSHOTS[name]
     parser = TraceParser()
-    wl = parser.parse_workload(node_file="openb_node_list_all_node.csv",
-                               pod_file="openb_pod_list_inflated080.csv")
-    snap = flat.make_snapshot(wl, zoo.best_fit(), 5888,
-                              SimConfig(node_prefilter_k=64))
+    wl = parser.parse_workload(node_file=nodes, pod_file=pods)
+    snap = flat.make_snapshot(wl, zoo.ZOO[policy](), e0,
+                              SimConfig(node_prefilter_k=k))
     if path is None:
-        path = Path(parser.csv_dir) / \
-            "openb_snapshot_inflated080_e5888.csv.gz"
+        path = Path(parser.csv_dir) / name
     write_snapshot_csv_gz(wl, snap, path)
     return path, snap
 
 
 def cmd_snapshot(args):
-    """Rewrite the committed ``--snapshot`` file byte for byte, as
+    """Rewrite the committed ``--snapshot`` files byte for byte, as
     ``python -m fks_tpu.data.inflate`` rewrites its pod list."""
     import numpy as np
 
-    path, snap = write_snapshot()
-    print(f"{path}: {snap.e0} residents on "
-          f"{len(np.unique(np.asarray(snap.node)))} nodes")
+    for name in COMMITTED_SNAPSHOTS:
+        path, snap = write_snapshot(name=name)
+        node = np.asarray(snap.node)
+        print(f"{path}: {snap.e0} events, {len(node)} CREATE attempts, "
+              f"{int((node < 0).sum())} of them refused, on "
+              f"{len(np.unique(node[node >= 0]))} nodes")
     return 0
 
 
@@ -2202,8 +2223,9 @@ def build_parser() -> argparse.ArgumentParser:
 
     ss = sub.add_parser(
         "snapshot",
-        help="rewrite the committed snapshot of the loaded cluster (the "
-             "--snapshot file of simulate / bench / evolve)")
+        help="rewrite the committed snapshots (the --snapshot files of "
+             "simulate / bench / evolve): the loaded cluster and the "
+             "real trace mid-run")
     ss.set_defaults(fn=cmd_snapshot)
     return ap
 

@@ -145,8 +145,8 @@ class Workload:
     ``faults`` is None for plain workloads (zero pytree leaves — fault-free
     programs compile unchanged) or a ``FaultEvents`` timeline for
     scenario-generated variants. ``snapshot`` is None for a cluster that
-    starts empty or a ``fks_tpu.data.snapshot.Snapshot``: the placements
-    of the first ``E0`` arrivals, from which the flat engine's
+    starts empty or a ``fks_tpu.data.snapshot.Snapshot``: what the first
+    ``E0`` events of a run decided, from which an engine's
     ``initial_state`` forks (no program reads it: it only shapes the
     initial carry).
     """

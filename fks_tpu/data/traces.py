@@ -280,10 +280,10 @@ class TraceParser:
                        pad_pods_to: Optional[int] = None,
                        snapshot_file: Optional[str] = None) -> Workload:
         """Defaults match the reference benchmark workload (parser.py:117-118).
-        ``snapshot_file`` (a ``name,node_sn,gpus`` CSV beside the traces,
-        ``fks_tpu.data.snapshot``) makes it a loaded cluster: the flat
-        engine then starts after the snapshot's arrivals. An invalid
-        snapshot raises ``ValueError`` here."""
+        ``snapshot_file`` (a CSV beside the traces,
+        ``fks_tpu.data.snapshot``) pins a moment of its run: an engine
+        then starts after the snapshot's events. An invalid snapshot
+        raises ``ValueError`` here."""
         cluster = self.parse_cluster(node_file, pad_nodes_to, pad_gpus_to)
         pods = self.parse_pods(pod_file, pad_pods_to)
         wl = Workload(cluster=cluster, pods=pods)

@@ -199,17 +199,22 @@ class CodeEvaluator:
                 "tiers are not wired to the exact engine's fork, which "
                 "serving uses; ROADMAP, Reach); use engine='flat'")
         self.start_event = 0 if snap is None else snap.e0
+        #: failed placements among the snapshot's events: in every
+        #: result's whole-run count, and no work of the policy's
+        self.fork_failed = 0
         if snap is None:
             self.state0 = self._mod.initial_state(workload, cfg)
         else:
             with obs.span("tier/fork_state", start_event=snap.e0,
-                          residents=snap.e0, nodes_loaded=int(len(
-                              np.unique(np.asarray(snap.node))))) as sp:
+                          rule=snap.rule) as sp:
                 self.state0 = jax.block_until_ready(
                     self._mod.initial_state(workload, cfg))
+                counts = self._mod.fork_counts(workload, self.state0)
                 sp.set(bytes=int(sum(
                     x.nbytes
-                    for x in jax.tree_util.tree_leaves(self.state0))))
+                    for x in jax.tree_util.tree_leaves(self.state0))),
+                    **counts)
+            self.fork_failed = counts["prefix_failed"]
         self._cache: Dict[str, object] = {}
         self._lock = threading.Lock()
         self.compile_count = 0  # observability: unique programs built
@@ -853,11 +858,11 @@ class CodeEvaluator:
             "fallback_lanes": len(jit_only) + len(general),
             # where the policy took over (0: from the empty cluster) and
             # the placements that failed after it, over the generation's
-            # lanes: a snapshot holds no failed placement, so a result's
-            # whole-run count is the count after the fork
+            # lanes: a result's whole-run count less the snapshot's own
             "start_event": self.start_event,
             "frag_events": sum(
-                int(np.sum(r.result.num_fragmentation_events))
+                int(np.sum(r.result.num_fragmentation_events
+                           - self.fork_failed))
                 for r in memo.values() if r.result is not None),
             "segments": self.segments_dispatched - seg0,
             # the large-cluster rule in effect (0 = every node is scored)

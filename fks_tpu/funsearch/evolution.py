@@ -274,7 +274,9 @@ class FunSearch:
         self.rng = random.Random(config.seed)
         self.log = log
         if evaluator.engine != "exact" and self._search_fitness_is_final:
-            log(f"snapshot: the exact engine cannot fork yet, so elites "
+            log(f"snapshot: no exact rescore from a fork (the exact "
+                f"engine forks for serving, from arrivals alone, and the "
+                f"tiers are not wired to it), so elites "
                 f"are ranked and champions saved by their "
                 f"[{evaluator.engine}] fitness from event "
                 f"{evaluator.start_event}, with no exact re-rank; saved "
@@ -402,8 +404,9 @@ class FunSearch:
     @property
     def _search_fitness_is_final(self) -> bool:
         """No exact rescore: the search engine IS exact, or the workload
-        forks from a snapshot, which the exact engine refuses by name
-        (its heap at the fork is not rebuilt yet, ROADMAP)."""
+        forks from a snapshot, from which candidates are evaluated on the
+        flat engine only (``CodeEvaluator`` refuses another by name; the
+        exact engine's fork serves queries, from arrivals alone)."""
         return (self.evaluator.engine == "exact"
                 or self.evaluator.workload.snapshot is not None)
 

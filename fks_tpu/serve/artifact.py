@@ -1000,15 +1000,13 @@ class ServeEngine:
         wl = Workload(cluster=cluster,
                       pods=_pods_from_dicts(doc.get("base_pods", [])))
         if doc.get("snapshot"):
-            from fks_tpu.data.snapshot import Snapshot
+            from fks_tpu.data.snapshot import placed_creates
             rows = doc["snapshot"]
             wl = dataclasses.replace(
                 wl, pods=dataclasses.replace(wl.pods, tie_rank=np.asarray(
                     rows["tie_rank"], np.int32)),
-                snapshot=Snapshot(
-                    pod=np.asarray(rows["pod"], np.int32),
-                    node=np.asarray(rows["node"], np.int32),
-                    gpus=np.asarray(rows["gpus"], np.uint32)))
+                snapshot=placed_creates(rows["pod"], rows["node"],
+                                        rows["gpus"]))
         extra = {}
         portfolio = doc.get("portfolio")
         if doc.get("engine_kind", "aot") == "vm" and cls.engine_kind != "vm":

@@ -138,7 +138,7 @@ class QueryFork:
     next events of the cluster's life look like with the queue in it.
 
     Held here, all NumPy: the residents' pod columns in event order, the
-    snapshot re-indexed to that order, ``sim.engine.ForkPrefix`` (the
+    snapshot re-indexed to that order, ``data.snapshot.Prefix`` (the
     cluster after the residents, their running sums for the evaluator).
     Per query, ``stack`` adds what does depend on the query: the trigger
     table, which is sized from the WHOLE run's pod count, with the
@@ -150,11 +150,12 @@ class QueryFork:
     residents)."""
 
     def __init__(self, workload: Workload):
-        from fks_tpu.data.snapshot import Snapshot
-        from fks_tpu.sim.engine import fork_prefix
+        from fks_tpu.data.snapshot import placed_creates
+        from fks_tpu.sim.engine import fork_prefix, require_placed_creates
 
         snap, p = workload.snapshot, workload.pods
         self.prefix = fork_prefix(workload)          # validates
+        require_placed_creates(self.prefix, "serving")
         order = np.asarray(snap.pod, np.int64)
         self.e0 = snap.e0
         fields = {"cpu_milli": p.cpu, "memory_mib": p.mem,
@@ -168,10 +169,8 @@ class QueryFork:
             np.arange(self.e0, dtype=np.int32)
         self.rank = rank        # the residents' pod-id order, made dense
         self.pod_ids = tuple(p.pod_ids[int(i)] for i in order)
-        self.snapshot = Snapshot(
-            pod=np.arange(self.e0, dtype=np.int32),
-            node=np.asarray(snap.node, np.int32),
-            gpus=np.asarray(snap.gpus, np.uint32))
+        self.snapshot = placed_creates(np.arange(self.e0), snap.node,
+                                       snap.gpus)
         #: no query pod may be created before this (the 4xx of
         #: ``validate_query_pods``): the last resident's arrival
         self.last_arrival = int(self.cols["creation_time"][-1]) \

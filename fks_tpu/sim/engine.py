@@ -32,13 +32,16 @@ carry those events leave (the heap slot for slot CPython's own after the
 prefix, because the retry rule above reads it in array order; the
 residents on the pod axis). ``fork_prefix`` / ``fork_leaves`` hold the
 arithmetic that this engine and the flat one (``sim.flat._loaded_leaves``)
-share; the fused engine refuses a snapshot by name.
+share: the host replay of the prefix, whatever its events. The flat engine
+forks from all of it; this one from a prefix of placed CREATEs, and
+refuses a departure or a refusal among the events by name
+(``require_placed_creates``); the fused engine refuses every snapshot.
 """
 from __future__ import annotations
 
 import dataclasses
 import functools
-from typing import Any, Callable, NamedTuple, Optional
+from typing import Any, Callable, Optional
 
 import jax
 import jax.numpy as jnp
@@ -244,64 +247,35 @@ def initial_state(workload: Workload, cfg: SimConfig) -> SimState:
     )
 
 
-class ForkPrefix(NamedTuple):
-    """What a snapshot's ``E0`` events leave of the cluster and of the
-    evaluator, whatever pods come after them: the part of a forked carry
-    that is computed once (serving builds it once per engine and forks
-    every query from it)."""
-
-    e0: int
-    left: Any           # data.snapshot.Loaded: the four ``*_left`` arrays
-    used: np.ndarray    # i64[E0, 4] cpu, mem, GPU count, GPU milli in use
-    totals: np.ndarray  # i64[4] the cluster's capacity of each
-    max_nodes: int      # active nodes after the last of the events
-
-
-def fork_prefix(workload: Workload) -> ForkPrefix:
-    """Validate the workload's snapshot (``place_residents`` raises
-    ``ValueError``) and sum its residents' requests in event order. The
-    ``E0`` events are placed CREATEs, so the cluster only fills: what is
-    in use after event ``i`` is a running sum, and ``max_nodes`` is the
-    count at the end."""
-    from fks_tpu.data.snapshot import gpu_slots, place_residents
+def fork_prefix(workload: Workload):
+    """Replay the workload's snapshot on the host
+    (``fks_tpu.data.snapshot.replay``: the whole of its validation, a
+    ``ValueError`` before any device program) and return what its ``E0``
+    events leave, a ``data.snapshot.Prefix``: the part of a forked carry
+    that is computed once, whatever pods come after the events (serving
+    builds it once per engine and forks every query from it)."""
+    from fks_tpu.data.snapshot import replay
 
     if workload.faults is not None:
         raise ValueError(
             "snapshot: a workload with fault events or a decision trace "
             "cannot start from a snapshot (the prefix holds neither)")
-    c, p, snap = workload.cluster, workload.pods, workload.snapshot
-    left = place_residents(workload, snap)
-    pod = np.asarray(snap.pod, np.int64)
-    totals = np.asarray([np.asarray(x, np.int64).sum() for x in (
-        c.cpu_total, c.mem_total, c.num_gpus, c.gpu_milli_total)])
-    ngpu = np.asarray(p.num_gpu, np.int64)[pod]
-    held = gpu_slots(snap, c.g_padded).sum(axis=1)
-    used = np.stack([
-        np.cumsum(np.asarray(p.cpu, np.int64)[pod]),
-        np.cumsum(np.asarray(p.mem, np.int64)[pod]),
-        np.cumsum(ngpu) + int((np.asarray(c.num_gpus, np.int64)
-                               - np.asarray(c.gpu_declared, np.int64)).sum()),
-        np.cumsum(np.asarray(p.gpu_milli, np.int64)[pod] * held),
-    ], axis=1)                            # [E0, 4] after each event
-    active = np.asarray(c.node_mask) & (
-        (left.cpu_left < np.asarray(c.cpu_total))
-        | (left.mem_left < np.asarray(c.mem_total))
-        | (left.gpu_left < np.asarray(c.num_gpus)))
-    return ForkPrefix(e0=snap.e0, left=left, used=used, totals=totals,
-                      max_nodes=int(active.sum()) if snap.e0 else 0)
+    return replay(workload, workload.snapshot)
 
 
-def fork_leaves(workload: Workload, cfg: SimConfig,
-                prefix: Optional[ForkPrefix] = None, ktable=None) -> dict:
+def fork_leaves(workload: Workload, cfg: SimConfig, prefix=None,
+                ktable=None) -> dict:
     """The leaves of a carry that a snapshot's ``E0`` events change and
-    that both engines hold alike, in NumPy: the cluster after the
-    residents, the counters and the evaluator's sums. Every one of the
-    events counts as an event and a step, nothing waits or fragments; the
-    utilization snapshots among them are the residents' running sums at
-    the trigger points of ``ktable`` (the workload's own, from
-    ``loop_tables``, unless given: it is sized from the WHOLE run's pod
-    count, so it differs by what follows the residents while ``prefix``
-    does not)."""
+    that both engines hold alike, in NumPy: the cluster after them, the
+    counters, the waiting histogram and the evaluator's sums, each float
+    sum in ``cfg.score_dtype`` and in the step's own order. Every one of
+    the events counts as an event and a step; each refused attempt adds
+    its fragmentation score; the utilization snapshots among the events
+    are the sums in use at the trigger points of ``ktable`` (the
+    workload's own, from ``loop_tables``, unless given: it is sized from
+    the WHOLE run's pod count, so it differs by what follows the prefix
+    while ``prefix`` does not). A prefix of placed CREATEs, a snapshot of
+    the loaded cluster, is the case in which nothing waits or fragments."""
     if cfg.decision_trace:
         raise ValueError(
             "snapshot: a workload with fault events or a decision trace "
@@ -329,28 +303,54 @@ def fork_leaves(workload: Workload, cfg: SimConfig,
                          prefix.used[events - 1].astype(f) * inv)
         snap_sums = (snap_sums + utils).astype(f)
         snap_idx += 1
-    left = prefix.left
+    # one fragmentation score per refused attempt, added in event order
+    # (cumsum adds left to right); 0 on a cluster without GPU milli
+    scores = prefix.frag_free.astype(f) * (inv[3] if totals[3] > 0
+                                           else f.type(0))
+    frag_sum = np.cumsum(scores, dtype=f)[-1] if len(scores) else f.type(0)
+    size = _hist_size(workload.pods, cfg)
     return dict(
-        cpu_left=left.cpu_left.astype(np.int32),
-        mem_left=left.mem_left.astype(np.int32),
-        gpu_left=left.gpu_left.astype(np.int32),
-        gpu_milli_left=left.gpu_milli_left.astype(np.int32),
+        cpu_left=prefix.cpu_left.astype(np.int32),
+        mem_left=prefix.mem_left.astype(np.int32),
+        gpu_left=prefix.gpu_left.astype(np.int32),
+        gpu_milli_left=prefix.gpu_milli_left.astype(np.int32),
+        wait_hist=np.bincount(np.clip(prefix.wait_milli, 0, size - 1),
+                              minlength=size).astype(np.int32),
         events_processed=np.int32(e0), steps=np.int32(e0),
         snap_idx=np.int32(snap_idx), snap_sums=snap_sums,
+        frag_sum=np.asarray(frag_sum, f),
+        frag_count=np.int32(prefix.refused),
         max_nodes=np.int32(prefix.max_nodes))
 
 
-def forked_state(workload: Workload, cfg: SimConfig,
-                 prefix: Optional[ForkPrefix] = None,
+def require_placed_creates(prefix, who: str) -> None:
+    """The refusal of everything that forks on the exact engine: its heap
+    after a prefix is CPython's own only where every event was a placed
+    CREATE (``ops.heap.heap_rows_after_prefix``), and its retry rule is
+    not the one a refusal in the prefix was re-queued under."""
+    if prefix.departed or prefix.refused:
+        raise ValueError(
+            f"snapshot: {who} forks from a prefix of placed CREATEs only; "
+            f"this one holds {prefix.departed} departures and "
+            f"{prefix.refused} refused placements. Candidate evaluation "
+            "on engine='flat' forks from it (ROADMAP R5)")
+
+
+def forked_state(workload: Workload, cfg: SimConfig, prefix=None,
                  ktable=None) -> SimState:
     """The exact engine's carry after the workload's snapshot, leaf for
     leaf what ``build_step`` reaches when a policy makes those ``E0``
     placements, as NumPy (``initial_state`` uploads it; serving stacks a
     batch of them first). The heap is CPython's own after the prefix
     (``ops.heap.heap_rows_after_prefix``: the retry rule reads it in array
-    order); ``pod_state`` holds the residents' node and GPU mask. ``prefix``
-    and ``ktable`` as in ``fork_leaves``."""
+    order), which is why a prefix with a departure or a refusal is
+    refused by name (``require_placed_creates``); ``pod_state`` holds the
+    residents' node and GPU mask. ``prefix`` and ``ktable`` as in
+    ``fork_leaves``."""
     c, p, snap = workload.cluster, workload.pods, workload.snapshot
+    if prefix is None:
+        prefix = fork_prefix(workload)
+    require_placed_creates(prefix, "the exact engine")
     shared = fork_leaves(workload, cfg, prefix, ktable)
     real = np.flatnonzero(np.asarray(p.pod_mask))
     rows, size = heap_rows_after_prefix(
@@ -364,14 +364,11 @@ def forked_state(workload: Workload, cfg: SimConfig,
     pod_state[res, SimState.COL_NODE] = np.asarray(snap.node)
     pod_state[res, SimState.COL_BITS] = np.asarray(
         snap.gpus, np.uint32).view(np.int32)
-    f = np.dtype(cfg.score_dtype)
     return SimState(
         heap=EventHeap(data=rows, size=np.int32(size)),
-        pod_state=pod_state,
-        wait_hist=np.zeros(_hist_size(p, cfg), np.int32),
-        frag_sum=np.zeros((), f), frag_count=np.int32(0),
-        failed=np.bool_(False), violations=np.int32(0),
-        numeric_flags=np.int32(0), trace=None, node_avail=None, **shared)
+        pod_state=pod_state, failed=np.bool_(False),
+        violations=np.int32(0), numeric_flags=np.int32(0), trace=None,
+        node_avail=None, **shared)
 
 
 def _widest_int():

@@ -80,7 +80,7 @@ from fks_tpu.ops.heap import KIND_NODE_UP
 from fks_tpu.sim.engine import (
     PREFILTER_MIN_NODES, SimConfig, _audit, _gather_node_view, _node_view,
     _prefilter_candidates, _trace_append, _widest_int, finalize_fields,
-    fork_leaves, loop_tables, run_batched_lanes,
+    fork_leaves, fork_prefix, loop_tables, run_batched_lanes,
 )
 from fks_tpu.sim.guards import guard_scores
 from fks_tpu.sim.types import FlatState, PodView, PolicyFn, SimResult, empty_trace
@@ -153,9 +153,12 @@ def initial_state(workload: Workload, cfg: SimConfig) -> FlatState:
     """t=0 carry: every real pod's slot (in tie-rank order) holds its
     CREATE time; ``aux`` starts at AUX_FRESH. A workload with a
     ``snapshot`` (``fks_tpu.data.snapshot``) gives the carry AFTER the
-    snapshot's ``E0`` events instead, leaf for leaf what ``build_step``
-    reaches when a policy makes those placements: every runner that
-    starts from here forks from the loaded cluster."""
+    snapshot's ``E0`` events instead, whatever they are (CREATEs placed
+    or refused, retries, DELETEs), leaf for leaf and bit for bit what
+    ``build_step`` reaches after ``E0`` steps under a policy that makes
+    the logged decisions: every runner that starts from here forks from
+    that moment of the run. The events are replayed on the host, so an
+    invalid snapshot is a ``ValueError`` before any device program."""
     c, p = workload.cluster, workload.pods
     pp = p.p_padded
     pm = np.asarray(p.pod_mask)
@@ -204,41 +207,54 @@ def initial_state(workload: Workload, cfg: SimConfig) -> FlatState:
     )
     if workload.snapshot is None:
         return state
-    return state._replace(**_loaded_leaves(workload, cfg, perm, ev_time, dt))
+    return state._replace(**_loaded_leaves(workload, cfg, perm, dt))
 
 
-def _loaded_leaves(workload: Workload, cfg: SimConfig, perm, ev_time,
-                   dt: dict) -> dict:
-    """The leaves of the carry that the snapshot's ``E0`` events change.
-    The cluster, the counters and the evaluator's sums are
-    ``sim.engine.fork_leaves``' (one arithmetic for both engines); this
-    engine's own are the residents' slots: placed pods with their DELETE
-    pending."""
-    c, p, snap = workload.cluster, workload.pods, workload.snapshot
-    shared = fork_leaves(workload, cfg)
+def _loaded_leaves(workload: Workload, cfg: SimConfig, perm, dt: dict) -> dict:
+    """The leaves of the carry that the snapshot's ``E0`` events change,
+    from ONE host replay of them (``sim.engine.fork_prefix``). The
+    cluster, the counters, the waiting histogram and the evaluator's sums
+    are ``sim.engine.fork_leaves``' (one arithmetic for both engines);
+    this engine's own are the pods' slots: a pod that has not arrived
+    holds its CREATE, a resident its DELETE, a waiting pod its retry
+    (``AUX_WAITING``, its ``pod_ctime`` moved), a departed or dropped one
+    nothing, and a placed pod keeps its node and GPUs after it has gone."""
+    c, p = workload.cluster, workload.pods
+    prefix = fork_prefix(workload)
     g = c.g_padded
-    pod = np.asarray(snap.pod, np.int64)
-    node = np.asarray(snap.node, np.int64)
-    bits = np.asarray(snap.gpus, np.int64)
-    slot = np.empty(p.p_padded, np.int64)
-    slot[perm] = np.arange(p.p_padded)    # slot index of each pod
-    slot = slot[pod]
-
-    ev_time = ev_time.copy()
-    ev_time[slot] = (np.asarray(p.creation_time, np.int64)
-                     + np.asarray(p.duration, np.int64))[pod]
     packed = _packable(c.n_padded, g)
-    aux = np.full(p.p_padded, AUX_FRESH, np.int64)
-    aux[slot] = (node << g) | bits if packed else node
-    out = dict(ev_time=jnp.asarray(ev_time, jnp.int32),
-               aux=jnp.asarray(aux, dt["aux"]))
+    aux = np.where(prefix.node >= 0,
+                   (prefix.node << g) | prefix.gpus if packed else prefix.node,
+                   np.where(prefix.waiting, AUX_WAITING, AUX_FRESH))
+    out = dict(ev_time=jnp.asarray(prefix.next_event[perm], jnp.int32),
+               aux=jnp.asarray(aux[perm], dt["aux"]),
+               pending=jnp.int32(prefix.pending))
     if not packed:
-        aux_gpus = np.zeros(p.p_padded, np.int64)
-        aux_gpus[slot] = bits
-        out["aux_gpus"] = jnp.asarray(aux_gpus, dt["aux_gpus"])
-    for name, leaf in shared.items():
+        out["aux_gpus"] = jnp.asarray(prefix.gpus[perm], dt["aux_gpus"])
+    if cfg.track_ctime:
+        out["pod_ctime"] = jnp.asarray(prefix.ctime[perm], jnp.int32)
+    for name, leaf in fork_leaves(workload, cfg, prefix).items():
         out[name] = jnp.asarray(leaf, dt.get(name, leaf.dtype))
     return out
+
+
+def fork_counts(workload: Workload, state: FlatState) -> dict:
+    """What a forked carry holds, read off its slots (the fields of the
+    ``tier/fork_state`` span): pods placed and not gone, the nodes they
+    sit on, pods gone, waiting pods whose retry is queued, and the failed
+    placements among the events."""
+    c = workload.cluster
+    aux = np.asarray(state.aux, np.int64)
+    queued = np.asarray(state.ev_time) < INF
+    placed = aux >= 0
+    on = aux[placed & queued]
+    if _packable(c.n_padded, c.g_padded):
+        on = on >> c.g_padded
+    return dict(residents=int((placed & queued).sum()),
+                nodes_loaded=int(len(np.unique(on))),
+                departed=int((placed & ~queued).sum()),
+                waiting=int(((aux == AUX_WAITING) & queued).sum()),
+                prefix_failed=int(state.frag_count))
 
 
 def _node_row(gpu_milli_left, gpu_mask, w):
@@ -669,27 +685,37 @@ def simulate(workload: Workload, policy: PolicyFn,
 
 def make_snapshot(workload: Workload, policy: PolicyFn, e0: int,
                   cfg: SimConfig = SimConfig()):
-    """The ``fks_tpu.data.snapshot.Snapshot`` of ``workload``'s first
-    ``e0`` arrivals as ``policy`` places them: this engine run for ``e0``
-    steps from the empty cluster, its placements read back. Raises
-    ``ValueError`` when those steps are not ``e0`` placed CREATEs (a
-    placement failed, or a pod left before arrival ``e0 - 1``)."""
-    from fks_tpu.data.snapshot import from_placements
+    """The ``fks_tpu.data.snapshot.Snapshot`` of the first ``e0`` events
+    of ``policy``'s run of ``workload``, whatever they are: this engine
+    run for ``e0`` steps from the empty cluster with its decision trace
+    on, every CREATE attempt read back with its node (or none) and GPUs.
+    Raises ``ValueError`` only for what cannot be forked: a run that ended
+    or aborted before event ``e0``."""
+    from fks_tpu.data.snapshot import RETRY_RULE, Snapshot, replay
+    from fks_tpu.sim.types import TRACE_DELETE, TraceBuffer
 
-    if workload.snapshot is not None:
-        workload = dataclasses.replace(workload, snapshot=None)
-    res = simulate(workload, policy,
-                   dataclasses.replace(cfg, max_steps=int(e0)))
-    placed, events, frag = (int(res.scheduled_pods),
-                            int(res.events_processed),
-                            int(res.num_fragmentation_events))
-    if (placed, events, frag) != (int(e0), int(e0), 0):
+    e0 = int(e0)
+    workload = dataclasses.replace(workload, snapshot=None)
+    res = simulate(workload, policy, dataclasses.replace(
+        cfg, max_steps=e0, decision_trace=True, trace_len=max(e0, 1)))
+    events = int(res.events_processed)
+    if events != e0 or bool(res.failed):
         raise ValueError(
-            f"snapshot: the first {e0} events under this policy are not "
-            f"{e0} placed CREATEs ({placed} pods placed, {events} events, "
-            f"{frag} failed placements)")
-    return from_placements(workload, e0, res.assigned_node,
-                           res.assigned_gpus)
+            f"snapshot: the run cannot be forked at event {e0}: it "
+            + ("aborted on a GPU shortfall" if bool(res.failed)
+               else "ended") + f" after {events} events")
+    rows = np.asarray(res.trace.data)[:e0]
+    event = np.flatnonzero(rows[:, TraceBuffer.COL_KIND] != TRACE_DELETE)
+    pod = rows[event, TraceBuffer.COL_POD]
+    node = rows[event, TraceBuffer.COL_NODE]
+    snap = Snapshot(
+        pod=pod.astype(np.int32), node=node.astype(np.int32),
+        gpus=np.where(node >= 0, np.asarray(res.assigned_gpus)[pod],
+                      0).astype(np.uint32),
+        event=event.astype(np.int32), e0=e0,
+        rule=RETRY_RULE if (node < 0).any() else "")
+    replay(workload, snap)
+    return snap
 
 
 def broadcast_state(state0: FlatState, lanes: int) -> FlatState:

@@ -306,8 +306,9 @@ def test_every_span_metric_is_declared_with_its_files():
     bench = json.load(open(os.path.join(cells.ROOT, "BENCHMARK.json")))
     declared = {m["name"]: m for m in bench["per_layer"]}
     # + vm.scatter_write_share (PR 35), serve.fork_state_ms and
-    # serve.retry_share (PR 37), tier.pooled_source_share (PR 39)
-    assert len(SPAN_METRICS) == 27
+    # serve.retry_share (PR 37), tier.pooled_source_share (PR 39),
+    # sim.fork_replay_us_per_event and sim.fork_waiting_pods (PR 42)
+    assert len(SPAN_METRICS) == 29
     for name in SPAN_METRICS:
         assert name in declared, name
         meta = json.load(open(os.path.join(cells.HERE, "metrics",

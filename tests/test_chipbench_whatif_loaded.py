@@ -65,13 +65,14 @@ def test_the_cell_is_declared_with_its_files():
 def test_benchmark_json_only_gained_entries():
     with open(os.path.join(cells.ROOT, "BENCHMARK.json")) as f:
         bench = json.load(f)
-    assert bench["configs"][-1]["name"] == "openb1523-loaded-snapshot"
-    assert bench["configs"][-1]["reduced"] == ["max_steps_factor"]
-    assert bench["workloads"][-1] == {
+    # in the place PR 37 gave them (PR 42 appended after them)
+    assert bench["configs"][4]["name"] == "openb1523-loaded-snapshot"
+    assert bench["configs"][4]["reduced"] == ["max_steps_factor"]
+    assert bench["workloads"][6] == {
         "name": CELL, "config": "openb1523-loaded-snapshot",
         "traffic": "whatif8-loaded", "chips": 1,
-        "why": bench["workloads"][-1]["why"]}
-    assert len(bench["workloads"][-1]["why"]) <= 200
+        "why": bench["workloads"][6]["why"]}
+    assert len(bench["workloads"][6]["why"]) <= 200
     names = [m["name"] for m in bench["per_layer"]]
     at = names.index(NEW[0])
     assert names[at:at + 2] == list(NEW)
@@ -81,7 +82,9 @@ def test_benchmark_json_only_gained_entries():
         "tier.pooled_source_share", "tier.lower_ms_per_source",
         "tier.pack_ms_per_call", "tier.pool_overhead_ms_per_call",
         "tier.gc_ms_per_call", "serve.gc_ms_per_call",
-        "tier.slow_call_share", "serve.slow_call_share"]
+        "tier.slow_call_share", "serve.slow_call_share",
+        # PR 42's two of the mid-run fork
+        "sim.fork_replay_us_per_event", "sim.fork_waiting_pods"]
     new = bench["per_layer"][at:at + 2]
     for m in new:
         assert m["workloads"] == [CELL] and m["layer"] == "serving serve/"
@@ -91,9 +94,10 @@ def test_benchmark_json_only_gained_entries():
             continue
         lists = m.get("workloads", [])
         assert (CELL in lists) == (SIBLING in lists), m["name"]
-        if CELL in lists:
-            assert lists[-1] == CELL
-    assert len(bench["workloads"]) == 7
+        if CELL in lists:   # last of the cells there were at PR 37
+            assert [w for w in lists
+                    if w != "openb16-cpu250-midrun.codegen8"][-1] == CELL
+    assert len(bench["workloads"]) == 8
     assert sum(w["chips"] == 4 for w in bench["workloads"]) == 1
 
 

@@ -110,10 +110,14 @@ def _tiny(durations=(50, 50, 50, 50)):
     return make_workload(nodes, pods)
 
 
-def _snap(pod, node, gpus):
+def _snap(pod, node, gpus, event=None, e0=None, rule=""):
+    """A log of attempts; by default one placed CREATE an event."""
+    if event is None:
+        return snap_mod.placed_creates(pod, node, gpus)
     return Snapshot(pod=np.asarray(pod, np.int32),
                     node=np.asarray(node, np.int32),
-                    gpus=np.asarray(gpus, np.uint32))
+                    gpus=np.asarray(gpus, np.uint32),
+                    event=np.asarray(event, np.int32), e0=e0, rule=rule)
 
 
 def test_a_valid_tiny_snapshot_loads():
@@ -125,19 +129,46 @@ def test_a_valid_tiny_snapshot_loads():
     assert np.asarray(s.gpu_milli_left[0]).tolist() == [400, 400]
 
 
-NOT_FIRST = "not the workload's first"
+WRONG_POD = "the log is not this workload's run"
+#: pod 0 leaves at t=1, before pod 1 arrives: events C0, D0, C1, C2
+LEAVES = (1, 50, 50, 50)
+RETRY = snap_mod.RETRY_RULE
 
 
 @pytest.mark.parametrize("why,snap,durations", [
-    (NOT_FIRST, ([0, 2], [0, 0], [1, 2]), None),          # skips an arrival
-    (NOT_FIRST, ([0, 0], [0, 1], [1, 1]), None),          # a pod twice
+    (WRONG_POD, ([0, 2], [0, 0], [1, 2]), None),          # skips an arrival
+    (WRONG_POD, ([0, 0], [0, 1], [1, 1]), None),          # a pod twice
     ("not a pod of the workload", ([0, 7], [0, 0], [1, 2]), None),
     ("a node the cluster does not have", ([0, 1], [0, 5], [1, 1]), None),
     ("holds a GPU that node", ([0, 1], [0, 0], [1, 4]), None),
     ("asks for 1 GPUs and holds 2", ([0, 1], [0, 0], [1, 3]), None),
     ("over-committed", ([0, 1], [0, 0], [1, 1]), None),
-    ("the prefix is not 3 CREATEs", ([0, 1, 2], [0, 1, 2], [1, 1, 1]),
-     (1, 50, 50, 50)),
+    # what was "the prefix is not 3 CREATEs": the third of the first
+    # three events is pod 0's DELETE, so the log is an attempt too long
+    ("attempt 1 is logged at event 1, which is pod p0's DELETE",
+     ([0, 1, 2], [0, 1, 2], [1, 1, 1]), LEAVES),
+    # ... and an attempt too short when it ends after the DELETE
+    ("event 2 of the run is a CREATE attempt of pod p1, and the log has "
+     "no attempt left", ([0], [0], [1], [0], 3), LEAVES),
+    ("its next attempt at event 3",
+     ([0, 2], [0, 1], [1, 1], [0, 3], 4), LEAVES),
+    # the same prefix, logged as it is: valid events, wrong pod there
+    (WRONG_POD, ([0, 2], [0, 1], [1, 1], [0, 2], 3), LEAVES),
+    # a refused pod that holds GPUs, events out of order or past the end
+    ("is refused at attempt 1 and holds GPUs",
+     ([0, 1], [0, -1], [1, 1], [0, 1], 2),
+     None),
+    ("not at rising events below 2", ([0, 1], [0, 0], [1, 2], [1, 0], 2),
+     None),
+    ("not at rising events below 2", ([0, 1], [0, 0], [1, 2], [0, 2], 2),
+     None),
+    # the run is over before the log is: 4 pods placed and gone by 8
+    ("the run ends after 8 events, before event 9",
+     ([0, 1, 2, 3], [0, 0, 1, 1], [1, 2, 1, 2], [0, 2, 4, 6], 9),
+     (1, 1, 1, 1)),
+    # made under a rule the flat engine does not re-queue by
+    ("made under the retry rule 'heap_array'",
+     ([0, 1], [0, -1], [1, 0], [0, 1], 2, "heap_array"), None),
 ])
 def test_an_invalid_snapshot_raises_on_the_host(why, snap, durations,
                                                 monkeypatch):
@@ -154,7 +185,7 @@ def test_an_invalid_snapshot_raises_on_the_host(why, snap, durations,
     ("p1,nowhere,0", "unknown node 'nowhere'"),
     ("p1,n0,9", "GPU slot 9"),
     ("p1,n0,0", "over-committed"),
-    ("p3,n0,1", "not the workload's first"),
+    ("p3,n0,1", "the log is not this workload's run"),
 ])
 def test_an_invalid_snapshot_file_raises_when_parsed(tmp_path, row, match):
     wl = _tiny()
@@ -180,11 +211,24 @@ def test_row_order_of_the_file_is_free(tmp_path):
         == "name,node_sn,gpus\np0,n0,0\np1,n2,1\n"
 
 
-def test_a_placement_that_fails_makes_no_snapshot():
+def test_a_placement_that_fails_is_in_the_snapshot():
+    """What ``make_snapshot`` refused before PR 42: a prefix with failed
+    placements is a snapshot like any other (two refusals, nobody to wait
+    for, so both pods are dropped); only a run that is over before the
+    fork cannot be forked."""
     wl = _tiny()
     refuse = lambda pod, nodes: jnp.zeros_like(nodes.cpu_milli_left)  # noqa: E731
-    with pytest.raises(ValueError, match="not 2 placed CREATEs"):
-        flat.make_snapshot(wl, refuse, 2)
+    snap = flat.make_snapshot(wl, refuse, 2)
+    assert (snap.e0, snap.rule) == (2, RETRY)
+    assert np.asarray(snap.pod).tolist() == [0, 1]
+    assert np.asarray(snap.node).tolist() == [-1, -1]
+    s = flat.initial_state(dataclasses.replace(wl, snapshot=snap),
+                           SimConfig())
+    assert (int(s.steps), int(s.frag_count), int(s.pending)) == (2, 2, 2)
+    assert np.asarray(s.aux).tolist()[:2] == [flat.AUX_WAITING] * 2
+    with pytest.raises(ValueError, match="cannot be forked at event 9: it "
+                                         "ended after 4 events"):
+        flat.make_snapshot(wl, refuse, 9)
 
 
 def test_engines_and_runners_that_cannot_fork_refuse_by_name():

@@ -52,6 +52,19 @@ def cluster_cut():
     return common.parse_workload({"pod_limit": 300}, files)
 
 
+@pytest.fixture(scope="module")
+def midrun(tmp_path_factory):
+    """The mid-run cell's tiny deployment (``chipbench/selftest/
+    midrun.py``): six nodes under the first 500 arrivals of cpu250, whose
+    pods leave while others arrive."""
+    from chipbench.selftest import midrun as tiny
+    from fks_tpu.data import TraceParser
+
+    d = str(tmp_path_factory.mktemp("midrun"))
+    tiny.tiny_deployment(d)
+    return TraceParser(d).parse_workload("nodes.csv", "pods.csv")
+
+
 def _workload(name, pressure, cluster_cut):
     return cluster_cut if name == "openb1523" else pressure[name]
 
@@ -70,7 +83,57 @@ def test_loaded_carry_is_the_engines_own_leaf_by_leaf(pressure, cluster_cut,
             _leaf_by_leaf(wl, name, e0, policy, state_pack)
 
 
-def _leaf_by_leaf(wl, name, e0, policy, state_pack):
+#: what the first ``e0`` events of first_fit's run of the tiny mid-run
+#: deployment hold: (departures, refused placements, waiting pods with a
+#: retry queued, whether event ``e0 - 1`` is a DELETE)
+MIDRUN_PREFIXES = {
+    16: (0, 0, 0, False),        # arrivals alone: the loaded-cluster case
+    128: (44, 0, 0, True),       # departures only, the last event one
+    238: (103, 1, 1, False),     # ends on the first refusal, just queued
+    301: (131, 7, 1, True),      # ends on a DELETE, a pod waiting
+    320: (139, 9, 1, False),     # the selftest's own fork
+}
+
+
+@pytest.mark.parametrize("e0", list(MIDRUN_PREFIXES))
+def test_midrun_carry_is_the_engines_own_leaf_by_leaf(midrun, e0):
+    """The same, for prefixes of any events: departures, refusals, a pod
+    waiting with its retry queued, a prefix that ends on a DELETE. The
+    float32 sums (26 such snapshots and 1,002 fragmentation scores at the
+    cell's own size) are the step's own bit for bit."""
+    snap = flat.make_snapshot(midrun, POLICIES["first_fit"], e0)
+    forked = dataclasses.replace(midrun, snapshot=snap)
+    at = flat.fork_counts(forked, flat.initial_state(forked, SimConfig()))
+    last_is_delete = e0 - 1 not in np.asarray(snap.event).tolist()
+    assert (at["departed"], at["prefix_failed"], at["waiting"],
+            last_is_delete) == MIDRUN_PREFIXES[e0]
+    assert snap.rule == ("earliest_delete" if at["prefix_failed"] else "")
+    for policy in POLICIES:
+        for state_pack in (False, True):
+            _leaf_by_leaf(midrun, "midrun", e0, policy, state_pack)
+
+
+def test_the_committed_midrun_carry_is_the_engines_own():
+    """The cell's own size: 16 nodes, cpu250, 12,288 events of first_fit,
+    the forked carry against 12,288 steps of the engine, leaf for leaf,
+    and what the configuration says of the state at the fork."""
+    from fks_tpu.data import TraceParser
+
+    wl = TraceParser().parse_workload(pod_file="openb_pod_list_cpu250.csv")
+    _leaf_by_leaf(wl, "cpu250", 12288, "first_fit", False, whole=False)
+    forked = TraceParser().parse_workload(
+        pod_file="openb_pod_list_cpu250.csv",
+        snapshot_file="openb_snapshot_cpu250_firstfit_e12288.csv")
+    s = flat.initial_state(forked, SimConfig())
+    assert flat.fork_counts(forked, s) == {
+        "residents": 50, "nodes_loaded": 15, "departed": 5618,
+        "waiting": 1, "prefix_failed": 1002}
+    assert (int(s.snap_idx), int(s.pending), int(s.steps)) \
+        == (26, 3751 + 50 + 1, 12288)
+    assert round(float(s.frag_sum) / 1002, 4) == 0.0617
+
+
+def _leaf_by_leaf(wl, name, e0, policy, state_pack, whole=True):
     case = (name, policy, state_pack)
     pol = POLICIES[policy]
     cfg = SimConfig(state_pack=state_pack, node_prefilter_k=64)
@@ -96,13 +159,15 @@ def _leaf_by_leaf(wl, name, e0, policy, state_pack):
         assert a.dtype == b.dtype and a.shape == b.shape, (case, field)
         assert np.array_equal(a, b), (case, field)
     assert int(loaded.steps) == int(loaded.events_processed) == e0
+    if not whole:
+        return
     whole = finish(advance(stepped, 2 ** 30))
     from_fork = finish(advance(loaded, 2 ** 30))
     for a, b in zip(jax.tree_util.tree_leaves(whole),
                     jax.tree_util.tree_leaves(from_fork)):
         assert np.array_equal(np.asarray(a), np.asarray(b)), case
     assert int(whole.events_processed) > e0
-    if name != "openb1523":     # the small deployments run under pressure
+    if name in pt.SEEDS:        # the small deployments run under pressure
         assert int(whole.num_fragmentation_events) > 0
         assert float(whole.policy_score) > 0
 

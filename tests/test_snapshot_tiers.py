@@ -192,3 +192,113 @@ def test_sharded_parametric_eval_forks_as_one_device_does(forked):
     scores, _, _ = make_sharded_eval(wls, mesh, cfg=cfg, elite_k=1,
                                      engine="flat")(w, len(w))
     assert np.array_equal(np.asarray(one.policy_score), np.asarray(scores))
+
+
+# ------------------------- forks from a moment of a run (PR 42): the
+# mid-run cell's tiny deployment, whose prefixes hold departures, refused
+# placements and a waiting pod
+
+#: (departures only) and (9 refusals, a pod waiting with its retry queued)
+MIDRUN_E0 = (128, 320)
+
+
+@pytest.fixture(scope="module")
+def midrun(tmp_path_factory):
+    """The tiny mid-run deployment's workload, the reference's inputs and
+    the sources: upstream's first_fit, whose run the snapshots are cut
+    from, and a ledger champion."""
+    from chipbench.selftest import midrun as tiny
+    from fks_tpu.data import TraceParser
+    from fks_tpu.funsearch import template
+
+    d = str(tmp_path_factory.mktemp("midrun"))
+    tiny.tiny_deployment(d)
+    wl = TraceParser(d).parse_workload("nodes.csv", "pods.csv")
+    cluster, pods = pt.reference_inputs(d)
+    codes = [template.seed_policies()["first_fit"], pt.policy_sources()[2]]
+    return d, wl, cluster, pods, codes
+
+
+def _forked_at(d, wl, e0, policy):
+    """(forked workload, the reference's log of the same file)."""
+    import dataclasses
+
+    from chipbench.reference import plain_sim_midrun
+
+    snap = flat.make_snapshot(wl, policy, e0)
+    path = os.path.join(d, "csv", f"snapshot_e{e0}.csv.gz")
+    snap_mod.write_snapshot_csv_gz(wl, snap, path)
+    log = plain_sim_midrun.load_log(path, os.path.join(d, "csv", "nodes.csv"),
+                                    os.path.join(d, "csv", "pods.csv"))
+    return dataclasses.replace(wl, snapshot=snap), log
+
+
+@pytest.mark.parametrize("tier", list(TIERS))
+def test_every_code_tier_forks_mid_run(midrun, tier):
+    """A whole run forked at an event of first_fit's run IS first_fit's
+    unforked run, every leaf of the result bit for bit, in every tier and
+    from either prefix; another source's forked run is the plain
+    reference's (``plain_sim_midrun``), and the counters say what the
+    prefix held."""
+    from chipbench.reference import plain_sim_midrun
+    from fks_tpu.obs import spans
+
+    d, wl, cluster, pods, codes = midrun
+    whole = CodeEvaluator(wl, engine="flat", **TIERS[tier]).evaluate(codes)
+    assert float(whole[0].result.policy_score) > 0
+    for e0 in MIDRUN_E0:
+        forked, log = _forked_at(d, wl, e0, zoo.first_fit())
+        plain_sim_midrun.validate(cluster, pods, log, "earliest_delete")
+        spans.LOG.clear()
+        ev = CodeEvaluator(forked, engine="flat", **TIERS[tier])
+        assert ev.start_event == e0
+        recs = ev.evaluate(codes)
+        assert recs[0].error is None and recs[1].error is None
+        for a, b in zip(jax.tree_util.tree_leaves(whole[0].result),
+                        jax.tree_util.tree_leaves(recs[0].result)):
+            assert np.array_equal(np.asarray(a), np.asarray(b)), (tier, e0)
+        ref = plain_sim_midrun.simulate_from(
+            cluster, pods, log, policies.source_policy(codes[1]))
+        _assert_equal(f"{tier}.e{e0}", ref, recs[1].result, pods)
+        assert int(recs[1].result.num_fragmentation_events) \
+            == ref.num_frag_events
+        assert int(recs[1].result.num_snapshots) == ref.num_snapshots
+        (fork,) = [r for r in spans.LOG.snapshot()
+                   if r.name == "tier/fork_state"]
+        refused = sum(1 for _, node, _ in log.attempts if node < 0)
+        want = {128: (44, 0, 0, ""), 320: (139, 1, 9, "earliest_delete")}
+        assert (fork.fields["departed"], fork.fields["waiting"],
+                fork.fields["prefix_failed"], fork.fields["rule"]) \
+            == want[e0] and refused == want[e0][2]
+        assert fork.fields["residents"] == len(
+            {i for i, node, _ in log.attempts if node >= 0}) - want[e0][0]
+        # the counter sim.retry_share divides: failed placements the
+        # policies made, not the prefix's
+        assert ev.last_eval_stats["frag_events"] == sum(
+            int(r.result.num_fragmentation_events) - refused for r in recs)
+
+
+def test_the_parametric_population_forks_mid_run(midrun):
+    """The same on the parametric tier: lane 0's weights made the
+    snapshot, so its forked run is its unforked one bit for bit."""
+    import dataclasses
+
+    from fks_tpu.parallel import make_population_eval
+
+    _, wl, _, _, _ = midrun
+    w = _weights()
+
+    def placing(pod, nodes):
+        return parametric.score(jnp.asarray(w[0]), pod, nodes)
+
+    whole = jax.device_get(make_population_eval(wl, engine="flat")(
+        jnp.asarray(w)))
+    for e0 in MIDRUN_E0:
+        forked = dataclasses.replace(
+            wl, snapshot=flat.make_snapshot(wl, placing, e0))
+        res = jax.device_get(make_population_eval(forked, engine="flat")(
+            jnp.asarray(w)))
+        assert res.events_processed.min() > e0
+        for a, b in zip(jax.tree_util.tree_leaves(whole),
+                        jax.tree_util.tree_leaves(res)):
+            assert np.array_equal(np.asarray(a)[0], np.asarray(b)[0]), e0

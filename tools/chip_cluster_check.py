@@ -4,6 +4,7 @@ here is a cell, and nothing is measured on another backend (exit 3).
 
     python tools/chip_cluster_check.py whole --seed N
     python tools/chip_cluster_check.py forked --seed N
+    python tools/chip_cluster_check.py midrun --seed N
     python tools/chip_cluster_check.py dense --seed N [--events 32]
 
 ``whole``: ONE whole evaluation (no step cap: fill, pressure, drain, about
@@ -18,6 +19,15 @@ every lane starts after the snapshot's 5,888 arrivals and runs to the
 queue's end (7,500-8,750 further events), compared with
 ``plain_sim_loaded.simulate_from``; the first fitness a code cell's
 configuration compares on the chip.
+
+``midrun``: ``forked`` on upstream's 16 nodes from the pinned moment of
+``openb16-cpu250-midrun`` (event 12,288 of cpu250: departed, resident and
+waiting pods): every lane runs to the end of the trace (12,370-17,836
+further events by the plain reference) or to the 8 x pods cap, compared
+as the cell compares
+(``plain_sim_midrun.simulate_from``, float32 sources, ``nearties.admit``);
+a lane that ends at the cap scores 0 on both sides, so one finished lane
+at least is asked for, not all.
 
 ``dense``: the same 8 lanes for ``--events`` events under the rule the
 program chooses (64) and DENSE (an explicit ``node_prefilter_k`` of the
@@ -42,6 +52,7 @@ from chipbench.drivers import common  # noqa: E402
 
 CELL = "openb1523-inflated.codegen8"
 LOADED = "openb1523-loaded.codegen8"
+MIDRUN = "openb16-cpu250-midrun.codegen8"
 DEVICE = ("tier/vm_batch/launch", "tier/vm_batch/wait_device")
 
 
@@ -61,10 +72,14 @@ def _inputs(seed: int, name: str = CELL):
     if "snapshot" in files:
         import functools
 
-        from chipbench.reference.plain_sim_loaded import simulate_from
         driver.e0 = int(cell.config["start_event"])
         wl = driver._workload()
-        reference = functools.partial(simulate_from, rows=driver.rows())
+        if name == MIDRUN:
+            from chipbench.reference.plain_sim_midrun import simulate_from
+            reference = functools.partial(simulate_from, log=driver.rows())
+        else:
+            from chipbench.reference.plain_sim_loaded import simulate_from
+            reference = functools.partial(simulate_from, rows=driver.rows())
     else:
         from chipbench.reference.plain_sim import simulate as reference
         wl = common.parse_workload(cell.config, files)
@@ -113,15 +128,29 @@ def whole(seed: int, name: str = CELL) -> bool:
         fallback_lanes=stats["fallback_lanes"])
     cluster, pods = common.reference_inputs(cell.config, files)
     ok = stats["vm_batch_lanes"] == len(sources)
+    kw = dict(retry=cell.config["retry_rule"],
+              prefilter_k=int(cell.config["node_prefilter_k"]))
+    finished = 0
     for lane, (rec, code) in enumerate(zip(recs, sources)):
         t0 = time.perf_counter()
-        ref = simulate(cluster, pods, policy=policies.source_policy(code),
-                       retry=cell.config["retry_rule"],
-                       prefilter_k=int(cell.config["node_prefilter_k"]))
-        numbers = compare(f"lane{lane}", ref,
-                          Output.of_lane(rec.result, pods.p),
-                          cell.config["guarantees"])
-        ok &= all(n.ok for n in numbers) and ref.policy_score > 0
+        got = Output.of_lane(rec.result, pods.p)
+        if name == MIDRUN:      # as the cell compares
+            from chipbench.reference.nearties import admit
+            policy = policies.source_policy(
+                code, dtype=cell.config["guarantees"]["score_dtype"])
+            ref, ties = admit(
+                lambda decide: simulate(cluster, pods, policy=policy,
+                                        decide=decide, **kw),
+                got.assigned_node, cell.config["guarantees"], f"lane{lane}")
+            numbers = [ties]
+        else:
+            ref = simulate(cluster, pods,
+                           policy=policies.source_policy(code), **kw)
+            numbers = []
+        numbers += compare(f"lane{lane}", ref, got,
+                           cell.config["guarantees"])
+        finished += ref.policy_score > 0
+        ok &= all(n.ok for n in numbers)
         say(row="lane", lane=lane, fitness=rec.score,
             reference_fitness=ref.policy_score,
             events=int(rec.result.events_processed),
@@ -130,7 +159,9 @@ def whole(seed: int, name: str = CELL) -> bool:
             reference_s=time.perf_counter() - t0,
             compared={n.name.split(".", 1)[1]: n.value for n in numbers},
             ok=all(n.ok for n in numbers))
-    say(row="whole", seed=seed, lanes=len(sources), all_equal=bool(ok))
+    ok &= finished > 0 if name == MIDRUN else finished == len(sources)
+    say(row="whole", seed=seed, lanes=len(sources), finished=finished,
+        all_equal=bool(ok))
     return bool(ok)
 
 
@@ -164,7 +195,7 @@ def dense(seed: int, events: int) -> bool:
 
 def main(argv=None) -> int:
     ap = argparse.ArgumentParser(description=__doc__.splitlines()[0])
-    ap.add_argument("what", choices=("whole", "forked", "dense"))
+    ap.add_argument("what", choices=("whole", "forked", "midrun", "dense"))
     ap.add_argument("--seed", type=int, required=True)
     ap.add_argument("--events", type=int, default=32)
     a = ap.parse_args(argv)
@@ -176,7 +207,8 @@ def main(argv=None) -> int:
         return 3
     place_compile_cache()
     ok = (dense(a.seed, a.events) if a.what == "dense"
-          else whole(a.seed, LOADED if a.what == "forked" else CELL))
+          else whole(a.seed, {"forked": LOADED, "midrun": MIDRUN}.get(
+              a.what, CELL)))
     return 0 if ok else 1
 
 
