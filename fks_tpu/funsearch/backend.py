@@ -239,9 +239,10 @@ class CodeEvaluator:
         self._vm_mesh_run = None  # lazily built SHARDED population program
         self._budget_eval = None  # lazily built rung ladder (budget mode)
         self.vm_batch_count = 0  # observability: batched VM launches
-        # (lanes, capacity) -> (slice, scatter) of vm.write_count() over
-        # the first batched launch of that bucket, which traced it
-        self._vm_writes: Dict[Tuple[int, int], Tuple[int, int]] = {}
+        # (lanes, capacity) -> (slice, scatter, merged, split) of
+        # vm.write_count() + vm.read_count() over the first batched launch
+        # of that bucket, which traced it
+        self._vm_traced: Dict[Tuple[int, int], Tuple[int, ...]] = {}
         # the most recent batched launch's [lanes] score array, on the
         # device: last_lanes_per_device reads its placement when asked
         self._last_scores = None
@@ -388,19 +389,23 @@ class CodeEvaluator:
         except Exception:  # noqa: BLE001 — pricing is best-effort
             pass
 
-    def _vm_write_fields(self, bucket: Tuple[int, int],
-                         before: Tuple[int, int]) -> Dict[str, int]:
-        """``slice_writes`` / ``scatter_writes`` of a batched launch: how
-        the bucket's runner lowered the op-slot loop's row write
-        (``vm.write_count``, counted while a program is traced). The
-        launch that moved the count since ``before`` traced the bucket's
-        program and the difference stays with the bucket; every later
-        launch of it traces nothing and reports the same two numbers."""
-        traced = vm.writes_since(before)
+    def _vm_traced_fields(self, bucket: Tuple[int, int],
+                          before: Tuple[int, ...]) -> Dict[str, int]:
+        """``slice_writes`` / ``scatter_writes`` and ``merged_reads`` /
+        ``split_reads`` of a batched launch: how the bucket's runner
+        lowered the op-slot loop's row write and its operand fetch
+        (``vm.write_count``, ``vm.read_count``, counted while a program is
+        traced). The launch that moved a count since ``before`` (the two
+        readings, joined) traced the bucket's program and the difference
+        stays with the bucket; every later launch of it traces nothing and
+        reports the same four numbers."""
+        now = vm.write_count() + vm.read_count()
+        traced = tuple(x - y for x, y in zip(now, before))
         if any(traced):
-            self._vm_writes[bucket] = traced
-        slices, scatters = self._vm_writes.get(bucket, (0, 0))
-        return {"slice_writes": slices, "scatter_writes": scatters}
+            self._vm_traced[bucket] = traced
+        return dict(zip(
+            ("slice_writes", "scatter_writes", "merged_reads", "split_reads"),
+            self._vm_traced.get(bucket, (0, 0, 0, 0))))
 
     def _run_vm_batch(self, progs: List[vm.VMProgram]) -> List[SimResult]:
         """Evaluate stacked VM candidates in ONE device launch — sharded
@@ -443,7 +448,7 @@ class CodeEvaluator:
                       register_bytes=(pop // self._n_shards)
                       * vm.register_rows(capacity) * view * c.g_padded
                       * stacked.imm.dtype.itemsize) as t_launch:
-            writes0 = vm.write_count()
+            traced0 = vm.write_count() + vm.read_count()
             if self._n_shards > 1 and self.suite is None:
                 # each device interprets pop/shards lanes; the elite
                 # outputs are discarded here (the evolution loop ranks on
@@ -455,7 +460,7 @@ class CodeEvaluator:
                 result, _, _ = self._vm_mesh_runner()(stacked, len(progs))
             else:
                 result = self._vm_pop_runner()(stacked, self.state0)
-            t_launch.set(**self._vm_write_fields((pop, capacity), writes0))
+            t_launch.set(**self._vm_traced_fields((pop, capacity), traced0))
         self._last_scores = result.policy_score
         with obs.span("tier/vm_batch/wait_device"):
             jax.block_until_ready(result)

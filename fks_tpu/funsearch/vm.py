@@ -764,8 +764,7 @@ def _branches(n: int, g: int):
 
     def col(va, vb, vc, im):
         c = jnp.clip(im.astype(jnp.int32), 0, g - 1)
-        return jnp.broadcast_to(
-            lax.dynamic_slice_in_dim(va, c, 1, axis=1), (n, g))
+        return jnp.broadcast_to(_col_picker(0)(va, c), (n, g))
 
     return [
         lambda va, vb, vc, im: va,  # NOP (value = operand a)
@@ -819,9 +818,9 @@ def _execute(prog: VMProgram, pod: PodView, nodes: NodeView,
     op_base = N_INPUTS + prog.consts.shape[0]
 
     def body(k, regs):
-        res = lax.switch(
-            prog.opcode[k], branches,
-            regs[prog.a[k]], regs[prog.b[k]], regs[prog.c[k]], prog.imm[k])
+        op, *operands = _slot_operands(0)(
+            regs, prog.opcode, prog.a, prog.b, prog.c, prog.imm, k)
+        res = lax.switch(op, branches, *operands)
         return _write_row(regs, res, op_base + k)
 
     regs = lax.fori_loop(0, bound, body, regs)
@@ -939,6 +938,129 @@ def _row_writer(axis: int):
 def _write_row(regs: jax.Array, res: jax.Array, row) -> jax.Array:
     """The op-slot loop's register write (`_row_writer`)."""
     return _row_writer(0)(regs, res, row)
+
+
+_READ_COUNT = threading.local()
+
+
+def read_count() -> Tuple[int, int]:
+    """(merged, split): how often, on THIS thread, the batching rule of a
+    slot's operand fetch (`_slot_operands`) fetched the three rows with
+    one gather and how often it fell back to JAX's own rules (a gather a
+    row). Counted while a runner is traced, as `write_count` is and with
+    the same meaning; a program that no ``vmap`` batches never moves
+    either. The evaluator keeps what a runner's first call added
+    (``merged_reads`` / ``split_reads`` of ``tier/vm_batch/launch``)."""
+    return getattr(_READ_COUNT, "n", (0, 0))
+
+
+def _per_file(f, axis: int):
+    """``f`` over the ``axis`` leading batch axes of its arguments."""
+    for _ in range(axis):
+        f = jax.vmap(f)
+    return f
+
+
+@functools.lru_cache(maxsize=None)
+def _slot_operands(axis: int):
+    """``fetch(regs, opcode, a, b, c, imm, k)``: op slot ``k``'s
+    ``(opcode, va, vb, vc, imm)``, the arguments of its ``lax.switch``
+    after the table, read from a register file whose row axis is ``axis``
+    (the number of ``vmap`` levels that batched the file and not the
+    program), with a batching rule of its own for program words that are
+    PER LANE.
+
+    One program reads ``opcode[k]``, ``a[k]``, ``b[k]``, ``c[k]`` and
+    ``imm[k]`` as scalars and slices three rows out of the file. Under
+    ``vmap`` over stacked programs JAX's rules make of that three index
+    fetches and three gathers of a row a lane, and on the chip a gather
+    costs its start whatever it moves (0.27 us for 8 rows, 0.295 for 24:
+    PERF.md section 6, PR 44). The rule stacks the program's three operand arrays to
+    ``[lanes, 3, capacity]`` (loop invariants: XLA builds the table
+    outside the slot loop), fetches ONE ``[lanes, 3]`` index a slot and
+    gathers the three rows with it in ONE gather. The index arithmetic is
+    the scalar read's (negative wrap, clamp to the file), so every row is
+    the row the three reads return, bit for bit.
+
+    A ``vmap`` that batches the file alone (queries, scenarios; serving
+    never batches the program) goes to the fetch of the next axis, which
+    traces what JAX's own rules trace there, and leaves the rule within
+    reach of an enclosing ``vmap`` that batches the programs. A batched
+    slot counter, which no runner makes, takes JAX's rules and is counted
+    (`read_count`)."""
+
+    def fetch_plain(regs, opcode, a, b, c, imm, k):
+        op = opcode[k]
+        rows = _per_file(
+            lambda regs: (regs[a[k]], regs[b[k]], regs[c[k]]), axis)(regs)
+        return (op, *rows, imm[k])
+
+    def rows_merged(regs, idx):
+        idx = jnp.where(idx < 0, idx + regs.shape[axis], idx)
+        got = jnp.take(regs, idx, axis=axis, mode="clip")
+        return tuple(lax.index_in_dim(got, i, axis, keepdims=False)
+                     for i in range(3))
+
+    fetch = jax.custom_batching.custom_vmap(fetch_plain)
+
+    @fetch.def_vmap
+    def fetch_lanes(axis_size, in_batched, regs, opcode, a, b, c, imm, k):
+        words, per_lane = (opcode, a, b, c, imm), in_batched[1:6]
+        merged, split = read_count()
+        if in_batched[6]:
+            _READ_COUNT.n = (merged, split + 1)
+            return jax.vmap(
+                fetch_plain, in_axes=[0 if b else None for b in in_batched],
+                axis_size=axis_size)(regs, *words, k), (True,) * 5
+        if not any(per_lane):
+            return (_slot_operands(axis + 1)(regs, *words, k),
+                    (False, True, True, True, False))
+        _READ_COUNT.n = (merged + 1, split)
+        opcode, a, b, c, imm = (
+            x if b else jnp.broadcast_to(x, (axis_size,) + x.shape)
+            for x, b in zip(words, per_lane))
+        idx = lax.dynamic_index_in_dim(jnp.stack([a, b, c], axis=1), k,
+                                       axis=2, keepdims=False)
+        rows = jax.vmap(rows_merged,
+                        in_axes=(0 if in_batched[0] else None, 0))(regs, idx)
+        return (opcode[:, k], *rows, imm[:, k]), (True,) * 5
+
+    return fetch
+
+
+@functools.lru_cache(maxsize=None)
+def _col_picker(axis: int):
+    """``pick(va, c)``: column ``c`` of ``va`` ([..., N, G] with ``axis``
+    leading batch axes) as [..., N, 1], COL's read, with a batching rule
+    of its own for a column that is PER LANE. One program slices at the
+    traced column. Under ``vmap`` over stacked programs that slice is a
+    gather from the row the slot's gather just returned, a second kernel
+    start in a row, every slot, whatever the opcode; the rule picks the
+    column by a select over the G static planes, which fuses into the
+    kernels around it. The same value either way: a select moves bits.
+    As in `_slot_operands`, a ``vmap`` that batches ``va`` alone goes to
+    the picker of the next axis."""
+
+    def pick_plain(va, c):
+        return _per_file(
+            lambda va: lax.dynamic_slice_in_dim(va, c, 1, axis=1), axis)(va)
+
+    def pick_planes(va, c):
+        out = va[..., :1]
+        for j in range(1, va.shape[-1]):
+            out = jnp.where(c == j, va[..., j:j + 1], out)
+        return out
+
+    pick = jax.custom_batching.custom_vmap(pick_plain)
+
+    @pick.def_vmap
+    def pick_lanes(axis_size, in_batched, va, c):
+        if not in_batched[1]:
+            return _col_picker(axis + 1)(va, c), True
+        return jax.vmap(pick_planes,
+                        in_axes=(0 if in_batched[0] else None, 0))(va, c), True
+
+    return pick
 
 
 def score(prog: VMProgram, pod: PodView, nodes: NodeView) -> jax.Array:

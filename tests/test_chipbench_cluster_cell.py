@@ -25,6 +25,7 @@ SPAN_METRICS = ("tier.preflight_ms_per_call", "tier.transpile_ms_per_call",
                 "vm.us_per_slot", "vm.register_mb",
                 "tier.traces_per_source", "vm.ops_kept_share",
                 "vm.scatter_write_share", "tier.pooled_source_share",
+                "vm.merged_read_share",
                 # the ring's other writers (PR 40; chipbench/reduce/hostspans.py)
                 "tier.lower_ms_per_source", "tier.pack_ms_per_call",
                 "tier.pool_overhead_ms_per_call", "tier.gc_ms_per_call",
@@ -93,6 +94,7 @@ def test_cell_runs_end_to_end_and_reports_its_span_metrics(monkeypatch,
     assert v["tier.traces_per_source"] == 1.0   # no dry trace before it
     assert 0 < v["vm.ops_kept_share"] < 100     # the simplifier engaged
     assert v["vm.scatter_write_share"] == 0.0   # every write stayed a slice
+    assert v["vm.merged_read_share"] == 100.0   # every fetch one gather
     assert 0.0 <= v["tier.pooled_source_share"] <= 100.0
     slots = v["vm.live_slot_share"] / 100 * 512
     assert v["vm.us_per_slot"] == pytest.approx(

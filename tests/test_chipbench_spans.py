@@ -181,6 +181,24 @@ def test_live_slot_share_reads_slots_over_capacity(metric, span, make,
     assert got == (want if want is None else pytest.approx(want))
 
 
+@pytest.mark.parametrize("fields,want", [
+    # the population runner: one vmap that batches the programs
+    ({"merged_reads": 1, "split_reads": 0}, 100.0),
+    ({"merged_reads": 2, "split_reads": 0}, 100.0),   # two passes, nested
+    ({"merged_reads": 0, "split_reads": 1}, 0.0),     # a batched counter
+    ({"merged_reads": 1, "split_reads": 1}, 50.0),
+    ({"merged_reads": 0, "split_reads": 0}, None),    # the rule never ran
+    # a parent without the fields (older than PR 44)
+    ({"slots": 292, "capacity": 512, "slice_writes": 1,
+      "scatter_writes": 0}, None),
+    ({}, None),
+])
+def test_merged_read_share_reads_the_launch_spans_fields(fields, want):
+    got = cells.metric_reader("vm.merged_read_share")(
+        {"_span_calls": _launch_calls(fields)})
+    assert got == (want if want is None else pytest.approx(want))
+
+
 def _transpile_calls(fields):
     """The window's calls of three generations (the first is the warm-up),
     each with a ``tier/transpile`` span that carries ``fields``."""
@@ -307,8 +325,9 @@ def test_every_span_metric_is_declared_with_its_files():
     declared = {m["name"]: m for m in bench["per_layer"]}
     # + vm.scatter_write_share (PR 35), serve.fork_state_ms and
     # serve.retry_share (PR 37), tier.pooled_source_share (PR 39),
-    # sim.fork_replay_us_per_event and sim.fork_waiting_pods (PR 42)
-    assert len(SPAN_METRICS) == 29
+    # sim.fork_replay_us_per_event and sim.fork_waiting_pods (PR 42),
+    # vm.merged_read_share (PR 44)
+    assert len(SPAN_METRICS) == 30
     for name in SPAN_METRICS:
         assert name in declared, name
         meta = json.load(open(os.path.join(cells.HERE, "metrics",
@@ -365,7 +384,7 @@ def test_traced_cell_reports_every_span_metric(name, tmp_path, monkeypatch):
             + v["serve.h2d_ms_per_call"] + v["serve.harvest_ms_per_call"]
         assert v["serve.h2d_kb_per_call"] > 0 and v["serve.d2h_kb_per_call"] > 0
     else:
-        assert len(want) == 13
+        assert len(want) == 14
         # a recorded generation: every source traced once, where it runs,
         # and the simplifier dropped part of what the lowering emitted
         assert res["metrics"]["tier.traces_per_source"]["value"] == 1.0
@@ -374,6 +393,8 @@ def test_traced_cell_reports_every_span_metric(name, tmp_path, monkeypatch):
         assert res["metrics"]["mesh.host_ms_per_call"]["value"] > 0
         # the mesh runner kept every register write one slice
         assert res["metrics"]["vm.scatter_write_share"]["value"] == 0.0
+        # and fetched every slot's three rows with one gather
+        assert res["metrics"]["vm.merged_read_share"]["value"] == 100.0
         # the generations went through the process's lowering pool as
         # far as this machine has the cores and the workers were up
         assert 0.0 <= res["metrics"]["tier.pooled_source_share"]["value"] \

@@ -84,7 +84,9 @@ def test_benchmark_json_only_gained_entries():
         "tier.gc_ms_per_call", "serve.gc_ms_per_call",
         "tier.slow_call_share", "serve.slow_call_share",
         # PR 42's two of the mid-run fork
-        "sim.fork_replay_us_per_event", "sim.fork_waiting_pods"]
+        "sim.fork_replay_us_per_event", "sim.fork_waiting_pods",
+        # PR 44's merged-read share of the code cells
+        "vm.merged_read_share"]
     new = bench["per_layer"][at:at + 2]
     for m in new:
         assert m["workloads"] == [CELL] and m["layer"] == "serving serve/"

@@ -178,3 +178,66 @@ def test_param256_compiles_with_the_population_on_the_lanes():
         "2,1,0", 0)
     kernels = sum(op in ("fusion", "copy") for op in ops)
     assert 30 <= kernels <= 70, kernels
+
+
+# ------------------------------- the interpreter's op-slot loop (PR 44)
+
+def _described_device():
+    device = describe_compile.topology_device("v5e:2x2")
+    if device is None:
+        pytest.skip("no TPU compiler in this installation can describe "
+                    "v5e:2x2 (jax.experimental.topologies)")
+    return device
+
+
+def _slot_kernels(hlo):
+    """The op-slot loop's kernels: what is not arithmetic on the scalar
+    core (the loop counter and the row it writes)."""
+    return [r for r in describe_compile.slot_loop(hlo)
+            if any(dims for _, dims, _ in r["arrays"])]
+
+
+@pytest.mark.parametrize("cluster,view", [("16", 16), ("1523", 64)])
+def test_codegen_slot_fetches_its_operands_with_one_gather(cluster, view):
+    """The batched VM tier's slot, compiled for a described v5e: ONE
+    gather from the register file (three rows a lane), no gather of COL's
+    own from a row, the file updated in place (no copy and no second
+    layout of it), and no more kernels a slot than PR 44 reached (the
+    parent: 14, four of them gathers)."""
+    from fks_tpu.funsearch import vm
+
+    lanes, g = 8, 8
+    with jax.enable_x64(False):   # the chip's program: int32 / float32
+        hlo = describe_compile.codegen(_described_device(), cluster, lanes)
+    kernels = _slot_kernels(hlo)
+    gathers = [r for r in kernels
+               if "gather" in describe_compile.fused_ops(hlo, r)]
+    assert [r["arrays"][0][1] for r in gathers] == [(3 * lanes, view, g)]
+    file_shape = (lanes, vm.register_rows(512), view, g)
+    files = [(r["op"], a[2]) for r in kernels for a in r["arrays"]
+             if a[1] == file_shape]
+    assert len(files) == 1 and files[0][0] == "fusion", files
+    carried = [a[2] for r in describe_compile.loop_body(hlo)
+               if r["op"] == "while" for a in r["arrays"]
+               if a[1] == file_shape]
+    assert carried == [files[0][1]]   # the layout the loop carries it in
+    assert not [r for r in kernels if r["op"] == "copy"]
+    assert len(kernels) <= 11, [r["name"] for r in kernels]
+
+
+def test_whatif_slot_loop_is_the_scalar_one():
+    """Serving never batches the program, so no rule of the fetch is
+    reached: its slot is what it was before PR 44, four scalar fetches,
+    the rows by dynamic-slice, a real conditional and the in-place
+    write, with no gather in it."""
+    import collections
+
+    with jax.enable_x64(False):
+        hlo = describe_compile.whatif(_described_device(), "1523", 2)
+    rows = describe_compile.slot_loop(hlo)
+    assert collections.Counter(r["op"] for r in rows) == {
+        "add": 6, "compare": 4, "select": 4, "dynamic-slice": 4,
+        "fusion": 4, "clamp": 1, "conditional": 1}
+    inside = [op for r in rows for op in describe_compile.fused_ops(hlo, r)]
+    assert "gather" not in inside
+    assert inside.count("dynamic-update-slice") == 1

@@ -351,6 +351,11 @@ def _assert_unbatched_op_slot_loop(closed_jaxpr, capacity):
     return len(loops)
 
 
+def _reads_since(before):
+    """`vm.read_count` now less an earlier reading of it."""
+    return tuple(x - y for x, y in zip(vm.read_count(), before))
+
+
 def _lowered_batched_paths(wl):
     """name -> (closed jaxpr, capacity) of every batched runner's device
     program, traced on a short + long stack."""
@@ -367,7 +372,16 @@ def _lowered_batched_paths(wl):
              for code in _mix_codes(["ff", "champ"])]
     stacked = vm.stack_programs(progs, capacity=CAP)
     cfg = SimConfig()
-    out = {}
+    reads = {}
+
+    class Traced(dict):     # name -> jaxpr, and what tracing it counted
+        def __setitem__(self, name, jaxpr):
+            reads[name] = _reads_since(self.mark)
+            self.mark = vm.read_count()
+            super().__setitem__(name, jaxpr)
+
+    out = Traced()
+    out.mark = vm.read_count()
     for name, mod in (("flat", flat), ("exact", exact)):
         run = mod.make_population_run_fn(wl, vm.score, cfg)
         out[f"population/{name}"] = jax.make_jaxpr(run)(
@@ -389,7 +403,7 @@ def _lowered_batched_paths(wl):
     suite = make_suite_eval(get_suite("smoke3", wl), vm.score, cfg,
                             population=True, jit=False, engine="exact")
     out["suite/exact"] = jax.make_jaxpr(suite)(stacked)
-    return {k: (v, CAP) for k, v in out.items()}
+    return {k: (v, CAP, reads[k]) for k, v in out.items()}
 
 
 @pytest.fixture(scope="module")
@@ -403,7 +417,7 @@ BATCHED_PATHS = ["population/flat", "population/exact", "segmented/flat",
 
 def test_no_batched_path_selects_the_register_file(batched_paths):
     assert sorted(batched_paths) == sorted(BATCHED_PATHS)
-    for name, (jaxpr, cap) in batched_paths.items():
+    for name, (jaxpr, cap, _) in batched_paths.items():
         assert _assert_unbatched_op_slot_loop(jaxpr, cap) >= 1, name
 
 
@@ -438,7 +452,7 @@ def _assert_one_slice_write_a_slot(closed_jaxpr, capacity):
 @pytest.mark.parametrize("name", BATCHED_PATHS)
 def test_every_batched_path_writes_a_register_as_one_slice(batched_paths,
                                                            name):
-    jaxpr, cap = batched_paths[name]
+    jaxpr, cap, _ = batched_paths[name]
     assert _assert_one_slice_write_a_slot(jaxpr, cap) >= 1
 
 
@@ -560,6 +574,249 @@ def test_a_batched_row_takes_the_counted_fall_back():
                 np.asarray(lax.dynamic_update_index_in_dim(
                     a[0][lane] if axes[0] == 0 else a[0],
                     a[1][lane] if axes[1] == 0 else a[1], 7, 0)))
+
+
+# ------------------------------------------- the slot's operand fetch
+
+def _file_gathers(closed_jaxpr, capacity):
+    """Per op-slot loop of the jaxpr: the shapes of what each ``gather``
+    in its body reads from. The register file ([..., rows, N, G]) is the
+    operand fetch; a gather from a row ([..., N, G]) is COL's column pick
+    as JAX's own rules make it."""
+    regs = vm.N_INPUTS + vm.CONST_POOL + capacity
+    loops = _op_slot_loops(closed_jaxpr.jaxpr, regs)
+    assert loops, "no op-slot loop in the program"
+    out = []
+    for eqn in loops:
+        shapes = [b.invars[0].aval.shape
+                  for b in _walk(eqn.params["body_jaxpr"].jaxpr)
+                  if b.primitive.name == "gather"]
+        from_file = [s for s in shapes if len(s) >= 3 and s[-3] == regs]
+        assert from_file, "the loop reads no register"
+        out.append((from_file,
+                    [s for s in shapes if s not in from_file
+                     and s[-2:] == from_file[0][-2:]]))
+    return out
+
+
+def _assert_one_gather_a_slot(closed_jaxpr, capacity):
+    """The merged fetch: every op-slot loop gathers from the register file
+    ONCE a slot (three rows a lane) and never from a row (COL)."""
+    found = _file_gathers(closed_jaxpr, capacity)
+    for from_file, from_row in found:
+        assert len(from_file) == 1 and not from_row, (from_file, from_row)
+    return len(found)
+
+
+@pytest.mark.parametrize("name", BATCHED_PATHS)
+def test_every_batched_path_fetches_a_slot_with_one_gather(batched_paths,
+                                                           name):
+    jaxpr, cap, (merged, split) = batched_paths[name]
+    assert merged > 0 and split == 0
+    assert _assert_one_gather_a_slot(jaxpr, cap) >= 1
+
+
+def test_the_default_rule_gathers_would_be_caught(monkeypatch):
+    """The check above is not vacuous: the fetch as it was, three bare
+    reads and COL's slice at a traced column, is under ``vmap`` three
+    gathers from the file and one from a row, and trips it."""
+    from jax import lax
+
+    rng = np.random.default_rng(2)
+    progs = [vm.compile_policy(c, N, G, capacity=256)
+             for c in _mix_codes(["ff", "bf"])]
+    stacked = vm.stack_programs(progs, capacity=256)
+    pod, nodes = _rand_views(rng)
+
+    def as_it_was(axis):
+        assert axis == 0
+        return lambda regs, opcode, a, b, c, imm, k: (
+            opcode[k], regs[a[k]], regs[b[k]], regs[c[k]], imm[k])
+
+    monkeypatch.setattr(vm, "_slot_operands", as_it_was)
+    monkeypatch.setattr(
+        vm, "_col_picker",
+        lambda axis: lambda va, c: lax.dynamic_slice_in_dim(va, c, 1, axis=1))
+    batched = jax.vmap(lambda *a: vm.score(*a), in_axes=(0, None, None))
+    jaxpr = jax.make_jaxpr(batched)(stacked, pod, nodes)
+    (from_file, from_row), = _file_gathers(jaxpr, 256)
+    assert len(from_file) == 3 and len(from_row) == 1
+    with pytest.raises(AssertionError):
+        _assert_one_gather_a_slot(jaxpr, 256)
+
+
+def test_unbatched_score_never_reaches_the_read_rule():
+    """One program alone: scalar fetches and slices of the file as before
+    (five fetches, three rows, COL's column: no gather anywhere), one
+    33-way conditional, and the rule's counter does not move."""
+    rng = np.random.default_rng(4)
+    prog = vm.compile_policy(_mix_codes(["bf"])[0], N, G, capacity=256)
+    pod, nodes = _rand_views(rng)
+    before = vm.read_count()
+    jaxpr = jax.make_jaxpr(vm.score)(prog, pod, nodes)
+    assert vm.read_count() == before
+    loop, = _op_slot_loops(jaxpr.jaxpr,
+                           vm.N_INPUTS + vm.CONST_POOL + 256)
+    body = list(_walk(loop.params["body_jaxpr"].jaxpr))
+    names = [e.primitive.name for e in body]
+    assert "gather" not in names and "select_n" in names
+    conds = [e for e in body if e.primitive.name == "cond"]
+    assert [len(e.params["branches"]) for e in conds] == [33]
+    # opcode, a, b, c, imm; the three rows; COL's column inside its branch
+    assert names.count("dynamic_slice") == 9
+
+
+#: ``imm`` below 0, in range, at G-1, at G and above; operand registers
+#: that wrap (negative), wrap and still miss (clamped to row 0) and
+#: overshoot (clamped to the last row)
+_IMMS = (-3.0, -1.0, 0.0, 1.0, G - 1.0, float(G), G + 5.0)
+_SPECIALS = (np.nan, 0.0, -0.0, np.inf, -np.inf, 1.5, -2.0, 7.0)
+
+
+def _slot_case(opcode, rng):
+    """A [lanes] stack of one-slot programs of ``opcode`` over a register
+    file of special values, one lane an ``imm``."""
+    rows = 12
+    regs = rng.choice(_SPECIALS, size=(rows, N, G))
+    regs[1] = rng.integers(0, G, size=(N, G))    # a finite row: POW, REM
+    lanes = len(_IMMS)
+    words = dict(
+        opcode=np.full((lanes, 4), opcode, np.int32),
+        a=rng.choice([0, 1, 5, rows - 1, -1, -rows - 5, rows + 7],
+                     size=(lanes, 4)).astype(np.int32),
+        b=rng.integers(-rows, rows + 3, size=(lanes, 4)).astype(np.int32),
+        c=rng.integers(0, rows, size=(lanes, 4)).astype(np.int32),
+        imm=np.tile(np.asarray(_IMMS)[:, None], (1, 4)))
+    return jnp.asarray(regs), {k: jnp.asarray(v) for k, v in words.items()}
+
+
+def _reads_as_they_were(regs, w, lane, k):
+    """What the slot read before the rule: three separate reads with the
+    scalar read's wrap and clamp, done here in NumPy."""
+    regs = np.asarray(regs)
+
+    def row(i):
+        i = int(i)
+        i = i + regs.shape[0] if i < 0 else i
+        return regs[min(max(i, 0), regs.shape[0] - 1)]
+
+    return tuple(row(np.asarray(w[f])[lane, k]) for f in "abc")
+
+
+@pytest.mark.parametrize("opcode", range(33))
+def test_merged_fetch_equals_the_three_reads_and_col(opcode):
+    """One slot of every opcode, a stack of lanes under ``vmap``: the
+    merged fetch returns bit for bit the rows three separate reads return,
+    the slot's value is what the table gives on those rows, and COL is the
+    column the slice at the clipped ``imm`` picked, on NaN, signed zeros
+    and infinities, for ``imm`` from below 0 to above G."""
+    from jax import lax
+
+    rng = np.random.default_rng(100 + opcode)
+    regs, w = _slot_case(opcode, rng)
+    k = 2
+    branches = vm._branches(N, G)
+
+    def slot(opcode, a, b, c, imm):
+        op, *operands = vm._slot_operands(0)(regs, opcode, a, b, c, imm, k)
+        return (op, *operands, lax.switch(op, branches, *operands))
+
+    before = vm.read_count()
+    op, va, vb, vc, im, res = jax.vmap(slot)(*(w[f] for f in (
+        "opcode", "a", "b", "c", "imm")))
+    assert _reads_since(before) == (1, 0)
+    bits = lambda x: np.asarray(x).view(np.uint64)   # x64 in the tests
+    for lane, imm in enumerate(_IMMS):
+        rows = _reads_as_they_were(regs, w, lane, k)
+        for got, want in zip((va, vb, vc), rows):
+            np.testing.assert_array_equal(bits(got[lane]), bits(want))
+        assert int(op[lane]) == opcode and float(im[lane]) == imm
+        if opcode == vm.OP_COL:
+            col = min(max(int(imm), 0), G - 1)
+            want = np.broadcast_to(rows[0][:, col:col + 1], (N, G))
+            np.testing.assert_array_equal(bits(res[lane]), bits(want))
+        elif opcode == vm.OP_SETCOL:       # an imm outside 0..G-1 writes
+            want = rows[0].copy()          # no column
+            if 0 <= imm < G:
+                want[:, int(imm)] = rows[1][:, int(imm)]
+            np.testing.assert_array_equal(bits(res[lane]), bits(want))
+        else:
+            want = branches[opcode](*(jnp.asarray(r) for r in rows),
+                                    jnp.asarray(imm))
+            np.testing.assert_array_equal(np.asarray(res[lane]),
+                                          np.asarray(want))
+        # and the unbatched slot, which no rule touches, says the same
+        alone = slot(*(w[f][lane] for f in ("opcode", "a", "b", "c", "imm")))
+        np.testing.assert_array_equal(np.asarray(res[lane]),
+                                      np.asarray(alone[-1]))
+
+
+@pytest.mark.parametrize("inner,outer,prog_of,view_of", [
+    ((0, None, None), (0, None, None), lambda i, j: j, None),
+    ((None, 0, 0), (0, None, None), lambda i, j: i, lambda i, j: j),
+    ((0, None, None), (None, 0, 0), lambda i, j: j, lambda i, j: i),
+])
+def test_nested_vmap_keeps_the_one_gather(inner, outer, prog_of, view_of):
+    """Two ``vmap`` levels around ``score``, the programs batched by the
+    inner one, by the outer one or by both: whichever level batches them
+    merges the fetch (a level that batches the views alone hands the rule
+    on), so the [A, B, rows, N, G] file is gathered from once a slot and
+    no row is, and every lane scores what its program scores alone."""
+    rng = np.random.default_rng(19)
+    progs = [vm.compile_policy(c, N, G, capacity=CAP)
+             for c in _mix_codes(["champ", "bf"])]    # the champion has COL
+    views = [_rand_views(rng) for _ in range(3)]
+    stacked = vm.stack_programs(progs, capacity=CAP)
+    if view_of is None:
+        prog = jax.tree_util.tree_map(lambda x: jnp.stack([x, x]), stacked)
+        view = views[0]
+    else:
+        prog = stacked
+        view = jax.tree_util.tree_map(lambda *x: jnp.stack(x), *views)
+    f = jax.vmap(jax.vmap(vm.score, in_axes=inner), in_axes=outer)
+    before = vm.read_count()
+    jaxpr = jax.make_jaxpr(f)(prog, *view)
+    merged, split = _reads_since(before)
+    assert merged >= 1 and split == 0
+    assert _assert_one_gather_a_slot(jaxpr, CAP) == 1
+    got = np.asarray(f(prog, *view))
+    for i in range(got.shape[0]):
+        for j in range(got.shape[1]):
+            pod, nodes = views[view_of(i, j) if view_of else 0]
+            np.testing.assert_array_equal(
+                got[i, j],
+                np.asarray(vm.score(progs[prog_of(i, j)], pod, nodes)))
+
+
+def test_a_batched_slot_counter_takes_the_counted_fall_back():
+    """No runner batches the slot counter (it is the loop's own). If one
+    did, the rule cannot merge: it falls back to JAX's rules, the rows are
+    the reference's, and ``read_count`` says so. Program words of which
+    only SOME are per lane are merged all the same."""
+    rng = np.random.default_rng(6)
+    regs, w = _slot_case(vm.OP_ADD, rng)
+    words = [w[f][0] for f in ("opcode", "a", "b", "c", "imm")]
+    ks = jnp.asarray([0, 3, 1], jnp.int32)
+    before = vm.read_count()
+    got = jax.vmap(vm._slot_operands(0), in_axes=(None,) * 6 + (0,))(
+        regs, *words, ks)
+    assert _reads_since(before) == (0, 1)
+    for lane, k in enumerate(np.asarray(ks)):
+        want = vm._slot_operands(0)(regs, *words, int(k))
+        for g_, w_ in zip(got, want):
+            np.testing.assert_array_equal(np.asarray(g_[lane]),
+                                          np.asarray(w_))
+    before = vm.read_count()
+    some = jax.vmap(vm._slot_operands(0),
+                    in_axes=(None, None, 0, None, None, None, None))(
+        regs, words[0], w["a"], *words[2:], 1)
+    assert _reads_since(before) == (1, 0)
+    for lane in range(w["a"].shape[0]):
+        want = vm._slot_operands(0)(regs, words[0], w["a"][lane],
+                                    *words[2:], 1)
+        for g_, w_ in zip(some, want):
+            np.testing.assert_array_equal(np.asarray(g_[lane]),
+                                          np.asarray(w_))
 
 
 def test_a_per_lane_bound_would_be_caught():

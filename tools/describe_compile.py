@@ -22,6 +22,8 @@ from __future__ import annotations
 
 import argparse
 import collections
+import functools
+import math
 import os
 import re
 import sys
@@ -66,6 +68,7 @@ def compile_for(device, fn, *args, **jit_kw) -> str:
     return jax.jit(fn, **jit_kw).lower(*spec).compile().as_text()
 
 
+@functools.lru_cache(maxsize=4)
 def computations(hlo: str) -> dict:
     """name -> the lines of each computation of an HLO module's text."""
     out, name = {}, None
@@ -82,34 +85,67 @@ def computations(hlo: str) -> dict:
     return out
 
 
-def loop_body(hlo: str) -> list:
-    """The kernels of the module's largest ``while`` body: one dict an
-    instruction (``name``, ``op``, ``result``: the result's text, ``arrays``:
+def _kernels(lines) -> list:
+    """One dict an instruction of a computation's ``lines`` that is not
+    plumbing (``name``, ``op``, ``result``: the result's text, ``arrays``:
     ``(dtype, dims, minor_to_major)`` of each array in it, ``cycles``:
     ``estimated_cycles`` x ``iteration_bounds`` where the compiler gave
-    them, else 0)."""
+    them, else 0, ``calls``: the computation a fusion runs or a ``while``
+    repeats, else None)."""
+    rows = []
+    for line in lines:
+        m = _INSTR.match(line)
+        if not m or m.group(3) in PLUMBING:
+            continue
+        cyc = _CYCLES.search(line)
+        cycles = 0
+        if cyc:
+            cycles = int(cyc.group(1))
+            for it in re.findall(r"\d+", cyc.group(2)):
+                cycles *= int(it)
+        arrays = [(d, tuple(int(x) for x in s.split(",") if x),
+                   tuple(int(x) for x in l.split(",") if x))
+                  for d, s, l in _ARRAY.findall(m.group(2))]
+        calls = re.search(r"(?:calls|body)=%([^,\s}]+)", line)
+        rows.append(dict(name=m.group(1), op=m.group(3),
+                         result=m.group(2), arrays=arrays, cycles=cycles,
+                         calls=calls and calls.group(1)))
+    return rows
+
+
+def fused_ops(hlo: str, row: dict) -> list:
+    """The opcodes inside a kernel of `_kernels`: those of the computation
+    a fusion calls, or the instruction's own."""
+    lines = computations(hlo).get(row["calls"], ())
+    return [m.group(3) for m in map(_INSTR.match, lines) if m] or [row["op"]]
+
+
+def _while_bodies(hlo: str) -> dict:
+    """name -> `_kernels` of every ``while`` body of the module."""
     comps = computations(hlo)
-    bodies = set(re.findall(r"body=%([^,\s}]+)", hlo))
-    rows_of = {}
-    for b in bodies:
-        rows = []
-        for line in comps.get(b, ()):
-            m = _INSTR.match(line)
-            if not m or m.group(3) in PLUMBING:
-                continue
-            cyc = _CYCLES.search(line)
-            cycles = 0
-            if cyc:
-                cycles = int(cyc.group(1))
-                for it in re.findall(r"\d+", cyc.group(2)):
-                    cycles *= int(it)
-            arrays = [(d, tuple(int(x) for x in s.split(",") if x),
-                       tuple(int(x) for x in l.split(",") if x))
-                      for d, s, l in _ARRAY.findall(m.group(2))]
-            rows.append(dict(name=m.group(1), op=m.group(3),
-                             result=m.group(2), arrays=arrays, cycles=cycles))
-        rows_of[b] = rows
-    return max(rows_of.values(), key=len, default=[])
+    return {b: _kernels(comps.get(b, ()))
+            for b in set(re.findall(r"body=%([^,\s}]+)", hlo))}
+
+
+def loop_body(hlo: str) -> list:
+    """The kernels of the module's largest ``while`` body (`_kernels`):
+    the event loop of a benchmark cell's program."""
+    return max(_while_bodies(hlo).values(), key=len, default=[])
+
+
+def slot_loop(hlo: str) -> list:
+    """The kernels of the interpreter's op-slot loop: the body of the
+    ``while`` inside `loop_body` that carries the largest array, which is
+    the register file ``[lanes, rows, N, G]``. Empty where the event loop
+    holds no ``while`` (a program that interprets nothing)."""
+    bodies = _while_bodies(hlo)
+    inner = [r for r in max(bodies.values(), key=len, default=[])
+             if r["op"] == "while"]
+    if not inner:
+        return []
+    file_loop = max(inner, key=lambda r: max(
+        (math.prod(dims) for _, dims, _ in r["arrays"]), default=0))
+    return bodies[file_loop["calls"]]
 
 
 def operand_layouts(hlo: str, shape: tuple) -> collections.Counter:
@@ -204,16 +240,17 @@ def main(argv=None) -> int:
     if args.hlo:
         with open(args.hlo, "w") as f:
             f.write(hlo)
-    rows = loop_body(hlo)
-    for r in sorted(rows, key=lambda r: -r["cycles"]):
-        print(f"{r['cycles']:>9} {r['op']:<12} {r['name']:<34} "
-              f"{r['result'][:150]}")
-    ops = collections.Counter(r["op"] for r in rows)
-    total = sum(r["cycles"] for r in rows)
-    print(f"# {args.executable} on {device.device_kind}: {len(rows)} "
-          f"instructions in the loop body ("
-          + ", ".join(f"{v} {k}" for k, v in ops.most_common())
-          + f"); estimated_cycles x iteration_bounds {total}")
+    for title, rows in (("event loop", loop_body(hlo)),
+                        ("op-slot loop", slot_loop(hlo))):
+        for r in sorted(rows, key=lambda r: -r["cycles"]):
+            print(f"{r['cycles']:>9} {r['op']:<12} {r['name']:<34} "
+                  f"{r['result'][:150]}")
+        ops = collections.Counter(r["op"] for r in rows)
+        total = sum(r["cycles"] for r in rows)
+        print(f"# {args.executable} on {device.device_kind}: {len(rows)} "
+              f"instructions in the {title}'s body ("
+              + ", ".join(f"{v} {k}" for k, v in ops.most_common())
+              + f"); estimated_cycles x iteration_bounds {total}")
     return 0
 
 
