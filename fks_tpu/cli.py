@@ -141,15 +141,31 @@ def _parse_workload(args):
     parser = TraceParser()
     return parser, parser.parse_workload(
         node_file=args.nodes, pod_file=args.trace,
-        snapshot_file=getattr(args, "snapshot", "") or None)
+        snapshot_file=getattr(args, "snapshot", "") or None,
+        gpu_spec=getattr(args, "gpu_spec", "ignore"))
 
 
 def _add_trace_flags(p, snapshot=False):
+    """``--trace`` / ``--nodes``; ``snapshot``: the commands that evaluate
+    policies (bench, simulate, evolve) also take ``--snapshot`` and
+    ``--gpu-spec``."""
     p.add_argument("--trace", default="openb_pod_list_default.csv",
-                   help="pod CSV under benchmarks/traces/csv/")
+                   help="pod CSV under benchmarks/traces/csv/ (its "
+                        "gpu_spec column is read only with --gpu-spec "
+                        "honor)")
     p.add_argument("--nodes", default="gpu_models_filtered.csv",
                    help="node CSV under benchmarks/traces/csv/")
     if snapshot:
+        p.add_argument("--gpu-spec", choices=("ignore", "honor"),
+                       default="ignore",
+                       help="what to do with the pod list's gpu_spec "
+                            "column (the |-joined GPU models a pod "
+                            "accepts; OpenB's gpuspec* lists fill it). "
+                            "ignore (default): as upstream does. honor: a "
+                            "pod that names models is placed only on a "
+                            "node whose model (the node list's column) is "
+                            "among them; every other node is to it as a "
+                            "cordoned node is. Engines exact and flat")
         p.add_argument("--snapshot", default="",
                        help="snapshot CSV under benchmarks/traces/csv/ "
                             "(name,node_sn,gpus for a cluster loaded by "
@@ -1601,20 +1617,29 @@ def cmd_traces(args):
 
 
 #: the committed snapshots: file -> (node list, pod list, placing policy
-#: of the zoo, events, node_prefilter_k), each the flat engine's float32
-#: run of the policy for that many events from the empty cluster
+#: of the zoo, events, node_prefilter_k, what the parse does with
+#: gpu_spec), each the flat engine's float32 run of the policy for that
+#: many events from the empty cluster
 COMMITTED_SNAPSHOTS = {
     # the loaded cluster: the first 5,888 arrivals of the inflated list
     # (70 % of the cluster's GPUs) under the large-cluster rule; nobody
     # has left, nothing was refused
     "openb_snapshot_inflated080_e5888.csv.gz": (
         "openb_node_list_all_node.csv", "openb_pod_list_inflated080.csv",
-        "best_fit", 5888, 64),
+        "best_fit", 5888, 64, "ignore"),
     # a real trace mid-run: upstream's 16 nodes after 12,288 events of
     # cpu250 (5,618 departures, 1,002 refused placements, a pod waiting)
     "openb_snapshot_cpu250_firstfit_e12288.csv.gz": (
         "gpu_models_filtered.csv", "openb_pod_list_cpu250.csv",
-        "first_fit", 12288, 0),
+        "first_fit", 12288, 0, "ignore"),
+    # the production cluster under GPU-type constraints: the first 4,864
+    # events of the inflated gpuspec25 list (58 % of the cluster's GPUs;
+    # a quarter of the GPU pods name the models they accept) with the
+    # constraints honoured
+    "openb_snapshot_gpuspec25_inflated080_e4864.csv.gz": (
+        "openb_node_list_all_node.csv",
+        "openb_pod_list_gpuspec25_inflated080.csv", "best_fit", 4864, 64,
+        "honor"),
 }
 
 
@@ -1630,9 +1655,10 @@ def write_snapshot(path=None,
     from fks_tpu.sim import flat
     from fks_tpu.sim.engine import SimConfig
 
-    nodes, pods, policy, e0, k = COMMITTED_SNAPSHOTS[name]
+    nodes, pods, policy, e0, k, gpu_spec = COMMITTED_SNAPSHOTS[name]
     parser = TraceParser()
-    wl = parser.parse_workload(node_file=nodes, pod_file=pods)
+    wl = parser.parse_workload(node_file=nodes, pod_file=pods,
+                               gpu_spec=gpu_spec)
     snap = flat.make_snapshot(wl, zoo.ZOO[policy](), e0,
                               SimConfig(node_prefilter_k=k))
     if path is None:

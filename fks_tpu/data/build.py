@@ -10,14 +10,19 @@ from typing import Optional, Sequence
 
 import numpy as np
 
-from fks_tpu.data.entities import ClusterArrays, PodArrays, Workload
+from fks_tpu.data.entities import (
+    ClusterArrays, PodArrays, Workload, gpu_model_leaves, gpu_spec_bits)
 
 
 def make_cluster(nodes: Sequence[dict], pad_nodes_to: Optional[int] = None,
-                 pad_gpus_to: Optional[int] = None) -> ClusterArrays:
+                 pad_gpus_to: Optional[int] = None,
+                 gpu_models: bool = False) -> ClusterArrays:
     """nodes: dicts with node_id, cpu_milli, memory_mib, and either
     ``gpus`` (list of per-GPU milli capacities) or ``gpu_count`` +
-    ``gpu_milli_capacity``; optional ``gpu_memory_mib``, ``gpu_declared``."""
+    ``gpu_milli_capacity``; optional ``gpu_memory_mib``, ``gpu_declared``.
+    ``gpu_models``: keep each node's ``model`` (a name, empty or absent
+    for none) as the CSV parser does for a workload that honours
+    ``gpu_spec``."""
     n = len(nodes)
     n_pad = pad_nodes_to or max(1, n)
     caps = []
@@ -46,15 +51,21 @@ def make_cluster(nodes: Sequence[dict], pad_nodes_to: Optional[int] = None,
         gmem[i, :k] = spec.get("gpu_memory_mib", 0)
         gmask[i, :k] = True
         nmask[i] = True
+    typed = gpu_model_leaves((s.get("model") or "" for s in nodes),
+                             n_pad) if gpu_models else {}
     return ClusterArrays(
         cpu_total=cpu, mem_total=mem, gpu_declared=declared, num_gpus=num,
         gpu_milli_total=gmt, gpu_mem_total=gmem, gpu_mask=gmask,
-        node_mask=nmask, node_ids=tuple(s["node_id"] for s in nodes))
+        node_mask=nmask, node_ids=tuple(s["node_id"] for s in nodes),
+        **typed)
 
 
-def make_pods(pods: Sequence[dict], pad_pods_to: Optional[int] = None) -> PodArrays:
+def make_pods(pods: Sequence[dict], pad_pods_to: Optional[int] = None,
+              gpu_models: Optional[Sequence[str]] = None) -> PodArrays:
     """pods: dicts with pod_id, cpu_milli, memory_mib, num_gpu, gpu_milli,
-    creation_time, duration_time."""
+    creation_time, duration_time. ``gpu_models`` (the cluster's model
+    names): keep each pod's ``gpu_spec``, names joined by ``|`` or the
+    bit word itself (absent: any node); None ignores it."""
     p = len(pods)
     p_pad = pad_pods_to or max(1, p)
     arr = {k: np.zeros(p_pad, np.int32) for k in
@@ -73,11 +84,21 @@ def make_pods(pods: Sequence[dict], pad_pods_to: Optional[int] = None) -> PodArr
     rank = np.zeros(p_pad, np.int32)
     for r, i in enumerate(order):
         rank[i] = r
+    if gpu_models is not None:
+        vocab = tuple(gpu_models)
+        arr["gpu_spec"] = np.zeros(p_pad, np.int32)
+        for i, spec in enumerate(s.get("gpu_spec", 0) for s in pods):
+            arr["gpu_spec"][i] = spec if isinstance(spec, (int, np.integer)) \
+                else gpu_spec_bits(spec, vocab)
     return PodArrays(tie_rank=rank, pod_mask=mask, pod_ids=tuple(ids), **arr)
 
 
 def make_workload(nodes: Sequence[dict], pods: Sequence[dict],
-                  **pad) -> Workload:
-    return Workload(
-        cluster=make_cluster(nodes, pad.get("pad_nodes_to"), pad.get("pad_gpus_to")),
-        pods=make_pods(pods, pad.get("pad_pods_to")))
+                  gpu_spec: str = "ignore", **pad) -> Workload:
+    """``gpu_spec="honor"`` as ``TraceParser.parse_workload``'s."""
+    honor = gpu_spec == "honor"
+    cluster = make_cluster(nodes, pad.get("pad_nodes_to"),
+                           pad.get("pad_gpus_to"), gpu_models=honor)
+    return Workload(cluster=cluster, pods=make_pods(
+        pods, pad.get("pad_pods_to"),
+        gpu_models=cluster.gpu_models if honor else None))

@@ -23,6 +23,58 @@ import jax
 import numpy as np
 
 
+#: a pod's accepted GPU models are bits 0..30 of an int32; the sign bit
+#: stands for "a name that is no node's model" (it matches no node)
+MAX_GPU_MODELS = 31
+GPU_SPEC_NO_NODE = np.int32(-2 ** 31)
+
+
+def gpu_model_vocabulary(models) -> tuple:
+    """The sorted distinct non-empty model names of a node list."""
+    vocab = tuple(sorted({m for m in models if m}))
+    if len(vocab) > MAX_GPU_MODELS:
+        raise ValueError(
+            f"gpu_spec: the node list has {len(vocab)} GPU models; a pod's "
+            f"accepted set is an int32 bit word of at most {MAX_GPU_MODELS}")
+    return vocab
+
+
+def gpu_model_leaves(models, n_padded: int) -> dict:
+    """The cluster's two type fields from its nodes' model names (empty:
+    none), in node order: ``gpu_model`` (index into the vocabulary, -1
+    for none and for padding) and ``gpu_models`` (the vocabulary)."""
+    models = list(models)
+    vocab = gpu_model_vocabulary(models)
+    index = np.full(n_padded, -1, np.int32)
+    index[:len(models)] = [vocab.index(m) if m else -1 for m in models]
+    return dict(gpu_model=index, gpu_models=vocab)
+
+
+def gpu_spec_bits(spec: str, vocab) -> int:
+    """A ``gpu_spec`` (model names joined by ``|``; a repeated name means
+    nothing) as a bit word over ``vocab``. Empty: 0, any node. A name
+    outside ``vocab`` sets the sign bit only, so a spec of such names
+    alone allows no node."""
+    bits = 0
+    for name in filter(None, (spec or "").split("|")):
+        bits |= (1 << vocab.index(name)) if name in vocab \
+            else int(GPU_SPEC_NO_NODE)
+    return bits
+
+
+def gpu_spec_allows(spec, gpu_model, xp=np):
+    """THE rule of GPU-type constraints, elementwise over broadcastable
+    int32 arrays (``xp``: numpy on the host, ``jax.numpy`` in the
+    engines' step): may a pod whose accepted-model word is ``spec`` take
+    a node whose model index is ``gpu_model``? A word of 0 allows every
+    node; otherwise the node needs a model (``>= 0``) whose bit is set,
+    so a node without GPUs is in no set and a word of unknown names
+    alone (the sign bit) allows nothing."""
+    node_bit = xp.where(gpu_model >= 0,
+                        xp.int32(1) << xp.maximum(gpu_model, 0), 0)
+    return (spec == 0) | ((spec & node_bit) != 0)
+
+
 def _pytree_dataclass(cls):
     """Register a dataclass as a JAX pytree (all array fields are leaves)."""
     cls = dataclasses.dataclass(cls)
@@ -59,6 +111,12 @@ class ClusterArrays:
     gpu_mask: Any  # bool[N, G] which GPU slots exist
     node_mask: Any  # bool[N] which node slots are real
     node_ids: tuple = static_field(default=())  # host-side node names, real nodes only
+    # GPU-type constraints (a workload parsed to honour ``gpu_spec``): the
+    # node's model as an index into ``gpu_models``, -1 for a node without
+    # one (and for padding). None on every other workload: no leaf, and
+    # the engines then emit no type term (``sim.engine.place_mask_of``).
+    gpu_model: Any = None  # i32[N] | None
+    gpu_models: tuple = static_field(default=())  # the sorted model names
 
     @property
     def n_padded(self) -> int:
@@ -102,6 +160,10 @@ class PodArrays:
     tie_rank: Any  # i32[P]
     pod_mask: Any  # bool[P]
     pod_ids: tuple = static_field(default=())  # host-side pod names, real pods only
+    # the GPU models a pod accepts as a bit word over the cluster's
+    # ``gpu_models`` (bit m = model m), 0 = any, ``GPU_SPEC_NO_NODE`` alone
+    # = names only models no node has; None where ``gpu_spec`` is ignored
+    gpu_spec: Any = None  # i32[P] | None
 
     @property
     def p_padded(self) -> int:
@@ -148,13 +210,21 @@ class Workload:
     starts empty or a ``fks_tpu.data.snapshot.Snapshot``: what the first
     ``E0`` events of a run decided, from which an engine's
     ``initial_state`` forks (no program reads it: it only shapes the
-    initial carry).
+    initial carry). GPU-type constraints are data too: ``typed`` says
+    whether the workload was parsed to honour ``gpu_spec`` (the nodes'
+    models AND the pods' accepted sets are there), and nothing else
+    switches the engines' type term on.
     """
 
     cluster: ClusterArrays
     pods: PodArrays
     faults: Any = None
     snapshot: Any = None
+
+    @property
+    def typed(self) -> bool:
+        return (self.cluster.gpu_model is not None
+                and self.pods.gpu_spec is not None)
 
     @property
     def num_nodes(self) -> int:

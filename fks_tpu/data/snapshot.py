@@ -67,7 +67,8 @@ from typing import Any, NamedTuple
 
 import numpy as np
 
-from fks_tpu.data.entities import Workload, _pytree_dataclass, static_field
+from fks_tpu.data.entities import (
+    Workload, _pytree_dataclass, gpu_spec_allows, static_field)
 from fks_tpu.data.traces import TraceParser
 
 SNAPSHOT_COLUMNS = ("name", "node_sn", "gpus")
@@ -149,7 +150,9 @@ def gpu_slots(snap: Snapshot, g: int) -> np.ndarray:
 
 def _check_rows(workload: Workload, snap: Snapshot) -> None:
     """What a row says by itself: a pod, a node and GPUs that exist, as
-    many GPUs as the pod asks for, events in order below ``e0``."""
+    many GPUs as the pod asks for, a node of a GPU model the pod accepts
+    (where the workload honours ``gpu_spec``), events in order below
+    ``e0``."""
     c, p = workload.cluster, workload.pods
     pod = np.asarray(snap.pod, np.int64)
     node = np.asarray(snap.node, np.int64)
@@ -193,12 +196,26 @@ def _check_rows(workload: Workload, snap: Snapshot) -> None:
         raise ValueError(
             f"snapshot: pod {_name(p.pod_ids, int(pod[i]))} asks for "
             f"{int(ngpu[i])} GPUs and holds {int(sel[i].sum())}")
+    if workload.typed:
+        # a workload that honours gpu_spec: the engines would never
+        # have made such a placement, so the log is no run of it
+        forbidden = placed & ~gpu_spec_allows(
+            np.asarray(p.gpu_spec, np.int32)[pod],
+            np.asarray(c.gpu_model, np.int32)[node])
+        if forbidden.any():
+            i = int(np.argmax(forbidden))
+            raise ValueError(
+                f"snapshot: pod {_name(p.pod_ids, int(pod[i]))} sits on "
+                f"node {_name(c.node_ids, int(node[i]))}, whose GPU model "
+                f"its gpu_spec does not name: a placement the workload's "
+                "type constraints forbid")
 
 
 def replay(workload: Workload, snap: Snapshot) -> Prefix:
     """Run the first ``e0`` events of ``workload`` with ``snap`` deciding
     every CREATE attempt: the whole of the validation (``ValueError`` for
-    a row that names what does not exist, another pod at an attempt than
+    a row that names what does not exist, a pod on a node its
+    ``gpu_spec`` forbids, another pod at an attempt than
     the logged one, an infeasible placement, a log that ends before or
     after event ``e0``, a run that ends before it) and the state those
     events leave. One pass in event order, the cluster's sums kept as

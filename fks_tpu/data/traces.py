@@ -19,6 +19,15 @@ Differences (deliberate):
 - Traces missing optional columns (creation/deletion times, gpu_spec -- e.g.
   the multigpu* traces, which the reference parser crashes on) parse with
   defaults of 0.
+- ``gpu_spec`` (the ``|``-joined GPU models a pod accepts; OpenB's
+  ``gpuspec*`` lists fill it) is IGNORED by default, as upstream ignores
+  it. ``parse_workload(..., gpu_spec="honor")`` keeps it: the cluster then
+  carries each node's ``model`` as an index into the node list's sorted
+  model names (``ClusterArrays.gpu_model`` / ``gpu_models``) and the pods
+  the accepted set as a bit word (``PodArrays.gpu_spec``), and the engines
+  place such a pod only on a node whose model is in its set
+  (``fks_tpu.sim.engine.place_mask_of``). Parsed without the choice a
+  workload has neither leaf and compiles to the programs it always had.
 - Output is numpy struct-of-arrays (see fks_tpu.data.entities), padded to
   caller-chosen sizes.
 """
@@ -30,11 +39,15 @@ import gzip
 import io
 import json
 from pathlib import Path
-from typing import Dict, List, Optional
+from typing import Dict, List, Optional, Sequence
 
 import numpy as np
 
-from fks_tpu.data.entities import ClusterArrays, PodArrays, Workload
+from fks_tpu.data.entities import (
+    ClusterArrays, PodArrays, Workload, gpu_model_leaves, gpu_spec_bits)
+
+#: what ``parse_workload`` may do with the pod lists' ``gpu_spec`` column
+GPU_SPEC_CHOICES = ("ignore", "honor")
 
 def default_traces_dir() -> Path:
     """benchmarks/traces next to the package root (source checkout), falling
@@ -176,10 +189,15 @@ class TraceParser:
     # ---------------------------------------------------------------- nodes
     def parse_cluster(self, node_file: str = "openb_node_list_gpu_node.csv",
                       pad_nodes_to: Optional[int] = None,
-                      pad_gpus_to: Optional[int] = None) -> ClusterArrays:
+                      pad_gpus_to: Optional[int] = None,
+                      gpu_models: bool = False) -> ClusterArrays:
+        """``gpu_models``: keep each node's ``model`` (``gpu_model``, an
+        index into the sorted names, -1 where the column is empty) for a
+        workload that honours ``gpu_spec``."""
         rows = self._read_csv(self.csv_dir / node_file)
         node_ids: List[str] = []
         cpu, mem, declared, materialized, gpu_mem = [], [], [], [], []
+        models = [row.get("model", "") for row in rows]
         for row in rows:
             node_ids.append(row["sn"])
             cpu.append(int(row["cpu_milli"]))
@@ -218,6 +236,8 @@ class TraceParser:
         node_mask = np.zeros(n_pad, dtype=bool)
         node_mask[:n] = True
 
+        typed = gpu_model_leaves(models, n_pad) if gpu_models else {}
+
         return ClusterArrays(
             cpu_total=vec(cpu),
             mem_total=vec(mem),
@@ -228,13 +248,21 @@ class TraceParser:
             gpu_mask=gpu_mask,
             node_mask=node_mask,
             node_ids=tuple(node_ids),
+            **typed,
         )
 
     # ----------------------------------------------------------------- pods
     def parse_pods(self, pod_file: str = "openb_pod_list_default.csv",
-                   pad_pods_to: Optional[int] = None) -> PodArrays:
+                   pad_pods_to: Optional[int] = None,
+                   gpu_models: Optional[Sequence[str]] = None) -> PodArrays:
+        """``gpu_models``: the cluster's model names
+        (``ClusterArrays.gpu_models``) to read ``gpu_spec`` against; None,
+        the default, ignores the column as upstream does."""
         rows = self._read_csv(self.csv_dir / pod_file)
         ids, cpu, mem, ngpu, gmilli, ctime, dur = [], [], [], [], [], [], []
+        vocab = None if gpu_models is None else tuple(gpu_models)
+        spec = None if vocab is None else [
+            gpu_spec_bits(row.get("gpu_spec") or "", vocab) for row in rows]
         for row in rows:
             ids.append(row["name"])
             cpu.append(int(row["cpu_milli"]))
@@ -270,6 +298,7 @@ class TraceParser:
             cpu=vec(cpu), mem=vec(mem), num_gpu=vec(ngpu), gpu_milli=vec(gmilli),
             creation_time=vec(ctime), duration=vec(dur), tie_rank=rank,
             pod_mask=pod_mask, pod_ids=tuple(ids),
+            gpu_spec=None if spec is None else vec(spec),
         )
 
     # ------------------------------------------------------------- combined
@@ -278,14 +307,24 @@ class TraceParser:
                        pad_nodes_to: Optional[int] = None,
                        pad_gpus_to: Optional[int] = None,
                        pad_pods_to: Optional[int] = None,
-                       snapshot_file: Optional[str] = None) -> Workload:
+                       snapshot_file: Optional[str] = None,
+                       gpu_spec: str = "ignore") -> Workload:
         """Defaults match the reference benchmark workload (parser.py:117-118).
+        ``gpu_spec="honor"`` keeps the pods' GPU-type constraints and the
+        nodes' models (module docstring); the default ignores them.
         ``snapshot_file`` (a CSV beside the traces,
         ``fks_tpu.data.snapshot``) pins a moment of its run: an engine
         then starts after the snapshot's events. An invalid snapshot
         raises ``ValueError`` here."""
-        cluster = self.parse_cluster(node_file, pad_nodes_to, pad_gpus_to)
-        pods = self.parse_pods(pod_file, pad_pods_to)
+        if gpu_spec not in GPU_SPEC_CHOICES:
+            raise ValueError(f"gpu_spec: {gpu_spec!r} is none of "
+                             f"{GPU_SPEC_CHOICES}")
+        honor = gpu_spec == "honor"
+        cluster = self.parse_cluster(node_file, pad_nodes_to, pad_gpus_to,
+                                     gpu_models=honor)
+        pods = self.parse_pods(pod_file, pad_pods_to,
+                               gpu_models=cluster.gpu_models if honor
+                               else None)
         wl = Workload(cluster=cluster, pods=pods)
         if snapshot_file:
             from fks_tpu.data.snapshot import load_snapshot

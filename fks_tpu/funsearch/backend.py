@@ -199,6 +199,16 @@ class CodeEvaluator:
                 "tiers are not wired to the exact engine's fork, which "
                 "serving uses; ROADMAP, Reach); use engine='flat'")
         self.start_event = 0 if snap is None else snap.e0
+        #: GPU-type constraints of the workload, as the spans report them:
+        #: pods that name the GPU models they accept (0 where the
+        #: workload was parsed without ``gpu_spec='honor'``) and the size
+        #: of the cluster's model vocabulary
+        self.typed_fields = dict(
+            typed_pods=int(np.count_nonzero(
+                np.asarray(workload.pods.gpu_spec)[
+                    np.asarray(workload.pods.pod_mask)]))
+            if workload.typed else 0,
+            node_models=len(workload.cluster.gpu_models))
         #: failed placements among the snapshot's events: in every
         #: result's whole-run count, and no work of the policy's
         self.fork_failed = 0
@@ -206,7 +216,7 @@ class CodeEvaluator:
             self.state0 = self._mod.initial_state(workload, cfg)
         else:
             with obs.span("tier/fork_state", start_event=snap.e0,
-                          rule=snap.rule) as sp:
+                          rule=snap.rule, **self.typed_fields) as sp:
                 self.state0 = jax.block_until_ready(
                     self._mod.initial_state(workload, cfg))
                 counts = self._mod.fork_counts(workload, self.state0)
@@ -641,7 +651,7 @@ class CodeEvaluator:
         stages are spans whether the profiler is enabled or not.
         """
         with obs.span("tier/evaluate", candidates=len(codes),
-                      start_event=self.start_event):
+                      start_event=self.start_event, **self.typed_fields):
             return self._evaluate(codes)
 
     def _evaluate(self, codes: Sequence[str]) -> List[EvalRecord]:

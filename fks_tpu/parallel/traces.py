@@ -45,12 +45,13 @@ def strip_ids(wl: Workload) -> Workload:
         cluster=ClusterArrays(**{
             **{f: getattr(wl.cluster, f) for f in (
                 "cpu_total", "mem_total", "gpu_declared", "num_gpus",
-                "gpu_milli_total", "gpu_mem_total", "gpu_mask", "node_mask")},
+                "gpu_milli_total", "gpu_mem_total", "gpu_mask", "node_mask",
+                "gpu_model", "gpu_models")},
             "node_ids": ()}),
         pods=PodArrays(**{
             **{f: getattr(wl.pods, f) for f in (
                 "cpu", "mem", "num_gpu", "gpu_milli", "creation_time",
-                "duration", "tie_rank", "pod_mask")},
+                "duration", "tie_rank", "pod_mask", "gpu_spec")},
             "pod_ids": ()}),
         faults=wl.faults)
 

@@ -70,6 +70,12 @@ class PortfolioEngine(VMServeEngine):
                 "snapshot: serving forks one champion an engine "
                 "(VMServeEngine); the portfolio's slot-table executables "
                 "are not built from a fork")
+        if workload.typed:
+            raise ValueError(
+                "gpu_spec: the portfolio serves queries whose pods carry no "
+                "GPU-type constraints (the query schema has no gpu_spec); "
+                "a workload parsed with gpu_spec='honor' would be answered "
+                "as if it had none. Parse it without the choice")
         self.n_slots = int(n_slots) if n_slots else len(champions)
         if self.n_slots < len(champions):
             raise ValueError(

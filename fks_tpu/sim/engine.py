@@ -47,7 +47,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from fks_tpu.data.entities import ClusterArrays, PodArrays, Workload
+from fks_tpu.data.entities import (
+    ClusterArrays, PodArrays, Workload, gpu_spec_allows)
 from fks_tpu.ops.allocator import best_fit_gpus, first_fit_gpus
 from fks_tpu.ops.heap import (
     KIND_CREATE, KIND_DELETE, KIND_NODE_DOWN, KIND_NODE_UP, EventHeap,
@@ -435,12 +436,58 @@ def _node_view(c: ClusterArrays, cpu_left, mem_left, gpu_left, gpu_milli_left):
     )
 
 
+def place_mask_of(c: ClusterArrays, node_avail, spec):
+    """The nodes a CREATE may be placed on, whatever the policy scores:
+    real nodes, not cordoned (``node_avail``, None on a fault-free
+    workload), of a GPU model the pod accepts (``spec``, the pod's
+    ``gpu_spec`` word, None on a workload without type constraints; the
+    rule is ``fks_tpu.data.entities.gpu_spec_allows``, which the snapshot
+    replay holds a log to on the host, and its node side is loop
+    invariant). Every term beyond ``node_mask``
+    is emitted only where the workload has the leaves for it. The ONE
+    mask of both engines: it feeds ``_prefilter_candidates`` and the
+    score gate, and nothing else reads it (GPU picks, utilization,
+    fragmentation and the retry rule know nothing of cordons or types)."""
+    mask = c.node_mask
+    if node_avail is not None:
+        mask = mask & node_avail
+    if spec is not None:
+        mask = mask & gpu_spec_allows(spec, c.gpu_model, jnp)
+    return mask
+
+
+def place_mask_at(c: ClusterArrays, place_mask, node_avail, spec, cand):
+    """``place_mask[cand]``: the mask re-read through the candidate
+    gather (``place_mask`` is ``place_mask_of(c, node_avail, spec)``).
+    On a typed workload the type term is evaluated AT the candidates,
+    from the cluster's own model vector (``c.gpu_model[cand]``: a gather
+    from a lane-invariant operand, as every constant ``NodeView`` leaf's
+    is), not gathered from the per-lane ``[N]`` mask: under the
+    population ``vmap`` that gather has a batched operand, and on the
+    v5e its flattened ``[lanes * k]`` form re-laid ``cand`` for every
+    gather that shares it (seven reshapes a step, 25.6 us an event of
+    0.71 ms; PERF.md section 6, PR 45). Equal by construction: both are
+    ``gpu_spec_allows`` of the same word and the same node's model."""
+    if spec is None:
+        return place_mask[cand]
+    return (place_mask_of(c, node_avail, None)[cand]
+            & gpu_spec_allows(spec, c.gpu_model[cand], jnp))
+
+
+#: the column of the engines' pod feature table (one ``[8]`` row a pod,
+#: read by the one gather of the popped pod's request) that holds the
+#: ``gpu_spec`` word on a typed workload; zeros, as always, on any other
+FEAT_GPU_SPEC = 5
+
+
 def _prefilter_candidates(pod: PodView, nodes: NodeView, place_mask, k: int):
     """Top-k candidate nodes for one creation event (SimConfig
     ``node_prefilter_k``): rank every node by a cheap static feasibility
     test — the same free CPU/mem/GPU-count/GPU-milli fit the zoo policies
-    gate on (fks_tpu.models.zoo.feasible_mask), under ``place_mask`` so a
-    cordoned or padding node can NEVER enter a candidate slot — and keep
+    gate on (fks_tpu.models.zoo.feasible_mask), under ``place_mask``
+    (``place_mask_of``: real, not cordoned, of a GPU model the pod
+    accepts) so a cordoned, forbidden or padding node can NEVER enter a
+    candidate slot — and keep
     the k best, i.e. the first k FEASIBLE nodes in ascending global
     index: argmax over the gathered view then preserves the dense sweep's
     lowest-index tie rule exactly. Selection is a cumsum + one-hot argmax
@@ -530,11 +577,14 @@ def build_step(workload: Workload, policy: PolicyFn, cfg: SimConfig,
     # cost serialized latency per INSTRUCTION under vmap; PROFILE.md).
     # Padded 5 -> 8 columns: power-of-two rows keep the gather's slice
     # aligned to the TPU lane tiling (same layout as flat.py's table).
+    # Python-static gating (like watchdog/decision_trace): a workload
+    # without GPU-type constraints, or without faults, compiles to the
+    # exact program it had before either existed.
+    has_types = workload.typed
     feat = jnp.stack([p.cpu, p.mem, p.num_gpu, p.gpu_milli, p.duration,
-                      jnp.zeros_like(p.cpu), jnp.zeros_like(p.cpu),
+                      p.gpu_spec if has_types else jnp.zeros_like(p.cpu),
+                      jnp.zeros_like(p.cpu),
                       jnp.zeros_like(p.cpu)], axis=-1).astype(jnp.int32)
-    # Python-static fault gating (like watchdog/decision_trace): fault-free
-    # workloads compile to the exact pre-scenario program.
     has_faults = workload.faults is not None
     # large-cluster scale tier: 0 = dense sweep (bit-identical program)
     prefilter_k = cfg.resolve_prefilter_k(n)
@@ -585,11 +635,13 @@ def build_step(workload: Workload, policy: PolicyFn, cfg: SimConfig,
         # ---- CREATION: score every node, strict argmax (main.py:101-111)
         pod_view = PodView(pcpu, pmem, pngpu, pmilli, pod_ct, pdur)
         node_view = _node_view(c, cpu_left, mem_left, gpu_left, gpu_milli_left)
+        spec = pf[FEAT_GPU_SPEC] if has_types else None
         if prefilter_k:
-            # a cordoned (downed) node scores 0 until NODE_UP — under the
-            # prefilter it must also never outrank a feasible candidate,
-            # so the cordon mask feeds the ranking itself
-            place_mask = c.node_mask & node_avail if has_faults else c.node_mask
+            # a cordoned (downed) node scores 0 until NODE_UP, and so
+            # does, for this pod, a node whose GPU model its gpu_spec does
+            # not name — under the prefilter neither may outrank a
+            # feasible candidate, so the mask feeds the ranking itself
+            place_mask = place_mask_of(c, node_avail, spec)
             cand = _prefilter_candidates(
                 pod_view, node_view, place_mask, prefilter_k)
             node_view = _gather_node_view(node_view, cand)
@@ -604,12 +656,15 @@ def build_step(workload: Workload, policy: PolicyFn, cfg: SimConfig,
             raw_scores, create, s.numeric_flags, enabled=cfg.watchdog)
         if prefilter_k:
             # re-mask through the gather: when fewer than k nodes are
-            # feasible the candidate tail is padding (cordoned nodes
-            # included) — zero those slots whatever the policy scored
-            scores = jnp.where(place_mask[cand], raw_scores, 0)
+            # feasible the candidate tail is padding (cordoned and
+            # forbidden nodes included) — zero those slots whatever the
+            # policy scored
+            scores = jnp.where(
+                place_mask_at(c, place_mask, node_avail, spec, cand),
+                raw_scores, 0)
         else:
-            # a cordoned (downed) node scores 0 — "cannot/refuse" — until NODE_UP
-            place_mask = c.node_mask & node_avail if has_faults else c.node_mask
+            # a cordoned or forbidden node scores 0 — "cannot/refuse"
+            place_mask = place_mask_of(c, node_avail, spec)
             scores = jnp.where(place_mask, raw_scores, 0)
         # wk indexes the scored view ([k] candidates or [N] nodes);
         # b is always the GLOBAL node index (gather-back through cand)

@@ -78,9 +78,10 @@ from fks_tpu.data.entities import Workload
 from fks_tpu.ops.allocator import best_fit_gpus, first_fit_gpus
 from fks_tpu.ops.heap import KIND_NODE_UP
 from fks_tpu.sim.engine import (
-    PREFILTER_MIN_NODES, SimConfig, _audit, _gather_node_view, _node_view,
-    _prefilter_candidates, _trace_append, _widest_int, finalize_fields,
-    fork_leaves, fork_prefix, loop_tables, run_batched_lanes,
+    FEAT_GPU_SPEC, PREFILTER_MIN_NODES, SimConfig, _audit,
+    _gather_node_view, _node_view, _prefilter_candidates, _trace_append,
+    _widest_int, finalize_fields, fork_leaves, fork_prefix, loop_tables,
+    place_mask_at, place_mask_of, run_batched_lanes,
 )
 from fks_tpu.sim.guards import guard_scores
 from fks_tpu.sim.types import FlatState, PodView, PolicyFn, SimResult, empty_trace
@@ -241,19 +242,25 @@ def _loaded_leaves(workload: Workload, cfg: SimConfig, perm, dt: dict) -> dict:
 def fork_counts(workload: Workload, state: FlatState) -> dict:
     """What a forked carry holds, read off its slots (the fields of the
     ``tier/fork_state`` span): pods placed and not gone, the nodes they
-    sit on, pods gone, waiting pods whose retry is queued, and the failed
-    placements among the events."""
-    c = workload.cluster
+    sit on, pods gone, waiting pods whose retry is queued (and those of
+    them that carry a GPU-type constraint), and the failed placements
+    among the events."""
+    c, p = workload.cluster, workload.pods
     aux = np.asarray(state.aux, np.int64)
     queued = np.asarray(state.ev_time) < INF
     placed = aux >= 0
     on = aux[placed & queued]
     if _packable(c.n_padded, c.g_padded):
         on = on >> c.g_padded
+    waiting = (aux == AUX_WAITING) & queued
+    spec = np.asarray(p.gpu_spec)[_rank_perm(
+        np.asarray(p.pod_mask), np.asarray(p.tie_rank))] \
+        if workload.typed else np.zeros(len(aux), np.int32)
     return dict(residents=int((placed & queued).sum()),
                 nodes_loaded=int(len(np.unique(on))),
                 departed=int((placed & ~queued).sum()),
-                waiting=int(((aux == AUX_WAITING) & queued).sum()),
+                waiting=int(waiting.sum()),
+                typed_waiting=int((waiting & (spec != 0)).sum()),
                 prefix_failed=int(state.frag_count))
 
 
@@ -317,9 +324,14 @@ def build_step(workload: Workload, policy: PolicyFn, cfg: SimConfig,
     # pod features permuted into slot (tie-rank) order, packed into one
     # gather table so the pop costs a single [8]-row read
     perm = _rank_perm(p.pod_mask, p.tie_rank)
+    # Python-static gating, as has_faults below: the gpu_spec word rides
+    # the row only where the workload is typed (engine.FEAT_GPU_SPEC)
+    has_types = workload.typed
     feat = jnp.stack([
         p.cpu[perm], p.mem[perm], p.num_gpu[perm], p.gpu_milli[perm],
-        p.duration[perm], jnp.zeros(pp, jnp.int32), jnp.zeros(pp, jnp.int32),
+        p.duration[perm],
+        p.gpu_spec[perm] if has_types else jnp.zeros(pp, jnp.int32),
+        jnp.zeros(pp, jnp.int32),
         jnp.zeros(pp, jnp.int32)], axis=-1).astype(jnp.int32)  # [Q, 8]
     if cfg.validate_invariants:
         import dataclasses as _dc
@@ -327,7 +339,8 @@ def build_step(workload: Workload, policy: PolicyFn, cfg: SimConfig,
             p, cpu=p.cpu[perm], mem=p.mem[perm], num_gpu=p.num_gpu[perm],
             gpu_milli=p.gpu_milli[perm], creation_time=p.creation_time[perm],
             duration=p.duration[perm], tie_rank=p.tie_rank[perm],
-            pod_mask=p.pod_mask[perm])
+            pod_mask=p.pod_mask[perm],
+            gpu_spec=p.gpu_spec[perm] if has_types else None)
 
     # Python-static fault gating (like watchdog/decision_trace): fault-free
     # workloads compile to the exact pre-scenario program.
@@ -402,11 +415,13 @@ def build_step(workload: Workload, policy: PolicyFn, cfg: SimConfig,
         # it always equals the event time).
         pod_view = PodView(pcpu, pmem, pngpu, pmilli, t, pdur)
         node_view = _node_view(c, cpu_left, mem_left, gpu_left, gpu_milli_left)
+        spec = pf[FEAT_GPU_SPEC] if has_types else None
         if prefilter_k:
-            # a cordoned (downed) node scores 0 until NODE_UP — under the
-            # prefilter it must also never outrank a feasible candidate,
-            # so the cordon mask feeds the ranking itself
-            place_mask = c.node_mask & node_avail if has_faults else c.node_mask
+            # a cordoned (downed) node scores 0 until NODE_UP, and so
+            # does, for this pod, a node whose GPU model its gpu_spec does
+            # not name — under the prefilter neither may outrank a
+            # feasible candidate, so the mask feeds the ranking itself
+            place_mask = place_mask_of(c, node_avail, spec)
             cand = _prefilter_candidates(
                 pod_view, node_view, place_mask, prefilter_k)
             node_view = _gather_node_view(node_view, cand)
@@ -421,12 +436,15 @@ def build_step(workload: Workload, policy: PolicyFn, cfg: SimConfig,
             raw_scores, create, s.numeric_flags, enabled=cfg.watchdog)
         if prefilter_k:
             # re-mask through the gather: when fewer than k nodes are
-            # feasible the candidate tail is padding (cordoned nodes
-            # included) — zero those slots whatever the policy scored
-            scores = jnp.where(place_mask[cand], raw_scores, 0)
+            # feasible the candidate tail is padding (cordoned and
+            # forbidden nodes included) — zero those slots whatever the
+            # policy scored
+            scores = jnp.where(
+                place_mask_at(c, place_mask, node_avail, spec, cand),
+                raw_scores, 0)
         else:
-            # a cordoned (downed) node scores 0 — "cannot/refuse" — until NODE_UP
-            place_mask = c.node_mask & node_avail if has_faults else c.node_mask
+            # a cordoned or forbidden node scores 0 — "cannot/refuse"
+            place_mask = place_mask_of(c, node_avail, spec)
             scores = jnp.where(place_mask, raw_scores, 0)
         # wk indexes the scored view ([k] candidates or [N] nodes);
         # w is always the GLOBAL node index (gather-back through cand)

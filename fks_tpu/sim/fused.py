@@ -123,6 +123,12 @@ def _build_plan(workload: Workload, cfg: SimConfig) -> _Plan:
         raise ValueError(
             "snapshot: flat engine only (the fused kernel builds its own "
             "initial state); use engine='flat'")
+    if workload.typed:
+        raise ValueError(
+            "gpu_spec: GPU-type constraints are not supported in the fused "
+            "kernel (its fixed-function sweep has no per-pod node mask); "
+            "use engine='flat' or 'exact', or parse the workload without "
+            "gpu_spec='honor'")
     if not _packable(n, g):
         raise ValueError("fused kernel needs packed aux (node_bits+G<=31); "
                          "use the XLA flat engine")

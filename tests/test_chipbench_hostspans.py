@@ -24,7 +24,8 @@ REPO = pathlib.Path(__file__).resolve().parent.parent
 CODE = ["openb16.codegen8", "openb16.codegen8x4",
         "openb1523-inflated.codegen8", "openb1523-loaded.codegen8",
         # appended by PR 42, with its cell
-        "openb16-cpu250-midrun.codegen8"]
+        "openb16-cpu250-midrun.codegen8",
+        "openb1523-gpuspec25-loaded.codegen8"]
 WHATIF = ["openb1523.whatif8", "openb1523-loaded.whatif8"]
 TIER = "candidate tiers funsearch/backend.py"
 METRICS = {
@@ -232,12 +233,12 @@ def test_without_a_ring_or_a_selection_every_reader_returns_none(
 def test_the_seven_are_declared_at_the_end_with_their_files():
     bench = json.load(open(os.path.join(cells.ROOT, "BENCHMARK.json")))
     # at the end as PR 40 left it; PR 42 appended its cell's two after,
-    # PR 44 the interpreter's merged-read share
+    # PR 44 the interpreter's merged-read share, PR 45 the typed pods'
     seven = bench["per_layer"][41:41 + 7]
     assert [m["name"] for m in seven] == list(METRICS)
     assert [m["name"] for m in bench["per_layer"][41 + 7:]] == [
         "sim.fork_replay_us_per_event", "sim.fork_waiting_pods",
-        "vm.merged_read_share"]
+        "vm.merged_read_share", "sim.typed_pod_share"]
     layers = {m["layer"] for m in bench["per_layer"][:41]}
     for m in seven:
         unit, source, layer, workloads = METRICS[m["name"]]

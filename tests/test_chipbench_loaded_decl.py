@@ -85,7 +85,8 @@ def test_benchmark_json_only_gained_entries():
     assert names[at:at + 2] == ["sim.retry_share", "sim.fork_state_ms"]
     own = bench["per_layer"][at:at + 2]
     others = bench["per_layer"][:at] + bench["per_layer"][at + 2:]
-    later = ("openb1523-loaded.whatif8", "openb16-cpu250-midrun.codegen8")
+    later = ("openb1523-loaded.whatif8", "openb16-cpu250-midrun.codegen8",
+             "openb1523-gpuspec25-loaded.codegen8")
     for m in bench["end_to_end"] + others:
         lists = m.get("workloads", [])
         assert (CELL in lists) == (
@@ -93,8 +94,8 @@ def test_benchmark_json_only_gained_entries():
             or m["name"] in ("tier.host_share", "vm.ms_per_event"))
         if CELL in lists:      # last of the cells there were at PR 31
             assert [w for w in lists if w not in later][-1] == CELL
-    for m in own:       # PR 42's forked cell reads both too
-        assert m["workloads"] == [CELL, later[1]]
+    for m in own:       # PR 42's and PR 45's forked cells read both too
+        assert m["workloads"] == [CELL, later[1], later[2]]
         assert m["layer"] == "engines sim/flat.py"
 
 

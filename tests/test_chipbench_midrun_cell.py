@@ -72,29 +72,32 @@ def test_the_cell_is_declared_with_its_files():
 def test_benchmark_json_only_gained_entries():
     with open(os.path.join(cells.ROOT, "BENCHMARK.json")) as f:
         bench = json.load(f)
-    assert bench["configs"][-1] == {
+    # in the place PR 42 gave them (PR 45 appended after them)
+    assert bench["configs"][5] == {
         "name": "openb16-cpu250-midrun",
         "source": cells.load_cell(CELL).config["source"],
         "file": "chipbench/configs/openb16-cpu250-midrun.json",
         "reduced": ["code_eval_max_steps"],
-        "why": bench["configs"][-1]["why"]}
-    assert bench["workloads"][-1] == {
+        "why": bench["configs"][5]["why"]}
+    assert bench["workloads"][7] == {
         "name": CELL, "config": "openb16-cpu250-midrun",
         "traffic": "codegen8-midrun", "chips": 1,
-        "why": bench["workloads"][-1]["why"]}
-    for text in (bench["configs"][-1]["why"], bench["configs"][-1]["source"],
-                 bench["workloads"][-1]["why"]):
+        "why": bench["workloads"][7]["why"]}
+    for text in (bench["configs"][5]["why"], bench["configs"][5]["source"],
+                 bench["workloads"][7]["why"]):
         assert len(text) <= 200
-    assert len(bench["workloads"]) == 8 and len(bench["configs"]) == 6
+    assert len(bench["workloads"]) == 9 and len(bench["configs"]) == 7
     assert sum(w["chips"] == 4 for w in bench["workloads"]) == 1
     at = [m["name"] for m in bench["per_layer"]].index(NEW[0])
     new = bench["per_layer"][at:at + 2]
     assert [m["name"] for m in new] == list(NEW)
-    # appended since, at the end: PR 44's metric of the code cells
+    # appended since, at the end: PR 44's metric of the code cells and
+    # PR 45's of the typed cell
     assert [m["name"] for m in bench["per_layer"][at + 2:]] == [
-        "vm.merged_read_share"]
+        "vm.merged_read_share", "sim.typed_pod_share"]
+    later = "openb1523-gpuspec25-loaded.codegen8"   # PR 45's forked cell
     for m in new:
-        assert m["workloads"] == [CELL]
+        assert m["workloads"] == [CELL, later]
         assert m["layer"] == "engines sim/flat.py"
         meta = json.load(open(os.path.join(cells.HERE, "metrics",
                                            m["name"] + ".json")))
@@ -107,8 +110,8 @@ def test_benchmark_json_only_gained_entries():
             continue
         lists = m.get("workloads", [])
         assert (CELL in lists) == (FORKED in lists), m["name"]
-        if CELL in lists:
-            assert lists[-1] == CELL
+        if CELL in lists:   # last of the cells there were at PR 42
+            assert [w for w in lists if w != later][-1] == CELL
 
 
 def test_new_readers_find_nothing_in_a_program_without_their_fields():

@@ -326,8 +326,8 @@ def test_every_span_metric_is_declared_with_its_files():
     # + vm.scatter_write_share (PR 35), serve.fork_state_ms and
     # serve.retry_share (PR 37), tier.pooled_source_share (PR 39),
     # sim.fork_replay_us_per_event and sim.fork_waiting_pods (PR 42),
-    # vm.merged_read_share (PR 44)
-    assert len(SPAN_METRICS) == 30
+    # vm.merged_read_share (PR 44), sim.typed_pod_share (PR 45)
+    assert len(SPAN_METRICS) == 31
     for name in SPAN_METRICS:
         assert name in declared, name
         meta = json.load(open(os.path.join(cells.HERE, "metrics",

@@ -86,7 +86,9 @@ def test_benchmark_json_only_gained_entries():
         # PR 42's two of the mid-run fork
         "sim.fork_replay_us_per_event", "sim.fork_waiting_pods",
         # PR 44's merged-read share of the code cells
-        "vm.merged_read_share"]
+        "vm.merged_read_share",
+        # PR 45's share of pods that name their GPU models
+        "sim.typed_pod_share"]
     new = bench["per_layer"][at:at + 2]
     for m in new:
         assert m["workloads"] == [CELL] and m["layer"] == "serving serve/"
@@ -97,9 +99,10 @@ def test_benchmark_json_only_gained_entries():
         lists = m.get("workloads", [])
         assert (CELL in lists) == (SIBLING in lists), m["name"]
         if CELL in lists:   # last of the cells there were at PR 37
-            assert [w for w in lists
-                    if w != "openb16-cpu250-midrun.codegen8"][-1] == CELL
-    assert len(bench["workloads"]) == 8
+            assert [w for w in lists if w not in (
+                "openb16-cpu250-midrun.codegen8",
+                "openb1523-gpuspec25-loaded.codegen8")][-1] == CELL
+    assert len(bench["workloads"]) == 9
     assert sum(w["chips"] == 4 for w in bench["workloads"]) == 1
 
 

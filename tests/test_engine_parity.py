@@ -68,6 +68,29 @@ def test_alt_trace_parity(pod_file, name, golden_alt):
     check_parity(res, golden_alt[pod_file][name], wl)
 
 
+def test_gpuspec33_parsed_by_default_is_upstreams_run(golden_alt):
+    """Upstream ignores ``gpu_spec`` (its golden ``gpuspec33`` run places
+    T4 pods on 16 nodes none of which is a T4), so the DEFAULT parse must:
+    no type leaves, upstream's run to every count. Honoured, the same
+    list is another run."""
+    pod_file = "openb_pod_list_gpuspec33.csv"
+    wl = TraceParser().parse_workload(pod_file=pod_file)
+    assert not wl.typed and wl.pods.gpu_spec is None \
+        and wl.cluster.gpu_model is None
+    cfg = SimConfig(score_dtype=jnp.float64)
+    policy = zoo.ZOO["first_fit"](dtype=jnp.float64)
+    check_parity(simulate(wl, policy, cfg), golden_alt[pod_file]["first_fit"],
+                 wl)
+    honoured = TraceParser().parse_workload(pod_file=pod_file,
+                                            gpu_spec="honor")
+    assert honoured.typed
+    assert int(np.count_nonzero(np.asarray(honoured.pods.gpu_spec))) == 2388
+    short = SimConfig(score_dtype=jnp.float64, max_steps=2000)
+    a, b = (simulate(w, policy, short) for w in (wl, honoured))
+    assert int(a.num_fragmentation_events) == 0 \
+        < int(b.num_fragmentation_events)
+
+
 @pytest.mark.slow
 def test_float32_fitness_within_1e5(default_workload, golden_default):
     """The TPU-fast dtype must still meet the 1e-5 north-star bar on the

@@ -121,3 +121,60 @@ def test_rejects_a_list_without_gpu_requests(cluster, source):
         source, num_gpu=np.zeros_like(np.asarray(source.num_gpu)))
     with pytest.raises(ValueError, match="requests GPUs"):
         inflate.inflate_pods(cluster, cpu_only, SHARE, SEED)
+
+
+# --------------------- the list whose pods name their GPU models (PR 45)
+
+SHA256 = {
+    "openb_pod_list_inflated080.csv":
+        "9bfa42cf16e4958a5ef7c9a8bb54cb9b435e35999c7aad36045e5b74147d584c",
+    "openb_pod_list_gpuspec25_inflated080.csv":
+        "45610eb37086497b3af4168f324d086e65be883203518d7b6c9c7b0d3af922fe",
+}
+
+
+@pytest.mark.parametrize("spec", inflate.COMMITTED, ids=lambda s: s[0])
+def test_the_command_rewrites_each_committed_list_byte_for_byte(
+        parser, spec, tmp_path):
+    """``python -m fks_tpu.data.inflate`` writes both lists; the one
+    three accepted configurations pin keeps its bytes although the writer
+    now fills ``gpu_spec`` from the source row (the default list's is
+    empty everywhere)."""
+    again = inflate.write_inflated(parser, spec, tmp_path)
+    with open(again, "rb") as f, \
+            open(parser.csv_dir / (spec[0] + ".gz"), "rb") as g:
+        got, want = f.read(), g.read()
+    assert got == want
+    assert hashlib.sha256(want).hexdigest() == SHA256[spec[0]]
+
+
+def test_the_typed_list_is_the_same_draw_with_the_source_rows_gpu_spec(
+        parser, cluster, source, inflated):
+    name, node_file, pod_file, share, seed = inflate.GPUSPEC25_INFLATED080
+    assert (node_file, share, seed) == (NODE_FILE, SHARE, SEED)
+    # the draw reads the request columns, which the two sources share
+    typed_source = parser.parse_pods(pod_file)
+    picks = inflate.arrival_picks(cluster, typed_source, share, seed)
+    np.testing.assert_array_equal(
+        picks, inflate.arrival_picks(cluster, source, SHARE, SEED))
+    mine = parser.parse_pods(name)
+    assert mine.pod_ids == inflated.pod_ids and mine.gpu_spec is None
+    for a, b in zip(_arrays(mine), _arrays(inflated)):
+        np.testing.assert_array_equal(a, b)
+    # the column is the sampled source row's own
+    raw = [r["gpu_spec"] for r in parser._read_csv(parser.csv_dir / pod_file)]
+    got = [r["gpu_spec"] for r in parser._read_csv(parser.csv_dir / name)]
+    assert got == [raw[j] for j in picks]
+    assert sum(1 for s in got if s) == 1375
+    assert "V100M16|V100M32|V100M32" in got      # written as the source has it
+    # and a list parsed to honour it keeps each source pod's set through
+    # the draw
+    models = parser.parse_cluster(node_file, gpu_models=True)
+    drawn = inflate.inflate_pods(
+        models, parser.parse_pods(pod_file, gpu_models=models.gpu_models),
+        share, seed)
+    read = parser.parse_pods(name, gpu_models=models.gpu_models)
+    np.testing.assert_array_equal(drawn.gpu_spec, read.gpu_spec)
+    assert int(np.count_nonzero(np.asarray(read.gpu_spec))) == 1375
+    # no pod without a GPU carries one
+    assert not np.asarray(read.gpu_spec)[np.asarray(read.num_gpu) == 0].any()

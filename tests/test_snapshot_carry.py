@@ -127,7 +127,7 @@ def test_the_committed_midrun_carry_is_the_engines_own():
     s = flat.initial_state(forked, SimConfig())
     assert flat.fork_counts(forked, s) == {
         "residents": 50, "nodes_loaded": 15, "departed": 5618,
-        "waiting": 1, "prefix_failed": 1002}
+        "waiting": 1, "typed_waiting": 0, "prefix_failed": 1002}
     assert (int(s.snap_idx), int(s.pending), int(s.steps)) \
         == (26, 3751 + 50 + 1, 12288)
     assert round(float(s.frag_sum) / 1002, 4) == 0.0617

@@ -213,8 +213,11 @@ def test_generation_leaves_the_tier_tree_without_a_profiler(micro_workload):
     got = _since(mark)
     assert all(r.ok for r in recs)
     roots = [r for r in got if r.name == "tier/evaluate"]
-    assert len(roots) == 1 and roots[0].fields == {"candidates": 4,
-                                                   "start_event": 0}
+    # typed_pods / node_models (PR 45): 0 on a workload parsed without
+    # gpu_spec="honor"
+    assert len(roots) == 1 and roots[0].fields == {
+        "candidates": 4, "start_event": 0, "typed_pods": 0,
+        "node_models": 0}
     root = roots[0]
     kids = [r for r in got if r.parent_id == root.span_id]
     assert {r.name for r in kids} == TIER_SPANS      # no fallback ran

@@ -56,7 +56,8 @@ def test_node_and_pod_file_discovery():
     # matches reference glob semantics (parser.py:103-115): openb_* only
     assert parser.get_available_node_files() == [
         "openb_node_list_all_node.csv", "openb_node_list_gpu_node.csv"]
-    assert len(parser.get_available_pod_files()) == 24  # 23 of OpenB + the inflated list
+    # 23 of OpenB + the two inflated lists (default, gpuspec25)
+    assert len(parser.get_available_pod_files()) == 25
 
 
 def test_duration_derivation(default_workload):

@@ -5,6 +5,7 @@ here is a cell, and nothing is measured on another backend (exit 3).
     python tools/chip_cluster_check.py whole --seed N
     python tools/chip_cluster_check.py forked --seed N
     python tools/chip_cluster_check.py midrun --seed N
+    python tools/chip_cluster_check.py gpuspec --seed N
     python tools/chip_cluster_check.py dense --seed N [--events 32]
 
 ``whole``: ONE whole evaluation (no step cap: fill, pressure, drain, about
@@ -28,6 +29,11 @@ as the cell compares
 (``plain_sim_midrun.simulate_from``, float32 sources, ``nearties.admit``);
 a lane that ends at the cap scores 0 on both sides, so one finished lane
 at least is asked for, not all.
+
+``gpuspec``: ``midrun``'s comparison on the production cluster under
+GPU-type constraints, from the pinned snapshot of
+``openb1523-gpuspec25-loaded`` (event 4,864; 8,900-13,100 further events
+by the plain reference), against ``plain_sim_gpuspec.simulate_from``.
 
 ``dense``: the same 8 lanes for ``--events`` events under the rule the
 program chooses (64) and DENSE (an explicit ``node_prefilter_k`` of the
@@ -53,6 +59,10 @@ from chipbench.drivers import common  # noqa: E402
 CELL = "openb1523-inflated.codegen8"
 LOADED = "openb1523-loaded.codegen8"
 MIDRUN = "openb16-cpu250-midrun.codegen8"
+GPUSPEC = "openb1523-gpuspec25-loaded.codegen8"
+#: cells compared as their drivers compare: float32 sources, near ties
+#: admitted, one finished lane at least asked for
+AS_THE_CELL = (MIDRUN, GPUSPEC)
 DEVICE = ("tier/vm_batch/launch", "tier/vm_batch/wait_device")
 
 
@@ -74,7 +84,11 @@ def _inputs(seed: int, name: str = CELL):
 
         driver.e0 = int(cell.config["start_event"])
         wl = driver._workload()
-        if name == MIDRUN:
+        if name == GPUSPEC:
+            from chipbench.reference.plain_sim_gpuspec import simulate_from
+            reference = functools.partial(
+                simulate_from, allowed=driver.allowed(), log=driver.rows())
+        elif name == MIDRUN:
             from chipbench.reference.plain_sim_midrun import simulate_from
             reference = functools.partial(simulate_from, log=driver.rows())
         else:
@@ -134,7 +148,7 @@ def whole(seed: int, name: str = CELL) -> bool:
     for lane, (rec, code) in enumerate(zip(recs, sources)):
         t0 = time.perf_counter()
         got = Output.of_lane(rec.result, pods.p)
-        if name == MIDRUN:      # as the cell compares
+        if name in AS_THE_CELL:
             from chipbench.reference.nearties import admit
             policy = policies.source_policy(
                 code, dtype=cell.config["guarantees"]["score_dtype"])
@@ -159,7 +173,8 @@ def whole(seed: int, name: str = CELL) -> bool:
             reference_s=time.perf_counter() - t0,
             compared={n.name.split(".", 1)[1]: n.value for n in numbers},
             ok=all(n.ok for n in numbers))
-    ok &= finished > 0 if name == MIDRUN else finished == len(sources)
+    ok &= finished > 0 if name in AS_THE_CELL \
+        else finished == len(sources)
     say(row="whole", seed=seed, lanes=len(sources), finished=finished,
         all_equal=bool(ok))
     return bool(ok)
@@ -195,7 +210,8 @@ def dense(seed: int, events: int) -> bool:
 
 def main(argv=None) -> int:
     ap = argparse.ArgumentParser(description=__doc__.splitlines()[0])
-    ap.add_argument("what", choices=("whole", "forked", "midrun", "dense"))
+    ap.add_argument("what", choices=("whole", "forked", "midrun", "gpuspec",
+                                     "dense"))
     ap.add_argument("--seed", type=int, required=True)
     ap.add_argument("--events", type=int, default=32)
     a = ap.parse_args(argv)
@@ -207,8 +223,8 @@ def main(argv=None) -> int:
         return 3
     place_compile_cache()
     ok = (dense(a.seed, a.events) if a.what == "dense"
-          else whole(a.seed, {"forked": LOADED, "midrun": MIDRUN}.get(
-              a.what, CELL)))
+          else whole(a.seed, {"forked": LOADED, "midrun": MIDRUN,
+                              "gpuspec": GPUSPEC}.get(a.what, CELL)))
     return 0 if ok else 1
 
 

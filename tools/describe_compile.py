@@ -16,7 +16,9 @@ model, not a clock: this says WHAT a change does to the program.
 shapes. ``codegen``: the batched VM tier's population runner, 8 lanes.
 ``whatif``: ``VMServeEngine``'s executable for 2 lanes of the 256-pod
 bucket on the exact engine (whatif8's largest chunk). ``--cluster 1523`` is the OpenB cluster (under
-the program's own large-cluster rule) with the inflated trace.
+the program's own large-cluster rule) with the inflated trace;
+``--cluster 1523-gpuspec25`` the same cluster with the inflated gpuspec25
+list and its GPU-type constraints honoured (the step's type term).
 """
 from __future__ import annotations
 
@@ -163,6 +165,11 @@ def _workload(cluster: str):
 
     if cluster == "16":
         return TraceParser().parse_workload()
+    if cluster == "1523-gpuspec25":
+        return TraceParser().parse_workload(
+            node_file="openb_node_list_all_node.csv",
+            pod_file="openb_pod_list_gpuspec25_inflated080.csv",
+            gpu_spec="honor")
     return TraceParser().parse_workload(
         node_file="openb_node_list_all_node.csv",
         pod_file="openb_pod_list_inflated080.csv")
@@ -224,7 +231,7 @@ def main(argv=None) -> int:
     ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
     ap.add_argument("executable", choices=sorted(EXECUTABLES))
     ap.add_argument("--describe", default="v5e:2x2", metavar="TOPOLOGY")
-    ap.add_argument("--cluster", choices=("16", "1523"))
+    ap.add_argument("--cluster", choices=("16", "1523", "1523-gpuspec25"))
     ap.add_argument("--lanes", type=int)
     ap.add_argument("--hlo", metavar="FILE",
                     help="also write the compiled module's text here")
