@@ -249,9 +249,8 @@ class CodeEvaluator:
         self._vm_mesh_run = None  # lazily built SHARDED population program
         self._budget_eval = None  # lazily built rung ladder (budget mode)
         self.vm_batch_count = 0  # observability: batched VM launches
-        # (lanes, capacity) -> (slice, scatter, merged, split) of
-        # vm.write_count() + vm.read_count() over the first batched launch
-        # of that bucket, which traced it
+        # (lanes, capacity) -> vm.trace_counts() over the first batched
+        # launch of that bucket, which traced it (vm.TRACE_FIELDS)
         self._vm_traced: Dict[Tuple[int, int], Tuple[int, ...]] = {}
         # the most recent batched launch's [lanes] score array, on the
         # device: last_lanes_per_device reads its placement when asked
@@ -399,23 +398,25 @@ class CodeEvaluator:
         except Exception:  # noqa: BLE001 — pricing is best-effort
             pass
 
-    def _vm_traced_fields(self, bucket: Tuple[int, int],
+    def _vm_traced_fields(self, bucket: Tuple[int, int], slots: int,
                           before: Tuple[int, ...]) -> Dict[str, int]:
-        """``slice_writes`` / ``scatter_writes`` and ``merged_reads`` /
-        ``split_reads`` of a batched launch: how the bucket's runner
-        lowered the op-slot loop's row write and its operand fetch
-        (``vm.write_count``, ``vm.read_count``, counted while a program is
-        traced). The launch that moved a count since ``before`` (the two
-        readings, joined) traced the bucket's program and the difference
-        stays with the bucket; every later launch of it traces nothing and
-        reports the same four numbers."""
-        now = vm.write_count() + vm.read_count()
-        traced = tuple(x - y for x, y in zip(now, before))
+        """``slice_writes`` / ``scatter_writes``, ``merged_reads`` /
+        ``split_reads`` and ``blocked_loops`` / ``plain_loops`` of a
+        batched launch: how the bucket's runner lowered the op-slot loop's
+        row write, its operand fetch and its trip structure
+        (``vm.write_count``, ``vm.read_count``, ``vm.loop_count``, counted
+        while a program is traced), and ``turns``: the turns that loop
+        makes an event over the launch's ``slots`` (``vm.loop_turns``).
+        The launch that moved a count since ``before``
+        (``vm.trace_counts``) traced the bucket's program and the
+        difference stays with the bucket; every later launch of it traces
+        nothing and reports the same six numbers."""
+        traced = tuple(x - y for x, y in zip(vm.trace_counts(), before))
         if any(traced):
             self._vm_traced[bucket] = traced
-        return dict(zip(
-            ("slice_writes", "scatter_writes", "merged_reads", "split_reads"),
-            self._vm_traced.get(bucket, (0, 0, 0, 0))))
+        traced = self._vm_traced.get(bucket, (0,) * 6)
+        return dict(zip(vm.TRACE_FIELDS, traced),
+                    turns=vm.loop_turns(slots, *traced[4:]))
 
     def _run_vm_batch(self, progs: List[vm.VMProgram]) -> List[SimResult]:
         """Evaluate stacked VM candidates in ONE device launch — sharded
@@ -451,14 +452,15 @@ class CodeEvaluator:
         c = self.workload.cluster
         capacity = int(stacked.opcode.shape[-1])
         view = self.cfg.resolve_prefilter_k(c.n_padded) or c.n_padded
+        slots = max(int(p.n_ops) for p in progs)
         with obs.span("tier/vm_batch/launch", lanes=pop,
                       shards=self._n_shards, start_event=self.start_event,
-                      slots=max(int(p.n_ops) for p in progs),
+                      slots=slots,
                       capacity=capacity, nodes=c.n_padded, view=view,
                       register_bytes=(pop // self._n_shards)
                       * vm.register_rows(capacity) * view * c.g_padded
                       * stacked.imm.dtype.itemsize) as t_launch:
-            traced0 = vm.write_count() + vm.read_count()
+            traced0 = vm.trace_counts()
             if self._n_shards > 1 and self.suite is None:
                 # each device interprets pop/shards lanes; the elite
                 # outputs are discarded here (the evolution loop ranks on
@@ -470,7 +472,8 @@ class CodeEvaluator:
                 result, _, _ = self._vm_mesh_runner()(stacked, len(progs))
             else:
                 result = self._vm_pop_runner()(stacked, self.state0)
-            t_launch.set(**self._vm_traced_fields((pop, capacity), traced0))
+            t_launch.set(**self._vm_traced_fields((pop, capacity), slots,
+                                                  traced0))
         self._last_scores = result.policy_score
         with obs.span("tier/vm_batch/wait_device"):
             jax.block_until_ready(result)

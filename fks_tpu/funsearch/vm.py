@@ -32,10 +32,11 @@ Pipeline:
        (`pack_program`).
 
 Execution (`score`, the one entry point, batched or not): ``fori_loop``
-over the LIVE op slots — one program's ``n_ops``, or under ``vmap`` the
-longest live program of the batch as one unbatched scalar
-(`_loop_bound`); NOP padding past it never runs. Each slot is a
-``lax.switch`` over a deliberately minimal 33-opcode table on [N, G]
+over the LIVE op slots — one program's ``n_ops``, a slot a turn; under
+``vmap`` over stacked programs the longest live program of the batch as
+one unbatched scalar (`_loop_bound`), a block of `SLOT_BLOCK` slots a
+turn (`_slot_loop`); NOP padding past the last turn never runs. Each slot
+is a ``lax.switch`` over a deliberately minimal 33-opcode table on [N, G]
 values (scalar literals load from a pooled register block, not op slots;
 boolean and sign ops are canonicalized into arithmetic at lowering — see the
 CONST_POOL / opcode-table comments below for the vmap rationale). Numeric model: everything runs at the
@@ -807,7 +808,6 @@ def _branches(n: int, g: int):
 def _execute(prog: VMProgram, pod: PodView, nodes: NodeView,
              bound) -> jax.Array:
     n, g = nodes.gpu_mask.shape
-    branches = _branches(n, g)
     inp = _inputs(pod, nodes)
     cap = prog.capacity
     pool = jnp.broadcast_to(
@@ -815,15 +815,8 @@ def _execute(prog: VMProgram, pod: PodView, nodes: NodeView,
         (prog.consts.shape[0], n, g))
     regs = jnp.concatenate(
         [inp, pool, jnp.zeros((cap, n, g), _ambient_float())])
-    op_base = N_INPUTS + prog.consts.shape[0]
-
-    def body(k, regs):
-        op, *operands = _slot_operands(0)(
-            regs, prog.opcode, prog.a, prog.b, prog.c, prog.imm, k)
-        res = lax.switch(op, branches, *operands)
-        return _write_row(regs, res, op_base + k)
-
-    regs = lax.fori_loop(0, bound, body, regs)
+    regs = _slot_loop(0)(regs, prog.opcode, prog.a, prog.b, prog.c, prog.imm,
+                         bound)
     out = regs[prog.out_reg][:, 0]
     # Non-finite values (a candidate dividing by zero, log of a negative)
     # would hit the int cast below with implementation-defined results;
@@ -833,6 +826,129 @@ def _execute(prog: VMProgram, pod: PodView, nodes: NodeView,
     out = jnp.where(jnp.isfinite(out), out, jnp.zeros_like(out))
     # the policy's jaxpr already ends in an int cast; values are integral
     return out.astype(jnp.int32)
+
+
+#: op slots a turn of the op-slot loop where the program words are per
+#: lane (`_slot_loop`)
+SLOT_BLOCK = 8
+
+_LOOP_COUNT = threading.local()
+
+
+def _bump(counter: threading.local, which: int) -> None:
+    """One more of the ``which``-th of a rule's two outcomes, on this
+    thread (`loop_count`, `write_count`, `read_count`)."""
+    n = list(getattr(counter, "n", (0, 0)))
+    n[which] += 1
+    counter.n = tuple(n)
+
+
+def loop_count() -> Tuple[int, int]:
+    """(blocked, plain): how often, on THIS thread, the batching rule of
+    the op-slot loop (`_slot_loop`) ran with per-lane program words and
+    traced a turn of `SLOT_BLOCK` slots, and how often it fell back to the
+    one-slot turn under them (a batched bound, a capacity that is no
+    multiple of the block). Counted while a runner is traced, as
+    `write_count` is and with the same meaning; a program that no ``vmap``
+    batches (serving, one program alone) keeps the one-slot turn and moves
+    neither. The evaluator keeps what a runner's first call added
+    (``blocked_loops`` / ``plain_loops`` of ``tier/vm_batch/launch``)."""
+    return getattr(_LOOP_COUNT, "n", (0, 0))
+
+
+#: the span fields the evaluator and the serve engines write from
+#: `trace_counts`, in its order
+TRACE_FIELDS = ("slice_writes", "scatter_writes", "merged_reads",
+                "split_reads", "blocked_loops", "plain_loops")
+
+
+def trace_counts() -> Tuple[int, ...]:
+    """`write_count`, `read_count` and `loop_count`, joined: what this
+    thread's traces have made of the op-slot loop so far. A runner's
+    first call moves it; the difference is kept with the runner."""
+    return write_count() + read_count() + loop_count()
+
+
+def loop_turns(slots: int, blocked: int, plain: int) -> int:
+    """Turns the op-slot loop makes over ``slots`` live slots in a runner
+    whose trace moved `loop_count` by ``(blocked, plain)``."""
+    return -(-slots // SLOT_BLOCK) if blocked and not plain else slots
+
+
+@functools.lru_cache(maxsize=None)
+def _slot_loop(axis: int):
+    """``run(regs, opcode, a, b, c, imm, bound)``: the op-slot loop over a
+    register file whose row axis is ``axis`` (the number of ``vmap``
+    levels that batched the file and not the program), with a batching
+    rule of its own for program words that are PER LANE.
+
+    One program turns the loop once a slot: ``fori_loop(0, bound, slot)``.
+    With a device-scalar ``bound`` that is a ``while`` whose counter,
+    bound and predicate the chip awaits on its scalar core every turn,
+    0.40-0.50 us whatever the slot holds (PERF.md section 6, PRs 44 and
+    46). Under ``vmap`` over stacked programs the rule turns the loop once
+    a BLOCK of `SLOT_BLOCK` slots, ``ceil(bound / SLOT_BLOCK)`` turns under
+    the same unbatched bound (`_loop_bound`), slot ``i * SLOT_BLOCK + j``
+    with ``j`` static. The slots of the last block past ``bound`` are the
+    OP_NOP padding every lane holds there (each copies register ``a`` into
+    a fresh row the output never reads), so every register the output
+    reads is the one-slot loop's, bit for bit: the rule moves control
+    flow, not arithmetic. It needs the table to end on a block (every
+    `capacity_bucket` does); under a capacity that does not, or a batched
+    bound, which no runner makes, it keeps the one-slot turn and is
+    counted (`loop_count`).
+
+    A ``vmap`` that batches the file alone (queries, scenarios; serving
+    never batches the program) goes to the loop of the next axis, which
+    traces what JAX's own rules trace there, and leaves the rule within
+    reach of an enclosing ``vmap`` that batches the programs."""
+
+    def run(block, regs, opcode, a, b, c, imm, bound):
+        n, g = regs.shape[axis + 1:]
+        branches = _branches(n, g)
+        op_base = regs.shape[axis] - opcode.shape[-1]
+
+        def slot(k, regs):
+            op, *operands = _slot_operands(axis)(
+                regs, opcode, a, b, c, imm, k)
+            res = _per_file(lambda *xs: lax.switch(op, branches, *xs), axis,
+                            in_axes=(0, 0, 0, None))(*operands)
+            return _write_row(regs, res, op_base + k, axis)
+
+        if block == 1:
+            return lax.fori_loop(0, bound, slot, regs)
+        # one trace of the slot a block, not ``block`` of them: a turn's
+        # calls share a jaxpr, which XLA inlines into the same kernels
+        slot_once = jax.jit(slot)
+
+        def turn(i, regs):
+            for j in range(block):
+                regs = slot_once(i * block + j, regs)
+            return regs
+
+        return lax.fori_loop(0, (bound + block - 1) // block, turn, regs)
+
+    loop = jax.custom_batching.custom_vmap(functools.partial(run, 1))
+
+    @loop.def_vmap
+    def loop_lanes(axis_size, in_batched, regs, opcode, a, b, c, imm, bound):
+        words = (opcode, a, b, c, imm)
+        if not any(in_batched[1:]):
+            # the next axis' loop writes its row as a slice of the file one
+            # axis further right: what the write's own rule says, and
+            # counts, where it is reached (`_row_writer`)
+            _bump(_WRITE_COUNT, 0)
+            return _slot_loop(axis + 1)(regs, *words, bound), True
+        block = SLOT_BLOCK
+        if in_batched[6] or opcode.shape[-1] % SLOT_BLOCK:
+            block = 1
+        _bump(_LOOP_COUNT, int(block == 1))
+        return jax.vmap(
+            functools.partial(run, block),
+            in_axes=[0 if b else None for b in in_batched],
+            axis_size=axis_size)(regs, *words, bound), True
+
+    return loop
 
 
 @jax.custom_batching.custom_vmap
@@ -851,7 +967,10 @@ def _loop_bound(n_ops: jax.Array) -> jax.Array:
     the output never reads), which is semantically free. So the batching
     rule below reduces the lanes' counts to their maximum and declares
     the result UNBATCHED: the loop predicate stays a scalar, nothing is
-    selected, and the padding past the longest live program never runs.
+    selected, and the padding past the longest live program never runs,
+    but for the rest of its last block: the loop such a bound drives makes
+    ``ceil(bound / SLOT_BLOCK)`` turns of `SLOT_BLOCK` slots (`_slot_loop`),
+    so up to ``SLOT_BLOCK - 1`` more of the same free slots.
     The maximum is taken on the device from the tables already there, so
     a new generation or a hot swap changes a value, never a shape. A
     program mapped with ``in_axes=None`` (serving) never reaches the
@@ -879,16 +998,10 @@ def write_count() -> Tuple[int, int]:
     per ``vmap`` around a write and per pass the tracer makes over the
     loop's body, so the two counts say which way the writes went, not how
     many there are; an unbatched program never reaches the rule. The
-    evaluator and the serve engines keep `writes_since` the start of a
-    runner's first call (``slice_writes`` / ``scatter_writes`` of
-    ``tier/vm_batch/launch`` and ``serve/chunk/enqueue``)."""
+    evaluator and the serve engines keep what a runner's first call added
+    (``slice_writes`` / ``scatter_writes`` of ``tier/vm_batch/launch`` and
+    ``serve/chunk/enqueue``)."""
     return getattr(_WRITE_COUNT, "n", (0, 0))
-
-
-def writes_since(before: Tuple[int, int]) -> Tuple[int, int]:
-    """`write_count` now less an earlier reading of it."""
-    slices, scatters = write_count()
-    return slices - before[0], scatters - before[1]
 
 
 @functools.lru_cache(maxsize=None)
@@ -921,13 +1034,11 @@ def _row_writer(axis: int):
 
     @write.def_vmap
     def write_lanes(axis_size, in_batched, regs, res, row):
-        slices, scatters = write_count()
+        _bump(_WRITE_COUNT, int(in_batched[2]))
         if in_batched[2]:
-            _WRITE_COUNT.n = (slices, scatters + 1)
             return jax.vmap(
                 write_plain, in_axes=[0 if b else None for b in in_batched],
                 axis_size=axis_size)(regs, res, row), True
-        _WRITE_COUNT.n = (slices + 1, scatters)
         regs, res = (x if b else jnp.broadcast_to(x, (axis_size,) + x.shape)
                      for x, b in zip((regs, res), in_batched))
         return _row_writer(axis + 1)(regs, res, row), True
@@ -935,9 +1046,10 @@ def _row_writer(axis: int):
     return write
 
 
-def _write_row(regs: jax.Array, res: jax.Array, row) -> jax.Array:
+def _write_row(regs: jax.Array, res: jax.Array, row,
+               axis: int = 0) -> jax.Array:
     """The op-slot loop's register write (`_row_writer`)."""
-    return _row_writer(0)(regs, res, row)
+    return _row_writer(axis)(regs, res, row)
 
 
 _READ_COUNT = threading.local()
@@ -954,10 +1066,11 @@ def read_count() -> Tuple[int, int]:
     return getattr(_READ_COUNT, "n", (0, 0))
 
 
-def _per_file(f, axis: int):
-    """``f`` over the ``axis`` leading batch axes of its arguments."""
+def _per_file(f, axis: int, in_axes=0):
+    """``f`` over the ``axis`` leading batch axes of its arguments (of
+    those ``in_axes`` maps)."""
     for _ in range(axis):
-        f = jax.vmap(f)
+        f = jax.vmap(f, in_axes=in_axes)
     return f
 
 
@@ -1006,16 +1119,15 @@ def _slot_operands(axis: int):
     @fetch.def_vmap
     def fetch_lanes(axis_size, in_batched, regs, opcode, a, b, c, imm, k):
         words, per_lane = (opcode, a, b, c, imm), in_batched[1:6]
-        merged, split = read_count()
         if in_batched[6]:
-            _READ_COUNT.n = (merged, split + 1)
+            _bump(_READ_COUNT, 1)
             return jax.vmap(
                 fetch_plain, in_axes=[0 if b else None for b in in_batched],
                 axis_size=axis_size)(regs, *words, k), (True,) * 5
         if not any(per_lane):
             return (_slot_operands(axis + 1)(regs, *words, k),
                     (False, True, True, True, False))
-        _READ_COUNT.n = (merged + 1, split)
+        _bump(_READ_COUNT, 0)
         opcode, a, b, c, imm = (
             x if b else jnp.broadcast_to(x, (axis_size,) + x.shape)
             for x, b in zip(words, per_lane))
@@ -1072,7 +1184,8 @@ def score(prog: VMProgram, pod: PodView, nodes: NodeView) -> jax.Array:
     candidates with ``stack_programs`` and pass this as the
     ``param_policy`` of ``make_population_run_fn``. The op-slot loop runs
     the LIVE slots only — to ``n_ops`` for one program, to the longest
-    live program of the batch under ``vmap`` (``_loop_bound``) — while
+    live program of the batch under ``vmap`` (``_loop_bound``), there
+    rounded up to a whole block of slots (``_slot_loop``) — while
     shapes and the register file stay at the padded capacity, so one
     executable serves every program of a capacity bucket.
     """

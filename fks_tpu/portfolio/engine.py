@@ -262,7 +262,7 @@ class PortfolioEngine(VMServeEngine):
                 slots0 = self._lane_put(np.zeros(lanes, np.int32))
                 example = ((self._prog_dev, slots0)
                            + self._example_batch(lanes, pod_bucket))
-                writes0 = vm.write_count()
+                writes0 = vm.trace_counts()
                 with warnings.catch_warnings():
                     warnings.filterwarnings("ignore",
                                             message="Some donated")

@@ -384,9 +384,9 @@ class ServeEngine:
                          else obs.NULL_PROFILER)
         self._mod = get_engine(engine)
         self._compiled: Dict[Tuple[int, int], Any] = {}
-        # id(executable) -> (slice, scatter) of vm.write_count() over its
-        # trace: how the op-slot loop's row write was lowered in it
-        self._vm_writes: Dict[int, Tuple[int, int]] = {}
+        # id(executable) -> vm.trace_counts() over its trace: how the
+        # op-slot loop's row write, fetch and turns were lowered in it
+        self._vm_writes: Dict[int, Tuple[int, ...]] = {}
         self.cold_compiles = 0
         # mesh-wide serving: lane axis sharded over the pop axes
         self.mesh = mesh
@@ -595,7 +595,7 @@ class ServeEngine:
                     fn = make_sharded_serve_fn(fn, self.mesh)
                 from fks_tpu.funsearch import vm
                 example = self._example_batch(lanes, pod_bucket)
-                writes0 = vm.write_count()
+                writes0 = vm.trace_counts()
                 with warnings.catch_warnings():
                     # buckets whose SimResult cannot alias a donated
                     # input warn once per compile; donation still lets
@@ -805,22 +805,26 @@ class ServeEngine:
                                   hh.span.record, t_enq.record]
         return _Inflight(res, list(idxs), bucket, lanes, real, chunk)
 
-    def _keep_writes(self, compiled, before: Tuple[int, int]) -> None:
-        """Keep with a new executable how its trace lowered the VM's row
-        write: ``vm.write_count`` since ``before`` (read just before the
-        ``lower()`` that traced it, on this thread; the executable is
-        never traced again)."""
+    def _keep_writes(self, compiled, before: Tuple[int, ...]) -> None:
+        """Keep with a new executable how its trace lowered the VM's
+        op-slot loop: ``vm.trace_counts`` since ``before`` (read just
+        before the ``lower()`` that traced it, on this thread; the
+        executable is never traced again)."""
         from fks_tpu.funsearch import vm
-        self._vm_writes[id(compiled)] = vm.writes_since(before)
+        self._vm_writes[id(compiled)] = tuple(
+            x - y for x, y in zip(vm.trace_counts(), before))
 
     def _write_fields(self, compiled) -> Dict[str, int]:
-        """``slice_writes`` / ``scatter_writes`` of the executable being
-        enqueued (`_keep_writes`). Empty where its trace held no batched
-        VM write (a champion on the jit tier)."""
-        slices, scatters = self._vm_writes.get(id(compiled), (0, 0))
-        if not slices + scatters:
+        """``vm.TRACE_FIELDS`` of the executable being enqueued
+        (`_keep_writes`): ``slice_writes`` / ``scatter_writes``, and how
+        its fetch and its loop's turns went (all 0 where the program is
+        never per lane). Empty where its trace held no batched VM write (a
+        champion on the jit tier)."""
+        from fks_tpu.funsearch import vm
+        traced = self._vm_writes.get(id(compiled), ())
+        if not sum(traced[:2]):
             return {}
-        return {"slice_writes": slices, "scatter_writes": scatters}
+        return dict(zip(vm.TRACE_FIELDS, traced))
 
     def _invoke(self, compiled, pods, kt_dev, s0):
         return compiled(pods, kt_dev, s0)

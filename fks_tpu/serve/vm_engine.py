@@ -324,7 +324,7 @@ class VMServeEngine(ServeEngine):
                     fn = make_sharded_vm_serve_fn(fn, self.mesh)
                 example = ((self._prog_dev,)
                            + super()._example_batch(lanes, pod_bucket))
-                writes0 = vm.write_count()
+                writes0 = vm.trace_counts()
                 with warnings.catch_warnings():
                     warnings.filterwarnings("ignore",
                                             message="Some donated")

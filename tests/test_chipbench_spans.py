@@ -199,6 +199,25 @@ def test_merged_read_share_reads_the_launch_spans_fields(fields, want):
     assert got == (want if want is None else pytest.approx(want))
 
 
+@pytest.mark.parametrize("fields,want", [
+    # the blocked loop: 292 live slots in 37 turns of 8
+    ({"slots": 292, "turns": 37, "blocked_loops": 1, "plain_loops": 0},
+     292 / 37),
+    ({"slots": 292, "turns": 73}, 4.0),               # a block of 4
+    ({"slots": 292, "turns": 292, "blocked_loops": 0, "plain_loops": 1},
+     1.0),                                            # the one-slot turn
+    ({"slots": 0, "turns": 0}, None),                 # nothing ran
+    # a parent without the field (older than PR 46)
+    ({"slots": 292, "capacity": 512, "merged_reads": 1, "split_reads": 0},
+     None),
+    ({}, None),
+])
+def test_slots_per_turn_reads_the_launch_spans_fields(fields, want):
+    got = cells.metric_reader("vm.slots_per_turn")(
+        {"_span_calls": _launch_calls(fields)})
+    assert got == (want if want is None else pytest.approx(want))
+
+
 def _transpile_calls(fields):
     """The window's calls of three generations (the first is the warm-up),
     each with a ``tier/transpile`` span that carries ``fields``."""
@@ -326,8 +345,9 @@ def test_every_span_metric_is_declared_with_its_files():
     # + vm.scatter_write_share (PR 35), serve.fork_state_ms and
     # serve.retry_share (PR 37), tier.pooled_source_share (PR 39),
     # sim.fork_replay_us_per_event and sim.fork_waiting_pods (PR 42),
-    # vm.merged_read_share (PR 44), sim.typed_pod_share (PR 45)
-    assert len(SPAN_METRICS) == 31
+    # vm.merged_read_share (PR 44), sim.typed_pod_share (PR 45),
+    # vm.slots_per_turn (PR 46)
+    assert len(SPAN_METRICS) == 32
     for name in SPAN_METRICS:
         assert name in declared, name
         meta = json.load(open(os.path.join(cells.HERE, "metrics",
@@ -384,7 +404,7 @@ def test_traced_cell_reports_every_span_metric(name, tmp_path, monkeypatch):
             + v["serve.h2d_ms_per_call"] + v["serve.harvest_ms_per_call"]
         assert v["serve.h2d_kb_per_call"] > 0 and v["serve.d2h_kb_per_call"] > 0
     else:
-        assert len(want) == 14
+        assert len(want) == 15
         # a recorded generation: every source traced once, where it runs,
         # and the simplifier dropped part of what the lowering emitted
         assert res["metrics"]["tier.traces_per_source"]["value"] == 1.0
@@ -395,6 +415,16 @@ def test_traced_cell_reports_every_span_metric(name, tmp_path, monkeypatch):
         assert res["metrics"]["vm.scatter_write_share"]["value"] == 0.0
         # and fetched every slot's three rows with one gather
         assert res["metrics"]["vm.merged_read_share"]["value"] == 100.0
+        # and turned its op-slot loop once a block of slots
+        from fks_tpu.funsearch import vm
+        from fks_tpu.obs import spans
+
+        launch = [r.fields for r in spans.LOG.snapshot()
+                  if r.name == "tier/vm_batch/launch"][-1]
+        assert launch["blocked_loops"] >= 1 and launch["plain_loops"] == 0
+        assert launch["turns"] == -(-launch["slots"] // vm.SLOT_BLOCK)
+        assert res["metrics"]["vm.slots_per_turn"]["value"] \
+            == pytest.approx(launch["slots"] / launch["turns"])
         # the generations went through the process's lowering pool as
         # far as this machine has the cores and the workers were up
         assert 0.0 <= res["metrics"]["tier.pooled_source_share"]["value"] \

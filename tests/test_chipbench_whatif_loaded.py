@@ -88,7 +88,9 @@ def test_benchmark_json_only_gained_entries():
         # PR 44's merged-read share of the code cells
         "vm.merged_read_share",
         # PR 45's share of pods that name their GPU models
-        "sim.typed_pod_share"]
+        "sim.typed_pod_share",
+        # PR 46's slots a turn of the interpreter's loop
+        "vm.slots_per_turn"]
     new = bench["per_layer"][at:at + 2]
     for m in new:
         assert m["workloads"] == [CELL] and m["layer"] == "serving serve/"

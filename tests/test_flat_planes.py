@@ -180,7 +180,7 @@ def test_param256_compiles_with_the_population_on_the_lanes():
     assert 30 <= kernels <= 70, kernels
 
 
-# ------------------------------- the interpreter's op-slot loop (PR 44)
+# ------------------------- the interpreter's op-slot loop (PRs 44, 46)
 
 def _described_device():
     device = describe_compile.topology_device("v5e:2x2")
@@ -199,30 +199,43 @@ def _slot_kernels(hlo):
 
 @pytest.mark.parametrize("cluster,view", [("16", 16), ("1523", 64)])
 def test_codegen_slot_fetches_its_operands_with_one_gather(cluster, view):
-    """The batched VM tier's slot, compiled for a described v5e: ONE
-    gather from the register file (three rows a lane), no gather of COL's
-    own from a row, the file updated in place (no copy and no second
-    layout of it), and no more kernels a slot than PR 44 reached (the
-    parent: 14, four of them gathers)."""
+    """The batched VM tier's op-slot loop, compiled for a described v5e,
+    read per BLOCK of ``vm.SLOT_BLOCK`` slots (PR 46; the ``while`` turns
+    once a block): a slot is ONE gather from the register file (three rows
+    a lane), no gather of COL's own from a row, and one write of the file
+    in place in the ONE layout the ``while`` carries (no copy and no
+    second layout of it); the index word is fetched once a block; and no
+    more than 9 kernels a slot (PR 44: 11, its parent 14, four of them
+    gathers)."""
     from fks_tpu.funsearch import vm
 
-    lanes, g = 8, 8
+    lanes, g, block = 8, 8, vm.SLOT_BLOCK
     with jax.enable_x64(False):   # the chip's program: int32 / float32
         hlo = describe_compile.codegen(_described_device(), cluster, lanes)
     kernels = _slot_kernels(hlo)
-    gathers = [r for r in kernels
-               if "gather" in describe_compile.fused_ops(hlo, r)]
-    assert [r["arrays"][0][1] for r in gathers] == [(3 * lanes, view, g)]
+    inside = {r["name"]: describe_compile.fused_ops(hlo, r) for r in kernels}
+    gathers = [r for r in kernels if "gather" in inside[r["name"]]]
+    assert [r["arrays"][0][1] for r in gathers] \
+        == [(3 * lanes, view, g)] * block
     file_shape = (lanes, vm.register_rows(512), view, g)
-    files = [(r["op"], a[2]) for r in kernels for a in r["arrays"]
-             if a[1] == file_shape]
-    assert len(files) == 1 and files[0][0] == "fusion", files
+    files = [(r["op"], a[2], inside[r["name"]].count("dynamic-update-slice"))
+             for r in kernels for a in r["arrays"] if a[1] == file_shape]
+    assert len(files) == block and len(set(files)) == 1, files
+    assert files[0][0] == "fusion" and files[0][2] == 1, files
     carried = [a[2] for r in describe_compile.loop_body(hlo)
                if r["op"] == "while" for a in r["arrays"]
                if a[1] == file_shape]
     assert carried == [files[0][1]]   # the layout the loop carries it in
     assert not [r for r in kernels if r["op"] == "copy"]
-    assert len(kernels) <= 11, [r["name"] for r in kernels]
+    # the [lanes, 3, 1] index word of every slot of the block: one fetch
+    fetches = [r for r in kernels
+               if (lanes, 3, 1) in [a[1] for a in r["arrays"]]]
+    assert len(fetches) == 1 and len(fetches[0]["arrays"]) == block, fetches
+    assert len(kernels) <= 9 * block, [r["name"] for r in kernels]
+    if cluster == "16":     # the event loop is still found where the
+        # blocked slot loop is the larger body of the two
+        assert len(describe_compile.slot_loop(hlo)) \
+            > len(describe_compile.loop_body(hlo))
 
 
 def test_whatif_slot_loop_is_the_scalar_one():

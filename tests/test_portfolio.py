@@ -606,9 +606,10 @@ def test_mixed_slot_batch_runs_to_the_longest_selected_program(
         wl, envelope, monkeypatch):
     """Per-lane slot dispatch gathers a per-lane ``n_ops``; the shared
     op-slot loop runs to the longest program any lane of the batch
-    SELECTED (never past the table's longest, and never the padded
-    capacity), its predicate stays a scalar, and the ``enqueue`` span
-    says the same number."""
+    SELECTED, rounded up to whole blocks of ``vm.SLOT_BLOCK`` slots (the
+    program words are per lane here; never a block past the table's
+    longest, and never the padded capacity), its predicate stays a
+    scalar, and the ``enqueue`` span says the live number."""
     import jax
 
     from tests.test_vm_batch import (
@@ -637,7 +638,8 @@ def test_mixed_slot_batch_runs_to_the_longest_selected_program(
             monkeypatch, run, np.asarray(slots, np.int32))
         monkeypatch.undo()
         events = int(np.max(np.asarray(res.events_processed)))
-        assert events > 0 and fired == longest * events, (slots, fired)
+        ran = -(-longest // vm.SLOT_BLOCK) * vm.SLOT_BLOCK
+        assert events > 0 and fired == ran * events, (slots, fired)
     # the host's copy of the same number, on the span the benchmark reads
     q = [_query(eng.base_pods, 0), _query(eng.base_pods, 1)]
     for slots, longest in (([0, 1], n_long), ([0, 0], n_short)):
@@ -667,6 +669,11 @@ def test_slot_dispatch_writes_a_register_as_one_slice(wl, envelope):
             np.asarray([0, 1], np.int32)), eng.program_capacity) == 1
     eng.answer_batch([_query(eng.base_pods, 0), _query(eng.base_pods, 1)],
                      slots=[0, 1])
-    got = [(r.fields["slice_writes"], r.fields["scatter_writes"])
-           for r in eng.last_batch_spans if r.name == "serve/chunk/enqueue"]
+    enqueued = [r.fields for r in eng.last_batch_spans
+                if r.name == "serve/chunk/enqueue"]
+    got = [(f["slice_writes"], f["scatter_writes"]) for f in enqueued]
     assert got and all(s >= 1 and c == 0 for s, c in got), got
+    # each lane gathers ITS champion's words (``vm.select_slot``), so the
+    # program is per lane here and the loop turns once a block of slots
+    assert all(f["blocked_loops"] >= 1 and f["plain_loops"] == 0
+               for f in enqueued), enqueued

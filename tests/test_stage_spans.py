@@ -329,9 +329,13 @@ def test_four_device_generation_leaves_the_mesh_spans(micro_workload,
         # the (lanes, capacity) bucket: every run of the rule a slice
         "slice_writes": launch.fields["slice_writes"], "scatter_writes": 0,
         # and its operand fetch: every run of that rule one gather
-        "merged_reads": launch.fields["merged_reads"], "split_reads": 0}
+        "merged_reads": launch.fields["merged_reads"], "split_reads": 0,
+        # and its trip structure: a block of slots a turn on every device
+        "blocked_loops": launch.fields["blocked_loops"], "plain_loops": 0,
+        "turns": -(-longest // vm.SLOT_BLOCK)}
     assert launch.fields["slice_writes"] >= 1
     assert launch.fields["merged_reads"] >= 1
+    assert launch.fields["blocked_loops"] >= 1
     assert longest < launch.fields["capacity"]
     mesh_spans = [r for r in got if r.name.startswith("mesh/")]
     top = sorted((r for r in mesh_spans if r.parent_id == launch.span_id),

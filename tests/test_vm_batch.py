@@ -286,8 +286,9 @@ def _count_slot_iterations(monkeypatch, fn, *args):
 ])
 def test_trip_count_is_the_longest_live_program(monkeypatch, mix, lanes):
     """A stack whose longest program has k live ops at capacity 512 runs
-    k slot iterations (pad lanes repeat the last program and never raise
-    the bound), and every lane scores what its program scores alone."""
+    k slot iterations rounded up to whole blocks of ``vm.SLOT_BLOCK`` (pad
+    lanes repeat the last program and never raise the bound), and every
+    lane scores what its program scores alone."""
     rng = np.random.default_rng(21)
     progs = [vm.compile_policy(c, N, G, capacity=CAP)
              for c in _mix_codes(mix)]
@@ -298,7 +299,9 @@ def test_trip_count_is_the_longest_live_program(monkeypatch, mix, lanes):
     batched = jax.vmap(vm.score, in_axes=(0, None, None))
     got, slots = _count_slot_iterations(monkeypatch, batched, stacked, pod,
                                         nodes)
-    assert slots == max(int(p.n_ops) for p in progs) < CAP
+    longest = max(int(p.n_ops) for p in progs)
+    assert slots == -(-longest // vm.SLOT_BLOCK) * vm.SLOT_BLOCK < CAP
+    assert slots - longest < vm.SLOT_BLOCK
     monkeypatch.undo()
     for i, prog in enumerate(progs):
         np.testing.assert_array_equal(
@@ -428,13 +431,15 @@ def _walk(jaxpr):
             yield from _walk(sub)
 
 
-def _assert_one_slice_write_a_slot(closed_jaxpr, capacity):
+def _assert_one_slice_write_a_slot(closed_jaxpr, capacity,
+                                   block=vm.SLOT_BLOCK):
     """What JAX's batching rule for ``dynamic_update_slice`` makes of the
     op-slot loop's row write: a ``scatter`` of the register file, every
     slot (on the chip a bounds test over its index vector and a copy of
     the whole file). The body of every op-slot loop must hold no scatter
     on a value of the file's shape and exactly ONE ``dynamic_update_slice``
-    on it (``vm._row_writer``)."""
+    on it (``vm._row_writer``) a slot: ``block`` of them a turn where the
+    programs are batched (``vm._slot_loop``), one for one program alone."""
     regs = vm.N_INPUTS + vm.CONST_POOL + capacity
     loops = _op_slot_loops(closed_jaxpr.jaxpr, regs)
     assert loops, "no op-slot loop in the program"
@@ -445,7 +450,7 @@ def _assert_one_slice_write_a_slot(closed_jaxpr, capacity):
                                                   "dynamic_update_slice"))
                   and any(len(v.aval.shape) >= 3
                           and v.aval.shape[-3] == regs for v in b.outvars)]
-        assert writes == ["dynamic_update_slice"], writes
+        assert writes == ["dynamic_update_slice"] * block, writes
     return len(loops)
 
 
@@ -472,11 +477,11 @@ def test_the_default_rule_scatter_would_be_caught(monkeypatch):
     assert _assert_one_slice_write_a_slot(
         jax.make_jaxpr(batched)(stacked, pod, nodes), 256) == 1
     assert _assert_one_slice_write_a_slot(
-        jax.make_jaxpr(vm.score)(progs[0], pod, nodes), 256) == 1
+        jax.make_jaxpr(vm.score)(progs[0], pod, nodes), 256, block=1) == 1
     monkeypatch.setattr(
         vm, "_write_row",
-        lambda regs, res, row: lax.dynamic_update_index_in_dim(
-            regs, res, row, 0))
+        lambda regs, res, row, axis: lax.dynamic_update_index_in_dim(
+            regs, res, row, axis))
     # fresh functions: a trace of the same function object is cached
     as_it_was = jax.vmap(lambda *a: vm.score(*a), in_axes=(0, None, None))
     with pytest.raises(AssertionError, match="scatter"):
@@ -484,7 +489,7 @@ def test_the_default_rule_scatter_would_be_caught(monkeypatch):
             jax.make_jaxpr(as_it_was)(stacked, pod, nodes), 256)
     assert _assert_one_slice_write_a_slot(
         jax.make_jaxpr(lambda *a: vm.score(*a))(progs[0], pod, nodes),
-        256) == 1
+        256, block=1) == 1
 
 
 def test_unbatched_score_never_reaches_the_write_rule():
@@ -601,10 +606,12 @@ def _file_gathers(closed_jaxpr, capacity):
 
 def _assert_one_gather_a_slot(closed_jaxpr, capacity):
     """The merged fetch: every op-slot loop gathers from the register file
-    ONCE a slot (three rows a lane) and never from a row (COL)."""
+    ONCE a slot (three rows a lane), ``vm.SLOT_BLOCK`` times a turn, and
+    never from a row (COL)."""
     found = _file_gathers(closed_jaxpr, capacity)
     for from_file, from_row in found:
-        assert len(from_file) == 1 and not from_row, (from_file, from_row)
+        assert len(from_file) == vm.SLOT_BLOCK and not from_row, (
+            from_file, from_row)
     return len(found)
 
 
@@ -640,7 +647,8 @@ def test_the_default_rule_gathers_would_be_caught(monkeypatch):
     batched = jax.vmap(lambda *a: vm.score(*a), in_axes=(0, None, None))
     jaxpr = jax.make_jaxpr(batched)(stacked, pod, nodes)
     (from_file, from_row), = _file_gathers(jaxpr, 256)
-    assert len(from_file) == 3 and len(from_row) == 1
+    assert len(from_file) == 3 * vm.SLOT_BLOCK
+    assert len(from_row) == vm.SLOT_BLOCK
     with pytest.raises(AssertionError):
         _assert_one_gather_a_slot(jaxpr, 256)
 

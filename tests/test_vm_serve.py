@@ -335,7 +335,10 @@ def test_serve_program_writes_a_register_as_one_slice(vm_engine):
     the op-slot loop's body holds one ``dynamic_update_slice`` of the
     register file and no scatter of it, the executables say so on the
     ``enqueue`` span (``slice_writes`` / ``scatter_writes``: how the rule
-    ran while each was traced), and a swap changes neither."""
+    ran while each was traced), and a swap changes neither. The loop turns
+    once a SLOT there (``blocked_loops`` / ``plain_loops`` both 0: the
+    program is never per lane, so the rule of ``vm._slot_loop`` that
+    chooses between them is never reached)."""
     from tests.test_vm_batch import _assert_one_slice_write_a_slot
 
     eng = vm_engine
@@ -343,11 +346,14 @@ def test_serve_program_writes_a_register_as_one_slice(vm_engine):
     batch = eng._example_batch(2, 8)
     assert _assert_one_slice_write_a_slot(
         jax.make_jaxpr(fn)(eng._prog_dev, *batch),
-        eng.program_capacity) == 1
+        eng.program_capacity, block=1) == 1
     eng.answer_batch([_query(3), _query(9, 5)])
-    got = [(r.fields["slice_writes"], r.fields["scatter_writes"])
-           for r in eng.last_batch_spans if r.name == "serve/chunk/enqueue"]
+    enqueued = [r.fields for r in eng.last_batch_spans
+                if r.name == "serve/chunk/enqueue"]
+    got = [(f["slice_writes"], f["scatter_writes"]) for f in enqueued]
     assert got and all(s >= 1 and c == 0 for s, c in got), got
+    assert all((f["blocked_loops"], f["plain_loops"]) == (0, 0)
+               for f in enqueued), enqueued
 
 
 def test_transpile_cache_makes_reswap_warm(wl, envelope):

@@ -122,6 +122,7 @@ def fused_ops(hlo: str, row: dict) -> list:
     return [m.group(3) for m in map(_INSTR.match, lines) if m] or [row["op"]]
 
 
+@functools.lru_cache(maxsize=4)
 def _while_bodies(hlo: str) -> dict:
     """name -> `_kernels` of every ``while`` body of the module."""
     comps = computations(hlo)
@@ -130,9 +131,14 @@ def _while_bodies(hlo: str) -> dict:
 
 
 def loop_body(hlo: str) -> list:
-    """The kernels of the module's largest ``while`` body (`_kernels`):
-    the event loop of a benchmark cell's program."""
-    return max(_while_bodies(hlo).values(), key=len, default=[])
+    """The kernels of the event loop of a benchmark cell's program
+    (`_kernels`): the largest ``while`` body that itself holds a ``while``
+    (the op-slot loop, which a block of slots a turn makes the larger of
+    the two), or the largest body of all where none nests (a program that
+    interprets nothing)."""
+    bodies = list(_while_bodies(hlo).values())
+    outer = [b for b in bodies if any(r["op"] == "while" for r in b)]
+    return max(outer or bodies, key=len, default=[])
 
 
 def slot_loop(hlo: str) -> list:
@@ -140,14 +146,12 @@ def slot_loop(hlo: str) -> list:
     ``while`` inside `loop_body` that carries the largest array, which is
     the register file ``[lanes, rows, N, G]``. Empty where the event loop
     holds no ``while`` (a program that interprets nothing)."""
-    bodies = _while_bodies(hlo)
-    inner = [r for r in max(bodies.values(), key=len, default=[])
-             if r["op"] == "while"]
+    inner = [r for r in loop_body(hlo) if r["op"] == "while"]
     if not inner:
         return []
     file_loop = max(inner, key=lambda r: max(
         (math.prod(dims) for _, dims, _ in r["arrays"]), default=0))
-    return bodies[file_loop["calls"]]
+    return _while_bodies(hlo)[file_loop["calls"]]
 
 
 def operand_layouts(hlo: str, shape: tuple) -> collections.Counter:
