@@ -399,14 +399,20 @@ class CodeEvaluator:
             pass
 
     def _vm_traced_fields(self, bucket: Tuple[int, int], slots: int,
-                          before: Tuple[int, ...]) -> Dict[str, int]:
+                          before: Tuple[int, ...], opcode,
+                          shards: int) -> Dict[str, int]:
         """``slice_writes`` / ``scatter_writes``, ``merged_reads`` /
         ``split_reads`` and ``blocked_loops`` / ``plain_loops`` of a
         batched launch: how the bucket's runner lowered the op-slot loop's
         row write, its operand fetch and its trip structure
         (``vm.write_count``, ``vm.read_count``, ``vm.loop_count``, counted
         while a program is traced), and ``turns``: the turns that loop
-        makes an event over the launch's ``slots`` (``vm.loop_turns``).
+        makes an event over the launch's ``slots`` (``vm.loop_turns``),
+        and ``wide_turns``: those of them in which some lane of a device
+        holds an opcode of ``vm.WIDE``, so that the turn runs the whole
+        opcode table and not the narrow one (``vm.loop_wide_turns`` over
+        the launch's stacked ``opcode`` words, ``shards`` devices each
+        with its own lanes, on the host).
         The launch that moved a count since ``before``
         (``vm.trace_counts``) traced the bucket's program and the
         difference stays with the bucket; every later launch of it traces
@@ -416,7 +422,9 @@ class CodeEvaluator:
             self._vm_traced[bucket] = traced
         traced = self._vm_traced.get(bucket, (0,) * 6)
         return dict(zip(vm.TRACE_FIELDS, traced),
-                    turns=vm.loop_turns(slots, *traced[4:]))
+                    turns=vm.loop_turns(slots, *traced[4:]),
+                    wide_turns=vm.loop_wide_turns(
+                        opcode, slots, *traced[4:], shards=shards))
 
     def _run_vm_batch(self, progs: List[vm.VMProgram]) -> List[SimResult]:
         """Evaluate stacked VM candidates in ONE device launch — sharded
@@ -437,7 +445,8 @@ class CodeEvaluator:
         # footprint the bucket's runner outside the launch span: the
         # once-per-bucket AOT lower must not land on the device clock
         # (same branch condition as the dispatch below)
-        if not (self._n_shards > 1 and self.suite is None):
+        sharded = self._n_shards > 1 and self.suite is None
+        if not sharded:
             self._maybe_record_vm_footprint(self._vm_pop_runner(),
                                             stacked, pop)
         # launch + wait_device is the device's part of the generation (a
@@ -453,6 +462,7 @@ class CodeEvaluator:
         capacity = int(stacked.opcode.shape[-1])
         view = self.cfg.resolve_prefilter_k(c.n_padded) or c.n_padded
         slots = max(int(p.n_ops) for p in progs)
+        opcode = np.asarray(stacked.opcode)
         with obs.span("tier/vm_batch/launch", lanes=pop,
                       shards=self._n_shards, start_event=self.start_event,
                       slots=slots,
@@ -461,7 +471,7 @@ class CodeEvaluator:
                       * vm.register_rows(capacity) * view * c.g_padded
                       * stacked.imm.dtype.itemsize) as t_launch:
             traced0 = vm.trace_counts()
-            if self._n_shards > 1 and self.suite is None:
+            if sharded:
                 # each device interprets pop/shards lanes; the elite
                 # outputs are discarded here (the evolution loop ranks on
                 # the host, where admission/dedup live). Suite mode skips
@@ -473,7 +483,9 @@ class CodeEvaluator:
             else:
                 result = self._vm_pop_runner()(stacked, self.state0)
             t_launch.set(**self._vm_traced_fields((pop, capacity), slots,
-                                                  traced0))
+                                                  traced0, opcode,
+                                                  self._n_shards if sharded
+                                                  else 1))
         self._last_scores = result.policy_score
         with obs.span("tier/vm_batch/wait_device"):
             jax.block_until_ready(result)

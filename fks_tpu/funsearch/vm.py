@@ -39,7 +39,14 @@ turn (`_slot_loop`); NOP padding past the last turn never runs. Each slot
 is a ``lax.switch`` over a deliberately minimal 33-opcode table on [N, G]
 values (scalar literals load from a pooled register block, not op slots;
 boolean and sign ops are canonicalized into arithmetic at lowering — see the
-CONST_POOL / opcode-table comments below for the vmap rationale). Numeric model: everything runs at the
+CONST_POOL / opcode-table comments below for the vmap rationale). Under
+``vmap`` every branch of that table runs for every lane and a select keeps
+one, and eight branches (`WIDE`: REM and the seven transcendentals) are
+half of what a slot's write then costs on the chip; so the table has a
+NARROW form without them, and the batched loop walks RUNS of turns: a
+block in which no lane holds a WIDE opcode runs on the narrow form, every
+other block on the whole table, chosen on the device from the opcode words
+once an event, so no live slot ever meets a stand-in. Numeric model: everything runs at the
 AMBIENT float precision — f64 when x64 is on (CPU tests / golden parity,
 where the transpiler also computes floats in f64, matching the reference's
 CPython binary64), f32 otherwise (TPU, where the jit tier is f32 too).
@@ -110,6 +117,19 @@ CONST_POOL = 32
  OP_ISFIN, OP_REM, OP_POW, OP_EXP, OP_LOG, OP_SQRT,
  OP_SIN, OP_COS, OP_TAN, OP_COL, OP_RSUM_G, OP_RMAX_G, OP_RMIN_G,
  OP_SETCOL) = range(33)
+
+#: the opcodes whose branch is DEAR on the chip: under ``vmap`` every
+#: branch of the table runs for every lane in every slot and a select keeps
+#: one, so these eight are most of what a slot's write kernel computes
+#: whether or not a lane holds one (PERF.md section 6, PR 47: the chip's
+#: readings with each group stubbed). The table therefore has a NARROW
+#: form, `_branches(n, g, narrow=True)`: every opcode keeps its number and
+#: a WIDE one's place holds NOP's stand-in, which costs nothing. The
+#: batched op-slot loop runs a block of slots on the narrow form wherever
+#: no lane holds a WIDE opcode in it, and on the whole table elsewhere
+#: (`_slot_loop`), so no live slot ever selects a stand-in. Membership is
+#: by what a branch costs on the chip and by nothing else.
+WIDE = (OP_REM, OP_POW, OP_EXP, OP_LOG, OP_SQRT, OP_SIN, OP_COS, OP_TAN)
 
 
 class VMUnsupported(Exception):
@@ -755,7 +775,10 @@ def _inputs(pod: PodView, nodes: NodeView) -> jax.Array:
     return jnp.stack(rows)
 
 
-def _branches(n: int, g: int):
+def _branches(n: int, g: int, narrow: bool = False):
+    """The opcode table, in opcode order. ``narrow``: the same table with
+    NOP's branch in the places of the `WIDE` opcodes, for a block of slots
+    in which no lane holds one (`_slot_loop`)."""
     F = _ambient_float()
 
     def red(fn):
@@ -767,7 +790,7 @@ def _branches(n: int, g: int):
         c = jnp.clip(im.astype(jnp.int32), 0, g - 1)
         return jnp.broadcast_to(_col_picker(0)(va, c), (n, g))
 
-    return [
+    table = [
         lambda va, vb, vc, im: va,  # NOP (value = operand a)
         lambda va, vb, vc, im: va + vb,
         lambda va, vb, vc, im: va - vb,
@@ -803,6 +826,10 @@ def _branches(n: int, g: int):
         lambda va, vb, vc, im: jnp.where(  # SETCOL: va with column im := vb
             jnp.arange(g)[None, :] == im.astype(jnp.int32), vb, va),
     ]
+    if narrow:
+        for op in WIDE:
+            table[op] = table[OP_NOP]
+    return table
 
 
 def _execute(prog: VMProgram, pod: PodView, nodes: NodeView,
@@ -875,6 +902,25 @@ def loop_turns(slots: int, blocked: int, plain: int) -> int:
     return -(-slots // SLOT_BLOCK) if blocked and not plain else slots
 
 
+def loop_wide_turns(opcode, slots: int, blocked: int, plain: int,
+                    shards: int = 1) -> int:
+    """Of `loop_turns`' turns, those that run the WHOLE opcode table: the
+    device's rule (`_slot_loop`: a block is wide if ANY of the lanes that
+    share the loop holds a `WIDE` opcode in it) in NumPy, over the live
+    turns of a launch whose stacked opcode words are ``opcode`` ``[lanes,
+    capacity]``; sharded ``shards`` ways along the lanes, the count of the
+    shard that has most. Every turn where the loop is not blocked: the
+    one-slot turn knows the whole table only."""
+    if not blocked or plain:
+        return slots
+    opcode = np.asarray(opcode)
+    lanes, cap = opcode.shape
+    wide = np.isin(opcode, WIDE).reshape(
+        shards, lanes // shards, cap // SLOT_BLOCK, SLOT_BLOCK)
+    live = wide.any(axis=(1, 3))[:, :loop_turns(slots, blocked, plain)]
+    return int(live.sum(axis=1).max())
+
+
 @functools.lru_cache(maxsize=None)
 def _slot_loop(axis: int):
     """``run(regs, opcode, a, b, c, imm, bound)``: the op-slot loop over a
@@ -898,6 +944,26 @@ def _slot_loop(axis: int):
     bound, which no runner makes, it keeps the one-slot turn and is
     counted (`loop_count`).
 
+    The blocked loop walks RUNS of turns. Under ``vmap`` the opcode is
+    per-lane data, so the whole table's 33 branches run for every lane in
+    every slot, and the eight of `WIDE` are half of what the write kernel
+    then costs on the chip (0.92 us a slot with them, 0.48 without; PERF.md
+    section 6, PR 47). From the lanes' opcode words the rule takes, once
+    an event and unbatched like the bound (`_block_runs`, `_any_lane`),
+    for every block the next block at or after it in which some lane holds
+    a WIDE opcode and the next in which none does; an outer ``while`` then
+    runs the narrow turns (`_branches(n, g, narrow=True)`) up to the next
+    wide block and the wide turns (the whole table) up to the next narrow
+    one, each run a ``fori_loop`` that may not turn at all. Every live
+    slot selects the branch it selected before and a narrow turn holds no
+    lane that could select a stand-in, so again control flow moves and
+    arithmetic does not. Every loop is a ``while`` that carries the file,
+    which therefore stays in the chip's memory space 1; a ``lax.cond`` a
+    turn would lose that, and a conditional a slot is the scalar-core wait
+    a slot that the blocks removed (ISSUE 47). A generation that holds no
+    WIDE opcode never enters the wide turn; one that holds them in every
+    block runs what it ran before, plus a few scalar fetches an event.
+
     A ``vmap`` that batches the file alone (queries, scenarios; serving
     never batches the program) goes to the loop of the next axis, which
     traces what JAX's own rules trace there, and leaves the rule within
@@ -905,28 +971,48 @@ def _slot_loop(axis: int):
 
     def run(block, regs, opcode, a, b, c, imm, bound):
         n, g = regs.shape[axis + 1:]
-        branches = _branches(n, g)
         op_base = regs.shape[axis] - opcode.shape[-1]
 
-        def slot(k, regs):
-            op, *operands = _slot_operands(axis)(
-                regs, opcode, a, b, c, imm, k)
-            res = _per_file(lambda *xs: lax.switch(op, branches, *xs), axis,
-                            in_axes=(0, 0, 0, None))(*operands)
-            return _write_row(regs, res, op_base + k, axis)
+        def slot_on(branches):
+            def slot(k, regs):
+                op, *operands = _slot_operands(axis)(
+                    regs, opcode, a, b, c, imm, k)
+                res = _per_file(lambda *xs: lax.switch(op, branches, *xs),
+                                axis, in_axes=(0, 0, 0, None))(*operands)
+                return _write_row(regs, res, op_base + k, axis)
+            return slot
 
         if block == 1:
-            return lax.fori_loop(0, bound, slot, regs)
-        # one trace of the slot a block, not ``block`` of them: a turn's
-        # calls share a jaxpr, which XLA inlines into the same kernels
-        slot_once = jax.jit(slot)
+            return lax.fori_loop(0, bound, slot_on(_branches(n, g)), regs)
 
-        def turn(i, regs):
-            for j in range(block):
-                regs = slot_once(i * block + j, regs)
-            return regs
+        def turn_on(branches):
+            # one trace of the slot a block, not ``block`` of them: a
+            # turn's calls share a jaxpr, which XLA inlines into the same
+            # kernels
+            slot_once = jax.jit(slot_on(branches))
 
-        return lax.fori_loop(0, (bound + block - 1) // block, turn, regs)
+            def turn(i, regs):
+                for j in range(block):
+                    regs = slot_once(i * block + j, regs)
+                return regs
+            return turn
+
+        narrow_turn = turn_on(_branches(n, g, narrow=True))
+        wide_turn = turn_on(_branches(n, g))
+        turns = (bound + block - 1) // block
+        next_wide, next_narrow = _block_runs(opcode, block)
+
+        def run_pair(carry):
+            # the narrow turns up to the next wide block, then the wide
+            # ones up to the next narrow block; either run may be empty
+            i, regs = carry
+            s = jnp.minimum(next_wide[i], turns)
+            regs = lax.fori_loop(i, s, narrow_turn, regs)
+            e = jnp.minimum(next_narrow[s], turns)
+            return e, lax.fori_loop(s, e, wide_turn, regs)
+
+        return lax.while_loop(lambda carry: carry[0] < turns, run_pair,
+                              (jnp.zeros_like(turns), regs))[1]
 
     loop = jax.custom_batching.custom_vmap(functools.partial(run, 1))
 
@@ -949,6 +1035,46 @@ def _slot_loop(axis: int):
             axis_size=axis_size)(regs, *words, bound), True
 
     return loop
+
+
+@jax.custom_batching.custom_vmap
+def _any_lane(wide: jax.Array) -> jax.Array:
+    """``bool[capacity]``, "this slot holds a `WIDE` opcode": one
+    program's own and, under ``vmap`` over stacked programs, ONE vector
+    for the whole batch, "some lane holds one here". Like `_loop_bound`
+    the rule reduces over the lanes that share the loop, declares the
+    result UNBATCHED, so that every ``while`` of the loop over runs keeps
+    a scalar predicate, and goes through the primitive again for an
+    enclosing ``vmap`` that also batches the programs; inside
+    ``shard_map`` the lanes are the device's own (no collective). Reached
+    from `_slot_loop`'s blocked form only, which serving never traces."""
+    return wide
+
+
+@_any_lane.def_vmap
+def _any_lane_lanes(axis_size, in_batched, wide):
+    del axis_size, in_batched  # one operand: the rule runs only if batched
+    return _any_lane(jnp.any(wide, axis=0)), False
+
+
+def _block_runs(opcode: jax.Array, block: int):
+    """``(next_wide, next_narrow)``, each ``i32[blocks + 1]``, from a
+    lane's opcode words under the blocked loop's ``vmap``: the first block
+    of ``block`` slots at or after ``i`` in which some lane holds a `WIDE`
+    opcode / in which none does, ``blocks`` where there is none (so the
+    last entry, which ends a run at the table's end). Taken on the device
+    from the tables already there, once an event: a new generation changes
+    values, never a shape."""
+    wide = _any_lane(functools.reduce(
+        jnp.logical_or, (opcode == op for op in WIDE)))
+    blocks = opcode.shape[-1] // block
+    wide = wide.reshape((blocks, block)).any(axis=1)
+    at = jnp.arange(blocks, dtype=jnp.int32)
+    end = jnp.full((1,), blocks, jnp.int32)
+    return tuple(
+        jnp.concatenate([lax.cummin(jnp.where(here, at, blocks),
+                                    reverse=True), end])
+        for here in (wide, ~wide))
 
 
 @jax.custom_batching.custom_vmap

@@ -26,6 +26,7 @@ SPAN_METRICS = ("tier.preflight_ms_per_call", "tier.transpile_ms_per_call",
                 "tier.traces_per_source", "vm.ops_kept_share",
                 "vm.scatter_write_share", "tier.pooled_source_share",
                 "vm.merged_read_share", "vm.slots_per_turn",
+                "vm.narrow_turn_share",
                 # the ring's other writers (PR 40; chipbench/reduce/hostspans.py)
                 "tier.lower_ms_per_source", "tier.pack_ms_per_call",
                 "tier.pool_overhead_ms_per_call", "tier.gc_ms_per_call",

@@ -95,10 +95,10 @@ def test_benchmark_json_only_gained_entries():
     assert len(bench["workloads"]) == 9 and len(bench["configs"]) == 7
     assert sum(w["chips"] == 4 for w in bench["workloads"]) == 1
     # at the end as PR 45 left it; PR 46 appended the interpreter's
-    # slots a turn after
-    assert [m["name"] for m in bench["per_layer"][-2:]] == [
-        NEW, "vm.slots_per_turn"]
-    new = bench["per_layer"][-2]
+    # slots a turn after, PR 47 its narrow turns' share
+    assert [m["name"] for m in bench["per_layer"][-3:]] == [
+        NEW, "vm.slots_per_turn", "vm.narrow_turn_share"]
+    new = bench["per_layer"][-3]
     meta = json.load(open(os.path.join(cells.HERE, "metrics",
                                        NEW + ".json")))
     assert new == {**{k: meta[k] for k in (

@@ -234,12 +234,13 @@ def test_the_seven_are_declared_at_the_end_with_their_files():
     bench = json.load(open(os.path.join(cells.ROOT, "BENCHMARK.json")))
     # at the end as PR 40 left it; PR 42 appended its cell's two after,
     # PR 44 the interpreter's merged-read share, PR 45 the typed pods',
-    # PR 46 the interpreter's slots a turn
+    # PR 46 the interpreter's slots a turn, PR 47 its narrow turns' share
     seven = bench["per_layer"][41:41 + 7]
     assert [m["name"] for m in seven] == list(METRICS)
     assert [m["name"] for m in bench["per_layer"][41 + 7:]] == [
         "sim.fork_replay_us_per_event", "sim.fork_waiting_pods",
-        "vm.merged_read_share", "sim.typed_pod_share", "vm.slots_per_turn"]
+        "vm.merged_read_share", "sim.typed_pod_share", "vm.slots_per_turn",
+        "vm.narrow_turn_share"]
     layers = {m["layer"] for m in bench["per_layer"][:41]}
     for m in seven:
         unit, source, layer, workloads = METRICS[m["name"]]

@@ -92,9 +92,10 @@ def test_benchmark_json_only_gained_entries():
     new = bench["per_layer"][at:at + 2]
     assert [m["name"] for m in new] == list(NEW)
     # appended since, at the end: PR 44's metric of the code cells,
-    # PR 45's of the typed cell and PR 46's of the code cells
+    # PR 45's of the typed cell and PR 46's and PR 47's of the code cells
     assert [m["name"] for m in bench["per_layer"][at + 2:]] == [
-        "vm.merged_read_share", "sim.typed_pod_share", "vm.slots_per_turn"]
+        "vm.merged_read_share", "sim.typed_pod_share", "vm.slots_per_turn",
+        "vm.narrow_turn_share"]
     later = "openb1523-gpuspec25-loaded.codegen8"   # PR 45's forked cell
     for m in new:
         assert m["workloads"] == [CELL, later]

@@ -218,6 +218,25 @@ def test_slots_per_turn_reads_the_launch_spans_fields(fields, want):
     assert got == (want if want is None else pytest.approx(want))
 
 
+@pytest.mark.parametrize("fields,want", [
+    # the cells' generations: one block of 37 holds a champion's REM
+    ({"slots": 292, "turns": 37, "wide_turns": 1}, 100.0 * 36 / 37),
+    ({"slots": 292, "turns": 37, "wide_turns": 0}, 100.0),   # no WIDE opcode
+    ({"slots": 292, "turns": 37, "wide_turns": 37}, 0.0),    # in every block
+    # the one-slot turn knows the whole table only
+    ({"slots": 292, "turns": 292, "wide_turns": 292}, 0.0),
+    ({"slots": 0, "turns": 0, "wide_turns": 0}, None),       # nothing ran
+    # a parent without the field (older than PR 47)
+    ({"slots": 292, "turns": 37, "blocked_loops": 1, "plain_loops": 0},
+     None),
+    ({}, None),
+])
+def test_narrow_turn_share_reads_the_launch_spans_fields(fields, want):
+    got = cells.metric_reader("vm.narrow_turn_share")(
+        {"_span_calls": _launch_calls(fields)})
+    assert got == (want if want is None else pytest.approx(want))
+
+
 def _transpile_calls(fields):
     """The window's calls of three generations (the first is the warm-up),
     each with a ``tier/transpile`` span that carries ``fields``."""
@@ -346,8 +365,8 @@ def test_every_span_metric_is_declared_with_its_files():
     # serve.retry_share (PR 37), tier.pooled_source_share (PR 39),
     # sim.fork_replay_us_per_event and sim.fork_waiting_pods (PR 42),
     # vm.merged_read_share (PR 44), sim.typed_pod_share (PR 45),
-    # vm.slots_per_turn (PR 46)
-    assert len(SPAN_METRICS) == 32
+    # vm.slots_per_turn (PR 46), vm.narrow_turn_share (PR 47)
+    assert len(SPAN_METRICS) == 33
     for name in SPAN_METRICS:
         assert name in declared, name
         meta = json.load(open(os.path.join(cells.HERE, "metrics",
@@ -404,7 +423,7 @@ def test_traced_cell_reports_every_span_metric(name, tmp_path, monkeypatch):
             + v["serve.h2d_ms_per_call"] + v["serve.harvest_ms_per_call"]
         assert v["serve.h2d_kb_per_call"] > 0 and v["serve.d2h_kb_per_call"] > 0
     else:
-        assert len(want) == 15
+        assert len(want) == 16
         # a recorded generation: every source traced once, where it runs,
         # and the simplifier dropped part of what the lowering emitted
         assert res["metrics"]["tier.traces_per_source"]["value"] == 1.0
@@ -425,6 +444,12 @@ def test_traced_cell_reports_every_span_metric(name, tmp_path, monkeypatch):
         assert launch["turns"] == -(-launch["slots"] // vm.SLOT_BLOCK)
         assert res["metrics"]["vm.slots_per_turn"]["value"] \
             == pytest.approx(launch["slots"] / launch["turns"])
+        # and ran the narrow opcode table but where a lane holds a WIDE
+        # opcode (a ledger champion holds one REM)
+        assert 0 <= launch["wide_turns"] <= 1
+        assert res["metrics"]["vm.narrow_turn_share"]["value"] \
+            == pytest.approx(100.0 * (1 - launch["wide_turns"]
+                                      / launch["turns"]))
         # the generations went through the process's lowering pool as
         # far as this machine has the cores and the workers were up
         assert 0.0 <= res["metrics"]["tier.pooled_source_share"]["value"] \

@@ -90,7 +90,9 @@ def test_benchmark_json_only_gained_entries():
         # PR 45's share of pods that name their GPU models
         "sim.typed_pod_share",
         # PR 46's slots a turn of the interpreter's loop
-        "vm.slots_per_turn"]
+        "vm.slots_per_turn",
+        # PR 47's share of its turns that ran the narrow opcode table
+        "vm.narrow_turn_share"]
     new = bench["per_layer"][at:at + 2]
     for m in new:
         assert m["workloads"] == [CELL] and m["layer"] == "serving serve/"

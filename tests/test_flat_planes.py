@@ -180,7 +180,7 @@ def test_param256_compiles_with_the_population_on_the_lanes():
     assert 30 <= kernels <= 70, kernels
 
 
-# ------------------------- the interpreter's op-slot loop (PRs 44, 46)
+# --------------------- the interpreter's op-slot loop (PRs 44, 46, 47)
 
 def _described_device():
     device = describe_compile.topology_device("v5e:2x2")
@@ -190,52 +190,80 @@ def _described_device():
     return device
 
 
-def _slot_kernels(hlo):
-    """The op-slot loop's kernels: what is not arithmetic on the scalar
-    core (the loop counter and the row it writes)."""
-    return [r for r in describe_compile.slot_loop(hlo)
-            if any(dims for _, dims, _ in r["arrays"])]
+def _vector_kernels(rows):
+    """Of a loop's instructions, the kernels: what is not arithmetic on
+    the scalar core (the loop counter and the row it writes)."""
+    return [r for r in rows if any(dims for _, dims, _ in r["arrays"])]
+
+
+#: what the branches of ``vm.WIDE`` are inside a compiled write
+WIDE_HLO = {"remainder", "power", "exponential", "log", "sqrt", "sine",
+            "cosine", "tan"}
 
 
 @pytest.mark.parametrize("cluster,view", [("16", 16), ("1523", 64)])
 def test_codegen_slot_fetches_its_operands_with_one_gather(cluster, view):
-    """The batched VM tier's op-slot loop, compiled for a described v5e,
-    read per BLOCK of ``vm.SLOT_BLOCK`` slots (PR 46; the ``while`` turns
-    once a block): a slot is ONE gather from the register file (three rows
-    a lane), no gather of COL's own from a row, and one write of the file
-    in place in the ONE layout the ``while`` carries (no copy and no
+    """The batched VM tier's op-slot loop, compiled for a described v5e.
+    Since PR 47 it is a loop over RUNS of turns that holds two inner
+    ``while``s over the register file, the narrow turn's and the wide
+    turn's, each read per BLOCK of ``vm.SLOT_BLOCK`` slots (PR 46). In
+    each of the two: a slot is ONE gather from the register file (three
+    rows a lane), no gather of COL's own from a row, and one write of the
+    file in place in the ONE layout the ``while`` carries (no copy and no
     second layout of it); the index word is fetched once a block; and no
     more than 9 kernels a slot (PR 44: 11, its parent 14, four of them
-    gathers)."""
+    gathers). The narrow turn's writes hold no branch of ``vm.WIDE`` and
+    the wide turn's hold all eight. The file lies in memory space 1
+    wherever the module names it (a ``lax.cond`` a turn loses that, and
+    the event's one copy of the file is then priced 66 times dearer:
+    ISSUE 47), and no loop copies it."""
     from fks_tpu.funsearch import vm
 
     lanes, g, block = 8, 8, vm.SLOT_BLOCK
     with jax.enable_x64(False):   # the chip's program: int32 / float32
         hlo = describe_compile.codegen(_described_device(), cluster, lanes)
-    kernels = _slot_kernels(hlo)
-    inside = {r["name"]: describe_compile.fused_ops(hlo, r) for r in kernels}
-    gathers = [r for r in kernels if "gather" in inside[r["name"]]]
-    assert [r["arrays"][0][1] for r in gathers] \
-        == [(3 * lanes, view, g)] * block
     file_shape = (lanes, vm.register_rows(512), view, g)
-    files = [(r["op"], a[2], inside[r["name"]].count("dynamic-update-slice"))
-             for r in kernels for a in r["arrays"] if a[1] == file_shape]
-    assert len(files) == block and len(set(files)) == 1, files
-    assert files[0][0] == "fusion" and files[0][2] == 1, files
+    run_loop, *turns = describe_compile.slot_loops(hlo, outer=True)
+    assert len(turns) == 2
     carried = [a[2] for r in describe_compile.loop_body(hlo)
                if r["op"] == "while" for a in r["arrays"]
                if a[1] == file_shape]
-    assert carried == [files[0][1]]   # the layout the loop carries it in
-    assert not [r for r in kernels if r["op"] == "copy"]
-    # the [lanes, 3, 1] index word of every slot of the block: one fetch
-    fetches = [r for r in kernels
-               if (lanes, 3, 1) in [a[1] for a in r["arrays"]]]
-    assert len(fetches) == 1 and len(fetches[0]["arrays"]) == block, fetches
-    assert len(kernels) <= 9 * block, [r["name"] for r in kernels]
-    if cluster == "16":     # the event loop is still found where the
-        # blocked slot loop is the larger body of the two
-        assert len(describe_compile.slot_loop(hlo)) \
-            > len(describe_compile.loop_body(hlo))
+    assert len(carried) == 1      # the layout the event loop hands over
+    wide_ops = []
+    for rows in turns:
+        kernels = _vector_kernels(rows)
+        inside = {r["name"]: describe_compile.fused_ops(hlo, r)
+                  for r in kernels}
+        gathers = [r for r in kernels if "gather" in inside[r["name"]]]
+        assert [r["arrays"][0][1] for r in gathers] \
+            == [(3 * lanes, view, g)] * block
+        writes = [r for r in kernels for a in r["arrays"]
+                  if a[1] == file_shape]
+        files = [(r["op"], r["arrays"][0][2],
+                  inside[r["name"]].count("dynamic-update-slice"))
+                 for r in writes]
+        assert files == [("fusion", carried[0], 1)] * block, files
+        wide_ops.append([WIDE_HLO & set(inside[r["name"]]) for r in writes])
+        # the [lanes, 3, 1] index word of every slot of the block: one fetch
+        fetches = [r for r in kernels
+                   if (lanes, 3, 1) in [a[1] for a in r["arrays"]]]
+        assert len(fetches) == 1 and len(fetches[0]["arrays"]) == block
+        assert len(kernels) <= 9 * block, [r["name"] for r in kernels]
+    assert wide_ops == [[set()] * block, [WIDE_HLO] * block], wide_ops
+    # what the run loop adds is bookkeeping: the two turns' loops and no
+    # kernel over the file
+    assert [a[1] for r in _vector_kernels(run_loop) if r["op"] != "while"
+            for a in r["arrays"] if a[1] == file_shape] == []
+    assert not [r for rows in (run_loop, *turns) for r in rows
+                if r["op"] == "copy"
+                and file_shape in [a[1] for a in r["arrays"]]]
+    # memory space 1 wherever the module names the file
+    named = describe_compile.array_mentions(hlo, file_shape)
+    assert len(named) >= 2 * block and all("S(1)" in m for m in named), \
+        [m for m in named if "S(1)" not in m]
+    if cluster == "16":     # the event loop is still found where a
+        # blocked turn is the larger body of the two
+        assert len(turns[0]) > len(describe_compile.loop_body(hlo))
 
 
 def test_whatif_slot_loop_is_the_scalar_one():

@@ -332,7 +332,10 @@ def test_four_device_generation_leaves_the_mesh_spans(micro_workload,
         "merged_reads": launch.fields["merged_reads"], "split_reads": 0,
         # and its trip structure: a block of slots a turn on every device
         "blocked_loops": launch.fields["blocked_loops"], "plain_loops": 0,
-        "turns": -(-longest // vm.SLOT_BLOCK)}
+        "turns": -(-longest // vm.SLOT_BLOCK),
+        # none of them on the whole opcode table: no lane holds an opcode
+        # of ``vm.WIDE``
+        "wide_turns": 0}
     assert launch.fields["slice_writes"] >= 1
     assert launch.fields["merged_reads"] >= 1
     assert launch.fields["blocked_loops"] >= 1

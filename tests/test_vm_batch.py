@@ -321,17 +321,23 @@ def _sub_jaxprs(eqn):
                 yield x.jaxpr
 
 
-def _op_slot_loops(jaxpr, regs):
-    """Every ``while`` of the jaxpr tree that carries a register file
-    ([..., regs, N, G])."""
+def _op_slot_loops(jaxpr, regs, which="all"):
+    """The ``while``s of the jaxpr tree that carry a register file
+    ([..., regs, N, G]): ``"all"`` of them; the ``"outermost"``, one an
+    op-slot loop of the program (where the programs are batched the loop
+    over RUNS of turns, PR 47; else the turn's own); or the ``"turns"``,
+    the innermost, whose bodies run slots (the narrow turn's and the wide
+    turn's inside a loop over runs)."""
     found = []
     for eqn in jaxpr.eqns:
+        inner = [loop for sub in _sub_jaxprs(eqn)
+                 for loop in _op_slot_loops(sub, regs, which)]
         if eqn.primitive.name == "while" and any(
                 len(v.aval.shape) >= 3 and v.aval.shape[-3] == regs
                 for v in eqn.outvars):
-            found.append(eqn)
-        for sub in _sub_jaxprs(eqn):
-            found += _op_slot_loops(sub, regs)
+            inner = {"all": [eqn] + inner, "outermost": [eqn],
+                     "turns": inner or [eqn]}[which]
+        found += inner
     return found
 
 
@@ -351,7 +357,7 @@ def _assert_unbatched_op_slot_loop(closed_jaxpr, capacity):
             if b.primitive.name == "select_n":
                 shape = b.outvars[0].aval.shape
                 assert not (len(shape) >= 3 and shape[-3] == regs), b
-    return len(loops)
+    return len(_op_slot_loops(closed_jaxpr.jaxpr, regs, "outermost"))
 
 
 def _reads_since(before):
@@ -439,10 +445,15 @@ def _assert_one_slice_write_a_slot(closed_jaxpr, capacity,
     the whole file). The body of every op-slot loop must hold no scatter
     on a value of the file's shape and exactly ONE ``dynamic_update_slice``
     on it (``vm._row_writer``) a slot: ``block`` of them a turn where the
-    programs are batched (``vm._slot_loop``), one for one program alone."""
+    programs are batched (``vm._slot_loop``: two turns' loops, the narrow
+    table's and the whole table's, inside the loop over runs), one for
+    one program alone. Returns the number of op-slot loops, a loop over
+    runs counting as one."""
     regs = vm.N_INPUTS + vm.CONST_POOL + capacity
-    loops = _op_slot_loops(closed_jaxpr.jaxpr, regs)
+    loops = _op_slot_loops(closed_jaxpr.jaxpr, regs, "turns")
+    nests = _op_slot_loops(closed_jaxpr.jaxpr, regs, "outermost")
     assert loops, "no op-slot loop in the program"
+    assert len(loops) == len(nests) * (2 if block > 1 else 1)
     for eqn in loops:
         writes = [b.primitive.name
                   for b in _walk(eqn.params["body_jaxpr"].jaxpr)
@@ -451,7 +462,7 @@ def _assert_one_slice_write_a_slot(closed_jaxpr, capacity,
                   and any(len(v.aval.shape) >= 3
                           and v.aval.shape[-3] == regs for v in b.outvars)]
         assert writes == ["dynamic_update_slice"] * block, writes
-    return len(loops)
+    return len(nests)
 
 
 @pytest.mark.parametrize("name", BATCHED_PATHS)
@@ -584,12 +595,12 @@ def test_a_batched_row_takes_the_counted_fall_back():
 # ------------------------------------------- the slot's operand fetch
 
 def _file_gathers(closed_jaxpr, capacity):
-    """Per op-slot loop of the jaxpr: the shapes of what each ``gather``
-    in its body reads from. The register file ([..., rows, N, G]) is the
+    """Per turn's loop of the jaxpr (`_op_slot_loops`): the shapes of
+    what each ``gather`` in its body reads from. The register file ([..., rows, N, G]) is the
     operand fetch; a gather from a row ([..., N, G]) is COL's column pick
     as JAX's own rules make it."""
     regs = vm.N_INPUTS + vm.CONST_POOL + capacity
-    loops = _op_slot_loops(closed_jaxpr.jaxpr, regs)
+    loops = _op_slot_loops(closed_jaxpr.jaxpr, regs, "turns")
     assert loops, "no op-slot loop in the program"
     out = []
     for eqn in loops:
@@ -612,7 +623,10 @@ def _assert_one_gather_a_slot(closed_jaxpr, capacity):
     for from_file, from_row in found:
         assert len(from_file) == vm.SLOT_BLOCK and not from_row, (
             from_file, from_row)
-    return len(found)
+    # the loops, a loop over runs (its two turns' loops) counting as one
+    return len(_op_slot_loops(
+        closed_jaxpr.jaxpr, vm.N_INPUTS + vm.CONST_POOL + capacity,
+        "outermost"))
 
 
 @pytest.mark.parametrize("name", BATCHED_PATHS)
@@ -646,7 +660,9 @@ def test_the_default_rule_gathers_would_be_caught(monkeypatch):
         lambda axis: lambda va, c: lax.dynamic_slice_in_dim(va, c, 1, axis=1))
     batched = jax.vmap(lambda *a: vm.score(*a), in_axes=(0, None, None))
     jaxpr = jax.make_jaxpr(batched)(stacked, pod, nodes)
-    (from_file, from_row), = _file_gathers(jaxpr, 256)
+    narrow, wide = _file_gathers(jaxpr, 256)    # the two turns' loops
+    assert narrow == wide
+    from_file, from_row = wide
     assert len(from_file) == 3 * vm.SLOT_BLOCK
     assert len(from_row) == vm.SLOT_BLOCK
     with pytest.raises(AssertionError):

@@ -6,7 +6,15 @@ of a stack scores, and through an engine places, bit for bit what its
 program gives alone through the one-slot loop, whatever the longest live
 count is modulo the block, under ``vmap``, under ``vmap`` in ``shard_map``
 and under suite x population; a table that does not end on a block keeps
-the one-slot turn and is counted."""
+the one-slot turn and is counted.
+
+Since PR 47 the blocked loop walks RUNS of turns: a block in which no lane
+holds an opcode of ``vm.WIDE`` runs on the narrow opcode table (NOP's
+stand-in in the WIDE places), every other block on the whole one. The same
+contract, for stacks whose lanes hold WIDE opcodes in no block, in one
+block, in each place of a block, in different and in adjacent blocks, in
+the last live block and in every slot; and ``vm.loop_wide_turns``, the
+host's count of the wide turns, is the device's rule in NumPy."""
 import functools
 
 import jax
@@ -30,19 +38,44 @@ _STEP = (vm.OP_ADD, vm.OP_SUB, vm.OP_MAX, vm.OP_MIN)
 _KEEP = (vm.OP_ABS, vm.OP_RMAX_G, vm.OP_RMIN_G, vm.OP_COL, vm.OP_NOP)
 
 
-def _chain(n_ops: int, seed: int, g: int):
+#: a run of consecutive WIDE slots applies these in turn to the value
+#: before it, whatever that is, and every value stays finite and small:
+#: SIN -> [-1, 1], EXP -> [0.37, 2.72], SQRT -> [0.6, 1.65], POW(., 3) ->
+#: [0.22, 4.5], LOG -> [-1.5, 1.5], TAN -> [-14.2, 14.2], COS -> [-1, 1],
+#: REM(., 3) -> (-1, 1), and round again
+_WIDE_RUN = (vm.OP_SIN, vm.OP_EXP, vm.OP_SQRT, vm.OP_POW, vm.OP_LOG,
+             vm.OP_TAN, vm.OP_COS, vm.OP_REM)
+#: a WIDE slot alone takes one of those that are safe on any finite value
+_WIDE_ALONE = (vm.OP_SIN, vm.OP_COS, vm.OP_REM)
+_THREE = vm.N_INPUTS + 2    # the pool register that holds 3.0
+
+
+def _chain(n_ops: int, seed: int, g: int, wide_at=frozenset()):
     """``(ops, consts, out_reg)`` of ``n_ops`` live ops, each reading the
     one before it (so the output needs every slot, the last one most of
     all) and an input or pool register: sums, extrema, column picks and
-    writes whose magnitudes stay far below 2^31 over 512 slots."""
+    writes whose magnitudes stay far below 2^31 over 512 slots. The slots
+    of ``wide_at`` hold an opcode of ``vm.WIDE`` applied to the value
+    before them (`_WIDE_RUN`, `_WIDE_ALONE`) and the slots after the
+    first of them only add and subtract, so that no extremum forgets what
+    a WIDE slot computed; the slots before it are what they are without
+    it."""
     rng = np.random.default_rng(seed)
     consts = [0.0, 1.0, 3.0, -7.0]
     acc = int(rng.integers(0, vm.N_INPUTS))
-    ops = []
+    ops, run = [], 0
     for k in range(n_ops):
         other = int(rng.integers(0, vm.N_INPUTS + len(consts)))
         kind = rng.integers(0, 10)
-        if kind < 6:
+        run = run + 1 if k in wide_at else 0
+        if run:
+            alone = run == 1 and k + 1 not in wide_at
+            op = (int(rng.choice(_WIDE_ALONE)) if alone
+                  else _WIDE_RUN[(run - 1) % len(_WIDE_RUN)],
+                  acc, _THREE, 0, 0.0)
+        elif wide_at and k > min(wide_at):
+            op = (int(rng.choice(_STEP[:2])), acc, other, 0, 0.0)
+        elif kind < 6:
             op = (int(rng.choice(_STEP)), acc, other, 0, 0.0)
         elif kind < 8:
             op = (int(rng.choice(_KEEP)), acc, 0, 0, float(rng.integers(g)))
@@ -173,6 +206,160 @@ def test_blocked_placements_equal_each_program_alone(micro_workload, runners,
                 np.asarray(getattr(want, field)), err_msg=f"{i} {field}")
 
 
+# ------------------------------ narrow turns and wide turns (PR 47)
+
+#: live counts of a WIDE stack's five programs: the longest ends two slots
+#: short of its block's end (a NOP tail beside live slots), and 46, 38 and
+#: 30 slots of `_WIDE_RUN` end on TAN, whose values spread over -14..14
+WIDE_COUNTS = (46, 38, 30, 12, 0)
+WIDE_CAP = 64
+#: case -> lane -> the slots that hold a WIDE opcode
+WIDE_CASES = {
+    "none": {},
+    "first_block_of_one_lane": {0: {3}},
+    **{f"place_{j}_of_a_block": {1: {2 * B + j}} for j in range(B)},
+    "different_blocks_of_different_lanes": {0: {5}, 1: {2 * B + 3},
+                                            2: {3 * B + 2}},
+    "adjacent_blocks": {0: {2 * B - 2, 2 * B - 1},
+                        1: {2 * B, 2 * B + 1, 3 * B}, 2: {3 * B + 1}},
+    "last_block_beside_the_nop_tail": {0: {45}, 1: {5 * B - 3}},
+    "every_slot": {i: set(range(WIDE_COUNTS[i])) for i in range(3)},
+}
+
+
+@functools.lru_cache(maxsize=None)
+def _wide_programs(case: str, g: int, cap: int = WIDE_CAP):
+    at = WIDE_CASES[case]
+    progs = [vm.pack_program(
+        *_chain(n, 4700 + i, g, frozenset(at.get(i, ()))), cap)
+        for i, n in enumerate(WIDE_COUNTS)]
+    for i, p in enumerate(progs):
+        got = np.flatnonzero(np.isin(np.asarray(p.opcode), vm.WIDE))
+        assert set(got) == set(at.get(i, ())), (case, i)
+    return progs
+
+
+def _count_wide_turns(opcode, slots: int, shards: int) -> int:
+    """The device's rule, spelt out: a block is wide on a device if any of
+    the device's lanes holds a WIDE opcode in any of its slots; the launch
+    reports the device that has most among its live blocks."""
+    lanes = opcode.shape[0] // shards
+    most = 0
+    for d in range(shards):
+        wide = 0
+        for i in range(-(-slots // B)):
+            block = opcode[d * lanes:(d + 1) * lanes, i * B:(i + 1) * B]
+            wide += any(int(op) in vm.WIDE for op in block.ravel())
+        most = max(most, wide)
+    return most
+
+
+def test_the_wide_cases_hit_every_wide_opcode():
+    hit = {int(op) for case in WIDE_CASES
+           for p in _wide_programs(case, G)
+           for op in np.asarray(p.opcode)} & set(vm.WIDE)
+    assert hit == set(vm.WIDE)
+    assert vm.OP_NOP not in vm.WIDE and len(set(vm.WIDE)) == len(vm.WIDE)
+
+
+@functools.lru_cache(maxsize=None)
+def _wide_scorer(how: str):
+    """``score(stacked[8], pod, nodes) -> [8, ...]``: the lanes under
+    ``vmap``; under ``vmap`` in ``shard_map`` over four virtual devices, 2
+    lanes each, as the four-chip cell; and under suite x population (the
+    programs ride the OUTER ``vmap``, three copies of the views the inner
+    one)."""
+    from jax.sharding import PartitionSpec as P
+
+    from fks_tpu.parallel import population_mesh
+    from fks_tpu.parallel.mesh import POP_AXIS
+
+    lanes = jax.vmap(vm.score, in_axes=(0, None, None))
+    if how == "shard_map":
+        lanes = jax.shard_map(
+            lanes, mesh=population_mesh(jax.devices()[:4]),
+            in_specs=(P(POP_AXIS), P(), P()), out_specs=P(POP_AXIS))
+    if how == "suite":
+        lanes = jax.vmap(jax.vmap(vm.score, in_axes=(None, 0, 0)),
+                         in_axes=(0, None, None))
+    return jax.jit(lanes)
+
+
+@pytest.mark.parametrize("case", sorted(WIDE_CASES))
+@pytest.mark.parametrize("how", ["vmap", "shard_map", "suite"])
+def test_narrow_and_wide_turns_score_each_program_alone(how, case):
+    """Every lane's scores are its program's alone through the one-slot
+    loop, which knows the whole table only. Under ``shard_map`` each
+    device takes its runs from its own two lanes, so the devices walk
+    different runs in one program."""
+    progs = _wide_programs(case, G)
+    stacked = vm.stack_programs(progs + [progs[-1]] * (8 - len(progs)),
+                                capacity=WIDE_CAP)
+    batched, (_, alone) = _wide_scorer(how), _scorers()
+    rng = np.random.default_rng(len(case))
+    if how == "vmap":
+        before = vm.loop_count()
+        jax.make_jaxpr(jax.vmap(vm.score, in_axes=(0, None, None)))(
+            stacked, *_rand_views(rng))
+        assert tuple(x - y for x, y
+                     in zip(vm.loop_count(), before)) == (1, 0)
+    for _ in range(2):
+        pod, nodes = _rand_views(rng)
+        views = (pod, nodes) if how != "suite" else jax.tree_util.tree_map(
+            lambda x: jnp.stack([x] * 3), (pod, nodes))
+        got = np.asarray(batched(stacked, *views))
+        for i, prog in enumerate(progs):
+            want = np.asarray(alone(prog, pod, nodes))
+            for mine in (got[i] if how == "suite" else got[i:i + 1]):
+                np.testing.assert_array_equal(mine, want, err_msg=str(i))
+    assert len({got[i].tobytes() for i in range(len(progs))}) > 1
+
+
+@pytest.mark.parametrize("case", sorted(WIDE_CASES))
+@pytest.mark.parametrize("kind", ["population", "shard_map", "suite"])
+def test_narrow_and_wide_turns_place_each_program_alone(
+        micro_workload, runners, kind, case):
+    """`test_blocked_placements_equal_each_program_alone` for the WIDE
+    stacks; under ``shard_map`` each device takes its runs from its own
+    two lanes, so the devices walk different runs in one program."""
+    progs = _wide_programs(case, micro_workload.cluster.g_padded)
+    run, lanes, alone = runners(kind)
+    stacked = vm.stack_programs(progs + [progs[-1]] * (lanes - len(progs)),
+                                capacity=WIDE_CAP)
+    res = jax.device_get(run(stacked))
+    for i, prog in enumerate(progs):
+        want = jax.device_get(alone(prog))
+        for field in FIELDS:
+            np.testing.assert_array_equal(
+                np.asarray(getattr(res, field))[i],
+                np.asarray(getattr(want, field)), err_msg=f"{i} {field}")
+
+
+@pytest.mark.parametrize("case", sorted(WIDE_CASES))
+def test_wide_turns_is_the_devices_rule_in_numpy(case):
+    progs = list(_wide_programs(case, G))
+    slots = max(WIDE_COUNTS)
+    turns = vm.loop_turns(slots, 1, 0)
+    for lanes, shards in ((5, 1), (8, 1), (8, 4)):
+        opcode = np.asarray(vm.stack_programs(
+            progs + [progs[-1]] * (lanes - len(progs)),
+            capacity=WIDE_CAP).opcode)
+        got = vm.loop_wide_turns(opcode, slots, 1, 0, shards)
+        assert got == _count_wide_turns(opcode, slots, shards) <= turns
+        # the one-slot loop, counted or not: the whole table every turn
+        for blocked, plain in ((0, 0), (0, 1), (1, 1)):
+            assert vm.loop_wide_turns(opcode, slots, blocked, plain,
+                                      shards) == slots
+    want = {"none": 0, "every_slot": turns,
+            "different_blocks_of_different_lanes": 3, "adjacent_blocks": 3,
+            "last_block_beside_the_nop_tail": 2}.get(case, 1)
+    assert vm.loop_wide_turns(opcode, slots, 1, 0, 1) == want
+    # a block past the live turns is not counted, whatever it holds
+    assert vm.loop_wide_turns(opcode, B, 1, 0, 1) == (
+        1 if case in ("first_block_of_one_lane", "every_slot",
+                      "different_blocks_of_different_lanes") else 0)
+
+
 # ------------------------------------------ where the blocks do not engage
 
 @pytest.mark.parametrize("cap", [B + 1, 100, 513 if B > 1 else 3])
@@ -180,12 +367,14 @@ def test_a_capacity_that_is_no_multiple_keeps_the_one_slot_turn(cap):
     """``pad_capacity`` / ``pack_program`` take any capacity. A table that
     does not end on a block is never read past its end: the rule keeps the
     one-slot loop (one write in the ``while`` body), counts it, and the
-    scores are each program's own."""
+    scores are each program's own, WIDE opcodes and all: that loop knows
+    the whole table only, so every one of its turns counts as wide."""
     from tests.test_vm_batch import _assert_one_slice_write_a_slot
 
     assert cap % B
-    progs = [vm.pack_program(*_chain(n, cap + n, G), cap)
-             for n in (cap, cap - 1, 2)]
+    progs = [vm.pack_program(*_chain(n, cap + n, G, frozenset({1, n - 2})),
+                             cap)
+             for n in (cap, cap - 1, 3)]
     stacked = vm.stack_programs(progs, capacity=cap)
     pod, nodes = _rand_views(np.random.default_rng(cap))
     batched = jax.vmap(vm.score, in_axes=(0, None, None))
@@ -193,6 +382,7 @@ def test_a_capacity_that_is_no_multiple_keeps_the_one_slot_turn(cap):
     jaxpr = jax.make_jaxpr(batched)(stacked, pod, nodes)
     assert tuple(x - y for x, y in zip(vm.loop_count(), before)) == (0, 1)
     assert _assert_one_slice_write_a_slot(jaxpr, cap, block=1) == 1
+    assert vm.loop_wide_turns(np.asarray(stacked.opcode), cap, 0, 1) == cap
     got = np.asarray(batched(stacked, pod, nodes))
     for i, prog in enumerate(progs):
         np.testing.assert_array_equal(
@@ -275,5 +465,11 @@ def test_population_launch_span_carries_the_trip_structure(micro_workload):
     first, again = seen
     assert first["blocked_loops"] >= 1 and first["plain_loops"] == 0
     assert first["turns"] == -(-first["slots"] // B) < first["slots"]
-    assert {k: again[k] for k in ("blocked_loops", "plain_loops", "turns")} \
-        == {k: first[k] for k in ("blocked_loops", "plain_loops", "turns")}
+    kept = ("blocked_loops", "plain_loops", "turns", "wide_turns")
+    assert {k: again[k] for k in kept} == {k: first[k] for k in kept}
+    # the same generation's words, counted by the device's rule spelt out
+    c = micro_workload.cluster
+    progs = [vm.compile_policy(code, c.n_padded, c.g_padded)
+             for code in _corpus()[:4]]
+    assert first["wide_turns"] == _count_wide_turns(
+        np.asarray(vm.stack_programs(progs).opcode), first["slots"], 1)

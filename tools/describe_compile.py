@@ -141,25 +141,52 @@ def loop_body(hlo: str) -> list:
     return max(outer or bodies, key=len, default=[])
 
 
-def slot_loop(hlo: str) -> list:
-    """The kernels of the interpreter's op-slot loop: the body of the
-    ``while`` inside `loop_body` that carries the largest array, which is
-    the register file ``[lanes, rows, N, G]``. Empty where the event loop
-    holds no ``while`` (a program that interprets nothing)."""
+def slot_loops(hlo: str, outer: bool = False) -> list:
+    """The kernels of the interpreter's op-slot loops, a list a loop: from
+    the ``while`` inside `loop_body` that carries the largest array, which
+    is the register file ``[lanes, rows, N, G]``, down to every innermost
+    ``while`` that carries an array of that size. One loop where a turn is
+    a slot (serving, one program alone) and before PR 47; two where the
+    batched VM walks RUNS of turns, the narrow turn's and the wide turn's
+    in the order the run loop holds them. The run loop's own body is
+    scalar bookkeeping and is listed only with ``outer`` (first). Empty
+    where the event loop holds no ``while`` (a program that interprets
+    nothing)."""
+    def largest(r):
+        return max((math.prod(dims) for _, dims, _ in r["arrays"]), default=0)
+
     inner = [r for r in loop_body(hlo) if r["op"] == "while"]
     if not inner:
         return []
-    file_loop = max(inner, key=lambda r: max(
-        (math.prod(dims) for _, dims, _ in r["arrays"]), default=0))
-    return _while_bodies(hlo)[file_loop["calls"]]
+    file_size = max(map(largest, inner))
+    holding, innermost, todo = [], [], [max(inner, key=largest)]
+    while todo:
+        body = _while_bodies(hlo)[todo.pop(0)["calls"]]
+        deeper = [r for r in body
+                  if r["op"] == "while" and largest(r) == file_size]
+        todo = deeper + todo
+        (holding if deeper else innermost).append(body)
+    return holding * outer + innermost
+
+
+def slot_loop(hlo: str) -> list:
+    """The kernels of every loop of `slot_loops`, joined."""
+    return [r for body in slot_loops(hlo) for r in body]
+
+
+def array_mentions(hlo: str, shape: tuple) -> list:
+    """The layout text (``2,3,1,0:T(8,128)S(1)``: minor to major, tiling,
+    memory space) of every mention of an array of ``shape`` in the module,
+    results and operands and fused parameters alike."""
+    dims = ",".join(str(d) for d in shape)
+    return re.findall(r"[a-z]+\d*\[" + dims + r"\]\{([^}]*)\}", hlo)
 
 
 def operand_layouts(hlo: str, shape: tuple) -> collections.Counter:
     """minor_to_major -> how many arrays of ``shape`` the whole module
     mentions with it (results and fused parameters alike)."""
-    dims = ",".join(str(d) for d in shape)
     return collections.Counter(
-        re.findall(r"[a-z]+\d*\[" + dims + r"\]\{([\d,]*)", hlo))
+        m.split(":")[0] for m in array_mentions(hlo, shape))
 
 
 # ------------------------------------------------------------ executables
@@ -251,8 +278,10 @@ def main(argv=None) -> int:
     if args.hlo:
         with open(args.hlo, "w") as f:
             f.write(hlo)
-    for title, rows in (("event loop", loop_body(hlo)),
-                        ("op-slot loop", slot_loop(hlo))):
+    loops = slot_loops(hlo)
+    for title, rows in [("event loop", loop_body(hlo))] + [
+            ("op-slot loop" + f" {i} of {len(loops)}" * (len(loops) > 1), rows)
+            for i, rows in enumerate(loops, 1)]:
         for r in sorted(rows, key=lambda r: -r["cycles"]):
             print(f"{r['cycles']:>9} {r['op']:<12} {r['name']:<34} "
                   f"{r['result'][:150]}")
