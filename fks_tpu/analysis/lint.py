@@ -25,12 +25,6 @@ per-site waivers:
   (engine.SimConfig docstrings); passing one as a traced jit argument
   would turn every flag read into FKS102. The static pattern — cfg
   captured by closure at build time — is untouched.
-- FKS106: an AOT ``.lower(...).compile()`` call whose enclosing function
-  never touches the footprint ledger (``record_footprint`` /
-  ``footprint_of`` / ``memory_analysis``). Module-wide — not limited to
-  decorator-jitted functions — because every cached executable claims
-  device memory for its lifetime, and an unpriced one is invisible to
-  ``cli mem`` and the memory budget gate.
 
 **Jaxpr pins** (``compute_pins`` / ``check_pins`` / ``write_pins``) —
 the dynamic half of the same contract. Every Python-static SimConfig
@@ -56,7 +50,7 @@ import json
 import os
 import re
 from pathlib import Path
-from typing import Dict, Iterable, List, Optional, Sequence, Set, Tuple
+from typing import Dict, List, Optional, Sequence, Set, Tuple
 
 REPO_ROOT = os.path.dirname(os.path.dirname(os.path.dirname(
     os.path.abspath(__file__))))
@@ -70,13 +64,7 @@ LINT_CODES = {
     "FKS103": "host sync (.item()/.tolist()) inside a jitted function",
     "FKS104": "numpy usage inside a jitted function",
     "FKS105": "SimConfig passed as a traced jit argument",
-    "FKS106": "AOT .lower(...).compile() without a footprint record",
 }
-
-#: names whose presence in the enclosing function waives FKS106 — the
-#: compile site is priced into the footprint ledger (fks_tpu.obs.memory)
-_FOOTPRINT_MARKS = {"record_footprint", "footprint_of", "memory_analysis"}
-
 
 @dataclasses.dataclass(frozen=True)
 class Finding:
@@ -230,58 +218,6 @@ def _lint_jitted(path: str, fn: ast.FunctionDef, np_aliases: Set[str],
                     f"in '{fn.name}' — use jnp (host numpy does not trace)")
 
 
-def _compile_sites(tree: ast.Module) -> Iterable[ast.Call]:
-    """``<expr>.lower(...).compile(...)`` chains — the AOT idiom whose
-    executable claims device memory for its whole cache lifetime."""
-    for node in ast.walk(tree):
-        if (isinstance(node, ast.Call)
-                and isinstance(node.func, ast.Attribute)
-                and node.func.attr == "compile"):
-            inner = node.func.value
-            if (isinstance(inner, ast.Call)
-                    and isinstance(inner.func, ast.Attribute)
-                    and inner.func.attr == "lower"):
-                yield node
-
-
-def _references_footprint(fn: ast.AST) -> bool:
-    for sub in ast.walk(fn):
-        if isinstance(sub, ast.Name) and sub.id in _FOOTPRINT_MARKS:
-            return True
-        if isinstance(sub, ast.Attribute) and sub.attr in _FOOTPRINT_MARKS:
-            return True
-    return False
-
-
-def _lint_compile_sites(path: str, tree: ast.Module,
-                        findings: List[Finding]) -> None:
-    """FKS106: every AOT ``.lower(...).compile()`` site must be priced
-    into the footprint ledger — waived when the innermost enclosing
-    function also references ``record_footprint`` / ``footprint_of`` /
-    ``memory_analysis`` (it files or prices the executable itself).
-    Unpriced executables are invisible to ``cli mem`` and the memory
-    budget gate, which is exactly how an HBM regression hides."""
-    funcs = [n for n in ast.walk(tree)
-             if isinstance(n, (ast.FunctionDef, ast.AsyncFunctionDef))]
-    for site in _compile_sites(tree):
-        enclosing = None
-        for fn in funcs:
-            end = getattr(fn, "end_lineno", None) or fn.lineno
-            if fn.lineno <= site.lineno <= end:
-                # innermost wins: the latest-starting containing span
-                if enclosing is None or fn.lineno > enclosing.lineno:
-                    enclosing = fn
-        if enclosing is not None and _references_footprint(enclosing):
-            continue
-        where = (f"in '{enclosing.name}'" if enclosing is not None
-                 else "at module scope")
-        findings.append(Finding(
-            path, site.lineno, "FKS106",
-            f"{LINT_CODES['FKS106']}: {where} — call "
-            f"obs.memory.record_footprint on the compiled executable "
-            f"(or price it via footprint_of/memory_analysis)"))
-
-
 def lint_source(path: str, source: str) -> List[Finding]:
     """Lint one module's source. Syntax errors surface as a finding (the
     gate must not crash on a broken tree mid-refactor)."""
@@ -303,7 +239,6 @@ def lint_source(path: str, source: str) -> List[Finding]:
             _lint_jitted(path, node, np_aliases, traced,
                          _simconfig_params(node), findings)
             break
-    _lint_compile_sites(path, tree, findings)
     return findings
 
 
@@ -410,17 +345,6 @@ def compute_pins() -> Dict[str, object]:
     step = flat.build_step(wl, policy, cfg, ktable, max_steps)
     with StageProfiler(scope="lint") as _prof, _prof.stage("pin"):
         pins["flat_step/profiled"] = _jaxpr_hash(
-            step, flat.initial_state(wl, cfg))
-
-    # the WatermarkSampler is likewise host-side only: the baseline step
-    # traced while an ENABLED sampler is live (and has just sampled) must
-    # hash identically to flat_step/baseline — the disabled path is
-    # covered a fortiori (NULL_SAMPLER does strictly nothing)
-    from fks_tpu.obs.memory import WatermarkSampler
-
-    with WatermarkSampler(enabled=True) as _samp:
-        _samp.sample(stage="pin")
-        pins["flat_step/mem_sampled"] = _jaxpr_hash(
             step, flat.initial_state(wl, cfg))
 
     # probe_score gates finalize (not the step program), so the flag's

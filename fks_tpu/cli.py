@@ -23,7 +23,6 @@ Usage:
     python -m fks_tpu.cli trace-diff --engines exact,flat [--policy P | --code F]
     python -m fks_tpu.cli scenarios [--suite NAME [--scenario I]]
     python -m fks_tpu.cli lint [PATHS...] [--write-pins | --no-pins]
-    python -m fks_tpu.cli mem [--run-dir DIR | --sample | --drill NAME]
     python -m fks_tpu.cli traces
     python -m fks_tpu.cli snapshot
 
@@ -741,7 +740,7 @@ def cmd_serve(args):
             return 0  # artifact-build invocation, nothing to serve
         slo = None
         if args.slo_p99_ms or args.slo_qps:
-            from fks_tpu.obs.history import SLOConfig
+            from fks_tpu.serve.accounting import SLOConfig
             slo = SLOConfig(p99_ms=args.slo_p99_ms, qps=args.slo_qps,
                             error_budget=args.slo_error_budget)
         service = ServeService(engine, recorder=rec,
@@ -785,7 +784,7 @@ def cmd_serve(args):
                       file=sys.stderr)
         stop_follow = None
         if args.follow_ledger:
-            from fks_tpu.obs.history import SLOConfig as _SLO
+            from fks_tpu.serve.accounting import SLOConfig as _SLO
             from fks_tpu.pipeline import (
                 PromotionConfig, PromotionController, follow_ledger,
             )
@@ -843,7 +842,6 @@ def cmd_loadgen(args):
     in-process client."""
     _apply_platform_flags(args)
     from fks_tpu import obs
-    from fks_tpu.obs.history import SLOConfig
     from fks_tpu.obs.workload import (
         http_client, parse_tenant_spec, run_loadgen, service_client,
     )
@@ -851,6 +849,7 @@ def cmd_loadgen(args):
         ChampionSpec, ServeEngine, ServeService, ShapeEnvelope,
         load_champion, make_http_server,
     )
+    from fks_tpu.serve.accounting import SLOConfig
 
     try:
         plan = parse_tenant_spec(args.tenants)
@@ -1126,7 +1125,7 @@ def cmd_report(args):
     back into a human-readable summary — generations table with a fitness
     sparkline, admit/reject breakdown, compile events, span hotspots — from
     the JSONL files alone (no in-process state)."""
-    from fks_tpu.obs import render_report
+    from fks_tpu.obs.report import render_report
 
     try:
         print(render_report(args.run_dir))
@@ -1140,7 +1139,7 @@ def cmd_export_metrics(args):
     """Render a flight-recorder run directory as OpenMetrics text
     exposition (``# TYPE``/``# HELP`` blocks, ``# EOF`` terminator) —
     scrape-able by any Prometheus textfile collector, no client library."""
-    from fks_tpu.obs import to_openmetrics
+    from fks_tpu.obs.exporter import to_openmetrics
 
     try:
         text = to_openmetrics(args.run_dir)
@@ -1165,7 +1164,7 @@ def cmd_watch(args):
     a heartbeat liveness verdict (HEALTHY / STALE / DEAD — thresholds at
     2x / 10x the run's own metric cadence) every ``--interval`` seconds.
     Exits 0 when the run finishes ok, 1 on error status or a dead run."""
-    from fks_tpu.obs import watch
+    from fks_tpu.obs.exporter import watch
 
     try:
         return watch(args.run_dir, interval=args.interval, once=args.once)
@@ -1290,8 +1289,10 @@ def cmd_compare(args):
     ``--baseline auto`` (the literal word as BASELINE) resolves the best
     healthy historical run under ``--history-root`` instead of a
     hand-picked path (fks_tpu.obs.history)."""
-    from fks_tpu.obs import compare_runs, format_comparison, has_regression
-    from fks_tpu.obs.compare import parse_threshold_overrides
+    from fks_tpu.obs.compare import (
+        compare_runs, format_comparison, has_regression,
+        parse_threshold_overrides,
+    )
 
     baseline = args.baseline
     if baseline == "auto":
@@ -1380,11 +1381,11 @@ def cmd_trends(args):
 
 def cmd_trace_diff(args):
     """Replay one policy through two engines with the decision trace on and
-    report the first divergent scheduling step (fks_tpu.obs.tracing).
+    report the first divergent scheduling step (fks_tpu.funsearch.tracing).
     Exit code contract: 0 = no divergence, 1 = divergence found, 2 = error
     — scriptable like ``compare``."""
     _apply_platform_flags(args)
-    from fks_tpu.obs import tracing
+    from fks_tpu.funsearch import tracing
     from fks_tpu.sim.engine import SimConfig
 
     engines = [e.strip() for e in args.engines.split(",") if e.strip()]
@@ -1539,66 +1540,6 @@ def cmd_scenarios(args):
                                 np.asarray(fe.node)[m],
                                 np.asarray(fe.kind)[m])])
     print(json.dumps(row, indent=2))
-    return 0
-
-
-def cmd_mem(args):
-    """Memory observability (fks_tpu.obs.memory). Three modes:
-
-    - view (default): render the memory view of a recorded run from
-      ``--run-dir``'s JSONL alone — the executable footprint ladder
-      (every compiled program's predicted HBM claim, largest first),
-      the per-mesh-layout roll-up, the watermark sampler's host/device
-      table, and the leak sentinel's verdict per fenced loop;
-    - ``--sample``: take one live watermark sample (host RSS +
-      normalized per-device ``memory_stats``) and print it as JSON;
-    - ``--drill NAME``: run one deterministic memory drill and exit
-      0/1 on its verdict — ``vm_swap_leak`` hammers ``swap_program``
-      against interleaved serve batches inside a live-array fence
-      (zero net drift required), ``snapshot_cache_bound`` proves the
-      device snapshot cache respects a byte ceiling under distinct
-      query shapes. Both record into ``--run-dir`` when given."""
-    if args.drill:
-        _apply_platform_flags(args)
-        from fks_tpu.obs import get_recorder
-        from fks_tpu.obs.memory import run_drill
-
-        kw = {}
-        if args.drill == "vm_swap_leak":
-            kw = {"swaps": args.swaps, "batches": args.batches}
-        with _flight_recorder(args, "mem"):
-            res = run_drill(args.drill, recorder=get_recorder(), **kw)
-        print(json.dumps(res))
-        return 0 if res.get("ok") else 1
-    if args.sample:
-        _apply_platform_flags(args)
-        from fks_tpu.obs.memory import WatermarkSampler
-
-        sampler = WatermarkSampler(enabled=True, trace_host=True)
-        sampler.start()
-        try:
-            rec = sampler.sample(stage="cli")
-        finally:
-            sampler.stop()
-        print(json.dumps(rec))
-        return 0
-    if not args.run_dir:
-        print("error: mem needs --run-dir DIR (view mode), --sample, or "
-              "--drill NAME", file=sys.stderr)
-        return 2
-    from fks_tpu.obs.report import _memory_section, load_run
-
-    try:
-        _meta, _events, metrics = load_run(args.run_dir)
-    except FileNotFoundError as e:
-        print(f"error: {e}", file=sys.stderr)
-        return 2
-    lines = _memory_section(metrics)
-    if not lines:
-        print(f"(no memory records in {args.run_dir} — footprints land "
-              "when an instrumented command compiles under --run-dir)")
-        return 0
-    print("\n".join(lines))
     return 0
 
 
@@ -1939,7 +1880,7 @@ def build_parser() -> argparse.ArgumentParser:
                     help="seconds between ledger polls (default 5)")
     sv.add_argument("--accounting", action="store_true",
                     help="per-tenant accounting + query fingerprinting "
-                         "(fks_tpu.obs.workload): tenant_stats / "
+                         "(fks_tpu.serve.accounting): tenant_stats / "
                          "workload_mix records in the run dir, "
                          "fks_tenant_* gauges from export-metrics, a "
                          "tenant table in 'report' (off by default — the "
@@ -2217,32 +2158,6 @@ def build_parser() -> argparse.ArgumentParser:
                     help="flight-recorder run directory for the "
                          "lint_report record")
     ln.set_defaults(fn=cmd_lint)
-
-    mm = sub.add_parser(
-        "mem",
-        help="memory observability: footprint ladder / watermark view "
-             "of a run, one live sample, or a leak drill (exit 1 on a "
-             "failed drill)",
-        parents=[common])
-    mm.add_argument("--drill",
-                    choices=("vm_swap_leak", "snapshot_cache_bound"),
-                    default="",
-                    help="run one deterministic memory drill and exit "
-                         "0/1 on its verdict")
-    mm.add_argument("--swaps", type=int, default=50,
-                    help="vm_swap_leak: swap_program iterations "
-                         "(default 50)")
-    mm.add_argument("--batches", type=int, default=200,
-                    help="vm_swap_leak: interleaved serve batches "
-                         "(default 200)")
-    mm.add_argument("--sample", action="store_true",
-                    help="take one live watermark sample (host RSS + "
-                         "per-device memory_stats) and print it as JSON")
-    mm.add_argument("--devices", type=int, default=0,
-                    help="with --cpu: size of the virtual CPU device "
-                         "mesh the drill runs against (without --cpu: "
-                         "fail unless that many real devices are visible)")
-    mm.set_defaults(fn=cmd_mem)
 
     t = sub.add_parser("traces", help="list available trace files")
     t.set_defaults(fn=cmd_traces)

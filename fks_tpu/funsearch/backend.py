@@ -370,34 +370,6 @@ class CodeEvaluator:
                 on_segment=self._count_segment)
         return self._vm_mesh_run
 
-    def _maybe_record_vm_footprint(self, run, stacked, pop: int) -> None:
-        """Evolve-tier footprint ledger entry: price this bucket's
-        population runner once per (pop, capacity) bucket — only while a
-        flight recorder is on (the AOT lower is not free, so the silent
-        path pays nothing) and only for runners that expose ``.lower``
-        (the plain jitted path; segmented/mesh runners manage their own
-        inner jits and stay unpriced)."""
-        from fks_tpu.obs.recorder import get_recorder
-        rec = get_recorder()
-        if not rec.enabled or getattr(run, "lower", None) is None:
-            return
-        cap = int(stacked.opcode.shape[-1])
-        key = (pop, cap)
-        done = getattr(self, "_footprinted_buckets", None)
-        if done is None:
-            done = self._footprinted_buckets = set()
-        if key in done:
-            return
-        done.add(key)
-        try:
-            from fks_tpu.obs.memory import record_footprint
-            compiled = run.lower(stacked, self.state0).compile()
-            record_footprint("evolve", f"pop={pop},cap={cap}", compiled,
-                             mesh=self.mesh, recorder=rec,
-                             engine=self.engine)
-        except Exception:  # noqa: BLE001 — pricing is best-effort
-            pass
-
     def _vm_traced_fields(self, bucket: Tuple[int, int], slots: int,
                           before: Tuple[int, ...], opcode,
                           shards: int) -> Dict[str, int]:
@@ -442,13 +414,7 @@ class CodeEvaluator:
                       candidates=len(progs), lanes=pop):
             padded = list(progs) + [progs[-1]] * (pop - len(progs))
             stacked = vm.stack_programs(padded)
-        # footprint the bucket's runner outside the launch span: the
-        # once-per-bucket AOT lower must not land on the device clock
-        # (same branch condition as the dispatch below)
         sharded = self._n_shards > 1 and self.suite is None
-        if not sharded:
-            self._maybe_record_vm_footprint(self._vm_pop_runner(),
-                                            stacked, pop)
         # launch + wait_device is the device's part of the generation (a
         # segmented runner already waits for its segments inside launch);
         # d2h is the one transfer and nothing else

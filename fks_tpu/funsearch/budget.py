@@ -30,7 +30,7 @@ are re-padded onto the bucket via ``parallel.mesh.pad_population``
 (replicating the last survivor's slice), so each rung compiles once per
 (bucket-size, probe-shape) pair — never per generation.
 
-Correctness is gated by ``fks_tpu.obs.watchdog.ParitySentinel.
+Correctness is gated by ``fks_tpu.funsearch.parity.ParitySentinel.
 check_champion``: pruning may never change which candidate wins a
 generation, only how cheaply — the sentinel rescoring the pruned
 candidates through the unpruned exact reference alerts (CLI exit 3) if

@@ -36,8 +36,10 @@ from fks_tpu.obs import trace_ctx
 from fks_tpu.funsearch import llm as llm_mod
 from fks_tpu.funsearch import template
 from fks_tpu.funsearch.backend import CodeEvaluator, EvalRecord
+from fks_tpu.funsearch.parity import ParitySentinel
 from fks_tpu.resilience.wal import GenerationWAL
 from fks_tpu.sim.engine import SimConfig
+from fks_tpu.sim.guards import combined_flags, describe_flags
 
 
 # ------------------------------------------------------------------ config
@@ -78,7 +80,7 @@ class EvolutionConfig:
     parametric_rounds: int = 0
     parametric_pop: int = 32
     parametric_noise: float = 0.05
-    # parity sentinel (fks_tpu.obs.watchdog.ParitySentinel): re-score this
+    # parity sentinel (fks_tpu.funsearch.parity.ParitySentinel): re-score this
     # many sampled population members per generation through the exact
     # reference evaluator on the JIT tier and alert when |Δfitness|
     # exceeds parity_tol (0 = off). NOTE: the default tol assumes an
@@ -288,7 +290,7 @@ class FunSearch:
         self.ledger = obs.EvolutionLedger(self.recorder, evaluator)
         # the parity sentinel is a no-op unless parity_sample > 0; its
         # lifetime ``alerts`` counter feeds the CLI's nonzero-exit policy
-        self.sentinel = obs.ParitySentinel(
+        self.sentinel = ParitySentinel(
             evaluator, sample=config.parity_sample, tol=config.parity_tol,
             seed=config.seed, recorder=self.recorder)
         self.rescore_fallbacks = 0  # lifetime count; per-gen delta in stats
@@ -628,12 +630,12 @@ class FunSearch:
             wd_flags = 0
             for r in records:
                 if r.result is not None:
-                    wd_flags |= obs.combined_flags(
+                    wd_flags |= combined_flags(
                         getattr(r.result, "numeric_flags", 0))
             if wd_flags:
                 self.recorder.event(
                     "watchdog", flags=wd_flags,
-                    kinds=obs.describe_flags(wd_flags),
+                    kinds=describe_flags(wd_flags),
                     generation=self.generation, candidates=len(records))
 
             accepted = rejected = 0

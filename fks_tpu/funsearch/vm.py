@@ -749,7 +749,7 @@ def compile_policy(code: str, n: int, g: int,
 def compile_for_workload(code: str, workload, capacity: int = 512) -> VMProgram:
     """``compile_policy`` with (n, g) taken from a parsed workload's padded
     cluster shape — the replay / trace-diff entry point
-    (fks_tpu.obs.tracing), where the caller holds a Workload, not shapes."""
+    (fks_tpu.funsearch.tracing), where the caller holds a Workload, not shapes."""
     c = workload.cluster
     return compile_policy(code, c.n_padded, c.g_padded, capacity=capacity)
 

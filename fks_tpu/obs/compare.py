@@ -65,13 +65,6 @@ DEFAULT_THRESHOLDS: Dict[str, Threshold] = {
     # at millisecond scale), and batched throughput must not drop >10%
     "serve_p99_ms": Threshold(higher_is_better=False, rel=0.25, abs_tol=2.0),
     "serve_qps": Threshold(higher_is_better=True, rel=0.10),
-    # memory budgets (obs.memory): the run's peak predicted device bytes and the largest executable's XLA scratch
-    # claim must not grow — one 4 KiB page of absolute floor absorbs
-    # buffer-assignment jitter at tiny CPU shapes, any real growth gates
-    "peak_device_bytes": Threshold(higher_is_better=False, rel=0.0,
-                                   abs_tol=4096.0),
-    "exe_temp_bytes": Threshold(higher_is_better=False, rel=0.0,
-                                abs_tol=4096.0),
     # sustained multi-tenant load (`cli loadgen`): throughput and
     # the Jain fairness index over per-tenant goodput must not drop,
     # tail latency and shed rate must not grow. qps/p99 get the serve
@@ -129,13 +122,6 @@ def _from_run_dir(run_dir: str) -> Dict[str, float]:
             v = _num(m.get(key))
             if v is not None:
                 out[key] = min(out.get(key, v), v)
-        # memory budgets: WORST (highest) observation — a peak metric's
-        # whole point is the high-water mark, so the gate judges the
-        # largest claim any stage recorded
-        for key in ("peak_device_bytes", "exe_temp_bytes"):
-            v = _num(m.get(key))
-            if v is not None:
-                out[key] = max(out.get(key, 0.0), v)
         v = _num(m.get("compile_seconds"))
         if v is not None:
             out["compile_seconds"] = out.get("compile_seconds", 0.0) + v
@@ -166,30 +152,20 @@ def _from_jsonl(path: str, allow_stale: bool = False) -> Dict[str, float]:
     candidate it would mask the very failure it records."""
     out: Dict[str, float] = {}
 
-    def take(rec: Dict[str, Any], stale: bool = False) -> None:
+    def take(rec: Dict[str, Any]) -> None:
         for key in ("evals_per_sec", "code_evals_per_sec",
                     "compile_seconds", "best_score", "median_score",
                     "parity_max_drift", "budget_speedup",
                     "budget_champion_match", "scale1k_events_per_sec",
-                    "serve_p99_ms", "serve_qps", "peak_device_bytes",
-                    "exe_temp_bytes", "loadgen_qps", "loadgen_p99_ms",
-                    "loadgen_shed_rate", "loadgen_fairness_index"):
+                    "serve_p99_ms", "serve_qps", "loadgen_qps",
+                    "loadgen_p99_ms", "loadgen_shed_rate",
+                    "loadgen_fairness_index"):
             v = _num(rec.get(key))
             if v is None:
-                continue
-            # memory budgets on a STALE fallback line are carried-forward
-            # donor evidence, not a live measurement — the same baseline-
-            # only asymmetry as the stale headline (take() runs on every
-            # record, so the guard must live here, not at the call site)
-            if (stale and not allow_stale
-                    and key in ("peak_device_bytes", "exe_temp_bytes")):
                 continue
             if key in ("compile_seconds", "serve_p99_ms",
                        "loadgen_p99_ms", "loadgen_shed_rate"):
                 out[key] = min(out.get(key, v), v)
-            elif key in ("peak_device_bytes", "exe_temp_bytes"):
-                # peak metrics: the high-water mark across records
-                out[key] = max(out.get(key, 0.0), v)
             else:
                 out[key] = max(out.get(key, v), v)
 
@@ -211,10 +187,9 @@ def _from_jsonl(path: str, allow_stale: bool = False) -> Dict[str, float]:
                 if v and (allow_stale or "stale_from_run" not in rec):
                     out["evals_per_sec"] = max(
                         out.get("evals_per_sec", 0.0), v)
-            stale = "stale_from_run" in rec
-            take(rec, stale=stale)
+            take(rec)
             if isinstance(rec.get("result"), dict):
-                take(rec["result"], stale=stale)
+                take(rec["result"])
     return out
 
 

@@ -193,7 +193,7 @@ def to_openmetrics(run_dir: str) -> str:
                     f"device-time attribution: stage {key}").add(
                     d[key], run_id=run_id, stage=stage, scope=d.get("scope"))
 
-    # SLO burn rates (fks_tpu.obs.history.slo_burn): latest record per SLO
+    # SLO burn rates (fks_tpu.serve.accounting.slo_burn): latest record per SLO
     latest_burn: Dict[str, dict] = {}
     for b in (m for m in metrics if m.get("kind") == "slo_burn"):
         latest_burn[str(b.get("slo", "?"))] = b
@@ -234,7 +234,7 @@ def to_openmetrics(run_dir: str) -> str:
                 0 if s.get("engine_state") == "normal" else 1,
                 run_id=run_id, state=str(s.get("engine_state")))
 
-    # per-tenant accounting (fks_tpu.obs.workload.TenantAccountant):
+    # per-tenant accounting (fks_tpu.serve.accounting.TenantAccountant):
     # latest tenant_stats record per tenant — the fairness index is a
     # GLOBAL value every row carries, exported once unlabeled
     latest_tenant: Dict[str, dict] = {}
@@ -273,7 +273,7 @@ def to_openmetrics(run_dir: str) -> str:
             "(1 = even, 1/n = one tenant has it all)").add(
             any_row.get("fairness_index"), run_id=run_id)
 
-    # workload-class mix (fks_tpu.obs.workload.QueryFingerprinter):
+    # workload-class mix (fks_tpu.serve.accounting.QueryFingerprinter):
     # latest windowed distribution, one gauge per class
     latest_mix = None
     for m in (m for m in metrics if m.get("kind") == "workload_mix"):
@@ -356,63 +356,6 @@ def to_openmetrics(run_dir: str) -> str:
             "host-to-device bytes shipped per answered query "
             "(post-packing, cache-discounted)").add(
             c.get("h2d_bytes_per_query"), run_id=run_id)
-
-    # executable-footprint ledger (fks_tpu.obs.memory): the predicted
-    # HBM claim of each compiled executable, latest record per
-    # (component, exe_key) — what the run WILL hold resident, from
-    # memory_analysis, before any allocator ever reports pressure
-    latest_fp: Dict[Tuple[str, str], dict] = {}
-    for m in (m for m in metrics if m.get("kind") == "memory_footprint"):
-        latest_fp[(str(m.get("component", "?")),
-                   str(m.get("exe_key", "?")))] = m
-    for component, exe_key in sorted(latest_fp):
-        m = latest_fp[(component, exe_key)]
-        fam("mem_exe_temp_bytes", "gauge",
-            "XLA scratch (temp) bytes reserved by this executable").add(
-            m.get("temp_bytes"), run_id=run_id, component=component,
-            exe=exe_key)
-        fam("mem_exe_total_bytes", "gauge",
-            "predicted HBM claim: temp + argument + output + "
-            "generated-code bytes").add(
-            m.get("total_bytes"), run_id=run_id, component=component,
-            exe=exe_key)
-
-    # watermark sampler (fks_tpu.obs.memory): the latest host/device
-    # high-water sample; per-device rows carry the allocator's view
-    latest_wm = None
-    for m in (m for m in metrics if m.get("kind") == "memory_watermark"):
-        latest_wm = m
-    if latest_wm is not None:
-        m = latest_wm
-        fam("mem_host_rss_kb", "gauge",
-            "host resident set size at the latest watermark sample").add(
-            m.get("host_rss_kb"), run_id=run_id, stage=m.get("stage"))
-        for d in (m.get("devices") or []):
-            if not isinstance(d, dict):
-                continue
-            did = d.get("id", "?")
-            fam("mem_device_bytes_in_use", "gauge",
-                "device allocator bytes in use at the latest watermark "
-                "sample").add(d.get("bytes_in_use"), run_id=run_id,
-                              device=did, platform=d.get("platform"))
-            fam("mem_device_peak_bytes", "gauge",
-                "device allocator peak bytes in use").add(
-                d.get("peak_bytes_in_use"), run_id=run_id, device=did,
-                platform=d.get("platform"))
-
-    # leak-sentinel verdicts (fks_tpu.obs.memory): net live-array drift
-    # across each fenced hot loop, latest record per loop
-    latest_leak: Dict[str, dict] = {}
-    for m in (m for m in metrics if m.get("kind") == "leak_check"):
-        latest_leak[str(m.get("loop", "?"))] = m
-    for loop in sorted(latest_leak):
-        m = latest_leak[loop]
-        fam("mem_leak_drift_bytes", "gauge",
-            "net live-array byte drift across the fenced loop").add(
-            m.get("drift_bytes"), run_id=run_id, loop=loop)
-        fam("mem_leak_ok", "gauge",
-            "1 when the fenced loop stayed within drift tolerance").add(
-            1 if m.get("ok") else 0, run_id=run_id, loop=loop)
 
     # per-request latency histogram with trace-id EXEMPLARS: each bucket
     # cites the slowest request that landed in it, so a fat-tail bucket
@@ -592,12 +535,6 @@ def watch(run_dir: str, interval: float = 5.0, once: bool = False,
             elif kind == "bench_stage":
                 v = m.get("value", m.get("evals_per_sec"))
                 out.write(f"bench {m.get('stage', '?')}: {v}\n")
-            elif kind == "leak_check":
-                verdict = "ok" if m.get("ok") else "LEAK"
-                out.write(f"leak {m.get('loop', '?')}: {verdict} "
-                          f"drift {m.get('drift_count', 0)} arrays / "
-                          f"{m.get('drift_bytes', 0)} bytes over "
-                          f"{m.get('iterations', 0)} iters\n")
             elif kind == "slo_burn":
                 rate = _num(m.get("burn_rate")) or 0.0
                 line = (f"slo {m.get('slo', '?')}: burn {rate:.2f}x "

@@ -1,4 +1,4 @@
-"""Cross-run history: index runs, render trends, flag regressions, burn SLOs.
+"""Cross-run history: index runs, render trends, flag regressions.
 
 ``cli compare`` is strictly pairwise and every bench probe's headline is
 a single JSON line — until this module, the repo had no durable perf
@@ -20,12 +20,6 @@ that index:
   what ``cli compare --baseline auto`` resolves, replacing hand-picked
   baselines.
 
-Serve-tier SLOs ride along: ``SLOConfig`` declares p99/qps targets and
-``slo_burn`` prices observed latencies against them as burn rates (the
-multiple of the error budget being consumed — burn_rate > 1 means the
-SLO is being violated), recorded as ``slo_burn`` metrics and surfaced by
-``cli watch`` and the OpenMetrics exporter.
-
 Health: a run dir is healthy when its meta status is ``ok`` and it
 recorded no alert events; a bench file is healthy when it carries a
 measured (nonzero, non-stale) headline. A record carrying another run's
@@ -34,7 +28,6 @@ them) is indexed but never selected as a baseline.
 """
 from __future__ import annotations
 
-import dataclasses
 import json
 import os
 from typing import Any, Dict, Iterable, List, Optional, Tuple
@@ -48,7 +41,7 @@ INDEX_NAME = "history.jsonl"
 TREND_METRICS = (
     "evals_per_sec", "code_evals_per_sec", "compile_seconds",
     "best_score", "serve_p99_ms", "serve_qps", "scale1k_events_per_sec",
-    "budget_speedup", "peak_device_bytes", "exe_temp_bytes",
+    "budget_speedup",
     "loadgen_qps", "loadgen_p99_ms", "loadgen_shed_rate",
     "loadgen_fairness_index",
 )
@@ -287,68 +280,3 @@ def resolve_auto_baseline(root: str, metric: str = "evals_per_sec"
         healthy = [e for e in hist.entries if e["healthy"]]
         best = healthy[-1] if healthy else None
     return best["path"] if best else None
-
-
-# -------------------------------------------------------------------- SLOs
-
-
-@dataclasses.dataclass(frozen=True)
-class SLOConfig:
-    """Serve-tier service-level objectives. ``p99_ms``: target warm tail
-    latency (the SLI is the fraction of requests slower than it;
-    ``error_budget`` of them are allowed). ``qps``: target sustained
-    throughput (the SLI is the relative shortfall against it). 0 leaves
-    an objective unset."""
-
-    p99_ms: float = 0.0
-    qps: float = 0.0
-    error_budget: float = 0.01
-
-    @property
-    def enabled(self) -> bool:
-        return bool(self.p99_ms or self.qps)
-
-
-def slo_burn(slo: SLOConfig, latencies_ms: List[float],
-             elapsed_s: float) -> List[Dict[str, Any]]:
-    """Price an observation window against the SLOs: one record per set
-    objective — ``{"slo", "target", "observed", "burn_rate", ...}`` —
-    where burn_rate is the multiple of the error budget the window is
-    consuming (>1 = violating; the alerting threshold everywhere)."""
-    records: List[Dict[str, Any]] = []
-    n = len(latencies_ms)
-    if slo.p99_ms and n:
-        over = sum(1 for v in latencies_ms if v > slo.p99_ms) / n
-        srt = sorted(latencies_ms)
-        p99 = srt[min(n - 1, int(0.99 * n))]
-        records.append({
-            "slo": "p99_ms", "target": float(slo.p99_ms),
-            "observed": round(float(p99), 3),
-            "over_fraction": round(over, 4),
-            "burn_rate": round(over / slo.error_budget, 3),
-            "requests": n,
-        })
-    if slo.qps and elapsed_s > 0 and n:
-        observed = n / elapsed_s
-        shortfall = max(0.0, 1.0 - observed / slo.qps)
-        records.append({
-            "slo": "qps", "target": float(slo.qps),
-            "observed": round(observed, 3),
-            "over_fraction": round(shortfall, 4),
-            "burn_rate": round(shortfall / slo.error_budget, 3),
-            "requests": n,
-        })
-    return records
-
-
-def record_slo_burn(slo: SLOConfig, latencies_ms: List[float],
-                    elapsed_s: float, recorder=None) -> List[Dict[str, Any]]:
-    """``slo_burn`` metrics onto ``recorder`` for each set objective;
-    returns the records."""
-    from fks_tpu.obs.recorder import get_recorder
-
-    rec = recorder if recorder is not None else get_recorder()
-    records = slo_burn(slo, latencies_ms, elapsed_s)
-    for r in records:
-        rec.metric("slo_burn", dict(r))
-    return records

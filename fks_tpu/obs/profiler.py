@@ -112,17 +112,10 @@ class StageProfiler:
     """
 
     def __init__(self, enabled: bool = True, scope: str = "evolve",
-                 recorder=None, watcher: Optional[CompileWatcher] = None,
-                 sampler=None):
+                 recorder=None, watcher: Optional[CompileWatcher] = None):
         self.enabled = bool(enabled)
         self.scope = scope
         self.recorder = recorder if recorder is not None else get_recorder()
-        # optional memory watermark hook (fks_tpu.obs.memory
-        # .WatermarkSampler): one sample per completed stage, so the
-        # watermark table attributes RSS/device bytes to pipeline stages.
-        # None (default) and a disabled sampler are both exact no-ops —
-        # the profiled/mem_sampled jaxpr pins stay bit-identical.
-        self.sampler = sampler
         self.records: List[Dict[str, Any]] = []
         self._depth = 0
         self._segments = 0
@@ -197,8 +190,6 @@ class StageProfiler:
             handle.record = rec
             self.records.append(rec)
             self.recorder.metric("device_profile", dict(rec))
-            if self.sampler is not None:
-                self.sampler.sample(stage=name)
 
     def segment_tick(self, n: int = 1) -> None:
         """Count a dispatched trace segment against the open stage (wired

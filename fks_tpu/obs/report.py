@@ -252,7 +252,7 @@ def _device_profile_section(metrics: List[Dict[str, Any]]) -> List[str]:
 
 
 def _slo_section(metrics: List[Dict[str, Any]]) -> List[str]:
-    """Latest burn rate per SLO (fks_tpu.obs.history.slo_burn): burn > 1
+    """Latest burn rate per SLO (fks_tpu.serve.accounting.slo_burn): burn > 1
     means the error budget is being consumed faster than allowed."""
     burns = [m for m in metrics if m.get("kind") == "slo_burn"]
     if not burns:
@@ -272,93 +272,9 @@ def _slo_section(metrics: List[Dict[str, Any]]) -> List[str]:
     return lines
 
 
-def _memory_section(metrics: List[Dict[str, Any]]) -> List[str]:
-    """Memory observability (fks_tpu.obs.memory): the footprint ladder —
-    every compiled executable's predicted HBM claim from
-    ``memory_analysis``, latest record per (component, exe), ranked
-    largest-first — plus the per-mesh-layout roll-up, the watermark
-    sampler's latest host/device high-water view, and the leak
-    sentinel's verdict per fenced hot loop."""
-    fps = [m for m in metrics if m.get("kind") == "memory_footprint"]
-    wms = [m for m in metrics if m.get("kind") == "memory_watermark"]
-    leaks = [m for m in metrics if m.get("kind") == "leak_check"]
-    if not (fps or wms or leaks):
-        return []
-    lines = ["memory (obs.memory):"]
-    if fps:
-        latest: Dict[Tuple[str, str], Dict[str, Any]] = {}
-        for m in fps:
-            latest[(str(m.get("component", "?")),
-                    str(m.get("exe_key", "?")))] = m
-        def total(m: Dict[str, Any]) -> int:
-            return int(m.get("total_bytes",
-                             sum(int(m.get(k, 0)) for k in
-                                 ("temp_bytes", "argument_bytes",
-                                  "output_bytes",
-                                  "generated_code_bytes"))))
-        ranked = sorted(latest.items(), key=lambda kv: -total(kv[1]))
-        rows = [{
-            "component": c,
-            "exe": e,
-            "temp_KiB": _num(int(m.get("temp_bytes", 0)) / 2**10, 1),
-            "args_KiB": _num(int(m.get("argument_bytes", 0)) / 2**10, 1),
-            "out_KiB": _num(int(m.get("output_bytes", 0)) / 2**10, 1),
-            "code_KiB": _num(
-                int(m.get("generated_code_bytes", 0)) / 2**10, 1),
-            "total_KiB": _num(total(m) / 2**10, 1),
-        } for (c, e), m in ranked]
-        lines.append(f"  footprint ladder ({len(rows)} executables, "
-                     "largest first):")
-        lines += ["  " + ln for ln in _fmt_table(
-            rows, ["component", "exe", "temp_KiB", "args_KiB", "out_KiB",
-                   "code_KiB", "total_KiB"])]
-        from fks_tpu.obs.memory import rollup  # deferred, like exporter
-        for a in rollup([m for _, m in ranked]):
-            layout = a["mesh_layout"] or "unsharded"
-            lines.append(
-                f"  {a['component']} [{layout}]: {a['executables']} "
-                f"executables, predicted "
-                f"{a['predicted_hbm_bytes'] / 2**20:.2f} MiB HBM, "
-                f"peak temp {a['peak_temp_bytes'] / 2**10:.1f} KiB")
-    if wms:
-        rss = [int(m.get("host_rss_kb", 0)) for m in wms]
-        lines.append(f"  watermarks: {len(wms)} samples, host RSS peak "
-                     f"{max(rss) / 1024:.0f} MiB")
-        last = wms[-1]
-        dev_rows = [{
-            "dev": d.get("id", "?"),
-            "platform": d.get("platform", "?"),
-            "in_use_MiB": ("" if "bytes_in_use" not in d else
-                           _num(int(d["bytes_in_use"]) / 2**20, 2)),
-            "peak_MiB": ("" if "peak_bytes_in_use" not in d else
-                         _num(int(d["peak_bytes_in_use"]) / 2**20, 2)),
-            "limit_MiB": ("" if "bytes_limit" not in d else
-                          _num(int(d["bytes_limit"]) / 2**20, 0)),
-            "delta_KiB": ("" if "delta_bytes" not in d else
-                          _num(int(d["delta_bytes"]) / 2**10, 1)),
-        } for d in (last.get("devices") or []) if isinstance(d, dict)]
-        if dev_rows:
-            lines += ["  " + ln for ln in _fmt_table(
-                dev_rows, ["dev", "platform", "in_use_MiB", "peak_MiB",
-                           "limit_MiB", "delta_KiB"])]
-    if leaks:
-        latest_leak: Dict[str, Dict[str, Any]] = {}
-        for m in leaks:
-            latest_leak[str(m.get("loop", "?"))] = m
-        for loop in sorted(latest_leak):
-            m = latest_leak[loop]
-            verdict = "ok" if m.get("ok") else "LEAK"
-            lines.append(
-                f"  leak sentinel {loop}: {verdict} — drift "
-                f"{m.get('drift_count', 0)} arrays / "
-                f"{m.get('drift_bytes', 0)} bytes over "
-                f"{m.get('iterations', 0)} iterations")
-    return lines
-
-
 def _tenant_section(metrics: List[Dict[str, Any]]) -> List[str]:
-    """Per-tenant accounting (fks_tpu.obs.workload): latest tenant_stats
-    row per tenant — request/shed/expired/degraded counters, EWMA and
+    """Per-tenant accounting (fks_tpu.serve.accounting): latest
+    tenant_stats row per tenant — request/shed/expired/degraded counters, EWMA and
     tail latency, goodput, SLO burn — plus the Jain fairness index over
     per-tenant goodput, the latest workload-mix window, and the last
     loadgen summary when the run drove synthetic load."""
@@ -367,7 +283,7 @@ def _tenant_section(metrics: List[Dict[str, Any]]) -> List[str]:
     lgs = [m for m in metrics if m.get("kind") == "loadgen_summary"]
     if not (stats or mixes or lgs):
         return []
-    lines = ["tenants (obs.workload):"]
+    lines = ["tenants (serve.accounting):"]
     if stats:
         latest: Dict[str, Dict[str, Any]] = {}
         for m in stats:
@@ -518,7 +434,7 @@ def render_report(run_dir: str) -> str:
                     _device_profile_section(metrics), _slo_section(metrics),
                     _tenant_section(metrics),
                     _portfolio_section(metrics, events),
-                    _memory_section(metrics), _compile_section(events),
+                    _compile_section(events),
                     _span_section(events)):
         if section:
             lines.append("")

@@ -186,9 +186,8 @@ _MEMORY_STAT_ALIASES = (
 def normalize_memory_stats(raw: Any) -> Optional[Dict[str, int]]:
     """Canonicalize a backend's ``Device.memory_stats()`` dict to the
     closed ``bytes_in_use`` / ``peak_bytes_in_use`` / ``bytes_limit``
-    subset every downstream reader (report tables, ``fks_mem_*`` gauges,
-    the watermark sampler) keys on. Backends that don't report — CPU
-    returns None, some raise — normalize to None; partial dicts keep
+    subset the report's device table keys on. Backends that don't report
+    — CPU returns None, some raise — normalize to None; partial dicts keep
     whichever canonical keys they can answer, so a reader never KeyErrors
     on a backend-specific spelling."""
     if not isinstance(raw, dict) or not raw:

@@ -43,11 +43,12 @@ from typing import Any, Callable, Dict, List, Optional
 import numpy as np
 
 from fks_tpu import obs
-from fks_tpu.obs import trace_ctx
-from fks_tpu.obs.history import SLOConfig, slo_burn
+from fks_tpu.funsearch.parity import ParitySentinel
 from fks_tpu.funsearch.vm import VMUnsupported
+from fks_tpu.obs import trace_ctx
 from fks_tpu.pipeline.faults import FaultPlan, KillSwitch, NO_FAULTS
 from fks_tpu.pipeline.state import PromotionLog, TERMINAL
+from fks_tpu.serve.accounting import SLOConfig, slo_burn
 from fks_tpu.serve.artifact import (
     CHAMPION_DIR, ChampionSpec, ServeEngine, latest_champion, load_champion,
 )
@@ -301,8 +302,8 @@ class PromotionController:
         if not queries:
             queries = self._synthetic_queries(incumbent, cfg.shadow_queries)
         failures: List[str] = []
-        sentinel = obs.ParitySentinel(None, tol=cfg.parity_tol,
-                                      recorder=self.recorder)
+        sentinel = ParitySentinel(None, tol=cfg.parity_tol,
+                                  recorder=self.recorder)
         delay = self.faults.shadow_delay_s()
         lat, inc_lat = [], []
         for i, q in enumerate(queries):

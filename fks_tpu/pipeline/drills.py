@@ -172,7 +172,7 @@ def _drill_p99_regression_rejected(stack: DrillStack) -> Dict[str, Any]:
         with tempfile.TemporaryDirectory(prefix="fks_drill_") as tmp:
             stack.traffic(service, 3)
             write_champion(tmp, stack.candidate_code, 0.9)
-            from fks_tpu.obs.history import SLOConfig
+            from fks_tpu.serve.accounting import SLOConfig
 
             ctrl = stack.controller(
                 service, tmp, faults=FaultPlan(shadow_latency_ms=400.0),
@@ -242,7 +242,7 @@ def _drill_kill_promoted(stack: DrillStack) -> Dict[str, Any]:
 def _drill_rollback_on_burn(stack: DrillStack) -> Dict[str, Any]:
     """Post-promotion SLO burn inside the probation window rolls back to
     the last-good engine automatically."""
-    from fks_tpu.obs.history import SLOConfig
+    from fks_tpu.serve.accounting import SLOConfig
 
     service = stack.service()
     try:

@@ -33,7 +33,6 @@ import numpy as np
 
 from fks_tpu import obs
 from fks_tpu.data.entities import Workload
-from fks_tpu.obs.memory import record_footprint
 from fks_tpu.funsearch import vm
 from fks_tpu.parallel.mesh import make_sharded_portfolio_serve_fn
 from fks_tpu.serve.artifact import ChampionSpec
@@ -271,12 +270,6 @@ class PortfolioEngine(VMServeEngine):
                 self._keep_writes(compiled, writes0)
         self._compiled[key] = compiled
         self.cold_compiles += 1
-        record_footprint(
-            "serve_vm",
-            f"lanes={lanes},pods={pod_bucket},"
-            f"cap={self.program_capacity},slots={self.n_slots}",
-            compiled, mesh=self.mesh, recorder=self.recorder,
-            engine=self.engine_name, engine_kind=self.engine_kind)
         return compiled
 
     # ----- answering (slot threading)

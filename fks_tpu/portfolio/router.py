@@ -22,7 +22,7 @@ import hashlib
 from typing import Dict, List, Optional, Sequence, Tuple
 
 from fks_tpu.funsearch import vm
-from fks_tpu.obs.workload import QueryFingerprinter
+from fks_tpu.serve.accounting import QueryFingerprinter
 
 #: slot sentinel: serve this request on the AOT fallback engine
 FALLBACK = -1

@@ -46,7 +46,6 @@ import numpy as np
 from fks_tpu import obs
 from fks_tpu.data.entities import ClusterArrays, Workload
 from fks_tpu.obs import trace_ctx
-from fks_tpu.obs.memory import record_footprint
 from fks_tpu.parallel.mesh import (
     lanes_per_device, make_sharded_serve_fn, num_shards, occupancy_stats,
     pad_population, serve_lane_count, serve_sharding,
@@ -607,12 +606,6 @@ class ServeEngine:
                 self._keep_writes(compiled, writes0)
         self._compiled[key] = compiled
         self.cold_compiles += 1
-        # executable-footprint ledger: every ladder rung's predicted HBM
-        # claim (memory_analysis) is one memory_footprint record
-        record_footprint("serve_aot", f"lanes={lanes},pods={pod_bucket}",
-                         compiled, mesh=self.mesh, recorder=self.recorder,
-                         engine=self.engine_name,
-                         engine_kind=self.engine_kind)
         return compiled
 
     def warmup(self, lane_buckets: Optional[Sequence[int]] = None,

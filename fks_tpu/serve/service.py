@@ -24,14 +24,14 @@ from typing import Any, Dict, List, Optional, Sequence, Tuple
 import numpy as np
 
 from fks_tpu import obs
+from fks_tpu.funsearch.parity import ParitySentinel
 from fks_tpu.obs import trace_ctx
-from fks_tpu.obs.history import SLOConfig, record_slo_burn
-from fks_tpu.obs.watchdog import ParitySentinel
-from fks_tpu.obs.workload import (
-    QueryFingerprinter, TenantAccountant, tenant_of,
-)
 from fks_tpu.resilience.deadline import Deadline, ResilienceError
 from fks_tpu.resilience.degrade import DegradeConfig, DegradedModeManager
+from fks_tpu.serve.accounting import (
+    QueryFingerprinter, SLOConfig, TenantAccountant, record_slo_burn,
+    tenant_of,
+)
 from fks_tpu.serve.artifact import ChampionSpec, ServeEngine
 from fks_tpu.serve.batcher import RequestBatcher, pods_to_dicts
 
@@ -56,7 +56,7 @@ class ServeService:
         self.engine = engine
         self.recorder = recorder if recorder is not None else obs.get_recorder()
         self.audit_every = int(audit_every)
-        # tenant/workload accounting (obs.workload): OFF by default —
+        # tenant/workload accounting (serve.accounting): OFF by default —
         # the disabled path allocates nothing and touches no lock, the
         # NullRecorder rule applied to accounting
         self.accountant: Optional[TenantAccountant] = None
@@ -70,7 +70,7 @@ class ServeService:
         # (a query's own deadline_ms always wins); 0 disables each
         self.default_deadline_s = float(default_deadline_s)
         self._degrade: Optional[DegradedModeManager] = None
-        # serve-tier SLO (fks_tpu.obs.history.SLOConfig): p99/qps targets
+        # serve-tier SLO (fks_tpu.serve.accounting.SLOConfig): p99/qps targets
         # priced as error-budget burn rates — one slo_burn metric every
         # ``slo_every`` requests plus one at summary(), so ``cli watch``
         # alerts live and the exporter publishes fks_slo_* gauges

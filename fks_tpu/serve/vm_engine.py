@@ -42,7 +42,6 @@ import jax
 
 from fks_tpu import obs
 from fks_tpu.data.entities import Workload
-from fks_tpu.obs.memory import record_footprint
 from fks_tpu.funsearch import vm
 from fks_tpu.parallel.mesh import make_sharded_vm_serve_fn
 from fks_tpu.serve.artifact import ChampionSpec, ServeEngine
@@ -333,13 +332,6 @@ class VMServeEngine(ServeEngine):
                 self._keep_writes(compiled, writes0)
         self._compiled[key] = compiled
         self.cold_compiles += 1
-        # footprint ledger: the capacity-bucket executable's predicted
-        # HBM claim — shared by every champion it will ever serve
-        record_footprint(
-            "serve_vm",
-            f"lanes={lanes},pods={pod_bucket},cap={self.program_capacity}",
-            compiled, mesh=self.mesh, recorder=self.recorder,
-            engine=self.engine_name, engine_kind=self.engine_kind)
         return compiled
 
     # ----- answering

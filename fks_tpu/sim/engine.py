@@ -96,7 +96,7 @@ class SimConfig:
     # NaN/Inf/out-of-[0,1]. Python-static, so the disabled path compiles
     # to the exact same program as a build without guards.
     watchdog: bool = False
-    # decision-trace instrument (fks_tpu.obs.tracing): log one row per
+    # decision-trace instrument (fks_tpu.funsearch.tracing): log one row per
     # processed event — kind (CREATE/DELETE/RETRY), pod id, chosen node,
     # winning score + second-best margin, pending count, post-step free
     # aggregates — into a bounded TraceBuffer carried in the engine state.

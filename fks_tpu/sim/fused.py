@@ -140,7 +140,7 @@ def _build_plan(workload: Workload, cfg: SimConfig) -> _Plan:
     if cfg.decision_trace:
         raise ValueError("decision trace is not supported in the fused "
                          "kernel; replay with engine='exact' or 'flat' "
-                         "(fks_tpu.obs.tracing / cli trace-diff)")
+                         "(fks_tpu.funsearch.tracing / cli trace-diff)")
     if cfg.probe_score:
         raise ValueError("budget probe rungs (SimConfig.probe_score, "
                          "fks_tpu.funsearch.budget) are not supported in "

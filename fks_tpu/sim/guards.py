@@ -19,14 +19,15 @@ When a guard fires, the offending scores are masked to 0 ("refuse", the
 engines' no-placement sentinel) — identity for finite inputs, so an
 enabled watchdog is also bit-identical whenever no violation fires.
 
-The host half (event emission, parity sentinel, divergence audit) lives in
-``fks_tpu.obs.watchdog``, which re-exports these symbols.
+The host half lives above: event emission and the divergence audit in
+``fks_tpu.obs.watchdog``, the parity sentinel in ``fks_tpu.funsearch.parity``.
 """
 from __future__ import annotations
 
-from typing import List
+from typing import Any, List
 
 import jax.numpy as jnp
+import numpy as np
 
 #: sticky violation bits carried in ``numeric_flags``
 FLAG_NAN = 1    # a policy score or the fitness was NaN
@@ -39,6 +40,15 @@ FLAG_NAMES = ((FLAG_NAN, "nan"), (FLAG_INF, "inf"), (FLAG_RANGE, "range"))
 def describe_flags(mask: int) -> List[str]:
     """Human-readable names for a violation bitmask (host-side)."""
     return [name for bit, name in FLAG_NAMES if int(mask) & bit]
+
+
+def combined_flags(numeric_flags: Any) -> int:
+    """OR-reduce a result's flag mask — a scalar, a per-lane array, or a
+    nested batch — to one Python int (host-side)."""
+    arr = np.asarray(numeric_flags)
+    if arr.size == 0:
+        return 0
+    return int(np.bitwise_or.reduce(arr.reshape(-1).astype(np.int64)))
 
 
 def score_flags(raw_scores, gate):

@@ -248,5 +248,5 @@ class SimResult(NamedTuple):
     # sticky OR of per-step policy-score violations + final fitness check
     numeric_flags: Any
     # decision TraceBuffer, or None unless SimConfig.decision_trace
-    # (fks_tpu.obs.tracing extracts/aligns it)
+    # (fks_tpu.funsearch.tracing extracts/aligns it)
     trace: Any = None

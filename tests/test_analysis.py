@@ -362,6 +362,19 @@ def test_lint_ignores_unjitted_and_closures():
     assert lint.lint_source("mod.py", src) == []
 
 
+def test_lint_leaves_an_aot_compile_site_alone():
+    """A ``.lower(...).compile()`` owes nothing to a ledger: the rule that
+    made every compile site file a memory footprint went with the ledger
+    nothing read."""
+    src = (
+        "import jax\n"
+        "def bucket(fn, example):\n"
+        "    return jax.jit(fn).lower(*example).compile()\n")
+    assert lint.lint_source("mod.py", src) == []
+    assert sorted(lint.LINT_CODES) == [
+        "FKS101", "FKS102", "FKS103", "FKS104", "FKS105"]
+
+
 def test_lint_syntax_error_is_a_finding():
     findings = lint.lint_source("broken.py", "def f(:\n")
     assert [f.code for f in findings] == ["FKS100"]

@@ -326,7 +326,7 @@ class _Recorder:
 
 
 def test_check_champion_silent_on_sound_pruning(budget_eval_setup):
-    from fks_tpu.obs.watchdog import ParitySentinel
+    from fks_tpu.funsearch.parity import ParitySentinel
 
     wl, suite, robust, budget, codes = budget_eval_setup
     ev = CodeEvaluator(wl, suite=suite, robust=robust, budget=budget)
@@ -343,7 +343,7 @@ def test_check_champion_silent_on_sound_pruning(budget_eval_setup):
 
 
 def test_check_champion_alerts_on_wrong_prune():
-    from fks_tpu.obs.watchdog import ParitySentinel
+    from fks_tpu.funsearch.parity import ParitySentinel
 
     wl = micro_workload()
     ev = CodeEvaluator(wl, suite=get_suite("smoke3", wl),
@@ -369,7 +369,7 @@ def test_check_champion_alerts_on_wrong_prune():
 
 
 def test_check_champion_skips_without_budget_records():
-    from fks_tpu.obs.watchdog import ParitySentinel
+    from fks_tpu.funsearch.parity import ParitySentinel
 
     wl = micro_workload()
     ev = CodeEvaluator(wl, suite=get_suite("smoke3", wl),

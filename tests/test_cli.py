@@ -26,6 +26,15 @@ def test_requires_subcommand():
         cli.main([])
 
 
+def test_mem_is_no_subcommand(capsys):
+    """The memory ledger went with its command (PR 48): argparse names the
+    subcommands that are."""
+    with pytest.raises(SystemExit) as e:
+        cli.main(["mem", "--sample"])
+    assert e.value.code == 2
+    assert "invalid choice: 'mem'" in capsys.readouterr().err
+
+
 def test_cli_scale_synthetic(capsys):
     from fks_tpu.cli import main
 

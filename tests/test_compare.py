@@ -188,6 +188,30 @@ def test_schema_checker_validates_watchdog_event_kinds(tmp_path):
     assert cjs.main(["--run-dir", str(bad)]) == 1
 
 
+#: kinds of PR 48's retired memory ledger; the golden run holds one row each
+RETIRED_KINDS = {"memory_footprint", "memory_watermark", "leak_check"}
+
+
+@pytest.mark.parametrize("reader",
+                         ["report", "export-metrics", "watch", "schema"])
+def test_rows_of_a_retired_kind_are_ignored(reader, capsys):
+    """A run directory recorded before the memory ledger went is still a
+    run directory: every reader accepts it and says nothing of the rows
+    it no longer knows."""
+    with open(os.path.join(GOLDEN, "metrics.jsonl")) as f:
+        assert RETIRED_KINDS <= {json.loads(l)["kind"] for l in f}
+    if reader == "schema":
+        cjs = _schema_tool()
+        assert cjs.main(["--run-dir", GOLDEN]) == 0
+        assert not RETIRED_KINDS & set(cjs.METRIC_KIND_REQUIRED)
+    else:
+        argv = [reader, GOLDEN] + (["--once"] if reader == "watch" else [])
+        assert cli.main(argv) == 0
+    out = capsys.readouterr().out
+    for word in ("fks_mem_", "memory (", "leak", "footprint", "watermark"):
+        assert word not in out, (reader, word)
+
+
 # -------------------------------------------------------------- liveness
 
 def _live_run(tmp_path, heartbeat_age, gap=10.0):

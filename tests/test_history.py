@@ -18,9 +18,8 @@ import time
 import pytest
 
 from fks_tpu import cli
-from fks_tpu.obs.history import (
-    RunHistory, SLOConfig, record_slo_burn, resolve_auto_baseline, slo_burn,
-)
+from fks_tpu.obs.history import RunHistory, resolve_auto_baseline
+from fks_tpu.serve.accounting import SLOConfig, record_slo_burn, slo_burn
 
 REPO = pathlib.Path(__file__).parent.parent
 GOLDEN = str(pathlib.Path(__file__).parent / "fixtures" / "golden_run")

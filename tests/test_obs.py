@@ -12,6 +12,7 @@ import pytest
 
 from fks_tpu import obs
 from fks_tpu.obs import recorder as recorder_mod
+from fks_tpu.obs.report import render_report, sparkline
 
 
 # --------------------------------------------------------------- recorder
@@ -247,6 +248,20 @@ def test_device_snapshot_cpu_guarded():
         assert "memory_stats" in d  # None on CPU is fine; key must exist
 
 
+def test_normalize_memory_stats_aliases_and_partials():
+    norm = obs.normalize_memory_stats
+    assert norm(None) is None
+    assert norm({}) is None
+    assert norm({"weird": 1}) is None
+    full = {"bytes_in_use": 10, "peak_bytes_in_use": 20, "bytes_limit": 30}
+    assert norm(full) == full
+    # another backend's spellings land on the canonical keys
+    assert norm({"bytes_used": 7, "bytes_reservable_limit": 9}) == {
+        "bytes_in_use": 7, "bytes_limit": 9}
+    # partial dicts keep what they can answer
+    assert norm({"bytes_in_use": 7}) == {"bytes_in_use": 7}
+
+
 def test_mesh_snapshot_pad_waste(tmp_path):
     from fks_tpu.parallel import population_mesh
     from fks_tpu.parallel.mesh import num_shards, pad_stats
@@ -358,9 +373,9 @@ def test_percentiles_nearest_rank():
 
 
 def test_sparkline():
-    assert obs.sparkline([]) == ""
-    assert obs.sparkline([1.0, 1.0]) == "▄▄"
-    s = obs.sparkline([0.0, 0.5, 1.0])
+    assert sparkline([]) == ""
+    assert sparkline([1.0, 1.0]) == "▄▄"
+    s = sparkline([0.0, 0.5, 1.0])
     assert s[0] == "▁" and s[-1] == "█" and len(s) == 3
 
 
@@ -386,7 +401,7 @@ def test_render_report_from_jsonl_alone(tmp_path):
                                    "compile_seconds": 9.5,
                                    "steady_state_seconds": 5.0})
         rec.annotate_meta(best_score=0.45)
-    out = obs.render_report(d)
+    out = render_report(d)
     assert "status ok" in out
     assert "[evolve]" in out
     assert "generations: 2" in out
@@ -408,11 +423,11 @@ def test_render_report_tolerates_torn_tail_and_missing_files(tmp_path):
     (d / "metrics.jsonl").write_text(
         json.dumps({"ts": 1, "kind": "generation", "generation": 1,
                     "best_score": 0.2}) + "\n" + '{"ts": 2, "kind": "gen')
-    out = obs.render_report(str(d))
+    out = render_report(str(d))
     assert "generations: 1" in out
     assert "status running" in out
     with pytest.raises(FileNotFoundError):
-        obs.render_report(str(tmp_path / "nope"))
+        render_report(str(tmp_path / "nope"))
 
 
 def test_read_jsonl_rejects_mid_file_corruption(tmp_path):

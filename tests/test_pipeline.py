@@ -30,7 +30,6 @@ import pytest
 from fks_tpu.data.synthetic import synthetic_workload
 from fks_tpu.funsearch import template
 from fks_tpu.obs import CompileWatcher, FlightRecorder, recording
-from fks_tpu.obs.history import SLOConfig
 from fks_tpu.pipeline import (
     FaultPlan, KillSwitch, PromotionConfig, PromotionController,
     PromotionLog, attempt_id, follow_ledger, write_champion,
@@ -40,6 +39,7 @@ from fks_tpu.serve import (
     ChampionSpec, ServeEngine, ServeService, ShapeEnvelope, latest_champion,
     load_champion,
 )
+from fks_tpu.serve.accounting import SLOConfig
 
 BETTER_LOGIC = ("score = 1000 + (node.cpu_milli_left - pod.cpu_milli) "
                 "/ max(1, node.cpu_milli_total)")

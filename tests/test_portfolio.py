@@ -17,7 +17,6 @@ import pytest
 from fks_tpu.data.synthetic import synthetic_workload
 from fks_tpu.funsearch import template, vm
 from fks_tpu.obs import CompileWatcher
-from fks_tpu.obs.workload import QueryFingerprinter
 from fks_tpu.pipeline import PromotionConfig, write_champion
 from fks_tpu.portfolio import (
     FALLBACK, FleetController, PortfolioEngine, PortfolioService,
@@ -26,6 +25,7 @@ from fks_tpu.portfolio import (
 from fks_tpu.serve import (
     ChampionSpec, ServeEngine, ShapeEnvelope, VMServeEngine,
 )
+from fks_tpu.serve.accounting import QueryFingerprinter
 from fks_tpu.serve.artifact import Workload
 from fks_tpu.serve.batcher import (
     pack_portfolio_tables, unpack_portfolio_tables,

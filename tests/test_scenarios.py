@@ -31,7 +31,7 @@ import pytest
 from fks_tpu.data.build import make_workload
 from fks_tpu.data.synthetic import synthetic_workload
 from fks_tpu.models import parametric, zoo
-from fks_tpu.obs import tracing
+from fks_tpu.funsearch import tracing
 from fks_tpu.ops.heap import KIND_NODE_DOWN, KIND_NODE_UP
 from fks_tpu.scenarios import (
     RobustConfig, ScenarioSpec, aggregate, fault_events_for, get_suite,

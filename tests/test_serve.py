@@ -93,6 +93,36 @@ def test_load_champion_single_and_list(tmp_path):
         load_champion(str(tmp_path / "bad.json"))
 
 
+# ------------------------------------------------------ snapshot cache
+
+
+def test_snapshot_cache_stays_under_its_byte_bound():
+    """The device-resident snapshot-table cache honours a ceiling in
+    BYTES, not only an entry count: eight queries with distinct tables
+    (the table is a function of the real pod count) through a cache that
+    holds two never take it over the bound, the oldest are evicted, and
+    the newest still hits."""
+    wl = synthetic_workload(8, 16, seed=0)
+    champ = ChampionSpec(code=template.fill_template("score = 1000"),
+                         score=0.4)
+    env = ShapeEnvelope(max_pods=8, min_pod_bucket=8, max_batch=2,
+                        max_gpu_milli=1000)
+    distinct = [_query(0, n) for n in range(1, 9)]
+    probe = ServeEngine(champ, wl, envelope=env, engine="flat")
+    probe.answer_batch(distinct[:1])
+    cap = 2 * probe.snapshot_cache_bytes
+    assert cap > 0
+    eng = ServeEngine(champ, wl, envelope=env, engine="flat",
+                      snapshot_cache_max_bytes=cap)
+    for q in distinct:
+        eng.answer_batch([q])
+        assert eng.snapshot_cache_bytes <= cap
+    stats = eng.snapshot_cache_stats()
+    assert stats["misses"] == len(distinct) > stats["entries"]
+    eng.answer_batch([distinct[-1]])
+    assert eng.snapshot_cache_stats()["hits"] == stats["hits"] + 1
+
+
 # ------------------------------------------------- prefilter auto-heuristic
 
 
@@ -405,7 +435,7 @@ def test_http_front_routes_and_errors(engine):
 
 
 def test_audit_served_alerts_on_drift():
-    from fks_tpu.obs import ParitySentinel
+    from fks_tpu.funsearch.parity import ParitySentinel
 
     class Rec:
         def __init__(self):

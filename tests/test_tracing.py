@@ -1,10 +1,10 @@
 """Decision-trace instrument + first-divergence localization.
 
-The contract under test (sim/types.TraceBuffer + obs/tracing docstrings):
+The contract under test (sim/types.TraceBuffer + funsearch/tracing docstrings):
 ``decision_trace=False`` compiles the IDENTICAL program (the trailing
 ``trace=None`` state field has zero pytree leaves); ``decision_trace=True``
 logs one row per processed event inside the jitted step, per-lane under
-vmap and the 8-virtual-device shard_map mesh; ``obs.tracing`` aligns two
+vmap and the 8-virtual-device shard_map mesh; ``funsearch.tracing`` aligns two
 engines' logs and names the first divergent step; the fused kernel
 rejects the instrument with a pointer at the replay path.
 """
@@ -20,7 +20,7 @@ import pytest
 
 from fks_tpu import cli, obs
 from fks_tpu.models import parametric, zoo
-from fks_tpu.obs import tracing
+from fks_tpu.funsearch import tracing
 from fks_tpu.sim import engine, flat, fused
 from fks_tpu.sim.engine import SimConfig
 from fks_tpu.sim.types import TRACE_KIND_NAMES, TraceBuffer

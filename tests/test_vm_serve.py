@@ -247,6 +247,32 @@ def test_swap_program_returns_rollback_handle(wl, envelope):
     assert rolled[0]["placements"] == before[0]["placements"]
 
 
+def test_swaps_and_batches_leave_no_array_behind(wl, envelope):
+    """A serving process swaps champions for as long as it lives: after
+    the warm-up (the bucket's program, the snapshot cache and both
+    champions' first use are residency, not leaks) six swaps with twelve
+    batches between them leave ``jax.live_arrays()`` where it was. Every
+    swap frees the tables it displaces, every batch's buffers are donated
+    or cache hits."""
+    import gc
+
+    champs = [_champ(SEED_LOGIC, 0.4, source="<a>"),
+              _champ(BETTER_LOGIC, 0.9, source="<b>")]
+    eng = VMServeEngine(champs[0], wl, envelope=envelope, engine="flat")
+    queries = [_query(0), _query(1)]
+    for c in (champs[1], champs[0]):
+        eng.swap_program(c)
+        eng.answer_batch(queries)
+    gc.collect()
+    before = len(jax.live_arrays())
+    for i in range(6):
+        eng.swap_program(champs[(i + 1) % 2])
+        eng.answer_batch(queries)
+        eng.answer_batch(queries)
+    gc.collect()
+    assert len(jax.live_arrays()) == before
+
+
 def _ledger_champion(score=0.9):
     """The pinned ledger champion: 292 live ops, the 512 bucket."""
     from tests.test_vm_batch import _champion_code

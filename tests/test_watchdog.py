@@ -16,12 +16,14 @@ import numpy as np
 import pytest
 
 from fks_tpu import obs
+from fks_tpu.funsearch.parity import ParitySentinel
 from fks_tpu.models import parametric, zoo
+from fks_tpu.obs.watchdog import check_result
 from fks_tpu.sim import engine, flat
 from fks_tpu.sim.engine import SimConfig
 from fks_tpu.sim.guards import (
-    FLAG_INF, FLAG_NAN, FLAG_RANGE, describe_flags, fitness_flags,
-    sanitize_scores, score_flags,
+    FLAG_INF, FLAG_NAN, FLAG_RANGE, combined_flags, describe_flags,
+    fitness_flags, sanitize_scores, score_flags,
 )
 
 CLEAN = parametric.seed_weights("first_fit")
@@ -85,9 +87,9 @@ def test_fitness_flags_range_check():
 def test_describe_and_combine_flags():
     assert describe_flags(FLAG_NAN | FLAG_INF) == ["nan", "inf"]
     assert describe_flags(0) == []
-    assert obs.combined_flags(np.asarray([[0, 1], [4, 0]])) == 5
-    assert obs.combined_flags(np.asarray([], np.int32)) == 0
-    assert obs.combined_flags(0) == 0
+    assert combined_flags(np.asarray([[0, 1], [4, 0]])) == 5
+    assert combined_flags(np.asarray([], np.int32)) == 0
+    assert combined_flags(0) == 0
 
 
 # ----------------------------------------------------- engine integration
@@ -103,8 +105,8 @@ def test_watchdog_enabled_clean_is_bit_identical(micro_workload, mod, pol):
     np.testing.assert_array_equal(np.asarray(on.assigned_node),
                                   np.asarray(off.assigned_node))
     assert int(on.scheduled_pods) == int(off.scheduled_pods)
-    assert obs.combined_flags(on.numeric_flags) == 0
-    assert obs.combined_flags(off.numeric_flags) == 0
+    assert combined_flags(on.numeric_flags) == 0
+    assert combined_flags(off.numeric_flags) == 0
 
 
 @pytest.mark.parametrize("mod", [engine, flat], ids=["exact", "flat"])
@@ -112,10 +114,10 @@ def test_nan_policy_flagged_and_fitness_stays_finite(micro_workload, mod):
     cfg = SimConfig(watchdog=True)
     run = jax.jit(mod.make_param_run_fn(micro_workload, _poison_policy, cfg))
     res = run(jnp.float64(1.0), mod.initial_state(micro_workload, cfg))
-    assert obs.combined_flags(res.numeric_flags) & FLAG_NAN
+    assert combined_flags(res.numeric_flags) & FLAG_NAN
     assert np.isfinite(float(res.policy_score))
     inf_res = run(jnp.float64(2.0), mod.initial_state(micro_workload, cfg))
-    assert obs.combined_flags(inf_res.numeric_flags) & FLAG_INF
+    assert combined_flags(inf_res.numeric_flags) & FLAG_INF
     assert np.isfinite(float(inf_res.policy_score))
 
 
@@ -124,7 +126,7 @@ def test_watchdog_off_does_not_flag(micro_workload):
     run = jax.jit(engine.make_param_run_fn(micro_workload, _poison_policy,
                                            cfg))
     res = run(jnp.float64(1.0), engine.initial_state(micro_workload, cfg))
-    assert obs.combined_flags(res.numeric_flags) == 0
+    assert combined_flags(res.numeric_flags) == 0
 
 
 def test_vmap_population_lane_isolation(micro_workload):
@@ -183,7 +185,7 @@ def test_check_result_emits_watchdog_event(tmp_path):
 
     d = tmp_path / "run"
     with obs.FlightRecorder(str(d)) as rec:
-        mask = obs.check_result(_Res(), recorder=rec, generation=4)
+        mask = check_result(_Res(), recorder=rec, generation=4)
     assert mask == FLAG_NAN | FLAG_INF
     events = [json.loads(l) for l in (d / "events.jsonl").read_text()
               .splitlines()]
@@ -200,8 +202,8 @@ def test_check_result_clean_and_flagless_objects(tmp_path):
 
     d = tmp_path / "run"
     with obs.FlightRecorder(str(d)) as rec:
-        assert obs.check_result(_Clean(), recorder=rec) == 0
-        assert obs.check_result(object(), recorder=rec) == 0
+        assert check_result(_Clean(), recorder=rec) == 0
+        assert check_result(object(), recorder=rec) == 0
     events = (d / "events.jsonl").read_text() \
         if (d / "events.jsonl").exists() else ""
     assert "watchdog" not in events
@@ -239,7 +241,7 @@ def _load(d, name):
 def test_parity_sentinel_zero_drift_no_alert(tmp_path):
     d = tmp_path / "run"
     with obs.FlightRecorder(str(d)) as rec:
-        s = obs.ParitySentinel(object(), sample=2, tol=1e-5, recorder=rec)
+        s = ParitySentinel(object(), sample=2, tol=1e-5, recorder=rec)
         s._ref = _StubReference({"a": 0.5, "b": 0.25})
         stats = s.check(1, [("a", 0.5), ("b", 0.25)])
     assert stats == {"generation": 1, "checked": 2, "max_drift": 0.0,
@@ -254,7 +256,7 @@ def test_parity_sentinel_zero_drift_no_alert(tmp_path):
 def test_parity_sentinel_alerts_on_drift(tmp_path):
     d = tmp_path / "run"
     with obs.FlightRecorder(str(d)) as rec:
-        s = obs.ParitySentinel(object(), sample=2, tol=1e-5, recorder=rec)
+        s = ParitySentinel(object(), sample=2, tol=1e-5, recorder=rec)
         s._ref = _StubReference({"a": 0.5, "b": 0.26})  # b drifted by 0.01
         stats = s.check(3, [("a", 0.5), ("b", 0.25)])
     assert stats["alerts"] == 1 and s.alerts == 1
@@ -268,7 +270,7 @@ def test_parity_sentinel_alerts_on_drift(tmp_path):
 
 
 def test_parity_sentinel_sample_zero_is_noop():
-    s = obs.ParitySentinel(object(), sample=0, recorder=obs.NULL)
+    s = ParitySentinel(object(), sample=0, recorder=obs.NULL)
     stats = s.check(1, [("a", 1.0)])
     assert stats == {"generation": 1, "checked": 0, "max_drift": 0.0,
                      "alerts": 0}
@@ -278,7 +280,7 @@ def test_parity_sentinel_sample_zero_is_noop():
 def test_parity_sentinel_survives_reference_failures(tmp_path):
     d = tmp_path / "run"
     with obs.FlightRecorder(str(d)) as rec:
-        s = obs.ParitySentinel(object(), sample=3, tol=1e-5, recorder=rec)
+        s = ParitySentinel(object(), sample=3, tol=1e-5, recorder=rec)
         s._ref = _StubReference({"a": "raise", "b": "not-ok", "c": 0.75})
         stats = s.check(2, [("a", 0.1), ("b", 0.2), ("c", 0.75)])
     assert stats["failed"] == 2 and stats["checked"] == 1
@@ -296,7 +298,7 @@ def test_parity_sentinel_exact_reference_round_trip(micro_workload):
     code = dict(template.seed_policies())["first_fit"]
     base = ev.evaluate_one(code)
     assert base.ok
-    s = obs.ParitySentinel(ev, sample=1, tol=1e-5, recorder=obs.NULL)
+    s = ParitySentinel(ev, sample=1, tol=1e-5, recorder=obs.NULL)
     s._ref = ev  # reuse the already-compiled evaluator as the reference
     stats = s.check(0, [(code, float(base.score))])
     assert stats["checked"] == 1

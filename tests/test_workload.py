@@ -1,4 +1,5 @@
-"""Workload observability layer (fks_tpu.obs.workload).
+"""Serve accounting (fks_tpu.serve.accounting) and the load generator
+(fks_tpu.obs.workload).
 
 The ISSUE-18 acceptance criteria, as tests:
 
@@ -33,11 +34,13 @@ import time
 
 import pytest
 
-from fks_tpu.obs.history import SLOConfig
 from fks_tpu.obs.workload import (
-    DEFAULT_TENANT, LOADGEN_MODES, QueryFingerprinter, TenantAccountant,
-    TenantLoad, default_make_pods, jain_fairness, parse_tenant_spec,
-    run_loadgen, tenant_of,
+    LOADGEN_MODES, TenantLoad, default_make_pods, parse_tenant_spec,
+    run_loadgen,
+)
+from fks_tpu.serve.accounting import (
+    DEFAULT_TENANT, QueryFingerprinter, SLOConfig, TenantAccountant,
+    jain_fairness, tenant_of,
 )
 
 REPO = pathlib.Path(__file__).parent.parent
@@ -116,7 +119,7 @@ def test_fingerprint_cross_process():
     local = fp.classify(PODS)
     code = (
         "import json,sys\n"
-        "from fks_tpu.obs.workload import QueryFingerprinter\n"
+        "from fks_tpu.serve.accounting import QueryFingerprinter\n"
         "pods=json.loads(sys.argv[1])\n"
         "print(QueryFingerprinter().classify(pods))\n"
     )

@@ -13,7 +13,7 @@ Two jobs, one helper:
 Beyond the generic ts/kind floor, records of KNOWN kinds (the watchdog /
 alert / parity / probe_failure vocabulary added with the numerics
 watchdog, plus the evolution ledger's generation records, plus the
-``decision_trace``/``trace_diff`` records from fks_tpu.obs.tracing —
+``decision_trace``/``trace_diff`` records from fks_tpu.funsearch.tracing —
 whose embedded trace rows must carry a known CREATE/DELETE/RETRY/
 NODE_DOWN/NODE_UP event kind, and the scenario-suite records from
 fks_tpu.scenarios) are checked for their kind-specific required keys — a watchdog
@@ -118,14 +118,6 @@ TRACE_EVENT_KINDS = {"CREATE", "DELETE", "RETRY", "NODE_DOWN", "NODE_UP"}
 VM_SWAP_OUTCOMES = {"swapped", "fallback"}
 ENGINE_KINDS = {"aot", "vm"}
 
-#: legal ``component`` values on a memory_footprint record — which tier
-#: compiled the executable (duplicated from fks_tpu.obs.memory
-#: .MEMORY_COMPONENTS; tests/test_memory.py pins the two copies)
-MEMORY_COMPONENTS = {"serve_aot", "serve_vm", "evolve", "bench"}
-#: legal ``loop`` values on a leak_check record (fks_tpu.obs.memory
-#: .LEAK_LOOPS) — which hot loop the leak sentinel fenced
-LEAK_LOOPS = {"serve_batch", "vm_swap", "promotion", "evolve_generation",
-              "drill"}
 #: legal ``mode`` values on a loadgen_summary record (duplicated from
 #: fks_tpu.obs.workload.LOADGEN_MODES; tests/test_workload.py pins the
 #: two copies) — the arrival process that produced the numbers
@@ -167,7 +159,7 @@ METRIC_KIND_REQUIRED: Dict[str, Tuple[str, ...]] = {
     # cross-run history (cli trends): per-metric timeline + robust-z
     # regression alerts over the bench-results archive
     "trend_report": ("metric", "runs", "alerts"),
-    # serve-tier SLO pricing (fks_tpu.obs.history.slo_burn): one record
+    # serve-tier SLO pricing (fks_tpu.serve.accounting.slo_burn): one record
     # per objective; burn_rate > 1 means the error budget is burning
     "slo_burn": ("slo", "target", "observed", "burn_rate"),
     # promotion pipeline (fks_tpu.pipeline.state): one record per
@@ -179,24 +171,11 @@ METRIC_KIND_REQUIRED: Dict[str, Tuple[str, ...]] = {
     # exporter renders these as fks_serve_snapshot_cache_* gauges
     "snapshot_cache": ("hits", "misses", "entries", "hit_rate",
                        "h2d_bytes_per_query"),
-    # executable-footprint ledger (fks_tpu.obs.memory): one record per
-    # compiled executable — its memory_analysis() byte breakdown tagged
-    # with the compiling tier and mesh layout
-    "memory_footprint": ("component", "exe_key", "temp_bytes",
-                         "argument_bytes", "output_bytes",
-                         "generated_code_bytes"),
-    # watermark sampler (fks_tpu.obs.memory): host RSS + per-device
-    # normalized memory watermarks, per stage or per sampling interval
-    "memory_watermark": ("stage", "host_rss_kb", "devices"),
-    # leak sentinel (fks_tpu.obs.memory): live_arrays() drift across N
-    # iterations of a fenced hot loop, judged against a tolerance
-    "leak_check": ("loop", "iterations", "drift_count", "drift_bytes",
-                   "ok"),
-    # workload fingerprinting (fks_tpu.obs.workload): the windowed
+    # workload fingerprinting (fks_tpu.serve.accounting): the windowed
     # distribution of query classes the serve path observed
     "workload_mix": ("window", "distinct", "classes"),
-    # per-tenant accounting (fks_tpu.obs.workload): one row per tenant —
-    # counters, latency, goodput, SLO burn, global fairness index
+    # per-tenant accounting (fks_tpu.serve.accounting): one row per
+    # tenant — counters, latency, goodput, SLO burn, global fairness index
     "tenant_stats": ("tenant", "requests", "shed", "expired", "ewma_ms",
                      "p99_ms", "goodput_qps", "burn_rate",
                      "fairness_index"),
@@ -307,18 +286,6 @@ def check_kinds(path: str, records: List[dict],
                 raise SchemaError(
                     f"{path}: record {i + 1}: unknown route reason "
                     f"{reason!r} (expect one of {sorted(ROUTE_REASONS)})")
-        elif rec.get("kind") == "memory_footprint":
-            comp = rec.get("component")
-            if comp not in MEMORY_COMPONENTS:
-                raise SchemaError(
-                    f"{path}: record {i + 1}: unknown memory component "
-                    f"{comp!r} (expect one of {sorted(MEMORY_COMPONENTS)})")
-        elif rec.get("kind") == "leak_check":
-            loop = rec.get("loop")
-            if loop not in LEAK_LOOPS:
-                raise SchemaError(
-                    f"{path}: record {i + 1}: unknown leak_check loop "
-                    f"{loop!r} (expect one of {sorted(LEAK_LOOPS)})")
         elif rec.get("kind") == "loadgen_summary":
             mode = rec.get("mode")
             if mode not in LOADGEN_MODES:

@@ -189,7 +189,7 @@ def make_scenario_fault_fixture():
 
     from fks_tpu.data.synthetic import synthetic_workload
     from fks_tpu.models import zoo
-    from fks_tpu.obs import tracing
+    from fks_tpu.funsearch import tracing
     from fks_tpu.scenarios import ScenarioSpec, perturb_workload
     from fks_tpu.sim.engine import SimConfig
 
