@@ -1581,6 +1581,16 @@ COMMITTED_SNAPSHOTS = {
         "openb_node_list_all_node.csv",
         "openb_pod_list_gpuspec25_inflated080.csv", "best_fit", 4864, 64,
         "honor"),
+    # the same cluster and list as what-if serving takes it: the first
+    # 5,888 arrivals (70 % of the GPUs, the loaded cluster's fork) as
+    # first_fit places them with the constraints honoured, every one
+    # placed (best_fit refuses a pod at event 3,569, and the exact engine
+    # forks from placed CREATEs only); first_fit's score has no
+    # arithmetic in it, so the file hangs on no precision
+    "openb_snapshot_gpuspec25_inflated080_firstfit_e5888.csv.gz": (
+        "openb_node_list_all_node.csv",
+        "openb_pod_list_gpuspec25_inflated080.csv", "first_fit", 5888, 64,
+        "honor"),
 }
 
 

@@ -62,6 +62,21 @@ def gpu_spec_bits(spec: str, vocab) -> int:
     return bits
 
 
+#: what ``gpu_spec_names`` writes for the sign bit: the names a word lost
+#: were no node's model, and neither is this one
+GPU_SPEC_NO_NODE_NAME = "<no-node>"
+
+
+def gpu_spec_names(bits: int, vocab) -> str:
+    """``gpu_spec_bits`` back: the names of a word's models joined by
+    ``|`` (empty for 0), ``GPU_SPEC_NO_NODE_NAME`` for the sign bit."""
+    bits = int(bits)
+    names = [m for i, m in enumerate(vocab) if bits >> i & 1]
+    if bits < 0:
+        names.append(GPU_SPEC_NO_NODE_NAME)
+    return "|".join(names)
+
+
 def gpu_spec_allows(spec, gpu_model, xp=np):
     """THE rule of GPU-type constraints, elementwise over broadcastable
     int32 arrays (``xp``: numpy on the host, ``jax.numpy`` in the

@@ -372,7 +372,8 @@ class PromotionController:
         from fks_tpu.data.entities import Workload
         from fks_tpu.serve.artifact import _pods_from_dicts
         return Workload(cluster=engine.cluster,
-                        pods=_pods_from_dicts(engine.base_pods))
+                        pods=_pods_from_dicts(engine.base_pods,
+                                              engine.cluster))
 
     def _synthetic_queries(self, engine, n: int) -> List[List[dict]]:
         """No live traffic yet (fresh service): slide windows over the

@@ -71,10 +71,12 @@ class PortfolioEngine(VMServeEngine):
                 "are not built from a fork")
         if workload.typed:
             raise ValueError(
-                "gpu_spec: the portfolio serves queries whose pods carry no "
-                "GPU-type constraints (the query schema has no gpu_spec); "
-                "a workload parsed with gpu_spec='honor' would be answered "
-                "as if it had none. Parse it without the choice")
+                "gpu_spec: the portfolio's slot-table executables are "
+                "built for queries whose pods carry no GPU-type "
+                "constraints; a query's gpu_spec is honoured by "
+                "ServeEngine / VMServeEngine, one champion an engine. "
+                "Serve a workload parsed with gpu_spec='honor' there, or "
+                "parse it without the choice")
         self.n_slots = int(n_slots) if n_slots else len(champions)
         if self.n_slots < len(champions):
             raise ValueError(

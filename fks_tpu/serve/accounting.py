@@ -41,6 +41,7 @@ from typing import Any, Dict, List, Optional, Sequence
 import numpy as np
 
 from fks_tpu.obs.recorder import get_recorder
+from fks_tpu.serve.batcher import query_gpu_spec
 from fks_tpu.sim.evaluator import max_snapshot_count, snapshot_trigger_table
 
 #: queries that name no tenant all account to one bucket — the
@@ -145,7 +146,8 @@ class QueryFingerprinter:
     """Deterministic workload-class signatures + a windowed class mix.
 
     ``classify(pods)`` is pure and ORDER-INDEPENDENT: the signature is
-    (pod-count power-of-two bucket, sorted resource-mix histogram,
+    (pod-count power-of-two bucket, sorted resource-mix histogram with
+    each pod's ``gpu_spec`` set where it names one,
     snapshot-trigger-table hash), digested with ``blake2b`` — the same
     query permuted, re-serialized, or classified in another process
     lands in the same class. ``observe`` classifies AND counts;
@@ -186,6 +188,13 @@ class QueryFingerprinter:
                 _decade(p.get("gpu_milli", 0)),
                 _decade(p.get("duration_time", 0)),
             ))
+            # the GPU models a pod accepts are part of its class (a set:
+            # neither the order of the names nor a repeat matters); a
+            # pod that names none keeps the token it always had
+            spec = query_gpu_spec(p)
+            if spec:
+                tok += "/" + "|".join(sorted(set(filter(
+                    None, spec.split("|")))))
             mix[tok] = mix.get(tok, 0) + 1
         canon = json.dumps(
             [bucket, sorted(mix.items()), self._ktable_digest(n)],

@@ -251,6 +251,10 @@ def _cluster_to_json(c: ClusterArrays) -> dict:
         "gpu_mask": np.asarray(c.gpu_mask).astype(int).tolist(),
         "node_mask": np.asarray(c.node_mask).astype(int).tolist(),
         "node_ids": list(c.node_ids),
+        # a typed cluster's two fields (``gpu_model`` None: not typed)
+        **({} if c.gpu_model is None else {
+            "gpu_model": np.asarray(c.gpu_model).tolist(),
+            "gpu_models": list(c.gpu_models)}),
     }
 
 
@@ -264,6 +268,8 @@ def _cluster_from_json(doc: dict) -> ClusterArrays:
         gpu_mask=np.asarray(doc["gpu_mask"], bool),
         node_mask=np.asarray(doc["node_mask"], bool),
         node_ids=tuple(doc["node_ids"]),
+        gpu_model=i32("gpu_model") if "gpu_model" in doc else None,
+        gpu_models=tuple(doc.get("gpu_models", ())),
     )
 
 
@@ -322,6 +328,17 @@ class ServeEngine:
     snapshot is the engine's, not a query's: a query pod created before
     the snapshot's last arrival is refused.
 
+    GPU-type constraints are data too, and nothing else switches them on:
+    on a ``workload`` that is ``typed`` (parsed with ``gpu_spec="honor"``:
+    the cluster carries ``gpu_model``, the pods ``gpu_spec``) a query pod
+    may carry ``gpu_spec``, the GPU model names it accepts joined by
+    ``|`` (or a list of them), and is placed only on a node whose model
+    is among them (``sim.engine.place_mask_of``); every query of such an
+    engine is built with the leaf, zeros where a pod names nothing, so a
+    bucket still has one program. An engine on any other workload builds
+    the queries and compiles the programs it always did, and refuses a
+    pod with a non-empty ``gpu_spec`` by name (``ValueError``).
+
     ``engine`` picks the simulation module ("exact" serves reference
     semantics and is the parity default; "flat" trades the documented
     retry-rule divergence for throughput). ``prefilter_k=None`` engages
@@ -349,8 +366,14 @@ class ServeEngine:
                 "the fused kernel evaluates parametric populations only; "
                 "serve champions on 'exact' (parity default) or 'flat'")
         self.champion = champion
-        self.cluster = workload.cluster
-        self.base_pods = pods_to_dicts(workload.pods)
+        # the nodes' GPU models stay with the cluster only where the pods
+        # name theirs: the leaf is what makes a query typed
+        # (``build_query_workload``)
+        self.cluster = workload.cluster if workload.typed else \
+            dataclasses.replace(workload.cluster, gpu_model=None,
+                                gpu_models=())
+        self.base_pods = pods_to_dicts(workload.pods,
+                                       gpu_models=self.cluster.gpu_models)
         self.envelope = envelope or ShapeEnvelope()
         #: the loaded cluster every query forks from, or None
         self.fork: Optional[QueryFork] = None
@@ -371,6 +394,9 @@ class ServeEngine:
                        nodes_loaded=self.fork.nodes_loaded,
                        heap_size=self.fork.e0,   # their DELETEs, pending
                        bytes=self.fork.lane_bytes)
+                if self.typed:
+                    sp.set(typed_residents=self.fork.typed_residents,
+                           node_models=len(self.cluster.gpu_models))
         self.engine_name = engine
         self.state_pack = bool(state_pack)
         self.max_steps_factor = int(max_steps_factor)
@@ -483,6 +509,12 @@ class ServeEngine:
     def start_event(self) -> int:
         """Where the champion takes over: 0, or the fork's ``E0``."""
         return 0 if self.fork is None else self.fork.e0
+
+    @property
+    def typed(self) -> bool:
+        """Do this engine's queries carry ``gpu_spec`` (was its workload
+        ``typed``)?"""
+        return self.cluster.gpu_model is not None
 
     def _klen(self, pod_bucket: int) -> int:
         """Fixed snapshot-table width for the bucket, sized at the
@@ -720,13 +752,13 @@ class ServeEngine:
                 return self._answer_chunks(pod_lists)
 
     def validate_query(self, pods: Sequence[dict]) -> None:
-        """``validate_query_pods`` under this engine's envelope and fork
-        (``ValueError``: the service's 4xx)."""
+        """``validate_query_pods`` under this engine's envelope, fork and
+        cluster (``ValueError``: the service's 4xx)."""
         validate_query_pods(
             pods, max_pods=self.envelope.max_pods,
             max_gpu_milli=self.envelope.max_gpu_milli,
             not_before=None if self.fork is None
-            else self.fork.last_arrival)
+            else self.fork.last_arrival, typed=self.typed)
 
     def _answer_chunks(self, pod_lists) -> List[dict]:
         for pods in pod_lists:
@@ -770,6 +802,13 @@ class ServeEngine:
                 self._mod, self.cluster, [pod_lists[i] for i in idxs],
                 bucket, self.bucket_config(bucket), self._klen(bucket),
                 self.fork)
+            if self.typed:
+                # read off the table that ships, not off the request: a
+                # word lost on the way in reads 0 here
+                t_stack.set(
+                    pods=sum(len(pod_lists[i]) for i in idxs),
+                    typed_pods=int(np.count_nonzero(np.asarray(
+                        pods.gpu_spec)[:, self.start_event:])))
         with obs.span("serve/chunk/pack", chunk=chunk) as t_pack:
             pods, kt = pack_query_tables(pods, kt, self._pack_plan(bucket))
         with self.profiler.stage("h2d", span="serve/chunk/h2d", chunk=chunk,
@@ -959,7 +998,7 @@ class ServeEngine:
             "max_steps_factor": self.max_steps_factor,
             "policy_tier": self.policy_tier,
             "cluster": _cluster_to_json(self.cluster),
-            "base_pods": self.base_pods,
+            "base_pods": self.base_pods,   # with gpu_spec, where typed
         }
         if self.fork is not None:
             # base_pods are in input order; the rows name them by index
@@ -995,7 +1034,8 @@ class ServeEngine:
                 f"{ARTIFACT_VERSION}")
         cluster = _cluster_from_json(doc["cluster"])
         wl = Workload(cluster=cluster,
-                      pods=_pods_from_dicts(doc.get("base_pods", [])))
+                      pods=_pods_from_dicts(doc.get("base_pods", []),
+                                            cluster))
         if doc.get("snapshot"):
             from fks_tpu.data.snapshot import placed_creates
             rows = doc["snapshot"]
@@ -1035,14 +1075,20 @@ class ServeEngine:
         return eng
 
 
-def _pods_from_dicts(pods: List[dict]):
-    """Query-schema dicts -> a real-sized PodArrays (artifact base trace)."""
+def _pods_from_dicts(pods: List[dict],
+                     cluster: Optional[ClusterArrays] = None):
+    """Query-schema dicts -> a real-sized PodArrays (artifact base trace).
+    With a typed ``cluster`` the pods get their ``gpu_spec`` words back
+    (``base_pods`` carries the strings), so the workload is typed again."""
     from fks_tpu.data.entities import PodArrays
+    from fks_tpu.serve.batcher import DEFAULT_DURATION, gpu_spec_words
 
     p = max(1, len(pods))
     col = lambda f, d=0: np.asarray(  # noqa: E731
         [int(x.get(f, d)) for x in pods] + [0] * (p - len(pods)), np.int32)
-    from fks_tpu.serve.batcher import DEFAULT_DURATION
+    spec = None
+    if cluster is not None and cluster.gpu_model is not None:
+        spec = gpu_spec_words(pods, cluster.gpu_models, p)
     return PodArrays(
         cpu=col("cpu_milli"), mem=col("memory_mib"),
         num_gpu=col("num_gpu"), gpu_milli=col("gpu_milli"),
@@ -1051,4 +1097,5 @@ def _pods_from_dicts(pods: List[dict]):
         tie_rank=np.arange(p, dtype=np.int32),
         pod_mask=np.arange(p) < len(pods),
         pod_ids=tuple(f"q-{i:05d}" for i in range(len(pods))),
+        gpu_spec=spec,
     )

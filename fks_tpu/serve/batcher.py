@@ -35,7 +35,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from fks_tpu.data.entities import PodArrays, Workload
+from fks_tpu.data.entities import (
+    PodArrays, Workload, gpu_spec_bits, gpu_spec_names)
 from fks_tpu.obs import trace_ctx
 from fks_tpu.parallel.traces import strip_ids
 from fks_tpu.resilience.admission import AdmissionConfig, AdmissionController
@@ -45,22 +46,58 @@ from fks_tpu.resilience.deadline import (
 from fks_tpu.sim.evaluator import max_snapshot_count, snapshot_trigger_table
 
 #: query pod schema — the reference entity field names (simulator/
-#: entities.py:29-43), matching the LLM-facing template docstring
+#: entities.py:29-43), matching the LLM-facing template docstring: the
+#: six whole numbers of a pod. A pod may carry ``gpu_spec`` beside them
+#: (``GPU_SPEC_FIELD``: the GPU models it accepts), which no policy reads
 POD_FIELDS = ("cpu_milli", "memory_mib", "num_gpu", "gpu_milli",
               "creation_time", "duration_time")
+GPU_SPEC_FIELD = "gpu_spec"
 
 #: default lifetime for query pods that omit duration_time: effectively
 #: "never deleted inside the what-if horizon"
 DEFAULT_DURATION = 1_000_000
 
 
+def query_gpu_spec(pod: Dict[str, Any]) -> str:
+    """A query pod's ``gpu_spec`` as the trace's CSV column writes it:
+    GPU model names joined by ``|``. A list of strings is the same set;
+    absent, None or empty allows every node. Anything else is a
+    ``ValueError`` (the service's 4xx)."""
+    spec = pod.get(GPU_SPEC_FIELD)
+    if spec is None or isinstance(spec, str):
+        return spec or ""
+    if isinstance(spec, (list, tuple)) and all(
+            isinstance(m, str) for m in spec):
+        return "|".join(spec)
+    raise ValueError(
+        f"gpu_spec {spec!r} is neither a string of GPU model names joined "
+        "by '|' nor a list of such names")
+
+
+def gpu_spec_words(pods: Sequence[Dict[str, Any]], vocab,
+                   size: int) -> np.ndarray:
+    """i32[size]: each pod's accepted-model word (``gpu_spec_bits``
+    against ``vocab``, the cluster's ``gpu_models``; 0 where a pod names
+    nothing, and in the padding)."""
+    words = np.zeros(size, np.int32)
+    for i, p in enumerate(pods):
+        if p.get(GPU_SPEC_FIELD):
+            words[i] = gpu_spec_bits(query_gpu_spec(p), vocab)
+    return words
+
+
 def validate_query_pods(pods: Sequence[Dict[str, Any]], *, max_pods: int,
                         max_gpu_milli: int,
-                        not_before: Optional[int] = None) -> None:
+                        not_before: Optional[int] = None,
+                        typed: bool = False) -> None:
     """Reject malformed queries before any device work (the error message
     is the service's 4xx body). ``not_before``: an engine that forks from
     a snapshot (``QueryFork``) takes no pod created before the snapshot's
-    last arrival, because the events before the fork are decided."""
+    last arrival, because the events before the fork are decided.
+    ``typed``: does the engine's cluster carry its nodes' GPU models
+    (``Workload.typed``)? One that does not refuses a pod with a
+    non-empty ``gpu_spec`` by name: it would answer as if the pod named
+    nothing, and a wrong answer is worse than none."""
     if not pods:
         raise ValueError("query has no pods")
     if len(pods) > max_pods:
@@ -83,6 +120,17 @@ def validate_query_pods(pods: Sequence[Dict[str, Any]], *, max_pods: int,
                 f"pod {i} creation_time {int(p.get('creation_time', 0))} "
                 f"lies before the fork: this engine answers from a "
                 f"snapshot whose last arrival is at {not_before}")
+        try:
+            spec = query_gpu_spec(p)
+        except ValueError as e:
+            raise ValueError(f"pod {i} {e}") from None
+        if spec and not typed:
+            raise ValueError(
+                f"pod {i} names the GPU models it accepts (gpu_spec "
+                f"{spec!r}), and this engine's cluster was parsed without "
+                "GPU models: it would answer as if the pod named none. "
+                "Build the engine on a workload parsed with "
+                "gpu_spec='honor'")
 
 
 def build_query_workload(cluster, pods: Sequence[Dict[str, Any]],
@@ -96,7 +144,16 @@ def build_query_workload(cluster, pods: Sequence[Dict[str, Any]],
     ``pad_workload`` idiom — never read by the engine). With a ``fork``
     the workload is ``residents ++ query pods`` on a pod axis of ``E0 +
     bucket`` and carries the fork's snapshot, the query's ``tie_rank``
-    after the residents'."""
+    after the residents'.
+
+    GPU-type constraints are data: on a cluster that carries its nodes'
+    models (``cluster.gpu_model``; a serve engine keeps the leaf only
+    where its workload is ``typed``) EVERY query has the ``gpu_spec``
+    leaf, each pod's word made by ``gpu_spec_bits`` against the cluster's
+    own vocabulary (0 where a pod names nothing), so a bucket has one
+    program whatever a query holds. On any other cluster no query has
+    the leaf, and the workload is what it was before the field existed
+    (``validate_query_pods`` has refused a pod that names models)."""
     p_real = len(pods)
     if p_real > bucket:
         raise ValueError(f"{p_real} pods exceed pod bucket {bucket}")
@@ -110,6 +167,11 @@ def build_query_workload(cluster, pods: Sequence[Dict[str, Any]],
             a[e0 + i] = int(p.get(field, default))
         return a
 
+    spec = None
+    if cluster.gpu_model is not None:
+        spec = gpu_spec_words(pods, cluster.gpu_models, bucket)
+        if e0:
+            spec = np.concatenate([fork.spec, spec])
     pa = PodArrays(
         cpu=col("cpu_milli"),
         mem=col("memory_mib"),
@@ -123,6 +185,7 @@ def build_query_workload(cluster, pods: Sequence[Dict[str, Any]],
         pod_mask=np.arange(e0 + bucket) < e0 + p_real,
         pod_ids=(() if fork is None else fork.pod_ids)
         + tuple(f"q-{i:05d}" for i in range(p_real)),
+        gpu_spec=spec,
     )
     return Workload(cluster=cluster, pods=pa, faults=None,
                     snapshot=None if fork is None else fork.snapshot)
@@ -137,9 +200,12 @@ class QueryFork:
     is where the queue lands on the cluster as it stands and what the
     next events of the cluster's life look like with the queue in it.
 
-    Held here, all NumPy: the residents' pod columns in event order, the
-    snapshot re-indexed to that order, ``data.snapshot.Prefix`` (the
-    cluster after the residents, their running sums for the evaluator).
+    Held here, all NumPy: the residents' pod columns in event order
+    (on a typed workload their ``gpu_spec`` words beside the six, as the
+    trace gave them: ``fork_prefix`` has refused a snapshot that puts
+    one of them on a node it may not take), the snapshot re-indexed to
+    that order, ``data.snapshot.Prefix`` (the cluster after the
+    residents, their running sums for the evaluator).
     Per query, ``stack`` adds what does depend on the query: the trigger
     table, which is sized from the WHOLE run's pod count, with the
     evaluator's sums at the fork read off the prefix, and the heap, which
@@ -164,6 +230,11 @@ class QueryFork:
                   "duration_time": p.duration}
         self.cols = {k: np.asarray(v)[order].astype(np.int32)
                      for k, v in fields.items()}
+        #: the residents' accepted-model words, or None (not typed)
+        self.spec = np.asarray(p.gpu_spec, np.int32)[order] \
+            if workload.typed else None
+        self.typed_residents = 0 if self.spec is None \
+            else int(np.count_nonzero(self.spec))
         rank = np.empty(self.e0, np.int32)
         rank[np.argsort(np.asarray(p.tie_rank)[order], kind="stable")] = \
             np.arange(self.e0, dtype=np.int32)
@@ -178,10 +249,11 @@ class QueryFork:
         self.nodes_loaded = int(len(np.unique(self.snapshot.node)))
         c = workload.cluster
         #: bytes of one lane's upload that are the residents' and not the
-        #: query's: their pod columns and mask, their heap and pod_state
-        #: rows, the cluster's four ``*_left`` arrays
+        #: query's: their pod columns (the ``gpu_spec`` words among them,
+        #: where there are any) and mask, their heap and pod_state rows,
+        #: the cluster's four ``*_left`` arrays
         self.lane_bytes = int(
-            self.e0 * (7 * 4 + 1 + 16 + 16)
+            self.e0 * ((7 + (self.spec is not None)) * 4 + 1 + 16 + 16)
             + 4 * c.n_padded * (3 + c.g_padded))
 
     def stack(self, cluster, pod_lists: Sequence[Sequence[dict]],
@@ -309,6 +381,10 @@ def query_pack_plan(cfg, bucket: int, max_gpu_milli: int) -> dict:
       envelope's ``max_gpu_milli``;
     - ``tie_rank`` -> int16: always ``arange(bucket)``.
 
+    Every other leaf ships as it is: the ``gpu_spec`` words of a typed
+    engine's queries are bit sets whose sign bit means something
+    (``GPU_SPEC_NO_NODE``), so nothing narrows them.
+
     All casts are integer->integer with proven ranges, so the round trip
     through ``pack_query_tables``/``unpack_query_tables`` is
     bit-identical (asserted by tests/test_serve_sharded.py)."""
@@ -414,9 +490,13 @@ def tree_h2d_bytes(*trees) -> int:
                    if hasattr(x, "nbytes")))
 
 
-def pods_to_dicts(pods: PodArrays, limit: Optional[int] = None) -> List[dict]:
+def pods_to_dicts(pods: PodArrays, limit: Optional[int] = None,
+                  gpu_models: Sequence[str] = ()) -> List[dict]:
     """Real pod rows back to query-schema dicts — sources selftest and
-    trace-replay queries from a parsed workload."""
+    trace-replay queries from a parsed workload. A pod of a typed
+    workload that names GPU models gets its ``gpu_spec`` back as the
+    trace wrote it, its word read against ``gpu_models`` (the cluster's
+    ``gpu_models``); one that names none has no such key."""
     mask = np.asarray(pods.pod_mask)
     idx = np.nonzero(mask)[0]
     if limit is not None:
@@ -429,7 +509,13 @@ def pods_to_dicts(pods: PodArrays, limit: Optional[int] = None) -> List[dict]:
         "creation_time": np.asarray(pods.creation_time),
         "duration_time": np.asarray(pods.duration),
     }
-    return [{k: int(v[i]) for k, v in cols.items()} for i in idx]
+    out = [{k: int(v[i]) for k, v in cols.items()} for i in idx]
+    if pods.gpu_spec is not None:
+        spec = np.asarray(pods.gpu_spec)
+        for row, i in zip(out, idx):
+            if spec[i]:
+                row[GPU_SPEC_FIELD] = gpu_spec_names(spec[i], gpu_models)
+    return out
 
 
 class QueuedRequest:

@@ -185,6 +185,21 @@ class ServeService:
         arrival stream here"), not a re-evaluation on the trace's own
         cluster.
 
+        A pod is an object of the six whole numbers ``cpu_milli``,
+        ``memory_mib``, ``num_gpu``, ``gpu_milli``, ``creation_time``,
+        ``duration_time`` and, optionally, ``gpu_spec``: the GPU model
+        names it accepts, joined by ``|`` as the trace's CSV column
+        writes them (a list of strings is the same set; absent or empty:
+        any node; a repeated name means nothing; a name no node of the
+        cluster has allows nothing, so such a pod waits). A pod with a
+        non-empty ``gpu_spec`` is placed only on a node whose model is in
+        the set; every other node is to it as a cordoned node is. That
+        takes an engine whose workload was parsed with
+        ``gpu_spec="honor"`` (``engine.typed``); any other refuses the
+        pod by name, and a ``gpu_spec`` that is neither string nor list
+        of strings is refused everywhere: this request's ``ValueError``
+        (HTTP 400) at submit, before it can reach a batch.
+
         The fork. An engine built on a workload that carries a snapshot
         (``fks_tpu.data.snapshot``; ``engine.fork``) answers every query
         from the LOADED cluster: the snapshot's residents stay where it
@@ -219,8 +234,11 @@ class ServeService:
         else:
             raise ValueError("query needs 'pods' (pod list) or 'trace' "
                              "(what-if trace to replay)")
-        if getattr(self.engine, "fork", None) is not None:
-            self.engine.validate_query(pods)
+        # this request's 4xx, not its batch's: a pod list its engine
+        # would refuse never reaches a batch (test doubles have no rules)
+        validate = getattr(self.engine, "validate_query", None)
+        if validate is not None:
+            validate(pods)
         return rid, pods
 
     def submit(self, query: Dict[str, Any]):

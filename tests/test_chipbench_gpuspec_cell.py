@@ -23,6 +23,7 @@ CELL = gpuspec.CELL
 CONTROL = "openb1523-loaded.codegen8"
 MIDRUN = "openb16-cpu250-midrun.codegen8"
 NEW = "sim.typed_pod_share"
+WHATIF = "openb1523-gpuspec25-loaded.whatif8"     # PR 49's, on this list
 #: constrained pods among the tiny deployment's 640
 TYPED = 138
 LANE_NUMBERS = {"near_ties_admitted", "placements_differ",
@@ -77,28 +78,31 @@ def test_the_cell_is_declared_with_its_files():
 def test_benchmark_json_only_gained_entries():
     with open(os.path.join(cells.ROOT, "BENCHMARK.json")) as f:
         bench = json.load(f)
-    assert bench["configs"][-1] == {
+    # in the place PR 45 gave them (PR 49 appended after them)
+    assert bench["configs"][6] == {
         "name": "openb1523-gpuspec25-loaded",
         "source": cells.load_cell(CELL).config["source"],
         "file": "chipbench/configs/openb1523-gpuspec25-loaded.json",
         "reduced": ["code_eval_max_steps"],
-        "why": bench["configs"][-1]["why"]}
-    assert bench["workloads"][-1] == {
+        "why": bench["configs"][6]["why"]}
+    assert bench["workloads"][8] == {
         "name": CELL, "config": "openb1523-gpuspec25-loaded",
         "traffic": "codegen8-gpuspec", "chips": 1,
-        "why": bench["workloads"][-1]["why"]}
-    for text in (bench["configs"][-1]["why"], bench["configs"][-1]["source"],
-                 bench["workloads"][-1]["why"]):
+        "why": bench["workloads"][8]["why"]}
+    for text in (bench["configs"][6]["why"], bench["configs"][6]["source"],
+                 bench["workloads"][8]["why"]):
         assert len(text) <= 200
     # a source of its own among the configurations
-    assert len({c["source"] for c in bench["configs"]}) == 7
-    assert len(bench["workloads"]) == 9 and len(bench["configs"]) == 7
+    assert len({c["source"] for c in bench["configs"]}) == 8
+    assert len(bench["workloads"]) == 10 and len(bench["configs"]) == 8
     assert sum(w["chips"] == 4 for w in bench["workloads"]) == 1
     # at the end as PR 45 left it; PR 46 appended the interpreter's
-    # slots a turn after, PR 47 its narrow turns' share
-    assert [m["name"] for m in bench["per_layer"][-3:]] == [
-        NEW, "vm.slots_per_turn", "vm.narrow_turn_share"]
-    new = bench["per_layer"][-3]
+    # slots a turn after, PR 47 its narrow turns' share, PR 49 the typed
+    # query pods' share
+    assert [m["name"] for m in bench["per_layer"][-4:]] == [
+        NEW, "vm.slots_per_turn", "vm.narrow_turn_share",
+        "serve.typed_pod_share"]
+    new = bench["per_layer"][-4]
     meta = json.load(open(os.path.join(cells.HERE, "metrics",
                                        NEW + ".json")))
     assert new == {**{k: meta[k] for k in (
@@ -112,8 +116,8 @@ def test_benchmark_json_only_gained_entries():
             continue
         lists = m.get("workloads", [])
         assert (CELL in lists) == (MIDRUN in lists), m["name"]
-        if CELL in lists:
-            assert lists[-1] == CELL
+        if CELL in lists:    # last of the cells there were at PR 45
+            assert [w for w in lists if w != WHATIF][-1] == CELL
 
 
 def test_the_new_reader_finds_nothing_in_a_program_without_its_field():

@@ -26,7 +26,9 @@ CODE = ["openb16.codegen8", "openb16.codegen8x4",
         # appended by PR 42, with its cell
         "openb16-cpu250-midrun.codegen8",
         "openb1523-gpuspec25-loaded.codegen8"]
-WHATIF = ["openb1523.whatif8", "openb1523-loaded.whatif8"]
+WHATIF = ["openb1523.whatif8", "openb1523-loaded.whatif8",
+          # appended by PR 49, with its cell
+          "openb1523-gpuspec25-loaded.whatif8"]
 TIER = "candidate tiers funsearch/backend.py"
 METRICS = {
     "tier.lower_ms_per_source": ("ms", "program_span", TIER, CODE),
@@ -234,13 +236,14 @@ def test_the_seven_are_declared_at_the_end_with_their_files():
     bench = json.load(open(os.path.join(cells.ROOT, "BENCHMARK.json")))
     # at the end as PR 40 left it; PR 42 appended its cell's two after,
     # PR 44 the interpreter's merged-read share, PR 45 the typed pods',
-    # PR 46 the interpreter's slots a turn, PR 47 its narrow turns' share
+    # PR 46 the interpreter's slots a turn, PR 47 its narrow turns' share,
+    # PR 49 the typed query pods' share
     seven = bench["per_layer"][41:41 + 7]
     assert [m["name"] for m in seven] == list(METRICS)
     assert [m["name"] for m in bench["per_layer"][41 + 7:]] == [
         "sim.fork_replay_us_per_event", "sim.fork_waiting_pods",
         "vm.merged_read_share", "sim.typed_pod_share", "vm.slots_per_turn",
-        "vm.narrow_turn_share"]
+        "vm.narrow_turn_share", "serve.typed_pod_share"]
     layers = {m["layer"] for m in bench["per_layer"][:41]}
     for m in seven:
         unit, source, layer, workloads = METRICS[m["name"]]

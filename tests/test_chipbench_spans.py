@@ -365,8 +365,9 @@ def test_every_span_metric_is_declared_with_its_files():
     # serve.retry_share (PR 37), tier.pooled_source_share (PR 39),
     # sim.fork_replay_us_per_event and sim.fork_waiting_pods (PR 42),
     # vm.merged_read_share (PR 44), sim.typed_pod_share (PR 45),
-    # vm.slots_per_turn (PR 46), vm.narrow_turn_share (PR 47)
-    assert len(SPAN_METRICS) == 33
+    # vm.slots_per_turn (PR 46), vm.narrow_turn_share (PR 47),
+    # serve.typed_pod_share (PR 49)
+    assert len(SPAN_METRICS) == 34
     for name in SPAN_METRICS:
         assert name in declared, name
         meta = json.load(open(os.path.join(cells.HERE, "metrics",

@@ -92,10 +92,15 @@ def test_benchmark_json_only_gained_entries():
         # PR 46's slots a turn of the interpreter's loop
         "vm.slots_per_turn",
         # PR 47's share of its turns that ran the narrow opcode table
-        "vm.narrow_turn_share"]
+        "vm.narrow_turn_share",
+        # PR 49's share of query pods that name their GPU models
+        "serve.typed_pod_share"]
     new = bench["per_layer"][at:at + 2]
+    # PR 49's forked cell reads both too
+    typed = "openb1523-gpuspec25-loaded.whatif8"
     for m in new:
-        assert m["workloads"] == [CELL] and m["layer"] == "serving serve/"
+        assert m["workloads"] == [CELL, typed]
+        assert m["layer"] == "serving serve/"
     assert [m["moves"] for m in new] == ["setup_s", "whatif_pods_per_s"]
     for m in bench["end_to_end"] + bench["per_layer"]:
         if m in new:
@@ -105,8 +110,8 @@ def test_benchmark_json_only_gained_entries():
         if CELL in lists:   # last of the cells there were at PR 37
             assert [w for w in lists if w not in (
                 "openb16-cpu250-midrun.codegen8",
-                "openb1523-gpuspec25-loaded.codegen8")][-1] == CELL
-    assert len(bench["workloads"]) == 9
+                "openb1523-gpuspec25-loaded.codegen8", typed)][-1] == CELL
+    assert len(bench["workloads"]) == 10
     assert sum(w["chips"] == 4 for w in bench["workloads"]) == 1
 
 

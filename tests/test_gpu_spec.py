@@ -433,9 +433,45 @@ def test_fused_and_portfolio_refuse_by_name(deployment):
     with pytest.raises(ValueError, match="gpu_spec: GPU-type constraints "
                        "are not supported in the fused kernel"):
         fused._build_plan(typed, SimConfig())
-    with pytest.raises(ValueError, match="gpu_spec: the portfolio serves "
-                       "queries whose pods carry no"):
+    # serving's two plain engines honour a query's gpu_spec since PR 49;
+    # the portfolio's slot table still does not, and says who does
+    with pytest.raises(ValueError, match="gpu_spec: the portfolio's "
+                       "slot-table executables .* honoured by ServeEngine"):
         PortfolioEngine([object()], typed)
+
+
+@pytest.mark.parametrize("kind", ["aot", "vm"])
+def test_the_two_plain_serve_engines_honour_a_typed_workload(deployment,
+                                                             kind):
+    """Since PR 49 (before it they answered a typed workload as if no pod
+    named a GPU, and said nothing): the engine's queries carry the leaf
+    where its workload is typed, and an engine on the untyped parse
+    refuses a pod that names models. The paths against the reference are
+    tests/test_vm_serve.py and tests/test_serve_fork.py."""
+    from fks_tpu.serve import (ChampionSpec, ServeEngine, ShapeEnvelope,
+                               VMServeEngine)
+    from fks_tpu.serve.batcher import build_query_workload
+
+    _, typed, untyped, _, _, _, codes, _ = deployment
+    cls = VMServeEngine if kind == "vm" else ServeEngine
+    env = ShapeEnvelope(max_pods=16, max_batch=2)
+    pod = {"cpu_milli": 1000, "memory_mib": 1024, "num_gpu": 1,
+           "gpu_milli": 500, "gpu_spec": "T4|P100"}
+    on = cls(ChampionSpec(code=codes[0]), typed, envelope=env,
+             engine="exact", prefilter_k=64)
+    off = cls(ChampionSpec(code=codes[0]), untyped, envelope=env,
+              engine="exact", prefilter_k=64)
+    assert on.typed and not off.typed
+    on.validate_query([pod])
+    with pytest.raises(ValueError, match="parsed without GPU models"):
+        off.validate_query([pod])
+    wl = build_query_workload(on.cluster, [pod], 16)
+    vocab = typed.cluster.gpu_models
+    assert wl.typed and int(wl.pods.gpu_spec[0]) == gpu_spec_bits(
+        "T4|P100", vocab) == (1 << vocab.index("T4")
+                              | 1 << vocab.index("P100"))
+    assert build_query_workload(off.cluster, [{"cpu_milli": 1}],
+                                16).pods.gpu_spec is None
 
 
 def test_trace_batching_carries_the_leaves(deployment):

@@ -503,3 +503,220 @@ def test_cli_serve_selftest_smoke(tmp_path):
                    "--save-artifact", str(tmp_path / "art")])
     assert rc == 0
     assert (tmp_path / "art" / "artifact.json").exists()
+
+
+# ------------------------------------------- gpu_spec in the query schema
+#
+# A query pod may carry ``gpu_spec``: GPU model names joined by ``|`` (a
+# list of strings is the same set). A pod with a non-empty one is placed
+# only on a node whose model is in the set; an engine whose workload is
+# not typed refuses it by name. The typed serve paths against the plain
+# reference are tests/test_vm_serve.py and tests/test_serve_fork.py.
+
+TYPED_NODES = [
+    {"node_id": "n0", "cpu_milli": 32000, "memory_mib": 65536,
+     "gpu_count": 0},
+    {"node_id": "n1", "cpu_milli": 32000, "memory_mib": 65536,
+     "gpu_count": 2, "model": "T4"},
+    {"node_id": "n2", "cpu_milli": 32000, "memory_mib": 65536,
+     "gpu_count": 2, "model": "G2"},
+    {"node_id": "n3", "cpu_milli": 32000, "memory_mib": 65536,
+     "gpu_count": 4, "model": "V100M16"},
+]
+
+
+def _typed_pod(i, spec=None, **kw):
+    pod = {"cpu_milli": 1000, "memory_mib": 1024, "num_gpu": 1,
+           "gpu_milli": 500, "creation_time": i, "duration_time": 50, **kw}
+    if spec is not None:
+        pod["gpu_spec"] = spec
+    return pod
+
+
+@pytest.fixture(scope="module")
+def typed_engine():
+    """A first-fit champion on four nodes of three GPU models, the
+    workload typed (``gpu_spec="honor"``)."""
+    from fks_tpu.data.build import make_workload
+
+    base = [{"pod_id": f"p{i}", **_typed_pod(i, s)}
+            for i, s in enumerate(["", "T4", "G2|T4"])]
+    wl = make_workload(TYPED_NODES, base, gpu_spec="honor")
+    assert wl.typed
+    champ = ChampionSpec(code=template.seed_policies()["first_fit"],
+                         score=0.4)
+    env = ShapeEnvelope(max_pods=8, min_pod_bucket=8, max_batch=2,
+                        max_gpu_milli=1000)
+    return ServeEngine(champ, wl, envelope=env, prefilter_k=0)
+
+
+@pytest.mark.parametrize("spec,word", [
+    (None, 0), ("", 0), ([], 0),                    # absent or empty: any
+    ("T4", 0b010), (["T4"], 0b010),
+    ("G2|T4", 0b011), (["T4", "G2"], 0b011),        # a set, in any order
+    ("G2|G2|T4", 0b011),                            # a repeat means nothing
+    ("A100", -2 ** 31),                             # no node's model
+    ("A100|V100M16", -2 ** 31 | 0b100),
+])
+def test_a_query_pods_gpu_spec_becomes_its_word(typed_engine, spec, word):
+    from fks_tpu.serve.batcher import build_query_workload, query_gpu_spec
+
+    pod = _typed_pod(0, spec)
+    assert isinstance(query_gpu_spec(pod), str)
+    typed_engine.validate_query([pod])
+    wl = build_query_workload(typed_engine.cluster, [pod, _typed_pod(1)], 8)
+    assert wl.typed and wl.cluster.gpu_models == ("G2", "T4", "V100M16")
+    got = np.asarray(wl.pods.gpu_spec)
+    assert got.dtype == np.int32 and got.tolist() == [word] + [0] * 7
+
+
+@pytest.mark.parametrize("spec", [7, 1.5, {"T4": 1}, ["T4", 3], [["T4"]],
+                                  True])
+def test_a_malformed_gpu_spec_is_a_4xx_before_any_device_work(
+        typed_engine, engine, spec):
+    for eng in (typed_engine, engine):      # typed or not: refused alike
+        with pytest.raises(ValueError, match="pod 1 gpu_spec .* neither a "
+                           "string of GPU model names"):
+            eng.validate_query([_typed_pod(0), _typed_pod(1, spec)])
+    with pytest.raises(ValueError, match="neither a string"):
+        ServeService(typed_engine).submit({"pods": [_typed_pod(0, spec)]})
+
+
+def test_an_untyped_engine_refuses_a_constrained_query_by_name(engine):
+    """A silent drop would be a wrong answer: the request is this
+    request's 4xx at submit and never reaches a batch; a pod with an
+    empty ``gpu_spec`` asks nothing and is served."""
+    assert not engine.typed and engine.cluster.gpu_model is None
+    with pytest.raises(ValueError, match="pod 1 names the GPU models it "
+                       "accepts .*'T4'.* parsed without GPU models"):
+        engine.answer_batch([[_query(0, 1)[0],
+                              {**_query(0, 1)[0], "gpu_spec": "T4"}]])
+    service = ServeService(engine, max_wait_s=0.002)
+    try:
+        before = service.summary(record=False)["batches"]
+        with pytest.raises(ValueError, match="parsed without GPU models"):
+            service.submit({"pods": [{**_query(0, 1)[0],
+                                      "gpu_spec": ["T4"]}]})
+        ans = service.submit({"pods": [{**_query(0, 1)[0],
+                                        "gpu_spec": ""}]}).result(60)
+        assert ans["placements"][0]["node"] >= 0
+        assert service.summary(record=False)["batches"] == before + 1
+    finally:
+        service.close()
+
+
+def test_a_typed_engine_places_a_pod_only_where_its_gpu_spec_allows(
+        typed_engine):
+    """first_fit takes the first node that fits: n1 (T4) for a GPU pod
+    that names nothing; the named model's node for one that does; no
+    node for a model the cluster does not have."""
+    pods = [_typed_pod(0), _typed_pod(1, "G2"), _typed_pod(2, "V100M16|G3"),
+            _typed_pod(3, ["G2", "T4"]), _typed_pod(4, "A100"),
+            _typed_pod(5, "T4", num_gpu=0, gpu_milli=0)]
+    a = typed_engine.answer_batch([pods])[0]
+    assert [r["node"] for r in a["placements"]] == [1, 2, 3, 1, -1, 1]
+    ref = typed_engine.reference_answer(pods)
+    assert ref["placements"] == a["placements"]
+    assert ref["score"] == a["score"]
+
+
+def test_pods_to_dicts_writes_the_gpu_spec_back(typed_engine):
+    from fks_tpu.data.entities import gpu_spec_bits, gpu_spec_names
+    from fks_tpu.serve.batcher import build_query_workload, pods_to_dicts
+
+    assert [p.get("gpu_spec") for p in typed_engine.base_pods] \
+        == [None, "T4", "G2|T4"]
+    sent = [_typed_pod(0), _typed_pod(1, "T4|G2|T4"), _typed_pod(2, "A100"),
+            _typed_pod(3, ["V100M16", "A100"])]
+    vocab = typed_engine.cluster.gpu_models
+    wl = build_query_workload(typed_engine.cluster, sent, 8)
+    back = pods_to_dicts(wl.pods, gpu_models=vocab)
+    assert [p.get("gpu_spec") for p in back] \
+        == [None, "G2|T4", "<no-node>", "V100M16|<no-node>"]
+    again = build_query_workload(typed_engine.cluster, back, 8)
+    assert np.array_equal(again.pods.gpu_spec, wl.pods.gpu_spec)
+    for tree_a, tree_b in zip(jax.tree_util.tree_leaves(again.pods),
+                              jax.tree_util.tree_leaves(wl.pods)):
+        assert np.array_equal(tree_a, tree_b)
+    for word in (0, 0b101, -2 ** 31, -2 ** 31 | 0b010):
+        assert gpu_spec_bits(gpu_spec_names(word, vocab), vocab) == word
+    # an untyped workload's pods have no such key, as before the field
+    assert all("gpu_spec" not in p for p in pods_to_dicts(
+        synthetic_workload(8, 16, seed=0).pods))
+
+
+def test_a_typed_artifact_round_trips_with_its_models(tmp_path,
+                                                      typed_engine):
+    q = [_typed_pod(0, "G2"), _typed_pod(1, "V100M16"), _typed_pod(2)]
+    before = typed_engine.answer_batch([q])[0]
+    d = str(tmp_path / "typed_artifact")
+    typed_engine.save(d)
+    loaded = ServeEngine.load(d)
+    assert loaded.typed and loaded.base_pods == typed_engine.base_pods
+    assert loaded.cluster.gpu_models == typed_engine.cluster.gpu_models
+    assert np.array_equal(loaded.cluster.gpu_model,
+                          typed_engine.cluster.gpu_model)
+    after = loaded.answer_batch([q])[0]
+    assert after["placements"] == before["placements"]
+    assert [r["node"] for r in after["placements"]] == [2, 3, 1]
+
+
+def test_queries_that_differ_only_in_gpu_spec_are_not_one_class():
+    from fks_tpu.serve.accounting import QueryFingerprinter
+
+    fp = QueryFingerprinter()
+    plain = [_typed_pod(0), _typed_pod(1)]
+    t4 = [_typed_pod(0, "T4"), _typed_pod(1)]
+    assert fp.classify(plain) != fp.classify(t4)
+    assert fp.classify(t4) != fp.classify([_typed_pod(0, "G2"),
+                                           _typed_pod(1)])
+    # a set: order, repeats and the list form do not matter; nor does
+    # the order of the pods
+    assert fp.classify([_typed_pod(0, "G2|T4"), _typed_pod(1)]) \
+        == fp.classify([_typed_pod(1), _typed_pod(0, ["T4", "G2", "T4"])])
+    # a pod that names nothing keeps the class it always had
+    assert fp.classify(plain) == fp.classify(
+        [_typed_pod(0, ""), _typed_pod(1, [])])
+
+
+def test_http_front_passes_the_gpu_spec_through(typed_engine, engine):
+    """The field rides the JSON body to the engine: a typed engine
+    honours it, an untyped one answers 400 with the reason."""
+    import json as _json
+    import threading
+    import urllib.error
+    import urllib.request
+
+    from fks_tpu.serve.service import make_http_server
+
+    def post(port, pods):
+        req = urllib.request.Request(
+            f"http://127.0.0.1:{port}/query",
+            data=_json.dumps({"pods": pods}).encode(),
+            headers={"Content-Type": "application/json"})
+        with urllib.request.urlopen(req, timeout=60) as r:
+            return _json.loads(r.read())
+
+    pods = [_typed_pod(0, "V100M16"), _typed_pod(1, ["G2"]), _typed_pod(2)]
+    for eng in (typed_engine, engine):
+        service = ServeService(eng, max_wait_s=0.002)
+        server = make_http_server(service, 0)
+        port = server.server_address[1]
+        threading.Thread(target=server.serve_forever, daemon=True).start()
+        try:
+            if eng.typed:
+                ans = post(port, pods)
+                assert [r["node"] for r in ans["placements"]] == [3, 2, 1]
+            else:
+                with pytest.raises(urllib.error.HTTPError) as ei:
+                    post(port, pods)
+                assert ei.value.code == 400
+                assert "parsed without GPU models" in _json.loads(
+                    ei.value.read())["error"]
+            with pytest.raises(urllib.error.HTTPError) as ei:
+                post(port, [_typed_pod(0, 7)])
+            assert ei.value.code == 400
+        finally:
+            server.shutdown()
+            server.server_close()
+            service.close()

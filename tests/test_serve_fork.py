@@ -377,3 +377,224 @@ def test_without_a_snapshot_an_answer_has_todays_keys():
     a = engine.answer_batch([pods_to_dicts(wl.pods, limit=4)])[0]
     assert sorted(a) == ["bucket_lanes", "bucket_pods", "events", "failed",
                          "placements", "scheduled", "score", "truncated"]
+
+
+# -------- the fork on a typed workload: a query pod carries its gpu_spec
+#
+# *A query pod with a non-empty ``gpu_spec`` may be placed only on a node
+# whose ``model`` is in the set; every other node is to it as a cordoned
+# node is. The residents keep the words the trace gave them; they stay
+# where the snapshot put them.* Both plain engines, forked, against
+# ``chipbench/reference/forked_query_gpuspec.py`` (a query pod's allowed
+# nodes from the string it was SENT).
+
+T_E0 = 200      # first_fit places the first 200 arrivals of the typed
+                # draw; the 100 after them fail 52 placements for a type
+
+
+@pytest.fixture(scope="module")
+def typed_fork(tmp_path_factory):
+    """(forked typed workload, reference cluster / pods / rows / allowed /
+    node models, the pod list's gpu_spec column as the reference reads
+    it)."""
+    from chipbench.reference import forked_query_gpuspec as fq
+    from chipbench.reference import plain_sim_gpuspec as gs
+    from chipbench.reference.data import _rows
+
+    d = str(tmp_path_factory.mktemp("typed_fork"))
+    wl = pt.write_typed_traces(d, SEED).parse_workload(
+        pt.NODE_FILE, pt.POD_FILE, gpu_spec="honor")
+    fwl = dataclasses.replace(wl, snapshot=_snapshot_of(
+        wl, zoo.first_fit(), T_E0, SimConfig(node_prefilter_k=RULE)))
+    cluster, pods = pt.reference_inputs(d)
+    nodes_csv = os.path.join(d, "csv", pt.NODE_FILE)
+    pods_csv = os.path.join(d, "csv", pt.POD_FILE)
+    snap = fwl.snapshot
+    rows = {int(i): (int(nd), int(g)) for i, nd, g in zip(
+        np.asarray(snap.pod), np.asarray(snap.node), np.asarray(snap.gpus))}
+    return (fwl, cluster, pods, rows, gs.load_allowed(nodes_csv, pods_csv),
+            fq.node_models(nodes_csv),
+            [r.get("gpu_spec") or "" for r in _rows(pods_csv)])
+
+
+def _typed_engine(cls, fwl, factor=2):
+    return cls(_champion(), fwl, engine="exact", max_steps_factor=factor,
+               prefilter_k=RULE,
+               envelope=ShapeEnvelope(max_batch=2, max_pods=256))
+
+
+def _sent(typed_fork, idx, with_spec=True):
+    """The query of the pod list's rows ``idx`` as the service is sent
+    it: six numbers a pod and, where the row names GPU models, their
+    string."""
+    _, _, pods, _, _, _, specs = typed_fork
+    out = []
+    for i in idx:
+        pod = {"cpu_milli": int(pods.cpu[i]), "memory_mib": int(pods.mem[i]),
+               "num_gpu": int(pods.num_gpu[i]),
+               "gpu_milli": int(pods.gpu_milli[i]),
+               "creation_time": int(pods.creation_time[i]),
+               "duration_time": int(pods.duration[i])}
+        if with_spec and specs[i]:
+            pod["gpu_spec"] = specs[i]
+        out.append(pod)
+    return out
+
+
+def _typed_reference(typed_fork, engine, idx, specs):
+    from chipbench.reference import forked_query_gpuspec as fq
+
+    _, cluster, pods, rows, allowed, models, _ = typed_fork
+    budget = max(64, engine.max_steps_factor
+                 * engine.envelope.pod_bucket_for(len(idx)))
+    taken, keyed, ok = fq.inputs(pods, rows, allowed, idx, specs, models)
+    return fq.simulate_query(
+        cluster, taken, keyed, ok,
+        policies.source_policy(_champion().code, dtype="float32"),
+        max_steps=T_E0 + budget, prefilter_k=RULE, retry="heap_array")
+
+
+@pytest.mark.parametrize("kind", ["vm", "aot"])
+def test_forked_typed_serving_answers_are_the_plain_references(typed_fork,
+                                                               kind):
+    """Two queries of one coalesced call through ``ServeService``, each
+    pod sent with its ``gpu_spec``: placements, GPU picks, the waiting
+    set, the counts and the fitness at the cut are the reference's, and
+    the spans say what the fork and the chunks carried."""
+    from fks_tpu.serve import ServeEngine
+
+    fwl, _, pods, rows, _, _, specs = typed_fork
+    obs.spans.LOG.clear()
+    engine = _typed_engine(VMServeEngine if kind == "vm" else ServeEngine,
+                           fwl)
+    assert engine.typed and engine.fork.typed_residents == 37
+    rest = [i for i in range(pods.p) if i not in rows]
+    picks = [rest[:100], rest[10:22]]
+    service = ServeService(engine, max_batch=2, max_wait_s=0.25)
+    try:
+        answers = _ask(service, [_sent(typed_fork, q) for q in picks], "t")
+    finally:
+        service.close()
+    for q, a in zip(picks, answers):
+        ref, waiting = _typed_reference(typed_fork, engine, q,
+                                        [specs[i] for i in q])
+        assert [r["node"] for r in a["placements"]] \
+            == ref.assigned_node[T_E0:].tolist()
+        assert [sum(1 << b for b in r["gpus"]) for r in a["placements"]] \
+            == ref.assigned_gpus[T_E0:].tolist()
+        assert (a["scheduled"], a["events"], a["failed"], a["truncated"]) \
+            == (ref.scheduled_pods, ref.events_processed, ref.failed,
+                ref.truncated)
+        assert a["waiting"] == waiting
+        assert (a["snapshots"], a["frag_events"], a["max_nodes"]) \
+            == (ref.num_snapshots, ref.num_frag_events, ref.max_nodes)
+        np.testing.assert_allclose(a["utilization"], ref.avg_util,
+                                   rtol=2e-6)
+        np.testing.assert_allclose(a["fragmentation"], ref.frag_mean,
+                                   rtol=2e-6, atol=1e-9)
+    # the regime: a type's scarcity fails placements in the larger query
+    assert answers[0]["frag_events"] == 52
+    log = obs.spans.LOG.snapshot()
+    fork_span = [r for r in log if r.name == "serve/fork_state"][-1]
+    assert (fork_span.fields["typed_residents"],
+            fork_span.fields["node_models"]) == (37, 6)
+    stacks = {r.fields["bucket"]: r.fields for r in log
+              if r.name == "serve/chunk/stack"}
+    assert (stacks[256]["pods"], stacks[256]["typed_pods"]) \
+        == (100, sum(1 for i in picks[0] if specs[i])) == (100, 32)
+    assert (stacks[16]["pods"], stacks[16]["typed_pods"]) \
+        == (12, sum(1 for i in picks[1] if specs[i]))
+
+
+def test_a_query_sent_without_its_gpu_spec_is_another_answer(typed_fork):
+    """The field-lost control in small: the same pods sent WITHOUT their
+    strings land elsewhere, and that answer is the reference's for pods
+    that name nothing."""
+    fwl, _, pods, rows, _, _, specs = typed_fork
+    engine = _typed_engine(VMServeEngine, fwl)
+    q = [i for i in range(pods.p) if i not in rows][:100]
+    with_spec, without = engine.answer_batch(
+        [_sent(typed_fork, q), _sent(typed_fork, q, with_spec=False)])
+    moved = sum(a["node"] != b["node"] for a, b in zip(
+        with_spec["placements"], without["placements"]))
+    assert moved == 78
+    ref, _ = _typed_reference(typed_fork, engine, q, [""] * len(q))
+    assert [r["node"] for r in without["placements"]] \
+        == ref.assigned_node[T_E0:].tolist()
+
+
+def test_the_typed_reference_without_constraints_is_the_untyped_one(
+        typed_fork):
+    """``forked_query_gpuspec`` with every ``gpu_spec`` empty, residents'
+    included, equals ``forked_query.simulate_query`` field for field."""
+    from chipbench.reference import forked_query_gpuspec as fq
+
+    _, cluster, pods, rows, allowed, models, _ = typed_fork
+    q = [i for i in range(pods.p) if i not in rows][:100]
+    policy = policies.source_policy(_champion().code, dtype="float32")
+    kw = dict(max_steps=T_E0 + 512, prefilter_k=RULE, retry="heap_array")
+    taken, keyed = forked_query.inputs(pods, rows, q)
+    plain, plain_waiting = forked_query.simulate_query(
+        cluster, taken, keyed, policy, **kw)
+    taken2, keyed2, ok = fq.inputs(pods, rows, np.ones_like(allowed), q,
+                                   [""] * len(q), models)
+    assert ok.all() and keyed2 == keyed
+    typed, typed_waiting = fq.simulate_query(cluster, taken2, keyed2, ok,
+                                             policy, **kw)
+    assert typed_waiting == plain_waiting
+    for f in dataclasses.fields(plain):
+        a, b = getattr(plain, f.name), getattr(typed, f.name)
+        assert np.array_equal(a, b), f.name
+    # and the strings decide: list or string, order and repeats alike
+    assert np.array_equal(fq.allowed_row("T4|G2|T4", models),
+                          fq.allowed_row(["G2", "T4"], models))
+    assert fq.allowed_row("", models).all() \
+        and fq.allowed_row(None, models).all()
+    assert not fq.allowed_row("A100", models).any()
+    # the snapshot is the typed workload's run and breaks no constraint
+    assert fq.validate_snapshot(cluster, pods, rows, allowed,
+                                "heap_array").scheduled_pods == T_E0
+
+
+def test_the_typed_fork_holds_the_residents_words_and_counts_them(
+        typed_fork):
+    fwl = typed_fork[0]
+    fork = QueryFork(fwl)
+    untyped = QueryFork(dataclasses.replace(
+        fwl, cluster=dataclasses.replace(fwl.cluster, gpu_model=None,
+                                         gpu_models=()),
+        pods=dataclasses.replace(fwl.pods, gpu_spec=None)))
+    order = np.asarray(fwl.snapshot.pod)
+    assert np.array_equal(fork.spec, np.asarray(fwl.pods.gpu_spec)[order])
+    assert fork.spec.dtype == np.int32 and untyped.spec is None
+    assert (fork.typed_residents, untyped.typed_residents) == (37, 0)
+    # the new column is counted: 4 bytes a resident a lane
+    assert fork.lane_bytes - untyped.lane_bytes == 4 * T_E0
+    wl = build_query_workload(fwl.cluster, [{"cpu_milli": 1, "gpu_spec": "T4",
+                                             "creation_time": 10 ** 6}],
+                              16, fork)
+    assert wl.typed and wl.pods.gpu_spec.shape == (T_E0 + 16,)
+    assert np.array_equal(wl.pods.gpu_spec[:T_E0], fork.spec)
+    assert wl.pods.gpu_spec[T_E0] == 1 << fwl.cluster.gpu_models.index("T4")
+    assert not wl.pods.gpu_spec[T_E0 + 1:].any()
+
+
+def test_a_snapshot_that_breaks_a_constraint_is_no_fork(typed_fork):
+    """The residents stay where the snapshot put them only if they may be
+    there: ``QueryFork`` goes on refusing a row the rule forbids."""
+    fwl = typed_fork[0]
+    snap = fwl.snapshot
+    spec = np.asarray(fwl.pods.gpu_spec)[np.asarray(snap.pod)]
+    k = int(np.flatnonzero(spec)[0])           # a constrained resident
+    model = np.asarray(fwl.cluster.gpu_model)
+    allowed = (spec[k] >> np.maximum(model, 0)) & 1
+    wrong = int(np.flatnonzero((model >= 0) & (allowed == 0))[0])
+    node = np.asarray(snap.node).copy()
+    node[k] = wrong
+    bad = dataclasses.replace(fwl, snapshot=dataclasses.replace(
+        snap, node=node))
+    with pytest.raises(ValueError, match="whose GPU model its gpu_spec "
+                                         "does not name"):
+        QueryFork(bad)
+    with pytest.raises(ValueError, match="does not name"):
+        _typed_engine(VMServeEngine, bad)

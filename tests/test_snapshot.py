@@ -358,3 +358,93 @@ def test_what_the_configuration_says_of_the_snapshot(real):
     assert int((gpu & (pods.gpu_milli[idx] < 1000)).sum()) == 2233
     assert (pods.creation_time[idx] + pods.duration[idx]).min() > E0
     assert ref_data.load_pods(real[0]["trace"]).p == 6695
+
+
+# ------------- (6) the typed cluster's snapshot for what-if serving (PR 49)
+
+TYPED_CONFIG = json.load(open(os.path.join(
+    cells.HERE, "configs", "openb1523-gpuspec25-loaded-snapshot.json")))
+TYPED_SNAPSHOT = "openb_snapshot_gpuspec25_inflated080_firstfit_e5888.csv.gz"
+COMMITTED_SHA256 = {
+    "openb_snapshot_inflated080_e5888.csv.gz":
+        "093c73f70d4260889cc95c434ca77a494294e7405ad1969b9d19e552ff8c4f32",
+    "openb_snapshot_cpu250_firstfit_e12288.csv.gz":
+        "bb59eba3054930ec773f3bbc4eaf1068fdde8c9757042319146777723fa7b07c",
+    "openb_snapshot_gpuspec25_inflated080_e4864.csv.gz":
+        "38f35dcb3daef1d1c55b1c1c9ee041b15c1822654bfc5df95525e89ead10205b",
+    TYPED_SNAPSHOT:
+        "46626075d2ae4b66319d3d5816b2d2e6cd4b5683d6fdec770419f4cdc257c362",
+}
+
+
+@pytest.mark.parametrize("name", sorted(COMMITTED_SHA256))
+def test_every_committed_snapshot_keeps_its_sha256(name):
+    """The three that stood before PR 49 are the bytes they were, and the
+    fourth is pinned with them; ``cli.COMMITTED_SNAPSHOTS`` names no
+    other."""
+    from fks_tpu import cli
+
+    assert sorted(cli.COMMITTED_SNAPSHOTS) == sorted(COMMITTED_SHA256)
+    with open(os.path.join(CSV, name), "rb") as f:
+        assert hashlib.sha256(f.read()).hexdigest() == COMMITTED_SHA256[name]
+
+
+def test_the_typed_serving_snapshot_is_what_the_command_writes(tmp_path):
+    from fks_tpu import cli
+
+    assert cli.COMMITTED_SNAPSHOTS[TYPED_SNAPSHOT] == (
+        "openb_node_list_all_node.csv",
+        "openb_pod_list_gpuspec25_inflated080.csv", "first_fit", 5888, 64,
+        "honor")
+    path, snap = cli.write_snapshot(tmp_path / "snap.csv.gz",
+                                    name=TYPED_SNAPSHOT)
+    with open(path, "rb") as a, open(os.path.join(CSV, TYPED_SNAPSHOT),
+                                     "rb") as b:
+        got, want = a.read(), b.read()
+    assert got == want
+    assert hashlib.sha256(want).hexdigest() \
+        == TYPED_CONFIG["snapshot"]["sha256"] \
+        == COMMITTED_SHA256[TYPED_SNAPSHOT]
+    assert TYPED_CONFIG["snapshot"]["file"].endswith(TYPED_SNAPSHOT)
+    node = np.asarray(snap.node)
+    assert (snap.e0, snap.rule, len(node), int((node < 0).sum()),
+            len(np.unique(node))) == (5888, "", 5888, 0, 1245)
+    assert snap.e0 == TYPED_CONFIG["start_event"] == E0
+    assert TYPED_CONFIG["shape"]["nodes_loaded"] == 1245
+    text = gzip.decompress(want).decode().splitlines()
+    assert text[0] == "name,node_sn,gpus" and len(text) == 1 + 5888
+
+
+def test_the_references_own_first_fit_prefix_gives_the_typed_rows():
+    """first_fit's score has no arithmetic in it, so the file hangs on no
+    precision: the plain reference's own first_fit under the type rule
+    places the first 5,888 arrivals on the same nodes and GPUs, refuses
+    none of them, and the configuration's counts are the reference's."""
+    from chipbench.reference import forked_query_gpuspec as fq
+    from chipbench.reference import plain_sim_gpuspec as gs
+
+    files = cells.verify_files(TYPED_CONFIG)
+    cluster, pods = common.reference_inputs(TYPED_CONFIG, files)
+    allowed = gs.load_allowed(files["cluster"], files["trace"])
+    rows = plain_sim_loaded.load_rows(files["snapshot"], files["cluster"],
+                                      files["trace"])
+    ref = gs.simulate(cluster, pods, allowed, policies.first_fit,
+                      retry="heap_array", max_steps=5888, prefilter_k=RULE)
+    assert (ref.scheduled_pods, ref.num_frag_events,
+            ref.num_snapshots) == (5888, 0, 17)
+    mine = {i: (int(ref.assigned_node[i]), int(ref.assigned_gpus[i]))
+            for i in np.flatnonzero(ref.assigned_node >= 0)}
+    assert mine == rows and sorted(rows) == list(range(5888))
+    typed = ~allowed.all(axis=1)
+    assert (int(typed.sum()), int(typed[:5888].sum()),
+            int(typed[5888:].sum())) \
+        == (TYPED_CONFIG["typed_pods"], TYPED_CONFIG["typed_residents"],
+            TYPED_CONFIG["typed_backlog"]) == (1375, 1216, 159)
+    idx = np.arange(5888)
+    asked = (pods.num_gpu[idx] * pods.gpu_milli[idx]).sum()
+    assert round(100 * asked / cluster.gpu_milli_total.sum(), 1) == 69.9
+    assert fq.validate_snapshot(cluster, pods, rows, allowed,
+                                "heap_array").max_nodes == 1245
+    assert fq.node_models(files["cluster"]).count("") == 310
+    assert sorted(set(fq.node_models(files["cluster"])) - {""}) \
+        == TYPED_CONFIG["node_models"]

@@ -69,7 +69,9 @@ def test_committed_snapshot_is_what_the_command_writes(tmp_path):
     assert text[-1] == ",,,12288,earliest_delete"
     assert set(cli.COMMITTED_SNAPSHOTS) == {
         SNAPSHOT_FILE + ".gz", "openb_snapshot_inflated080_e5888.csv.gz",
-        "openb_snapshot_gpuspec25_inflated080_e4864.csv.gz"}
+        "openb_snapshot_gpuspec25_inflated080_e4864.csv.gz",
+        # PR 49's, the typed cluster's for what-if serving
+        "openb_snapshot_gpuspec25_inflated080_firstfit_e5888.csv.gz"}
 
 
 def test_the_old_file_reads_as_it_did_and_writes_its_own_bytes():

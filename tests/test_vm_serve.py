@@ -650,3 +650,156 @@ def test_concurrent_swap_never_tears_a_batch(wl, envelope):
     assert not errors, errors
     assert not torn, f"{len(torn)} torn batches, first: {torn[:1]}"
     assert watcher.backend_compile_count == 0  # swaps never rebuild
+
+
+# ------------------------------------------ a query pod carries gpu_spec
+#
+# Unforked, on a tiny typed deployment (320 nodes of six GPU models,
+# ``pressure_traces.write_typed_traces``): both engines against the plain
+# reference ``plain_sim_gpuspec.simulate``, a typed engine whose queries
+# name nothing against the untyped engine bit for bit, and an untyped
+# engine's programs as they always were. The forked path is
+# tests/test_serve_fork.py, the schema tests/test_serve.py.
+
+@pytest.fixture(scope="module")
+def typed_deployment(tmp_path_factory):
+    from chipbench.reference import forked_query_gpuspec as fq
+    from chipbench.reference.data import _rows
+    from tests import pressure_traces as pt
+
+    d = str(tmp_path_factory.mktemp("typed_serve"))
+    parser = pt.write_typed_traces(d, 5)
+    typed = parser.parse_workload(pt.NODE_FILE, pt.POD_FILE,
+                                  gpu_spec="honor")
+    untyped = parser.parse_workload(pt.NODE_FILE, pt.POD_FILE)
+    cluster, pods = pt.reference_inputs(d)
+    models = fq.node_models(os.path.join(d, "csv", pt.NODE_FILE))
+    specs = [r.get("gpu_spec") or ""
+             for r in _rows(os.path.join(d, "csv", pt.POD_FILE))]
+    return typed, untyped, cluster, pods, models, specs
+
+
+def _ledger_champion():
+    from fks_tpu.data import default_traces_dir
+    from fks_tpu.serve import load_champion
+    from tests import pressure_traces as pt
+
+    root = default_traces_dir().parent.parent / "policies" / "discovered"
+    return load_champion(str(root / pt.CHAMPIONS[0]))
+
+
+def _typed_serve_engine(cls, wl):
+    return cls(_ledger_champion(), wl, engine="exact", prefilter_k=64,
+               max_steps_factor=8,
+               envelope=ShapeEnvelope(max_batch=2, max_pods=320,
+                                      min_pod_bucket=64))
+
+
+def _rows_as_sent(pods, specs, idx, with_spec=True):
+    out = []
+    for i in idx:
+        pod = {"cpu_milli": int(pods.cpu[i]), "memory_mib": int(pods.mem[i]),
+               "num_gpu": int(pods.num_gpu[i]),
+               "gpu_milli": int(pods.gpu_milli[i]),
+               "creation_time": int(pods.creation_time[i]),
+               "duration_time": int(pods.duration[i])}
+        if with_spec and specs[i]:
+            pod["gpu_spec"] = specs[i]
+        out.append(pod)
+    return out
+
+
+@pytest.mark.parametrize("kind", ["vm", "aot"])
+def test_typed_serving_unforked_is_the_plain_references(typed_deployment,
+                                                        kind):
+    """The whole 310-pod list as ONE query (72 pods name their GPUs) and
+    a window of it, each pod sent with its ``gpu_spec``: every placement,
+    GPU pick, count and the fitness are ``plain_sim_gpuspec.simulate``'s
+    on the allowed rows made from the strings that were sent."""
+    from chipbench.reference import forked_query_gpuspec as fq
+    from chipbench.reference import plain_sim_gpuspec as gs
+    from chipbench.reference import policies
+
+    typed, _, cluster, pods, models, specs = typed_deployment
+    engine = _typed_serve_engine(
+        VMServeEngine if kind == "vm" else ServeEngine, typed)
+    assert engine.typed and engine.fork is None
+    picks = [list(range(pods.p)), list(range(40, 100))]
+    answers = engine.answer_batch([_rows_as_sent(pods, specs, q)
+                                   for q in picks])
+    policy = policies.source_policy(_ledger_champion().code, dtype="float32")
+    for q, a in zip(picks, answers):
+        allowed = np.array([fq.allowed_row(specs[i], models) for i in q])
+        bucket = engine.envelope.pod_bucket_for(len(q))
+        ref = gs.simulate(cluster, pods.take(q, query=True), allowed,
+                          policy, retry="heap_array", prefilter_k=64,
+                          max_steps=max(64, 8 * bucket))
+        assert [r["node"] for r in a["placements"]] \
+            == ref.assigned_node.tolist()
+        assert [sum(1 << b for b in r["gpus"]) for r in a["placements"]] \
+            == ref.assigned_gpus.tolist()
+        assert (a["scheduled"], a["events"], a["failed"], a["truncated"]) \
+            == (ref.scheduled_pods, ref.events_processed, ref.failed,
+                ref.truncated)
+        np.testing.assert_allclose(a["score"], ref.policy_score, rtol=2e-6)
+        placed = ref.assigned_node >= 0
+        assert allowed[np.flatnonzero(placed),
+                       ref.assigned_node[placed]].all()
+    # the whole list is under a type's pressure and still finishes
+    assert answers[0]["score"] > 0 and not answers[0]["truncated"]
+    assert answers[0]["events"] > 2 * pods.p
+
+
+def test_a_typed_engine_asked_nothing_answers_as_the_untyped_engine(
+        typed_deployment):
+    """Every ``gpu_spec`` empty: the typed engine's answer (its program
+    carries the type term, every word 0) equals the untyped engine's,
+    key for key and bit for bit."""
+    typed, untyped, _, pods, _, specs = typed_deployment
+    q = _rows_as_sent(pods, specs, range(120), with_spec=False)
+    a = _typed_serve_engine(VMServeEngine, typed).answer_batch([q, q[:50]])
+    b = _typed_serve_engine(VMServeEngine, untyped).answer_batch(
+        [q, q[:50]])
+    assert a == b
+    # and with the strings the answer is another one
+    c = _typed_serve_engine(VMServeEngine, typed).answer_batch(
+        [_rows_as_sent(pods, specs, range(120))])
+    assert c[0]["placements"] != a[0]["placements"]
+
+
+def test_an_untyped_engines_programs_are_what_they_were(typed_deployment,
+                                                        wl, envelope):
+    """Data decides, no switch: an engine whose workload is not typed
+    (parsed without the choice, or holding only half of the leaves) builds
+    queries without the ``gpu_spec`` leaf, lowers each bucket to the text
+    it lowered to before the field existed, and the typed engine's text
+    differs from it by the type term alone. (The optimized modules of the
+    benchmark's own buckets against the parent commit:
+    ``tools/describe_compile.py whatif --hlo``, PERF.md section 6, PR 49;
+    ``serve_bucket/exact_l1_p16`` in the lint pins is an untyped one.)"""
+    import dataclasses
+
+    typed, untyped, _, _, _, _ = typed_deployment
+    half = dataclasses.replace(untyped, cluster=typed.cluster)
+    assert half.cluster.gpu_model is not None and not half.typed
+
+    def lowered(workload):
+        eng = _typed_serve_engine(VMServeEngine, workload)
+        example = (eng._prog_dev,) + eng._example_batch(2, 64)
+        leaves = jax.tree_util.tree_leaves(example[1])
+        text = jax.jit(eng._make_serve_fn(64)).lower(*example).as_text()
+        return eng, len(leaves), text
+
+    e_untyped, n_untyped, t_untyped = lowered(untyped)
+    e_half, n_half, t_half = lowered(half)
+    e_typed, n_typed, t_typed = lowered(typed)
+    assert (e_untyped.typed, e_half.typed, e_typed.typed) \
+        == (False, False, True)
+    assert e_half.cluster.gpu_model is None
+    assert n_untyped == n_half == 8 and n_typed == 9
+    assert t_untyped == t_half != t_typed
+    assert len(t_typed.splitlines()) > len(t_untyped.splitlines())
+    # the module's older fixtures are untyped engines too
+    eng = VMServeEngine(_champ(BETTER_LOGIC), wl, envelope=envelope,
+                        engine="flat")
+    assert not eng.typed and "gpu_spec" not in eng.base_pods[0]
