@@ -735,6 +735,7 @@ class CodeEvaluator:
                             source=i, pid=low.pid,
                             pooled=int(low.sent is not None),
                             trace_ms=(low.t_traced - low.t0) * 1e3,
+                            eqns=low.eqns,
                             ops_lowered=low.ops_lowered,
                             ops_kept=len(low.kept[0]) if low.kept else 0)
                 packed = 0

@@ -1,8 +1,9 @@
 """A generation's sources lowered side by side, on the host's idle cores.
 
 Lowering a candidate (``vm.lower_ops``: one ``jax.make_jaxpr`` trace of
-its body, some 100 ms of pure Python that holds the GIL) is nine tenths of
-``backend._evaluate``'s transpile stage, and a generation's sources are
+its body, 30-45 ms of pure Python that holds the GIL since PR 50, some 100
+when the pool was built) is the larger half of ``backend._evaluate``'s
+transpile stage, and a generation's sources are
 independent. Threads cannot split that work, processes can: what a source
 leaves behind is plain Python (``simplify_ops``' op tuples, the pool's
 constants, a register number) and pickles in microseconds. So the batched
@@ -72,6 +73,7 @@ class Lowered(NamedTuple):
     t0: float = 0.0  # `lower_source` entered
     t_traced: float = 0.0  # ``vm.lower_ops`` was through (or raised)
     t1: float = 0.0  # `lower_source` returned
+    eqns: int = 0  # equations of the traced jaxpr (vm.eqns_traced)
     # the parent's own stamps around a worker's task (before the send,
     # after the receive); None for a source lowered in process
     sent: Optional[float] = None
@@ -84,7 +86,7 @@ def lower_source(code: str, n: int, g: int) -> Lowered:
     raised, rebuilt from its message so that it always pickles:
     ``VMUnsupported`` and ``TranspileError`` keep their class, anything
     else (candidate code is untrusted) becomes a ``RuntimeError``."""
-    runs0 = transpiler.body_runs()
+    runs0, eqns0 = transpiler.body_runs(), vm.eqns_traced()
     kept, lowered, error, t_traced = None, 0, None, None
     t0 = time.perf_counter()
     try:
@@ -97,7 +99,8 @@ def lower_source(code: str, n: int, g: int) -> Lowered:
         error = RuntimeError(str(e))
     t1 = time.perf_counter()
     return Lowered(kept, lowered, transpiler.body_runs() - runs0, error,
-                   os.getpid(), t0, t1 if t_traced is None else t_traced, t1)
+                   os.getpid(), t0, t1 if t_traced is None else t_traced, t1,
+                   vm.eqns_traced() - eqns0)
 
 
 def clock_misfit(lowered: Sequence[Lowered]) -> bool:

@@ -12,7 +12,7 @@ speed, with no Python in the hot path.
 Lowering rules (SURVEY.md §7 "dynamic policy code on device"):
 - every value is (broadcastable to) an array over the node axis N;
 - ``if``/``elif``/``else`` -> both branches execute, assignments blend under
-  the branch predicate (``jnp.where``) — classic predication;
+  the branch predicate (a select) — classic predication;
 - ``return`` -> a per-lane ``returned`` mask + first-return-wins value blend;
 - ``for gpu in node.gpus`` -> a static unrolled loop over the padded GPU
   axis G, body masked by ``gpu_mask[:, g]`` (real-GPU lanes only);
@@ -37,7 +37,9 @@ import math
 import threading
 from typing import Any, Dict, Optional
 
-import jax.numpy as jnp
+import jax
+import numpy as np
+from jax import lax
 
 from fks_tpu.funsearch import sandbox
 from fks_tpu.sim.types import NodeView, PodView, PolicyFn
@@ -47,10 +49,302 @@ class TranspileError(ValueError):
     """Candidate uses syntax outside the JAX-lowerable subset."""
 
 
+# ---------------------------------------------------------- staged arithmetic
+#
+# Every value `_Interp` computes is a Python scalar or an array (under a
+# trace, a tracer) of rank 0, [N] or [N, G]. The helpers below stage the `lax`
+# primitives that `jax.numpy` binds for such operands, with `jax.numpy`'s own
+# promotion (weak Python scalars against bool / int / float arrays:
+# `jax.dtypes.result_type`, memoised per combination of kinds) and nothing
+# between them and the primitive. Under ``jax.make_jaxpr`` a `jax.numpy` call
+# is a `pjit` cache miss that traces a jaxpr of its own in Python (414 of them,
+# 150 of 244 ms of a ledger champion's profiled lowering: PERF.md section 6,
+# PR 50); a `lax` call is one bind. A policy's jaxpr therefore holds
+# `jax.numpy`'s primitives in `jax.numpy`'s order and no nested call; the one
+# equation that went is the conversion of a scalar literal that sat inside a
+# nested ``_where``: a Python scalar is converted here (`_convert`), as the
+# trace folded it everywhere else.
+
+_BOOL, _I32 = np.dtype(np.bool_), np.dtype(np.int32)
+_PY = (bool, int, float)
+
+
+def _float():
+    """The ambient float: f64 under x64 (tests, golden parity), else f32."""
+    return jax.dtypes.canonicalize_dtype(np.float64)
+
+
+def _default_int():
+    return jax.dtypes.canonicalize_dtype(np.int64)
+
+
+def _aval(v):
+    try:
+        return v.aval
+    except AttributeError:  # a numpy scalar: `_convert`'s, `_const`'s
+        return jax.typeof(v)
+
+
+def _kind(v):
+    """What promotion sees of ``v``: the type of a Python scalar, else
+    ``(dtype, weak_type)``."""
+    if type(v) in _PY:
+        return type(v)
+    a = _aval(v)
+    return a.dtype, a.weak_type
+
+
+def _dtype(v):
+    return _join(v)[0] if type(v) in _PY else _aval(v).dtype
+
+
+def _shape(v):
+    return () if type(v) in _PY else _aval(v).shape
+
+
+def _is_int(v) -> bool:
+    return np.issubdtype(_dtype(v), np.integer)
+
+
+def _is_float(v) -> bool:
+    return np.issubdtype(_dtype(v), np.floating)
+
+
+_JOINS: Dict[tuple, tuple] = {}
+
+
+def _join(*vals):
+    """``(dtype, weak_type)`` that ``vals`` are promoted to."""
+    key = (_float(), *map(_kind, vals))
+    out = _JOINS.get(key)
+    if out is None:
+        dtype, weak = jax.dtypes.result_type(*vals, return_weak_type_flag=True)
+        out = _JOINS[key] = (np.dtype(dtype), bool(weak))
+    return out
+
+
+def _convert(v, dtype, weak=False):
+    """``v`` as ``dtype``. No equation for an array that is one already,
+    and none for a Python scalar: it is converted here, as the trace
+    would fold the equation (a weak type is a Python scalar's own, and a
+    Python float that stays one stays unrounded)."""
+    if type(v) in _PY:
+        if not weak:
+            return np.asarray(v).astype(dtype)
+        return v if _join(v)[0] == dtype else dtype.type(v).item()
+    a = _aval(v)
+    if a.dtype == dtype and a.weak_type == weak and isinstance(v, jax.Array):
+        return v
+    return lax.convert_element_type_p.bind(
+        v, new_dtype=dtype, weak_type=weak, sharding=None)
+
+
+def _astype(v, dtype):
+    return _convert(v, np.dtype(dtype), False)
+
+
+def _promote(*vals):
+    dtype, weak = _join(*vals)
+    return [_convert(v, dtype, weak) for v in vals]
+
+
+def _promote_numeric(*vals):
+    """`_promote`, bools as ints."""
+    dtype, weak = _join(*vals)
+    if dtype == _BOOL:
+        dtype = _default_int()
+    return [_convert(v, dtype, weak) for v in vals]
+
+
+def _binary(prim, on_bool=None):
+    """``prim`` on promoted operands; ``+`` and ``*`` are OR and AND
+    (``on_bool``) where both are bools, as `jax.numpy` has them."""
+    def go(a, b):
+        a, b = _promote(a, b)
+        if on_bool is not None and _dtype(a) == _BOOL:
+            return on_bool(a, b)
+        return prim(a, b)
+    return go
+
+
+_plus = _binary(lax.add, lax.bitwise_or)
+_times = _binary(lax.mul, lax.bitwise_and)
+_sub, _min, _max = _binary(lax.sub), _binary(lax.min), _binary(lax.max)
+_eq, _ne = _binary(lax.eq), _binary(lax.ne)
+_lt, _le = _binary(lax.lt), _binary(lax.le)
+_gt, _ge = _binary(lax.gt), _binary(lax.ge)
+_and, _or = _binary(lax.bitwise_and), _binary(lax.bitwise_or)
+
+
+def _not(v):
+    return (not v) if type(v) is bool else lax.bitwise_not(v)
+
+
+def _abs(v):
+    return v if _dtype(v) == _BOOL else lax.abs(v)
+
+
+def _div(a, b):
+    """True division: ints divide as the float of their width."""
+    dtype, weak = _join(a, b)
+    if not np.issubdtype(dtype, np.inexact):
+        dtype = jax.dtypes.canonicalize_dtype(
+            np.float64 if dtype.itemsize == 8 else np.float32)
+    return lax.div(_convert(a, dtype, weak), _convert(b, dtype, weak))
+
+
+def _round(v):
+    """Half to even, as ``jax.numpy.round``; an int is whole already."""
+    return v if _is_int(v) else lax.round(
+        v, lax.RoundingMethod.TO_NEAREST_EVEN)
+
+
+def _const(like, value):
+    """``value`` as a scalar of ``like``'s dtype."""
+    return np.array(value, _dtype(like))
+
+
+def _full(shape, v, dtype):
+    return lax.broadcast_in_dim(_astype(v, dtype), shape, ())
+
+
+def _broadcast(v, shape):
+    """A scalar over ``shape``; an array of that shape as it is."""
+    return v if _shape(v) == shape else lax.broadcast_in_dim(v, shape, ())
+
+
+def _where(mask, new, old):
+    """``where(mask, new, old)`` on a bool ``mask``; a rank-0 mask selects
+    without being broadcast."""
+    new, old = _promote(new, old)
+    shape = max(_shape(new), _shape(old), key=len)
+    if _shape(mask):
+        shape = max(shape, _shape(mask), key=len)
+        mask = _broadcast(mask, shape)
+    return lax.select(mask, _broadcast(new, shape), _broadcast(old, shape))
+
+
+def _trunc(a):
+    """A float rounded toward zero."""
+    return _where(lax.lt(a, _const(a, 0)), lax.ceil(a), lax.floor(a))
+
+
+def _floor_divide(a, b):
+    """``a // b`` with Python's sign, as ``jax.numpy.floor_divide``."""
+    a, b = _promote_numeric(a, b)
+    if _is_int(a):
+        quotient = lax.div(a, b)
+        select = _and(_ne(lax.sign(a), lax.sign(b)), _ne(lax.rem(a, b), 0))
+        return _where(select, _sub(quotient, 1), quotient)
+    mod = lax.rem(a, b)
+    div = lax.div(lax.sub(a, mod), b)
+    ind = lax.bitwise_and(_ne(mod, 0), _ne(lax.sign(b), lax.sign(mod)))
+    return lax.round(lax.select(ind, _sub(div, _const(div, 1)), div))
+
+
+def _mod(a, b):
+    """``a % b`` with the sign of ``b``, as ``jax.numpy.remainder``."""
+    a, b = _promote_numeric(a, b)
+    zero = _const(a, 0)
+    if _is_int(b):
+        b = _where(_eq(b, 0), _full(_shape(b), 1, _dtype(b)), b)
+    trunc_mod = lax.rem(a, b)
+    not_zero = lax.ne(trunc_mod, zero)
+    do_plus = lax.bitwise_and(
+        lax.ne(lax.lt(trunc_mod, zero), lax.lt(b, zero)), not_zero)
+    return lax.select(do_plus, lax.add(trunc_mod, b), trunc_mod)
+
+
+def _power(a, b):
+    """``a ** b`` as ``jax.numpy.power``: a static int exponent is
+    `lax.integer_pow`, ints to an int power square and multiply, a float to
+    an int power is `lax.pow` on the operands as they are."""
+    if type(b) is int:
+        return lax.integer_pow(*_promote_numeric(a), b)
+    pa, pb = _promote_numeric(a, b)
+    if _is_int(pa):
+        zero, one = _const(pb, 0), _const(pb, 1)
+        acc = _where(lax.bitwise_and(lax.eq(pa, zero), lax.ne(pb, zero)),
+                     zero, one)
+        for _ in range(6):  # more bits would overflow for any base > 1
+            acc = _where(lax.bitwise_and(pb, one), lax.mul(acc, pa), acc)
+            pa = lax.mul(pa, pa)
+            pb = lax.shift_right_logical(pb, one)
+        return acc
+    if _is_float(a) and _is_int(b):
+        return lax.pow(a, b)
+    return lax.pow(pa, pb)
+
+
+def _col(grid, g: int):
+    """Column ``g`` of an [N, G] array (the primitives bound as they are:
+    `lax.slice` and `lax.squeeze` canonicalise what is canonical here,
+    at two thirds of the pair's cost, 96 times a champion)."""
+    n = _shape(grid)[0]
+    return lax.squeeze_p.bind(
+        lax.slice_p.bind(grid, start_indices=(0, g),
+                         limit_indices=(n, g + 1), strides=None),
+        dimensions=(1,))
+
+
+def _stack(cols):
+    """[N] arrays side by side: [N, len(cols)]."""
+    cols = _promote(*(lax.expand_dims(c, (1,)) for c in cols))
+    while len(cols) > 1:  # jax.numpy concatenates sixteen at a time
+        cols = [lax.concatenate(cols[i:i + 16], 1)
+                for i in range(0, len(cols), 16)]
+    return cols[0]
+
+
+def _sum_rows(grid):
+    """``sum(axis=1)``: bools count as int32, and an int narrower than the
+    default int sums in the default int (int64 under x64)."""
+    if _dtype(grid) == _BOOL:
+        grid = _astype(grid, _I32)
+    dtype = _dtype(grid)
+    if np.issubdtype(dtype, np.signedinteger) \
+            and dtype.itemsize < _default_int().itemsize:
+        dtype = _default_int()
+    return lax.reduce_sum(_astype(grid, dtype), (1,))
+
+
+def _min_rows(grid):
+    return lax.reduce_min(_astype(grid, _dtype(grid)), (1,))
+
+
+def _max_rows(grid):
+    return lax.reduce_max(_astype(grid, _dtype(grid)), (1,))
+
+
+def _any_rows(grid):
+    return lax.reduce_or(grid, (1,))
+
+
+def _extreme(dtype, low: bool):
+    """The least (``low``) or greatest value of ``dtype``."""
+    if np.issubdtype(dtype, np.integer):
+        info = np.iinfo(dtype)
+        return int(info.min if low else info.max)
+    return -math.inf if low else math.inf
+
+
+def _take_rows(grid, idx):
+    """``grid[i, idx[i]]`` as an [N, 1] array, an index below zero counted
+    from the row's end (``jax.numpy.take_along_axis`` on ``idx[:, None]``)."""
+    n, g = _shape(grid)
+    idx = _astype(lax.expand_dims(idx, (1,)), _default_int())
+    idx = lax.select(_lt(idx, 0), lax.add(idx, _const(idx, g)), idx)
+    dnums = lax.GatherDimensionNumbers(
+        offset_dims=(), collapsed_slice_dims=(1,), start_index_map=(1,),
+        operand_batching_dims=(0,), start_indices_batching_dims=(0,))
+    return lax.gather(grid, lax.reshape(idx, (n, 1, 1)), dnums, (1, 1),
+                      mode="fill")
+
+
 # ------------------------------------------------------------ object model
 
 class _Pod:
-    """Scalar pod fields (broadcast over N by jnp)."""
+    """Scalar pod fields (rank 0: `lax` broadcasts them over N)."""
 
     FIELDS = ("cpu_milli", "memory_mib", "num_gpu", "gpu_milli",
               "creation_time", "duration_time")
@@ -90,11 +384,11 @@ class _Gpu:
     def attr(self, name: str):
         n, g = self.nodes, self.g
         if name == "gpu_milli_left":
-            return n.gpu_milli_left[:, g]
+            return _col(n.gpu_milli_left, g)
         if name == "gpu_milli_total":
-            return n.gpu_milli_total[:, g]
+            return _col(n.gpu_milli_total, g)
         if name in ("memory_mib_left", "memory_mib_total"):
-            return n.gpu_mem_total[:, g]
+            return _col(n.gpu_mem_total, g)
         raise TranspileError(f"unknown gpu attribute {name!r}")
 
 
@@ -108,22 +402,20 @@ class _SortedVals:
     """
 
     def __init__(self, vals, sel):
-        vals = jnp.asarray(vals)
-        if jnp.issubdtype(vals.dtype, jnp.integer):
-            big = jnp.iinfo(vals.dtype).max
-        else:
-            big = jnp.asarray(jnp.inf, vals.dtype)
-        self.vals = jnp.sort(jnp.where(sel, vals, big), axis=1)
-        self.count = jnp.sum(sel, axis=1).astype(jnp.int32)
+        big = _extreme(_dtype(vals), low=False)
+        if not _is_int(vals):
+            big = _const(vals, big)
+        self.vals = lax.sort(_where(sel, vals, big), dimension=1)
+        self.count = _astype(_sum_rows(sel), _I32)
 
     def index(self, k: int, mask, interp):
-        gp = self.vals.shape[1]
+        gp = _shape(self.vals)[1]
         if k >= 0:
-            interp.poison = interp.poison | (mask & (self.count <= k))
-            return self.vals[:, min(k, gp - 1)]
-        interp.poison = interp.poison | (mask & (self.count < -k))
-        idx = jnp.clip(self.count + k, 0, gp - 1)
-        return jnp.take_along_axis(self.vals, idx[:, None], axis=1)[:, 0]
+            interp.doom(_and(mask, _le(self.count, k)))
+            return _col(self.vals, min(k, gp - 1))
+        interp.doom(_and(mask, _lt(self.count, -k)))
+        idx = _min(gp - 1, _max(0, _plus(self.count, k)))
+        return _col(_take_rows(self.vals, idx), 0)
 
 
 class _Node:
@@ -153,10 +445,7 @@ def _to_inexact(v):
     on which entity field fed the expression — and the VM tier
     (fks_tpu.funsearch.vm), which runs a single-dtype register model,
     cannot reproduce the mix."""
-    a = jnp.asarray(v)
-    if jnp.issubdtype(a.dtype, jnp.inexact):
-        return a
-    return a.astype(jnp.float64 if _x64() else jnp.float32)
+    return v if _is_float(v) else _astype(v, _float())
 
 
 def _mathfn(fn):
@@ -166,34 +455,28 @@ def _mathfn(fn):
 
 
 _MATH_FNS = {
-    "sqrt": _mathfn(jnp.sqrt), "log": _mathfn(jnp.log),
-    "exp": _mathfn(jnp.exp), "pow": _mathfn(jnp.power),
-    "sin": _mathfn(jnp.sin), "cos": _mathfn(jnp.cos),
-    "tan": _mathfn(jnp.tan),
+    "sqrt": _mathfn(lax.sqrt), "log": _mathfn(lax.log),
+    "exp": _mathfn(lax.exp), "pow": _mathfn(_power),
+    "sin": _mathfn(lax.sin), "cos": _mathfn(lax.cos),
+    "tan": _mathfn(lax.tan),
 }
 
 
 def _truthy(v):
     if isinstance(v, bool):
         return v
-    a = jnp.asarray(v)
-    return a if a.dtype == jnp.bool_ else a != 0
+    return v if _dtype(v) == _BOOL else _ne(v, 0)
 
 
 def _int_trunc(v):
     """Python int(): truncate toward zero. Non-finite inputs (where Python
     raises OverflowError/ValueError and the reference maps the candidate to
     fitness 0) become 0 — the lane refuses (module docstring divergence)."""
-    a = jnp.asarray(v)
-    if jnp.issubdtype(a.dtype, jnp.integer):
-        return a
-    if a.dtype == bool:
-        return a.astype(jnp.int32)
-    return jnp.where(jnp.isfinite(a), jnp.trunc(a), 0).astype(jnp.int32)
-
-
-def _where(mask, new, old):
-    return jnp.where(mask, new, old)
+    if _is_int(v):
+        return v
+    if _dtype(v) == _BOOL:
+        return _astype(v, _I32)
+    return _astype(_where(lax.is_finite(v), _trunc(v), 0), _I32)
 
 
 class _Interp:
@@ -213,13 +496,13 @@ class _Interp:
             "pod": _Pod(pod), "node": _Node(nodes), "math": "math",
         }
         self.nodes = nodes
-        self.returned = jnp.zeros(self.n, bool)
-        self.retval = jnp.zeros(self.n, jnp.int32)
+        self.returned = _full((self.n,), False, _BOOL)
+        self.retval = _full((self.n,), 0, _I32)
         # lanes where Python would have raised (int() of a non-finite,
         # min()/max() of an empty generator, read of a variable the taken
         # path never assigned); they refuse at the end instead of aborting
         # the whole candidate
-        self.poison = jnp.zeros(self.n, bool)
+        self.poison = _full((self.n,), False, _BOOL)
         # per-variable "assigned on this lane" masks; absent = all lanes
         self.defined: Dict[str, Any] = {}
         # syntactic conditional-nesting depth: 0 = function top level, where
@@ -228,11 +511,19 @@ class _Interp:
         # "unconditional" must be tracked syntactically, not by value)
         self.cond_depth = 0
 
+    def live(self, mask):
+        """The lanes of ``mask`` that have not returned."""
+        return _and(mask, _not(self.returned))
+
+    def doom(self, lanes):
+        """Poison ``lanes``: Python would have raised there."""
+        self.poison = _or(self.poison, lanes)
+
     # ----- statements
 
     def run_block(self, stmts, mask):
         for st in stmts:
-            self.run_stmt(st, mask & ~self.returned)
+            self.run_stmt(st, self.live(mask))
 
     def run_stmt(self, st, mask):
         if isinstance(st, ast.Assign):
@@ -249,18 +540,18 @@ class _Interp:
             cond = _truthy(self.eval(st.test, mask))
             self.cond_depth += 1
             try:
-                self.run_block(st.body, mask & cond)
+                self.run_block(st.body, _and(mask, cond))
                 if st.orelse:
-                    self.run_block(st.orelse, mask & ~cond)
+                    self.run_block(st.orelse, _and(mask, _not(cond)))
             finally:
                 self.cond_depth -= 1
         elif isinstance(st, ast.Return):
             if st.value is None:
                 raise TranspileError("bare return not allowed")
             val = self.eval(st.value, mask)
-            active = mask & ~self.returned
+            active = self.live(mask)
             self.retval = _where(active, val, self.retval)
-            self.returned = self.returned | active
+            self.returned = _or(self.returned, active)
         elif isinstance(st, ast.For):
             self.run_for(st, mask)
         elif isinstance(st, ast.Expr):
@@ -282,7 +573,8 @@ class _Interp:
             self.cond_depth += 1  # bodies run under a per-lane gpu mask
             try:
                 for g in range(it.padded):
-                    gmask = mask & self.nodes.gpu_mask[:, g] & ~self.returned
+                    gmask = self.live(_and(mask,
+                                           _col(self.nodes.gpu_mask, g)))
                     self.env[st.target.id] = _Gpu(self.nodes, g)
                     self.run_block(st.body, gmask)
             finally:
@@ -297,7 +589,8 @@ class _Interp:
             self.cond_depth += 1
             try:
                 for g in range(it.gpus.padded):
-                    gmask = mask & self.nodes.gpu_mask[:, g] & ~self.returned
+                    gmask = self.live(_and(mask,
+                                           _col(self.nodes.gpu_mask, g)))
                     self.env[iname] = g
                     self.env[gname] = _Gpu(self.nodes, g)
                     self.run_block(st.body, gmask)
@@ -312,7 +605,7 @@ class _Interp:
                 raise TranspileError(f"range loop longer than {self.MAX_UNROLL}")
             for i in it:
                 self.env[st.target.id] = i
-                self.run_block(st.body, mask & ~self.returned)
+                self.run_block(st.body, self.live(mask))
             self.env.pop(st.target.id, None)
         else:
             raise TranspileError(
@@ -342,7 +635,7 @@ class _Interp:
             raise TranspileError(f"cannot rebind {name!r}")
         if isinstance(val, (_Pod, _Node, _Gpu, _GpuList, _EnumGpus)):
             raise TranspileError("cannot store entity objects in variables")
-        active = mask & ~self.returned
+        active = self.live(mask)
         all_active = _statically_true(active)
         if isinstance(self.env.get(name), _SortedVals) \
                 and not isinstance(val, _SortedVals):
@@ -367,7 +660,7 @@ class _Interp:
                     "cannot conditionally reassign a sorted() list")
             self.env[name] = val
             if name in self.defined:
-                self.defined[name] = self.defined[name] | active
+                self.defined[name] = _or(self.defined[name], active)
             elif not all_active:
                 self.defined[name] = active
             return
@@ -379,7 +672,7 @@ class _Interp:
             else:
                 self.env[name] = _where(active, val, old)
             if name in self.defined:
-                self.defined[name] = self.defined[name] | active
+                self.defined[name] = _or(self.defined[name], active)
         else:
             if isinstance(val, (int, float)) and all_active:
                 self.env[name] = val
@@ -396,7 +689,7 @@ class _Interp:
         if name not in self.env:
             raise TranspileError(f"undefined variable {name!r}")
         if mask is not None and name in self.defined:
-            self.poison = self.poison | (mask & ~self.defined[name])
+            self.doom(_and(mask, _not(self.defined[name])))
         return self.env[name]
 
     # ----- expressions
@@ -421,12 +714,12 @@ class _Interp:
         if isinstance(node, ast.UnaryOp):
             v = self.eval(node.operand, mask)
             if isinstance(node.op, ast.USub):
-                return -v if isinstance(v, (int, float)) else jnp.negative(v)
+                return -v if isinstance(v, (int, float)) else lax.neg(v)
             if isinstance(node.op, ast.UAdd):
                 return v
             if isinstance(node.op, ast.Not):
                 t = _truthy(v)
-                return (not t) if isinstance(t, bool) else jnp.logical_not(t)
+                return (not t) if isinstance(t, bool) else _not(t)
             raise TranspileError("unsupported unary operator")
         if isinstance(node, ast.BoolOp):
             # later operands evaluate under the lanes where Python would
@@ -442,10 +735,10 @@ class _Interp:
                     else:
                         out = out if t else self.eval(v, reach)
                 elif isinstance(node.op, ast.And):
-                    reach = reach & t
+                    reach = _and(reach, t)
                     out = _where(t, self.eval(v, reach), out)
                 else:
-                    reach = reach & ~t
+                    reach = _and(reach, _not(t))
                     out = _where(t, out, self.eval(v, reach))
             return out
         if isinstance(node, ast.Compare):
@@ -455,17 +748,17 @@ class _Interp:
             for op, rhs_node in zip(node.ops, node.comparators):
                 rhs = self.eval(rhs_node, reach)
                 c = self.compare(op, left, rhs)
-                result = c if result is None else jnp.logical_and(result, c)
+                result = c if result is None else _and(result, c)
                 if not isinstance(result, bool):
-                    reach = reach & result  # chained comparisons short-circuit
+                    reach = _and(reach, result)  # chains short-circuit
                 left = rhs
             return result
         if isinstance(node, ast.IfExp):
             cond = _truthy(self.eval(node.test, mask))
             if isinstance(cond, bool):
                 return self.eval(node.body if cond else node.orelse, mask)
-            a = self.eval(node.body, mask & cond)
-            b = self.eval(node.orelse, mask & ~cond)
+            a = self.eval(node.body, _and(mask, cond))
+            b = self.eval(node.orelse, _and(mask, _not(cond)))
             return _where(cond, a, b)
         if isinstance(node, ast.Call):
             return self.call(node, mask)
@@ -493,54 +786,54 @@ class _Interp:
             if k < 0:
                 raise TranspileError("negative gpu index not supported")
             if k >= base.padded:
-                self.poison = self.poison | mask
+                self.doom(mask)
                 return _Gpu(self.nodes, 0)
-            self.poison = self.poison | (mask & ~self.nodes.gpu_mask[:, k])
+            self.doom(_and(mask, _not(_col(self.nodes.gpu_mask, k))))
             return _Gpu(self.nodes, k)
         raise TranspileError("subscript of unsupported value")
 
     def binop(self, op, a, b):
         both_py = isinstance(a, (int, float)) and isinstance(b, (int, float))
         if isinstance(op, ast.Add):
-            return a + b
+            return a + b if both_py else _plus(a, b)
         if isinstance(op, ast.Sub):
-            return a - b
+            return a - b if both_py else _sub(a, b)
         if isinstance(op, ast.Mult):
-            return a * b
+            return a * b if both_py else _times(a, b)
         if isinstance(op, ast.Div):
             if both_py:
                 return a / b if b != 0 else math.inf  # lowered to refuse later
-            return _to_inexact(a) / _to_inexact(b)
+            return _div(_to_inexact(a), _to_inexact(b))
         if isinstance(op, ast.FloorDiv):
             if both_py:
                 return a // b if b != 0 else math.inf
-            return jnp.floor_divide(jnp.asarray(a), jnp.asarray(b))
+            return _floor_divide(a, b)
         if isinstance(op, ast.Mod):
             if both_py:
                 return a % b if b != 0 else math.inf
-            return jnp.mod(jnp.asarray(a), jnp.asarray(b))
+            return _mod(a, b)
         if isinstance(op, ast.Pow):
             if both_py:
                 try:
                     return a ** b
                 except (OverflowError, ZeroDivisionError):
                     return math.inf
-            return jnp.power(a, b)
+            return _power(a, b)
         raise TranspileError("unsupported binary operator")
 
     def compare(self, op, a, b):
         if isinstance(op, ast.Eq):
-            return jnp.equal(a, b) if not _is_py(a, b) else a == b
+            return _eq(a, b) if not _is_py(a, b) else a == b
         if isinstance(op, ast.NotEq):
-            return jnp.not_equal(a, b) if not _is_py(a, b) else a != b
+            return _ne(a, b) if not _is_py(a, b) else a != b
         if isinstance(op, ast.Lt):
-            return jnp.less(a, b) if not _is_py(a, b) else a < b
+            return _lt(a, b) if not _is_py(a, b) else a < b
         if isinstance(op, ast.LtE):
-            return jnp.less_equal(a, b) if not _is_py(a, b) else a <= b
+            return _le(a, b) if not _is_py(a, b) else a <= b
         if isinstance(op, ast.Gt):
-            return jnp.greater(a, b) if not _is_py(a, b) else a > b
+            return _gt(a, b) if not _is_py(a, b) else a > b
         if isinstance(op, ast.GtE):
-            return jnp.greater_equal(a, b) if not _is_py(a, b) else a >= b
+            return _ge(a, b) if not _is_py(a, b) else a >= b
         raise TranspileError("unsupported comparison")
 
     def call(self, node, mask):
@@ -572,11 +865,11 @@ class _Interp:
         _check_arity(name, len(args))
         if name == "abs":
             (a,) = args
-            return abs(a) if isinstance(a, (int, float)) else jnp.abs(a)
+            return abs(a) if isinstance(a, (int, float)) else _abs(a)
         if name in ("min", "max"):
             if len(args) < 2:
                 raise TranspileError(f"{name}() needs 2+ args or a generator")
-            fn = jnp.minimum if name == "min" else jnp.maximum
+            fn = _min if name == "min" else _max
             py = min if name == "min" else max
             out = args[0]
             for a in args[1:]:
@@ -591,17 +884,16 @@ class _Interp:
             (a,) = args
             if isinstance(a, (int, float)):
                 if not math.isfinite(a):
-                    self.poison = self.poison | mask
+                    self.doom(mask)
                     return 0
                 return int(a)
-            arr = jnp.asarray(a)
-            if jnp.issubdtype(arr.dtype, jnp.floating):
-                self.poison = self.poison | (mask & ~jnp.isfinite(arr))
+            if _is_float(a):
+                self.doom(_and(mask, _not(lax.is_finite(a))))
             return _int_trunc(a)
         if name == "float":
             (a,) = args
             return float(a) if isinstance(a, (int, float)) \
-                else jnp.asarray(a).astype(jnp.float64 if _x64() else jnp.float32)
+                else _astype(a, _float())
         if name == "bool":
             (a,) = args
             return _truthy(a)
@@ -613,8 +905,8 @@ class _Interp:
                 if not isinstance(args2[1], int):
                     raise TranspileError("round() digits must be static")
                 s = 10 ** args2[1]
-                return jnp.round(jnp.asarray(args2[0]) * s) / s
-            return jnp.round(jnp.asarray(args2[0]))
+                return _div(_round(_times(args2[0], s)), s)
+            return _round(args2[0])
         if name == "sum":
             raise TranspileError("sum() only over a generator")
         raise TranspileError(f"call to unsupported function {name!r}")
@@ -637,36 +929,31 @@ class _Interp:
         cols, conds = [], []
         for g in range(it.padded):
             self.env[tname] = _Gpu(self.nodes, g)
-            sel = self.nodes.gpu_mask[:, g]
+            sel = _col(self.nodes.gpu_mask, g)
             for if_ in comp.ifs:
-                sel = sel & _truthy(self.eval(if_, mask))
-            cols.append(jnp.asarray(self.eval(gen.elt, mask)))
+                sel = _and(sel, _truthy(self.eval(if_, mask)))
+            cols.append(self.eval(gen.elt, mask))
             conds.append(sel)
         if saved is None:
             self.env.pop(tname, None)
         else:
             self.env[tname] = saved
-        vals = jnp.stack([jnp.broadcast_to(c, (self.n,)) for c in cols], axis=1)
-        sel = jnp.stack(conds, axis=1)
-        return vals, sel
+        vals = _stack([_broadcast(c, (self.n,)) for c in cols])
+        return vals, _stack(conds)
 
     def reduce_genexp(self, name, gen, mask):
         """``sum/min/max(expr for gpu in node.gpus [if cond])`` -> masked
         reduction over the padded GPU axis."""
         vals, sel = self.genexp_grid(gen, mask)
         if name == "sum":
-            return jnp.sum(jnp.where(sel, vals, 0), axis=1)
+            return _sum_rows(_where(sel, vals, 0))
         # Python min()/max() of an empty iterable raises (-> reference maps
         # the candidate to fitness 0); lanes whose generator selects nothing
         # are poisoned so the identity sentinel can never leak as a score
-        self.poison = self.poison | (mask & ~jnp.any(sel, axis=1))
-        if jnp.issubdtype(vals.dtype, jnp.integer):
-            info = jnp.iinfo(vals.dtype)
-            big = info.max if name == "min" else info.min
-        else:
-            big = jnp.inf if name == "min" else -jnp.inf
-        out = jnp.where(sel, vals, jnp.asarray(big, vals.dtype))
-        return jnp.min(out, axis=1) if name == "min" else jnp.max(out, axis=1)
+        self.doom(_and(mask, _not(_any_rows(sel))))
+        out = _where(sel, vals, _const(
+            vals, _extreme(_dtype(vals), low=name == "max")))
+        return _min_rows(out) if name == "min" else _max_rows(out)
 
 
 class _EnumGpus:
@@ -699,17 +986,12 @@ def _is_py(*vals):
 def _statically_true(mask) -> bool:
     """True iff ``mask`` is a compile-time constant that is all-True (safe
     under jit: tracers — data-dependent masks — report False)."""
-    import jax
     if isinstance(mask, jax.core.Tracer):
         return False
     try:
-        return bool(jnp.all(mask))
+        return bool(np.all(np.asarray(mask)))
     except Exception:
         return False
-
-
-def _x64() -> bool:
-    return jnp.zeros(0).dtype == jnp.float64
 
 
 # --------------------------------------------------------------- public API
@@ -749,16 +1031,14 @@ def build_policy(code: str,
     def policy(pod: PodView, nodes: NodeView):
         _BODY_RUNS.n = body_runs() + 1
         interp = _Interp(pod, nodes)
-        interp.run_block(body, jnp.ones(interp.n, bool))
-        val = interp.retval
+        interp.run_block(body, _full((interp.n,), True, _BOOL))
+        vf = interp.retval
         # lanes that never returned, or whose arithmetic went non-finite,
         # refuse (see module docstring divergence note)
-        vf = jnp.asarray(val)
-        if not jnp.issubdtype(vf.dtype, jnp.integer):
-            finite = jnp.isfinite(vf)
-            vf = jnp.where(finite, vf, 0)
-        out = _int_trunc(vf).astype(jnp.int32)
-        return jnp.where(interp.returned & ~interp.poison, out, 0)
+        if not _is_int(vf):
+            vf = _where(lax.is_finite(vf), vf, 0)
+        out = _astype(_int_trunc(vf), _I32)
+        return _where(_and(interp.returned, _not(interp.poison)), out, 0)
 
     return policy
 
@@ -783,13 +1063,12 @@ def _dry_trace(policy: PolicyFn) -> None:
     path: ``vm.compile_policy`` traces ``build_policy``'s closure at the
     workload's padded shape straight away, and that trace raises the same
     errors."""
-    import jax
-
     n, g = 2, 2
-    i = jnp.zeros((), jnp.int32)
+    i = jax.ShapeDtypeStruct((), _I32)
     pod = PodView(i, i, i, i, i, i)
-    vn = jnp.zeros(n, jnp.int32)
-    vg = jnp.zeros((n, g), jnp.int32)
+    vn = jax.ShapeDtypeStruct((n,), _I32)
+    vg = jax.ShapeDtypeStruct((n, g), _I32)
     nodes = NodeView(vn, vn, vn, vn, vn, vn, vg, vg, vg,
-                     jnp.ones((n, g), bool), jnp.ones(n, bool))
+                     jax.ShapeDtypeStruct((n, g), _BOOL),
+                     jax.ShapeDtypeStruct((n,), _BOOL))
     jax.eval_shape(policy, pod, nodes)

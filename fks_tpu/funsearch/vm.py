@@ -17,7 +17,8 @@ Pipeline:
        the candidate's body, so also its subset validation (a violation
        raises TranspileError from inside it; transpiler.transpile's 2 x 2
        dry trace is for callers without a shape and is not run here)
-    -> this module lowers the (inlined) jaxpr to a register program:
+    -> this module lowers the jaxpr (flat: the transpiler stages `lax`
+       primitives, there is no nested call) to a register program:
        every value lives as an f32[N, G] register (scalars and [N] values
        broadcast across G), each op writes one fresh register, reductions
        over the GPU axis re-broadcast their result (`lower_ops`)
@@ -220,15 +221,6 @@ class _Lowerer:
             raise VMUnsupported("concatenate consumed by non-reduce op")
         return r
 
-    def reg_any(self, atom) -> int:
-        """Operand lookup that lets stacked-pieces placeholders through —
-        used at call boundaries (nested jit) so a concatenate can reach the
-        reduce inside the callee; any real consumer still goes via reg()."""
-        r = self.reg_of.get(id(atom))
-        if r is not None:
-            return r
-        return self.reg(atom)
-
     def bind(self, var, reg: int) -> None:
         self.reg_of[id(var)] = reg
 
@@ -237,7 +229,7 @@ class _Lowerer:
     def lower_closed(self, closed, in_regs: Sequence[int]) -> List[int]:
         jaxpr = closed.jaxpr
         if len(jaxpr.invars) != len(in_regs):
-            raise VMUnsupported("arity mismatch in nested jaxpr")
+            raise VMUnsupported("arity mismatch in the policy's jaxpr")
         for var, reg in zip(jaxpr.invars, in_regs):
             self.bind(var, reg)
         for var, val in zip(jaxpr.constvars, closed.consts):
@@ -248,7 +240,7 @@ class _Lowerer:
                 raise VMUnsupported(f"array constant of shape {arr.shape}")
         for eqn in jaxpr.eqns:
             self.eqn(eqn)
-        return [self.reg_any(v) for v in jaxpr.outvars]
+        return [self.reg(v) for v in jaxpr.outvars]
 
     def eqn(self, eqn) -> None:
         name = eqn.primitive.name
@@ -272,20 +264,8 @@ class _Lowerer:
 
     # -- structural primitives
 
-    def _p_pjit(self, eqn):
-        outs = self.lower_closed(eqn.params["jaxpr"],
-                                 [self.reg_any(v) for v in eqn.invars])
-        for var, reg in zip(eqn.outvars, outs):
-            self.bind(var, reg)
-
-    _p_closed_call = _p_pjit
-    _p_jit = _p_pjit  # jax>=0.7 names the inlineable call primitive "jit"
-
-    def _p_custom_jvp_call(self, eqn):
-        outs = self.lower_closed(eqn.params["call_jaxpr"],
-                                 [self.reg_any(v) for v in eqn.invars])
-        for var, reg in zip(eqn.outvars, outs):
-            self.bind(var, reg)
+    # (a policy's jaxpr is flat, the transpiler stages `lax` primitives:
+    # there is no call primitive here, and a `jit` equation is VMUnsupported)
 
     def _p_broadcast_in_dim(self, eqn):
         # storage is already fully broadcast [N, G]; pure aliasing
@@ -481,8 +461,11 @@ class _Lowerer:
         self._binary(eqn, OP_NE)
 
     def _p_select_n(self, eqn):
-        pred, x0, x1 = (self.reg(v) for v in eqn.invars)
-        # select_n picks cases[pred]: pred==0 -> x0, pred==1 -> x1
+        # select_n picks cases[pred]: pred==0 -> x0, pred==1 -> x1. The
+        # arm taken is read FIRST, as ``where(pred, x1, x0)`` names it: a
+        # literal enters the pool when it is first read, and the pool's
+        # order is part of the program
+        pred, x1, x0 = (self.reg(eqn.invars[k]) for k in (0, 2, 1))
         self.bind(eqn.outvars[0], self.emit(OP_SEL, pred, x0, x1))
 
     # -- reductions (GPU axis or stacked-pieces folds)
@@ -527,12 +510,24 @@ class _Lowerer:
 
 
 def _dummy_views(n: int, g: int) -> Tuple[PodView, NodeView]:
-    i = jnp.zeros((), jnp.int32)
-    vn = jnp.zeros(n, jnp.int32)
-    vg = jnp.zeros((n, g), jnp.int32)
+    """The shapes and dtypes a policy is traced at: nothing is allocated."""
+    def of(dtype, *shape):
+        return jax.ShapeDtypeStruct(shape, dtype)
+
+    i, vn, vg = of(jnp.int32), of(jnp.int32, n), of(jnp.int32, n, g)
     return (PodView(i, i, i, i, i, i),
             NodeView(vn, vn, vn, vn, vn, vn, vg, vg, vg,
-                     jnp.ones((n, g), bool), jnp.ones(n, bool)))
+                     of(jnp.bool_, n, g), of(jnp.bool_, n)))
+
+
+_EQNS = threading.local()
+
+
+def eqns_traced() -> int:
+    """Equations of the jaxprs `lower_ops` has traced on THIS thread (770
+    for a ledger champion): `lower_pool.lower_source` reads the difference
+    over a lowering, the ``eqns`` field of ``tier/transpile/lower``."""
+    return getattr(_EQNS, "n", 0)
 
 
 def lower_ops(code: str, n: int, g: int):
@@ -544,6 +539,7 @@ def lower_ops(code: str, n: int, g: int):
     policy = transpiler.build_policy(code)
     pod, nodes = _dummy_views(n, g)
     closed = jax.make_jaxpr(policy)(pod, nodes)
+    _EQNS.n = eqns_traced() + len(closed.jaxpr.eqns)
 
     lo = _Lowerer(n, g)
     # jaxpr invars = flattened (PodView, NodeView) leaves, in pytree order,

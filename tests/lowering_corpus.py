@@ -11,7 +11,8 @@ exception's class and message. ``mixed_batch_records`` is what
 .evaluate`` on a generation of valid sources, subset violations, a
 VMUnsupported source and a syntax error, every field of every record.
 ``python -m tests.lowering_corpus lowering|records`` prints the pins of the
-tree it runs on. The records were recorded from PR 28's PARENT, whose
+tree it runs on (``... primitives``: `flat_primitives` of three policies,
+``tests/fixtures/policy_primitives.json``, recorded from PR 50's parent). The records were recorded from PR 28's PARENT, whose
 ``compile_policy`` still dry-traced at 2 x 2 before it traced at the real
 shape; the lowering pins were re-recorded by PR 30, whose ``compile_policy``
 packs what ``vm.simplify_ops`` keeps (182 of the 244 cases are programs and
@@ -25,7 +26,7 @@ import os
 
 import numpy as np
 
-from fks_tpu.funsearch import llm, template, vm
+from fks_tpu.funsearch import llm, template, transpiler, vm
 
 SHAPES = ((16, 8), (64, 8))
 CAPACITY = 512
@@ -193,6 +194,61 @@ def mode_key(vm_batch: bool, preflight: bool) -> str:
     return f"vm_batch={int(vm_batch)},preflight={int(preflight)}"
 
 
+#: the sources whose jaxpr's primitives are pinned (ISSUE 50): a ledger
+#: champion and the two seed policies of every generation
+PRIMITIVE_SOURCES = ("champion:20260801_045536_score0.5365",
+                     "seed:first_fit", "seed:best_fit")
+_CALLS = {"jit": "jaxpr", "pjit": "jaxpr", "closed_call": "call_jaxpr",
+          "custom_jvp_call": "call_jaxpr"}
+
+
+def flat_primitives(closed) -> list:
+    """``[name, note]`` for every equation of a policy's jaxpr in order,
+    nested calls walked in place. ``note`` is "" for an equation that
+    computes something, and says why for the three kinds that do not: a
+    "call" (the ``jit`` equation around a ``jax.numpy`` function), a
+    ``convert_element_type`` that "folds" (its operand is a scalar literal:
+    bound outside a nested call, the trace folds it into the literal), and
+    the "x64 probe" (``jnp.zeros(0)``, whose dtype the old transpiler
+    read to learn the ambient float)."""
+    from jax.extend.core import Literal
+
+    out = []
+
+    def walk(jaxpr, literal):
+        def is_literal(atom):
+            return isinstance(atom, Literal) or id(atom) in literal
+
+        for e in jaxpr.eqns:
+            name = e.primitive.name
+            if name in _CALLS:
+                out.append([name, "call"])
+                sub = e.params[_CALLS[name]].jaxpr
+                outs = walk(sub, {id(v) for v, a in zip(sub.invars, e.invars)
+                                  if is_literal(a)})
+                literal.update(id(v) for v, lit in zip(e.outvars, outs)
+                               if lit)
+            elif name == "convert_element_type" and is_literal(e.invars[0]) \
+                    and not e.invars[0].aval.shape:
+                literal.add(id(e.outvars[0]))
+                out.append([name, "folds"])
+            elif name == "broadcast_in_dim" and e.params["shape"] == (0,):
+                out.append([name, "x64 probe"])
+            else:
+                out.append([name, ""])
+        return [is_literal(v) for v in jaxpr.outvars]
+
+    walk(closed.jaxpr, set())
+    return out
+
+
+def policy_primitives(code: str, n: int, g: int) -> list:
+    import jax
+
+    return flat_primitives(jax.make_jaxpr(transpiler.build_policy(code))(
+        *vm._dummy_views(n, g)))
+
+
 def main(what: str):
     import jax
 
@@ -203,6 +259,10 @@ def main(what: str):
         pins = {name: {shape_key(n, g): outcome(code, n, g)
                        for n, g in SHAPES}
                 for name, code in sources().items()}
+    elif what == "primitives":  # run in a checkout of PR 50's PARENT
+        print(json.dumps({name: policy_primitives(sources()[name], *SHAPES[0])
+                          for name in PRIMITIVE_SOURCES}))
+        return
     else:
         pins = {mode_key(*m): mixed_batch_records(*m) for m in MIXED_MODES}
     print(json.dumps(pins, indent=1, sort_keys=True))

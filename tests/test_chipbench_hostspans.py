@@ -66,7 +66,8 @@ def generations(lowers=((0.00, 0.11), (0.00, 0.12), (0.01, 0.10)),
         for j, (l0, l1) in enumerate(lowers):
             recs.append(rec(seq, "tier/transpile/lower", a + l0, a + l1,
                             f"{x}l{j}", x, g, source=j, pid=100 + j,
-                            pooled=1))
+                            pooled=1, trace_ms=(l1 - l0) * 900.0,
+                            eqns=770))  # as the program writes them
             seq += 1
         if pack:
             recs.append(rec(seq, "tier/transpile/pack", a + pack[0],

@@ -195,8 +195,13 @@ def test_every_source_leaves_a_lower_span_inside_the_stage(
         assert r.trace_id == stage.trace_id
         assert r.fields["pooled"] == pooled
         assert 0 <= r.fields["trace_ms"] <= (r.t1 - r.t0) * 1e3
+        # a traced source says how many equations its jaxpr held (a
+        # subset violation raises inside the trace: none)
+        assert r.fields["eqns"] >= r.fields["ops_lowered"] >= 0
         # lowered, then packed: the uploads follow the last lowering
         assert r.t1 <= pack.t0
+    assert sum(r.fields["eqns"] > r.fields["ops_lowered"] > 0
+               for r in lowers) >= 4
     pids = {r.fields["pid"] for r in lowers}
     if pooled:
         assert pids <= {w["pid"] for w in up()} and os.getpid() not in pids

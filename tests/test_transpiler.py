@@ -267,3 +267,37 @@ def test_canonical_key_ignores_formatting():
     a = template.fill_template("score = 1 + 2")
     b = a.replace("score = 1 + 2", "score = 1   +    2")
     assert transpiler.canonical_key(a) == transpiler.canonical_key(b)
+
+
+# ------------------- the body is staged as `lax` primitives (ISSUE 50)
+
+def _parents_primitives():
+    import json
+    import pathlib
+
+    path = pathlib.Path(__file__).parent / "fixtures" / "policy_primitives.json"
+    with open(path) as f:
+        return json.load(f)
+
+
+@pytest.mark.parametrize("name", ["champion:20260801_045536_score0.5365",
+                                  "seed:first_fit", "seed:best_fit"])
+def test_policy_jaxpr_is_flat_and_holds_the_parents_primitives(name):
+    """What ``make_jaxpr(build_policy(code))`` holds: no ``jit`` / ``pjit``
+    / ``closed_call`` equation (35 around ``jax.numpy`` functions for a
+    champion in PR 49's tree, each a trace of its own in Python), and
+    otherwise that tree's primitives in that tree's order, less the two
+    kinds that computed nothing: the conversions of scalar literals that
+    sat inside a nested ``_where`` (the trace folds them now) and the
+    empty ``jnp.zeros(0)`` that asked for the ambient float. A
+    ``jax.numpy`` call that creeps back into `_Interp` shows here as a
+    nested call or as a changed list."""
+    from tests import lowering_corpus as corpus
+
+    assert name in corpus.PRIMITIVE_SOURCES
+    parent = _parents_primitives()[name]
+    got = corpus.policy_primitives(corpus.sources()[name], *corpus.SHAPES[0])
+    assert [note for _, note in got if note] == []
+    assert [p for p, _ in got] == [p for p, note in parent if not note]
+    # the fixture is the PARENT's: it did hold what this tree must not
+    assert sum(note == "call" for _, note in parent) >= 20
