@@ -410,11 +410,21 @@ class CodeEvaluator:
         (funsearch_integration.py:535-562) with one XLA program.
         """
         pop = vm.bucket_lanes(len(progs), self._n_shards)
-        with obs.span("tier/vm_batch/stack_programs",
-                      candidates=len(progs), lanes=pop):
-            padded = list(progs) + [progs[-1]] * (pop - len(progs))
-            stacked = vm.stack_programs(padded)
         sharded = self._n_shards > 1 and self.suite is None
+        with obs.span("tier/vm_batch/stack_programs",
+                      candidates=len(progs), lanes=pop) as ts:
+            # the generation's programs are NumPy words (``_evaluate``):
+            # padded and stacked on the host, read on the host (slots,
+            # the opcode words of `_vm_traced_fields`), and uploaded ONCE,
+            # the eight leaves of the batch in one ``device_put``. A mesh
+            # runner's sharded ``device_put`` (``mesh.shard_population``)
+            # takes the host batch as it is: no stop on the first device
+            padded = list(progs) + [progs[-1]] * (pop - len(progs))
+            host = vm.stack_programs(padded)
+            slots = max(int(p.n_ops) for p in progs)
+            opcode = np.asarray(host.opcode)
+            stacked = host if sharded else jax.device_put(host)
+            ts.set(uploads=len(host))
         # launch + wait_device is the device's part of the generation (a
         # segmented runner already waits for its segments inside launch);
         # d2h is the one transfer and nothing else
@@ -427,8 +437,6 @@ class CodeEvaluator:
         c = self.workload.cluster
         capacity = int(stacked.opcode.shape[-1])
         view = self.cfg.resolve_prefilter_k(c.n_padded) or c.n_padded
-        slots = max(int(p.n_ops) for p in progs)
-        opcode = np.asarray(stacked.opcode)
         with obs.span("tier/vm_batch/launch", lanes=pop,
                       shards=self._n_shards, start_event=self.start_event,
                       slots=slots,
@@ -636,6 +644,19 @@ class CodeEvaluator:
             return self._evaluate(codes)
 
     def _evaluate(self, codes: Sequence[str]) -> List[EvalRecord]:
+        """A generation's host stages, then its launch. What runs where:
+        everything that is per SOURCE (the static pre-flight, the
+        canonical key, the lowering, the packing into words) is
+        ``lower_pool.lower_source``, run once a distinct text by the
+        process's lowering workers side by side, or here, one after
+        another, where there is no pool for it; this thread keeps what is
+        per GENERATION: the exact-text dedup before the pool
+        (``tier/preflight``), then, on what came back and in input order,
+        the canonical-key and fingerprint dedup, the rejections' records,
+        events and counters, the lane order (``tier/transpile/pack``), the
+        stack and the one upload (`_run_vm_batch`). Two sources that
+        differ in text and agree in key or fingerprint are lowered side
+        by side and the first is kept."""
         seg0 = self.segments_dispatched
         vm0 = self.vm_count
         pf_rejected = 0
@@ -644,145 +665,148 @@ class CodeEvaluator:
         fps: Dict[str, Optional[str]] = {}  # canonical key -> fingerprint
         keyed: List[Optional[str]] = []
         errors: Dict[int, EvalRecord] = {}
-        analysis = None
         unique: Dict[str, str] = {}
         alias: Dict[str, str] = {}
         with self.profiler.stage("sandbox+preflight", span="tier/preflight",
                                  candidates=len(codes)) as hp:
-            if self.preflight or self.fp_dedup:
-                # lazy: fks_tpu.analysis pulls funsearch tables, and
-                # funsearch/__init__ imports this module first
-                from fks_tpu import analysis
-            g_padded = self.workload.cluster.g_padded
-            for i, code in enumerate(codes):
-                rep = None
-                if analysis is not None:
-                    rep = analysis.preflight_check(code)
-                    if self.preflight and not rep.ok:
-                        # statically doomed: never reaches sandbox.validate,
-                        # transpile, or any compile tier (pinned by tests)
-                        keyed.append(None)
-                        errors[i] = EvalRecord(
-                            code, 0.0,
-                            f"preflight: {rep.taxonomy}: {rep.reason}")
-                        obs.get_recorder().event(
-                            "candidate_rejected", taxonomy=rep.taxonomy,
-                            stage="preflight", reason=rep.reason[:200])
-                        pf_rejected += 1
-                        continue
-                    if rep.ok and rep.cost is not None:
-                        works.append(rep.cost.work(g_padded))
-                try:
-                    key = transpiler.canonical_key(code)
-                except SyntaxError as e:
-                    keyed.append(None)
-                    errors[i] = EvalRecord(code, 0.0, f"syntax: {e}")
-                    continue
-                keyed.append(key)
-                if rep is not None and key not in fps:
-                    fps[key] = rep.fingerprint
-            for key, code in zip(keyed, codes):
-                if key is not None and key not in unique:
-                    unique[key] = code
-
-            # normalized-AST near-duplicate suppression (within this
-            # batch): fingerprint-colliding sources collapse onto one
-            # representative — one sandbox/transpile/compile/eval instead
-            # of k — and every echo still receives the representative's
-            # full EvalRecord
-            if self.fp_dedup:
-                by_fp: Dict[str, str] = {}
-                for key in list(unique):
-                    fp = fps.get(key)
-                    if fp is None:
-                        continue
-                    owner = by_fp.setdefault(fp, key)
-                    if owner != key:
-                        alias[key] = owner
-                        del unique[key]
-                        fp_dupes += 1
-                        obs.get_recorder().event(
-                            "candidate_rejected",
-                            taxonomy="duplicate_fingerprint",
-                            stage="fp_dedup", reason=f"fingerprint {fp}")
-            hp.annotate(rejected=pf_rejected, duplicates=fp_dupes,
-                        unique=len(unique))
+            # an exact echo is checked and lowered once, with its first
+            texts = list(dict.fromkeys(codes))
+            hp.annotate(unique=len(texts))
 
         memo: Dict[str, EvalRecord] = {}
         vm_progs: Dict[str, vm.VMProgram] = {}
         jit_only: Dict[str, str] = {}  # known outside the VM vocabulary
         general: Dict[str, str] = {}  # default tier choice (VM then jit)
         c = self.workload.cluster
+        sources = [lower_pool.Source(code, self.preflight, self.fp_dedup)
+                   for code in texts]
+        # the batched tier takes a generation; the other tiers lower for
+        # themselves, a source at a time, and need the check alone
+        lower = self.use_vm and self.vm_batch and len(texts) > 1
         with self.profiler.stage("transpile", span="tier/transpile") as ht:
-            lowered: List[lower_pool.Lowered] = []
-            pool, misfit = lower_pool.NOT_POOLED, False
-            if self.use_vm and self.vm_batch and len(unique) > 1:
-                # every source is lowered once, side by side in the
-                # process's workers where it has them; what is raised or
-                # recorded for a source is what its lowering raised,
-                # wherever it ran, and vm_progs keeps unique's order (it
-                # is the lane order)
+            if lower:
+                # every source is checked, keyed, lowered and packed once,
+                # side by side in the process's workers where it has
+                # them; what is raised or recorded for a source is what
+                # its task returned, wherever it ran
                 lowered, pool = lower_pool.lower_all(
-                    list(unique.values()), c.n_padded, c.g_padded)
-                # each lowering as a child span, on the stamps of the
-                # process that did it; none of them where a worker's
-                # clock is not this one's (a wrong interval is worse)
-                misfit = lower_pool.clock_misfit(lowered)
-                if not misfit:
-                    for i, low in enumerate(lowered):
-                        ht.span.child(
-                            "tier/transpile/lower", low.t0, low.t1,
-                            source=i, pid=low.pid,
-                            pooled=int(low.sent is not None),
-                            trace_ms=(low.t_traced - low.t0) * 1e3,
-                            eqns=low.eqns,
-                            ops_lowered=low.ops_lowered,
-                            ops_kept=len(low.kept[0]) if low.kept else 0)
-                packed = 0
-                with obs.span("tier/transpile/pack") as tp:
-                    for (key, code), low in zip(unique.items(), lowered):
-                        try:
-                            if low.error is not None:
-                                raise low.error
-                            prog = vm.pack_program(*low.kept)
-                            packed += 1
-                            if prog.capacity > self.VM_CAPACITY:
-                                raise vm.VMUnsupported(
-                                    f"program too long: capacity "
-                                    f"{prog.capacity}")
-                            vm_progs[key] = prog
-                        except vm.VMUnsupported:
-                            jit_only[key] = code
-                        except transpiler.TranspileError as e:
-                            memo[key] = EvalRecord(code, 0.0,
-                                                   f"transpile: {e}")
-                        except Exception as e:  # noqa: BLE001 — untrusted
-                            memo[key] = EvalRecord(code, 0.0,
-                                                   f"runtime: {e}")
-                    # every array of a program is an upload of its own
-                    tp.set(programs=packed,
-                           uploads=packed * len(vm.VMProgram._fields))
-                if len(vm_progs) == 1:  # a population program for one lane
-                    (key,) = vm_progs  # isn't worth it: unbatched VM tier
-                    general[key] = unique[key]
-                    vm_progs = {}
+                    sources, c.n_padded, c.g_padded)
             else:
-                general = dict(unique)
+                lowered, pool = [lower_pool.check_source(src, c.g_padded)
+                                 for src in sources], lower_pool.NOT_POOLED
+            # each check and each lowering as a child span, on the stamps
+            # of the process that did it; none of them where a worker's
+            # clock is not this one's (a wrong interval is worse)
+            misfit = lower_pool.clock_misfit(lowered)
+            for i, low in enumerate(() if misfit else lowered):
+                where = dict(source=i, pid=low.pid,
+                             pooled=int(low.sent is not None))
+                ht.span.child("tier/transpile/check", low.t0, low.t_checked,
+                              ok=int(low.rejection is None), **where)
+                if lower and low.rejection is None:
+                    ht.span.child(
+                        "tier/transpile/lower", low.t_checked, low.t1,
+                        trace_ms=(low.t_traced - low.t_checked) * 1e3,
+                        eqns=low.eqns, ops_lowered=low.ops_lowered,
+                        ops_kept=len(low.kept[0]) if low.kept else 0,
+                        **where)
+            by_text = dict(zip(texts, lowered))
+            with obs.span("tier/transpile/pack") as tp:
+                # the parent's dedup, in input order, on what came back:
+                # the records, events and counters of the rejected, then
+                # the first source of a canonical key
+                for i, code in enumerate(codes):
+                    low = by_text[code]
+                    keyed.append(low.key)
+                    if low.rejection is None:
+                        if low.work is not None:
+                            works.append(low.work)
+                        fps.setdefault(low.key, low.fingerprint)
+                        unique.setdefault(low.key, code)
+                        continue
+                    taxonomy, reason = low.rejection
+                    if taxonomy is None:
+                        errors[i] = EvalRecord(code, 0.0, f"syntax: {reason}")
+                        continue
+                    # statically doomed: never reaches sandbox.validate,
+                    # transpile, or any compile tier (pinned by tests)
+                    errors[i] = EvalRecord(
+                        code, 0.0, f"preflight: {taxonomy}: {reason}")
+                    obs.get_recorder().event(
+                        "candidate_rejected", taxonomy=taxonomy,
+                        stage="preflight", reason=reason[:200])
+                    pf_rejected += 1
+
+                # normalized-AST near-duplicate suppression (within this
+                # batch): fingerprint-colliding sources collapse onto one
+                # representative — one compile/eval instead of k — and
+                # every echo still receives the representative's full
+                # EvalRecord
+                if self.fp_dedup:
+                    by_fp: Dict[str, str] = {}
+                    for key in list(unique):
+                        fp = fps.get(key)
+                        if fp is None:
+                            continue
+                        owner = by_fp.setdefault(fp, key)
+                        if owner != key:
+                            alias[key] = owner
+                            del unique[key]
+                            fp_dupes += 1
+                            obs.get_recorder().event(
+                                "candidate_rejected",
+                                taxonomy="duplicate_fingerprint",
+                                stage="fp_dedup", reason=f"fingerprint {fp}")
+
+                # the representatives' programs, in unique's order: it is
+                # the lane order. They are NumPy words as they came back:
+                # nothing is uploaded before the generation is stacked
+                # (`_run_vm_batch`)
+                if lower and len(unique) > 1:
+                    for key, code in unique.items():
+                        low = by_text[code]
+                        if low.error is None:
+                            if low.words.capacity <= self.VM_CAPACITY:
+                                vm_progs[key] = low.words
+                            else:  # over the op budget: the jit tier's
+                                jit_only[key] = code
+                        elif isinstance(low.error, vm.VMUnsupported):
+                            jit_only[key] = code
+                        else:
+                            tag = ("transpile" if isinstance(
+                                low.error, transpiler.TranspileError)
+                                else "runtime")
+                            memo[key] = EvalRecord(code, 0.0,
+                                                   f"{tag}: {low.error}")
+                else:
+                    general = dict(unique)
+                tp.set(programs=len(vm_progs), uploads=0)
+            if len(vm_progs) == 1:  # a population program for one lane
+                (key,) = vm_progs  # isn't worth it: unbatched VM tier
+                general[key] = unique[key]
+                vm_progs = {}
             ht.annotate(vm_lanes=len(vm_progs),
-                        jit_fallback=len(jit_only) + len(general))
-            # traces: counted where a policy body runs, so a second trace
-            # per source shows (chipbench: tier.traces_per_source);
-            # ops_lowered / ops_kept: what vm.simplify_ops was given and
-            # what it left to pack (chipbench: vm.ops_kept_share);
-            # pooled / workers: lower_pool.lower_all (chipbench:
-            # tier.pooled_source_share); clock_misfit: 1 where the
-            # workers' stamps were refused and no lower span written
+                        jit_fallback=len(jit_only) + len(general),
+                        rejected=pf_rejected, duplicates=fp_dupes,
+                        unique=len(unique))
+            # sources: the representatives, which enter the tiers, and
+            # pooled: those of them a worker ran (chipbench:
+            # tier.pooled_source_share); workers: that ran a task. Over
+            # every distinct text, as the child spans are: traces,
+            # counted where a policy body runs, so a second trace per
+            # source shows, as a key or fingerprint echo's does
+            # (chipbench: tier.traces_per_source); ops_lowered /
+            # ops_kept: what vm.simplify_ops was given and what it left
+            # to pack (chipbench: vm.ops_kept_share). clock_misfit: 1
+            # where the workers' stamps were refused and no child written
             ht.span.set(sources=len(unique), clock_misfit=int(misfit),
                         traces=sum(low.traces for low in lowered),
                         ops_lowered=sum(low.ops_lowered for low in lowered),
                         ops_kept=sum(len(low.kept[0]) for low in lowered
                                      if low.kept is not None),
-                        **pool)
+                        pooled=sum(by_text[code].sent is not None
+                                   for code in unique.values()),
+                        workers=pool["workers"])
 
         batch_served = 0
         self.last_budget_stats = []

@@ -703,10 +703,14 @@ def simplify_ops(ops, consts, out_reg: int):
     return kept, s.consts, slot.get(out, out)
 
 
-def pack_program(ops, consts, out_reg: int,
-                 capacity: Optional[int] = None) -> VMProgram:
-    """An op list as a ``VMProgram`` padded (OP_NOP) to ``capacity``, or to
-    its own `capacity_bucket`."""
+def pack_words(ops, consts, out_reg: int,
+               capacity: Optional[int] = None) -> VMProgram:
+    """An op list as a ``VMProgram`` of NumPy leaves, padded (OP_NOP) to
+    ``capacity``, or to its own `capacity_bucket`, in the dtypes the
+    device takes (int32 words; immediates and constants in the ambient
+    float). Pure host work, no upload: what a lowering worker sends home
+    (``lower_pool.lower_source``) and what `stack_programs` stacks on the
+    host for a generation's ONE upload."""
     n_ops = len(ops)
     cap = capacity or capacity_bucket(n_ops)
     if n_ops > cap:
@@ -716,16 +720,22 @@ def pack_program(ops, consts, out_reg: int,
         arr[:, k] = (op, a, b, c, imm)
     pool = np.zeros(CONST_POOL, np.float64)
     pool[: len(consts)] = consts
+    word, real = np.dtype(np.int32), np.dtype(_ambient_float())
     return VMProgram(
-        opcode=jnp.asarray(arr[0], jnp.int32),
-        a=jnp.asarray(arr[1], jnp.int32),
-        b=jnp.asarray(arr[2], jnp.int32),
-        c=jnp.asarray(arr[3], jnp.int32),
-        imm=jnp.asarray(arr[4], _ambient_float()),
-        consts=jnp.asarray(pool, _ambient_float()),
-        n_ops=jnp.asarray(n_ops, jnp.int32),
-        out_reg=jnp.asarray(out_reg, jnp.int32),
-    )
+        opcode=arr[0].astype(word), a=arr[1].astype(word),
+        b=arr[2].astype(word), c=arr[3].astype(word),
+        imm=arr[4].astype(real), consts=pool.astype(real),
+        n_ops=np.asarray(n_ops, word), out_reg=np.asarray(out_reg, word))
+
+
+def pack_program(ops, consts, out_reg: int,
+                 capacity: Optional[int] = None) -> VMProgram:
+    """`pack_words`, uploaded: a ``VMProgram`` of device arrays, eight
+    ``jnp.asarray`` transfers a program. For the callers that hold ONE
+    program (``compile_policy``: the unbatched tier, serving, the
+    portfolio); a generation stays on the host until it is stacked."""
+    return jax.tree_util.tree_map(
+        jnp.asarray, pack_words(ops, consts, out_reg, capacity))
 
 
 def compile_policy(code: str, n: int, g: int,
@@ -1329,8 +1339,15 @@ def capacity_bucket(n_ops: int) -> int:
     return max(64, 1 << max(0, int(n_ops) - 1).bit_length())
 
 
+def _on_host(prog: VMProgram) -> bool:
+    """Whether a program's leaves are NumPy (`pack_words`) and not device
+    arrays (`pack_program`)."""
+    return all(isinstance(x, np.ndarray) for x in prog)
+
+
 def pad_capacity(prog: VMProgram, capacity: int) -> VMProgram:
-    """Re-pad a program's op arrays to ``capacity`` (NOP fill)."""
+    """Re-pad a program's op arrays to ``capacity`` (NOP fill): with NumPy
+    where its leaves are NumPy, on the device where they live there."""
     n_live = int(prog.n_ops)
     if n_live > capacity:
         raise VMUnsupported(f"program too long: {n_live} ops > {capacity}")
@@ -1339,10 +1356,11 @@ def pad_capacity(prog: VMProgram, capacity: int) -> VMProgram:
         return prog
     if cur < capacity:
         pad = capacity - cur
+        xp = np if _on_host(prog) else jnp
 
         def ext(x, fill):
-            return jnp.concatenate(
-                [x, jnp.full((pad,), fill, x.dtype)])
+            return xp.concatenate(
+                [x, xp.full((pad,), fill, x.dtype)])
 
         return prog._replace(
             opcode=ext(prog.opcode, OP_NOP), a=ext(prog.a, 0),
@@ -1363,13 +1381,20 @@ def stack_programs(progs: Sequence[VMProgram],
     generation by forking a subprocess per candidate (reference:
     funsearch/funsearch_integration.py:535-562); here a generation is one
     stacked pytree handed to one XLA program.
+
+    Programs of NumPy leaves (`pack_words`) are padded and stacked with
+    NumPy and stay on the host: no transfer, no device program, ``n_ops``
+    read where it lies; the caller uploads the batch once. Device
+    programs (`pack_program`) are stacked on the device as ever, leaf for
+    leaf the same values, shapes and dtypes.
     """
     if not progs:
         raise ValueError("stack_programs needs at least one program")
     longest = max(int(p.n_ops) for p in progs)
     cap = capacity or max(32, 1 << max(0, (longest - 1)).bit_length())
     padded = [pad_capacity(p, cap) for p in progs]
-    return jax.tree_util.tree_map(lambda *xs: jnp.stack(xs), *padded)
+    xp = np if all(_on_host(p) for p in padded) else jnp
+    return jax.tree_util.tree_map(lambda *xs: xp.stack(xs), *padded)
 
 
 def select_slot(stacked: VMProgram, slot) -> VMProgram:

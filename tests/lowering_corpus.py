@@ -10,6 +10,8 @@ exception's class and message. ``mixed_batch_records`` is what
 ``tests/fixtures/mixed_batch_records.json`` records: ``CodeEvaluator
 .evaluate`` on a generation of valid sources, subset violations, a
 VMUnsupported source and a syntax error, every field of every record.
+``echo_generation`` is what ``tests/fixtures/echo_generation.json`` records
+(``... echoes``, from PR 51's PARENT).
 ``python -m tests.lowering_corpus lowering|records`` prints the pins of the
 tree it runs on (``... primitives``: `flat_primitives` of three policies,
 ``tests/fixtures/policy_primitives.json``, recorded from PR 50's parent). The records were recorded from PR 28's PARENT, whose
@@ -194,6 +196,75 @@ def mode_key(vm_batch: bool, preflight: bool) -> str:
     return f"vm_batch={int(vm_batch)},preflight={int(preflight)}"
 
 
+#: a generation that holds every kind of echo and rejection (ISSUE 51):
+#: an exact echo, a source that differs in text and agrees in canonical
+#: key, a pair of fingerprint twins, a syntax error, a statically doomed
+#: source and its exact echo, a subset violation only the trace finds, a
+#: VMUnsupported source, and a ledger champion (another capacity bucket)
+ECHOES = ("seed:first_fit", "seed:best_fit", "twin:a", "syntax:broken",
+          "seed:first_fit", "doomed", "key_echo:best_fit", "fake3:00",
+          "twin:b", "subset:2", "vm:unsupported", "doomed",
+          "champion:20260801_045536_score0.5365", "block:gpu_loop_if")
+#: (preflight, fp_dedup) of the evaluator
+ECHO_MODES = ((True, True), (False, True), (False, False))
+
+
+def echo_sources() -> dict:
+    fill = template.fill_template
+    return dict(sources(), **{
+        "twin:a": fill("x = 1\n    score = x + pod.cpu_milli * 1.5"),
+        "twin:b": fill("y = 1\n    score = y + pod.cpu_milli * 1.7"),
+        "doomed": fill("score = str(pod.cpu_milli)"),
+        "key_echo:best_fit": sources()["seed:best_fit"] + "\n# an echo\n"})
+
+
+def echo_generation(preflight: bool, fp_dedup: bool) -> dict:
+    """`ECHOES` as one generation of the batched tier: every field of
+    every record, the evaluator's counters and ``last_eval_stats``, the
+    ``candidate_rejected`` events in order, and the lanes of the launch
+    (a hash of each lane's program, in lane order). Runs on any tree:
+    ``tests/fixtures/echo_generation.json`` was recorded from PR 51's
+    PARENT (``python -m tests.lowering_corpus echoes``), whose evaluator
+    checked and keyed the sources itself, one after another."""
+    from fks_tpu import obs
+    from fks_tpu.funsearch import backend
+
+    class Events(obs.NullRecorder):
+        def __init__(self):
+            self.seen = []
+
+        def event(self, kind, **fields):
+            self.seen.append({"kind": kind, **fields})
+
+    src = echo_sources()
+    codes = [src[n] for n in ECHOES]
+    ev = backend.CodeEvaluator(mixed_workload(), vm_batch=True,
+                               preflight=preflight, fp_dedup=fp_dedup)
+    lanes, run = [], ev._run_vm_batch
+
+    def launch(progs):
+        lanes.extend(program_hash(vm.pad_capacity(p, CAPACITY))
+                     for p in progs)
+        return run(progs)
+
+    ev._run_vm_batch = launch
+    events = Events()
+    with obs.recording(events):
+        recs = ev.evaluate(codes)
+    out = []
+    for name, code, r in zip(ECHOES, codes, recs):
+        assert r.code == code
+        out.append({"source": name, "score": r.score, "error": r.error,
+                    "result": r.result and _hash_leaves(
+                        (f, leaf) for f, leaf in zip(r.result._fields,
+                                                     r.result)
+                        if leaf is not None)})
+    return {"records": out, "events": events.seen, "lanes": lanes,
+            "preflight_rejected": ev.preflight_rejected,
+            "preflight_duplicates": ev.preflight_duplicates,
+            "stats": ev.last_eval_stats}
+
+
 #: the sources whose jaxpr's primitives are pinned (ISSUE 50): a ledger
 #: champion and the two seed policies of every generation
 PRIMITIVE_SOURCES = ("champion:20260801_045536_score0.5365",
@@ -263,6 +334,9 @@ def main(what: str):
         print(json.dumps({name: policy_primitives(sources()[name], *SHAPES[0])
                           for name in PRIMITIVE_SOURCES}))
         return
+    elif what == "echoes":  # run in a checkout of PR 51's PARENT
+        pins = {f"preflight={int(p)},fp_dedup={int(f)}": echo_generation(p, f)
+                for p, f in ECHO_MODES}
     else:
         pins = {mode_key(*m): mixed_batch_records(*m) for m in MIXED_MODES}
     print(json.dumps(pins, indent=1, sort_keys=True))

@@ -254,6 +254,70 @@ def test_statically_rejected_never_reaches_sandbox(micro_workload,
     assert stats["mean_static_work"] > 0
 
 
+@pytest.mark.parametrize("taxonomy,form", BAD,
+                         ids=[f"{t}-{i}" for i, (t, _) in enumerate(BAD)])
+def test_a_doomed_source_comes_back_before_the_lowering(taxonomy, form,
+                                                        monkeypatch):
+    """The check rides the task that lowers the source (ISSUE 51):
+    ``lower_pool.lower_source`` returns at once with the pre-flight's
+    verdict where the evaluator asks for it, ``vm.lower_ops`` is never
+    entered and no equation is traced; without ``preflight`` the same
+    source goes on and fails where it always did."""
+    from fks_tpu.funsearch import lower_pool, vm
+
+    code = (form if form.startswith("def ")
+            else template.fill_template(form))
+    rep = analysis.preflight_check(code)
+    eqns, lower_ops, entered = vm.eqns_traced(), vm.lower_ops, []
+    monkeypatch.setattr(vm, "lower_ops", lambda *a: entered.append(a)
+                        or lower_ops(*a))
+    low = lower_pool.lower_source(lower_pool.Source(code, True, True), 16, 8)
+    assert low.rejection == (taxonomy, rep.reason)
+    assert (low.key, low.kept, low.words, low.error, low.fingerprint,
+            low.work) == (None,) * 6
+    assert (low.traces, low.eqns, low.ops_lowered) == (0, 0, 0)
+    assert not entered and vm.eqns_traced() == eqns
+    assert low.t0 < low.t_checked == low.t_traced == low.t1
+    # the verdict is the evaluator's to ask for: without ``preflight``
+    # the source is keyed and lowered, and the lowering fails on it
+    low = lower_pool.lower_source(lower_pool.Source(code, False, True),
+                                  16, 8)
+    if taxonomy == "syntax":
+        assert low.rejection.taxonomy is None and not entered
+    else:
+        assert low.rejection is None and len(entered) == 1
+        assert low.key == transpiler.canonical_key(code)
+        assert isinstance(low.error, transpiler.TranspileError)
+    assert low.kept is None and low.words is None
+    assert low.fingerprint is None and low.work is None
+
+
+def test_an_accepted_source_brings_its_fingerprint_and_work_home():
+    """What the evaluator's dedup and ``mean_static_work`` read comes back
+    with the lowering: the pre-flight's fingerprint and its static work at
+    the cluster's GPUs a node; none of it for a source that asks for no
+    check (a bare string)."""
+    from fks_tpu.funsearch import lower_pool
+
+    code = template.fill_template(GOOD[0])
+    rep = analysis.preflight_check(code)
+    for asked in ((True, True), (True, False), (False, True)):
+        low = lower_pool.lower_source(lower_pool.Source(code, *asked), 16, 8)
+        assert low.rejection is None and low.error is None
+        assert low.fingerprint == rep.fingerprint
+        assert low.work == rep.cost.work(8) > rep.cost.work(1) > 0
+        assert low.key == transpiler.canonical_key(code)
+        assert int(low.words.n_ops) == len(low.kept[0]) > 0
+    for bare in (code, lower_pool.Source(code)):
+        low = lower_pool.lower_source(bare, 16, 8)
+        assert (low.fingerprint, low.work) == (None, None)
+        assert low.key == transpiler.canonical_key(code)
+    check = lower_pool.check_source(lower_pool.Source(code, True, True), 8)
+    assert (check.key, check.fingerprint, check.work) \
+        == (low.key, rep.fingerprint, rep.cost.work(8))
+    assert check.kept is None and check.words is None and check.traces == 0
+
+
 def test_preflight_off_restores_legacy_path(micro_workload):
     """preflight=False / fp_dedup=False must fall back to the pre-analyzer
     pipeline: rejects still fail (downstream), duplicates evaluate twice."""

@@ -30,7 +30,9 @@ SPAN_METRICS = ("tier.preflight_ms_per_call", "tier.transpile_ms_per_call",
                 # the ring's other writers (PR 40; chipbench/reduce/hostspans.py)
                 "tier.lower_ms_per_source", "tier.pack_ms_per_call",
                 "tier.pool_overhead_ms_per_call", "tier.gc_ms_per_call",
-                "tier.slow_call_share")
+                "tier.slow_call_share",
+                # where the checks ran and how the programs went up (PR 51)
+                "tier.check_ms_per_source", "tier.uploads_per_call")
 
 
 def _run(monkeypatch, tmp_path, trace, seed=2 ** 31 + 5):
@@ -97,6 +99,7 @@ def test_cell_runs_end_to_end_and_reports_its_span_metrics(monkeypatch,
     assert v["vm.scatter_write_share"] == 0.0   # every write stayed a slice
     assert v["vm.merged_read_share"] == 100.0   # every fetch one gather
     assert 0.0 <= v["tier.pooled_source_share"] <= 100.0
+    assert v["tier.uploads_per_call"] == 8.0    # one put of eight leaves
     slots = v["vm.live_slot_share"] / 100 * 512
     assert v["vm.us_per_slot"] == pytest.approx(
         v["vm.device_ms_per_event"] * 1e3 / slots, rel=1e-6)

@@ -98,11 +98,12 @@ def test_benchmark_json_only_gained_entries():
     assert sum(w["chips"] == 4 for w in bench["workloads"]) == 1
     # at the end as PR 45 left it; PR 46 appended the interpreter's
     # slots a turn after, PR 47 its narrow turns' share, PR 49 the typed
-    # query pods' share
-    assert [m["name"] for m in bench["per_layer"][-4:]] == [
+    # query pods' share, PR 51 the check a source and the uploads a call
+    assert [m["name"] for m in bench["per_layer"][-6:]] == [
         NEW, "vm.slots_per_turn", "vm.narrow_turn_share",
-        "serve.typed_pod_share"]
-    new = bench["per_layer"][-4]
+        "serve.typed_pod_share", "tier.check_ms_per_source",
+        "tier.uploads_per_call"]
+    new = bench["per_layer"][-6]
     meta = json.load(open(os.path.join(cells.HERE, "metrics",
                                        NEW + ".json")))
     assert new == {**{k: meta[k] for k in (

@@ -53,12 +53,16 @@ def rec(seq, name, t0, t1, sid, parent=None, trace="t", **fields):
 
 def generations(lowers=((0.00, 0.11), (0.00, 0.12), (0.01, 0.10)),
                 pack=(0.125, 0.135), stage=(0.1, 0.25), gc=(), slow=(),
-                **stage_fields):
+                checks=(), uploads=24, stack=None, **stage_fields):
     """Three generations of 2 s (the first is the warm-up), each with a
     ``tier/transpile`` of ``stage`` seconds after the call's start whose
     children are ``lowers`` (offsets from the stage's start) and ``pack``;
     ``gc``: pauses as offsets into generation 1; ``slow``: the window
-    calls (0, 1) the program found slow."""
+    calls (0, 1) the program found slow. Since PR 51 the stage may hold
+    ``checks`` (``tier/transpile/check``, offsets like ``lowers``), its
+    pack says ``uploads`` (None: no such field) and the launch's
+    ``tier/vm_batch/stack_programs`` span (``stack``: its fields, or None
+    for no span) may say how many leaves it put."""
     recs, t, seq = [], 0.0, 0
     for i in range(3):
         g, x = f"g{i}", f"x{i}"
@@ -69,10 +73,20 @@ def generations(lowers=((0.00, 0.11), (0.00, 0.12), (0.01, 0.10)),
                             pooled=1, trace_ms=(l1 - l0) * 900.0,
                             eqns=770))  # as the program writes them
             seq += 1
+        for j, (c0, c1) in enumerate(checks):
+            recs.append(rec(seq, "tier/transpile/check", a + c0, a + c1,
+                            f"{x}c{j}", x, g, source=j, pid=100 + j,
+                            pooled=1, ok=1))
+            seq += 1
         if pack:
+            said = {} if uploads is None else {"uploads": uploads}
             recs.append(rec(seq, "tier/transpile/pack", a + pack[0],
-                            a + pack[1], f"{x}p", x, g, programs=3,
-                            uploads=24))
+                            a + pack[1], f"{x}p", x, g, programs=3, **said))
+            seq += 1
+        if stack is not None:
+            recs.append(rec(seq, "tier/vm_batch/stack_programs",
+                            t + stage[1], t + stage[1] + 0.001, f"{x}s", g,
+                            g, candidates=3, lanes=4, **stack))
             seq += 1
         recs.append(rec(seq, "tier/transpile", a, t + stage[1], x, g, g,
                         sources=3, pooled=3, **stage_fields))
@@ -146,6 +160,72 @@ def test_refused_stamps_leave_no_overhead_reading():
     calls = generations(clock_misfit=0)
     assert read("tier.pool_overhead_ms_per_call", calls) \
         == pytest.approx(20.0)
+
+
+# ------------------- the check a source and the uploads a call (PR 51)
+
+def test_check_ms_is_the_mean_of_the_windows_check_spans():
+    """One span a distinct text, on the stamps of the process that ran
+    the check before it lowered the source."""
+    calls = generations(checks=((0.000, 0.004), (0.000, 0.006),
+                                (0.010, 0.012)),
+                        lowers=((0.004, 0.11), (0.006, 0.12), (0.012, 0.10)))
+    assert read("tier.check_ms_per_source", calls) == pytest.approx(4.0)
+    # the check is neither a lower nor a pack: the overhead's reader
+    # leaves the longest source's in the stage's remainder (0-4 ms of
+    # the first source's, which starts the stage)
+    assert read("tier.lower_ms_per_source", calls) \
+        == pytest.approx((106 + 114 + 88) / 3)
+    assert read("tier.pool_overhead_ms_per_call", calls) \
+        == pytest.approx(150.0 - (120.0 - 4.0) - 10.0)
+    # a program that checks in its own thread (older than PR 51), and one
+    # that refused its workers' stamps, write no such span
+    assert read("tier.check_ms_per_source", generations()) is None
+    assert read("tier.check_ms_per_source",
+                generations(lowers=(), clock_misfit=1)) is None
+
+
+@pytest.mark.parametrize("uploads,stack,want", [
+    (24, {}, 24.0),              # PR 50: eight arrays a program, uploaded
+    (24, None, 24.0),            # ... and a ring that holds no launch
+    (0, {"uploads": 8}, 8.0),    # PR 51: one put of the batch's leaves
+    (0, {}, 0.0),                # a 0 is a reading
+    (None, {}, None),            # older than PR 40: nobody counted
+    (None, {"uploads": 8}, 8.0)],
+    ids=("parent", "parent_no_launch", "change", "zero", "unsaid",
+         "stack_alone"))
+def test_uploads_are_the_packs_and_the_stacks_fields_summed(uploads, stack,
+                                                            want):
+    calls = generations(uploads=uploads, stack=stack)
+    got = read("tier.uploads_per_call", calls)
+    assert got == want if want is None else got == pytest.approx(want)
+    # no pack span and no stack span: nothing to read
+    assert read("tier.uploads_per_call",
+                generations(lowers=(), pack=())) is None
+
+
+def test_the_two_of_pr51_are_declared_with_their_files():
+    bench = json.load(open(os.path.join(cells.ROOT, "BENCHMARK.json")))
+    two = bench["per_layer"][-2:]
+    assert [m["name"] for m in two] == ["tier.check_ms_per_source",
+                                        "tier.uploads_per_call"]
+    for m, (unit, source) in zip(two, (("ms", "program_span"),
+                                       ("uploads", "program_counter"))):
+        assert m == {"name": m["name"], "unit": unit, "better": "lower",
+                     "source": source, "layer": TIER,
+                     "moves": "lane_events_per_s", "workloads": CODE}
+        meta = json.load(open(os.path.join(cells.HERE, "metrics",
+                                           m["name"] + ".json")))
+        for key in ("name", "unit", "better", "source", "layer", "moves"):
+            assert meta[key] == m[key], (m["name"], key)
+        assert "PR 51" in meta["doc"]
+        assert cells.metric_reader(m["name"])({}) is None
+    for name in CODE:
+        listed = [m["name"] for m in cells.load_cell(name).per_layer]
+        assert listed[-2:] == [m["name"] for m in two]
+    for name in WHATIF + ["openb16.param256"]:
+        assert not {m["name"] for m in two} & {
+            m["name"] for m in cells.load_cell(name).per_layer}
 
 
 def test_gc_is_the_union_of_the_pauses_inside_the_windows_calls():
@@ -238,13 +318,15 @@ def test_the_seven_are_declared_at_the_end_with_their_files():
     # at the end as PR 40 left it; PR 42 appended its cell's two after,
     # PR 44 the interpreter's merged-read share, PR 45 the typed pods',
     # PR 46 the interpreter's slots a turn, PR 47 its narrow turns' share,
-    # PR 49 the typed query pods' share
+    # PR 49 the typed query pods' share, PR 51 the check a source and the
+    # uploads a call
     seven = bench["per_layer"][41:41 + 7]
     assert [m["name"] for m in seven] == list(METRICS)
     assert [m["name"] for m in bench["per_layer"][41 + 7:]] == [
         "sim.fork_replay_us_per_event", "sim.fork_waiting_pods",
         "vm.merged_read_share", "sim.typed_pod_share", "vm.slots_per_turn",
-        "vm.narrow_turn_share", "serve.typed_pod_share"]
+        "vm.narrow_turn_share", "serve.typed_pod_share",
+        "tier.check_ms_per_source", "tier.uploads_per_call"]
     layers = {m["layer"] for m in bench["per_layer"][:41]}
     for m in seven:
         unit, source, layer, workloads = METRICS[m["name"]]

@@ -22,7 +22,9 @@ SPAN_METRICS = ("tier.preflight_ms_per_call", "tier.transpile_ms_per_call",
                 "tier.lower_ms_per_source", "tier.pack_ms_per_call",
                 "tier.pool_overhead_ms_per_call", "tier.gc_ms_per_call",
                 "tier.slow_call_share",
-                "sim.fork_state_ms")
+                "sim.fork_state_ms",
+                # where the checks ran and how the programs went up (PR 51)
+                "tier.check_ms_per_source", "tier.uploads_per_call")
 #: read from the driver's counters: the profiler's device-eval stage
 #: against the calls' seconds and the window's lockstep events
 COUNTER_METRICS = ("tier.host_share", "vm.ms_per_event", "sim.retry_share")

@@ -92,11 +92,12 @@ def test_benchmark_json_only_gained_entries():
     new = bench["per_layer"][at:at + 2]
     assert [m["name"] for m in new] == list(NEW)
     # appended since, at the end: PR 44's metric of the code cells,
-    # PR 45's of the typed cell, PR 46's and PR 47's of the code cells and
-    # PR 49's of the typed what-if cell
+    # PR 45's of the typed cell, PR 46's and PR 47's of the code cells,
+    # PR 49's of the typed what-if cell and PR 51's two of the code cells
     assert [m["name"] for m in bench["per_layer"][at + 2:]] == [
         "vm.merged_read_share", "sim.typed_pod_share", "vm.slots_per_turn",
-        "vm.narrow_turn_share", "serve.typed_pod_share"]
+        "vm.narrow_turn_share", "serve.typed_pod_share",
+        "tier.check_ms_per_source", "tier.uploads_per_call"]
     later = "openb1523-gpuspec25-loaded.codegen8"   # PR 45's forked cell
     for m in new:
         assert m["workloads"] == [CELL, later]

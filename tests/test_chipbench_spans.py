@@ -366,8 +366,9 @@ def test_every_span_metric_is_declared_with_its_files():
     # sim.fork_replay_us_per_event and sim.fork_waiting_pods (PR 42),
     # vm.merged_read_share (PR 44), sim.typed_pod_share (PR 45),
     # vm.slots_per_turn (PR 46), vm.narrow_turn_share (PR 47),
-    # serve.typed_pod_share (PR 49)
-    assert len(SPAN_METRICS) == 34
+    # serve.typed_pod_share (PR 49), tier.check_ms_per_source and
+    # tier.uploads_per_call (PR 51)
+    assert len(SPAN_METRICS) == 36
     for name in SPAN_METRICS:
         assert name in declared, name
         meta = json.load(open(os.path.join(cells.HERE, "metrics",
@@ -424,7 +425,11 @@ def test_traced_cell_reports_every_span_metric(name, tmp_path, monkeypatch):
             + v["serve.h2d_ms_per_call"] + v["serve.harvest_ms_per_call"]
         assert v["serve.h2d_kb_per_call"] > 0 and v["serve.d2h_kb_per_call"] > 0
     else:
-        assert len(want) == 16
+        assert len(want) == 18
+        # the checks ran with the lowerings (PR 51), and the programs of
+        # a generation reach the devices in one sharded put of 8 leaves
+        assert res["metrics"]["tier.check_ms_per_source"]["value"] > 0
+        assert res["metrics"]["tier.uploads_per_call"]["value"] == 8.0
         # a recorded generation: every source traced once, where it runs,
         # and the simplifier dropped part of what the lowering emitted
         assert res["metrics"]["tier.traces_per_source"]["value"] == 1.0

@@ -96,7 +96,11 @@ def test_benchmark_json_only_gained_entries():
     assert len({c["source"] for c in bench["configs"]}) == 8
     assert len(bench["workloads"]) == 10 and len(bench["configs"]) == 8
     assert sum(w["chips"] == 4 for w in bench["workloads"]) == 1
-    new = bench["per_layer"][-1]
+    # at the end as PR 49 left it; PR 51 appended the code cells' check
+    # a source and uploads a call after
+    assert [m["name"] for m in bench["per_layer"][-3:]] == [
+        NEW, "tier.check_ms_per_source", "tier.uploads_per_call"]
+    new = bench["per_layer"][-3]
     meta = json.load(open(os.path.join(cells.HERE, "metrics",
                                        NEW + ".json")))
     assert new == {**{k: meta[k] for k in (
@@ -105,7 +109,8 @@ def test_benchmark_json_only_gained_entries():
     assert (new["name"], new["layer"], new["moves"]) \
         == (NEW, "serving serve/", "whatif_pods_per_s")
     # appended to every list that held the control, at its end
-    for m in bench["end_to_end"] + bench["per_layer"][:-1]:
+    for m in bench["end_to_end"] + bench["per_layer"][:-3] \
+            + bench["per_layer"][-2:]:
         lists = m.get("workloads", [])
         assert (CELL in lists) == (CONTROL in lists), m["name"]
         if CELL in lists:

@@ -94,7 +94,9 @@ def test_benchmark_json_only_gained_entries():
         # PR 47's share of its turns that ran the narrow opcode table
         "vm.narrow_turn_share",
         # PR 49's share of query pods that name their GPU models
-        "serve.typed_pod_share"]
+        "serve.typed_pod_share",
+        # PR 51's check a source and uploads a call of the code cells
+        "tier.check_ms_per_source", "tier.uploads_per_call"]
     new = bench["per_layer"][at:at + 2]
     # PR 49's forked cell reads both too
     typed = "openb1523-gpuspec25-loaded.whatif8"

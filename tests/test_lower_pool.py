@@ -8,6 +8,7 @@ for its start, survives a lost worker and never outlives its parent.
 the pool to engage tell it there are cores for that (``usable_cores``),
 whatever this machine has, and wait for its workers themselves (``up``).
 """
+import json
 import os
 import struct
 import subprocess
@@ -56,11 +57,19 @@ def bits(x):
     return x
 
 
+def words(prog):
+    """A hash of a program's leaves (dtype, shape, bytes), or None."""
+    return prog and corpus.program_hash(prog)
+
+
 def same(a: lower_pool.Lowered, b: lower_pool.Lowered) -> bool:
     return (bits(a.kept) == bits(b.kept)
             and (a.ops_lowered, a.traces) == (b.ops_lowered, b.traces)
             and type(a.error) is type(b.error)
-            and str(a.error) == str(b.error))
+            and str(a.error) == str(b.error)
+            and (a.key, a.fingerprint, a.work, a.rejection)
+            == (b.key, b.fingerprint, b.work, b.rejection)
+            and words(a.words) == words(b.words))
 
 
 def transpile_span(mark: int):
@@ -102,7 +111,8 @@ def test_pooled_lowering_is_the_in_process_lowering(shape, x64, whole,
     """The 13 ledger champions and the seed policies at both shapes in
     both precisions, the whole lowering corpus in two of the four:
     ``(ops, consts, out_reg)``, the counts, the exception's class and
-    message, and every array of the packed program."""
+    message, the key, and every array of the packed program: the words
+    that came back are the ones ``vm.pack_program`` uploads."""
     named = {k: v for k, v in corpus.sources().items()
              if whole or k.startswith(("champion:", "seed:"))}
     codes = list(named.values())
@@ -120,11 +130,238 @@ def test_pooled_lowering_is_the_in_process_lowering(shape, x64, whole,
             assert pg.capacity == pw.capacity \
                 == vm.capacity_bucket(len(w.kept[0])), name
             assert int(pg.n_ops) == int(pw.n_ops) == len(w.kept[0]), name
-            assert corpus.program_hash(pg) == corpus.program_hash(pw), name
-            assert pg.imm.dtype == (np.float64 if x64 else np.float32)
+            assert corpus.program_hash(pg) == corpus.program_hash(pw) \
+                == corpus.program_hash(g.words), name
+            assert vm._on_host(g.words) and not vm._on_host(pg)
+            assert pg.imm.dtype == g.words.imm.dtype \
+                == (np.float64 if x64 else np.float32)
         # every champion and seed policy lowers, the violations do not
         assert (80 < lowered < len(codes)) if whole \
             else (13 < lowered == len(codes))
+
+
+def checked_sources():
+    """The champions and seed policies (they lower) and every source the
+    static pre-flight or the parser stops (they must not)."""
+    from fks_tpu import analysis
+
+    src = corpus.echo_sources()
+    return {k: v for k, v in src.items()
+            if k.startswith(("champion:", "seed:", "twin:", "key_echo:"))
+            or not analysis.preflight_check(v).ok}
+
+
+@pytest.mark.parametrize("shape,x64", [
+    ((16, 8), False), ((16, 8), True), ((1528, 8), False),
+    ((1528, 8), True)],
+    ids=("16x8-f32", "16x8-x64", "1528x8-f32", "1528x8-x64"))
+def test_pooled_check_is_the_in_process_check(shape, x64, cores):
+    """With the checks an evaluator asks for: the key, the fingerprint,
+    the static work, the rejection and the packed words of every source
+    are the same from a worker and from this process, and a source that
+    is stopped is lowered by neither."""
+    named = checked_sources()
+    asked = [lower_pool.Source(c, True, True) for c in named.values()]
+    with jax.enable_x64(x64):
+        got, stats = lower_pool.lower_all(asked, *shape)
+        eqns = vm.eqns_traced()
+        want = [lower_pool.lower_source(a, *shape) for a in asked]
+        traced_here = vm.eqns_traced() - eqns
+    assert stats["pooled"] == len(asked) and stats["workers"] == 2
+    stopped = 0
+    for name, g, w in zip(named, got, want):
+        assert same(g, w), name
+        assert g.sent is not None and w.sent is None
+        assert g.pid != w.pid == os.getpid()
+        if w.rejection is not None:
+            stopped += 1
+            assert (w.kept, w.words, w.key, w.error) == (None,) * 4, name
+            assert (w.traces, w.eqns, g.traces, g.eqns) == (0,) * 4, name
+            assert w.t_checked == w.t_traced == w.t1
+        else:
+            assert w.key == transpiler.canonical_key(named[name])
+            assert w.fingerprint and w.work > 0, name
+            assert w.words.imm.dtype == (np.float64 if x64 else np.float32)
+            assert w.words.opcode.dtype == w.words.n_ops.dtype == np.int32
+            assert w.words.capacity == vm.capacity_bucket(len(w.kept[0]))
+            assert int(w.words.n_ops) == len(w.kept[0])
+    assert stopped > 20 and len(asked) - stopped >= 17
+    # only the sources that got through the check were traced at all
+    assert traced_here == sum(w.eqns for w in want)
+    # text and key differ, the program does not
+    by = dict(zip(named, want))
+    assert by["key_echo:best_fit"].key == by["seed:best_fit"].key
+    assert by["twin:a"].fingerprint == by["twin:b"].fingerprint \
+        and by["twin:a"].key != by["twin:b"].key
+
+
+@pytest.mark.parametrize("pooled", (1, 0), ids=("pooled", "in_process"))
+@pytest.mark.parametrize("mode", corpus.ECHO_MODES,
+                         ids=lambda m: "preflight=%d,fp_dedup=%d" % m)
+def test_a_generation_of_echoes_and_rejections_is_the_parents(
+        mode, pooled, cores, monkeypatch):
+    """An exact echo, a canonical-key echo, fingerprint twins, a syntax
+    error, a doomed source twice, a subset violation and a VMUnsupported
+    source in one generation: the records, the counters, ``last_eval_
+    stats``, the ``candidate_rejected`` events in order and the lanes of
+    the launch in order are what PR 51's parent gave, whose evaluator
+    checked and keyed every source itself (``tests/fixtures/echo_
+    generation.json``), wherever the sources are checked now."""
+    with open(os.path.join(ROOT, "tests", "fixtures",
+                           "echo_generation.json")) as f:
+        want = json.load(f)["preflight=%d,fp_dedup=%d" % mode]
+    monkeypatch.setattr(lower_pool, "usable_cores",
+                        lambda: 4 if pooled else 1)
+    mark = len(spans.LOG.snapshot())
+    got = json.loads(json.dumps(corpus.echo_generation(*mode)))
+    assert got == want
+    new = spans.LOG.snapshot()[mark:]
+    fields = transpile_span(mark)
+    texts = len(set(corpus.ECHOES))
+    checks = [r for r in new if r.name == "tier/transpile/check"]
+    assert len(checks) == texts
+    assert {r.fields["pooled"] for r in checks} == {pooled}
+    # every source that got through its check was lowered once, an echo
+    # of a key or a fingerprint too; the stage counts the representatives
+    ok = sum(r.fields["ok"] for r in checks)
+    assert ok == texts - (3 if mode[0] else 1)
+    assert fields["traces"] == ok
+    assert fields["sources"] == want["stats"]["unique"]
+    assert fields["pooled"] == pooled * fields["sources"]
+    assert len([r for r in new if r.name == "tier/transpile/lower"]) == ok
+
+
+def test_the_unbatched_tier_checks_in_process_and_lowers_for_itself(
+        cores, monkeypatch):
+    """An evaluator that does not batch needs the check alone: no task
+    goes to the pool, every text leaves its check span, none a lower
+    span, and the records are the batched tier's."""
+    monkeypatch.setattr(lower_pool, "lower_all", None)   # must not be called
+    src = corpus.echo_sources()
+    codes = [src[n] for n in corpus.ECHOES]
+    ev = backend.CodeEvaluator(corpus.mixed_workload(), vm_batch=False)
+    mark = len(spans.LOG.snapshot())
+    recs = ev.evaluate(codes)
+    new = spans.LOG.snapshot()[mark:]
+    fields = transpile_span(mark)
+    assert (fields["sources"], fields["pooled"], fields["workers"],
+            fields["traces"]) == (7, 0, 0, 0)
+    checks = [r for r in new if r.name == "tier/transpile/check"]
+    assert len(checks) == len(set(codes))
+    assert {r.fields["pid"] for r in checks} == {os.getpid()}
+    assert not [r for r in new if r.name == "tier/transpile/lower"]
+    with open(os.path.join(ROOT, "tests", "fixtures",
+                           "echo_generation.json")) as f:
+        want = json.load(f)["preflight=1,fp_dedup=1"]
+    assert [(r.score, r.error) for r in recs] \
+        == [(w["score"], w["error"]) for w in want["records"]]
+    assert ev.preflight_rejected == want["preflight_rejected"]
+    assert ev.preflight_duplicates == want["preflight_duplicates"]
+
+
+# ------------------- the programs stay on the host until they are stacked
+
+def program_pairs(x64: bool):
+    """Three programs of three capacity buckets, as NumPy words and as
+    device arrays."""
+    src = corpus.sources()
+    names = ("seed:best_fit", CHAMPION, "block:gpu_loop_if")
+    with jax.enable_x64(x64):
+        kept = [lower_pool.lower_source(src[n], 16, 8).kept for n in names]
+        return ([vm.pack_words(*k) for k in kept],
+                [vm.pack_program(*k) for k in kept])
+
+
+@pytest.mark.parametrize("capacity", (None, 512, 1024),
+                         ids=("own_bucket", "cap512", "cap1024"))
+@pytest.mark.parametrize("x64", (False, True), ids=("f32", "x64"))
+def test_a_stack_of_numpy_programs_is_the_stack_of_device_programs(
+        x64, capacity):
+    host, device = program_pairs(x64)
+    assert len({p.capacity for p in host}) > 1     # the stack has to pad
+    with jax.enable_x64(x64):
+        a = vm.stack_programs(host, capacity)
+        b = vm.stack_programs(device, capacity)
+        mixed = vm.stack_programs([host[0], *device[1:]], capacity)
+    assert vm._on_host(a) and not vm._on_host(b) and not vm._on_host(mixed)
+    for leaf, x, y, z in zip(a._fields, a, b, mixed):
+        assert isinstance(y, jax.Array) and isinstance(z, jax.Array), leaf
+        assert x.dtype == y.dtype == z.dtype, leaf
+        assert x.shape == y.shape == z.shape, leaf
+        assert x.tobytes() == np.asarray(y).tobytes() \
+            == np.asarray(z).tobytes(), leaf
+    want = capacity or max(32, 1 << (max(
+        int(p.n_ops) for p in host) - 1).bit_length())
+    assert a.opcode.shape == (3, want) and a.n_ops.shape == (3,)
+    assert a.imm.dtype == (np.float64 if x64 else np.float32)
+    # one program, re-padded up and cut down: the same words either way
+    for p, q in zip(host, device):
+        for cap in (p.capacity * 2, vm.capacity_bucket(int(p.n_ops))):
+            hp, dp = vm.pad_capacity(p, cap), vm.pad_capacity(q, cap)
+            assert vm._on_host(hp) and not vm._on_host(dp)
+            assert corpus.program_hash(hp) == corpus.program_hash(dp)
+    with pytest.raises(vm.VMUnsupported):
+        vm.pad_capacity(host[1], 64)
+
+
+@pytest.mark.parametrize("devices", (1, 4), ids=("one_device", "mesh4"))
+def test_a_generation_uploads_its_programs_once_and_reads_nothing_back(
+        devices, cores, monkeypatch):
+    """The batched tier hands the device ONE ``device_put`` of program
+    words a generation, the stacked batch of eight leaves (on a mesh: the
+    runner's one sharded put, from the host batch), and nothing is read
+    back from a device before the launch."""
+    from jax._src import array as jarray
+
+    from fks_tpu.parallel import population_mesh
+
+    src = corpus.sources()
+    codes = [src[n] for n in corpus.MIXED]
+    mesh = population_mesh(jax.devices()[:4]) if devices == 4 else None
+    # bounded segments, as a TPU host picks: the mesh runner then shards
+    # the batch itself, in Python (``mesh.shard_population``)
+    monkeypatch.setenv("FKS_VM_SEG_STEPS", "8")
+    ev = backend.CodeEvaluator(corpus.mixed_workload(), vm_batch=True,
+                               mesh=mesh, engine="flat")
+    want = ev.evaluate(codes)                              # warm
+    puts, reads, launched = [], [], []
+    put, value = jax.device_put, jarray.ArrayImpl._value
+
+    def counting_put(x, *a, **k):
+        if isinstance(x, vm.VMProgram):
+            puts.append((vm._on_host(x), len(x), bool(a or k),
+                         bool(launched)))
+        return put(x, *a, **k)
+
+    def counting_value(self):
+        reads.append(bool(launched))
+        return value.fget(self)
+
+    runner = "_vm_mesh_run" if devices == 4 else "_vm_pop_run"
+    run = getattr(ev, runner)
+
+    def launch(*a):
+        launched.append(1)
+        return run(*a)
+
+    monkeypatch.setattr(ev, runner, launch)
+    monkeypatch.setattr(jax, "device_put", counting_put)
+    monkeypatch.setattr(jarray.ArrayImpl, "_value", property(counting_value))
+    mark = len(spans.LOG.snapshot())
+    got = ev.evaluate(codes)
+    monkeypatch.undo()
+    assert [(r.score, r.error) for r in got] \
+        == [(r.score, r.error) for r in want]
+    # NumPy leaves, eight of them, once: with the default placement
+    # before the launch, or sharded by the mesh runner inside it
+    assert puts == [(True, 8, devices == 4, devices == 4)]
+    assert len(launched) == 1 and not reads.count(False)
+    assert reads                      # the harvest does read, afterwards
+    new = spans.LOG.snapshot()[mark:]
+    (stack,) = [r for r in new if r.name == "tier/vm_batch/stack_programs"]
+    (pack,) = [r for r in new if r.name == "tier/transpile/pack"]
+    assert stack.fields["uploads"] == 8 and pack.fields["uploads"] == 0
+    assert pack.fields["programs"] == stack.fields["candidates"] >= 4
 
 
 # --------------------------- (b), (c) records, routing, the span's fields
@@ -179,17 +416,33 @@ def generation_spans(cores_now, monkeypatch, lower_all=None):
 @pytest.mark.parametrize("pooled", (1, 0), ids=("pooled", "in_process"))
 def test_every_source_leaves_a_lower_span_inside_the_stage(
         pooled, cores, monkeypatch):
-    """Wherever a source is lowered it writes one ``tier/transpile/lower``
-    on the parent's clock, nested in the stage, with the process that did
-    it; the uploads are ``tier/transpile/pack``."""
+    """Wherever a source is checked and lowered it writes one
+    ``tier/transpile/check`` and, if it got that far, one
+    ``tier/transpile/lower`` on the parent's clock, nested in the stage,
+    with the process that did it; ``tier/transpile/pack`` is the dedup
+    and the programs, and uploads nothing."""
     stage, kids = generation_spans(4 if pooled else 1, monkeypatch)
     assert stage.fields["sources"] == 8
     assert stage.fields["pooled"] == 8 * pooled
     assert stage.fields["clock_misfit"] == 0
+    checks = [r for r in kids if r.name == "tier/transpile/check"]
     lowers = [r for r in kids if r.name == "tier/transpile/lower"]
     (pack,) = [r for r in kids if r.name == "tier/transpile/pack"]
-    assert len(kids) == 9 and len(lowers) == 8
-    assert sorted(r.fields["source"] for r in lowers) == list(range(8))
+    # ten sources, nine texts (one exact echo), one of them a syntax
+    # error, which is checked and never lowered
+    broken = corpus.MIXED.index("syntax:broken")
+    assert len(kids) == 18 and len(checks) == 9 and len(lowers) == 8
+    assert sorted(r.fields["source"] for r in checks) == list(range(9))
+    assert sorted(r.fields["source"] for r in lowers) \
+        == [i for i in range(9) if i != broken]
+    assert [r.fields["ok"] for r in sorted(
+        checks, key=lambda r: r.fields["source"])] \
+        == [int(i != broken) for i in range(9)]
+    by_source = {r.fields["source"]: r for r in checks}
+    for r in checks:
+        assert stage.t0 <= r.t0 < r.t1 <= pack.t0
+        assert r.trace_id == stage.trace_id
+        assert set(r.fields) == {"source", "pid", "pooled", "ok"}
     for r in lowers:
         assert stage.t0 <= r.t0 < r.t1 <= stage.t1
         assert r.trace_id == stage.trace_id
@@ -198,17 +451,19 @@ def test_every_source_leaves_a_lower_span_inside_the_stage(
         # a traced source says how many equations its jaxpr held (a
         # subset violation raises inside the trace: none)
         assert r.fields["eqns"] >= r.fields["ops_lowered"] >= 0
-        # lowered, then packed: the uploads follow the last lowering
+        # checked, then lowered, by one process; then the dedup
+        check = by_source[r.fields["source"]]
+        assert check.t1 == r.t0 and check.fields["pid"] == r.fields["pid"]
         assert r.t1 <= pack.t0
     assert sum(r.fields["eqns"] > r.fields["ops_lowered"] > 0
                for r in lowers) >= 4
-    pids = {r.fields["pid"] for r in lowers}
+    pids = {r.fields["pid"] for r in checks}
     if pooled:
         assert pids <= {w["pid"] for w in up()} and os.getpid() not in pids
     else:
         assert pids == {os.getpid()}
         # one after another on one core: no two overlap
-        ordered = sorted(lowers, key=lambda r: r.t0)
+        ordered = sorted(checks + lowers, key=lambda r: r.t0)
         assert all(a.t1 <= b.t0 for a, b in zip(ordered, ordered[1:]))
     assert stage.fields["ops_lowered"] \
         == sum(r.fields["ops_lowered"] for r in lowers)
@@ -216,23 +471,23 @@ def test_every_source_leaves_a_lower_span_inside_the_stage(
         == sum(r.fields["ops_kept"] for r in lowers)
     # three of the eight do not lower (TranspileError, VMUnsupported ...)
     packed = sum(r.fields["ops_kept"] > 0 for r in lowers)
-    assert pack.fields == {"programs": packed,
-                           "uploads": packed * len(vm.VMProgram._fields)}
+    assert pack.fields == {"programs": packed, "uploads": 0}
     assert stage.t0 <= pack.t0 <= pack.t1 <= stage.t1
 
 
 def test_a_worker_on_another_clock_leaves_no_lower_span(cores, monkeypatch):
     """Stamps that do not lie inside the parent's own send and receive
     stamps are refused: the stage says so and writes no child from them
-    (the uploads are the parent's own and stay)."""
+    (the dedup is the parent's own and stays)."""
     real = lower_pool.lower_all
 
     def shifted(codes, n, g):
         out, stats = real(codes, n, g)
         assert stats["pooled"] == len(codes)
-        return [low._replace(t0=low.t0 + 3600.0, t_traced=low.t_traced
-                             + 3600.0, t1=low.t1 + 3600.0)
-                for low in out], stats
+        return [low._replace(
+            t0=low.t0 + 3600.0, t_checked=low.t_checked + 3600.0,
+            t_traced=low.t_traced + 3600.0, t1=low.t1 + 3600.0)
+            for low in out], stats
 
     stage, kids = generation_spans(4, monkeypatch, shifted)
     assert stage.fields["clock_misfit"] == 1 and stage.fields["pooled"] == 8
@@ -242,7 +497,7 @@ def test_a_worker_on_another_clock_leaves_no_lower_span(cores, monkeypatch):
 def test_clock_misfit_reads_the_parents_window():
     low = lower_pool.lower_source(corpus.sources()["seed:best_fit"], 16, 8)
     assert low.pid == os.getpid() and low.sent is None
-    assert low.t0 < low.t_traced < low.t1
+    assert low.t0 < low.t_checked < low.t_traced < low.t1
     assert not lower_pool.clock_misfit([low])        # in process: no window
     inside = low._replace(sent=low.t0 - 1e-3, received=low.t1 + 1e-3)
     assert not lower_pool.clock_misfit([low, inside])
@@ -251,7 +506,8 @@ def test_clock_misfit_reads_the_parents_window():
         assert lower_pool.clock_misfit([inside, bad])
     # a source that does not lower is stamped all the same
     bad = lower_pool.lower_source(corpus.sources()["subset:2"], 16, 8)
-    assert bad.error is not None and bad.t0 < bad.t_traced == bad.t1
+    assert bad.error is not None \
+        and bad.t0 < bad.t_checked < bad.t_traced == bad.t1
 
 
 # ----------------------------------------------------- (d) a lost worker
@@ -378,8 +634,14 @@ def test_lower_source_returns_what_compile_policy_raises():
     with pytest.raises(transpiler.TranspileError) as e:
         vm.compile_policy(src["subset:2"], 16, 8)
     assert str(e.value) == str(low.error)
+    # a source that does not parse stops at the key, as a rejection
     low = lower_pool.lower_source(src["syntax:broken"], 16, 8)
-    assert low.error is not None and low.traces == 0
+    assert low.error is None and low.kept is None and low.traces == 0
+    assert low.rejection.taxonomy is None and low.key is None
+    with pytest.raises(SyntaxError) as e:
+        transpiler.canonical_key(src["syntax:broken"])
+    assert str(e.value) == low.rejection.reason
+    assert low.t0 < low.t_checked == low.t_traced == low.t1
 
 
 # --------------------- (f) one pool a process; none outlives its process
