@@ -173,7 +173,7 @@ def _add_trace_flags(p, snapshot=False):
                             "from the state it pins; needs --engine flat on "
                             "these commands (fks_tpu.data.snapshot; "
                             "serving forks on the exact engine through "
-                            "the library, from arrivals alone: "
+                            "the library, from either kind of file: "
                             "VMServeEngine on a workload parsed with "
                             "snapshot_file=)")
 
@@ -1584,9 +1584,11 @@ COMMITTED_SNAPSHOTS = {
     # the same cluster and list as what-if serving takes it: the first
     # 5,888 arrivals (70 % of the GPUs, the loaded cluster's fork) as
     # first_fit places them with the constraints honoured, every one
-    # placed (best_fit refuses a pod at event 3,569, and the exact engine
-    # forks from placed CREATEs only); first_fit's score has no
-    # arithmetic in it, so the file hangs on no precision
+    # placed (best_fit refuses a pod at event 3,569; when the file was
+    # made, PR 49, the exact engine forked from placed CREATEs only:
+    # since PR 52 it forks from any valid prefix, and the file stays as
+    # pinned); first_fit's score has no arithmetic in it, so the file
+    # hangs on no precision
     "openb_snapshot_gpuspec25_inflated080_firstfit_e5888.csv.gz": (
         "openb_node_list_all_node.csv",
         "openb_pod_list_gpuspec25_inflated080.csv", "first_fit", 5888, 64,

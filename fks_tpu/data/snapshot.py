@@ -20,25 +20,44 @@ everything a result reports (``events_processed``, ``scheduled_pods``,
 stays a quantity of the whole run and ``SimConfig.max_steps`` stays
 absolute, so a window of ``k`` events after the fork is ``max_steps =
 E0 + k``. A snapshot names the rule that re-queued its refused attempts
-(``rule``; empty when it has none, and the prefix is then the same run
-under every rule); an engine with another rule refuses it.
+(``rule``: ``""`` when it has none, and the prefix is then the same run
+under every rule, or ``earliest_delete``); ``_check_rows`` refuses a
+rule it does not know.
+
+One definition of a forked run, for every engine. *A snapshot says what
+HAPPENED: the first ``E0`` events of the run, timed by the rule the
+snapshot names. A forked run is the run of ``base pods ++ query pods``
+(no query pods in candidate evaluation) in which those ``E0`` events
+happen as logged and every later event is the engine's own: the policy
+decides each CREATE attempt and the ENGINE's retry rule re-queues each
+refusal* (``earliest_delete`` on the flat engine, upstream's
+``heap_array`` on the exact one). The logged rule has to be one that
+reads no heap layout: upstream's rule re-queues at ``1 +`` the first
+DELETE in heap-ARRAY order, and the array's layout depends on every item
+in the heap, a query's own CREATEs among them (``heappop`` moves the last
+item to the root, a push sifts from slot ``size``), so a log timed by it
+would change its own retry times with the size of the query asked and
+could not be valid for two queries. ``earliest_delete`` (``1 +`` the
+earliest pending DELETE) is the same run whatever else waits in the
+heap. The exact engine's heap at the fork is what CPython's ``heapq``
+holds after ``heapify`` of all CREATEs (base and query, pod-list order)
+and the ``E0`` logged pops and pushes, slot for slot: ``replay`` runs the
+real ``heapq`` over exactly those operations and hands them on
+(``Prefix.pushes``), ``heap_after`` re-runs them alone.
 
 The snapshot is data on the workload (``Workload.snapshot``, as
 ``faults`` is): ``TraceParser.parse_workload(..., snapshot_file=...)``
 sets it and ``initial_state`` of the flat and of the exact engine
 (``fks_tpu.sim.flat``, ``fks_tpu.sim.engine``) then returns the carry
 after those events, so every runner built on either forks with no
-further argument. The flat engine (candidate evaluation,
-``CodeEvaluator``, the populations, the mesh) forks from any valid
-snapshot: departed, resident and waiting pods, the waiting histogram,
-the fragmentation and utilization sums as its own step accumulates
-them. The exact engine (what-if serving, ``ServeEngine`` /
-``VMServeEngine``, whose queries arrive after the residents:
-``fks_tpu.serve.batcher.QueryFork``) forks from a prefix of placed
-CREATEs only, its heap slot for slot CPython's own
-(``fks_tpu.ops.heap.heap_rows_after_prefix``), and refuses a prefix with
-a departure or a refusal by name, as the fused engine and the portfolio
-refuse every snapshot.
+further argument, from any valid snapshot: departed, resident and
+waiting pods, the waiting histogram, the fragmentation and utilization
+sums as the engine's own step accumulates them. Candidate evaluation
+(``CodeEvaluator``, the populations, the mesh) forks on the flat engine;
+what-if serving (``ServeEngine`` / ``VMServeEngine``, whose queries
+arrive after the prefix: ``fks_tpu.serve.batcher.QueryFork``) on the
+exact one. The fused engine and the portfolio refuse every snapshot by
+name.
 
 File format: a CSV (plain or ``.gz``). A prefix of placed CREATEs, one
 per arrival, is the header ``name,node_sn,gpus`` and a row an arrival:
@@ -63,7 +82,7 @@ from __future__ import annotations
 import gzip
 import heapq
 from pathlib import Path
-from typing import Any, NamedTuple
+from typing import Any, NamedTuple, Optional
 
 import numpy as np
 
@@ -74,8 +93,9 @@ from fks_tpu.data.traces import TraceParser
 SNAPSHOT_COLUMNS = ("name", "node_sn", "gpus")
 #: the columns a prefix with a departure or a refusal adds
 EVENT_COLUMNS = ("event", "rule")
-#: the flat engine's retry rule, the one ``replay`` re-queues under: a
-#: refused pod comes back at 1 + the earliest pending DELETE
+#: the rule a log's refusals are timed by (the flat engine's own, and the
+#: one ``replay`` re-queues under): a refused pod comes back at 1 + the
+#: earliest pending DELETE, whatever the heap's layout
 RETRY_RULE = "earliest_delete"
 NO_EVENT = np.iinfo(np.int32).max   # no event queued (the engines' INF)
 
@@ -127,11 +147,97 @@ class Prefix(NamedTuple):
     frag_free: np.ndarray       # i64[F] per refused attempt: the milli
                                 # free in GPUs too small for any waiting pod
     departed: int               # DELETEs among the events
+    last_time: Optional[int]    # time of event e0 - 1 (None: no event)
+    pushes: list                # per event, the item (a ``heap_key``) that
+                                # its pop was followed by on the event
+                                # heap, or None: pushed nothing
+    heap: list                  # the event heap after the events, as
+                                # CPython's heapq lays it out (keys):
+                                # ``heap_after`` of the CREATEs and pushes
 
     @property
     def refused(self) -> int:
         """Failed placements among the events."""
         return int(len(self.frag_free))
+
+
+#: An item of the event heap as ONE int, ``time << 22 | rank << 2 | kind``
+#: (``heap_key``). The engines' heap rows are ``(time, rank, kind, pod)``
+#: and order by ``(time, rank)``, which is unique among queued items (a
+#: pod has one event queued at a time and ``rank`` names the pod), so the
+#: keys compare as the rows do and every ``heapq`` operation lays them out
+#: alike; the pod is read back off the rank. A replay on ints allocates
+#: no tuple (serving makes one a query: 6,700 tuples a query were 20 ms of
+#: the collector's pauses a call of eight, my chip runs, PR 52).
+KEY_RANK_BITS, KEY_KIND_BITS = 20, 2
+_RANK_MASK = (1 << KEY_RANK_BITS) - 1
+_KIND_MASK = (1 << KEY_KIND_BITS) - 1
+_CREATE, _DELETE = 0, 1         # ``fks_tpu.ops.heap.KIND_CREATE`` / ``_DELETE``
+
+
+def heap_key(time, rank, kind):
+    """The key of a heap item (Python ints, or int64 arrays of them)."""
+    return (time << (KEY_RANK_BITS + KEY_KIND_BITS)) \
+        | (rank << KEY_KIND_BITS) | kind
+
+
+def key_fields(keys):
+    """``(time, rank, kind)`` of ``heap_key``s."""
+    return (keys >> (KEY_RANK_BITS + KEY_KIND_BITS),
+            (keys >> KEY_KIND_BITS) & _RANK_MASK, keys & _KIND_MASK)
+
+
+def rekey(keys, new_rank) -> list:
+    """``keys`` (a list of ``heap_key``s and Nones) with every rank ``r``
+    replaced by ``new_rank[r]``; an item whose new rank is negative is
+    dropped, a None stays one."""
+    new_rank, out = np.asarray(new_rank).tolist(), []
+    for key in keys:
+        if key is None:
+            out.append(None)
+            continue
+        t, r, kind = key_fields(key)
+        if new_rank[r] >= 0:
+            out.append(heap_key(t, new_rank[r], kind))
+    return out
+
+
+def heap_after(creates, pushes, pod_of_rank, capacity=None):
+    """The exact engine's heap rows after a logged prefix of a run, slot
+    for slot what CPython holds there: ``heapify`` of ``creates`` (the
+    ``heap_key`` of every CREATE, in pod-list order), then one ``heappop``
+    per entry of ``pushes`` followed by the ``heappush`` of that entry
+    where it is a key and not None. The engine's retry rule reads the
+    array in ARRAY order, so a valid heap of the same events is not
+    enough; the real ``heapq`` re-runs the operations (no validation and
+    no cluster arithmetic: WHICH operations a prefix makes is ``replay``'s
+    to say, in ``Prefix.pushes``: a placed CREATE's DELETE, a refused
+    one's retry, nothing after a DELETE or a dropped pod). The ONE replay
+    of a prefix's heap beside ``replay`` itself: the exact engine's fork
+    and, once per query, serving's (``creates`` then holds the query's
+    CREATEs too, which is why the layout cannot be computed once: 4 ms a
+    query for the 12,288 events of cpu250's moment on this sandbox's
+    CPU). ``pod_of_rank[r]`` is the pod whose ``tie_rank`` is ``r``.
+    Returns ``(i32[capacity, 4] rows (time, rank, kind, pod), size)``."""
+    pod_of_rank = np.asarray(pod_of_rank, np.int64)
+    if len(pod_of_rank) > 1 << KEY_RANK_BITS:
+        raise ValueError(f"snapshot: a heap key holds ranks below "
+                         f"{1 << KEY_RANK_BITS}, not {len(pod_of_rank)}")
+    items = np.asarray(creates, np.int64).tolist()
+    heapq.heapify(items)
+    pop, push = heapq.heappop, heapq.heappush
+    for key in pushes:
+        pop(items)
+        if key is not None:
+            push(items, key)
+    n = len(items)
+    cap = capacity or n
+    if cap < n:
+        raise ValueError(f"heap capacity {cap} < {n}")
+    rows = np.zeros((cap, 4), np.int32)
+    t, r, kind = key_fields(np.asarray(items, np.int64))
+    rows[:n] = np.stack([t, r, kind, pod_of_rank[r]], axis=1)
+    return rows, n
 
 
 def event_order(pods) -> np.ndarray:
@@ -160,9 +266,9 @@ def _check_rows(workload: Workload, snap: Snapshot) -> None:
     placed = node >= 0
     if snap.rule not in ("", RETRY_RULE):
         raise ValueError(
-            f"snapshot: made under the retry rule {snap.rule!r}; the flat "
-            f"engine re-queues under {RETRY_RULE!r}, and no other engine "
-            "forks from a prefix with a refusal")
+            f"snapshot: made under the retry rule {snap.rule!r}; a log is "
+            f"timed by {RETRY_RULE!r} (or by no rule, where it holds no "
+            "refusal), the one rule that reads no heap layout")
     if ((pod < 0) | (pod >= p.p_padded)).any() or \
             not np.asarray(p.pod_mask)[pod].all():
         raise ValueError("snapshot: an attempt is not a pod of the workload")
@@ -220,7 +326,13 @@ def replay(workload: Workload, snap: Snapshot) -> Prefix:
     after event ``e0``, a run that ends before it) and the state those
     events leave. One pass in event order, the cluster's sums kept as
     they change, so an event costs the same on 16 nodes and on 1,523;
-    only a refused attempt sweeps the GPUs (its fragmentation)."""
+    only a refused attempt sweeps the GPUs (its fragmentation). The event
+    queue is the real ``heapq`` over the ``heap_key``s of the exact
+    engine's heap rows: what it holds at the end (``Prefix.heap``) and
+    what each pop was followed by (``Prefix.pushes``) go with the result,
+    so that the heap of the same events among OTHER pending CREATEs (a
+    query's) is ``heap_after``: the pops and pushes alone, with no
+    validation and no cluster arithmetic."""
     _check_rows(workload, snap)
     c, p = workload.cluster, workload.pods
     e0 = int(snap.e0)
@@ -244,24 +356,29 @@ def replay(workload: Workload, snap: Snapshot) -> Prefix:
     rank = np.asarray(p.tie_rank, np.int64).tolist()
     ctime = np.asarray(p.creation_time, np.int64).tolist()
 
-    create, delete = 0, 1
-    queue = [(ctime[i], rank[i], create, i)
-             for i in np.flatnonzero(np.asarray(p.pod_mask)).tolist()]
+    create, delete = _CREATE, _DELETE
+    real = np.flatnonzero(np.asarray(p.pod_mask)).tolist()
+    pod_of_rank = {rank[i]: i for i in real}
+    if len(pod_of_rank) != len(real) or any(
+            not 0 <= r < 1 << KEY_RANK_BITS for r in pod_of_rank):
+        raise ValueError("snapshot: the pods' tie ranks do not name them "
+                         f"(distinct, below {1 << KEY_RANK_BITS})")
+    queue = [heap_key(ctime[i], rank[i], create) for i in real]
     heapq.heapify(queue)
     deletes: list = []               # (time, rank) of the pending DELETEs
     pp = p.p_padded
     node, bits, held = [-1] * pp, [0] * pp, [()] * pp   # held: GPU slots
     waiting = [False] * pp
     next_event = [NO_EVENT] * pp
-    for t, _, _, i in queue:
-        next_event[i] = t
+    for i in real:
+        next_event[i] = ctime[i]
     wait_milli: dict = {}            # gpu_milli -> waiting GPU pods
     # what is in use, as the engines' step sums it: GPUs by num_gpus -
     # gpu_left, so a node that declares more than it has starts below 0
     in_use = [0, 0, sum(num_gpus) - sum(gpu_left), 0]
     active = [False] * c.n_padded
     n_active = max_nodes = departed = k = 0
-    used, frag_free = [], []
+    used, frag_free, pushes, t = [], [], [], None
 
     def touch(nd):
         nonlocal n_active
@@ -275,7 +392,8 @@ def replay(workload: Workload, snap: Snapshot) -> Prefix:
             raise ValueError(
                 f"snapshot: the run ends after {e} events, before event "
                 f"{e0}, where the log ends")
-        t, r, kind, i = heapq.heappop(queue)
+        t, r, kind = key_fields(heapq.heappop(queue))
+        i, pushed = pod_of_rank[r], None
         if kind == delete:
             if k < len(log_pod) and log_event[k] == e:
                 raise ValueError(
@@ -310,7 +428,7 @@ def replay(workload: Workload, snap: Snapshot) -> Prefix:
                     gpu_mask & (milli_left > 0) & (milli_left < need)].sum()))
                 if deletes:
                     again = deletes[0][0] + 1
-                    heapq.heappush(queue, (again, r, create, i))
+                    pushed = heap_key(again, r, create)
                     next_event[i] = ctime[i] = again
                 else:           # nobody leaves: the pod is dropped
                     next_event[i] = NO_EVENT
@@ -333,10 +451,13 @@ def replay(workload: Workload, snap: Snapshot) -> Prefix:
                         if not wait_milli[milli[i]]:
                             del wait_milli[milli[i]]
                 leave = t + dur[i]
-                heapq.heappush(queue, (leave, r, delete, i))
+                pushed = heap_key(leave, r, delete)
                 heapq.heappush(deletes, (leave, r))
                 next_event[i] = leave
             k += 1
+        if pushed is not None:
+            heapq.heappush(queue, pushed)
+        pushes.append(pushed)
         if nd >= 0:
             cpu_left[nd] += sign * cpu[i]
             mem_left[nd] += sign * mem[i]
@@ -361,7 +482,8 @@ def replay(workload: Workload, snap: Snapshot) -> Prefix:
                                for _ in range(n)], np.int64),
         next_event=np.asarray(next_event, np.int64),
         ctime=np.asarray(ctime, np.int64), pending=len(queue),
-        frag_free=np.asarray(frag_free, np.int64), departed=departed)
+        frag_free=np.asarray(frag_free, np.int64), departed=departed,
+        last_time=t, pushes=pushes, heap=queue)
 
 
 def _name(ids, i: int) -> str:

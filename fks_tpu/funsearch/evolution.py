@@ -277,7 +277,7 @@ class FunSearch:
         self.log = log
         if evaluator.engine != "exact" and self._search_fitness_is_final:
             log(f"snapshot: no exact rescore from a fork (the exact "
-                f"engine forks for serving, from arrivals alone, and the "
+                f"engine forks for serving, and the "
                 f"tiers are not wired to it), so elites "
                 f"are ranked and champions saved by their "
                 f"[{evaluator.engine}] fitness from event "
@@ -408,7 +408,7 @@ class FunSearch:
         """No exact rescore: the search engine IS exact, or the workload
         forks from a snapshot, from which candidates are evaluated on the
         flat engine only (``CodeEvaluator`` refuses another by name; the
-        exact engine's fork serves queries, from arrivals alone)."""
+        exact engine's fork serves queries: ROADMAP R5)."""
         return (self.evaluator.engine == "exact"
                 or self.evaluator.workload.snapshot is not None)
 

@@ -44,7 +44,8 @@ spans do on a value the next line fetches anyway.
 Span names are a contract (PERF.md section 3 lists each with the metric
 that reads it): ``serve/request`` (+ ``/queue_wait``, ``/batch_wait``),
 ``serve/batch`` (+ ``/swap_wait``), ``serve/chunk/{stack,pack,h2d,
-enqueue,wait_device,d2h,extract}``, ``tier/evaluate``, ``tier/preflight``,
+enqueue,wait_device,d2h,extract}`` (+ ``serve/chunk/stack/heap_replay``,
+a forked chunk's per-query heap replays), ``tier/evaluate``, ``tier/preflight``,
 ``tier/transpile``, ``tier/vm_batch/{stack_programs,launch,wait_device,
 d2h}``, ``tier/record``, ``tier/fallback``, ``mesh/shard_put``,
 ``mesh/segment`` (+ ``/wait``), ``mesh/finish``. A ``wait_device`` span

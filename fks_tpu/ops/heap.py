@@ -121,41 +121,6 @@ def heap_from_events(times, ranks, kinds, pods, capacity: int | None = None) -> 
                      size=jnp.asarray(n, jnp.int32))
 
 
-def heap_rows_after_prefix(times, ranks, pods, durations, e0: int,
-                           capacity: int | None = None):
-    """The heap's backing array after the first ``e0`` events of a run
-    whose prefix is ``e0`` placed CREATEs (a snapshot's, see
-    ``fks_tpu.data.snapshot``), slot for slot what CPython holds there:
-    ``heapify`` of every CREATE in the given (pod-list) order, then ``e0``
-    rounds of pop the CREATE / push its DELETE at ``time + duration``
-    under the same rank, in event order. The retry rule reads the array
-    in ARRAY order, so a valid heap of the same events is not enough; the
-    real ``heapq`` replays the prefix (host-side, a few milliseconds for
-    some thousand events). ``durations`` is indexed by the payload
-    ``pods``. Returns ``(i32[capacity, 4] rows, size)`` as NumPy; raises
-    ``ValueError`` when one of the ``e0`` pops is not a CREATE."""
-    items = list(zip(np.asarray(times).tolist(), np.asarray(ranks).tolist(),
-                     [KIND_CREATE] * len(times), np.asarray(pods).tolist()))
-    dur = np.asarray(durations).tolist()
-    heapq.heapify(items)
-    pop, push = heapq.heappop, heapq.heappush
-    for i in range(int(e0)):
-        t, r, kind, p = pop(items)
-        if kind != KIND_CREATE:
-            raise ValueError(
-                f"snapshot: event {i} of the prefix is pod {p}'s DELETE, "
-                f"not a CREATE: the prefix is not {e0} CREATEs")
-        push(items, (t + dur[p], r, KIND_DELETE, p))
-    n = len(items)
-    cap = capacity or n
-    if cap < n:
-        raise ValueError(f"heap capacity {cap} < {n}")
-    arr = np.zeros((cap, 4), np.int32)
-    if n:
-        arr[:n] = np.asarray(items, np.int64)
-    return arr, n
-
-
 def _rows(h: EventHeap, idx):
     """Clamped row-gather of items at ``idx`` (any shape): one instruction.
     Returns ``[..., 4]`` rows."""

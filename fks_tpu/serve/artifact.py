@@ -316,17 +316,21 @@ class ServeEngine:
     runner.
 
     A ``workload`` that carries a ``snapshot`` (``fks_tpu.data.snapshot``)
-    makes every query a FORK from the loaded cluster (``serve.batcher.
-    QueryFork``, exact engine only): a query's run is ``residents ++ query
-    pods`` with the snapshot deciding the first ``E0`` events; the pod
-    axis of a bucket is ``E0 + bucket``, its step budget counts from the
-    fork (``SimConfig.max_steps`` stays absolute: ``E0 + max(64,
+    makes every query a FORK from that moment of the cluster's run
+    (``serve.batcher.QueryFork``, exact engine only), whatever its
+    ``E0`` events hold (arrivals alone, or departures, refusals and
+    waiting pods too): a query's run is ``base pods ++ query pods`` in
+    which those events happen as logged; the pod axis of a bucket is
+    ``fork.base + bucket`` (every pod with an attempt in the log, then
+    the query's), its step budget counts from the fork
+    (``SimConfig.max_steps`` stays absolute: ``E0 + max(64,
     max_steps_factor x bucket)``), and an answer lists the query's pods
-    only, says which of them still wait at the cut (``waiting``) and
-    reports whole-run counts (``events``, ``scheduled``: the residents'
-    included; ``start_event`` says where the champion took over). The
-    snapshot is the engine's, not a query's: a query pod created before
-    the snapshot's last arrival is refused.
+    only, says which of them still wait at the end (``waiting``), whether
+    the run ended with an empty heap inside its budget (``finished``) and
+    reports whole-run counts (``events``, ``scheduled``, ``frag_events``:
+    the prefix's included; ``start_event`` says where the champion took
+    over). The snapshot is the engine's, not a query's: a query pod
+    created before the time of the snapshot's last event is refused.
 
     GPU-type constraints are data too, and nothing else switches them on:
     on a ``workload`` that is ``typed`` (parsed with ``gpu_spec="honor"``:
@@ -375,7 +379,7 @@ class ServeEngine:
         self.base_pods = pods_to_dicts(workload.pods,
                                        gpu_models=self.cluster.gpu_models)
         self.envelope = envelope or ShapeEnvelope()
-        #: the loaded cluster every query forks from, or None
+        #: the moment of a run every query forks from, or None
         self.fork: Optional[QueryFork] = None
         if workload.snapshot is not None:
             if engine != "exact":
@@ -389,11 +393,14 @@ class ServeEngine:
                 workload.pods.tie_rank)[np.asarray(workload.pods.pod_mask)])
             with obs.span("serve/fork_state",
                           start_event=workload.snapshot.e0) as sp:
-                self.fork = QueryFork(workload)
-                sp.set(residents=self.fork.e0,
-                       nodes_loaded=self.fork.nodes_loaded,
-                       heap_size=self.fork.e0,   # their DELETEs, pending
-                       bytes=self.fork.lane_bytes)
+                self.fork = fork = QueryFork(workload)
+                sp.set(events=fork.e0, residents=fork.residents,
+                       departed=fork.prefix.departed,
+                       refused=fork.prefix.refused, waiting=fork.waiting,
+                       nodes_loaded=fork.nodes_loaded,
+                       # the base's own: residents' DELETEs, queued retries
+                       heap_size=len(fork.prefix.heap),
+                       bytes=fork.lane_bytes)
                 if self.typed:
                     sp.set(typed_residents=self.fork.typed_residents,
                            node_models=len(self.cluster.gpu_models))
@@ -511,6 +518,12 @@ class ServeEngine:
         return 0 if self.fork is None else self.fork.e0
 
     @property
+    def base_pods_on_axis(self) -> int:
+        """Rows of a bucket's pod axis that lie before a query's: 0, or
+        the fork's base (every pod with an attempt in the snapshot)."""
+        return 0 if self.fork is None else self.fork.base
+
+    @property
     def typed(self) -> bool:
         """Do this engine's queries carry ``gpu_spec`` (was its workload
         ``typed``)?"""
@@ -523,7 +536,8 @@ class ServeEngine:
         cfg = self.bucket_config(pod_bucket)
         return max_snapshot_count(
             cfg.max_steps,
-            self.start_event + self.envelope.min_real_pods(pod_bucket),
+            self.base_pods_on_axis
+            + self.envelope.min_real_pods(pod_bucket),
             cfg.snapshot_interval)
 
     def _pack_plan(self, pod_bucket: int) -> dict:
@@ -531,7 +545,7 @@ class ServeEngine:
         ``state_pack``) — shared by compile, example and dispatch so the
         packed avals can never diverge from the executable's."""
         return query_pack_plan(self.bucket_config(pod_bucket),
-                               self.start_event + pod_bucket,
+                               self.base_pods_on_axis + pod_bucket,
                                self.envelope.max_gpu_milli)
 
     def _make_serve_fn(self, pod_bucket: int):
@@ -589,7 +603,7 @@ class ServeEngine:
         mesh, exact shardings), for ``lower()``: the smallest query
         routing can send here, replicated across lanes by the same
         pack/pad path real batches use."""
-        t0 = 0 if self.fork is None else self.fork.last_arrival or 0
+        t0 = 0 if self.fork is None else self.fork.not_before or 0
         pods = [{"cpu_milli": 1, "memory_mib": 1, "creation_time": t0 + t,
                  "duration_time": 10}
                 for t in range(self.envelope.min_real_pods(pod_bucket))]
@@ -743,13 +757,18 @@ class ServeEngine:
 
         One ``serve/batch`` span is the root of the call; when the
         batcher's flush context is active it joins that flush's trace
-        and lists the request traces it carries."""
+        and lists the request traces it carries. From a fork it counts
+        the lanes that ended with an empty heap inside their budget
+        (``finished_lanes``)."""
         ctx = trace_ctx.current()
         with obs.span("serve/batch", queries=len(pod_lists)) as root:
             if ctx is not None and ctx.carries:
                 root.set(requests=list(ctx.carries))
             with self._batch_guard():
-                return self._answer_chunks(pod_lists)
+                answers = self._answer_chunks(pod_lists)
+            if self.fork is not None:
+                root.set(finished_lanes=sum(a["finished"] for a in answers))
+            return answers
 
     def validate_query(self, pods: Sequence[dict]) -> None:
         """``validate_query_pods`` under this engine's envelope, fork and
@@ -758,7 +777,7 @@ class ServeEngine:
             pods, max_pods=self.envelope.max_pods,
             max_gpu_milli=self.envelope.max_gpu_milli,
             not_before=None if self.fork is None
-            else self.fork.last_arrival, typed=self.typed)
+            else self.fork.not_before, typed=self.typed)
 
     def _answer_chunks(self, pod_lists) -> List[dict]:
         for pods in pod_lists:
@@ -791,7 +810,7 @@ class ServeEngine:
         chunk = len(self.last_batch_chunks)
         self.last_batch_chunks.append(list(idxs))
         lanes = self._global_lanes(len(idxs))
-        # of a forked chunk's upload, what is the residents' and not the
+        # of a forked chunk's upload, what is the base's and not the
         # queries' (the base is shipped with every batch, lane for lane)
         forked = {} if self.fork is None else {
             "start_event": self.fork.e0,
@@ -808,7 +827,7 @@ class ServeEngine:
                 t_stack.set(
                     pods=sum(len(pod_lists[i]) for i in idxs),
                     typed_pods=int(np.count_nonzero(np.asarray(
-                        pods.gpu_spec)[:, self.start_event:])))
+                        pods.gpu_spec)[:, self.base_pods_on_axis:])))
         with obs.span("serve/chunk/pack", chunk=chunk) as t_pack:
             pods, kt = pack_query_tables(pods, kt, self._pack_plan(bucket))
         with self.profiler.stage("h2d", span="serve/chunk/h2d", chunk=chunk,
@@ -901,9 +920,12 @@ class ServeEngine:
                                            bucket, lanes, waiting)
             if self.fork is not None:
                 # the regime a forked call ran in: failed placements of
-                # its real lanes over their events after the fork
+                # its real lanes FROM THE FORK (an answer's count is the
+                # whole run's, the prefix's refusals included) over their
+                # events after the fork
                 real_ans = [answers[i] for i in idxs]
-                t_ext.set(frag_events=sum(a["frag_events"]
+                before = self.fork.prefix.refused
+                t_ext.set(frag_events=sum(a["frag_events"] - before
                                           for a in real_ans),
                           lane_events=sum(a["events"] - self.fork.e0
                                           for a in real_ans))
@@ -915,14 +937,16 @@ class ServeEngine:
         """One lane's SimResult slice -> an answer dict (``lane=None``
         reads an unbatched scalar result). Placements cover REAL pods
         only; node -1 means unplaced; GPU bitmask unpacked to indices.
-        From a fork the query's pods follow the residents on the pod
+        From a fork the query's pods follow the base's on the pod
         axis and are the only ones listed; the counts stay the whole
-        run's, and ``waiting`` (the lanes' flags, where the executable
-        gave them) names the query's pods that a placement failed for
-        and that hold no node at the end."""
+        run's, ``finished`` says that the run ended with an empty heap
+        inside its budget, and ``waiting`` (the lanes' flags, where the
+        executable gave them) names the query's pods that a placement
+        failed for and that hold no node at the end."""
         pick = (lambda x: np.asarray(x)) if lane is None else \
             (lambda x: np.asarray(x)[lane])
-        mine = slice(self.start_event, self.start_event + p_real)
+        mine = slice(self.base_pods_on_axis,
+                     self.base_pods_on_axis + p_real)
         assigned = pick(res.assigned_node)[mine]
         gpus = pick(res.assigned_gpus)[mine].astype(np.int64)
         node_ids = self.cluster.node_ids
@@ -950,6 +974,7 @@ class ServeEngine:
             # queue in it
             out.update(
                 start_event=self.fork.e0,
+                finished=not (out["truncated"] or out["failed"]),
                 frag_events=int(pick(res.num_fragmentation_events)),
                 snapshots=int(pick(res.num_snapshots)),
                 max_nodes=int(pick(res.max_nodes)),
@@ -1007,6 +1032,8 @@ class ServeEngine:
                 "pod": np.asarray(snap.pod).tolist(),
                 "node": np.asarray(snap.node).tolist(),
                 "gpus": np.asarray(snap.gpus).tolist(),
+                "event": np.asarray(snap.event).tolist(),
+                "e0": int(snap.e0), "rule": snap.rule,
                 "tie_rank": ranks.tolist()}
         cap = getattr(self, "program_capacity", None)
         if cap is not None:
@@ -1037,13 +1064,17 @@ class ServeEngine:
                       pods=_pods_from_dicts(doc.get("base_pods", []),
                                             cluster))
         if doc.get("snapshot"):
-            from fks_tpu.data.snapshot import placed_creates
+            from fks_tpu.data.snapshot import Snapshot, placed_creates
             rows = doc["snapshot"]
+            snap = placed_creates(rows["pod"], rows["node"], rows["gpus"])
+            if "event" in rows:     # a moment of a run, not arrivals alone
+                snap = Snapshot(snap.pod, snap.node, snap.gpus,
+                                np.asarray(rows["event"], np.int32),
+                                e0=int(rows["e0"]), rule=rows["rule"])
             wl = dataclasses.replace(
                 wl, pods=dataclasses.replace(wl.pods, tie_rank=np.asarray(
                     rows["tie_rank"], np.int32)),
-                snapshot=placed_creates(rows["pod"], rows["node"],
-                                        rows["gpus"]))
+                snapshot=snap)
         extra = {}
         portfolio = doc.get("portfolio")
         if doc.get("engine_kind", "aot") == "vm" and cls.engine_kind != "vm":

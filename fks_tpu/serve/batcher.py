@@ -37,7 +37,7 @@ import numpy as np
 
 from fks_tpu.data.entities import (
     PodArrays, Workload, gpu_spec_bits, gpu_spec_names)
-from fks_tpu.obs import trace_ctx
+from fks_tpu.obs import spans, trace_ctx
 from fks_tpu.parallel.traces import strip_ids
 from fks_tpu.resilience.admission import AdmissionConfig, AdmissionController
 from fks_tpu.resilience.deadline import (
@@ -92,8 +92,8 @@ def validate_query_pods(pods: Sequence[Dict[str, Any]], *, max_pods: int,
                         typed: bool = False) -> None:
     """Reject malformed queries before any device work (the error message
     is the service's 4xx body). ``not_before``: an engine that forks from
-    a snapshot (``QueryFork``) takes no pod created before the snapshot's
-    last arrival, because the events before the fork are decided.
+    a snapshot (``QueryFork``) takes no pod created before the time of the
+    snapshot's last event, because the events before the fork happened.
     ``typed``: does the engine's cluster carry its nodes' GPU models
     (``Workload.typed``)? One that does not refuses a pod with a
     non-empty ``gpu_spec`` by name: it would answer as if the pod named
@@ -119,7 +119,7 @@ def validate_query_pods(pods: Sequence[Dict[str, Any]], *, max_pods: int,
             raise ValueError(
                 f"pod {i} creation_time {int(p.get('creation_time', 0))} "
                 f"lies before the fork: this engine answers from a "
-                f"snapshot whose last arrival is at {not_before}")
+                f"snapshot whose last event is at {not_before}")
         try:
             spec = query_gpu_spec(p)
         except ValueError as e:
@@ -142,9 +142,9 @@ def build_query_workload(cluster, pods: Sequence[Dict[str, Any]],
     tie order equals index order and ``tie_rank = arange`` reproduces it
     exactly. Padding rows are zeros under a False pod_mask (the
     ``pad_workload`` idiom — never read by the engine). With a ``fork``
-    the workload is ``residents ++ query pods`` on a pod axis of ``E0 +
-    bucket`` and carries the fork's snapshot, the query's ``tie_rank``
-    after the residents'.
+    the workload is ``base pods ++ query pods`` on a pod axis of
+    ``fork.base + bucket`` and carries the fork's snapshot, the query's
+    ``tie_rank`` after the base's.
 
     GPU-type constraints are data: on a cluster that carries its nodes'
     models (``cluster.gpu_model``; a serve engine keeps the leaf only
@@ -157,20 +157,20 @@ def build_query_workload(cluster, pods: Sequence[Dict[str, Any]],
     p_real = len(pods)
     if p_real > bucket:
         raise ValueError(f"{p_real} pods exceed pod bucket {bucket}")
-    e0 = 0 if fork is None else fork.e0
+    base = 0 if fork is None else fork.base     # its rows come first
 
     def col(field: str, default: int = 0) -> np.ndarray:
-        a = np.zeros(e0 + bucket, np.int32)
-        if e0:
-            a[:e0] = fork.cols[field]
+        a = np.zeros(base + bucket, np.int32)
+        if base:
+            a[:base] = fork.cols[field]
         for i, p in enumerate(pods):
-            a[e0 + i] = int(p.get(field, default))
+            a[base + i] = int(p.get(field, default))
         return a
 
     spec = None
     if cluster.gpu_model is not None:
         spec = gpu_spec_words(pods, cluster.gpu_models, bucket)
-        if e0:
+        if base:
             spec = np.concatenate([fork.spec, spec])
     pa = PodArrays(
         cpu=col("cpu_milli"),
@@ -181,8 +181,9 @@ def build_query_workload(cluster, pods: Sequence[Dict[str, Any]],
         duration=col("duration_time", DEFAULT_DURATION),
         tie_rank=(np.arange(bucket, dtype=np.int32) if fork is None
                   else np.concatenate([
-                      fork.rank, np.arange(e0, e0 + bucket, dtype=np.int32)])),
-        pod_mask=np.arange(e0 + bucket) < e0 + p_real,
+                      fork.rank,
+                      np.arange(base, base + bucket, dtype=np.int32)])),
+        pod_mask=np.arange(base + bucket) < base + p_real,
         pod_ids=(() if fork is None else fork.pod_ids)
         + tuple(f"q-{i:05d}" for i in range(p_real)),
         gpu_spec=spec,
@@ -192,84 +193,121 @@ def build_query_workload(cluster, pods: Sequence[Dict[str, Any]],
 
 
 class QueryFork:
-    """The loaded cluster every query of one serve engine forks from: the
-    part of a workload's ``snapshot`` (``fks_tpu.data.snapshot``) that no
-    query changes, built ONCE per engine. A forked query's run is the run
-    of ``residents ++ query pods`` in which the snapshot decides the
-    first ``E0`` events and the champion every later one, so its answer
-    is where the queue lands on the cluster as it stands and what the
-    next events of the cluster's life look like with the queue in it.
+    """The moment of a run that every query of one serve engine forks
+    from: the part of a workload's ``snapshot`` (``fks_tpu.data.snapshot``)
+    that no query changes, built and validated ONCE per engine. *A
+    snapshot says what happened: the first ``E0`` events of the run. A
+    forked query's run is the run of ``base pods ++ query pods`` in which
+    those events happen as logged and every later event is the exact
+    engine's own: the champion decides each CREATE attempt, upstream's
+    heap-array rule re-queues each refusal.* Its answer is where the queue
+    lands on the cluster as it stands and what the cluster's next events
+    look like with the queue in it.
 
-    Held here, all NumPy: the residents' pod columns in event order
-    (on a typed workload their ``gpu_spec`` words beside the six, as the
-    trace gave them: ``fork_prefix`` has refused a snapshot that puts
-    one of them on a node it may not take), the snapshot re-indexed to
-    that order, ``data.snapshot.Prefix`` (the cluster after the
-    residents, their running sums for the evaluator).
+    The base is every pod with an attempt in the log, once, in
+    first-attempt order (``base`` of them: after a prefix of placed
+    CREATEs the residents, ``base == e0``; after a general prefix the
+    departed pods too, whose rows stay on the pod axis, inert). Held
+    here, all NumPy: the base's pod columns in that order (on a typed
+    workload their ``gpu_spec`` words beside the six, as the trace gave
+    them: the replay has refused a snapshot that puts one of them on a
+    node it may not take), the snapshot re-indexed to it, and
+    ``data.snapshot.Prefix`` re-indexed alike (the cluster after the
+    events, their running sums for the evaluator, each base pod's node,
+    GPUs, waiting flag and moved creation time, the heap operations).
     Per query, ``stack`` adds what does depend on the query: the trigger
     table, which is sized from the WHOLE run's pod count, with the
     evaluator's sums at the fork read off the prefix, and the heap, which
-    CPython builds by heapifying the query's CREATEs together with the
-    residents' before the prefix runs (``ops.heap.heap_rows_after_prefix``
-    through ``sim.engine.forked_state``: the retry rule reads the heap in
-    array order, so the replay is made for every query; 6 ms for 5,888
-    residents)."""
+    CPython lays out by heapifying the query's CREATEs together with the
+    base's before the prefix's pops and pushes run
+    (``data.snapshot.heap_after`` through ``sim.engine.forked_state``: the
+    retry rule reads the heap in array order, so the operations are
+    re-run for every query, on ints, and nothing else is: 3 ms for 5,888
+    placed CREATEs, 4 ms for the 12,288 events of cpu250's moment on this
+    sandbox's CPU)."""
 
     def __init__(self, workload: Workload):
-        from fks_tpu.data.snapshot import placed_creates
-        from fks_tpu.sim.engine import fork_prefix, require_placed_creates
+        from fks_tpu.data.snapshot import NO_EVENT, Snapshot, rekey
+        from fks_tpu.sim.engine import fork_prefix
 
         snap, p = workload.snapshot, workload.pods
-        self.prefix = fork_prefix(workload)          # validates
-        require_placed_creates(self.prefix, "serving")
-        order = np.asarray(snap.pod, np.int64)
-        self.e0 = snap.e0
+        prefix = fork_prefix(workload)          # validates, once
+        attempts = np.asarray(snap.pod, np.int64)
+        _, first = np.unique(attempts, return_index=True)
+        order = attempts[np.sort(first)]
+        #: events of the prefix; pods of the base (their rows on the axis)
+        self.e0, self.base = snap.e0, len(order)
         fields = {"cpu_milli": p.cpu, "memory_mib": p.mem,
                   "num_gpu": p.num_gpu, "gpu_milli": p.gpu_milli,
                   "creation_time": p.creation_time,
                   "duration_time": p.duration}
         self.cols = {k: np.asarray(v)[order].astype(np.int32)
                      for k, v in fields.items()}
-        #: the residents' accepted-model words, or None (not typed)
+        #: the base's accepted-model words, or None (not typed)
         self.spec = np.asarray(p.gpu_spec, np.int32)[order] \
             if workload.typed else None
-        self.typed_residents = 0 if self.spec is None \
-            else int(np.count_nonzero(self.spec))
-        rank = np.empty(self.e0, np.int32)
+        rank = np.empty(self.base, np.int32)
         rank[np.argsort(np.asarray(p.tie_rank)[order], kind="stable")] = \
-            np.arange(self.e0, dtype=np.int32)
-        self.rank = rank        # the residents' pod-id order, made dense
+            np.arange(self.base, dtype=np.int32)
+        self.rank = rank        # the base's pod-id order, made dense
         self.pod_ids = tuple(p.pod_ids[int(i)] for i in order)
-        self.snapshot = placed_creates(np.arange(self.e0), snap.node,
-                                       snap.gpus)
+        at = np.full(p.p_padded, -1, np.int64)  # a pod's row in the base
+        at[order] = np.arange(self.base)
+        self.snapshot = Snapshot(
+            pod=at[attempts].astype(np.int32), node=np.asarray(snap.node),
+            gpus=np.asarray(snap.gpus), event=np.asarray(snap.event),
+            e0=snap.e0, rule=snap.rule)
+
+        # the heap's items name a pod by its rank: the base's, made dense
+        old = np.asarray(p.tie_rank, np.int64)
+        new_rank = np.full(int(old.max(initial=-1)) + 1, -1, np.int64)
+        new_rank[old[order]] = rank
+        self.prefix = prefix._replace(
+            node=prefix.node[order], gpus=prefix.gpus[order],
+            waiting=prefix.waiting[order], ctime=prefix.ctime[order],
+            next_event=prefix.next_event[order],
+            pushes=rekey(prefix.pushes, new_rank),
+            heap=rekey(prefix.heap, new_rank))
         #: no query pod may be created before this (the 4xx of
-        #: ``validate_query_pods``): the last resident's arrival
-        self.last_arrival = int(self.cols["creation_time"][-1]) \
-            if self.e0 else None
-        self.nodes_loaded = int(len(np.unique(self.snapshot.node)))
+        #: ``validate_query_pods``): the time of the last prefix event
+        self.not_before = prefix.last_time
+        resident = (self.prefix.node >= 0) \
+            & (self.prefix.next_event != NO_EVENT)
+        self.residents = int(resident.sum())
+        self.waiting = int((self.prefix.waiting
+                            & (self.prefix.next_event != NO_EVENT)).sum())
+        self.typed_residents = 0 if self.spec is None \
+            else int(np.count_nonzero(self.spec[resident]))
+        self.nodes_loaded = int(len(np.unique(self.prefix.node[resident])))
         c = workload.cluster
-        #: bytes of one lane's upload that are the residents' and not the
-        #: query's: their pod columns (the ``gpu_spec`` words among them,
-        #: where there are any) and mask, their heap and pod_state rows,
-        #: the cluster's four ``*_left`` arrays
+        #: bytes of one lane's upload that are the base's and not the
+        #: query's: its rows on the pod axis (pod columns, the
+        #: ``gpu_spec`` words among them where there are any, mask,
+        #: ``pod_state`` and as many rows of the heap, which is as wide
+        #: as the axis) and the cluster's four ``*_left`` arrays
         self.lane_bytes = int(
-            self.e0 * ((7 + (self.spec is not None)) * 4 + 1 + 16 + 16)
+            self.base * ((7 + (self.spec is not None)) * 4 + 1 + 16 + 16)
             + 4 * c.n_padded * (3 + c.g_padded))
 
     def stack(self, cluster, pod_lists: Sequence[Sequence[dict]],
               bucket: int, cfg, klen: int):
         """``stack_query_tables`` for forked queries, in NumPy throughout
-        (the one upload is the engine's h2d stage): ``(pods[Q, E0 +
+        (the one upload is the engine's h2d stage): ``(pods[Q, base +
         bucket], ktable[Q, K], state0[Q, ...])``, ``state0`` the exact
         engine's ``forked_state`` of each query. ``cfg.max_steps`` is
-        absolute: ``E0`` plus the bucket's budget."""
+        absolute: ``E0`` plus the bucket's budget. The per-query heap
+        replays lie in the ``serve/chunk/stack/heap_replay`` span (the
+        whole of ``forked_state``: the heap operations and a few array
+        fills)."""
         from fks_tpu.sim.engine import forked_state
 
         wls = [build_query_workload(cluster, p, bucket, self)
                for p in pod_lists]
         kt = _query_ktable(wls, cfg, klen)
-        states = [forked_state(w, cfg, self.prefix, kt[i])
-                  for i, w in enumerate(wls)]
+        with spans.span("serve/chunk/stack/heap_replay",
+                        queries=len(wls), events=self.e0):
+            states = [forked_state(w, cfg, self.prefix, kt[i])
+                      for i, w in enumerate(wls)]
         stack = lambda *xs: np.stack([np.asarray(x) for x in xs])  # noqa: E731
         # the ids are static pytree meta: dropped, as ``strip_ids`` does
         bare = [dataclasses.replace(w.pods, pod_ids=()) for w in wls]

@@ -202,21 +202,25 @@ class ServeService:
 
         The fork. An engine built on a workload that carries a snapshot
         (``fks_tpu.data.snapshot``; ``engine.fork``) answers every query
-        from the LOADED cluster: the snapshot's residents stay where it
-        put them and leave when their duration ends, and the query's
-        pods arrive among them. The snapshot belongs to the engine's
-        workload, never to a query, and the events before it are
-        decided: every pod's ``creation_time`` must be at or after the
-        snapshot's last arrival (``engine.fork.last_arrival``), on the
-        clock of the pod list the snapshot was taken from; an earlier
-        one is this request's ``ValueError`` (HTTP 400) at submit, before
-        it can reach a batch. The answer lists the query's pods only,
-        names those still ``waiting`` for a node when the run ended or
-        was cut at the bucket's step budget (which counts from the fork),
-        and reports the whole run's ``events``, ``scheduled``,
-        ``snapshots``, ``max_nodes``, ``utilization``, ``fragmentation``
-        and ``frag_events``, the residents' included, with
-        ``start_event`` saying where the champion took over."""
+        from that MOMENT of the cluster's run: the snapshot's residents
+        stay where it put them and leave when their duration ends, a pod
+        it left waiting comes back when its queued retry says, and the
+        query's pods arrive among them. The snapshot belongs to the
+        engine's workload, never to a query, and the events before it
+        have happened: every pod's ``creation_time`` must be at or after
+        the time of the snapshot's last event
+        (``engine.fork.not_before``), on the clock of the pod list the
+        snapshot was taken from; an earlier one is this request's
+        ``ValueError`` (HTTP 400) at submit, before it can reach a batch.
+        The answer lists the query's pods only, names those still
+        ``waiting`` for a node when the run ended or was cut at the
+        bucket's step budget (which counts from the fork), says whether
+        the run ``finished`` (an empty heap inside the budget: ``score``
+        is then the finished run's gated fitness) and reports the whole
+        run's ``events``, ``scheduled``, ``snapshots``, ``max_nodes``,
+        ``utilization``, ``fragmentation`` and ``frag_events``, the
+        prefix's included, with ``start_event`` saying where the
+        champion took over."""
         if not isinstance(query, dict):
             raise ValueError("query must be a JSON object")
         rid = str(query.get("id", ""))

@@ -28,14 +28,20 @@ population axis is added OUTSIDE via ``vmap`` (see fks_tpu.parallel).
 
 A workload that carries a ``snapshot`` (``fks_tpu.data.snapshot``) starts
 AFTER its ``E0`` events: ``initial_state`` returns ``forked_state``, the
-carry those events leave (the heap slot for slot CPython's own after the
-prefix, because the retry rule above reads it in array order; the
-residents on the pod axis). ``fork_prefix`` / ``fork_leaves`` hold the
-arithmetic that this engine and the flat one (``sim.flat._loaded_leaves``)
-share: the host replay of the prefix, whatever its events. The flat engine
-forks from all of it; this one from a prefix of placed CREATEs, and
-refuses a departure or a refusal among the events by name
-(``require_placed_creates``); the fused engine refuses every snapshot.
+carry those events leave, whatever they are (placed and refused CREATEs,
+retries, DELETEs). *A snapshot says what happened: the first E0 events of
+the run, timed by the rule the snapshot names; every later event is this
+engine's own: the policy decides each CREATE attempt and the heap-array
+rule above re-queues each refusal* (the one definition of a forked run,
+``fks_tpu.data.snapshot``). Because that rule reads the heap in array
+order, the heap at the fork is CPython's own after the prefix, slot for
+slot (``data.snapshot.heap_after``: the logged pops and pushes re-run on
+the real ``heapq`` among all the CREATEs of the workload at hand); the pod
+axis holds the residents, the waiting and the departed pods as the step
+leaves them. ``fork_prefix`` / ``fork_leaves`` hold the arithmetic that
+this engine and the flat one (``sim.flat._loaded_leaves``) share: the
+host replay of the prefix, whatever its events. The fused engine and the
+portfolio refuse every snapshot by name.
 """
 from __future__ import annotations
 
@@ -53,7 +59,6 @@ from fks_tpu.ops.allocator import best_fit_gpus, first_fit_gpus
 from fks_tpu.ops.heap import (
     KIND_CREATE, KIND_DELETE, KIND_NODE_DOWN, KIND_NODE_UP, EventHeap,
     first_deletion_in_array_order, heap_from_events, heap_pop, heap_push,
-    heap_rows_after_prefix,
 )
 from fks_tpu.sim.evaluator import max_snapshot_count, snapshot_trigger_table
 from fks_tpu.sim.guards import fitness_flags, guard_scores
@@ -324,47 +329,43 @@ def fork_leaves(workload: Workload, cfg: SimConfig, prefix=None,
         max_nodes=np.int32(prefix.max_nodes))
 
 
-def require_placed_creates(prefix, who: str) -> None:
-    """The refusal of everything that forks on the exact engine: its heap
-    after a prefix is CPython's own only where every event was a placed
-    CREATE (``ops.heap.heap_rows_after_prefix``), and its retry rule is
-    not the one a refusal in the prefix was re-queued under."""
-    if prefix.departed or prefix.refused:
-        raise ValueError(
-            f"snapshot: {who} forks from a prefix of placed CREATEs only; "
-            f"this one holds {prefix.departed} departures and "
-            f"{prefix.refused} refused placements. Candidate evaluation "
-            "on engine='flat' forks from it (ROADMAP R5)")
-
-
 def forked_state(workload: Workload, cfg: SimConfig, prefix=None,
                  ktable=None) -> SimState:
     """The exact engine's carry after the workload's snapshot, leaf for
-    leaf what ``build_step`` reaches when a policy makes those ``E0``
-    placements, as NumPy (``initial_state`` uploads it; serving stacks a
-    batch of them first). The heap is CPython's own after the prefix
-    (``ops.heap.heap_rows_after_prefix``: the retry rule reads it in array
-    order), which is why a prefix with a departure or a refusal is
-    refused by name (``require_placed_creates``); ``pod_state`` holds the
-    residents' node and GPU mask. ``prefix`` and ``ktable`` as in
-    ``fork_leaves``."""
-    c, p, snap = workload.cluster, workload.pods, workload.snapshot
+    leaf what ``build_step`` reaches when those ``E0`` events happen as
+    logged, as NumPy (``initial_state`` uploads it; serving stacks a
+    batch of them first). Any valid prefix: the heap is CPython's own
+    after its pops and pushes among ALL the workload's CREATEs
+    (``data.snapshot.heap_after``: the retry rule reads it in array order),
+    the pending retry of a waiting pod in it; ``pod_state`` holds what
+    the step leaves in a pod's row: node and GPU bits of a placed pod
+    (a departed one keeps them), ``COL_WAIT`` of a pod refused and not
+    placed since, ``COL_CTIME`` as the retries moved it. ``prefix`` and
+    ``ktable`` as in ``fork_leaves``; a ``prefix`` may end before the
+    workload's pod axis does (serving's: the pods behind it are a
+    query's, untouched by the events)."""
+    from fks_tpu.data.snapshot import heap_after, heap_key
+
+    p = workload.pods
     if prefix is None:
         prefix = fork_prefix(workload)
-    require_placed_creates(prefix, "the exact engine")
     shared = fork_leaves(workload, cfg, prefix, ktable)
     real = np.flatnonzero(np.asarray(p.pod_mask))
-    rows, size = heap_rows_after_prefix(
-        np.asarray(p.creation_time)[real], np.asarray(p.tie_rank)[real],
-        real, np.asarray(p.duration), snap.e0, capacity=p.p_padded)
-    pp = p.p_padded
-    pod_state = np.zeros((pp, 4), np.int32)
+    rank = np.asarray(p.tie_rank, np.int64)[real]
+    pod_of_rank = np.zeros(int(rank.max(initial=-1)) + 1, np.int64)
+    pod_of_rank[rank] = real
+    rows, size = heap_after(
+        heap_key(np.asarray(p.creation_time, np.int64)[real], rank,
+                 KIND_CREATE),
+        prefix.pushes, pod_of_rank, capacity=p.p_padded)
+    pod_state = np.zeros((p.p_padded, 4), np.int32)
     pod_state[:, SimState.COL_NODE] = -1
     pod_state[:, SimState.COL_CTIME] = np.asarray(p.creation_time)
-    res = np.asarray(snap.pod, np.int64)
-    pod_state[res, SimState.COL_NODE] = np.asarray(snap.node)
-    pod_state[res, SimState.COL_BITS] = np.asarray(
-        snap.gpus, np.uint32).view(np.int32)
+    known = pod_state[:len(prefix.node)]
+    known[:, SimState.COL_NODE] = prefix.node
+    known[:, SimState.COL_BITS] = prefix.gpus.astype(np.uint32).view(np.int32)
+    known[:, SimState.COL_CTIME] = prefix.ctime
+    known[:, SimState.COL_WAIT] = prefix.waiting
     return SimState(
         heap=EventHeap(data=rows, size=np.int32(size)),
         pod_state=pod_state, failed=np.bool_(False),

@@ -93,17 +93,19 @@ def test_benchmark_json_only_gained_entries():
                  bench["workloads"][8]["why"]):
         assert len(text) <= 200
     # a source of its own among the configurations
-    assert len({c["source"] for c in bench["configs"]}) == 8
-    assert len(bench["workloads"]) == 10 and len(bench["configs"]) == 8
+    assert len({c["source"] for c in bench["configs"]}) == 9
+    assert len(bench["workloads"]) == 11 and len(bench["configs"]) == 9
     assert sum(w["chips"] == 4 for w in bench["workloads"]) == 1
     # at the end as PR 45 left it; PR 46 appended the interpreter's
     # slots a turn after, PR 47 its narrow turns' share, PR 49 the typed
-    # query pods' share, PR 51 the check a source and the uploads a call
-    assert [m["name"] for m in bench["per_layer"][-6:]] == [
+    # query pods' share, PR 51 the check a source and the uploads a call,
+    # PR 52 the mid-run what-if cell's three
+    assert [m["name"] for m in bench["per_layer"][-9:]] == [
         NEW, "vm.slots_per_turn", "vm.narrow_turn_share",
         "serve.typed_pod_share", "tier.check_ms_per_source",
-        "tier.uploads_per_call"]
-    new = bench["per_layer"][-6]
+        "tier.uploads_per_call", "serve.heap_replay_ms_per_call",
+        "serve.fork_waiting_pods", "serve.finished_lane_share"]
+    new = bench["per_layer"][-9]
     meta = json.load(open(os.path.join(cells.HERE, "metrics",
                                        NEW + ".json")))
     assert new == {**{k: meta[k] for k in (
@@ -118,7 +120,8 @@ def test_benchmark_json_only_gained_entries():
         lists = m.get("workloads", [])
         assert (CELL in lists) == (MIDRUN in lists), m["name"]
         if CELL in lists:    # last of the cells there were at PR 45
-            assert [w for w in lists if w != WHATIF][-1] == CELL
+            assert [w for w in lists
+                    if w not in (WHATIF, "openb16-cpu250-midrun.whatif8")][-1] == CELL
 
 
 def test_the_new_reader_finds_nothing_in_a_program_without_its_field():

@@ -27,8 +27,9 @@ CODE = ["openb16.codegen8", "openb16.codegen8x4",
         "openb16-cpu250-midrun.codegen8",
         "openb1523-gpuspec25-loaded.codegen8"]
 WHATIF = ["openb1523.whatif8", "openb1523-loaded.whatif8",
-          # appended by PR 49, with its cell
-          "openb1523-gpuspec25-loaded.whatif8"]
+          # appended by PR 49 and by PR 52, each with its cell
+          "openb1523-gpuspec25-loaded.whatif8",
+          "openb16-cpu250-midrun.whatif8"]
 TIER = "candidate tiers funsearch/backend.py"
 METRICS = {
     "tier.lower_ms_per_source": ("ms", "program_span", TIER, CODE),
@@ -206,7 +207,7 @@ def test_uploads_are_the_packs_and_the_stacks_fields_summed(uploads, stack,
 
 def test_the_two_of_pr51_are_declared_with_their_files():
     bench = json.load(open(os.path.join(cells.ROOT, "BENCHMARK.json")))
-    two = bench["per_layer"][-2:]
+    two = bench["per_layer"][-5:-3]     # PR 52 appended its three after
     assert [m["name"] for m in two] == ["tier.check_ms_per_source",
                                         "tier.uploads_per_call"]
     for m, (unit, source) in zip(two, (("ms", "program_span"),
@@ -222,7 +223,7 @@ def test_the_two_of_pr51_are_declared_with_their_files():
         assert cells.metric_reader(m["name"])({}) is None
     for name in CODE:
         listed = [m["name"] for m in cells.load_cell(name).per_layer]
-        assert listed[-2:] == [m["name"] for m in two]
+        assert listed[-2:] == [m["name"] for m in two]   # no serving metric
     for name in WHATIF + ["openb16.param256"]:
         assert not {m["name"] for m in two} & {
             m["name"] for m in cells.load_cell(name).per_layer}
@@ -319,14 +320,16 @@ def test_the_seven_are_declared_at_the_end_with_their_files():
     # PR 44 the interpreter's merged-read share, PR 45 the typed pods',
     # PR 46 the interpreter's slots a turn, PR 47 its narrow turns' share,
     # PR 49 the typed query pods' share, PR 51 the check a source and the
-    # uploads a call
+    # uploads a call, PR 52 the mid-run what-if cell's three
     seven = bench["per_layer"][41:41 + 7]
     assert [m["name"] for m in seven] == list(METRICS)
     assert [m["name"] for m in bench["per_layer"][41 + 7:]] == [
         "sim.fork_replay_us_per_event", "sim.fork_waiting_pods",
         "vm.merged_read_share", "sim.typed_pod_share", "vm.slots_per_turn",
         "vm.narrow_turn_share", "serve.typed_pod_share",
-        "tier.check_ms_per_source", "tier.uploads_per_call"]
+        "tier.check_ms_per_source", "tier.uploads_per_call",
+        "serve.heap_replay_ms_per_call", "serve.fork_waiting_pods",
+        "serve.finished_lane_share"]
     layers = {m["layer"] for m in bench["per_layer"][:41]}
     for m in seven:
         unit, source, layer, workloads = METRICS[m["name"]]

@@ -90,7 +90,8 @@ def test_benchmark_json_only_gained_entries():
     others = bench["per_layer"][:at] + bench["per_layer"][at + 2:]
     later = ("openb1523-loaded.whatif8", "openb16-cpu250-midrun.codegen8",
              "openb1523-gpuspec25-loaded.codegen8",
-             "openb1523-gpuspec25-loaded.whatif8")
+             "openb1523-gpuspec25-loaded.whatif8",
+             "openb16-cpu250-midrun.whatif8")
     for m in bench["end_to_end"] + others:
         lists = m.get("workloads", [])
         assert (CELL in lists) == (

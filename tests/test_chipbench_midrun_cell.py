@@ -86,18 +86,21 @@ def test_benchmark_json_only_gained_entries():
     for text in (bench["configs"][5]["why"], bench["configs"][5]["source"],
                  bench["workloads"][7]["why"]):
         assert len(text) <= 200
-    assert len(bench["workloads"]) == 10 and len(bench["configs"]) == 8
+    assert len(bench["workloads"]) == 11 and len(bench["configs"]) == 9
     assert sum(w["chips"] == 4 for w in bench["workloads"]) == 1
     at = [m["name"] for m in bench["per_layer"]].index(NEW[0])
     new = bench["per_layer"][at:at + 2]
     assert [m["name"] for m in new] == list(NEW)
     # appended since, at the end: PR 44's metric of the code cells,
     # PR 45's of the typed cell, PR 46's and PR 47's of the code cells,
-    # PR 49's of the typed what-if cell and PR 51's two of the code cells
+    # PR 49's of the typed what-if cell, PR 51's two of the code cells
+    # and PR 52's three of the what-if cell on this cell's snapshot
     assert [m["name"] for m in bench["per_layer"][at + 2:]] == [
         "vm.merged_read_share", "sim.typed_pod_share", "vm.slots_per_turn",
         "vm.narrow_turn_share", "serve.typed_pod_share",
-        "tier.check_ms_per_source", "tier.uploads_per_call"]
+        "tier.check_ms_per_source", "tier.uploads_per_call",
+        "serve.heap_replay_ms_per_call", "serve.fork_waiting_pods",
+        "serve.finished_lane_share"]
     later = "openb1523-gpuspec25-loaded.codegen8"   # PR 45's forked cell
     for m in new:
         assert m["workloads"] == [CELL, later]
@@ -115,7 +118,8 @@ def test_benchmark_json_only_gained_entries():
         assert (CELL in lists) == (FORKED in lists), m["name"]
         if CELL in lists:   # last of the cells there were at PR 42
             assert [w for w in lists if w not in (
-                later, "openb1523-gpuspec25-loaded.whatif8")][-1] == CELL
+                later, "openb1523-gpuspec25-loaded.whatif8",
+                "openb16-cpu250-midrun.whatif8")][-1] == CELL
 
 
 def test_new_readers_find_nothing_in_a_program_without_their_fields():

@@ -367,8 +367,9 @@ def test_every_span_metric_is_declared_with_its_files():
     # vm.merged_read_share (PR 44), sim.typed_pod_share (PR 45),
     # vm.slots_per_turn (PR 46), vm.narrow_turn_share (PR 47),
     # serve.typed_pod_share (PR 49), tier.check_ms_per_source and
-    # tier.uploads_per_call (PR 51)
-    assert len(SPAN_METRICS) == 36
+    # tier.uploads_per_call (PR 51), serve.heap_replay_ms_per_call,
+    # serve.fork_waiting_pods and serve.finished_lane_share (PR 52)
+    assert len(SPAN_METRICS) == 39
     for name in SPAN_METRICS:
         assert name in declared, name
         meta = json.load(open(os.path.join(cells.HERE, "metrics",

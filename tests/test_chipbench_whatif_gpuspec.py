@@ -79,28 +79,31 @@ def test_the_cell_is_declared_with_its_files():
 def test_benchmark_json_only_gained_entries():
     with open(os.path.join(cells.ROOT, "BENCHMARK.json")) as f:
         bench = json.load(f)
-    assert bench["configs"][-1] == {
+    assert bench["configs"][-2] == {
         "name": "openb1523-gpuspec25-loaded-snapshot",
         "source": cells.load_cell(CELL).config["source"],
         "file": "chipbench/configs/openb1523-gpuspec25-loaded-snapshot.json",
         "reduced": ["max_steps_factor"],
-        "why": bench["configs"][-1]["why"]}
-    assert bench["workloads"][-1] == {
+        "why": bench["configs"][-2]["why"]}
+    assert bench["workloads"][-2] == {
         "name": CELL, "config": "openb1523-gpuspec25-loaded-snapshot",
         "traffic": "whatif8-gpuspec", "chips": 1,
-        "why": bench["workloads"][-1]["why"]}
-    for text in (bench["configs"][-1]["why"], bench["configs"][-1]["source"],
-                 bench["workloads"][-1]["why"]):
+        "why": bench["workloads"][-2]["why"]}
+    for text in (bench["configs"][-2]["why"], bench["configs"][-2]["source"],
+                 bench["workloads"][-2]["why"]):
         assert len(text) <= 200
     # a source of its own among the configurations
-    assert len({c["source"] for c in bench["configs"]}) == 8
-    assert len(bench["workloads"]) == 10 and len(bench["configs"]) == 8
+    assert len({c["source"] for c in bench["configs"]}) == 9
+    assert len(bench["workloads"]) == 11 and len(bench["configs"]) == 9
     assert sum(w["chips"] == 4 for w in bench["workloads"]) == 1
     # at the end as PR 49 left it; PR 51 appended the code cells' check
-    # a source and uploads a call after
-    assert [m["name"] for m in bench["per_layer"][-3:]] == [
-        NEW, "tier.check_ms_per_source", "tier.uploads_per_call"]
-    new = bench["per_layer"][-3]
+    # a source and uploads a call after, PR 52 the mid-run what-if
+    # cell's three
+    assert [m["name"] for m in bench["per_layer"][-6:]] == [
+        NEW, "tier.check_ms_per_source", "tier.uploads_per_call",
+        "serve.heap_replay_ms_per_call", "serve.fork_waiting_pods",
+        "serve.finished_lane_share"]
+    new = bench["per_layer"][-6]
     meta = json.load(open(os.path.join(cells.HERE, "metrics",
                                        NEW + ".json")))
     assert new == {**{k: meta[k] for k in (
@@ -109,12 +112,13 @@ def test_benchmark_json_only_gained_entries():
     assert (new["name"], new["layer"], new["moves"]) \
         == (NEW, "serving serve/", "whatif_pods_per_s")
     # appended to every list that held the control, at its end
-    for m in bench["end_to_end"] + bench["per_layer"][:-3] \
-            + bench["per_layer"][-2:]:
+    for m in bench["end_to_end"] + bench["per_layer"][:-6] \
+            + bench["per_layer"][-5:]:
         lists = m.get("workloads", [])
+        # PR 52's waiting pods and finished lanes are its own cell's
         assert (CELL in lists) == (CONTROL in lists), m["name"]
-        if CELL in lists:
-            assert lists[-1] == CELL
+        if CELL in lists:   # last of the cells there were at PR 49
+            assert [w for w in lists if w != "openb16-cpu250-midrun.whatif8"][-1] == CELL
 
 
 def test_the_new_reader_finds_nothing_on_spans_without_its_field():

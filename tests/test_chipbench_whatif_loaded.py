@@ -58,8 +58,9 @@ def test_the_cell_is_declared_with_its_files():
     assert [m["name"] for m in cell.end_to_end] == ["whatif_pods_per_s",
                                                     "setup_s"]
     mine = {m["name"] for m in cell.per_layer}
+    # PR 52's heap replay is on this cell's stacking path too
     assert mine == {m["name"] for m in cells.load_cell(SIBLING).per_layer} \
-        | set(NEW)
+        | set(NEW) | {"serve.heap_replay_ms_per_call"}
 
 
 def test_benchmark_json_only_gained_entries():
@@ -96,24 +97,29 @@ def test_benchmark_json_only_gained_entries():
         # PR 49's share of query pods that name their GPU models
         "serve.typed_pod_share",
         # PR 51's check a source and uploads a call of the code cells
-        "tier.check_ms_per_source", "tier.uploads_per_call"]
+        "tier.check_ms_per_source", "tier.uploads_per_call",
+        # PR 52's three of the what-if cell forked mid-run
+        "serve.heap_replay_ms_per_call", "serve.fork_waiting_pods",
+        "serve.finished_lane_share"]
     new = bench["per_layer"][at:at + 2]
-    # PR 49's forked cell reads both too
+    # PR 49's and PR 52's forked cells read both too
     typed = "openb1523-gpuspec25-loaded.whatif8"
+    midrun = "openb16-cpu250-midrun.whatif8"
     for m in new:
-        assert m["workloads"] == [CELL, typed]
+        assert m["workloads"] == [CELL, typed, midrun]
         assert m["layer"] == "serving serve/"
     assert [m["moves"] for m in new] == ["setup_s", "whatif_pods_per_s"]
     for m in bench["end_to_end"] + bench["per_layer"]:
-        if m in new:
+        if m in new or m["name"] == "serve.heap_replay_ms_per_call":
             continue
         lists = m.get("workloads", [])
         assert (CELL in lists) == (SIBLING in lists), m["name"]
         if CELL in lists:   # last of the cells there were at PR 37
             assert [w for w in lists if w not in (
                 "openb16-cpu250-midrun.codegen8",
-                "openb1523-gpuspec25-loaded.codegen8", typed)][-1] == CELL
-    assert len(bench["workloads"]) == 10
+                "openb1523-gpuspec25-loaded.codegen8", typed,
+                midrun)][-1] == CELL
+    assert len(bench["workloads"]) == 11
     assert sum(w["chips"] == 4 for w in bench["workloads"]) == 1
 
 

@@ -5,7 +5,10 @@ through ``ServeService`` against the plain reference
 (``chipbench/reference/forked_query.py`` around ``simulate_from``,
 ``retry="heap_array"``). The flat engine's fork is
 ``tests/test_snapshot_carry.py``; the benchmark's cell on this path is
-``tests/test_chipbench_whatif_loaded.py``."""
+``tests/test_chipbench_whatif_loaded.py``. The fork from a MOMENT of a
+run (departures, refusals, a waiting pod in the prefix) against
+``chipbench/reference/forked_query_midrun.py`` is
+``tests/test_serve_fork_midrun.py``."""
 import dataclasses
 import hashlib
 import heapq
@@ -23,7 +26,6 @@ from fks_tpu.data.entities import Workload
 from fks_tpu.data.snapshot import from_placements
 from fks_tpu.funsearch import transpiler
 from fks_tpu.models import zoo
-from fks_tpu.ops import heap as heap_ops
 from fks_tpu.serve import (ServeService, ShapeEnvelope, VMServeEngine,
                            load_champion)
 from fks_tpu.serve.batcher import (QueryFork, build_query_workload,
@@ -107,9 +109,43 @@ def test_fork_heap_is_cpythons_slot_for_slot_on_16_nodes(e0):
     assert np.array_equal(np.asarray(stepped.heap.data)[:size], want)
 
 
-def test_a_prefix_that_pops_a_delete_is_refused():
-    with pytest.raises(ValueError, match="not 2 CREATEs"):
-        heap_ops.heap_rows_after_prefix([0, 5], [0, 1], [0, 1], [1, 1], 2)
+def test_a_prefix_that_pops_a_delete_forks_with_cpythons_heap():
+    """The inputs the placed-CREATE replay refused until PR 52 (two pods
+    at 0 and 5 that hold for 1: the prefix of 2 events is C0 D0) now
+    fork: the one replay re-runs whatever the prefix pushed, and the
+    exact engine's heap after it is ``heapq``'s list."""
+    from fks_tpu.data import snapshot as snap_mod
+    from fks_tpu.data.build import make_workload
+    from fks_tpu.data.snapshot import replay
+    from fks_tpu.sim import flat
+
+    wl = make_workload(
+        [{"node_id": "n0", "cpu_milli": 4000, "memory_mib": 4096,
+          "gpus": []}],
+        [{"pod_id": f"p{i}", "cpu_milli": 10, "memory_mib": 10,
+          "num_gpu": 0, "gpu_milli": 0, "creation_time": t,
+          "duration_time": 1} for i, t in enumerate((0, 5))])
+    snap = flat.make_snapshot(wl, zoo.first_fit(), 2)
+    prefix = replay(wl, snap)
+    key = snap_mod.heap_key
+    # event 0 pops C0 and pushes D0 at 1, event 1 pops it and pushes none
+    assert prefix.pushes == [key(1, 0, 1), None] and prefix.departed == 1
+    want = [(0, 0, 0, 0), (5, 1, 0, 1)]         # (time, rank, kind, pod)
+    heapq.heapify(want)
+    for item in ((1, 0, 1, 0), None):
+        heapq.heappop(want)
+        if item:
+            heapq.heappush(want, item)
+    assert want == [(5, 1, 0, 1)] and prefix.heap == [key(5, 1, 0)]
+    rows, size = snap_mod.heap_after(
+        [key(0, 0, 0), key(5, 1, 0)], prefix.pushes, [0, 1], capacity=4)
+    assert size == 1 and rows[:1].tolist() == [[5, 1, 0, 1]]
+    state = exact.initial_state(dataclasses.replace(wl, snapshot=snap),
+                                SimConfig())
+    assert int(state.heap.size) == 1 and int(state.steps) == 2
+    assert np.asarray(state.heap.data)[0].tolist() == [5, 1, 0, 1]
+    # the departed pod's row is what the step leaves: its node kept
+    assert np.asarray(state.pod_state)[0].tolist() == [0, 0, 0, 0]
 
 
 # -------- exact-engine fork identity
@@ -119,13 +155,36 @@ def _policies():
             "champion": transpiler.transpile(_champion().code)}
 
 
-@pytest.mark.parametrize("name", ["first_fit", "best_fit", "champion"])
-def test_exact_fork_is_the_whole_run(pressure, name):
-    """Policy ``p`` run whole == the snapshot of ``p``'s first ``e0``
-    placements, then ``p`` from the snapshot: the carry at the fork leaf
-    by leaf (the live heap slot for slot) and the ``SimResult`` at the
-    end bit for bit, on a trace whose retries all lie after the fork."""
+@pytest.fixture(scope="module")
+def churn(pressure):
+    """The pressured deployment with short lives: the pods hold for 5-60
+    seconds, so the first 200 events of a run hold departures, and the
+    cluster never fills before the fork: no refusal, and the prefix is
+    the same run under every retry rule (``rule`` "")."""
     _, wl = pressure
+    p = wl.pods
+    rng = np.random.default_rng(11)
+    return dataclasses.replace(wl, pods=dataclasses.replace(
+        p, duration=np.where(p.pod_mask, rng.integers(5, 60, p.p_padded),
+                             0).astype(np.asarray(p.duration).dtype)))
+
+
+@pytest.mark.parametrize("name,trace", [
+    ("first_fit", "pressure"), ("best_fit", "pressure"),
+    ("champion", "pressure"), ("first_fit", "churn"),
+    ("champion", "churn")])
+def test_exact_fork_is_the_whole_run(pressure, churn, name, trace):
+    """Policy ``p`` run whole == the snapshot of ``p``'s first ``e0``
+    events, then ``p`` from the snapshot: the carry at the fork leaf
+    by leaf (the live heap slot for slot) and the ``SimResult`` at the
+    end bit for bit, on a trace whose retries all lie after the fork;
+    ``churn``: on one whose prefix holds DEPARTURES and no refusal (the
+    exact engine's own run of the same decisions reaches the fork, so the
+    departed pods' rows, the refunds and the heap after DELETE pops are
+    held to the step's own)."""
+    from fks_tpu.sim import flat
+
+    wl = churn if trace == "churn" else pressure[1]
     pol = _policies()[name]
     e0 = 200
     cfg = SimConfig(node_prefilter_k=RULE)
@@ -141,8 +200,13 @@ def test_exact_fork_is_the_whole_run(pressure, name):
     finish = jax.jit(lambda s: exact.finalize(wl, cfg, s))
     stepped = advance(exact.initial_state(wl, cfg), e0)
     assert int(stepped.frag_count) == 0
-    forked = dataclasses.replace(wl, snapshot=from_placements(
-        wl, e0, stepped.assigned_node, stepped.assigned_gpus))
+    if trace == "churn":
+        snap = flat.make_snapshot(wl, pol, e0, cfg)
+        assert snap.rule == "" and 40 < len(snap.pod) < e0   # departures
+    else:
+        snap = from_placements(wl, e0, stepped.assigned_node,
+                               stepped.assigned_gpus)
+    forked = dataclasses.replace(wl, snapshot=snap)
     loaded = exact.initial_state(forked, cfg)
     live = int(stepped.heap.size)
     for (path, a), (_, b) in zip(
@@ -158,7 +222,8 @@ def test_exact_fork_is_the_whole_run(pressure, name):
     for a, b in zip(jax.tree_util.tree_leaves(whole),
                     jax.tree_util.tree_leaves(from_fork)):
         assert np.array_equal(np.asarray(a), np.asarray(b)), name
-    assert int(whole.num_fragmentation_events) > 0      # a retry cascade
+    if trace == "pressure":
+        assert int(whole.num_fragmentation_events) > 0  # a retry cascade
     assert float(whole.policy_score) > 0
 
 
@@ -283,7 +348,9 @@ def test_forked_serving_uncut_finishes_with_the_references_fitness(forked):
 def test_a_query_pod_created_before_the_fork_is_a_4xx(forked):
     fwl = forked[0]
     engine = _engine(fwl, 1)
-    last = engine.fork.last_arrival
+    last = engine.fork.not_before
+    assert last == int(np.asarray(fwl.pods.creation_time)[
+        np.asarray(fwl.snapshot.pod)[-1]])      # the last prefix event
     service = ServeService(engine, max_batch=2, max_wait_s=0.01)
     try:
         with pytest.raises(ValueError, match="lies before the fork"):
@@ -314,6 +381,16 @@ def test_the_forked_stack_is_initial_state_of_each_query(forked):
     pods, kt, s0 = stack_query_tables(exact, fwl.cluster, queries, 16, cfg,
                                       40, fork)
     assert pods.cpu.shape == (2, E0 + 16) and kt.shape == (2, 40)
+    # a prefix of placed CREATEs through the general fork (PR 52: any
+    # valid prefix, one heap replay): the bytes of every leaf as PR 52's
+    # parent commit (0ff8270) stacks them, and of ``forked_state``
+    assert _digest((pods, kt, s0)) == (
+        "ad5d89764c07ef25176955a9c82f6b8ac0f5fd07a741adf242cb98ad1cce0b39")
+    assert _digest(exact.forked_state(
+        fwl, SimConfig(node_prefilter_k=RULE))) == (
+        "0645dd2d1af1f689557c1f5419046272445ea10ea22b4a5b7604feb8fded4c51")
+    assert (fork.base, fork.residents, fork.waiting, fork.lane_bytes) \
+        == (E0, E0, 0, 29330)
     for lane, q in enumerate(queries):
         wl = build_query_workload(fwl.cluster, q, 16, fork)
         assert wl.num_pods == E0 + len(q)
@@ -327,13 +404,22 @@ def test_the_forked_stack_is_initial_state_of_each_query(forked):
                               loop_tables(wl, cfg)[0])
 
 
+def _digest(tree) -> str:
+    h = hashlib.sha256()
+    for leaf in jax.tree_util.tree_leaves(tree):
+        a = np.asarray(leaf)
+        h.update(str((a.dtype, a.shape)).encode())
+        h.update(np.ascontiguousarray(a).tobytes())
+    return h.hexdigest()
+
+
 def test_a_forked_engine_survives_save_and_load(forked, tmp_path):
     fwl = forked[0]
     engine = _engine(fwl, 1)
     engine.save(str(tmp_path))
     again = VMServeEngine.load(str(tmp_path))
     assert again.fork is not None and again.fork.e0 == E0
-    assert again.fork.last_arrival == engine.fork.last_arrival
+    assert again.fork.not_before == engine.fork.not_before
     assert np.array_equal(again.fork.rank, engine.fork.rank)
     assert again.bucket_config(64).max_steps == E0 + 64
 
@@ -358,14 +444,9 @@ def test_without_a_snapshot_the_stacked_tables_are_todays_bytes():
     rows = pods_to_dicts(wl.pods, limit=64)
     queries = [rows[:5], rows[7:23], rows[30:33]]
     cfg = SimConfig(max_steps=128, wait_hist_size=1001)
-    h = hashlib.sha256()
-    for leaf in jax.tree_util.tree_leaves(stack_query_tables(
-            exact, wl.cluster, queries, 16, cfg, 900)):
-        a = np.asarray(leaf)
-        h.update(str((a.dtype, a.shape)).encode())
-        h.update(np.ascontiguousarray(a).tobytes())
-    assert h.hexdigest() == ("e81c60f356716b9437252de1365d7e3b540a5a420ec0"
-                             "b18fdcf4b5a1f1ef71ce")
+    assert _digest(stack_query_tables(
+        exact, wl.cluster, queries, 16, cfg, 900)) == (
+        "e81c60f356716b9437252de1365d7e3b540a5a420ec0b18fdcf4b5a1f1ef71ce")
 
 
 def test_without_a_snapshot_an_answer_has_todays_keys():

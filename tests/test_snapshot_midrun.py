@@ -2,9 +2,10 @@
 the committed file of ``openb16-cpu250-midrun`` and its bytes, the file
 format with its two further columns, the plain reference's fork
 (``chipbench/reference/plain_sim_midrun.py``) against its unedited
-``simulate`` and what it refuses, and who refuses a prefix with a
-departure or a refusal by name. The forked carry leaf by leaf is
-``tests/test_snapshot_carry.py``, the forked runners
+``simulate`` and what it refuses, and that every engine that forks
+forks from a prefix with a departure or a refusal (the exact engine and
+serving since PR 52: ``tests/test_serve_fork_midrun.py``). The forked
+carry leaf by leaf is ``tests/test_snapshot_carry.py``, the forked runners
 ``tests/test_snapshot_tiers.py``, the invalid logs
 ``tests/test_snapshot.py``."""
 import dataclasses
@@ -259,35 +260,47 @@ def test_a_prefix_of_a_midrun_snapshot_is_one():
             assert np.array_equal(getattr(got, f), getattr(want, f)), e0
 
 
-# --------------------------------------- who refuses what, by name
+# ------------------------------- every engine that forks, forks from it
 
-def test_the_exact_engine_and_serving_refuse_a_departure_or_a_refusal():
-    """One message, before any device program: the exact engine's heap
-    after a prefix is CPython's own only for placed CREATEs."""
-    import jax
-
+def test_the_exact_engine_and_serving_fork_from_a_departure_or_a_refusal():
+    """The two prefixes that the exact engine and serving refused by name
+    until PR 52 (C0 D0, and C0 D0 C1 C2 C3 with the last refused and its
+    retry queued): ``initial_state`` and ``QueryFork`` now take them, and
+    the exact engine's run from each to the end is the plain reference's
+    (``plain_sim_fork``: the log's events as logged, then free under
+    ``heap_array``). The fused engine goes on refusing every snapshot."""
+    from chipbench.reference import plain_sim_fork
     from fks_tpu.serve.batcher import QueryFork
+    from fks_tpu.sim import fused
 
     wl = _tiny((1, 50, 50, 50))
     full = flat.make_snapshot(wl, _first_fit(), 5)
-    real_jit = jax.jit
-    for e0, held in ((2, "1 departures and 0 refused"),
-                     (5, "1 departures and 1 refused")):
-        forked = dataclasses.replace(wl, snapshot=snap_mod.head(full, e0))
-        jax.jit = None      # nothing may reach a program
-        try:
-            for who, build in (
-                    ("the exact engine",
-                     lambda: exact.initial_state(forked, SimConfig())),
-                    ("serving", lambda: QueryFork(forked))):
-                with pytest.raises(ValueError, match="snapshot: ") as e:
-                    build()
-                assert f"{who} forks from a prefix of placed CREATEs " \
-                    "only" in str(e.value)
-                assert held in str(e.value) and "ROADMAP R5" in str(e.value)
-        finally:
-            jax.jit = real_jit
+    cluster, pods = _reference(wl)
+    for e0, held in ((2, (1, 0, 0, 0)), (5, (1, 1, 2, 1))):
+        snap = snap_mod.head(full, e0)
+        forked = dataclasses.replace(wl, snapshot=snap)
+        state = exact.initial_state(forked, SimConfig())
+        assert int(state.steps) == int(state.events_processed) == e0
         assert int(flat.initial_state(forked, SimConfig()).steps) == e0
-    # what it takes today it still takes, cut from the same snapshot
-    one = dataclasses.replace(wl, snapshot=snap_mod.head(full, 1))
-    assert int(exact.initial_state(one, SimConfig()).steps) == 1
+        fork = QueryFork(forked)
+        assert (fork.prefix.departed, fork.prefix.refused, fork.residents,
+                fork.waiting) == held
+        assert (fork.e0, fork.base) == (e0, 1 if e0 == 2 else 4)
+        log = mid.Log([(int(i), int(nd), int(g)) for i, nd, g in zip(
+            snap.pod, snap.node, snap.gpus)], e0, snap.rule)
+        ref, waiting = plain_sim_fork.simulate(
+            cluster, pods, log, policies.first_fit, retry="heap_array")
+        res = exact.simulate(forked, _first_fit(), SimConfig())
+        assert np.array_equal(np.asarray(res.assigned_node)[:4],
+                              ref.assigned_node)
+        assert np.array_equal(np.asarray(res.assigned_gpus)[:4],
+                              ref.assigned_gpus)
+        assert (int(res.events_processed), int(res.scheduled_pods),
+                int(res.num_fragmentation_events), bool(res.truncated)) \
+            == (ref.events_processed, ref.scheduled_pods,
+                ref.num_frag_events, ref.truncated) == (9, 4, 1, False)
+        assert not waiting.any()
+        np.testing.assert_allclose(float(res.policy_score),
+                                   ref.policy_score, rtol=2e-6)
+        with pytest.raises(ValueError, match="snapshot: flat engine only"):
+            fused._build_plan(forked, SimConfig())
