@@ -797,13 +797,16 @@ class CodeEvaluator:
             # source shows, as a key or fingerprint echo's does
             # (chipbench: tier.traces_per_source); ops_lowered /
             # ops_kept: what vm.simplify_ops was given and what it left
-            # to pack (chipbench: vm.ops_kept_share). clock_misfit: 1
+            # to pack (chipbench: vm.ops_kept_share), chains_folded: the
+            # whole column chains it folded on the way. clock_misfit: 1
             # where the workers' stamps were refused and no child written
             ht.span.set(sources=len(unique), clock_misfit=int(misfit),
                         traces=sum(low.traces for low in lowered),
                         ops_lowered=sum(low.ops_lowered for low in lowered),
                         ops_kept=sum(len(low.kept[0]) for low in lowered
                                      if low.kept is not None),
+                        chains_folded=sum(low.chains_folded
+                                          for low in lowered),
                         pooled=sum(by_text[code].sent is not None
                                    for code in unique.values()),
                         workers=pool["workers"])

@@ -108,6 +108,9 @@ class Lowered(NamedTuple):
     t_traced: float = 0.0  # ``vm.lower_ops`` was through (or raised)
     t1: float = 0.0  # ``vm.simplify_ops`` was through (the words follow)
     eqns: int = 0  # equations of the traced jaxpr (vm.eqns_traced)
+    # whole column chains simplify_ops folded into their grid
+    # (vm.chains_folded): 5 for a ledger champion
+    chains_folded: int = 0
     # the parent's own stamps around a worker's task (before the send,
     # after the receive); None for a source lowered in process
     sent: Optional[float] = None
@@ -168,11 +171,12 @@ def lower_source(source: Union[str, Source], n: int, g: int) -> Lowered:
     if low.rejection is not None:
         return low
     runs0, eqns0 = transpiler.body_runs(), vm.eqns_traced()
+    folds0 = vm.chains_folded()
     kept, lowered, error, t_traced = None, 0, None, None
     try:
         ops, consts, out_reg = vm.lower_ops(source.code, n, g)
         t_traced = time.perf_counter()
-        kept, lowered = vm.simplify_ops(ops, consts, out_reg), len(ops)
+        kept, lowered = vm.simplify_ops(ops, consts, out_reg, g), len(ops)
     except (vm.VMUnsupported, transpiler.TranspileError) as e:
         error = type(e)(str(e))
     except Exception as e:  # noqa: BLE001 — untrusted code
@@ -183,6 +187,7 @@ def lower_source(source: Union[str, Source], n: int, g: int) -> Lowered:
         traces=transpiler.body_runs() - runs0,
         t_traced=t1 if t_traced is None else t_traced, t1=t1,
         eqns=vm.eqns_traced() - eqns0,
+        chains_folded=vm.chains_folded() - folds0,
         words=None if kept is None else vm.pack_words(*kept))
 
 

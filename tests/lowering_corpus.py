@@ -11,14 +11,19 @@ exception's class and message. ``mixed_batch_records`` is what
 .evaluate`` on a generation of valid sources, subset violations, a
 VMUnsupported source and a syntax error, every field of every record.
 ``echo_generation`` is what ``tests/fixtures/echo_generation.json`` records
-(``... echoes``, from PR 51's PARENT).
+(``... echoes``, from PR 51's PARENT; PR 53 replaced the hash of the ONE
+lane whose program its simplifier shortens, the champion's, and nothing
+else: records, events, counters and lane order are that parent's).
 ``python -m tests.lowering_corpus lowering|records`` prints the pins of the
 tree it runs on (``... primitives``: `flat_primitives` of three policies,
 ``tests/fixtures/policy_primitives.json``, recorded from PR 50's parent). The records were recorded from PR 28's PARENT, whose
 ``compile_policy`` still dry-traced at 2 x 2 before it traced at the real
 shape; the lowering pins were re-recorded by PR 30, whose ``compile_policy``
 packs what ``vm.simplify_ops`` keeps (182 of the 244 cases are programs and
-all 182 got shorter; the 62 errors are unchanged).
+all 182 got shorter; the 62 errors are unchanged), and again by PR 53, whose
+``simplify_ops`` folds a whole column chain into its grid (88 of the 182
+programs hold one and got shorter; the other 94 and the errors are
+unchanged, hash for hash).
 """
 import functools
 import glob

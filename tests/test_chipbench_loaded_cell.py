@@ -73,12 +73,12 @@ def test_cell_runs_end_to_end_and_counts_from_the_fork(monkeypatch,
         assert m in res["metrics"], m
         assert math.isfinite(res["metrics"][m]["value"]), m
     v = {m: res["metrics"][m]["value"] for m in res["metrics"]}
-    assert v["vm.register_mb"] == 4 * 561 * 64 * 8 * 8 / 1e6
+    assert v["vm.register_mb"] == 4 * 305 * 64 * 8 * 8 / 1e6
     assert v["sim.fork_state_ms"] > 0
     # the first 300 arrivals do not fill the cluster: no placement fails
     assert v["sim.retry_share"] == 0.0
     # per-event metrics divide by the window's events, not by the prefix
-    slots = v["vm.live_slot_share"] / 100 * 512
+    slots = v["vm.live_slot_share"] / 100 * 256     # a champion's bucket
     assert v["vm.us_per_slot"] == pytest.approx(
         v["vm.device_ms_per_event"] * 1e3 / slots, rel=1e-6)
     call_ms = sum(r["t1"] - r["t0"] for r in calls) / len(calls) * 1e3

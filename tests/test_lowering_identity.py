@@ -13,6 +13,13 @@ FakeLLM candidates), the generations the codegen drivers of the benchmark
 build for seeds 0-5 (jittered champions: other constants, the same op
 lists), and `EXTRA`, one source for each path of the interpreter that the
 rest does not walk. A case is one source: four lowerings.
+
+PR 53 re-recorded the ``packed`` digests, and those alone, from its own
+tree: its ``vm.simplify_ops`` is told the lowering's GPU width and folds
+a whole SETCOL chain into the grid it rebuilds (332 of the 752 digests,
+83 sources; ``tests/test_vm_chain_corpus.py`` holds the new program to
+the old one's bits). Every ``ops`` and
+``scores`` digest is PR 50's parent's.
 """
 import glob
 import hashlib
@@ -219,7 +226,7 @@ def outcome(code: str, scored: bool) -> dict:
                     case["ops"] = _digest(repr(raw))
                     try:
                         case["packed"] = lc.program_hash(vm.pack_program(
-                            *vm.simplify_ops(*raw), lc.CAPACITY))[:20]
+                            *vm.simplify_ops(*raw, g), lc.CAPACITY))[:20]
                     except vm.VMUnsupported as e:
                         case["packed"] = _failure(e)
                 if scored and (n, g) == lc.SHAPES[0]:

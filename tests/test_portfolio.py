@@ -621,7 +621,7 @@ def test_mixed_slot_batch_runs_to_the_longest_selected_program(
     eng = PortfolioEngine([_champ(SEED_LOGIC, 0.4, "<c0>"), long_], wl,
                           envelope=envelope, engine="flat", n_slots=3)
     n_short, n_long = (int(p.n_ops) for p in eng._slot_progs[:2])
-    assert n_short < n_long == 292 < eng.program_capacity == 512
+    assert n_short < n_long == 238 < eng.program_capacity == 256
     lanes, bucket = 2, 8
     fn = eng._make_serve_fn(bucket)
     batch = eng._example_batch(lanes, bucket)
@@ -647,7 +647,7 @@ def test_mixed_slot_batch_runs_to_the_longest_selected_program(
         got = [(r.fields["slots"], r.fields["capacity"])
                for r in eng.last_batch_spans
                if r.name == "serve/chunk/enqueue"]
-        assert got and set(got) == {(longest, 512)}
+        assert got and set(got) == {(longest, 256)}
 
 
 def test_slot_dispatch_writes_a_register_as_one_slice(wl, envelope):

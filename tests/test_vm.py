@@ -235,14 +235,15 @@ def test_transpile_stage_counts_sources_and_traces():
            ("seed:first_fit", "seed:best_fit", "vm:unsupported", "fake3:00",
             "rebind:conditional", "block:gpu_loop_if") if n in LOWERS]
     assert sp.fields["ops_lowered"] == sum(raw) > sp.fields["ops_kept"] > 0
+    assert sp.fields["chains_folded"] == 0       # no per-GPU generator here
     ev1 = backend.CodeEvaluator(corpus.mixed_workload(), vm_batch=False)
     mark = len(spans.LOG.snapshot())
     ev1.evaluate(codes[:1])
     (sp,) = [r for r in spans.LOG.snapshot()[mark:]
              if r.name == "tier/transpile"]
     assert sp.fields == {"sources": 1, "traces": 0, "ops_lowered": 0,
-                         "ops_kept": 0, "pooled": 0, "workers": 0,
-                         "clock_misfit": 0}
+                         "ops_kept": 0, "chains_folded": 0, "pooled": 0,
+                         "workers": 0, "clock_misfit": 0}
 
 
 # ---------------------------------------------------------------------------
@@ -306,8 +307,9 @@ def test_simplified_program_scores_as_the_raw_lowering_and_the_closure(
     for x64 in (True, False):
         with jax.enable_x64(x64):
             raw = vm.lower_ops(code, n, g)
-            kept = vm.simplify_ops(*raw)
-            assert len(kept[0]) <= len(raw[0])
+            kept = vm.simplify_ops(*raw, g)
+            assert len(kept[0]) <= len(vm.simplify_ops(*raw, None)[0]) \
+                <= len(raw[0])
             p_raw = vm.pack_program(*raw, corpus.CAPACITY)
             p_new = vm.pack_program(*kept, corpus.CAPACITY)
             policy = transpiler.build_policy(code)
@@ -324,19 +326,29 @@ def test_simplified_program_scores_as_the_raw_lowering_and_the_closure(
 
 @pytest.mark.parametrize("shape", ((16, 8), (64, 8), (1528, 8)),
                          ids=lambda s: corpus.shape_key(*s))
-def test_the_best_champion_keeps_292_of_370_ops(shape):
+def test_the_best_champion_keeps_238_of_370_ops(shape):
     code = corpus.sources()["champion:20260801_045536_score0.5365"]
     raw = vm.lower_ops(code, *shape)
-    kept = vm.simplify_ops(*raw)
-    assert (len(raw[0]), len(kept[0])) == (370, 292)
+    folds = vm.chains_folded()
+    kept = vm.simplify_ops(*raw, shape[1])
+    assert (len(raw[0]), len(kept[0])) == (370, 238)
+    assert vm.chains_folded() - folds == 5
     count = lambda ops, op: sum(o[0] == op for o in ops)
     # half the products were a 0/1 mask times a factor it already held
-    assert (count(raw[0], vm.OP_MUL), count(kept[0], vm.OP_MUL)) == (130, 66)
-    # the grids rebuilt column by column stay (see simplify_ops)
-    assert count(raw[0], vm.OP_SETCOL) == count(kept[0], vm.OP_SETCOL) == 40
-    assert int(vm.compile_policy(code, *shape).n_ops) == 292
+    assert (count(raw[0], vm.OP_MUL), count(kept[0], vm.OP_MUL)) == (130, 59)
+    # the five grids rebuilt column by column are the grids they rebuild:
+    # no SETCOL is left, nor the COLs and MULs only a chain read
+    assert (count(raw[0], vm.OP_SETCOL), count(kept[0], vm.OP_SETCOL)) \
+        == (40, 0)
+    assert (count(raw[0], vm.OP_COL), count(kept[0], vm.OP_COL)) == (24, 16)
+    # told no width, the pass keeps every chain: PR 30's program
+    plain = vm.simplify_ops(*raw, None)
+    assert (len(plain[0]), count(plain[0], vm.OP_SETCOL)) == (292, 40)
+    prog = vm.compile_policy(code, *shape)
+    assert (int(prog.n_ops), prog.capacity) == (238, 256)
     low = lower_pool.lower_source(code, *shape)
-    assert (low.ops_lowered, len(low.kept[0]), low.traces) == (370, 292, 1)
+    assert (low.ops_lowered, len(low.kept[0]), low.traces,
+            low.chains_folded) == (370, 238, 1, 5)
 
 
 # -- rule by rule, on hand-made op lists --------------------------------
@@ -368,20 +380,38 @@ def _same_bits(x, y):
         np.array_equal(np.signbit(x), np.signbit(y))
 
 
-def _check(ops, consts, out_reg, n_kept, g=4, out_new=None):
-    """simplify_ops keeps ``n_kept`` ops and the output register holds the
-    raw program's bits on an edge state and a random one."""
-    kept = vm.simplify_ops(ops, consts, out_reg)
+def _poisoned(rng, nodes):
+    """``nodes`` with NaN, inf, -inf and -0.0 strewn over its three GPU
+    grids, as floats (``vm._inputs`` takes them as they are): what no
+    engine hands a policy and a rule that holds for ALL inputs must
+    survive."""
+    def strew(grid):
+        grid = np.asarray(grid, np.float64)
+        bad = rng.choice([np.nan, np.inf, -np.inf, -0.0], grid.shape)
+        return jnp.asarray(np.where(rng.random(grid.shape) < 0.4, bad, grid))
+
+    return nodes._replace(**{f: strew(getattr(nodes, f))
+                             for f in vm._NODE_GRIDS})
+
+
+def _check(ops, consts, out_reg, n_kept, g=4, out_new=None, told="g"):
+    """simplify_ops, told the width ``told`` (the states' own ``g`` unless
+    given), keeps ``n_kept`` ops and the output register holds the raw
+    program's bits on an edge state, a random one and a poisoned one."""
+    kept = vm.simplify_ops(ops, consts, out_reg, g if told == "g" else told)
     assert len(kept[0]) == n_kept, kept
     if out_new is not None:
         assert kept[2] == out_new
     rng = np.random.default_rng(11)
-    for kind in ("edge", "random"):
+    cap = vm.capacity_bucket(len(ops))
+    for kind in ("edge", "random", "poisoned"):
         pod, nodes = _state(rng, 4, g, kind)
+        if kind == "poisoned":
+            nodes = _poisoned(rng, nodes)
         want = _registers(ops, consts, pod, nodes)[out_reg]
         got = _registers(*kept[:2], pod, nodes)[kept[2]]
         assert _same_bits(got, want), (kind, got, want)
-        p_raw, p_new = (vm.pack_program(*t, 64)
+        p_raw, p_new = (vm.pack_program(*t, cap)
                         for t in ((ops, consts, out_reg), kept))
         np.testing.assert_array_equal(np.asarray(_SCORE(p_new, pod, nodes)),
                                       np.asarray(_SCORE(p_raw, pod, nodes)))
@@ -462,36 +492,191 @@ def test_simplify_rule(ops, out, n_kept, out_new):
     _check(ops, C, out, n_kept, out_new=out_new)
 
 
-def _chain(base, sources, cols=None):
-    """COL(src_j, j) into column j of a SETCOL chain from ``base``."""
+# -- the whole-grid column chain (PR 53) ---------------------------------
+
+GPU_TOTAL, GPU_MILLI = 13, 3   # gpu_milli_total; the pod's gpu_milli
+W = 4                          # the width the hand-made chains are made at
+
+
+def _chain(value, cols=range(W), base=ZERO, at=R0):
+    """A SETCOL chain from ``base`` whose first op is register ``at``:
+    for every ``j`` of ``cols`` in turn, ``value(j, r)`` gives the ops
+    that compute column j's value, the first of them register ``r`` and
+    the last the value (none: ``value`` returned a register), then the
+    link. ``(ops, the last link's register)``."""
     ops, acc = [], base
-    for j, src in enumerate(sources):
-        if cols is not None and j not in cols:
-            continue
-        ops.append((vm.OP_COL, src, 0, 0, float(j)))
-        ops.append((vm.OP_SETCOL, acc, R0 + len(ops) - 1, 0, float(j)))
-        acc = R0 + len(ops) - 1
+    for j in cols:
+        v = value(j, at + len(ops))
+        if isinstance(v, list):
+            ops += v
+            v = at + len(ops) - 1
+        ops.append((vm.OP_SETCOL, acc, v, 0, float(j)))
+        acc = at + len(ops) - 1
     return ops, acc
 
 
-@pytest.mark.parametrize("sources,cols,n_kept", [
-    ([GPU_LEFT] * 4, None, 8),     # x rebuilt whole: exact, but not a rule
-    ([GPU_LEFT] * 4, (0, 1, 2), 6),                 # column 3 never written
-    ([GPU_LEFT, GPU_LEFT, GPU_MASK, GPU_LEFT], None, 8),   # two sources
+def _col(x, shift=0):
+    return lambda j, r: [(vm.OP_COL, x, 0, 0, float((j + shift) % W))]
+
+
+def _over(op, *operands):
+    """Column j's value: ``op`` over ``operands``, each a register (the
+    same for every column) or a function like this one's result."""
+    def value(j, r):
+        ops, regs = [], []
+        for x in operands:
+            if callable(x):
+                ops += x(j, r + len(ops))
+                x = r + len(ops) - 1
+            regs.append(x)
+        return ops + [(op, *regs, *[0] * (3 - len(regs)), 0.0)]
+    return value
+
+
+MASKED_FIT = _over(vm.OP_MUL, _col(GPU_MASK),
+                   _over(vm.OP_GE, _col(GPU_LEFT), GPU_MILLI))
+
+
+@pytest.mark.parametrize("value,kw,n_kept,out_new", [
+    # rule 1, one register into every column: a pool constant (what
+    # ``sum(1 for gpu in node.gpus)`` stacks), a grid, an op's result
+    (lambda j, r: TWO, {}, 0, TWO),
+    (lambda j, r: GPU_LEFT, {}, 0, GPU_LEFT),
+    (lambda j, r: R0, {"at": R0 + 1}, 1, R0),
+    # rule 2, COL(x, j) into column j: the chain IS x, in any order of
+    # the columns and from any base, which never shows
+    (_col(GPU_LEFT), {}, 0, GPU_LEFT),
+    (_col(GPU_MASK), {"cols": (2, 0, 3, 1)}, 0, GPU_MASK),
+    (_col(GPU_TOTAL), {"base": CPU_LEFT}, 0, GPU_TOTAL),
+    # ... and the newest W links say: a column written before them is
+    # overwritten like the base
+    (lambda j, r: TWO if r == R0 else _col(GPU_LEFT)(j, r),
+     {"cols": (1, 0, 1, 2, 3)}, 0, GPU_LEFT),
+    # rule 3, one elementwise opcode over columns: unary, binary with a
+    # register all columns share, three operands, two levels
+    (_over(vm.OP_SQRT, _col(GPU_LEFT)), {}, 1, R0),
+    (_over(vm.OP_DIV, _col(GPU_LEFT), CPU_TOTAL), {}, 1, R0),
+    (_over(vm.OP_SEL, _col(GPU_MASK), TWO, _col(GPU_LEFT)), {}, 1, R0),
+    (_over(vm.OP_ADD, _over(vm.OP_MUL, _col(GPU_LEFT), TWO),
+           _col(GPU_TOTAL)), {}, 2, R0 + 1),
+    (MASKED_FIT, {}, 2, R0 + 1),
+    (_over(vm.OP_SUB, _col(GPU_TOTAL, 0), R0), {"at": R0 + 1}, 2, R0 + 1),
 ])
-def test_setcol_chains_stay(sources, cols, n_kept):
-    """No rule reads SETCOL: a chain keeps every op, whether it rebuilds
-    one register column by column, stops short or mixes two."""
-    ops, acc = _chain(ZERO, sources, cols)
-    _check(ops, C, acc, n_kept)
+def test_a_whole_column_chain_folds(value, kw, n_kept, out_new):
+    ops, acc = _chain(value, **kw)
+    if kw.get("at"):                  # R0: an op's result, NaN on the edge
+        ops = [DIV0] + ops
+    folds = vm.chains_folded()
+    _check(ops, C, acc, n_kept, out_new=out_new)
+    assert vm.chains_folded() - folds == 1
+    # a reader of the grid reads the folded register
     _check(ops + [(vm.OP_RSUM_G, acc, 0, 0, 0.0)], C, R0 + len(ops),
            n_kept + 1)
 
 
-def test_a_constant_written_into_every_column_stays():
-    ones = [(vm.OP_SETCOL, ZERO if j == 0 else R0 + j - 1, TWO, 0, float(j))
-            for j in range(4)]
-    _check(ones, C, R0 + 3, 4)
+@pytest.mark.parametrize("value,kw,n_kept", [
+    (_col(GPU_LEFT), {"cols": (0, 1, 2)}, 6),       # column 3 never written
+    (_col(GPU_LEFT), {"cols": (0, 1, 2, 2)}, 7),    # ... or 2 written twice
+    (_col(GPU_LEFT), {"cols": (0, 1, 2, 4)}, 7),    # ... or no column at all
+    # two grids; two opcodes; another column than the link's
+    (lambda j, r: _col(GPU_MASK if j == 2 else GPU_LEFT)(j, r), {}, 8),
+    (lambda j, r: _over(vm.OP_ABS if j == 2 else vm.OP_SQRT,
+                        _col(GPU_LEFT))(j, r), {}, 12),
+    (_col(GPU_LEFT, shift=1), {}, 8),
+    # an operand that is a reduction over G, a COL of a COL, a link of
+    # another chain: column j of these is not the op over column j
+    (_over(vm.OP_RSUM_G, _col(GPU_LEFT)), {}, 12),
+    (_over(vm.OP_MUL, _col(GPU_MASK), _over(vm.OP_RMAX_G, _col(GPU_LEFT))),
+     {}, 20),
+    (_over(vm.OP_COL, _col(GPU_LEFT)), {}, 12),     # COL(COL(x, j), 0)
+    (lambda j, r: [(vm.OP_SETCOL, GPU_LEFT, TWO, 0, float(j))], {}, 8),
+    # a stack placeholder has identity and is never lifted
+    (_over(vm.OP_NOP, _col(GPU_LEFT)), {}, 12),
+    # an operand that differs from column to column and is no COL
+    (lambda j, r: _over(vm.OP_ADD, _col(GPU_LEFT), (CPU_LEFT, CPU_TOTAL,
+                                                    CPU_LEFT, 8)[j])(j, r),
+     {}, 12),
+])
+def test_a_chain_that_is_no_whole_grid_stays(value, kw, n_kept):
+    ops, acc = _chain(value, **kw)
+    folds = vm.chains_folded()
+    _check(ops, C, acc, n_kept)
+    _check(ops + [(vm.OP_RSUM_G, acc, 0, 0, 0.0)], C, R0 + len(ops),
+           n_kept + 1)
+    assert vm.chains_folded() == folds
+
+
+@pytest.mark.parametrize("told", (None, 8, 5), ids=lambda t: f"told_{t}")
+def test_a_chain_of_another_width_than_the_grid_stays(told):
+    """The W columns of a chain are a whole grid only on a grid W wide:
+    told no width, or another than the chain's, every link stays and the
+    program is word for word what the pass made of it before PR 53."""
+    ops, acc = _chain(MASKED_FIT)
+    g = told or W
+    kept = _check(ops, C, acc, len(ops), g=g, told=told)
+    assert kept == (ops, C, acc)
+
+
+def test_a_link_another_op_reads_keeps_its_value():
+    """The fold replaces the LAST link; an earlier one is what it was for
+    its other reader (here: columns 0-1 of gpu_milli_left over 0.0)."""
+    ops, acc = _chain(_col(GPU_LEFT))
+    half = R0 + 3                                   # the second link
+    ops += [(vm.OP_RSUM_G, half, 0, 0, 0.0),
+            (vm.OP_ADD, acc, R0 + len(ops), 0, 0.0)]
+    kept = _check(ops, C, R0 + len(ops) - 1, 6)
+    assert [op for op, *_ in kept[0]] == [
+        vm.OP_COL, vm.OP_SETCOL, vm.OP_COL, vm.OP_SETCOL, vm.OP_RSUM_G,
+        vm.OP_ADD]
+    assert kept[0][-1][1] == GPU_LEFT               # ADD reads the grid
+
+
+def test_a_folded_grid_meets_the_rules_of_the_0_1_domain():
+    """The lifted op is entered like any other: typed 0/1, numbered, and
+    a product that already holds a factor absorbs it."""
+    ops, acc = _chain(MASKED_FIT)
+    n = len(ops)
+    ops += [(vm.OP_MUL, acc, GPU_MASK, 0, 0.0),     # holds gpu_mask already
+            (vm.OP_GE, GPU_LEFT, GPU_MILLI, 0, 0.0),  # the lifted GE, again
+            (vm.OP_MUL, R0 + n, R0 + n + 1, 0, 0.0)]  # ... and holds it
+    _check(ops, C, R0 + n + 2, 2, out_new=R0 + 1)
+
+
+def test_two_chains_over_the_same_columns_are_one_grid():
+    ops, acc = _chain(MASKED_FIT)
+    more, acc2 = _chain(MASKED_FIT, base=ONE, at=R0 + len(ops))
+    ops += more + [(vm.OP_SUB, acc, acc2, 0, 0.0)]
+    kept = _check(ops, C, R0 + len(ops) - 1, 3)
+    assert kept[0][-1][:3] == (vm.OP_SUB, R0 + 1, R0 + 1)
+
+
+def test_a_deep_column_expression_stays_column_by_column():
+    """`_lift` recurses over a column's expression and a candidate is
+    untrusted: past ``vm._LIFT_DEPTH`` the chain stays, and one level
+    short of it the chain folds."""
+    def nest(depth):
+        value = _col(GPU_LEFT)
+        for _ in range(depth):
+            value = _over(vm.OP_ABS, _over(vm.OP_SUB, value, TWO))
+        return _chain(value)
+
+    ops, acc = nest(vm._LIFT_DEPTH // 2 - 1)        # COL at the last level
+    assert len(_check(ops, C, acc, vm._LIFT_DEPTH - 2)[0]) < len(ops)
+    ops, acc = nest(vm._LIFT_DEPTH // 2)
+    _check(ops, C, acc, len(ops))
+
+
+def test_a_shared_subexpression_is_lifted_once():
+    """``x + x`` thirty levels deep is thirty ops a column and thirty
+    lifted: the memo keeps the walk linear (2 ** 30 without it)."""
+    def value(j, r):
+        ops = [(vm.OP_COL, GPU_LEFT, 0, 0, float(j))]
+        for k in range(30):
+            ops.append((vm.OP_ADD, r + k, r + k, 0, 0.0))
+        return ops
+
+    ops, acc = _chain(value)
+    _check(ops, C, acc, 30)
 
 
 def test_simplify_adds_the_pool_zero_only_when_there_is_room():
@@ -510,7 +695,7 @@ def test_positive_and_negative_zero_stay_distinct_pool_constants():
         "score = 100 + 1.0 / (0.0 * node.gpu_left + -0.0) "
         "+ 1.0 / (0.0 * node.gpu_left + 0.0)")
     raw = vm.lower_ops(code, 4, 4)
-    kept = vm.simplify_ops(*raw)
+    kept = vm.simplify_ops(*raw, 4)
     signs = sorted(np.signbit(v) for v in kept[1] if v == 0.0)
     assert signs == [False, True]
 

@@ -274,7 +274,7 @@ def test_swaps_and_batches_leave_no_array_behind(wl, envelope):
 
 
 def _ledger_champion(score=0.9):
-    """The pinned ledger champion: 292 live ops, the 512 bucket."""
+    """The pinned ledger champion: 238 live ops, the 256 bucket."""
     from tests.test_vm_batch import _champion_code
 
     return ChampionSpec(code=_champion_code(), score=score,
@@ -294,14 +294,14 @@ def test_swap_between_lengths_in_one_bucket_compiles_nothing(wl, envelope):
     reference's."""
     short, long_ = _champ(SEED_LOGIC, 0.4, "<short>"), _ledger_champion()
     eng = VMServeEngine(short, wl, envelope=envelope, engine="flat",
-                        program_capacity=512)
+                        program_capacity=256)
     eng.warmup()
     n_short = int(eng.params.n_ops)
     queries = [_query(3), _query(9, 5)]
     eng.answer_batch(queries)  # the eager stacking ops of this batch shape
     # compile_policy's own eager dtype cast compiles once per capacity it
-    # lowers AT (the short source lowered at 256 and was padded): not the
-    # swap's, and not the serve executables'
+    # lowers AT (256, the bucket of both sources): not the swap's, and
+    # not the serve executables'
     c = wl.cluster
     vm.compile_policy(long_.code, c.n_padded, c.g_padded)
     watcher = CompileWatcher().install()
@@ -317,9 +317,9 @@ def test_swap_between_lengths_in_one_bucket_compiles_nothing(wl, envelope):
     finally:
         watcher.uninstall()
     assert compiles == 0, f"{compiles} programs compiled across the swaps"
-    assert n_short < 292 <= eng.program_capacity == 512
-    assert f_short and set(f_short) == {(n_short, 512)}
-    assert f_long and set(f_long) == {(292, 512)}
+    assert n_short < 238 <= eng.program_capacity == 256
+    assert f_short and set(f_short) == {(n_short, 256)}
+    assert f_long and set(f_long) == {(238, 256)}
     for q, a, b in zip(queries, a_short, a_back):
         ref = eng.reference_answer(q)
         assert a["score"] == b["score"]

@@ -13,9 +13,11 @@ model, not a clock: this says WHAT a change does to the program.
     JAX_PLATFORMS=cpu python tools/describe_compile.py whatif --hlo /tmp/w.hlo
 
 ``population``: ``make_population_eval(engine="flat")`` at param256's
-shapes. ``codegen``: the batched VM tier's population runner, 8 lanes.
-``whatif``: ``VMServeEngine``'s executable for 2 lanes of the 256-pod
-bucket on the exact engine (whatif8's largest chunk). ``--cluster 1523`` is the OpenB cluster (under
+shapes. ``codegen``: the batched VM tier's population runner, 8 lanes of
+a ledger champion at its own capacity bucket (the generation's register
+file). ``whatif``: ``VMServeEngine``'s executable, for that champion, for
+2 lanes of the 256-pod bucket on the exact engine (whatif8's largest
+chunk). ``--cluster 1523`` is the OpenB cluster (under
 the program's own large-cluster rule) with the inflated trace;
 ``--cluster 1523-gpuspec25`` the same cluster with the inflated gpuspec25
 list and its GPU-type constraints honoured (the step's type term).
@@ -30,7 +32,8 @@ import os
 import re
 import sys
 
-sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+sys.path.insert(0, ROOT)
 # quiet libtpu's search for a metadata server; the topology is described
 os.environ.setdefault("TPU_ACCELERATOR_TYPE", "v5litepod-4")
 os.environ.setdefault("TPU_WORKER_HOSTNAMES", "localhost")
@@ -220,8 +223,20 @@ def population(device, cluster: str, lanes: int) -> str:
         device, ev, jnp.zeros((lanes, parametric.NUM_FEATURES), jnp.float32))
 
 
+def _champion() -> str:
+    """The ledger champion the benchmark's VM cells hold (score 0.5365):
+    the longest program of every generation and the one serving answers
+    with, so its capacity bucket is the register file's."""
+    import json
+
+    with open(os.path.join(
+            ROOT, "policies", "discovered",
+            "funsearch_20260801_045536_score0.5365.json")) as f:
+        return json.load(f)["code"]
+
+
 def codegen(device, cluster: str, lanes: int) -> str:
-    from fks_tpu.funsearch import template, vm
+    from fks_tpu.funsearch import vm
     from fks_tpu.sim import flat
     from fks_tpu.sim.engine import SimConfig, shape_prefilter_k
 
@@ -230,22 +245,20 @@ def codegen(device, cluster: str, lanes: int) -> str:
     cfg = SimConfig(max_steps=2048 if cluster == "16" else 1024,
                     node_prefilter_k=shape_prefilter_k(c.n_padded))
     view = cfg.resolve_prefilter_k(c.n_padded) or c.n_padded
-    prog = vm.compile_policy(template.seed_policies()["best_fit"], view,
-                             c.g_padded, capacity=512)
-    stacked = vm.stack_programs([prog] * lanes, capacity=512)
+    prog = vm.compile_policy(_champion(), view, c.g_padded)
+    stacked = vm.stack_programs([prog] * lanes)
     return compile_for(device,
                        flat.make_population_run_fn(wl, vm.score, cfg),
                        stacked, flat.initial_state(wl, cfg))
 
 
 def whatif(device, cluster: str, lanes: int) -> str:
-    from fks_tpu.funsearch import template
     from fks_tpu.serve import ChampionSpec, VMServeEngine
     from fks_tpu.sim.engine import shape_prefilter_k
 
     wl = _workload(cluster)
     eng = VMServeEngine(
-        ChampionSpec(code=template.seed_policies()["best_fit"]), wl,
+        ChampionSpec(code=_champion()), wl,
         engine="exact", prefilter_k=shape_prefilter_k(wl.cluster.n_padded))
     bucket = 256
     example = (eng._prog_dev,) + eng._example_batch(lanes, bucket)
